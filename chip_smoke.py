@@ -1,0 +1,796 @@
+"""chip_smoke.py — the quickest proof that the training main path still
+starts on the chip.
+
+One process, no children.  It drives the path a user of this framework
+runs — ``hvd.init()`` -> the default ``dp`` mesh over every local chip ->
+``hvd.DistributedOptimizer`` fed per-rank gradients (``pvary_tree``, so
+the framework's own fused, bucketed exchange is in the compiled program)
+-> ``hvd.donated_step`` -> a few steps — at the full width of the two
+models the repo has on-chip history for, and checks what comes out:
+
+* the ``bert-large`` LM (seq 512) and ResNet-50 (bs 128 per chip): one
+  compile, one warm-up, three steps, loss finite and falling;
+* the same LM at seq 4096, where ``auto`` must pick the streaming Pallas
+  attention kernel with no knob set, against XLA attention on the forward;
+* every ``pallas_call`` the package ships, compiled through Mosaic once
+  and compared with the ``jnp`` reference beside it;
+* on more than one chip: the work and the memory are spread over all of
+  them, the compiled step holds all-reduces over every replica that carry
+  the whole gradient pytree, and ``dp=n`` training matches one device.
+
+The step times printed are smoke timings of a handful of steps, not a
+benchmark.  Without a TPU the script exits non-zero having run nothing;
+its ``__main__`` has no CPU route.  The phase functions take their sizes
+as arguments so tests/test_chip_smoke.py can call them at toy size on the
+CPU simulator.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import json
+import os
+import re
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+
+# examples/jax_transformer_lm.py PRESETS["bert-large"]
+BERT_LARGE = dict(layers=24, d_model=1024, heads=16, d_ff=4096, vocab=30528,
+                  loss_chunk=8192)
+# Sequences per chip for the seq-512 LM: the recorded configuration, which
+# fits 16 GB on this path too.
+LM_PER_CHIP_BATCH = 128
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+@contextlib.contextmanager
+def env_knob(name: str, value: str):
+    """Set one env knob for the duration of a trace (the model reads its
+    knobs at trace time)."""
+    before = os.environ.get(name)
+    os.environ[name] = value
+    try:
+        yield
+    finally:
+        if before is None:
+            os.environ.pop(name, None)
+        else:
+            os.environ[name] = before
+
+
+def fetch_losses(run_step, steps: int):
+    """Warm-up plus ``steps`` timed calls of ``run_step() -> loss``, each
+    ended by a host fetch of the loss.  Returns (losses, seconds) with the
+    warm-up first."""
+    losses, secs = [], []
+    for _ in range(steps + 1):
+        t0 = time.perf_counter()
+        losses.append(float(run_step()))
+        secs.append(time.perf_counter() - t0)
+    return losses, secs
+
+
+def check_losses(name: str, losses) -> None:
+    import math
+
+    if not all(math.isfinite(v) for v in losses):
+        raise AssertionError(f"{name}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(
+            f"{name}: loss did not fall on the fixed batch: {losses}")
+
+
+# ---------------------------------------------------------------------------
+# The main path: DistributedOptimizer over per-rank gradients inside
+# shard_map over dp, jitted by donated_step.
+# ---------------------------------------------------------------------------
+
+
+def build_dp_step(mesh, loss_fn, optimizer, n_batch_args: int):
+    """``step(params, opt_state, *batch) -> (params, opt_state, loss)``
+    over ``mesh``'s ``dp`` axis, plus the DistributedOptimizer it uses.
+    ``loss_fn(params, *local_batch)`` sees one rank's shard."""
+    import jax
+    import optax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    import horovod_tpu as hvd
+
+    opt = hvd.DistributedOptimizer(optimizer)
+
+    def local_step(params, opt_state, *batch):
+        # Per-rank gradients: with unvarying params AD would psum the
+        # cotangents itself and the exchange layer would be bypassed.
+        diff = hvd.optimizer.pvary_tree(params, "dp")
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_fn(p, *batch))(diff)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                lax.pmean(loss, "dp"))
+
+    step = hvd.donated_step(jax.shard_map(
+        local_step, mesh=mesh,
+        in_specs=(P(), P()) + (P("dp"),) * n_batch_args,
+        out_specs=(P(), P(), P())), donate_argnums=(0, 1))
+    return step, opt
+
+
+def place(mesh, tree, spec):
+    import jax
+    from jax.sharding import NamedSharding
+
+    return jax.device_put(tree, NamedSharding(mesh, spec))
+
+
+def compile_step(name: str, step, *args):
+    """AOT-compile ``step`` once; the returned executable is what the
+    phase then runs, so compile time and step time stay apart."""
+    t0 = time.perf_counter()
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    say(f"[{name}] compile {time.perf_counter() - t0:.1f} s; per device: "
+        f"arguments {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
+        f"temporaries {mem.temp_size_in_bytes / 2**30:.2f} GiB")
+    return compiled
+
+
+def lm_config(seq: int, *, dtype=None, **model):
+    """``model`` is BERT_LARGE or a toy of the same keys."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import TransformerConfig
+
+    return TransformerConfig(
+        kv_heads=model["heads"], max_seq=seq, dtype=dtype or jnp.bfloat16,
+        remat=True, **model)
+
+
+def lm_setup(mesh, cfg, global_batch: int, seed: int = 0):
+    """(compiled-step inputs) for the LM on ``mesh``: params and AdamW
+    state replicated, one fixed token batch sharded over dp."""
+    import jax
+    import numpy as np
+    import optax
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.models import transformer_init, transformer_loss
+
+    step, opt = build_dp_step(
+        mesh, lambda p, t: transformer_loss(p, t, cfg), optax.adamw(3e-4),
+        n_batch_args=1)
+    params = place(mesh, transformer_init(jax.random.PRNGKey(seed), cfg),
+                   P())
+    opt_state = place(mesh, opt.init(params), P())
+    tokens = place(mesh, np.random.default_rng(seed).integers(
+        0, cfg.vocab, (global_batch, cfg.max_seq)).astype(np.int32),
+        P("dp"))
+    return step, params, opt_state, tokens
+
+
+def run_steps(name: str, compiled, params, opt_state, batch, steps: int):
+    """Warm-up plus ``steps`` steps of ``compiled`` on one fixed batch,
+    losses checked and timings printed; returns (losses, params)."""
+    state = [params, opt_state]
+
+    def one():
+        state[0], state[1], loss = compiled(state[0], state[1], *batch)
+        return loss
+
+    losses, secs = fetch_losses(one, steps)
+    check_losses(name, losses)
+    say(f"[{name}] warm-up {secs[0]:.2f} s; smoke step times "
+        + " ".join(f"{s:.3f}" for s in secs[1:]) + " s; loss "
+        + " -> ".join(f"{v:.4f}" for v in losses))
+    return losses, state[0]
+
+
+def run_lm(name: str, mesh, cfg, global_batch: int, steps: int,
+           inspect=None):
+    """Compile once, warm up, take ``steps`` steps of the LM; returns
+    (losses, params, tokens).  ``inspect(compiled, params, tokens)`` runs
+    between compile and the first step."""
+    step, params, opt_state, tokens = lm_setup(mesh, cfg, global_batch)
+    compiled = compile_step(name, step, params, opt_state, tokens)
+    if inspect is not None:
+        inspect(compiled, params, tokens)
+    losses, params = run_steps(name, compiled, params, opt_state,
+                               (tokens,), steps)
+    return losses, params, tokens
+
+
+def phase_lm(mesh, *, seq: int, per_chip_batch: int, steps: int = 3,
+             model=BERT_LARGE, inspect=None):
+    """The LM at ``seq`` on the whole mesh."""
+    n = mesh.devices.size
+    cfg = lm_config(seq, **model)
+    return run_lm(f"lm seq{seq} b{per_chip_batch}x{n}", mesh, cfg,
+                  per_chip_batch * n, steps, inspect)[0]
+
+
+def phase_resnet(mesh, *, per_chip_batch: int, image_size: int = 224,
+                 steps: int = 3, depth: int = 50, num_classes: int = 1000):
+    """ResNet, bf16, SGD-momentum — the recipe of
+    examples/jax_synthetic_benchmark.py on the main path."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.models import ResNetConfig, resnet50_init, resnet_loss
+
+    n = mesh.devices.size
+    name = f"resnet{depth} b{per_chip_batch}x{n}"
+    cfg = ResNetConfig(num_classes=num_classes, dtype=jnp.bfloat16,
+                       depth=depth)
+    params, stats = resnet50_init(jax.random.PRNGKey(0), cfg)
+    step, opt = build_dp_step(
+        mesh, lambda p, x, y: resnet_loss(p, stats, x, y, cfg)[0],
+        optax.sgd(0.01, momentum=0.9), n_batch_args=2)
+    params = place(mesh, params, P())
+    opt_state = place(mesh, opt.init(params), P())
+    batch = per_chip_batch * n
+    images = place(mesh, jax.random.normal(
+        jax.random.PRNGKey(1), (batch, image_size, image_size, 3),
+        jnp.bfloat16), P("dp"))
+    labels = place(mesh, jax.random.randint(
+        jax.random.PRNGKey(2), (batch,), 0, num_classes), P("dp"))
+    compiled = compile_step(name, step, params, opt_state, images, labels)
+    return run_steps(name, compiled, params, opt_state, (images, labels),
+                     steps)[0]
+
+
+def phase_long_seq(mesh, *, seq: int, per_chip_batch: int, steps: int = 2,
+                   model=BERT_LARGE, compare_sequences: int = 2,
+                   tol: float = 2e-2):
+    """The LM at a length where the flash kernel is what ``_flash_fn``
+    returns for the per-chip shapes, then the forward loss of the trained
+    weights on the batch's first ``compare_sequences`` sequences: Pallas
+    kernel against XLA attention (forward only — XLA attention with its
+    backward does not fit at 4096)."""
+    import jax
+    import numpy as np
+
+    from horovod_tpu.models import transformer as tr
+    from horovod_tpu.models import transformer_loss
+    from horovod_tpu.ops.pallas_kernels import _use_interpret
+
+    n = mesh.devices.size
+    cfg = lm_config(seq, **model)
+    name = f"lm seq{seq} b{per_chip_batch}x{n}"
+    if tr._flash_fn(seq, cfg.head_dim, batch=per_chip_batch,
+                    heads=cfg.heads) is None:
+        raise AssertionError(
+            f"{name}: the flash kernel was not selected "
+            f"(HVDT_FLASH_ATTENTION="
+            f"{os.environ.get('HVDT_FLASH_ATTENTION', '<unset>')!r})")
+
+    def inspect(compiled, params, tokens):
+        if not _use_interpret() and "tpu_custom_call" not in \
+                compiled.as_text():
+            raise AssertionError(
+                f"{name}: no Mosaic custom call in the compiled step")
+
+    _, params, tokens = run_lm(name, mesh, cfg, per_chip_batch * n, steps,
+                               inspect)
+
+    # Both attention paths on ONE device: a Mosaic kernel outside
+    # shard_map cannot be partitioned over the mesh the weights sit on.
+    params = jax.device_put(params, mesh.devices.flat[0])
+    toks = np.asarray(tokens[:compare_sequences])
+    fwd = {}
+    for mode in ("on", "off"):
+        with env_knob("HVDT_FLASH_ATTENTION", mode):
+            fwd[mode] = float(jax.jit(
+                lambda p, t: transformer_loss(p, t, cfg))(params, toks))
+    rel = abs(fwd["on"] - fwd["off"]) / abs(fwd["off"])
+    say(f"[{name}] forward loss on {compare_sequences} sequences: "
+        f"pallas {fwd['on']:.5f} vs xla {fwd['off']:.5f} (rel {rel:.2e})")
+    if not rel < tol:
+        raise AssertionError(
+            f"{name}: flash and XLA attention disagree: {fwd}")
+    return fwd
+
+
+# ---------------------------------------------------------------------------
+# More than one chip: who did the work, whose exchange, is it right.
+# ---------------------------------------------------------------------------
+
+
+def assert_sharded_over(array, n: int) -> None:
+    devs = {s.device for s in array.addressable_shards}
+    if len(devs) != n:
+        raise AssertionError(
+            f"batch sits on {len(devs)} device(s), expected {n}")
+
+
+def assert_even_peak_memory(devices, tolerance: float = 0.25):
+    """Every device's lifetime peak within ``tolerance`` of device 0's."""
+    peaks = [d.memory_stats()["peak_bytes_in_use"] for d in devices]
+    say("peak_bytes_in_use per device: "
+        + " ".join(f"{p / 2**30:.2f}GiB" for p in peaks)
+        + f" (limit {devices[0].memory_stats()['bytes_limit'] / 2**30:.2f}"
+        "GiB)")
+    for d, p in zip(devices, peaks):
+        if abs(p - peaks[0]) > tolerance * peaks[0]:
+            raise AssertionError(
+                f"{d} peaked at {p} bytes, device 0 at {peaks[0]}")
+    return peaks
+
+
+_SHAPE_RE = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]")
+_ALLREDUCE_RE = re.compile(
+    r"=\s+(?P<shape>.*?)\s+all-reduce(?:-start)?\((?P<rest>.*)$")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+                "u64": 8}
+
+
+def hlo_allreduces(hlo_text: str):
+    """(operand bytes, replica-group size) of every all-reduce (or
+    all-reduce-start) in an HLO module's text."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _ALLREDUCE_RE.search(line)
+        if not m:
+            continue
+        nbytes = 0
+        for dtype, dims in _SHAPE_RE.findall(m.group("shape")):
+            count = 1
+            for d in filter(None, dims.split(",")):
+                count *= int(d)
+            nbytes += count * _DTYPE_BYTES[dtype]
+        rest = m.group("rest")
+        g = re.search(r"replica_groups=\{\{([\d,]*)\}", rest)
+        if g:
+            group = len(g.group(1).split(","))
+        else:
+            g = re.search(r"replica_groups=\[(\d+),(\d+)\]", rest)
+            group = int(g.group(2)) if g else 0
+        out.append((nbytes, group))
+    return out
+
+
+def check_exchange(name: str, compiled, params, n: int) -> None:
+    """The compiled step's all-reduces span all ``n`` replicas and carry
+    the gradient pytree's bytes.  XLA's combiner may merge buckets, so the
+    count is printed beside the bucket plan's, not compared."""
+    import jax
+
+    from horovod_tpu.ops.device import fused_allreduce_buckets
+
+    leaves = jax.tree.leaves(params)
+    grad_bytes = sum(l.size * l.dtype.itemsize for l in leaves)
+    plan = fused_allreduce_buckets(leaves, None)
+    found = hlo_allreduces(compiled.as_text())
+    over_all = [b for b, g in found if g == n]
+    say(f"[{name}] HLO all-reduces: {len(found)} ({len(over_all)} over "
+        f"{n} replicas, {sum(over_all)} bytes); bucket plan: {len(plan)} "
+        f"buckets, gradient pytree {grad_bytes} bytes")
+    # The scalar loss pmean rides along, hence the small allowance.
+    if not grad_bytes <= sum(over_all) <= grad_bytes * 1.001 + 4096:
+        raise AssertionError(
+            f"{name}: all-reduces over {n} replicas carry "
+            f"{sum(over_all)} bytes, the gradient pytree is {grad_bytes}")
+
+
+def phase_dp_matches_single(devices, *, seq: int, global_batch: int,
+                            steps: int = 3, model=BERT_LARGE, dtype=None):
+    """The LM from one seed on the dp=n mesh and on a one-device mesh:
+    first-step loss equal to 1e-3 relative, last to 2e-2.  (ResNet's local
+    batch-norm statistics make it unfit for this check.)"""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    cfg = lm_config(seq, dtype=dtype, **model)
+    runs = {}
+    for label, devs in (("dp", devices), ("one", devices[:1])):
+        mesh = Mesh(np.asarray(devs, dtype=object), ("dp",))
+        runs[label] = run_lm(
+            f"lm seq{seq} gb{global_batch} on {len(devs)}", mesh, cfg,
+            global_batch, steps)[0]
+    for idx, tol in ((0, 1e-3), (-1, 2e-2)):
+        a, b = runs["dp"][idx], runs["one"][idx]
+        if abs(a - b) > tol * abs(b):
+            raise AssertionError(
+                f"dp={len(devices)} loss {a} vs one-device {b} at step "
+                f"{idx} (tolerance {tol}): {runs}")
+    say(f"dp={len(devices)} matches one device: {runs}")
+    return runs
+
+
+# ---------------------------------------------------------------------------
+# Every pallas_call the package ships, once through the compiler, against
+# the jnp reference beside it.
+# ---------------------------------------------------------------------------
+
+
+def _close(name, got, want, *, rtol, atol):
+    """allclose on the device (the outputs are up to 100 MB each): only
+    the worst excess over ``atol + rtol * |want|`` comes to the host."""
+    import jax
+    import jax.numpy as jnp
+
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        excess = float(jnp.max(jnp.abs(g - w) - (atol + rtol * jnp.abs(w))))
+        if not excess <= 0:         # also catches NaN
+            raise AssertionError(
+                f"{name}: off the reference by {excess} beyond "
+                f"rtol={rtol}, atol={atol}")
+
+
+def _qkv(b, l, h, d, seed=0):
+    """bf16 q, k, v and an upstream gradient, [B, L, H, D]."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return tuple(jax.random.normal(k, (b, l, h, d), jnp.bfloat16)
+                 for k in ks)
+
+
+def kernel_flash_forward(*, batch=1, seq=4096, heads=16, head_dim=64):
+    """pallas_kernels._flash_call through flash_attention."""
+    import jax
+
+    from horovod_tpu.ops.pallas_kernels import (attention_reference,
+                                                flash_attention)
+
+    q, k, v, _ = _qkv(batch, seq, heads, head_dim)
+    _close("flash_attention", jax.jit(flash_attention)(q, k, v),
+           jax.jit(attention_reference)(q, k, v), rtol=2e-2, atol=2e-2)
+
+
+def kernel_flash_ring_step(*, batch=1, seq=2048, heads=16, head_dim=64):
+    """The ring block kernel (HVDT_RING_PALLAS): flash_block_update with
+    traced global offsets, one fully visible and one diagonal block."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.pallas_kernels import flash_block_update
+    from horovod_tpu.parallel.ring_attention import _block_update
+
+    q, k, v, _ = _qkv(batch, seq, heads, head_dim)
+    scale = head_dim ** -0.5
+    acc = jnp.zeros((batch, seq, heads, head_dim), jnp.float32)
+    m = jnp.full((batch, heads, seq), -1e30, jnp.float32)
+    s = jnp.zeros((batch, heads, seq), jnp.float32)
+
+    def normalized(update):
+        # acc is the unnormalized sum over up to ``seq`` keys: compare what
+        # attention returns (acc / row_sum) and the softmax statistics.
+        acc_, m_, s_ = update
+        return (acc_ / jnp.maximum(s_, 1e-30).transpose(0, 2, 1)[..., None],
+                m_, jnp.log(jnp.maximum(s_, 1e-30)))
+
+    @jax.jit
+    def kern(q_off, k_off):
+        return normalized(flash_block_update(
+            q, k, v, acc, m, s, q_offset=q_off, k_offset=k_off,
+            causal=True, scale=scale))
+
+    @jax.jit
+    def ref(q_off, k_off):
+        mask = ((q_off + jnp.arange(seq))[:, None]
+                >= (k_off + jnp.arange(seq))[None, :])[None, None]
+        return normalized(_block_update(q, k, v, acc, m, s, mask, scale))
+
+    for q_off, k_off in ((seq, 0), (seq, seq)):
+        _close("flash_block_update", kern(q_off, k_off), ref(q_off, k_off),
+               rtol=2e-2, atol=2e-2)
+
+
+def _attention_grads(fn, q, k, v, do):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
+                                * do.astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, k, v)
+
+
+def kernel_flash_backward(*, batch=1, seq=2048, heads=16, head_dim=64):
+    """flash_grad_block's dq and dk/dv pallas_calls (HVDT_FLASH_BWD=kernel
+    routes flash_attention's backward through them)."""
+    from horovod_tpu.ops.pallas_kernels import (attention_reference,
+                                                flash_attention)
+
+    q, k, v, do = _qkv(batch, seq, heads, head_dim)
+    with env_knob("HVDT_FLASH_BWD", "kernel"):
+        got = _attention_grads(flash_attention, q, k, v, do)
+    _close("flash_grad_block", got,
+           _attention_grads(attention_reference, q, k, v, do),
+           rtol=5e-2, atol=5e-2)
+
+
+def kernel_smallseq_forward(*, batch=2, seq=512, heads=16, head_dim=64):
+    """flash_attention_smallseq's forward pallas_call."""
+    import jax
+
+    from horovod_tpu.ops.pallas_kernels import (attention_reference,
+                                                flash_attention_smallseq)
+
+    q, k, v, _ = _qkv(batch, seq, heads, head_dim)
+    _close("flash_attention_smallseq",
+           jax.jit(flash_attention_smallseq)(q, k, v),
+           jax.jit(attention_reference)(q, k, v), rtol=2e-2, atol=2e-2)
+
+
+def kernel_smallseq_backward(*, batch=2, seq=512, heads=16, head_dim=64):
+    """flash_attention_smallseq's backward pallas_call."""
+    from horovod_tpu.ops.pallas_kernels import (attention_reference,
+                                                flash_attention_smallseq)
+
+    q, k, v, do = _qkv(batch, seq, heads, head_dim)
+    _close("flash_attention_smallseq backward",
+           _attention_grads(flash_attention_smallseq, q, k, v, do),
+           _attention_grads(attention_reference, q, k, v, do),
+           rtol=5e-2, atol=5e-2)
+
+
+def _conv_inputs(batch, hw, cin, cout):
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(ks[0], (batch, hw, hw, cin), jnp.bfloat16)
+    w = (jax.random.normal(ks[1], (cin, cout)) * cin ** -0.5).astype(
+        jnp.bfloat16)
+    scale = 1.0 + 0.1 * jax.random.normal(ks[2], (cout,))
+    bias = 0.1 * jax.random.normal(ks[3], (cout,))
+    return x, w, scale, bias
+
+
+def kernel_conv_bn_relu(*, batch=128, hw=14, cin=256, cout=1024):
+    """ops/conv_fused.matmul_bn_relu at a ResNet-50 stage-3 1x1 conv."""
+    import jax
+
+    from horovod_tpu.ops import conv_fused as cf
+
+    args = _conv_inputs(batch, hw, cin, cout)
+    _close("conv1x1_bn_relu", jax.jit(cf.conv1x1_bn_relu)(*args),
+           jax.jit(cf.conv1x1_bn_relu_reference)(*args),
+           rtol=2e-2, atol=2e-2)
+
+
+def kernel_conv_bn_train(*, batch=128, hw=14, cin=256, cout=1024):
+    """ops/conv_fused.matmul_batch_stats (train-form BN), same shape."""
+    import jax
+
+    from horovod_tpu.ops import conv_fused as cf
+
+    args = _conv_inputs(batch, hw, cin, cout)
+    _close("conv1x1_bn_train", jax.jit(cf.conv1x1_bn_train)(*args),
+           jax.jit(cf.conv1x1_bn_train_reference)(*args),
+           rtol=2e-2, atol=2e-2)
+
+
+def kernel_fused_adam(*, shape=(24, 1024, 1024)):
+    """ops/optim_kernels fused_adam on a bert-large leaf, against the XLA
+    lowering of the same update."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import optim_kernels as ok
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    p, g, m = (jax.random.normal(k, shape) for k in ks[:3])
+    v = jnp.square(jax.random.normal(ks[3], shape))
+    sc = jnp.asarray([3e-4, 1.0 / (1 - 0.9 ** 3), 1.0 / (1 - 0.999 ** 3)],
+                     jnp.float32)
+    kw = dict(b1=0.9, b2=0.999, eps=1e-8, eps_root=0.0, wd=1e-4)
+    if not ok.fused_update_eligible(g, p.dtype, m.dtype, v.dtype):
+        raise AssertionError(f"adam leaf {shape} not kernel-eligible")
+    want = jax.jit(lambda *a: ok._adam_leaf_xla(*a, **kw))(p, g, m, v, sc)
+    _close("fused_adam",
+           jax.jit(lambda *a: ok._adam_leaf_fused(*a, **kw))(p, g, m, v,
+                                                             sc),
+           want, rtol=1e-5, atol=1e-7)
+
+
+def kernel_fused_sgd(*, shape=(3, 3, 512, 512)):
+    """ops/optim_kernels fused_sgd on a ResNet-50 leaf."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import optim_kernels as ok
+
+    g, m = (jax.random.normal(k, shape)
+            for k in jax.random.split(jax.random.PRNGKey(0)))
+    sc = jnp.asarray([0.01], jnp.float32)
+    kw = dict(momentum=0.9, nesterov=False)
+    if not ok.fused_update_eligible(g, m.dtype):
+        raise AssertionError(f"sgd leaf {shape} not kernel-eligible")
+    want = jax.jit(lambda *a: ok._sgd_leaf_xla(*a, **kw))(g, m, sc)
+    _close("fused_sgd",
+           jax.jit(lambda *a: ok._sgd_leaf_fused(*a, **kw))(g, m, sc),
+           want, rtol=1e-5, atol=1e-7)
+
+
+def _quant_roundtrip(name, quant, dequant, eligible, size, block):
+    """One quantize/dequantize pair on a flat gradient bucket, Pallas
+    against the XLA lowering.  A code may differ by one where the two
+    lowerings round a scale differently, so values are compared to
+    within one quantization step."""
+    import jax
+    import jax.numpy as jnp
+
+    if not eligible(size, block):
+        raise AssertionError(f"{name}: {size}/{block} not kernel-eligible")
+    x = jax.random.normal(jax.random.PRNGKey(0), (size,), jnp.float32)
+
+    def roundtrip(use_kernels):
+        @jax.jit
+        def f(x):
+            q, s = quant(x, block, use_kernels)
+            return dequant(q, s, block, use_kernels), s
+        return f(x)
+
+    got, got_s = roundtrip(True)
+    want, want_s = roundtrip(False)
+    _close(f"quant {name} scales", got_s, want_s, rtol=1e-6, atol=0)
+    step = float(jnp.max(want_s))
+    err = float(jnp.max(jnp.abs(got - want)))
+    if not err <= step * 1.001:
+        raise AssertionError(
+            f"quant {name}: kernel and XLA differ by {err}, one "
+            f"quantization step is {step}")
+    if not float(jnp.max(jnp.abs(want - x))) <= step * 0.5001:
+        raise AssertionError(f"quant {name}: round trip off")
+
+
+def kernel_quant_int8(*, size=1 << 24, block=256):
+    """quant/kernels int8 pair on one 64 MiB gradient bucket."""
+    from horovod_tpu.quant import kernels as qk
+
+    _quant_roundtrip("int8", qk.quantize_flat, qk.dequantize_flat,
+                     qk.quant_kernel_eligible, size, block)
+
+
+def kernel_quant_int4(*, size=1 << 24, block=256):
+    """quant/kernels int4 pair, same bucket."""
+    from horovod_tpu.quant import kernels as qk
+
+    _quant_roundtrip("int4", qk.quantize_flat_int4, qk.dequantize_flat_int4,
+                     qk.quant_kernel_eligible_int4, size, block)
+
+
+KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
+           kernel_flash_backward, kernel_smallseq_forward,
+           kernel_smallseq_backward, kernel_conv_bn_relu,
+           kernel_conv_bn_train, kernel_fused_adam, kernel_fused_sgd,
+           kernel_quant_int8, kernel_quant_int4)
+
+
+def phase_kernels(kernels=KERNELS, **sizes):
+    """Run each kernel check; ``sizes`` maps a check's name to its
+    keyword arguments (toy sizes on the CPU)."""
+    for fn in kernels:
+        t0 = time.perf_counter()
+        fn(**sizes.get(fn.__name__, {}))
+        say(f"[kernels] {fn.__name__} ok "
+            f"({time.perf_counter() - t0:.1f} s incl. compile)")
+
+
+# ---------------------------------------------------------------------------
+# Start-up checks and the run itself.
+# ---------------------------------------------------------------------------
+
+
+class CompileLog:
+    """Counts what the persistent compilation cache did in this process
+    (jax.monitoring events): requests, hits, and the backend compiles of
+    >= 1 s that were not hits."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.requests = self.hits = 0
+        self.slow = []
+        self._hit = False       # the request being served was a hit
+        monitoring.register_event_listener(self._event)
+        monitoring.register_event_duration_secs_listener(self._duration)
+
+    def _event(self, name, **_):
+        if name == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+            self._hit = False
+        elif name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+            self._hit = True
+
+    def _duration(self, name, secs, **_):
+        # Fires for a hit too (the time to load the executable).
+        if name == "/jax/core/compile/backend_compile_duration" \
+                and secs >= 1.0 and not self._hit:
+            self.slow.append(round(secs, 1))
+
+
+def main() -> int:
+    from horovod_tpu.ops import overlap
+    from horovod_tpu.step_pipeline import enable_compilation_cache
+    from horovod_tpu.telemetry.step_stats import peak_flops_for
+
+    import jax
+
+    if overlap._jax_backend_initialized():
+        raise AssertionError("a JAX backend is up before hvd.init()")
+    # Floor at 0.5 s, not the knob's 1 s: a compile that takes 0.9 s in
+    # one run and 1.2 s in the next would otherwise never be found again.
+    cache_dir = enable_compilation_cache(
+        default=os.path.join(ROOT, ".xla_cache"), min_compile_secs=0.5)
+    compiles = CompileLog()
+
+    import horovod_tpu as hvd
+
+    hvd.init()
+    dev = jax.devices()[0]
+    n = len(jax.devices())
+    say(f"jax {jax.__version__}; platform: {dev.platform}; device_kind: "
+        f"{dev.device_kind}; devices: {n}; compile cache: {cache_dir}")
+    if dev.platform != "tpu":
+        print(f"chip_smoke: no TPU (platform {dev.platform!r}); nothing "
+              "was run", file=sys.stderr)
+        return 1
+    if peak_flops_for(dev.device_kind) == (None, None):
+        print(f"chip_smoke: device kind {dev.device_kind!r} is not in "
+              "telemetry/step_stats.PEAK_BY_DEVICE_KIND; nothing was run",
+              file=sys.stderr)
+        return 1
+    if not cache_dir:
+        raise AssertionError("the compilation cache did not engage")
+    # hvd.init() put the latency-hiding flags into LIBTPU_INIT_ARGS while
+    # no backend was up (checked above); libtpu aborts on a flag it does
+    # not accept, so reaching this line means it took all of them.
+    init_args = os.environ.get("LIBTPU_INIT_ARGS", "")
+    for flag in overlap._ASYNC_COLLECTIVE_FLAGS:
+        if flag not in init_args:
+            raise AssertionError(f"{flag} not in LIBTPU_INIT_ARGS")
+    say(f"LIBTPU_INIT_ARGS: {init_args}")
+    if os.environ.get("HVDT_FLASH_ATTENTION"):
+        raise AssertionError("HVDT_FLASH_ATTENTION is set; the smoke "
+                             "checks what auto selects")
+
+    mesh = hvd.mesh()
+    devices = list(mesh.devices.flat)
+    if mesh.axis_names != ("dp",) or len(devices) != n:
+        raise AssertionError(f"default mesh is {mesh}")
+
+    def inspect_lm(compiled, params, tokens):
+        assert_sharded_over(tokens, n)
+        if n > 1:
+            check_exchange("lm seq512", compiled, params, n)
+
+    phase_lm(mesh, seq=512, per_chip_batch=LM_PER_CHIP_BATCH,
+             inspect=inspect_lm)
+    assert_even_peak_memory(devices)
+    phase_resnet(mesh, per_chip_batch=128)
+    phase_long_seq(mesh, seq=4096, per_chip_batch=8)
+    if n > 1:
+        phase_dp_matches_single(devices, seq=512, global_batch=32)
+    phase_kernels()
+
+    cached = os.listdir(cache_dir)
+    if not cached:
+        raise AssertionError(f"compile cache {cache_dir} is empty")
+    say(f"compile cache: {compiles.requests} requests, {compiles.hits} "
+        f"hits, {len(compiles.slow)} cache-miss compiles of >= 1 s "
+        f"{compiles.slow}; "
+        f"{len(cached)} entries in {cache_dir}")
+    if "horovod_tpu.native" in sys.modules:
+        raise AssertionError("the jit path loaded the native core")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind, "count": n}}),
+        flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

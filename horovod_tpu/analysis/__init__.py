@@ -12,9 +12,8 @@ CI gate (``python -m horovod_tpu.analysis --all`` / ``hvdtrun lint``):
   feed the flight recorder's static-expected-vs-runtime-observed
   desync reports (``HVDT_EXPECTED_SCHEDULE``).
 * :mod:`~horovod_tpu.analysis.lint` — AST rule registry (knob drift,
-  unguarded version-sensitive jax APIs, zero-overhead gates, set-order
-  nondeterminism, bare sleep polls) with a ratcheting baseline, plus
-  the generated knob table (``docs/knobs.md``) and its drift check.
+  zero-overhead gates, set-order nondeterminism, bare sleep polls)
+  with a ratcheting baseline, plus the generated knob table (``docs/knobs.md``) and its drift check.
 * :mod:`~horovod_tpu.analysis.locks` — static lock-order graph over
   the threaded control plane; new acquisition-order cycles fail CI.
 * :mod:`~horovod_tpu.analysis.costmodel` /

@@ -2,9 +2,7 @@
 
 Every correctness contract this codebase relies on is (was) enforced by
 convention and hand-written tests: knobs must be declared in
-``common/config.py``, version-sensitive jax APIs must be guarded for the
-container's jax 0.4.37 (the exact set that broke PRs 1/3), env-gated
-subsystems must keep a ``None``-when-unset zero-overhead path, nothing
+``common/config.py``, env-gated subsystems must keep a ``None``-when-unset zero-overhead path, nothing
 feeding collective issue order may iterate a ``set``, and transient-
 failure polls must ride ``resilience.retry.Backoff`` instead of bare
 ``time.sleep`` loops.  This module turns each convention into a checked
@@ -41,12 +39,6 @@ __all__ = [
 
 _KNOB_RE = re.compile(r"^HVDT_[A-Z0-9]+(?:_[A-Z0-9]+)*$")
 _DOC_TOKEN_RE = re.compile(r"HVDT_[A-Z0-9_]*[A-Z0-9]")
-
-# The jax APIs that broke the container repeatedly (jax 0.4.37 has none
-# of them): attribute uses and imports must sit under a try/except or a
-# getattr/hasattr probe (PRs 1/3; ops/device._axis_size_static is the
-# blessed guarded helper).
-VERSION_SENSITIVE_APIS = ("typeof", "pcast", "axis_size", "shard_map")
 
 
 @dataclasses.dataclass
@@ -133,34 +125,6 @@ def _attr_chain(node: ast.AST) -> Tuple[str, ...]:
     return tuple(reversed(parts))
 
 
-def _in_try(node: ast.AST, parents: Dict[ast.AST, ast.AST]) -> bool:
-    return any(isinstance(a, ast.Try) and a.handlers
-               for a in _ancestors(node, parents))
-
-
-def _enclosing_function(node: ast.AST, parents: Dict[ast.AST, ast.AST]
-                        ) -> Optional[ast.AST]:
-    for a in _ancestors(node, parents):
-        if isinstance(a, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            return a
-    return None
-
-
-def _has_version_probe(scope: ast.AST) -> bool:
-    """True when ``scope`` contains a getattr/hasattr probe for any
-    version-sensitive API name — the function is version-aware and its
-    direct uses are reachable only on capable jax builds."""
-    for n in ast.walk(scope):
-        if (isinstance(n, ast.Call) and isinstance(n.func, ast.Name)
-                and n.func.id in ("getattr", "hasattr")):
-            for arg in n.args:
-                if (isinstance(arg, ast.Constant)
-                        and isinstance(arg.value, str)
-                        and arg.value in VERSION_SENSITIVE_APIS):
-                    return True
-    return False
-
-
 # ---------------------------------------------------------------------------
 # Rules
 # ---------------------------------------------------------------------------
@@ -205,74 +169,6 @@ class KnobDriftRule(Rule):
                 f"common/config.py (add a Knob, or a CONTRACT_VARS "
                 f"entry if it is launcher-internal wiring)",
                 snippet=snippet, occurrence=occ)
-
-
-@register
-class UnguardedJaxApiRule(Rule):
-    """``jax.typeof`` / ``lax.pcast`` / ``lax.axis_size`` /
-    ``jax.shard_map`` (and shard_map imports) raise AttributeError or
-    ImportError on the container's jax 0.4.37 unless guarded by
-    try/except or a getattr/hasattr probe — the exact breakage class of
-    PRs 1/3.  Use ``ops.device._axis_size_static`` and the guarded
-    import idiom instead."""
-
-    name = "unguarded-jax-api"
-    doc = ("version-sensitive jax APIs (typeof/pcast/axis_size/"
-           "shard_map) must be guarded for jax 0.4.37")
-
-    _SENSITIVE_TAILS = {
-        ("jax", "typeof"), ("lax", "pcast"), ("lax", "axis_size"),
-        ("jax", "shard_map"),
-    }
-
-    def _is_sensitive(self, chain: Tuple[str, ...]) -> bool:
-        if len(chain) < 2:
-            return False
-        tail2 = chain[-2:]
-        if tail2 in self._SENSITIVE_TAILS:
-            return True
-        # jax.lax.pcast / jax.lax.axis_size
-        return (len(chain) >= 3 and chain[-3] == "jax"
-                and chain[-2] == "lax"
-                and chain[-1] in ("pcast", "axis_size"))
-
-    def check(self, tree, src, path, ctx):
-        lines = src.splitlines()
-        parents = _parent_map(tree)
-        seen: Dict[str, int] = {}
-
-        def emit(node, what):
-            snippet = _line_of(lines, node.lineno)
-            occ = seen.get(what, 0)
-            seen[what] = occ + 1
-            return Finding(
-                self.name, path, node.lineno,
-                f"{what} is absent on jax 0.4.37 — guard with "
-                f"try/except or getattr (see "
-                f"ops.device._axis_size_static / the guarded "
-                f"shard_map import idiom)",
-                snippet=snippet, occurrence=occ)
-
-        for node in ast.walk(tree):
-            if isinstance(node, ast.Attribute):
-                chain = _attr_chain(node)
-                if not self._is_sensitive(chain):
-                    continue
-                if _in_try(node, parents):
-                    continue
-                fn = _enclosing_function(node, parents)
-                if fn is not None and _has_version_probe(fn):
-                    continue
-                yield emit(node, ".".join(chain))
-            elif isinstance(node, ast.ImportFrom):
-                mod = node.module or ""
-                if mod in ("jax", "jax.experimental.shard_map",
-                           "jax.experimental"):
-                    for alias in node.names:
-                        if alias.name == "shard_map" and \
-                                not _in_try(node, parents):
-                            yield emit(
-                                node, f"'from {mod} import shard_map'")
 
 
 @register

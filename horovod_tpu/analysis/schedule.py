@@ -236,7 +236,7 @@ def _sub_jaxprs(eqn) -> List[Tuple[str, Any]]:
     """(context_name, jaxpr) pairs for every sub-jaxpr an equation
     carries — cond branches, while cond/body, scan/shard_map/pjit
     bodies, custom-VJP call jaxprs."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     out: List[Tuple[str, Any]] = []
     name = eqn.primitive.name

@@ -119,12 +119,41 @@ def _jax_distributed_initialized() -> bool:
     before backend init."""
     import jax
 
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    from jax._src import distributed as _dist  # fallback for older jax
+    return bool(jax.distributed.is_initialized())
 
-    return getattr(_dist.global_state, "client", None) is not None
+
+def _refuse_shared_chips(local_size: int) -> None:
+    """Fail now, with the cause, when several worker processes on this
+    host would each open all of its TPU chips.
+
+    A chip belongs to one process.  ``hvdtrun`` binds each local slot to
+    a chip of its own for 2 or 4 slots per host
+    (runner/hosts.local_chip_env); for any other count every worker
+    opens every chip, one wins libtpu's lock, the rest fail on the lock
+    file, and all of them then sit in the coordination service's
+    timeouts.  Checked before anything connects or touches a backend, so
+    the launcher sees a non-zero exit within seconds."""
+    import jax
+
+    if (local_size <= 1 or os.environ.get("TPU_VISIBLE_CHIPS")
+            or os.environ.get("TPU_VISIBLE_DEVICES")):
+        return
+    platforms = (jax.config.jax_platforms or "").lower()
+    if platforms and "tpu" not in platforms:
+        return
+    # The PCI scan JAX's own TPU start-up uses; it touches no backend.
+    from jax._src import hardware_utils
+
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if chips:
+        raise RuntimeError(
+            f"hvd.init(): {local_size} worker processes were started on "
+            f"this host (HVDT_LOCAL_SIZE) and nothing binds this one to a "
+            f"TPU chip of its own (TPU_VISIBLE_CHIPS is unset), so each "
+            f"would open all {chips} chip(s) and all but one would fail. "
+            "A chip belongs to one process: run one process per host — "
+            "it drives every local chip through the dp mesh — or 2 or 4 "
+            "per host, for which hvdtrun exports the per-chip binding.")
 
 
 def _build_default_mesh(devices: Sequence[Any]):
@@ -183,9 +212,10 @@ def init(
             log.debug("init() called twice; ignoring")
             return
 
-        # Persistent XLA compilation cache (HVDT_COMPILATION_CACHE):
-        # engage before anything compiles, so launcher-forwarded env
-        # (hvdtrun --compilation-cache-dir) takes effect in every worker.
+        # Persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR,
+        # else HVDT_COMPILATION_CACHE): engage before anything compiles,
+        # so launcher-forwarded env (hvdtrun --compilation-cache-dir)
+        # takes effect in every worker.
         from ..step_pipeline import enable_compilation_cache
 
         enable_compilation_cache()
@@ -194,13 +224,10 @@ def init(
         # (HVDT_XLA_LATENCY_HIDING, ops/overlap.py): engage BEFORE the
         # first jax computation below initializes the backend — libtpu
         # reads LIBTPU_INIT_ARGS once at TPU init.  auto (default) keeps
-        # non-TPU environments untouched; never raises.
-        try:
-            from ..ops.overlap import enable_latency_hiding
+        # non-TPU environments untouched.
+        from ..ops.overlap import enable_latency_hiding
 
-            enable_latency_hiding()
-        except Exception as e:  # flags must never sink init
-            log.warning("latency-hiding flags not engaged: %r", e)
+        enable_latency_hiding()
 
         # Wire-compression env selection (HVDT_COMPRESSION / HVDT_QUANT):
         # resolve NOW so an unknown name fails at init with the valid
@@ -232,6 +259,8 @@ def init(
         if _env_zero_stage is not None:
             log.info("ZeRO state sharding from env: stage=%s",
                      _env_zero_stage)
+
+        _refuse_shared_chips(config.get_int("HVDT_LOCAL_SIZE"))
 
         env_size = config.get_int("HVDT_SIZE")
         env_rank = config.get_int("HVDT_RANK")

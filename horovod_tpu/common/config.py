@@ -632,7 +632,10 @@ KNOBS: Dict[str, Knob] = {
         _k("HVDT_COMPILATION_CACHE", "", str,
            "Directory for JAX's persistent XLA compilation cache "
            "(step_pipeline.enable_compilation_cache; engaged inside "
-           "hvd.init() and by bench.py).  Empty/off = disabled."),
+           "hvd.init()).  JAX_COMPILATION_CACHE_DIR, where set, wins "
+           "over it; the root scripts (chip_smoke.py, bench.py, "
+           "bench_allreduce.py) default to <checkout>/.xla_cache below "
+           "it.  Empty = unset; off = disabled."),
         _k("HVDT_COMPILATION_CACHE_MIN_COMPILE_SECS", 1.0, float,
            "Only persist compilations at least this expensive — keeps "
            "the multi-second train steps, skips trivial helper jits."),
@@ -812,13 +815,8 @@ KNOBS: Dict[str, Knob] = {
         _k("HVDT_DFSHARD_TIMEOUT", 120.0, float,
            "Seconds the estimator's dataframe-shard fetch waits for "
            "each worker's partition to materialize."),
-        # --- bench / example harness A/B switches (read by bench.py and
-        #     examples/, documented in docs/performance.md) ---
-        _k("HVDT_BENCH_NO_CACHE", False, _parse_bool,
-           "bench.py: bypass the persistent compilation cache for this "
-           "run — keeps an experimental config's compilations out of "
-           "the shared cache during A/B sweeps (tools/tpu_ab.py sets "
-           "it on the experiment leg)."),
+        # --- example harness A/B switches (read by examples/,
+        #     documented in docs/performance.md) ---
         _k("HVDT_LM_SINGLE", True, _parse_bool,
            "examples/jax_transformer_lm.py: run the single-island step "
            "layout (default); 0/false re-runs the per-stage island leg "

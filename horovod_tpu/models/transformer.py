@@ -239,11 +239,7 @@ def _attention(p, x, positions, cfg: TransformerConfig):
         fn = _flash_fn(l, dh, batch=max(1, b // dp_size),
                        heads=max(1, h // tp_size))
         spec = P(dp_axes if dp_axes else None, None, tp_ax, None)
-        # _flash_plan only emits island plans when the public
-        # jax.shard_map exists (jax 0.4.x has neither it nor
-        # AxisType-aware abstract meshes).
-        shard_map_fn = getattr(jax, "shard_map", None)
-        o = shard_map_fn(
+        o = jax.shard_map(
             fn, in_specs=(spec, spec, spec), out_specs=spec,
             axis_names=names)(q, k, v)
     else:
@@ -391,16 +387,11 @@ def _flash_plan(b: int, l: int, h: int, hk: int, dh: int):
     by GSPMD), or None (fall back to XLA attention).  The memory policy
     (_flash_enabled) is evaluated on the per-shard shapes the kernel
     would actually see."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        auto = ([n for n, t in zip(am.axis_names, am.axis_types)
-                 if t == jax.sharding.AxisType.Auto]
-                if not am.empty else [])
-        manual = ([n for n, t in zip(am.axis_names, am.axis_types)
-                   if t == jax.sharding.AxisType.Manual]
-                  if not am.empty else [])
-    except Exception:       # pragma: no cover - very old jax
-        auto, manual = [], []
+    am = jax.sharding.get_abstract_mesh()
+    auto = [n for n, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Auto]
+    manual = [n for n, t in zip(am.axis_names, am.axis_types)
+              if t == jax.sharding.AxisType.Manual]
     if not auto:
         return ("direct"
                 if _flash_fn(l, dh, batch=b, heads=h) is not None else None)
@@ -411,11 +402,6 @@ def _flash_plan(b: int, l: int, h: int, hk: int, dh: int):
         # dimension shardings mix manual-after-free axes — verified on
         # jax 0.9: "manual axes must come before free axes").  Fall back
         # to XLA attention; pure-auto meshes (dp/fsdp/tp) still engage.
-        return None
-    if getattr(jax, "shard_map", None) is None:
-        # Island plans need the public partial-manual shard_map API
-        # (absent on jax 0.4.x — where AxisType meshes don't exist
-        # either, so this is belt-and-braces).
         return None
     # Shard batch over dp-like axes and heads over tp, where divisible.
     dp_axes: Tuple[str, ...] = tuple(a for a in ("dp", "fsdp")
@@ -676,12 +662,8 @@ def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
             jnp.zeros((b * t,), jnp.float32))
     # Inside a shard_map island (sp/pp) the hidden states are varying, so
     # the scan body's outputs are too — the carry init must match the
-    # body's output vma or the scan type check rejects it.  jax builds
-    # without vma tracking (0.4.x: no jax.typeof/lax.pcast) need no
-    # alignment — there is no vma type to mismatch.
-    typeof = getattr(jax, "typeof", None)
-    vma = (tuple(set(typeof(xf).vma) | set(typeof(tgt).vma))
-           if typeof is not None else ())
+    # body's output vma or the scan type check rejects it.
+    vma = tuple(set(jax.typeof(xf).vma) | set(jax.typeof(tgt).vma))
     if vma:
         init = jax.tree.map(lambda a: lax.pcast(a, vma, to="varying"), init)
     (m, s, tl), _ = lax.scan(jax.checkpoint(body), init,

@@ -39,10 +39,9 @@ class NativeError(RuntimeError):
     """A native-core call returned nonzero; message from hvdt_last_error."""
 
 
-def _build() -> bool:
-    makefile = os.path.join(_NATIVE_DIR, "Makefile")
-    if not os.path.exists(makefile):
-        return False
+def _build() -> Optional[str]:
+    """Run ``make`` in the source tree; returns None on success, else
+    why the build failed."""
     try:
         # Cross-process lock: multiple ranks on one host all call load()
         # on startup; without it concurrent `make` invocations write the
@@ -56,9 +55,14 @@ def _build() -> bool:
                                capture_output=True, check=True, timeout=300)
             finally:
                 fcntl.flock(lockf, fcntl.LOCK_UN)
-    except (subprocess.SubprocessError, OSError):
-        return False
-    return os.path.exists(_LIB_PATH)
+    except subprocess.CalledProcessError as e:
+        tail = (e.stderr or e.stdout or b"").decode("utf-8", "replace")
+        return f"make failed (rc {e.returncode}): {tail[-400:]}"
+    except (subprocess.SubprocessError, OSError) as e:
+        return repr(e)
+    if not os.path.exists(_LIB_PATH):
+        return f"make succeeded but left no {_LIB_PATH}"
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -97,19 +101,24 @@ def load() -> ctypes.CDLL:
             return _lib
         if _load_failed is not None:
             raise NativeError(_load_failed)
-        # Always run make in a source tree: the Makefile's dependency
+        # In a source tree always run make: the Makefile's dependency
         # tracking no-ops when the .so is current and rebuilds it when a
-        # C++ source changed — a stale binary must never shadow the
-        # sources.  The .so is a build artifact (gitignored), not a
-        # vendored blob.  Installed wheels have no source tree; they use
-        # the library setup.py packaged next to this module.
-        if _build() or os.path.exists(_LIB_PATH):
+        # C++ source changed.  The .so is a build artifact (gitignored),
+        # so a failed build is an error even when an older binary lies
+        # around — a stale library must never stand in for the sources.
+        # Installed wheels have no source tree; they use the library
+        # setup.py packaged next to this module.
+        if os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
+            err = _build()
+            if err is not None:
+                _load_failed = f"native core build failed: {err}"
+                raise NativeError(_load_failed)
             lib_path = _LIB_PATH
         elif os.path.exists(_PKG_LIB_PATH):
             lib_path = _PKG_LIB_PATH
         else:
-            _load_failed = ("native core unavailable "
-                            "(build failed and no existing .so)")
+            _load_failed = ("native core unavailable (no source tree to "
+                            "build and no packaged library)")
             raise NativeError(_load_failed)
         try:
             _lib = _bind(ctypes.CDLL(lib_path))

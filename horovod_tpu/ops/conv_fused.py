@@ -49,11 +49,7 @@ def _ct_to_primal_vma(ct, primal):
     (a replicated weight meeting sharded activations): custom_vjp must
     return cotangents with the primal's vma — the same psum XLA's
     autodiff inserts when transposing the implicit broadcast."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:      # JAX without vma tracking: nothing to reduce
-        return ct
-    extra = tuple(set(getattr(typeof(ct), "vma", frozenset()))
-                  - set(getattr(typeof(primal), "vma", frozenset())))
+    extra = tuple(set(jax.typeof(ct).vma) - set(jax.typeof(primal).vma))
     return jax.lax.psum(ct, extra) if extra else ct
 
 
@@ -83,16 +79,12 @@ def _fit_lanes(n: int, block_n: int) -> int:
 def _tpu_params() -> dict:
     """compiler_params kwargs for the matmul grids: M/N tiles are
     independent, only K carries the accumulator.  Empty in interpret
-    mode (and under a JAX without the params class)."""
+    mode."""
     if _use_interpret():
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
-    params_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-    if params_cls is None:
-        return {}
-    return {"compiler_params": params_cls(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
@@ -305,8 +297,14 @@ def matmul_batch_stats(a: jax.Array, w: jax.Array, *, block_m: int = 512,
     grid = (m // bm, n // bn, k // bk)
     a, w = _vma_align(a, w)
 
-    stat_spec = pl.BlockSpec((1, bn), lambda i, j, kk: (i, j))
-    return pl.pallas_call(
+    # Partial sums are [M/bm, 1, N] with the row of one M-block as a
+    # squeezed leading dim: a (1, bn) block of a 2-D [M/bm, N] array is
+    # below Mosaic's 8-sublane block floor, while here the block's last
+    # two dims (1, bn) equal / tile the array's.
+    stat_spec = pl.BlockSpec((None, 1, bn), lambda i, j, kk: (i, 0, j))
+    stat_shape = jax.ShapeDtypeStruct((m // bm, 1, n), jnp.float32,
+                                      **_vma_kw(a, w))
+    z, s1, s2 = pl.pallas_call(
         _mm_stats_kernel,
         grid=grid,
         in_specs=[
@@ -317,14 +315,12 @@ def matmul_batch_stats(a: jax.Array, w: jax.Array, *, block_m: int = 512,
                    stat_spec, stat_spec],
         out_shape=(jax.ShapeDtypeStruct((m, n), a.dtype,
                                         **_vma_kw(a, w)),
-                   jax.ShapeDtypeStruct((m // bm, n), jnp.float32,
-                                        **_vma_kw(a, w)),
-                   jax.ShapeDtypeStruct((m // bm, n), jnp.float32,
-                                        **_vma_kw(a, w))),
+                   stat_shape, stat_shape),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=_use_interpret(),
         **_tpu_params(),
     )(a, w)
+    return z, s1[:, 0], s2[:, 0]
 
 
 def conv1x1_bn_train(x: jax.Array, w: jax.Array, gamma: jax.Array,

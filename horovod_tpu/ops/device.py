@@ -65,14 +65,11 @@ def _axes_tuple(axis: AxisName) -> Tuple[str, ...]:
 
 
 def _axis_size_static(axis: AxisName) -> int:
-    """Static size of the bound mesh axis/axes.  Guarded for JAX builds
-    without ``lax.axis_size`` (<= 0.4.x): ``lax.psum`` of the literal 1
-    is constant-folded to a python int under shard_map on every JAX.
-    Raises (NameError) when ``axis`` is not bound, like axis_size."""
-    size_fn = getattr(lax, "axis_size", None)
+    """Static size of the bound mesh axis/axes.  Raises (NameError)
+    when ``axis`` is not bound, like ``lax.axis_size``."""
     n = 1
     for a in _axes_tuple(axis):
-        n *= int(size_fn(a)) if size_fn is not None else int(lax.psum(1, a))
+        n *= int(lax.axis_size(a))
     return n
 
 
@@ -107,7 +104,7 @@ def is_varying(x, axis: AxisName) -> bool:
     below keep Horovod allreduce semantics exact in both regimes.
 
     Conservatively returns True (collective WILL be issued) whenever
-    tracking cannot be positively confirmed: older jax, eager, or
+    tracking cannot be positively confirmed: eager, or
     ``check_vma=False`` shard_maps.
     """
     if not _vma_tracking_active(axis):

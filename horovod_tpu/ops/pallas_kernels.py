@@ -49,14 +49,10 @@ def _vma_kw(*ops) -> dict:
     """``{"vma": ...}`` kwargs for pallas_call out_shapes: inside
     shard_map (check_vma) out types must carry the varying-axes set, and
     outputs vary over every axis any operand varies over.  Empty when no
-    operand varies (plain jit) — and on JAX builds without ``jax.typeof``
-    (no vma tracking at all), where empty is the only correct answer."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return {}
+    operand varies (plain jit)."""
     vma = frozenset()
     for op in ops:
-        vma |= frozenset(getattr(typeof(op), "vma", frozenset()))
+        vma |= frozenset(jax.typeof(op).vma)
     return {"vma": vma} if vma else {}
 
 

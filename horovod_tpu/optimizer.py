@@ -111,28 +111,18 @@ def pvary_tree(tree, axis="dp"):
     from jax import lax
 
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        # jax builds without vma tracking (0.4.x): every value is
-        # already treated as varying (ops.device.is_varying returns
-        # True conservatively), so the mark is the identity.
-        return tree
-    return jax.tree.map(lambda t: pcast(t, axes, to="varying"), tree)
+    return jax.tree.map(lambda t: lax.pcast(t, axes, to="varying"), tree)
 
 
 def _axis_bound(axis) -> bool:
     """True when ``axis`` is a bound manual mesh axis (i.e. we are inside a
     shard_map body).  Under plain auto-sharded jit/pjit there are no bound
     axes — gradients there are already globally correct and the comm link
-    must be the identity.  Probed through the guarded size helper so JAX
-    builds without ``lax.axis_size`` (<= 0.4.x) still detect bound axes
-    instead of silently skipping the collective."""
-    axes = (axis,) if isinstance(axis, str) else tuple(axis)
+    must be the identity."""
     try:
-        for a in axes:
-            dev._axis_size_static(a)
+        dev._axis_size_static(axis)
         return True
-    except Exception:
+    except NameError:
         return False
 
 

@@ -14,7 +14,35 @@ import re
 from typing import Dict, List, Optional, Sequence
 
 __all__ = ["HostInfo", "SlotInfo", "parse_hosts", "parse_host_files",
-           "get_host_assignments", "rank_env_from_hosts"]
+           "get_host_assignments", "rank_env_from_hosts", "local_chip_env"]
+
+# How libtpu lays one-chip processes over one host's chips
+# (TPU_PROCESS_BOUNDS), by the number of processes on the host.  Only
+# what has run on a chip is listed: both on a four-chip v5e host (PR 21).
+_PROCESS_BOUNDS = {2: "1,2,1", 4: "2,2,1"}
+_TPU_PROCESS_BASE_PORT = 8476
+
+
+def local_chip_env(local_rank: int, local_size: int) -> Dict[str, str]:
+    """The libtpu variables that give each of ``local_size`` processes on
+    one host its own chip.  A chip belongs to one process: without these,
+    every local worker opens all of the host's chips and all but the first
+    fail at backend start-up.  Empty for one process per host (the
+    process then drives all local chips) and for a ``local_size`` libtpu
+    has no process grid for — there the workers fail at start-up and
+    ``hvd.init()`` says why.  Inert where there is no TPU."""
+    bounds = _PROCESS_BOUNDS.get(local_size)
+    if bounds is None:
+        return {}
+    ports = [_TPU_PROCESS_BASE_PORT + i for i in range(local_size)]
+    return {
+        "TPU_VISIBLE_CHIPS": str(local_rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+        "TPU_PROCESS_PORT": str(ports[local_rank]),
+        "CLOUD_TPU_TASK_ID": str(local_rank),
+    }
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +95,8 @@ class SlotInfo:
             "HVDT_CROSS_RANK": str(self.cross_rank),
             "HVDT_CROSS_SIZE": str(self.cross_size),
         }
+        if self.cross_size == 1:
+            env.update(local_chip_env(self.local_rank, self.local_size))
         if self.pod:
             env.update({
                 "HVDT_POD": self.pod,

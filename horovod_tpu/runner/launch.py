@@ -111,9 +111,8 @@ def _print_check_build() -> None:
     import horovod_tpu as hvd
 
     print(f"horovod_tpu v{hvd.__version__}")
-    # TPU probe in a TIME-BOUNDED child: jax.devices() on a tunnelled/
-    # remote TPU backend can claim the chip for minutes — --check-build
-    # must stay snappy like the reference's link-time checks.
+    # TPU probe in a time-bounded child: this process must not touch
+    # JAX (a launcher that holds the chip starves its own workers).
     try:
         r = subprocess.run(
             [sys.executable, "-c",

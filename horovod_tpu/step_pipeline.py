@@ -14,11 +14,12 @@ leaving on the table:
 * **Persistent compilation cache** — the measured bench run pays
   ~15.8 s compile + ~14.7 s warmup on EVERY invocation for a program
   that hasn't changed.  :func:`enable_compilation_cache` points JAX's
-  persistent cache (``jax.config jax_compilation_cache_dir``) at a
-  directory so the second run of the same program skips XLA entirely.
-  Engagement is env-transparent via the ``HVDT_COMPILATION_CACHE`` knob
-  (set by ``bench.py``, forwardable by ``hvdtrun
-  --compilation-cache-dir``, engaged for workers inside ``hvd.init()``).
+  persistent cache at a directory so the second run of the same program
+  skips XLA entirely.  ``JAX_COMPILATION_CACHE_DIR`` places it from
+  outside and wins; below it the ``HVDT_COMPILATION_CACHE`` knob
+  (forwardable by ``hvdtrun --compilation-cache-dir``, engaged for
+  workers inside ``hvd.init()``); the root scripts default to
+  ``<checkout>/.xla_cache``.
 
 Both are library-level conveniences: hand-rolled ``jax.jit(...,
 donate_argnums=...)`` remains first-class everywhere.
@@ -36,51 +37,70 @@ __all__ = ["enable_compilation_cache", "donated_step", "overlap_step"]
 
 log = get_logger(__name__)
 
-_DISABLED = ("", "0", "off", "none", "false")
+_DISABLED = ("0", "off", "none", "false")
 _engaged: Optional[str] = None
 
 
 def enable_compilation_cache(path: Optional[str] = None, *,
+                             default: Optional[str] = None,
                              min_compile_secs: Optional[float] = None
                              ) -> Optional[str]:
     """Engage JAX's persistent XLA compilation cache.
 
-    ``path`` defaults to the ``HVDT_COMPILATION_CACHE`` knob; empty /
-    "off" means disabled and the call is a no-op returning None.
+    Where the directory comes from, first match wins:
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` — JAX reads it itself at import;
+       this function then sets NO directory in code, so a cache placed
+       from outside the program is the one every process uses.
+    2. ``path``, else the ``HVDT_COMPILATION_CACHE`` knob (what
+       ``hvdtrun --compilation-cache-dir`` forwards).  "off" disables.
+    3. ``default`` — the root entry points (chip_smoke.py, bench.py,
+       bench_allreduce.py) pass ``<checkout>/.xla_cache``: a fixed path,
+       because the path is part of what a later run must find again.
+
+    With none of the three the call is a no-op returning None.
     ``min_compile_secs`` (default: the
     ``HVDT_COMPILATION_CACHE_MIN_COMPILE_SECS`` knob) filters out
-    trivially cheap compilations so the cache holds the ~15 s train
-    steps, not every 10 ms helper jit.  Idempotent; returns the engaged
-    directory.  Never raises — an unwritable cache dir degrades to a
-    warning, not a failed run.
+    trivially cheap compilations so the cache holds the train steps, not
+    every 10 ms helper jit.  Idempotent; returns the engaged directory.
+    An unwritable directory is a warning, not a failed run — callers
+    that need the cache (chip_smoke.py) check the returned path.
     """
     global _engaged
 
-    if path is None:
-        path = config.get_str("HVDT_COMPILATION_CACHE")
-    if path is None or str(path).strip().lower() in _DISABLED:
-        return _engaged
-    path = os.path.abspath(os.path.expanduser(str(path)))
+    import jax
+
+    from_jax_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    if from_jax_env:
+        path = jax.config.jax_compilation_cache_dir
+    else:
+        if path is None:
+            path = config.get_str("HVDT_COMPILATION_CACHE")
+        path = str(path or "").strip() or default
+        if path is None or str(path).strip().lower() in _DISABLED:
+            return _engaged
+        path = os.path.abspath(os.path.expanduser(str(path)))
     if _engaged == path:
         return _engaged
     if min_compile_secs is None:
         min_compile_secs = config.get_float(
             "HVDT_COMPILATION_CACHE_MIN_COMPILE_SECS")
     try:
-        import jax
-
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_secs))
-        # Cache small entries too: the knob above is the only filter a
-        # user asked for.
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _engaged = path
-        log.info("persistent compilation cache at %s (min compile %.2fs)",
-                 path, float(min_compile_secs))
-    except Exception as e:     # cache must never sink a training run
+        if not from_jax_env:
+            jax.config.update("jax_compilation_cache_dir", path)
+    except OSError as e:
         log.warning("compilation cache not engaged at %s: %r", path, e)
+        return _engaged
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_secs))
+    # Cache small entries too: the knob above is the only filter a
+    # user asked for.
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _engaged = path
+    log.info("persistent compilation cache at %s (min compile %.2fs%s)",
+             path, float(min_compile_secs),
+             ", from JAX_COMPILATION_CACHE_DIR" if from_jax_env else "")
     return _engaged
 
 

@@ -92,53 +92,6 @@ class TestKnobDriftRule:
         assert fs == []
 
 
-class TestUnguardedJaxApiRule:
-    def test_bare_uses_flagged(self):
-        src = '''
-        import jax
-        from jax import lax
-        a = jax.typeof(x).vma
-        b = lax.pcast(x, "dp", to="varying")
-        c = lax.axis_size("dp")
-        d = jax.lax.axis_size("dp")
-        e = jax.shard_map(f, in_specs=None, out_specs=None)
-        '''
-        fs = _findings(src, rule="unguarded-jax-api")
-        assert len(fs) == 5
-
-    def test_unguarded_import_flagged(self):
-        fs = _findings("from jax import shard_map\n",
-                       rule="unguarded-jax-api")
-        assert len(fs) == 1
-
-    def test_try_guard_passes(self):
-        src = '''
-        import jax
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
-        def f(x):
-            try:
-                return jax.typeof(x).vma
-            except Exception:
-                return ()
-        '''
-        assert _findings(src, rule="unguarded-jax-api") == []
-
-    def test_getattr_probe_guards_function(self):
-        src = '''
-        import jax
-        from jax import lax
-        def f(x, axes):
-            pcast = getattr(lax, "pcast", None)
-            if pcast is None:
-                return x
-            return lax.pcast(x, axes, to="varying")
-        '''
-        assert _findings(src, rule="unguarded-jax-api") == []
-
-
 class TestZeroOverheadGateRule:
     def test_gate_without_none_path_flagged(self):
         src = '''

@@ -121,3 +121,32 @@ def test_private_distributed_api_resolves():
     # access path itself must resolve (hasattr on the instance would hide
     # a renamed slot behind __getattr__-less AttributeError).
     assert hasattr(gs, "client")
+
+
+def test_init_refuses_workers_sharing_a_hosts_chips(monkeypatch):
+    """Several workers on one TPU host with nothing binding each to a
+    chip of its own: hvd.init() fails at once, naming the cause, instead
+    of leaving them waiting for a chip a sibling holds."""
+    import jax
+    from jax._src import hardware_utils
+
+    from horovod_tpu.common import basics
+
+    monkeypatch.setattr(hardware_utils,
+                        "num_available_tpu_chips_and_device_id",
+                        lambda: (4, "0x0063"))
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    monkeypatch.delenv("TPU_VISIBLE_DEVICES", raising=False)
+    before = jax.config.jax_platforms
+    try:
+        jax.config.update("jax_platforms", "tpu,cpu")
+        with pytest.raises(RuntimeError, match="one process per host"):
+            basics._refuse_shared_chips(3)
+        basics._refuse_shared_chips(1)          # one process: fine
+        monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2")
+        basics._refuse_shared_chips(4)          # bound by the launcher
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS")
+        jax.config.update("jax_platforms", "cpu")
+        basics._refuse_shared_chips(3)          # CPU workers share nothing
+    finally:
+        jax.config.update("jax_platforms", before)

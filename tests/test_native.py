@@ -322,3 +322,15 @@ def test_tcp_backend_dispatch(monkeypatch):
         expected = np.concatenate([np.full((1, 2), 0, np.int32),
                                    np.full((2, 2), 1, np.int32)])
         np.testing.assert_array_equal(r3, expected)
+
+
+def test_failed_build_is_an_error_even_with_a_binary_on_disk(monkeypatch):
+    """The .so is a build artifact: when make fails in a source tree, the
+    older library still lying in native/ must not stand in for it."""
+    assert os.path.exists(native._LIB_PATH)     # built by the tests above
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_failed", None)
+    monkeypatch.setattr(native, "_build", lambda: "make failed (rc 2): boom")
+    with pytest.raises(native.NativeError, match="build failed.*boom"):
+        native.load()
+    assert not native.available()

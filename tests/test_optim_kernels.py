@@ -218,12 +218,25 @@ class TestDistributedComposition:
 
 
 class TestStepPipeline:
-    def test_compilation_cache_knob(self, monkeypatch, tmp_path):
+    @pytest.fixture()
+    def fresh_cache_state(self, monkeypatch):
+        """No cache engaged and none named by the environment; JAX's own
+        setting is put back afterwards so later tests' compiles don't
+        land in a deleted tmp_path."""
         from horovod_tpu import step_pipeline as sp
 
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("HVDT_COMPILATION_CACHE", raising=False)
+        monkeypatch.setattr(sp, "_engaged", None)
+        yield sp
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_compilation_cache_knob(self, fresh_cache_state, monkeypatch,
+                                    tmp_path):
+        sp = fresh_cache_state
         cache = tmp_path / "xla-cache"
         monkeypatch.setenv("HVDT_COMPILATION_CACHE", str(cache))
-        monkeypatch.setattr(sp, "_engaged", None)
         engaged = sp.enable_compilation_cache()
         assert engaged == str(cache)
         assert cache.is_dir()
@@ -231,12 +244,45 @@ class TestStepPipeline:
         # Idempotent
         assert sp.enable_compilation_cache() == str(cache)
 
-    def test_disabled_by_default(self, monkeypatch):
-        from horovod_tpu import step_pipeline as sp
+    def test_jax_env_var_wins_and_is_not_overwritten(
+            self, fresh_cache_state, monkeypatch, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR places the cache from outside: the
+        knob, an explicit path and a root script's default all sit below
+        it, and the program never writes jax_compilation_cache_dir."""
+        sp = fresh_cache_state
+        outside = tmp_path / "outside"
+        # What JAX itself does at import when the variable is set.
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(outside))
+        jax.config.update("jax_compilation_cache_dir", str(outside))
+        monkeypatch.setenv("HVDT_COMPILATION_CACHE", str(tmp_path / "knob"))
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (updates.append(k), real_update(k, v))[1])
+        assert sp.enable_compilation_cache() == str(outside)
+        assert sp.enable_compilation_cache(
+            str(tmp_path / "arg"), default=str(tmp_path / "dflt")
+        ) == str(outside)
+        assert "jax_compilation_cache_dir" not in updates
+        assert jax.config.jax_compilation_cache_dir == str(outside)
+        assert not (tmp_path / "knob").exists()
 
-        monkeypatch.delenv("HVDT_COMPILATION_CACHE", raising=False)
+    def test_root_script_default_sits_below_the_knob(
+            self, fresh_cache_state, monkeypatch, tmp_path):
+        sp = fresh_cache_state
+        dflt = tmp_path / "dflt"
+        assert sp.enable_compilation_cache(default=str(dflt)) == str(dflt)
         monkeypatch.setattr(sp, "_engaged", None)
-        assert sp.enable_compilation_cache() is None
+        monkeypatch.setenv("HVDT_COMPILATION_CACHE", str(tmp_path / "knob"))
+        assert sp.enable_compilation_cache(default=str(dflt)) == str(
+            tmp_path / "knob")
+        monkeypatch.setattr(sp, "_engaged", None)
+        monkeypatch.setenv("HVDT_COMPILATION_CACHE", "off")
+        assert sp.enable_compilation_cache(default=str(dflt)) is None
+
+    def test_disabled_by_default(self, fresh_cache_state):
+        assert fresh_cache_state.enable_compilation_cache() is None
 
     def test_donated_step_runs_and_is_jitted(self, monkeypatch):
         from horovod_tpu.step_pipeline import donated_step

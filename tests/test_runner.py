@@ -46,6 +46,32 @@ class TestHosts:
         assert env["HVDT_RANK"] == "0"
         assert env["HVDT_SIZE"] == "1"
         assert env["HVDT_HOSTNAME"] == "x"
+        # one process per host drives every local chip: no chip binding
+        assert not any(k.startswith("TPU_") for k in env)
+
+    def test_local_slots_each_get_one_chip(self):
+        """Four slots on one host: libtpu's per-process variables give
+        each worker its own chip (a chip belongs to one process)."""
+        slots = hosts_mod.get_host_assignments(
+            hosts_mod.parse_hosts("localhost:4"), 4)
+        envs = [s.to_env() for s in slots]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == list("0123")
+        assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+        for i, e in enumerate(envs):
+            assert e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+            assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["CLOUD_TPU_TASK_ID"] == str(i)
+            addrs = e["TPU_PROCESS_ADDRESSES"].split(",")
+            assert addrs[i] == f"localhost:{e['TPU_PROCESS_PORT']}"
+
+    def test_no_chip_binding_without_a_process_grid(self):
+        """Three slots have no libtpu process grid, and two hosts need a
+        global one: nothing is exported, and hvd.init() refuses on a TPU
+        host (tests/test_basics.py)."""
+        for spec, n in (("localhost:3", 3), ("a:2,b:2", 4)):
+            for s in hosts_mod.get_host_assignments(
+                    hosts_mod.parse_hosts(spec), n):
+                assert "TPU_VISIBLE_CHIPS" not in s.to_env()
 
 
 class TestKV:

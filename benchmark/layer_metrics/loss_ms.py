@@ -1,0 +1,10 @@
+"""Models: device time per step in the loss (``hvdt.loss``: the tied
+head's matmul and the cross entropy), forward, recompute and backward
+together (device trace joined to the compiled step's ``op_name``s,
+``benchmark/phase_split.py``).  Moves ``tokens_per_s_chip``."""
+
+from benchmark.phase_split import scope_metric
+
+
+def read(ctx):
+    return scope_metric(ctx, "hvdt.loss")

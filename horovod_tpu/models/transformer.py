@@ -520,11 +520,16 @@ def remat_from_env(cfg: TransformerConfig,
 
 
 def _block(p, x, positions, cfg: TransformerConfig):
-    x = x + _attention(p, _rmsnorm(x, p["ln1"]), positions, cfg)
-    if cfg.num_experts:
-        y, _ = _moe_mlp(p, _rmsnorm(x, p["ln2"]), cfg)
-    else:
-        y = _mlp(p, _rmsnorm(x, p["ln2"]))
+    # Each sublayer with its pre-norm under one name, for the profiler
+    # and the benchmark's phase split (docs/observability.md).
+    with jax.named_scope("hvdt.attention"):
+        a = _attention(p, _rmsnorm(x, p["ln1"]), positions, cfg)
+    x = x + a
+    with jax.named_scope("hvdt.mlp"):
+        if cfg.num_experts:
+            y, _ = _moe_mlp(p, _rmsnorm(x, p["ln2"]), cfg)
+        else:
+            y = _mlp(p, _rmsnorm(x, p["ln2"]))
     return x + y
 
 
@@ -616,7 +621,11 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
 def transformer_apply(params: Dict, tokens: jax.Array,
                       cfg: TransformerConfig) -> jax.Array:
     """Logits for next-token prediction (see transformer_hidden)."""
-    x = transformer_hidden(params, tokens, cfg)
+    return _head(params, transformer_hidden(params, tokens, cfg))
+
+
+def _head(params: Dict, x: jax.Array) -> jax.Array:
+    """The tied output projection: hidden states to f32 logits."""
     return (x @ params["embed"].astype(x.dtype).T).astype(jnp.float32)
 
 
@@ -685,13 +694,16 @@ def transformer_loss(params: Dict, tokens: jax.Array,
     ``cfg.loss_chunk > 0`` switches to the chunked-vocab logsumexp path
     (no [tokens, vocab] logits tensor)."""
     targets = tokens[:, 1:]
-    if cfg.loss_chunk:
-        x = transformer_hidden(params, tokens, cfg)[:, :-1]
-        return _chunked_xent(x, params["embed"], targets, cfg.loss_chunk)
-    logits = transformer_apply(params, tokens, cfg)[:, :-1]
-    logp = jax.nn.log_softmax(logits, -1)
-    ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
-    return -ll.mean()
+    x = transformer_hidden(params, tokens, cfg)
+    # The tied head's matmul is inside the scope on both branches.
+    with jax.named_scope("hvdt.loss"):
+        if cfg.loss_chunk:
+            return _chunked_xent(x[:, :-1], params["embed"], targets,
+                                 cfg.loss_chunk)
+        logits = _head(params, x)[:, :-1]
+        logp = jax.nn.log_softmax(logits, -1)
+        ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
+        return -ll.mean()
 
 
 # ---------------------------------------------------------------------------

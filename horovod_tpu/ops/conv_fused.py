@@ -155,23 +155,24 @@ def _mm_forward(a, w, scale, bias, relu, block_m, block_n, block_k):
     grid = (m // bm, n // bn, k // bk)
     a, w, scale, bias = _vma_align(a, w, scale, bias)
 
-    return pl.pallas_call(
-        functools.partial(_mm_kernel, relu=relu),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), a.dtype,
-                                       **_vma_kw(a, w, scale, bias)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=_use_interpret(),
-        **_tpu_params(),
-    )(a, w, scale.astype(jnp.float32).reshape(1, n),
-      bias.astype(jnp.float32).reshape(1, n))
+    with jax.named_scope("hvdt.kernel.conv1x1_bn"):
+        return pl.pallas_call(
+            functools.partial(_mm_kernel, relu=relu),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+                pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+                pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+                pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((m, n), a.dtype,
+                                           **_vma_kw(a, w, scale, bias)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            interpret=_use_interpret(),
+            **_tpu_params(),
+        )(a, w, scale.astype(jnp.float32).reshape(1, n),
+          bias.astype(jnp.float32).reshape(1, n))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -304,22 +305,23 @@ def matmul_batch_stats(a: jax.Array, w: jax.Array, *, block_m: int = 512,
     stat_spec = pl.BlockSpec((None, 1, bn), lambda i, j, kk: (i, 0, j))
     stat_shape = jax.ShapeDtypeStruct((m // bm, 1, n), jnp.float32,
                                       **_vma_kw(a, w))
-    z, s1, s2 = pl.pallas_call(
-        _mm_stats_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=[pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-                   stat_spec, stat_spec],
-        out_shape=(jax.ShapeDtypeStruct((m, n), a.dtype,
-                                        **_vma_kw(a, w)),
-                   stat_shape, stat_shape),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=_use_interpret(),
-        **_tpu_params(),
-    )(a, w)
+    with jax.named_scope("hvdt.kernel.conv1x1_bn_stats"):
+        z, s1, s2 = pl.pallas_call(
+            _mm_stats_kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+                pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+            ],
+            out_specs=[pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+                       stat_spec, stat_spec],
+            out_shape=(jax.ShapeDtypeStruct((m, n), a.dtype,
+                                            **_vma_kw(a, w)),
+                       stat_shape, stat_shape),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            interpret=_use_interpret(),
+            **_tpu_params(),
+        )(a, w)
     return z, s1[:, 0], s2[:, 0]
 
 

@@ -160,18 +160,19 @@ def _adam_leaf_fused(p, g, m, v, scalars, *, b1, b2, eps, eps_root, wd):
     # tensor operands; m and v are the last two inputs → alias onto the
     # m_new/v_new outputs (in-place moments under donation).
     aliases = {n_in - 1: 1, n_in: 2}
-    d, mo, vo = pl.pallas_call(
-        functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps,
-                          eps_root=eps_root, wd=wd),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(rows // br,),
-            in_specs=[spec] * n_in, out_specs=[spec, spec, spec]),
-        out_shape=(jax.ShapeDtypeStruct(ops2d[0].shape, p.dtype, **kw),
-                   jax.ShapeDtypeStruct(ops2d[0].shape, m.dtype, **kw),
-                   jax.ShapeDtypeStruct(ops2d[0].shape, v.dtype, **kw)),
-        input_output_aliases=aliases,
-        interpret=_use_interpret(),
-    )(scalars, *ops2d)
+    with jax.named_scope("hvdt.kernel.fused_adam"):
+        d, mo, vo = pl.pallas_call(
+            functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps,
+                              eps_root=eps_root, wd=wd),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(rows // br,),
+                in_specs=[spec] * n_in, out_specs=[spec, spec, spec]),
+            out_shape=(jax.ShapeDtypeStruct(ops2d[0].shape, p.dtype, **kw),
+                       jax.ShapeDtypeStruct(ops2d[0].shape, m.dtype, **kw),
+                       jax.ShapeDtypeStruct(ops2d[0].shape, v.dtype, **kw)),
+            input_output_aliases=aliases,
+            interpret=_use_interpret(),
+        )(scalars, *ops2d)
     return d.reshape(shape), mo.reshape(shape), vo.reshape(shape)
 
 
@@ -309,17 +310,18 @@ def _sgd_leaf_fused(g, m, scalars, *, momentum, nesterov):
     rows = g2.shape[0]
     br = _row_block(rows)
     spec = pl.BlockSpec((br, _LANES), lambda i, *_: (i, 0))
-    d, mo = pl.pallas_call(
-        functools.partial(_sgd_kernel, momentum=momentum,
-                          nesterov=nesterov),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(rows // br,),
-            in_specs=[spec, spec], out_specs=[spec, spec]),
-        out_shape=(jax.ShapeDtypeStruct(g2.shape, g.dtype, **kw),
-                   jax.ShapeDtypeStruct(g2.shape, m.dtype, **kw)),
-        input_output_aliases={2: 1},     # m (after scalars, g) → m_new
-        interpret=_use_interpret(),
-    )(scalars, g2, m2)
+    with jax.named_scope("hvdt.kernel.fused_sgd"):
+        d, mo = pl.pallas_call(
+            functools.partial(_sgd_kernel, momentum=momentum,
+                              nesterov=nesterov),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(rows // br,),
+                in_specs=[spec, spec], out_specs=[spec, spec]),
+            out_shape=(jax.ShapeDtypeStruct(g2.shape, g.dtype, **kw),
+                       jax.ShapeDtypeStruct(g2.shape, m.dtype, **kw)),
+            input_output_aliases={2: 1},     # m (after scalars, g) → m_new
+            interpret=_use_interpret(),
+        )(scalars, g2, m2)
     return d.reshape(shape), mo.reshape(shape)
 
 

@@ -193,14 +193,15 @@ def _flash_call(q, k, v, acc, m, l, q_offset, k_offset, *, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)])
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shapes,
-        interpret=_use_interpret(),
-    )(jnp.atleast_1d(q_offset).astype(jnp.int32),
-      jnp.atleast_1d(k_offset).astype(jnp.int32),
-      q, k, v, acc, m, l)
+    with jax.named_scope("hvdt.kernel.flash_fwd"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=out_shapes,
+            interpret=_use_interpret(),
+        )(jnp.atleast_1d(q_offset).astype(jnp.int32),
+          jnp.atleast_1d(k_offset).astype(jnp.int32),
+          q, k, v, acc, m, l)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -557,19 +558,21 @@ def flash_grad_block(q, k, v, do, out, lse, *, q_offset=0, k_offset=0,
     col_q = pl.BlockSpec((1, 1, block_q, 1),
                          lambda bb, hh, qq, kk, *_: (bb, hh, qq, 0))
 
-    dq, = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=float(scale)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, lq // block_q, lk // block_k),
-            in_specs=[qspec, kvspec, kvspec, qspec, col_q, col_q],
-            out_specs=[qspec],
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
-        out_shape=(jax.ShapeDtypeStruct((b, h, lq, d), jnp.float32, **kw),),
-        interpret=_use_interpret(),
-    )(jnp.atleast_1d(q_offset).astype(jnp.int32),
-      jnp.atleast_1d(k_offset).astype(jnp.int32),
-      qt, kt, vt, dot, dl, lse_c)
+    with jax.named_scope("hvdt.kernel.flash_dq"):
+        dq, = pl.pallas_call(
+            functools.partial(_dq_kernel, causal=causal, scale=float(scale)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b, h, lq // block_q, lk // block_k),
+                in_specs=[qspec, kvspec, kvspec, qspec, col_q, col_q],
+                out_specs=[qspec],
+                scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
+            out_shape=(jax.ShapeDtypeStruct((b, h, lq, d), jnp.float32,
+                                            **kw),),
+            interpret=_use_interpret(),
+        )(jnp.atleast_1d(q_offset).astype(jnp.int32),
+          jnp.atleast_1d(k_offset).astype(jnp.int32),
+          qt, kt, vt, dot, dl, lse_c)
 
     # dkv pass: grid loops K blocks outer, Q blocks inner.  BlockSpec
     # index maps receive (bb, hh, kk, qq).
@@ -582,21 +585,22 @@ def flash_grad_block(q, k, v, do, out, lse, *, q_offset=0, k_offset=0,
                           lambda bb, hh, kk, qq, *_: (bb, hh, kk, 0))
     col_q2 = pl.BlockSpec((1, 1, block_q, 1),
                           lambda bb, hh, kk, qq, *_: (bb, hh, qq, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=float(scale)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, lk // block_k, lq // block_q),
-            in_specs=[qspec2, kvspec2, kvspec2, qspec2, col_q2, col_q2],
-            out_specs=[kvout2, kvout2],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)]),
-        out_shape=(jax.ShapeDtypeStruct((b, h, lk, d), jnp.float32, **kw),
-                   jax.ShapeDtypeStruct((b, h, lk, d), jnp.float32, **kw)),
-        interpret=_use_interpret(),
-    )(jnp.atleast_1d(q_offset).astype(jnp.int32),
-      jnp.atleast_1d(k_offset).astype(jnp.int32),
-      qt, kt, vt, dot, dl, lse_c)
+    with jax.named_scope("hvdt.kernel.flash_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, causal=causal, scale=float(scale)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b, h, lk // block_k, lq // block_q),
+                in_specs=[qspec2, kvspec2, kvspec2, qspec2, col_q2, col_q2],
+                out_specs=[kvout2, kvout2],
+                scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                                pltpu.VMEM((block_k, d), jnp.float32)]),
+            out_shape=(jax.ShapeDtypeStruct((b, h, lk, d), jnp.float32, **kw),
+                       jax.ShapeDtypeStruct((b, h, lk, d), jnp.float32, **kw)),
+            interpret=_use_interpret(),
+        )(jnp.atleast_1d(q_offset).astype(jnp.int32),
+          jnp.atleast_1d(k_offset).astype(jnp.int32),
+          qt, kt, vt, dot, dl, lse_c)
 
     dq = dq.transpose(0, 2, 1, 3)                               # [B,Lq,H,D]
     dk = dk.transpose(0, 2, 1, 3)                               # [B,Lk,H,D]
@@ -739,16 +743,17 @@ def _smallseq_call(q, k, v, causal, scale, hb):
     qspec = pl.BlockSpec((1, hb, l, d), lambda bb, hh: (bb, hh, 0, 0))
     kvspec = pl.BlockSpec((1, hb_kv, l, d), lambda bb, hh: (bb, hh, 0, 0))
     col = pl.BlockSpec((1, hb, l, 1), lambda bb, hh: (bb, hh, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_smallseq_fwd_kernel, causal=causal,
-                          scale=scale, group=group),
-        grid=(b, h // hb),
-        in_specs=[qspec, kvspec, kvspec],
-        out_specs=[qspec, col],
-        out_shape=(jax.ShapeDtypeStruct((b, h, l, d), q.dtype, **kw),
-                   jax.ShapeDtypeStruct((b, h, l, 1), jnp.float32, **kw)),
-        interpret=_use_interpret(),
-    )(q, k, v)
+    with jax.named_scope("hvdt.kernel.flash_smallseq_fwd"):
+        return pl.pallas_call(
+            functools.partial(_smallseq_fwd_kernel, causal=causal,
+                              scale=scale, group=group),
+            grid=(b, h // hb),
+            in_specs=[qspec, kvspec, kvspec],
+            out_specs=[qspec, col],
+            out_shape=(jax.ShapeDtypeStruct((b, h, l, d), q.dtype, **kw),
+                       jax.ShapeDtypeStruct((b, h, l, 1), jnp.float32, **kw)),
+            interpret=_use_interpret(),
+        )(q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -778,18 +783,19 @@ def _smallseq_diff_bwd(causal, scale, hb, res, do):
     qspec = pl.BlockSpec((1, hb, lq, d), lambda bb, hh: (bb, hh, 0, 0))
     kvspec = pl.BlockSpec((1, hb_kv, lq, d), lambda bb, hh: (bb, hh, 0, 0))
     col = pl.BlockSpec((1, hb, lq, 1), lambda bb, hh: (bb, hh, 0, 0))
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_smallseq_bwd_kernel, causal=causal,
-                          scale=scale, group=group),
-        grid=(b, h // hb),
-        in_specs=[qspec, kvspec, kvspec, qspec, qspec, col],
-        out_specs=[qspec, kvspec, kvspec],
-        out_shape=(
-            jax.ShapeDtypeStruct((b, h, lq, d), jnp.float32, **kw),
-            jax.ShapeDtypeStruct((b, hkv, lq, d), jnp.float32, **kw),
-            jax.ShapeDtypeStruct((b, hkv, lq, d), jnp.float32, **kw)),
-        interpret=_use_interpret(),
-    )(qt, kt, vt, dot, out_t, lse)
+    with jax.named_scope("hvdt.kernel.flash_smallseq_bwd"):
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_smallseq_bwd_kernel, causal=causal,
+                              scale=scale, group=group),
+            grid=(b, h // hb),
+            in_specs=[qspec, kvspec, kvspec, qspec, qspec, col],
+            out_specs=[qspec, kvspec, kvspec],
+            out_shape=(
+                jax.ShapeDtypeStruct((b, h, lq, d), jnp.float32, **kw),
+                jax.ShapeDtypeStruct((b, hkv, lq, d), jnp.float32, **kw),
+                jax.ShapeDtypeStruct((b, hkv, lq, d), jnp.float32, **kw)),
+            interpret=_use_interpret(),
+        )(qt, kt, vt, dot, out_t, lse)
     return (dq.transpose(0, 2, 1, 3).astype(q.dtype),
             dk.transpose(0, 2, 1, 3).astype(k.dtype),
             dv.transpose(0, 2, 1, 3).astype(v.dtype))

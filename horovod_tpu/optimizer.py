@@ -235,15 +235,32 @@ def DistributedGradientTransformation(
 
     def update_fn(updates, state, params=None):
         del params
-        updates = allreduce_gradients(
-            updates, axis=axis, op=op, compression=compression,
-            threshold_bytes=threshold_bytes,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor,
-            _exchange=exchange)
+        # Collectives and the packing, scaling and unpacking around them
+        # under one name; the per-bucket scopes nest inside it.
+        with jax.named_scope("hvdt.exchange"):
+            updates = allreduce_gradients(
+                updates, axis=axis, op=op, compression=compression,
+                threshold_bytes=threshold_bytes,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor,
+                _exchange=exchange)
         return updates, state
 
     return optax.GradientTransformation(init_fn, update_fn)
+
+
+def _scoped_update(optimizer):
+    """``optimizer`` with its ``update`` traced under ``hvdt.optimizer``;
+    ``init`` and the state pytree are the wrapped transformation's own."""
+    import optax
+
+    inner = optax.with_extra_args_support(optimizer)
+
+    def update_fn(updates, state, params=None, **extra_args):
+        with jax.named_scope("hvdt.optimizer"):
+            return inner.update(updates, state, params, **extra_args)
+
+    return optax.GradientTransformationExtraArgs(inner.init, update_fn)
 
 
 def DistributedOptimizer(optimizer,
@@ -354,6 +371,7 @@ def DistributedOptimizer(optimizer,
         axis=axis, op=op, compression=compression,
         threshold_bytes=threshold_bytes, prescale_factor=prescale_factor,
         postscale_factor=postscale_factor, zero=_stage)
+    optimizer = _scoped_update(optimizer)
     if backward_passes_per_step > 1:
         # Communication precedes accumulation so every value MultiSteps
         # holds across its internal lax.cond is replicated (type-stable

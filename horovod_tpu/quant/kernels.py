@@ -159,16 +159,17 @@ def _quantize_pallas(x2):
     kw = _vma_kw(x2)
     spec = pl.BlockSpec((br, block), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    q, s = pl.pallas_call(
-        _quant_kernel,
-        grid=(nblocks // br,),
-        in_specs=[spec],
-        out_specs=[spec, sspec],
-        out_shape=(jax.ShapeDtypeStruct((nblocks, block), jnp.int8, **kw),
-                   jax.ShapeDtypeStruct((nblocks, _LANES), jnp.float32,
-                                        **kw)),
-        interpret=_use_interpret(),
-    )(x2)
+    with jax.named_scope("hvdt.kernel.quantize"):
+        q, s = pl.pallas_call(
+            _quant_kernel,
+            grid=(nblocks // br,),
+            in_specs=[spec],
+            out_specs=[spec, sspec],
+            out_shape=(jax.ShapeDtypeStruct((nblocks, block), jnp.int8, **kw),
+                       jax.ShapeDtypeStruct((nblocks, _LANES), jnp.float32,
+                                            **kw)),
+            interpret=_use_interpret(),
+        )(x2)
     return q, s[:, 0]
 
 
@@ -181,14 +182,16 @@ def _dequantize_pallas(q2, scales):
     kw = _vma_kw(q2, scales)
     spec = pl.BlockSpec((br, block), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        _dequant_kernel,
-        grid=(nblocks // br,),
-        in_specs=[spec, sspec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks, block), jnp.float32, **kw),
-        interpret=_use_interpret(),
-    )(q2, s2)
+    with jax.named_scope("hvdt.kernel.dequantize"):
+        return pl.pallas_call(
+            _dequant_kernel,
+            grid=(nblocks // br,),
+            in_specs=[spec, sspec],
+            out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct((nblocks, block), jnp.float32,
+                                           **kw),
+            interpret=_use_interpret(),
+        )(q2, s2)
 
 
 # ---- public API ----------------------------------------------------------
@@ -338,17 +341,18 @@ def _quantize4_pallas(x2):
     spec = pl.BlockSpec((br, block), lambda i: (i, 0))
     pspec = pl.BlockSpec((br, block // 2), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    p, s = pl.pallas_call(
-        _quant4_kernel,
-        grid=(nblocks // br,),
-        in_specs=[spec],
-        out_specs=[pspec, sspec],
-        out_shape=(jax.ShapeDtypeStruct((nblocks, block // 2), jnp.int8,
-                                        **kw),
-                   jax.ShapeDtypeStruct((nblocks, _LANES), jnp.float32,
-                                        **kw)),
-        interpret=_use_interpret(),
-    )(x2)
+    with jax.named_scope("hvdt.kernel.quantize4"):
+        p, s = pl.pallas_call(
+            _quant4_kernel,
+            grid=(nblocks // br,),
+            in_specs=[spec],
+            out_specs=[pspec, sspec],
+            out_shape=(jax.ShapeDtypeStruct((nblocks, block // 2), jnp.int8,
+                                            **kw),
+                       jax.ShapeDtypeStruct((nblocks, _LANES), jnp.float32,
+                                            **kw)),
+            interpret=_use_interpret(),
+        )(x2)
     return p, s[:, 0]
 
 
@@ -362,15 +366,16 @@ def _dequantize4_pallas(p2, scales):
     pspec = pl.BlockSpec((br, half), lambda i: (i, 0))
     spec = pl.BlockSpec((br, 2 * half), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    return pl.pallas_call(
-        _dequant4_kernel,
-        grid=(nblocks // br,),
-        in_specs=[pspec, sspec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks, 2 * half), jnp.float32,
-                                       **kw),
-        interpret=_use_interpret(),
-    )(p2, s2)
+    with jax.named_scope("hvdt.kernel.dequantize4"):
+        return pl.pallas_call(
+            _dequant4_kernel,
+            grid=(nblocks // br,),
+            in_specs=[pspec, sspec],
+            out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct((nblocks, 2 * half), jnp.float32,
+                                           **kw),
+            interpret=_use_interpret(),
+        )(p2, s2)
 
 
 def quantize_flat_int4(flat, block_size: Optional[int] = None,

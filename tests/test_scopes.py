@@ -1,0 +1,226 @@
+"""The names the program puts on its own step (PR 24): every ``hvdt.*``
+scope is in the lowered text of the path that should carry it, and every
+``pallas_call`` site lowers under its ``hvdt.kernel.<kernel>`` name.  The
+benchmark's phase split (``benchmark/phase_split.py``) and an operator's
+XProf trace (docs/observability.md) read these names; a refactor that
+drops one fails here, on the CPU.  Names are metadata: nothing here runs
+a kernel for its result."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from horovod_tpu import models
+
+
+def lowered_text(fn, *args) -> str:
+    """The lowered module with the name stack of every operation (inside
+    a called function the stack is relative to the call)."""
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+def compiled_text(fn, *args) -> str:
+    """The compiled module: every ``op_name`` is the whole path, as the
+    benchmark's ``Context.hlo_text`` has it."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def lm_config(loss_chunk):
+    return models.TransformerConfig(
+        vocab=256, d_model=64, layers=2, heads=4, kv_heads=4, d_ff=128,
+        max_seq=32, remat=True, loss_chunk=loss_chunk)
+
+
+@pytest.fixture(scope="module", params=[128, 0],
+                ids=["chunked_loss", "dense_loss"])
+def lm_grad_text(request):
+    cfg = lm_config(request.param)
+    params = jax.eval_shape(
+        lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    return compiled_text(jax.value_and_grad(
+        lambda p, t: models.transformer_loss(p, t, cfg)), params, tokens)
+
+
+# What JAX itself wraps around a scope under value_and_grad of a scanned,
+# checkpointed block: these strings are what phase_split.phase() matches.
+@pytest.mark.parametrize("path", [
+    "jvp()/while/body/closed_call/hvdt.attention/",
+    "jvp()/while/body/closed_call/hvdt.mlp/",
+    "transpose(jvp())/while/body/closed_call/checkpoint/hvdt.attention/",
+    "transpose(jvp())/while/body/closed_call/checkpoint/hvdt.mlp/",
+    "checkpoint/rematted_computation/hvdt.attention/",
+    "checkpoint/rematted_computation/hvdt.mlp/",
+    "jvp(hvdt.loss)/",
+    "transpose(jvp(hvdt.loss))/",
+])
+def test_lm_loss_and_grad_carry_the_model_scopes(lm_grad_text, path):
+    assert path in lm_grad_text
+
+
+def test_the_tied_heads_matmul_is_inside_the_loss_scope(lm_grad_text):
+    # Outside the block scan the only matmul of the forward is the head's.
+    outside = [line for line in lm_grad_text.splitlines()
+               if "dot_general" in line and "jvp(" in line
+               and "hvdt.attention" not in line and "hvdt.mlp" not in line
+               and "transpose(" not in line]
+    assert outside and all("jvp(hvdt.loss)" in line for line in outside)
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    return Mesh(np.asarray(jax.devices()[:4], dtype=object), ("dp",))
+
+
+def update_text(hvd, mesh4, **kwargs):
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3), **kwargs)
+    params = {"w": jnp.ones((64, 128)), "b": jnp.ones((128,))}
+    state = opt.init(params)
+
+    def local(params, state, grads):
+        grads = hvd.optimizer.pvary_tree(grads, "dp")
+        return opt.update(grads, state, params)
+
+    step = jax.shard_map(local, mesh=mesh4, in_specs=(P(), P(), P()),
+                         out_specs=(P(), P()))
+    return compiled_text(step, params, state, params)
+
+
+@pytest.mark.parametrize("passes", [1, 2], ids=["chain", "multisteps"])
+@pytest.mark.parametrize("path", [
+    "shard_map/hvdt.exchange/",
+    "hvdt.exchange/hvdt.fused_allreduce.b0/",
+    "hvdt.optimizer/",
+])
+def test_distributed_optimizer_update_carries_its_scopes(hvd, mesh4, path,
+                                                         passes):
+    text = update_text(hvd, mesh4, backward_passes_per_step=passes)
+    assert path in text
+    # The exchange is not under the optimizer's name, nor the other way.
+    assert "hvdt.optimizer/hvdt.exchange" not in text
+    assert "hvdt.exchange/hvdt.optimizer" not in text
+
+
+def test_the_optimizer_scope_leaves_init_and_the_state_as_they_were(hvd):
+    inner = optax.adamw(1e-3)
+    opt = hvd.DistributedOptimizer(inner)
+    params = {"w": jnp.ones((8, 128))}
+    state = opt.init(params)
+    want = (optax.EmptyState(), inner.init(params))
+    assert jax.tree.structure(state) == jax.tree.structure(want)
+    assert type(state[1]) is type(want[1])
+    # Extra arguments still reach a transformation that takes them.
+    seen = {}
+
+    def update(updates, state, params=None, *, value):
+        seen["value"] = value
+        return updates, state
+
+    takes_extra = optax.GradientTransformationExtraArgs(
+        lambda p: optax.EmptyState(), update)
+    opt = hvd.DistributedOptimizer(takes_extra, axis=())
+    opt.update(params, opt.init(params), params, value=3.0)
+    assert seen == {"value": 3.0}
+
+
+# ---------------------------------------------------------------------------
+# Every pallas_call site, lowered in interpret mode.
+# ---------------------------------------------------------------------------
+
+
+def _flash(kernel):
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    if kernel == "flash_fwd":
+        return lambda: pk.flash_attention(q, q, q, block_q=128,
+                                          block_k=128)
+    if kernel in ("flash_dq", "flash_dkv"):
+        lse = jnp.zeros((1, 2, 128), jnp.float32)
+        return lambda: pk.flash_grad_block(q, q, q, q, q, lse,
+                                           block_q=128, block_k=128)
+    small = lambda q: pk.flash_attention_smallseq(  # noqa: E731
+        q, q, q, heads_per_block=2).sum()
+    if kernel == "flash_smallseq_fwd":
+        return lambda: small(q)
+    return lambda: jax.grad(small)(q)
+
+
+def _conv(kernel):
+    from horovod_tpu.ops import conv_fused as cf
+
+    a, w = jnp.ones((64, 128), jnp.float32), jnp.ones((128, 128))
+    if kernel == "conv1x1_bn":
+        return lambda: cf.matmul_bn_relu(a, w, jnp.ones(128),
+                                         jnp.zeros(128))
+    return lambda: cf.matmul_batch_stats(a, w, block_m=64)
+
+
+def _optim(kernel):
+    from horovod_tpu.ops import optim_kernels as ok
+
+    p = jnp.ones((8, 128), jnp.float32)
+    scalars = jnp.ones((3,), jnp.float32)
+    if kernel == "fused_adam":
+        return lambda: ok.adam_leaf_update(p, p, p, p, scalars)
+    return lambda: ok.sgd_leaf_update(p, p, scalars[:1], momentum=0.9,
+                                      nesterov=False)
+
+
+def _quant(kernel):
+    from horovod_tpu.quant import kernels as qk
+
+    flat = jnp.linspace(-1.0, 1.0, 32 * 256)
+    if kernel == "quantize":
+        return lambda: qk.quantize_flat(flat, 256, use_kernels=True)
+    if kernel == "dequantize":
+        return lambda: qk.dequantize_flat(
+            *qk.quantize_flat(flat, 256, use_kernels=False), 256,
+            use_kernels=True)
+    if kernel == "quantize4":
+        return lambda: qk.quantize_flat_int4(flat, 256, use_kernels=True)
+    return lambda: qk.dequantize_flat_int4(
+        *qk.quantize_flat_int4(flat, 256, use_kernels=False), 256,
+        use_kernels=True)
+
+
+KERNEL_SITES = (
+    [(_flash, k) for k in ("flash_fwd", "flash_dq", "flash_dkv",
+                           "flash_smallseq_fwd", "flash_smallseq_bwd")]
+    + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
+    + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
+    + [(_quant, k) for k in ("quantize", "dequantize", "quantize4",
+                             "dequantize4")])
+
+
+@pytest.mark.parametrize("build, kernel", KERNEL_SITES,
+                         ids=[k for _, k in KERNEL_SITES])
+def test_every_pallas_call_site_lowers_under_its_name(build, kernel):
+    text = lowered_text(build(kernel))
+    # A whole segment of the path, bare or inside jvp(..)/transpose(..).
+    assert re.search(rf"[/(]hvdt\.kernel\.{kernel}[/)]", text)
+
+
+def test_no_pallas_call_site_is_left_without_a_name():
+    """A new ``pl.pallas_call`` arrives with a ``hvdt.kernel.`` scope on
+    the line above it, and with a case in KERNEL_SITES."""
+    import inspect
+
+    from horovod_tpu.ops import conv_fused, optim_kernels, pallas_kernels
+    from horovod_tpu.quant import kernels as quant_kernels
+
+    named = []
+    for mod in (pallas_kernels, conv_fused, optim_kernels, quant_kernels):
+        lines = inspect.getsource(mod).splitlines()
+        for i, line in enumerate(lines):
+            if re.search(r"(=|return) pl\.pallas_call\($", line):
+                m = re.search(r'named_scope\("hvdt\.kernel\.(\w+)"\)',
+                              lines[i - 1])
+                assert m, f"{mod.__name__}:{i + 1} has no kernel scope"
+                named.append(m.group(1))
+    assert sorted(named) == sorted(k for _, k in KERNEL_SITES)

@@ -435,16 +435,32 @@ def _qkv(b, l, h, d, seed=0):
                  for k in ks)
 
 
-def kernel_flash_forward(*, batch=1, seq=4096, heads=16, head_dim=64):
-    """pallas_kernels._flash_call through flash_attention."""
+def kernel_flash_forward(*, batch=8, seq=4096, heads=16, head_dim=64):
+    """The local forward (pallas_kernels._flash_local_call) at the blocks
+    flash_attention itself chooses, ``out`` and the logsumexp.  The default
+    shape is the benchmark cell's (lm24x1024_s4096_b8): a block choice that
+    Mosaic cannot lower fails here and not in the benchmark.  The reference
+    takes one sequence at a time (its f32 score square is 1 GiB each)."""
     import jax
 
-    from horovod_tpu.ops.pallas_kernels import (attention_reference,
-                                                flash_attention)
+    from horovod_tpu.ops.pallas_kernels import (_flash_fwd_core,
+                                                _forward_blocks,
+                                                attention_reference)
 
     q, k, v, _ = _qkv(batch, seq, heads, head_dim)
-    _close("flash_attention", jax.jit(flash_attention)(q, k, v),
-           jax.jit(attention_reference)(q, k, v), rtol=2e-2, atol=2e-2)
+    blocks = _forward_blocks(seq, seq, head_dim, q.dtype)
+    got = jax.jit(lambda q, k, v: _flash_fwd_core(
+        q, k, v, True, head_dim ** -0.5, *blocks))(q, k, v)
+    want = jax.jit(lambda q, k, v: jax.lax.map(
+        lambda qkv: jax.tree.map(
+            lambda x: x[0],
+            attention_reference(*(x[None] for x in qkv), with_lse=True)),
+        (q, k, v)))(q, k, v)
+    _close("flash_attention", got[0], want[0], rtol=2e-2, atol=2e-2)
+    # f32 statistic of bf16 products accumulated in f32: 5e-5 off on the
+    # v5e at this shape (PR 25).
+    _close("flash_attention logsumexp", got[1], want[1], rtol=1e-3,
+           atol=1e-3)
 
 
 def kernel_flash_ring_step(*, batch=1, seq=2048, heads=16, head_dim=64):

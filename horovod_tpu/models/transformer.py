@@ -267,7 +267,11 @@ def _flash_enabled(seq_len: int, head_dim: int, *, batch: int = 1,
     at seq 512 bs 128 (2.1 GB scores) XLA's fused attention is ~1.5x
     faster than kernel-forward + blockwise backward, while at 4+ GB the
     kernel admits 2x the batch and past ~8 GB XLA attention doesn't fit
-    at all.  'on' forces it whenever shapes tile.
+    at all.  (Those were taken with the forward that passed its running
+    state through HBM; the self-contained call of PR 25 is 2.3x that one
+    at seq 4096 and has not been measured at seq 512, where the XLA
+    backward is most of the kernel path's time anyway.)  'on' forces it
+    whenever shapes tile.
 
     ``batch``/``heads`` are the sizes the kernel will actually see —
     pass LOCAL (per-shard) sizes when the call site shards them."""

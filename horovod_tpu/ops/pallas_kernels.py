@@ -8,27 +8,38 @@ streams K/V blocks through VMEM with an online softmax — HBM traffic
 drops from O(L²) to O(L·D), which is the difference between
 bandwidth-bound and MXU-bound at long sequence.
 
-Two entry points:
+Two entry points, one tile body (``_online_softmax_update``):
 
 * ``flash_attention(q, k, v)`` — fused causal/full attention for the
-  non-ring path (one device holds the whole sequence).
+  non-ring path (one device holds the whole sequence).  Its forward is
+  one self-contained call (``_flash_local_call``): q, k, v in, ``out`` in
+  the activation dtype and the f32 logsumexp out; the running
+  (acc, row_max, row_sum) lives in VMEM scratch from the first K/V block
+  to the last and never touches HBM.
 * ``flash_block_update(...)`` — one ring-attention step: takes the
   running (acc, row_max, row_sum) online-softmax carry and a K/V block
-  (with its global position offset), returns the updated carry.
+  (with its global position offset), returns the updated carry
+  (``_flash_call``: a ring step has to hand its state to the next one,
+  so this call keeps the carry in HBM on both sides).
   ``parallel/ring_attention.py`` composes it around ``lax.ppermute``.
 
-Both run in Pallas interpret mode off-TPU, so the CPU test suite
-exercises the very same kernel code (tests/test_pallas.py compares
-against the jnp reference).
+Which of the two runs is decided by which function the caller calls,
+and by nothing a user sets.  Both run in Pallas interpret mode off-TPU,
+so the CPU test suite exercises the very same kernel code
+(tests/test_pallas.py compares against the jnp reference).
 
 Layout: kernels work in [B, H, L, D]; wrappers accept the framework's
-[B, L, H, D] and transpose.  GQA/MQA is handled in the BlockSpec index
-maps (kv head = q head // group) — K/V are never materially expanded.
+[B, L, H, D] and transpose (XLA folds most of those into the producers'
+layouts).  The local forward's logsumexp leaves as lane-dense rows
+[B, H, 1, L], not as a [B, H, L, 1] column, which HBM pads to 128 lanes.
+GQA/MQA is handled in the BlockSpec index maps (kv head = q head //
+group) — K/V are never materially expanded.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -82,6 +93,30 @@ def _fit_block(n: int, block: int, *dtypes) -> int:
     return fitted
 
 
+def _online_softmax_update(s, v_blk, acc_s, m_s, l_s, mask=None,
+                           rows_may_be_empty=False):
+    """The tile arithmetic both flash forwards share: fold one K/V block,
+    whose f32 score tile is ``s`` [bq, bk], into the running (acc, m, l)
+    in VMEM scratch.  ``mask`` (True = visible) is None on a tile with
+    nothing to hide.  A masked score is -1e30, so its exp underflows to an
+    exact 0 once the row's max is finite; only a caller whose rows can
+    still be at the initial max (``rows_may_be_empty``) pays a second
+    select to zero them."""
+    if mask is not None:
+        s = jnp.where(mask, s, _NEG_INF)
+    m = m_s[...]
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if mask is not None and rows_may_be_empty:
+        p = jnp.where(mask, p, 0.0)
+    corr = jnp.exp(m - m_new)
+    acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    l_s[...] = l_s[...] * corr + p.sum(axis=-1, keepdims=True)
+    m_s[...] = m_new
+
+
 def _kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
             oacc_ref, om_ref, ol_ref, acc_s, m_s, l_s, *, causal: bool,
             scale: float):
@@ -109,32 +144,21 @@ def _kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
         l_s[...] = l_ref[0, 0, :, :].astype(jnp.float32)
 
     def _compute():
-        q = q_ref[0, 0, :, :]                   # [bq, d]
-        k_blk = k_ref[0, 0, :, :]               # [bk, d]
-        v_blk = v_ref[0, 0, :, :]
         s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
+            q_ref[0, 0, :, :], k_ref[0, 0, :, :],
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [bq, bk]
+        mask = None
         if causal:
             q_pos = (qo_ref[0] + iq * bq
                      + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0))
             k_pos = (ko_ref[0] + ik * bk
                      + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
             mask = q_pos >= k_pos               # [bq, bk]
-            s = jnp.where(mask, s, _NEG_INF)
-        m = m_s[...]
-        l = l_s[...]
-        acc = acc_s[...]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        if causal:
-            p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m - m_new)
-        acc_s[...] = acc * corr + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_s[...] = l * corr + p.sum(axis=-1, keepdims=True)
-        m_s[...] = m_new
+        # A ring step can meet a row with no visible key before any
+        # earlier block has given it a finite max: rows_may_be_empty.
+        _online_softmax_update(s, v_ref[0, 0, :, :], acc_s, m_s, l_s,
+                               mask, rows_may_be_empty=True)
 
     if causal:
         # Causal block pruning: when even this q-block's LAST row precedes
@@ -155,7 +179,7 @@ def _kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
 
 def _flash_call(q, k, v, acc, m, l, q_offset, k_offset, *, causal, scale,
                 block_q, block_k):
-    """pallas_call plumbing shared by both entry points.  All operands in
+    """The ring step's pallas_call: carry in, carry out.  All operands in
     [B, H(q or kv), L, D] / [B, H, L, 1] layout; returns (acc, m, l)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -204,43 +228,228 @@ def _flash_call(q, k, v, acc, m, l, q_offset, k_offset, *, causal, scale,
           q, k, v, acc, m, l)
 
 
+def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
+                  l_s, *, causal: bool, scale: float, fold_scale: bool,
+                  rows: int, one_tile: bool):
+    """The local (non-ring) forward, grid (b, h, iq, ik) with ik innermost:
+    nothing to carry in and nothing to hand on, so (acc, m, l) are born in
+    VMEM scratch at ik == 0 and die in the flush, which normalises, casts
+    and writes ``out`` [1,1,bq,d] in the activation dtype and the
+    logsumexp as one lane-dense row [1,1,1,bq].
+
+    Positions start at 0 on both sides, so which tiles the diagonal
+    crosses is known from (iq, ik) alone: only those build a mask.  A tile
+    is worked through in chunks of ``rows`` query rows; on a square tile's
+    diagonal a chunk stops at its own last key, so the masked triangle
+    above it costs neither matmul nor exp."""
+    import jax.experimental.pallas as pl
+
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    nk = pl.num_programs(3)
+    bq = q_ref.shape[2]
+    bk = k_ref.shape[2]
+
+    @pl.when(ik == 0)
+    def _init():
+        q = q_ref[0, 0, :, :]
+        q_s[...] = (q * scale).astype(q_s.dtype) if fold_scale else q
+        acc_s[...] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+
+    def _tile(straddles: bool):
+        for r in range(0, bq, rows):
+            # bq == bk puts a straddling tile on the diagonal (iq == ik):
+            # rows r.. see no key past r + rows.
+            keys = min(bk, r + rows) if straddles and bq == bk else bk
+            chunk = pl.ds(r, rows)
+            s = jax.lax.dot_general(
+                q_s[chunk, :], k_ref[0, 0, :keys, :],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [rows, keys]
+            if not fold_scale:
+                s = s * scale
+            mask = None
+            if straddles:
+                mask = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                        >= ik * bk - iq * bq - r)
+            # Key 0 is visible to every row and ik == 0 comes first, so no
+            # row meets a masked score with its max still at -1e30.
+            _online_softmax_update(s, v_ref[0, 0, :keys, :],
+                                   acc_s.at[chunk], m_s.at[chunk],
+                                   l_s.at[chunk], mask)
+
+    if not causal:
+        _tile(False)
+    elif one_tile:
+        # The whole sequence is the diagonal's tile.  ik is 0: the cond is
+        # there for interpret mode under shard_map (_smallseq_fwd_kernel).
+        pl.when(ik == 0)(lambda: _tile(True))
+    else:
+        visited = (iq + 1) * bq - 1 >= ik * bk            # else: all masked
+        visible = iq * bq >= (ik + 1) * bk - 1            # nothing masked
+        pl.when(visible)(lambda: _tile(False))
+        pl.when(jnp.logical_and(visited, jnp.logical_not(visible)))(
+            lambda: _tile(True))
+
+    @pl.when(ik == nk - 1)
+    def _flush():
+        l = jnp.maximum(l_s[...], 1e-30)
+        o_ref[0, 0, :, :] = (acc_s[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0, :, :] = _column_to_row(m_s[...] + jnp.log(l))
+
+
+def _column_to_row(col):
+    """[n, 1] -> [1, n]: a transpose of the column spread over one lane
+    tile, the form of the move Mosaic lowers (XLU)."""
+    n = col.shape[0]
+    return jnp.broadcast_to(col, (n, 128)).T[:1, :]
+
+
+# Scoped VMEM the local forward asks Mosaic for (half the 128 MiB of a
+# v5e or v6e core; Mosaic's default is 16), and what _forward_blocks lets
+# its own estimate reach.
+_FWD_VMEM_LIMIT = 64 * 1024 * 1024
+_FWD_VMEM_BUDGET = 56 * 1024 * 1024
+
+
+def _chunk_rows(block_q: int) -> int:
+    """Query rows per chunk of a tile.  512 measured best at every tile
+    size (PERF.md, PR 25): fewer rows pay the per-chunk work more often,
+    more rows trim less of the diagonal's masked triangle."""
+    rows = min(512, block_q)
+    while block_q % rows:
+        rows //= 2
+    return rows
+
+
+def _forward_blocks(lq: int, lk: int, head_dim: int, dtype
+                    ) -> Tuple[int, int]:
+    """(block_q, block_k) for the local forward, from the shape and VMEM
+    alone, fitted to the two lengths.
+
+    Square, and as large as fits: the whole sequence where it does.  The
+    tile's vector work sets the pace (head_dim 64 leaves the MXU four
+    fifths idle), so what a block shape can save is the work done once per
+    chunk and key block (rescaling ``acc``, the exp of a [rows, 1] column
+    that fills one lane in 128: both amortised over ``block_k``), the
+    grid step, and the masked part of the tiles on the diagonal, which
+    only a square tile can trim.  Measured on the v5e at b8 h16 L4096
+    d64 bf16, ms a call (PERF.md, PR 25): 4096 x 4096 3.95, 2048 x 2048
+    4.70, 1024 x 1024 5.25, 1024 x 2048 6.47, 512 x 1024 (no chunks) 6.49.
+
+    The estimate is fitted to what Mosaic's own account needed at seven
+    (head_dim, dtype, block) points, found by halving the limit until the
+    compile failed: per row of the block, 5 KiB + 13 x head_dim x
+    itemsize for the pipelined q/k/v/out blocks and the scratch, plus two
+    f32 score chunks; it is 0-4 MiB above each point up to 4096."""
+    per_row = 5 * 1024 + 13 * head_dim * jnp.dtype(dtype).itemsize
+    block = max(lq, lk)
+    while (block * (per_row + 8 * _chunk_rows(block)) > _FWD_VMEM_BUDGET
+           and block > 128):
+        block //= 2
+    return _fit_block(lq, block, dtype), _fit_block(lk, block, dtype)
+
+
+def _scale_folds_exactly(scale: float, dtype) -> bool:
+    """Whether ``q * scale`` in ``dtype`` loses nothing the product with
+    the f32 score tile would have kept: a power of two always, any scale
+    in f32 (to rounding)."""
+    return (jnp.dtype(dtype).itemsize >= 4
+            or math.frexp(scale)[0] == 0.5)
+
+
+def _flash_local_call(q, k, v, *, causal, scale, block_q, block_k,
+                      rows=None):
+    """The self-contained forward: q [B,H,Lq,D], k/v [B,Hkv,Lk,D] ->
+    (out [B,H,Lq,D] in q.dtype, lse [B,H,1,Lq] f32).  One pallas_call and
+    nothing around it: no carry operand or result (``_flash_call`` keeps
+    those, for the ring), statistics leave as a row, never as [..., 1]."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, lq, d = q.shape
+    _, hkv, lk, _ = k.shape
+    group = h // hkv
+    if lq % block_q or lk % block_k:
+        raise ValueError(
+            f"seq lens (q={lq}, k={lk}) must divide block sizes "
+            f"({block_q}, {block_k})")
+
+    def kv_index(bb, hh, qq, kk):
+        if causal:
+            # A tile past the diagonal is skipped: name the block that is
+            # already resident, so nothing is fetched for it.
+            kk = jnp.minimum(kk, ((qq + 1) * block_q - 1) // block_k)
+        return (bb, hh // group, kk, 0)
+
+    qspec = pl.BlockSpec((1, 1, block_q, d),
+                         lambda bb, hh, qq, kk: (bb, hh, qq, 0))
+    kvspec = pl.BlockSpec((1, 1, block_k, d), kv_index)
+    lse_spec = pl.BlockSpec((1, 1, 1, block_q),
+                            lambda bb, hh, qq, kk: (bb, hh, 0, qq))
+    kw = _vma_kw(q, k, v)
+    with jax.named_scope("hvdt.kernel.flash_fwd"):
+        return pl.pallas_call(
+            functools.partial(
+                _local_kernel, causal=causal, scale=scale,
+                fold_scale=_scale_folds_exactly(scale, q.dtype),
+                rows=rows or _chunk_rows(block_q),
+                one_tile=(lq, lk) == (block_q, block_k)),
+            grid=(b, h, lq // block_q, lk // block_k),
+            in_specs=[qspec, kvspec, kvspec],
+            out_specs=[qspec, lse_spec],
+            out_shape=(jax.ShapeDtypeStruct((b, h, lq, d), q.dtype, **kw),
+                       jax.ShapeDtypeStruct((b, h, 1, lq), jnp.float32,
+                                            **kw)),
+            scratch_shapes=[pltpu.VMEM((block_q, d), q.dtype),
+                            pltpu.VMEM((block_q, d), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_FWD_VMEM_LIMIT),
+            interpret=_use_interpret(),
+        )(q, k, v)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
-                    block_q: int = 512, block_k: int = 1024) -> jax.Array:
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None) -> jax.Array:
     """Fused flash attention; layouts/API match
     parallel.ring_attention (q,k,v: [B, L, H, D]; GQA via fewer kv heads).
 
-    Differentiable: the forward runs the Pallas kernel (pallas_call has
-    no autodiff rule of its own); the backward is the standard flash
+    Differentiable: the forward is one Pallas call, q, k, v -> (out,
+    logsumexp), with nothing around it but the layout moves (pallas_call
+    has no autodiff rule of its own); the backward is the standard flash
     gradient recomputed BLOCKWISE over K in plain XLA — the saved
     logsumexp makes the recomputation exact, and the [B,H,Lq,block_k]
     working set keeps backward memory O(L·block) instead of O(L²)
     (the property that makes long-context training fit in HBM at all).
+
+    ``block_q`` / ``block_k`` default to :func:`_forward_blocks`' choice
+    for the shape; a test passes its own to meet a given tiling.
     """
     b, lq, h, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    block_q = _fit_block(lq, block_q, q.dtype)
-    block_k = _fit_block(k.shape[1], block_k, k.dtype, v.dtype)
+    auto_q, auto_k = _forward_blocks(lq, k.shape[1], d, q.dtype)
+    block_q = _fit_block(lq, block_q, q.dtype) if block_q else auto_q
+    block_k = (_fit_block(k.shape[1], block_k, k.dtype, v.dtype)
+               if block_k else auto_k)
     return _flash_attn_diff(q, k, v, causal, float(scale), block_q,
                             block_k)
 
 
 def _flash_fwd_core(q, k, v, causal, scale, block_q, block_k):
     """Kernel forward returning (out [B,L,H,D], lse [B,H,Lq])."""
-    b, lq, h, d = q.shape
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    acc = jnp.zeros((b, h, lq, d), jnp.float32)
-    m = jnp.full((b, h, lq, 1), _NEG_INF, jnp.float32)
-    l = jnp.zeros((b, h, lq, 1), jnp.float32)
-    acc, m, l = _flash_call(qt, kt, vt, acc, m, l, 0, 0, causal=causal,
-                            scale=scale, block_q=block_q, block_k=block_k)
-    l = jnp.maximum(l, 1e-30)
-    out = (acc / l).transpose(0, 2, 1, 3).astype(q.dtype)
-    lse = (m + jnp.log(l))[..., 0]                       # [B, H, Lq]
-    return out, lse
+    out, lse = _flash_local_call(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=causal, scale=scale,
+        block_q=block_q, block_k=block_k)
+    return out.transpose(0, 2, 1, 3), lse[:, :, 0, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -265,10 +474,14 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
         # traced under jit); flipping the env after a grad function is
         # compiled does not change its backward until re-trace.  The
         # caller's forward block sizes are forwarded so the A/B against
-        # the XLA path above is like-for-like (both re-fit internally).
+        # the XLA path above is like-for-like (both re-fit internally),
+        # up to the 512 x 1024 these kernels were measured with: their
+        # whole-tile bodies cannot hold the forward's whole-sequence
+        # blocks in VMEM.
         dq, dk, dv = flash_grad_block(q, k, v, do, out, lse,
                                       causal=causal, scale=scale,
-                                      block_q=block_q, block_k=block_k)
+                                      block_q=min(block_q, 512),
+                                      block_k=min(block_k, 1024))
         return (dq.astype(q.dtype), dk.astype(k.dtype),
                 dv.astype(v.dtype))
     b, lq, h, d = q.shape
@@ -833,8 +1046,11 @@ def flash_attention_smallseq(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return _smallseq_diff(q, k, v, causal, float(scale), hb)
 
 
-def attention_reference(q, k, v, *, causal=True, scale=None):
-    """Naive jnp attention (materializes scores) — the correctness oracle."""
+def attention_reference(q, k, v, *, causal=True, scale=None,
+                        with_lse=False):
+    """Naive jnp attention (materializes scores) — the correctness oracle.
+    ``with_lse`` also returns the f32 logsumexp of the scaled, masked
+    scores, [B, H, Lq]: the statistic the flash forwards save."""
     b, lq, h, d = q.shape
     hkv = k.shape[2]
     if scale is None:
@@ -849,5 +1065,8 @@ def attention_reference(q, k, v, *, causal=True, scale=None):
         mask = jnp.arange(lq)[:, None] >= jnp.arange(lk)[None, :]
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p,
+                     v.astype(jnp.float32)).astype(q.dtype)
+    if with_lse:
+        return out, jax.nn.logsumexp(s, axis=-1)
+    return out

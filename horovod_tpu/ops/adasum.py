@@ -107,7 +107,7 @@ def adasum_allreduce(x, axis: str = "dp"):
     import jax.numpy as jnp
     from jax import lax
 
-    from .device import invariant_allgather_shards
+    from .device import _axis_size_static, invariant_allgather_shards
 
     def _one(t):
         n = _axis_size_static(axis)

@@ -59,12 +59,12 @@ def _use_interpret() -> bool:
 def _vma_kw(*ops) -> dict:
     """``{"vma": ...}`` kwargs for pallas_call out_shapes: inside
     shard_map (check_vma) out types must carry the varying-axes set, and
-    outputs vary over every axis any operand varies over.  Empty when no
-    operand varies (plain jit)."""
+    outputs vary over every axis any operand varies over.  The empty set
+    too: all-invariant operands (psum'd gradients) need a non-None vma."""
     vma = frozenset()
     for op in ops:
         vma |= frozenset(jax.typeof(op).vma)
-    return {"vma": vma} if vma else {}
+    return {"vma": vma}
 
 
 def _fit_block(n: int, block: int, *dtypes) -> int:

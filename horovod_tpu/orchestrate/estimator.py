@@ -24,11 +24,11 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import socket
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..runner.http_kv import free_port
 from .executor import Executor
 from .ml_params import MLParams
 
@@ -753,12 +753,6 @@ def _is_spark_dataframe(x) -> bool:
             and hasattr(x, "repartition"))
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
 def split_and_shard(x: np.ndarray, y: np.ndarray, validation_split: float,
                     num_workers: int):
     """Shared estimator data discipline: GLOBAL validation tail split
@@ -804,5 +798,5 @@ def collective_worker_env(env: Optional[Dict[str, str]],
     env = dict(env or {})
     env.setdefault("JAX_PLATFORMS", "cpu")
     if local_coordinator:
-        env.setdefault("HVDT_COORDINATOR_ADDR", f"127.0.0.1:{_free_port()}")
+        env.setdefault("HVDT_COORDINATOR_ADDR", f"127.0.0.1:{free_port()}")
     return env

@@ -5,7 +5,8 @@ model.  Programmatic API mirrors ref: runner/__init__.py:210 hvd.run().
 """
 
 from .hosts import HostInfo, SlotInfo, parse_hosts, get_host_assignments  # noqa: F401
-from .http_kv import RendezvousServer, KVClient, new_secret  # noqa: F401
+from .http_kv import (RendezvousServer, KVClient, free_port,  # noqa: F401
+                      new_secret)
 
 
 def run(func, np: int = 1, hosts=None, verbose: bool = False, **kwargs):
@@ -31,6 +32,11 @@ def run(func, np: int = 1, hosts=None, verbose: bool = False, **kwargs):
         argv = ["-np", str(np)]
         if hosts:
             argv += ["-H", hosts]
+        else:
+            # All workers are local, so any free port serves the
+            # coordinator; the CLI's fixed default would be shared by
+            # every run() on this machine at the same time.
+            argv += ["--coordinator-port", str(free_port())]
         if verbose:
             argv += ["--verbose"]
         argv += ["--", sys.executable, "-m", "horovod_tpu.runner.run_task"]

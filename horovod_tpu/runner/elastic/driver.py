@@ -557,7 +557,8 @@ class ElasticDriver:
             if self.registry.reset_limit_reached():
                 self._finish(1)
                 return
-            threading.Thread(target=self._rendezvous, daemon=True).start()
+            threading.Thread(target=self._safe_rerendezvous,
+                             daemon=True).start()
         elif states[FAILURE] and not states[READY]:
             if len(states[FAILURE]) >= len(self._assignments):
                 self._finish(1)

@@ -81,6 +81,13 @@ def _count_kv_error(op: str) -> None:
         m[1].inc(op=op)
 
 
+def free_port() -> int:
+    """A TCP port that is free on this host now."""
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
 def new_secret() -> bytes:
     return _secrets.token_bytes(32)
 

@@ -343,11 +343,15 @@ def run_static(args) -> int:
     try:
         for t in threads:
             t.join()
-    except KeyboardInterrupt:
+    except BaseException as e:
+        # Whatever ends the wait (Ctrl-C, or an exception raised into a
+        # programmatic caller's thread), no worker outlives its launcher.
         terminate.set()
         for t in threads:
             t.join(timeout=10)
-        return 130
+        if isinstance(e, KeyboardInterrupt):
+            return 130
+        raise
     finally:
         server.stop()
     failed = {r: c for r, c in exit_codes.items() if c != 0}

@@ -64,7 +64,11 @@ def safe_execute(command: str,
         t.start()
 
     def _killer():
-        terminate_event.wait()
+        # ends with the process: a wait without a timeout would keep one
+        # idle thread per finished command for the launcher's lifetime
+        while not terminate_event.wait(0.2):
+            if proc.poll() is not None:
+                return
         if proc.poll() is None:
             _terminate_group(proc, graceful_s)
 

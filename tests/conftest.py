@@ -18,11 +18,90 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
+import contextlib  # noqa: E402
+import signal  # noqa: E402
+import subprocess  # noqa: E402
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+# Seconds a test (its function-scoped fixtures included) may run before it
+# is failed.  Its job is to keep one hang from eating the suite's 1470 s,
+# not to police speed, so it is far above the slowest test; every wait in
+# a test helper adds up to less than this.  ``@pytest.mark.time_limit(n)``
+# gives one test another number.
+TEST_TIME_LIMIT_S = 240
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """Fail a test that is still running after its limit (SIGALRM; xdist
+    runs tests on each worker's main thread, where the handler's
+    exception interrupts a sleep, a join or a wait on a child)."""
+    marker = request.node.get_closest_marker("time_limit")
+    seconds = marker.args[0] if marker else TEST_TIME_LIMIT_S
+
+    def expired(signum, frame):
+        pytest.fail(f"still running after its limit of {seconds} s")
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+def _descendants(pid):
+    """The pids of every live descendant of ``pid`` (from /proc): a
+    launcher puts each worker into a session of its own, so a process
+    group does not reach them."""
+    children = {}
+    for entry in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            with open(f"/proc/{entry}/stat") as f:
+                ppid = int(f.read().rsplit(")", 1)[1].split()[1])
+        except OSError:     # gone since the listing
+            continue
+        children.setdefault(ppid, []).append(int(entry))
+    found, todo = [], [pid]
+    while todo:
+        for child in children.get(todo.pop(), []):
+            found.append(child)
+            todo.append(child)
+    return found
+
+
+@contextlib.contextmanager
+def spawned(*args, **kwargs):
+    """``subprocess.Popen`` whose child does not outlive the block.  On
+    every way out (a return, a failed assert, the time limit) the child
+    and all it started (a launcher's workers, and theirs) are killed and
+    the child is reaped."""
+    proc = subprocess.Popen(*args, **kwargs)
+    try:
+        yield proc
+    finally:
+        for pid in [proc.pid, *_descendants(proc.pid)]:
+            with contextlib.suppress(ProcessLookupError):
+                os.kill(pid, signal.SIGKILL)
+        proc.wait()
+        for stream in (proc.stdout, proc.stderr, proc.stdin):
+            if stream is not None:
+                stream.close()
+
+
+@pytest.fixture()
+def spawn():
+    """``subprocess.Popen`` for a test: same arguments, and every child
+    started through it is gone when the test ends (``spawned``)."""
+    with contextlib.ExitStack() as stack:
+        yield lambda *args, **kwargs: stack.enter_context(
+            spawned(*args, **kwargs))
 
 
 @pytest.fixture(scope="session")
@@ -56,6 +135,19 @@ def hvd():
     hvd_mod.shutdown()
 
 
+@pytest.fixture(autouse=True)
+def _framework_down_after():
+    """Shut the framework down after a test that left it initialized (a
+    stubbed executor runs the worker function, and its ``hvd.init()``, in
+    this process): its controller thread would outlive the test."""
+    yield
+    import sys
+
+    hvd_mod = sys.modules.get("horovod_tpu")
+    if hvd_mod is not None and hvd_mod.is_initialized():
+        hvd_mod.shutdown()
+
+
 def pickle_by_value(fn):
     """Ship a worker function to runner.run-spawned processes by VALUE:
     workers cannot import the defining test module (it lives on pytest's
@@ -66,3 +158,11 @@ def pickle_by_value(fn):
 
     cloudpickle.register_pickle_by_value(sys.modules[fn.__module__])
     return fn
+
+
+def jit_shard_map(f, **kwargs):
+    """``jax.jit(jax.shard_map(f, **kwargs))``.  Called eagerly, a
+    shard_map compiles and dispatches every primitive of its body as a
+    multi-device program of its own, which was most of the seconds of the
+    tests that did so; the product only ever runs it under jit."""
+    return jax.jit(jax.shard_map(f, **kwargs))

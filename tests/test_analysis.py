@@ -21,10 +21,7 @@ from jax import lax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 from horovod_tpu.analysis import lint as lint_mod
 from horovod_tpu.analysis import locks as locks_mod
@@ -42,7 +39,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _smap_kw():
-    sig = inspect.signature(shard_map).parameters
+    sig = inspect.signature(jax.shard_map).parameters
     if "check_rep" in sig:
         return {"check_rep": False}
     if "check_vma" in sig:
@@ -792,7 +789,8 @@ class TestExpectedScheduleUnit:
 
 
 @pytest.mark.integration
-def test_hang_desync_report_names_expected_collective(mesh8, tmp_path):
+def test_hang_desync_report_names_expected_collective(mesh8, tmp_path,
+                                                      spawn):
     """The PR-6 hang scenario with HVDT_EXPECTED_SCHEDULE exported by
     the static analyzer: rank 1 wedges before step 6's collective; the
     desync report's expected_schedule section must name seq 6 and the
@@ -825,7 +823,7 @@ def test_hang_desync_report_names_expected_collective(mesh8, tmp_path):
                 "DESYNC_TEST_ABORT_S": "1.0",
             })
             env.pop("HVDT_FAULT_JOURNAL", None)
-            procs.append(subprocess.Popen(
+            procs.append(spawn(
                 [sys.executable,
                  os.path.join(REPO, "tests", "data", "desync_main.py")],
                 env=env, cwd=REPO, stdout=subprocess.PIPE,
@@ -868,7 +866,7 @@ def test_hang_desync_report_names_expected_collective(mesh8, tmp_path):
 def test_cli_all_gate_exits_zero():
     r = subprocess.run(
         [sys.executable, "-m", "horovod_tpu.analysis", "--all"],
-        cwd=REPO, capture_output=True, text=True, timeout=240,
+        cwd=REPO, capture_output=True, text=True, timeout=200,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-2000:]
     assert "hvdt-analysis: CLEAN" in r.stdout

@@ -875,7 +875,7 @@ def _write_synthetic_fingerprint(path):
         json.dump(doc, fh)
 
 
-def test_multiprocess_hang_fires_cluster_attribution(tmp_path):
+def test_multiprocess_hang_fires_cluster_attribution(tmp_path, spawn):
     """Acceptance scenario: two ranks (pods podA/podB) run a lockstep
     step loop under full attribution telemetry; a hang@step fault
     wedges rank 1 inside one timed step.  The driver side (this
@@ -915,7 +915,7 @@ def test_multiprocess_hang_fires_cluster_attribution(tmp_path):
                 "ATTR_TEST_STEP_S": "0.04",
             })
             env.pop("HVDT_FAULT_JOURNAL", None)
-            procs.append(subprocess.Popen(
+            procs.append(spawn(
                 [sys.executable,
                  os.path.join(REPO, "tests", "data",
                               "attribution_main.py")],

@@ -106,7 +106,7 @@ def test_bench_without_a_chip_exits_nonzero_with_no_metric():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
                           env=env, capture_output=True, text=True,
-                          timeout=300)
+                          timeout=200)
     assert proc.returncode != 0
     assert proc.stdout == ""
     assert "not a TPU" in proc.stderr

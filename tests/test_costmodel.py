@@ -631,7 +631,7 @@ def test_cli_perf_gate_subprocess():
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "horovod_tpu.analysis", "--perf"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=420)
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=200)
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
     assert "hvdt-perf: 0 problem(s)" in proc.stdout
     assert "hvdt-analysis: CLEAN" in proc.stdout

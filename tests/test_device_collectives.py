@@ -11,10 +11,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 from horovod_tpu.common.types import ReduceOp
 from horovod_tpu.ops import device as dev
@@ -204,7 +201,7 @@ class TestHierarchicalAllreduce:
             return device.hierarchical_allreduce(
                 x, inner_axis="tp", outer_axis="dp", op=op)
 
-        got = jax.shard_map(
+        got = shard_map(
             local, mesh=mesh,
             in_specs=P(("dp", "tp")), out_specs=P())(xs)
         want = xs.sum(0) if op == ReduceOp.SUM else xs.mean(0)
@@ -219,7 +216,7 @@ class TestHierarchicalAllreduce:
         mesh = make_mesh(dp=2, tp=2, devices=jax.devices()[:4])
         xs = jnp.ones((4, 4))
 
-        got = jax.shard_map(
+        got = shard_map(
             lambda x: device.hierarchical_allreduce(
                 x.reshape(4), inner_axis="tp", outer_axis="dp",
                 op=ReduceOp.SUM, prescale_factor=2.0,
@@ -240,7 +237,7 @@ class TestShardedAdasum:
         inputs = rng.normal(size=(n, count)).astype(np.float32)
         mesh = hvd.mesh()
 
-        got = jax.shard_map(
+        got = shard_map(
             lambda x: adasum_allreduce(x.reshape(count), axis="dp"),
             mesh=mesh, in_specs=P("dp"), out_specs=P())(
                 jnp.asarray(inputs).reshape(n * count))

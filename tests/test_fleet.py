@@ -14,7 +14,6 @@ that one is ``slow`` and runs in the test-smoke compose service.
 import http.client
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -791,7 +790,7 @@ class TestWiring:
 # which does not filter the slow marker.
 @pytest.mark.slow
 @pytest.mark.integration
-def test_fleet_day_in_the_life(tmp_path, kv_server):
+def test_fleet_day_in_the_life(tmp_path, kv_server, spawn):
     """One fleet, two workloads, one simulated day: a real ServeDriver
     spawns replica *subprocesses* against the shared RendezvousServer, a
     real Router carries client load, and the fleet scheduler moves pods
@@ -826,7 +825,7 @@ def test_fleet_day_in_the_life(tmp_path, kv_server):
             "HVDT_SERVE_REPLICA_ID": str(rid),
             "HVDT_RANK": str(rid),
         })
-        proc = subprocess.Popen(
+        proc = spawn(
             [sys.executable, "-m", "horovod_tpu.serve",
              "--checkpoint", ckpt_dir, "--model", "mlp",
              "--mlp-sizes", "6,16,3", "--buckets", "1,4",

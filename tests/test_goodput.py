@@ -650,13 +650,13 @@ def _scenario_env(tmp_path, extra):
     return env
 
 
-def _run_scenario(tmp_path, env, discover_lines, port, min_np, max_np,
-                  timeout=300):
+def _run_scenario(spawn, tmp_path, env, discover_lines, port, min_np,
+                  max_np, timeout=200):
     discover = os.path.join(str(tmp_path), "discover.sh")
     with open(discover, "w") as f:
         f.write("#!/bin/sh\n" + discover_lines + "\n")
     os.chmod(discover, 0o755)
-    proc = subprocess.Popen(
+    proc = spawn(
         [sys.executable, "-m", "horovod_tpu.runner.launch",
          "--min-np", str(min_np), "--max-np", str(max_np),
          "--host-discovery-script", discover,
@@ -720,7 +720,7 @@ def _assert_goodput_invariants(records, text, total, budget_s=30.0,
     assert f"final: batches={total} w0={total / 10:.1f}" in text
 
 
-def test_kill_rank1_recovers_from_peer_ram_within_budget(tmp_path):
+def test_kill_rank1_recovers_from_peer_ram_within_budget(tmp_path, spawn):
     """Acceptance scenario 1: crash@step=10:rank=1 under
     HVDT_ASYNC_CKPT=1 + HVDT_PEER_STORE=1 — recovery restores both
     ranks from the peer RAM tier (zero disk restores), inside the 30 s
@@ -728,7 +728,7 @@ def test_kill_rank1_recovers_from_peer_ram_within_budget(tmp_path):
     env = _scenario_env(str(tmp_path), {
         "HVDT_FAULT_PLAN": "crash@step=10:rank=1",
     })
-    rc, text = _run_scenario(tmp_path, env, "echo localhost:2",
+    rc, text = _run_scenario(spawn, tmp_path, env, "echo localhost:2",
                              port=29791, min_np=2, max_np=2)
     assert rc == 0, text[-3000:]
     records = _records(env["ELASTIC_TEST_LOG"])
@@ -738,7 +738,7 @@ def test_kill_rank1_recovers_from_peer_ram_within_budget(tmp_path):
 
 
 @pytest.mark.slow
-def test_pod_kill_recovers_from_peer_ram(tmp_path):
+def test_pod_kill_recovers_from_peer_ram(tmp_path, spawn):
     """Acceptance scenario 2 (pod variant): pod_crash@step=10:pod=podB
     kills both ranks of pod B; every respawned rank restores from the
     peer RAM tier and the committed batch stream stays gap-free.
@@ -753,8 +753,9 @@ def test_pod_kill_recovers_from_peer_ram(tmp_path):
         "ELASTIC_TEST_SLEEP": "0.1",
     })
     rc, text = _run_scenario(
-        tmp_path, env, "echo localhost:2@podA\necho 127.0.0.1:2@podB",
-        port=29796, min_np=2, max_np=4, timeout=360)
+        spawn, tmp_path, env,
+        "echo localhost:2@podA\necho 127.0.0.1:2@podB",
+        port=29796, min_np=2, max_np=4)
     assert rc == 0, text[-3000:]
     records = _records(env["ELASTIC_TEST_LOG"])
     _assert_goodput_invariants(records, text, total=16,

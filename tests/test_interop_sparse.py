@@ -4,9 +4,10 @@ test_torch.py sparse_allreduce cases; torch binding API tests)."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from conftest import jit_shard_map
 
 
 class TestSparseAllreduce:
@@ -44,7 +45,7 @@ class TestSparseAllreduce:
 
         idx = jnp.arange(n, dtype=jnp.int32)          # one row per shard
         val = jnp.ones((n, 2), jnp.float32) * 4.0
-        gi, gv = jax.shard_map(
+        gi, gv = jit_shard_map(
             local, mesh=mesh, in_specs=(P("dp"), P("dp")),
             out_specs=(P("dp"), P("dp")))(idx, val)
         assert gi.shape == (n * n,)  # each shard now holds all indices

@@ -35,6 +35,12 @@ class TestKerasEstimator:
         with pytest.raises(ValueError, match="requires a compiled"):
             KerasEstimator()
 
+    # ``slow``: 18 s alone and 35 s beside five busy workers, nearly all
+    # of it a worker and the driver importing TensorFlow.  What it checks
+    # of fit, transform, the store's checkpoint and the handle's save is
+    # checked on the two-worker fit below, which stays in tier-1; only the
+    # one-worker world is left to the compose test-integration service.
+    @pytest.mark.slow
     @pytest.mark.integration
     def test_fit_transform_single_worker(self, tmp_path):
         from horovod_tpu.orchestrate import KerasEstimator
@@ -55,31 +61,24 @@ class TestKerasEstimator:
         # handle round-trips through keras save
         model.save(str(tmp_path / "final.keras"))
 
-    @pytest.mark.integration
-    def test_fit_two_workers_matches_contract(self):
-        """2 worker processes forming ONE world: per-step gradients
-        average across ranks (wrapped optimizer), initial state
-        broadcast, and both ranks end with IDENTICAL weights — the
-        proof the collectives actually ran (fit() itself verifies
-        hvd.size()==2 in every worker and raises otherwise)."""
-        from horovod_tpu.orchestrate import KerasEstimator
+    _two_worker_fit = None
 
-        x, y = _toy_regression(n=64)
-        est = KerasEstimator(model=_compiled_model(), num_workers=2,
-                             epochs=10, batch_size=16,
-                             validation_split=0.25)
-        model = est.fit(x, y)
-        pred = model.predict(x)
-        mse = float(np.mean((pred - y) ** 2))
-        assert mse < 3.0, mse
-        assert est.history_ and est.history_[-1]["loss"] < \
-            est.history_[0]["loss"]
-        assert "val_loss" in est.history_[0]
+    @pytest.fixture()
+    def two_worker_fit(self, tmp_path_factory):
+        """ONE fit over two worker processes for the two tests below:
+        what each worker returned (through a spy on ``Executor.run``),
+        the estimator, the model and the data.  The workers' start-up,
+        each importing TensorFlow and jax, is nearly all of the time.
+        Kept on the class by hand and not by a class scope, so that the
+        fit runs inside a test's time limit."""
+        cls = type(self)
+        if cls._two_worker_fit is None:
+            cls._two_worker_fit = self._fit_two_workers(
+                tmp_path_factory.mktemp("keras_two_workers"))
+        return cls._two_worker_fit
 
-    @pytest.mark.integration
-    def test_two_workers_end_in_sync(self, monkeypatch):
-        """Rank checksums after fit must MATCH — divergent weights mean
-        the gradient averaging silently no-opped."""
+    @staticmethod
+    def _fit_two_workers(tmp_path):
         from horovod_tpu.orchestrate import KerasEstimator
         from horovod_tpu.orchestrate.executor import Executor
 
@@ -87,16 +86,45 @@ class TestKerasEstimator:
         orig_run = Executor.run
 
         def spy(self, fn, args=(), kwargs=None, per_rank_args=None):
-            results = orig_run(self, fn, args=args, kwargs=kwargs,
-                               per_rank_args=per_rank_args)
-            captured["results"] = results
-            return results
+            captured["results"] = orig_run(
+                self, fn, args=args, kwargs=kwargs,
+                per_rank_args=per_rank_args)
+            return captured["results"]
 
-        monkeypatch.setattr(Executor, "run", spy)
-        x, y = _toy_regression(n=48, seed=4)
-        KerasEstimator(model=_compiled_model(seed=5), num_workers=2,
-                       epochs=3, batch_size=12).fit(x, y)
-        res = captured["results"]
+        x, y = _toy_regression(n=64)
+        est = KerasEstimator(model=_compiled_model(), num_workers=2,
+                             epochs=10, batch_size=16,
+                             validation_split=0.25,
+                             store=str(tmp_path / "store"))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(Executor, "run", spy)
+            model = est.fit(x, y)
+        return est, model, captured["results"], x, y, tmp_path
+
+    @pytest.mark.integration
+    def test_fit_two_workers_matches_contract(self, two_worker_fit):
+        """2 worker processes forming ONE world: per-step gradients
+        average across ranks (wrapped optimizer), initial state
+        broadcast, and both ranks end with IDENTICAL weights — the
+        proof the collectives actually ran (fit() itself verifies
+        hvd.size()==2 in every worker and raises otherwise)."""
+        est, model, _, x, y, tmp_path = two_worker_fit
+        pred = model.predict(x)
+        mse = float(np.mean((pred - y) ** 2))
+        assert mse < 3.0, mse
+        assert est.history_ and est.history_[-1]["loss"] < \
+            est.history_[0]["loss"]
+        assert "val_loss" in est.history_[0]
+        assert model.transform(x).shape == (len(x), 1)
+        assert (tmp_path / "store" / "checkpoint.keras").exists()
+        # handle round-trips through keras save
+        model.save(str(tmp_path / "final.keras"))
+
+    @pytest.mark.integration
+    def test_two_workers_end_in_sync(self, two_worker_fit):
+        """Rank checksums after fit must MATCH — divergent weights mean
+        the gradient averaging silently no-opped."""
+        res = two_worker_fit[2]
         assert [r["size"] for r in res] == [2, 2]
         assert res[0]["checksum"] == pytest.approx(res[1]["checksum"],
                                                    abs=1e-8)

@@ -20,7 +20,7 @@ TINY = ["--layers", "2", "--d-model", "64", "--heads", "4",
         "--batch", "8", "--steps", "3"]
 
 
-def _run(extra, env_extra=None, timeout=420):
+def _run(extra, env_extra=None, timeout=200):
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",

@@ -15,10 +15,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 from horovod_tpu import optimizer as hvd_opt
 from horovod_tpu import quant
@@ -144,35 +141,26 @@ class TestInt4Kernels:
 
 
 class TestInt4Allreduce:
-    def test_matches_f32_allreduce_within_bound(self, mesh8):
-        x = jnp.asarray(np.random.RandomState(4).randn(8, 500),
+    # AVERAGE over a length that needs padding to the blocks, SUM over
+    # one that does not: two lossy stages, each bounded by its block's
+    # absmax / 7 / 2, and a sum carries eight ranks' worth of it.
+    @pytest.mark.parametrize("op,seed,n,ranks_of_error", [
+        (ReduceOp.AVERAGE, 4, 500, 1), (ReduceOp.SUM, 5, 512, 8)],
+        ids=["average", "sum"])
+    def test_matches_f32_allreduce_within_bound(self, mesh8, op, seed, n,
+                                                ranks_of_error):
+        x = jnp.asarray(np.random.RandomState(seed).randn(8, n),
                         jnp.float32)
 
         def body(xl):
             return quant.quantized_allreduce_flat(
-                xl[0], "dp", ReduceOp.AVERAGE, block_size=BLOCK,
-                wire="int4")
+                xl[0], "dp", op, block_size=BLOCK, wire="int4")
 
         out = shard_map(body, mesh=mesh8, in_specs=(P("dp"),),
                         out_specs=P())(x)
-        want = np.asarray(x).mean(0)
-        # two lossy stages, each bounded by its block absmax/7/2
-        tol = np.abs(np.asarray(x)).max() / 7.0 + 1e-6
-        np.testing.assert_allclose(np.asarray(out), want, atol=tol)
-
-    def test_sum_matches_f32(self, mesh8):
-        x = jnp.asarray(np.random.RandomState(5).randn(8, 512),
-                        jnp.float32)
-
-        def body(xl):
-            return quant.quantized_allreduce_flat(
-                xl[0], "dp", ReduceOp.SUM, block_size=BLOCK,
-                wire="int4")
-
-        out = shard_map(body, mesh=mesh8, in_specs=(P("dp"),),
-                        out_specs=P())(x)
-        want = np.asarray(x).sum(0)
-        tol = 8 * np.abs(np.asarray(x)).max() / 7.0 + 1e-5
+        want = (np.asarray(x).mean(0) if op == ReduceOp.AVERAGE
+                else np.asarray(x).sum(0))
+        tol = ranks_of_error * np.abs(np.asarray(x)).max() / 7.0 + 1e-5
         np.testing.assert_allclose(np.asarray(out), want, atol=tol)
 
     def test_identical_on_grid_ranks_exact(self, mesh8):

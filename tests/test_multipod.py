@@ -400,18 +400,24 @@ class _PodCluster:
         # hosts: [(hostname, slots, pod)]
         self.hosts = {h: (s, p) for h, s, p in hosts}
         self.exited = {}
+        self.stopped = threading.Event()
 
     def discover(self):
         return [HostInfo(h, s, p)
                 for h, (s, p) in sorted(self.hosts.items())]
 
     def spawn(self, slot, gen):
+        """A worker that runs until the test scripts its exit, or ends
+        (``stop``), so no worker thread outlives its test."""
         deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
+        while time.monotonic() < deadline and not self.stopped.is_set():
             if (slot.rank, gen) in self.exited:
                 return self.exited[(slot.rank, gen)]
             time.sleep(0.02)
         return 0
+
+    def stop(self):
+        self.stopped.set()
 
 
 def _wait_for_generation(driver, gen, timeout=5.0):
@@ -457,6 +463,7 @@ class TestDriverPodSemantics:
             assert len(driver.assignments) == 4
         finally:
             driver.stop()
+            cluster.stop()
 
     def test_preempt_exit_drains_whole_pod(self):
         cluster = _PodCluster([("a", 2, "A"), ("b", 2, "A"),
@@ -478,6 +485,7 @@ class TestDriverPodSemantics:
             assert {s.pod for s in driver.assignments} == {"A"}
         finally:
             driver.stop()
+            cluster.stop()
 
     def test_straggler_eviction_resizes_down(self):
         cluster = _PodCluster([("a", 2, "A"), ("b", 2, "A"),
@@ -520,6 +528,7 @@ class TestDriverPodSemantics:
             assert {s.pod for s in driver.assignments} == {"A"}
         finally:
             driver.stop()
+            cluster.stop()
             server.stop()
 
     def test_wait_for_available_slots_timeout(self):
@@ -624,8 +633,18 @@ def _rows(path):
     return out
 
 
+# ``slow``: 35 s alone, 47 s beside five busy workers: a launcher and three
+# generations of four jax workers, so process start-up nearly all of it,
+# and its 70 s windows are the first to go on a loaded machine.  The
+# compose test-smoke service runs it (this file is on its list).  Tier-1
+# keeps the driver's side in TestDriverPodSemantics (correlated exits fold
+# into one pod-removal, the drain, the resize down and up) and the real
+# launcher's crash-and-resume in tests/test_resilience.py
+# (test_injected_crash_recovers_with_step_continuity) and
+# tests/test_goodput.py (test_kill_rank1_recovers_from_peer_ram_within_budget).
+@pytest.mark.slow
 @pytest.mark.integration
-def test_pod_crash_recovery_and_rejoin(tmp_path):
+def test_pod_crash_recovery_and_rejoin(tmp_path, spawn):
     """The acceptance scenario: ``pod_crash@step=10:pod=podB`` kills both
     ranks of pod B mid-training over a real RendezvousServer.  The
     driver must collapse the two exits into a single pod-removal (one
@@ -673,7 +692,7 @@ if [ -f {control} ]; then
 fi
 """)
     os.chmod(discover, 0o755)
-    proc = subprocess.Popen(
+    proc = spawn(
         [sys.executable, "-m", "horovod_tpu.runner.launch",
          "--min-np", "2", "--max-np", "4",
          "--host-discovery-script", discover,
@@ -708,18 +727,18 @@ fi
     #    reclaims the dead slice).
     _wait_until(lambda: any("pod-removal event for pod podB" in ln
                             for ln in lines),
-                "pod crash never collapsed into a pod-removal", 180)
+                "pod crash never collapsed into a pod-removal", 70)
     os.remove(control)
     # 2. The survivors resize to the one remaining pod and make progress
     #    past the crash point...
     _wait_until(lambda: os.path.exists(log_path) and any(
         s == 2 and b >= 20 for _, s, _, b, _ in _rows(log_path)),
-                "shrunk pod-multiple world never resumed", 180)
+                "shrunk pod-multiple world never resumed", 70)
     # 3. ...then pod B comes back (cooldown long expired) and the run
     #    scales back up to both pods.
     open(control, "w").write("up")
     try:
-        proc.wait(timeout=240)
+        proc.wait(timeout=70)
     except subprocess.TimeoutExpired:
         proc.kill()
         pytest.fail(f"multipod chaos run hung:\n{''.join(lines)[-3000:]}")

@@ -15,10 +15,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax spelling
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 from horovod_tpu.ops.optim_kernels import (fused_adam, fused_sgd,
                                            fused_update_eligible)

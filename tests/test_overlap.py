@@ -14,10 +14,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 from horovod_tpu import optimizer as hvd_opt
 from horovod_tpu import step_pipeline
@@ -32,7 +29,7 @@ def _smap_kw():
     """check_rep/check_vma off where the kwarg exists: pre-vma JAX has
     no replication rule for pallas_call (same pattern as
     tests/test_optim_kernels.py)."""
-    sig = inspect.signature(shard_map).parameters
+    sig = inspect.signature(jax.shard_map).parameters
     if "check_rep" in sig:
         return {"check_rep": False}
     if "check_vma" in sig:
@@ -314,6 +311,15 @@ class TestExchangeNumerics:
 # ---------------------------------------------------------------------------
 
 
+def _collectives_and_dots(lowered):
+    """Text offsets of the all-reduces and of the matmuls in a lowered
+    step, in the order the program issues them."""
+    txt = lowered.as_text().lower()
+    ar = [m.start() for m in re.finditer(r"all[-_]reduce", txt)]
+    dots = [m.start() for m in re.finditer(r"dot_general|\bdot\(", txt)]
+    return ar, dots
+
+
 class TestHloInterleaving:
     def _stages(self, rng, depth=3):
         sizes = [(16, 32)] + [(32, 32)] * (depth - 2) + [(32, 1)]
@@ -379,10 +385,7 @@ class TestHloInterleaving:
         fn = jax.jit(shard_map(body, mesh=mesh8,
                                in_specs=(P("dp"),) + (P(),) * 4,
                                out_specs=(P(),) * 5))
-        txt = fn.lower(x, *params).as_text().lower()
-        ar = [m.start() for m in re.finditer(r"all[-_]reduce", txt)]
-        dots = [m.start() for m in
-                re.finditer(r"dot_general|\bdot\(", txt)]
+        ar, dots = _collectives_and_dots(fn.lower(x, *params))
         assert len(ar) >= 4, "expected one collective per stage"
         assert dots, "expected dot ops in the lowered text"
         # interleaved: backward matmuls appear AFTER the first issued
@@ -405,7 +408,13 @@ class TestHloInterleaving:
             return a
 
         def body(xl, *ps):
-            loss, grads = jax.value_and_grad(loss_all)(list(ps), xl[0])
+            # The product's monolithic path: gradients w.r.t. pvary'd
+            # params stay per-rank, and the exchange after the backward
+            # reduces them.  W.r.t. replicated params jax's vma autodiff
+            # psums each gradient inside the backward itself and leaves
+            # fused_allreduce nothing to do: no trailing block to see.
+            loss, grads = jax.value_and_grad(loss_all)(
+                hvd_opt.pvary_tree(list(ps), "dp"), xl[0])
             grads = [dev.fused_allreduce(g, "dp", ReduceOp.AVERAGE)
                      for g in grads]
             return (jax.lax.pmean(loss, "dp"),) + tuple(
@@ -414,14 +423,11 @@ class TestHloInterleaving:
         fn = jax.jit(shard_map(body, mesh=mesh8,
                                in_specs=(P("dp"),) + (P(),) * 4,
                                out_specs=(P(),) * 5))
-        txt = fn.lower(x, *params).as_text().lower()
-        ar = [m.start() for m in re.finditer(r"all[-_]reduce", txt)]
-        dots = [m.start() for m in
-                re.finditer(r"dot_general|\bdot\(", txt)]
-        # monolithic: gradient collectives all trace after the backward
-        # dots (the pmean may still ride along; the param-grad
-        # collectives are the len(stages) last all_reduces)
-        assert all(a > dots[-1] for a in ar[-len(stages):])
+        ar, dots = _collectives_and_dots(fn.lower(x, *params))
+        # one all_reduce per stage's gradient and the loss's pmean, every
+        # one of them after the last backward matmul
+        assert len(ar) == len(stages) + 1
+        assert all(a > dots[-1] for a in ar)
 
     def test_rejects_nonscalar_last_stage(self, overlap_on):
         ovg = ovl.overlap_value_and_grad(

@@ -29,7 +29,9 @@ def shard_map(*args, **kw):
     kw.pop("check_rep", None)
     kw.pop("check_vma", None)
     kw.update(_SMAP_KW)
-    return _shard_map(*args, **kw)
+    # jitted: an eager shard_map compiles every primitive of its body
+    # as a multi-device program of its own
+    return jax.jit(_shard_map(*args, **kw))
 
 
 from horovod_tpu.parallel import (
@@ -300,37 +302,51 @@ class TestRingCustomVjp:
     autodiff through the forward scan would save O(Lq x Lglobal) scores
     per device."""
 
-    @pytest.mark.parametrize("causal,h,hkv,sp_n",
-                             [(True, 2, 2, 4), (False, 2, 2, 4),
-                              (True, 4, 2, 2)])
-    def test_ring_grads_match_dense(self, causal, h, hkv, sp_n):
+    # jnp ring and Pallas ring (flash_block_update forward,
+    # flash_grad_block backward: the ring is TRAINABLE through its
+    # kernels, VERDICT r2 #4) are cases of one test.  The kernels tile at
+    # 128, so their cases hold 128 positions a rank, the jnp ones 64.
+    @pytest.mark.parametrize("use_pallas,causal,h,hkv,sp_n", [
+        (False, True, 2, 2, 4), (False, False, 2, 2, 4),
+        (False, True, 4, 2, 2),
+        (True, True, 2, 2, 4), (True, False, 2, 2, 2),
+        (True, True, 4, 2, 2)],
+        ids=["jnp-causal-sp4", "jnp-full-sp4", "jnp-causal-gqa-sp2",
+             "pallas-causal-sp4", "pallas-full-sp2", "pallas-causal-gqa-sp2"])
+    def test_ring_grads_match_dense(self, use_pallas, causal, h, hkv, sp_n):
         from jax.sharding import Mesh, PartitionSpec as P
 
         from horovod_tpu.ops.pallas_kernels import attention_reference
         from horovod_tpu.parallel import ring_attention
 
         mesh = Mesh(np.array(jax.devices()[:sp_n]).reshape(sp_n), ("sp",))
-        rng = np.random.RandomState(1)
-        L = 64 * sp_n
-        q = jnp.asarray(rng.randn(2, L, h, 16), jnp.float32)
-        k = jnp.asarray(rng.randn(2, L, hkv, 16), jnp.float32)
-        v = jnp.asarray(rng.randn(2, L, hkv, 16), jnp.float32)
+        rng = np.random.RandomState(3 if use_pallas else 1)
+        b, L = (1, 128 * sp_n) if use_pallas else (2, 64 * sp_n)
+        q = jnp.asarray(rng.randn(b, L, h, 16), jnp.float32)
+        k = jnp.asarray(rng.randn(b, L, hkv, 16), jnp.float32)
+        v = jnp.asarray(rng.randn(b, L, hkv, 16), jnp.float32)
         w = jnp.asarray(rng.randn(16), jnp.float32)
 
         def ring_loss(q, k, v):
             def local(q, k, v):
-                return ring_attention(q, k, v, axis="sp", causal=causal)
+                return ring_attention(q, k, v, axis="sp", causal=causal,
+                                      use_pallas=use_pallas)
+            # check_vma=False for the kernels: interpret-mode pallas_call
+            # slices operand blocks with plain indices, which the vma
+            # checker rejects for 'sp'-varying operands (real TPU lowers
+            # natively with check_vma on).  The module's shard_map
+            # wrapper turns it off for both.
             out = shard_map(local, mesh=mesh,
-                                in_specs=(P(None, "sp"),) * 3,
-                                out_specs=P(None, "sp"))(q, k, v)
+                            in_specs=(P(None, "sp"),) * 3,
+                            out_specs=P(None, "sp"))(q, k, v)
             return ((out * w) ** 2).sum()
 
         def ref_loss(q, k, v):
             return ((attention_reference(q, k, v, causal=causal) * w) ** 2
                     ).sum()
 
-        got = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-        ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+        got = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+        ref = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(got, ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-5, rtol=1e-4)
@@ -356,54 +372,6 @@ class TestRingCustomVjp:
 
         g = jax.grad(loss)(q)
         assert np.all(np.isfinite(np.asarray(g)))
-
-
-class TestRingPallasBackward:
-    """ring_attention(use_pallas=True) is TRAINABLE: both ring passes run
-    Pallas kernels (flash_block_update fwd, flash_grad_block bwd) and
-    grads must match dense attention (VERDICT r2 #4 — beyond-parity:
-    SURVEY §5.7 notes the reference has no long-context substrate)."""
-
-    @pytest.mark.parametrize("causal,h,hkv,sp_n",
-                             [(True, 2, 2, 4), (False, 2, 2, 2),
-                              (True, 4, 2, 2)])
-    def test_pallas_ring_grads_match_dense(self, causal, h, hkv, sp_n):
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        from horovod_tpu.ops.pallas_kernels import attention_reference
-        from horovod_tpu.parallel import ring_attention
-
-        mesh = Mesh(np.array(jax.devices()[:sp_n]).reshape(sp_n), ("sp",))
-        rng = np.random.RandomState(3)
-        L = 128 * sp_n
-        q = jnp.asarray(rng.randn(1, L, h, 16), jnp.float32)
-        k = jnp.asarray(rng.randn(1, L, hkv, 16), jnp.float32)
-        v = jnp.asarray(rng.randn(1, L, hkv, 16), jnp.float32)
-        w = jnp.asarray(rng.randn(16), jnp.float32)
-
-        def ring_loss(q, k, v):
-            def local(q, k, v):
-                return ring_attention(q, k, v, axis="sp", causal=causal,
-                                      use_pallas=True)
-            # check_vma=False: interpret-mode pallas_call slices operand
-            # blocks with plain indices, which the vma checker rejects
-            # for 'sp'-varying operands (same workaround as the forward
-            # test above; real TPU lowers natively with check_vma on).
-            out = shard_map(local, mesh=mesh,
-                                in_specs=(P(None, "sp"),) * 3,
-                                out_specs=P(None, "sp"),
-                                check_vma=False)(q, k, v)
-            return ((out * w) ** 2).sum()
-
-        def ref_loss(q, k, v):
-            return ((attention_reference(q, k, v, causal=causal) * w) ** 2
-                    ).sum()
-
-        got = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-        ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(got, ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-5, rtol=1e-4)
 
 
 class TestFlashGradBlockKernel:

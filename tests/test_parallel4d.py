@@ -45,7 +45,9 @@ _SMAP_KW = ({"check_rep": False} if "check_rep" in _SMAP_SIG
 
 def shard_map(*args, **kw):
     kw.update(_SMAP_KW)
-    return _shard_map(*args, **kw)
+    # jitted: an eager shard_map compiles every primitive of its body
+    # as a multi-device program of its own
+    return jax.jit(_shard_map(*args, **kw))
 
 
 # Acceptance geometry: 2 stages x 2 experts x 2 dp on 8 chips.

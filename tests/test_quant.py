@@ -11,10 +11,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 from horovod_tpu import optimizer as hvd_opt
 from horovod_tpu import quant

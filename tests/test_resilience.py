@@ -394,14 +394,14 @@ class TestPreemptionGuard:
 
     @pytest.mark.integration
     def test_sigterm_subprocess_emergency_checkpoint_and_exit_code(
-            self, tmp_path):
+            self, tmp_path, spawn):
         """Acceptance: SIGTERM produces an emergency checkpoint and the
         clean-removal exit code (real process, real signal)."""
         out = os.path.join(tmp_path, "emergency.json")
         env = dict(os.environ, PREEMPT_TEST_OUT=out, JAX_PLATFORMS="cpu",
                    PYTHONPATH=REPO + os.pathsep + os.environ.get(
                        "PYTHONPATH", ""))
-        proc = subprocess.Popen(
+        proc = spawn(
             [sys.executable, os.path.join(REPO, "tests", "data",
                                           "preempt_main.py")],
             env=env, cwd=REPO, stdout=subprocess.PIPE, text=True)
@@ -428,11 +428,17 @@ class TestPreemptionGuard:
         driver._assignments = get_host_assignments(
             [HostInfo("a", 2)], 2)
         driver.registry.reset(2)
-        driver.record_exit(driver._assignments[1], 1, PREEMPT_EXIT_CODE)
-        assert driver.registry.count("READY") == 1
-        assert not hm.is_blacklisted("a")
-        driver.record_exit(driver._assignments[0], 1, 1)   # real crash
-        assert hm.is_blacklisted("a")
+        try:
+            driver.record_exit(driver._assignments[1], 1, PREEMPT_EXIT_CODE)
+            assert driver.registry.count("READY") == 1
+            assert not hm.is_blacklisted("a")
+            driver.record_exit(driver._assignments[0], 1, 1)  # real crash
+            assert hm.is_blacklisted("a")
+        finally:
+            # the second exit completes the barrier, which starts a
+            # re-rendezvous thread that waits for slots the blacklist
+            # has just taken away: end it with the test
+            driver.stop()
 
 
 # ---------------------------------------------------------------------------
@@ -785,7 +791,7 @@ def _rows(path):
 
 
 @pytest.mark.integration
-def test_injected_crash_recovers_with_step_continuity(tmp_path):
+def test_injected_crash_recovers_with_step_continuity(tmp_path, spawn):
     """Acceptance scenario: HVDT_FAULT_PLAN kills rank 1 at a commit
     point mid-training.  The hardened stack must recover — the
     survivor's peer-stall detection converts the dead peer into the
@@ -818,7 +824,7 @@ def test_injected_crash_recovers_with_step_continuity(tmp_path):
     with open(discover, "w") as f:
         f.write("#!/bin/sh\necho localhost:2\n")
     os.chmod(discover, 0o755)
-    proc = subprocess.Popen(
+    proc = spawn(
         [sys.executable, "-m", "horovod_tpu.runner.launch",
          "--min-np", "2", "--max-np", "2",
          "--host-discovery-script", discover,
@@ -828,7 +834,7 @@ def test_injected_crash_recovers_with_step_continuity(tmp_path):
         env=env, cwd=REPO, stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT)
     try:
-        out, _ = proc.communicate(timeout=300)
+        out, _ = proc.communicate(timeout=200)
     except subprocess.TimeoutExpired:
         proc.kill()
         out, _ = proc.communicate()

@@ -3,6 +3,8 @@ pure-Python unit tests of launcher/elastic logic with fake discovery, plus
 a real-subprocess programmatic-run integration test.
 """
 
+import os
+import subprocess
 import sys
 import threading
 import time
@@ -18,6 +20,8 @@ from horovod_tpu.runner.elastic.driver import ElasticDriver
 from horovod_tpu.runner.elastic.registration import (WorkerStateRegistry,
                                                      READY)
 from horovod_tpu.runner.hosts import HostInfo
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class TestHosts:
@@ -156,6 +160,7 @@ class _FakeCluster:
         self.fail_ranks = set()
         self.exited = {}
         self.running = threading.Semaphore(0)
+        self.stopped = threading.Event()
 
     def discover(self):
         return [HostInfo(h, s) for h, s in sorted(self.hosts.items())]
@@ -164,7 +169,7 @@ class _FakeCluster:
         self.running.release()
         # Workers run until told to exit (simulate a training process).
         deadline = time.monotonic() + 10
-        while time.monotonic() < deadline:
+        while time.monotonic() < deadline and not self.stopped.is_set():
             if (slot.rank, gen) in self.exited:
                 return self.exited[(slot.rank, gen)]
             if slot.rank in self.fail_ranks and \
@@ -172,6 +177,10 @@ class _FakeCluster:
                 return 1
             time.sleep(0.02)
         return 0
+
+    def stop(self):
+        """End the workers still running, so none outlives its test."""
+        self.stopped.set()
 
 
 class TestElasticDriver:
@@ -212,6 +221,7 @@ class TestElasticDriver:
             assert hm.is_blacklisted("b")
         finally:
             driver.stop()
+            cluster.stop()
 
     def test_all_success_finishes_zero(self):
         cluster = _FakeCluster([("a", 2)])
@@ -225,6 +235,7 @@ class TestElasticDriver:
             assert driver.wait(timeout=5.0) == 0
         finally:
             driver.stop()
+            cluster.stop()
 
     def test_total_failure_finishes_nonzero(self):
         cluster = _FakeCluster([("a", 2)])
@@ -238,6 +249,7 @@ class TestElasticDriver:
             assert driver.wait(timeout=5.0) == 1
         finally:
             driver.stop()
+            cluster.stop()
 
 
 class TestRegistry:
@@ -373,76 +385,69 @@ class TestConfigParser:
         assert hvd.run is runner.run
 
 
+def _hvdtrun(spawn, tmp_path, argv):
+    """The real CLI as a subprocess, ``hvdtrun <argv>``, with its
+    workers; returns (returncode, stdout, stderr).  ``spawn`` ends the
+    launcher and its workers with the test, the timeout well before the
+    test's own limit."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    proc = spawn([sys.executable, "-m", "horovod_tpu.runner.launch"] + argv,
+                 env=env, cwd=str(tmp_path), stdout=subprocess.PIPE,
+                 stderr=subprocess.PIPE, text=True)
+    out, err = proc.communicate(timeout=200)
+    return proc.returncode, out, err
+
+
 @pytest.mark.integration
-def test_static_cli_end_to_end(tmp_path):
+def test_static_cli_end_to_end(tmp_path, spawn):
     """The real CLI as a subprocess: `hvdtrun -np 2 -- python main.py`
     (ref: test/integration/test_static_run.py)."""
-    import os
-    import subprocess
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.update({
-        "JAX_PLATFORMS": "cpu",
-        "PYTHONPATH": repo + os.pathsep + env.get("PYTHONPATH", ""),
-    })
-    out = subprocess.run(
-        [sys.executable, "-m", "horovod_tpu.runner.launch",
-         "-np", "2", "--coordinator-port", "29763",
-         "--fusion-threshold-mb", "8",
-         "--", sys.executable,
-         os.path.join(repo, "tests", "data", "static_main.py")],
-        env=env, cwd=str(tmp_path), capture_output=True, text=True,
-        timeout=180)
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    text = out.stdout
+    rc, text, err = _hvdtrun(spawn, tmp_path, [
+        "-np", "2", "--coordinator-port", "29763",
+        "--fusion-threshold-mb", "8",
+        "--", sys.executable,
+        os.path.join(REPO, "tests", "data", "static_main.py")])
+    assert rc == 0, text[-2000:] + err[-2000:]
     assert "STATIC_MAIN rank=0 size=2 red=1.50" in text
     assert "STATIC_MAIN rank=1 size=2 red=1.50" in text
 
 
+# The two ports of the reference's MNIST examples are ``slow``: 13 and
+# 29 s alone, nearly all of it two workers importing torch or TensorFlow
+# beside jax.  The compose test-integration service runs them.  Tier-1
+# keeps the CLI end to end (test_static_cli_end_to_end), the torch
+# DistributedOptimizer over two real processes
+# (tests/test_torch_optimizer.py test_two_process_equivalence) and the
+# Keras one with its scalar-variable broadcast
+# (tests/test_interop_tf_keras.py, tests/test_interop_tf.py
+# test_two_process_tf_tape).
+@pytest.mark.slow
 @pytest.mark.integration
-def test_ported_torch_mnist_under_cli(tmp_path):
+def test_ported_torch_mnist_under_cli(tmp_path, spawn):
     """The porting-guide proof artifact keeps working: the reference's
     pytorch_mnist port runs under the real CLI with 2 workers."""
-    import os
-    import subprocess
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.update({"JAX_PLATFORMS": "cpu",
-                "PYTHONPATH": repo + os.pathsep + env.get("PYTHONPATH", "")})
-    out = subprocess.run(
-        [sys.executable, "-m", "horovod_tpu.runner.launch",
-         "-np", "2", "--coordinator-port", "29764",
-         "--", sys.executable,
-         os.path.join(repo, "examples", "torch_mnist_ported.py"),
-         "--epochs", "1", "--train-size", "512", "--test-batch-size",
-         "256", "--log-interval", "100"],
-        env=env, cwd=str(tmp_path), capture_output=True, text=True,
-        timeout=300)
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
-    assert "Test set: Average loss" in out.stdout
+    rc, out, err = _hvdtrun(spawn, tmp_path, [
+        "-np", "2", "--coordinator-port", "29764",
+        "--", sys.executable,
+        os.path.join(REPO, "examples", "torch_mnist_ported.py"),
+        "--epochs", "1", "--train-size", "512", "--test-batch-size",
+        "256", "--log-interval", "100"])
+    assert rc == 0, out[-2000:] + err[-2000:]
+    assert "Test set: Average loss" in out
 
 
+@pytest.mark.slow
 @pytest.mark.integration
-def test_ported_tf_keras_mnist_under_cli(tmp_path):
+def test_ported_tf_keras_mnist_under_cli(tmp_path, spawn):
     """The TF/Keras porting proof runs under the real CLI with 2 workers:
     DistributedOptimizer in model.fit, BroadcastGlobalVariables (incl.
     the optimizer's SCALAR iteration counter — regression for the 0-d
     host-broadcast shard bug), MetricAverage, LR warmup."""
-    import os
-    import subprocess
-
     pytest.importorskip("tensorflow")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.update({"PYTHONPATH": repo + os.pathsep + env.get("PYTHONPATH", "")})
-    out = subprocess.run(
-        [sys.executable, "-m", "horovod_tpu.runner.launch",
-         "-np", "2", "--coordinator-port", "29768",
-         "--", sys.executable,
-         os.path.join(repo, "examples", "tf_keras_mnist_ported.py"),
-         "--epochs", "1", "--steps-per-epoch", "4", "--samples", "256"],
-        env=env, cwd=str(tmp_path), capture_output=True, text=True,
-        timeout=420)
-    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    rc, out, err = _hvdtrun(spawn, tmp_path, [
+        "-np", "2", "--coordinator-port", "29768",
+        "--", sys.executable,
+        os.path.join(REPO, "examples", "tf_keras_mnist_ported.py"),
+        "--epochs", "1", "--steps-per-epoch", "4", "--samples", "256"])
+    assert rc == 0, out[-2000:] + err[-2000:]

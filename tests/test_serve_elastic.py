@@ -201,13 +201,16 @@ class TestBatcherRobustness:
 
         b = DynamicBatcher(lethal_infer, max_batch_size=4,
                            max_delay_ms=0.0, max_queue_depth=64)
-        f = b.submit(np.zeros((1, 4), np.float32))
-        with pytest.raises(DispatcherDied):
-            f.result(timeout=5.0)
-        _wait_until(lambda: not b._thread.is_alive(),
-                    "dispatch thread survived SystemExit")
-        with pytest.raises(DispatcherDied):
-            b.submit(np.zeros((1, 4), np.float32))
+        try:
+            f = b.submit(np.zeros((1, 4), np.float32))
+            with pytest.raises(DispatcherDied):
+                f.result(timeout=5.0)
+            _wait_until(lambda: not b._thread.is_alive(),
+                        "dispatch thread survived SystemExit")
+            with pytest.raises(DispatcherDied):
+                b.submit(np.zeros((1, 4), np.float32))
+        finally:
+            b.close()       # the deadline watchdog is still running
 
     def test_fail_pending_abandonment_is_typed(self):
         release = threading.Event()
@@ -497,6 +500,7 @@ class TestRouter:
                         timeout=5.0)
         finally:
             router.stop()
+            reps[0].server.stop()    # the crash left its batcher running
             for rep in reps[1:]:
                 rep.stop()
 
@@ -933,7 +937,7 @@ class TestCliWiring:
 # (ci/gen-matrix.sh --smoke), which does not filter the slow marker.
 @pytest.mark.slow
 @pytest.mark.integration
-def test_serve_elastic_resize_and_crash_zero_dropped(tmp_path):
+def test_serve_elastic_resize_and_crash_zero_dropped(tmp_path, spawn):
     """The acceptance scenario: a real `hvdtrun serve --replicas`
     control plane (RendezvousServer + ServeDriver + Router, replica
     subprocesses) scales 1 -> 3 -> 2 under synthetic client load while
@@ -954,7 +958,7 @@ def test_serve_elastic_resize_and_crash_zero_dropped(tmp_path):
         "HVDT_ELASTIC_BLACKLIST_COOLDOWN_S": "2",
         "HVDT_FAULT_PLAN": "serve_crash@step=25:rank=1",
     })
-    proc = subprocess.Popen(
+    proc = spawn(
         [sys.executable, "-m", "horovod_tpu.runner.launch", "serve",
          "--checkpoint", ckpt_dir, "--model", "mlp",
          "--mlp-sizes", ",".join(map(str, SIZES)),

@@ -312,12 +312,18 @@ class TestStore:
         assert st.exists(p + "/ckpt.bin")
         assert st.read_bytes(p + "/ckpt.bin") == b"abc"
 
-    def test_remote_prefix_resolves_filesystem_store(self):
+    def test_remote_prefix_resolves_filesystem_store(self, monkeypatch):
+        import fsspec.config
+
         from horovod_tpu.orchestrate.store import FilesystemStore, Store
 
         # fsspec+gcsfs are importable in this image, so the remote
         # prefix resolves to a FilesystemStore (IO would need real
         # credentials; only construction + path discipline here).
+        # Anonymous: left to find credentials, gcsfs asks a metadata
+        # server that is not there and backs off for 14 s before it
+        # gives up.
+        monkeypatch.setitem(fsspec.config.conf, "gcs", {"token": "anon"})
         st = Store.create("gs://bucket/prefix")
         assert isinstance(st, FilesystemStore)
         assert st.get_checkpoint_path("r").startswith("gs://bucket/prefix")

@@ -18,12 +18,7 @@ from horovod_tpu import telemetry as tele
 from horovod_tpu.telemetry import instrument as tinst
 from horovod_tpu.telemetry import metrics as tmetrics
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax layouts
-    from jax.experimental import shard_map as _sm
-
-    shard_map = _sm.shard_map
+from conftest import jit_shard_map as shard_map
 
 from jax.sharding import PartitionSpec as P
 

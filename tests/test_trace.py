@@ -24,12 +24,7 @@ from horovod_tpu.telemetry import instrument as tinst
 from horovod_tpu.telemetry import metrics as tmetrics
 from horovod_tpu.telemetry import trace as ttrace
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax layouts
-    from jax.experimental import shard_map as _sm
-
-    shard_map = _sm.shard_map
+from conftest import jit_shard_map as shard_map
 
 from jax.sharding import PartitionSpec as P
 
@@ -505,7 +500,7 @@ class TestLauncherFlags:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.integration
-def test_multiprocess_hang_emits_desync_report(tmp_path):
+def test_multiprocess_hang_emits_desync_report(tmp_path, spawn):
     """Two ranks in a lockstep loop; a hang@step fault wedges rank 1
     before it records step 6's collective.  Rank 0's escalation abort
     rung must gather both rings over the rendezvous KV and emit a desync
@@ -533,7 +528,7 @@ def test_multiprocess_hang_emits_desync_report(tmp_path):
                 "DESYNC_TEST_ABORT_S": "1.0",
             })
             env.pop("HVDT_FAULT_JOURNAL", None)
-            procs.append(subprocess.Popen(
+            procs.append(spawn(
                 [sys.executable,
                  os.path.join(REPO, "tests", "data", "desync_main.py")],
                 env=env, cwd=REPO, stdout=subprocess.PIPE,

@@ -12,10 +12,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 
 def test_distributed_optimizer_converges(hvd, mesh8):

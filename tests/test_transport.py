@@ -16,10 +16,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from conftest import jit_shard_map as shard_map
 
 from horovod_tpu import optimizer as hvd_opt
 from horovod_tpu import transport
@@ -34,7 +31,7 @@ from horovod_tpu.transport import policy as tp
 def _smap_kw():
     """check_rep/check_vma off where the kwarg exists (same pattern as
     tests/test_overlap.py)."""
-    sig = inspect.signature(shard_map).parameters
+    sig = inspect.signature(jax.shard_map).parameters
     if "check_rep" in sig:
         return {"check_rep": False}
     if "check_vma" in sig:
@@ -808,7 +805,7 @@ class TestBenchHierarchicalSweep:
              "--max-bytes", "4096", "--iters", "1", "--warmup", "0",
              "--inner", "1", "--json-out", str(out)],
             cwd=repo, env=env, capture_output=True, text=True,
-            timeout=420)
+            timeout=200)
         assert proc.returncode == 0, proc.stderr[-2000:]
         doc = json.loads(out.read_text())
         assert doc["schema_version"] >= 1

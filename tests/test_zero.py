@@ -51,7 +51,9 @@ _SMAP_KW = ({"check_rep": False} if "check_rep" in _SMAP_SIG
 
 def shard_map(*args, **kw):
     kw.update(_SMAP_KW)
-    return _shard_map(*args, **kw)
+    # jitted: an eager shard_map compiles every primitive of its body
+    # as a multi-device program of its own
+    return jax.jit(_shard_map(*args, **kw))
 
 
 @pytest.fixture(autouse=True)
@@ -1242,7 +1244,7 @@ class TestBenchReduceScatterSweep:
              "--max-bytes", "4096", "--iters", "1", "--warmup", "0",
              "--inner", "1", "--json-out", str(out)],
             cwd=repo, env=env, capture_output=True, text=True,
-            timeout=420)
+            timeout=200)
         assert proc.returncode == 0, proc.stderr[-2000:]
         doc = json.loads(out.read_text())
         assert doc["metric"] == "reduce_scatter_sweep"

@@ -512,16 +512,64 @@ def _attention_grads(fn, q, k, v, do):
         argnums=(0, 1, 2)))(q, k, v)
 
 
-def kernel_flash_backward(*, batch=1, seq=2048, heads=16, head_dim=64):
-    """flash_grad_block's dq and dk/dv pallas_calls (HVDT_FLASH_BWD=kernel
-    routes flash_attention's backward through them)."""
-    from horovod_tpu.ops.pallas_kernels import (attention_reference,
-                                                flash_attention)
+def _reference_grads_by_head(q, k, v, do):
+    """dq, dk, dv of sum(attention_reference * do), one (sequence, head)
+    at a time: a head's f32 score square is 64 MiB at seq 4096 and its
+    gradient holds a handful of them."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.pallas_kernels import attention_reference
+
+    b, l, h, d = q.shape
+
+    def one(qkvdo):
+        q1, k1, v1, do1 = (x[None, :, None, :] for x in qkvdo)
+        grads = jax.grad(
+            lambda q, k, v: jnp.sum(
+                attention_reference(q, k, v).astype(jnp.float32)
+                * do1.astype(jnp.float32)), argnums=(0, 1, 2))(q1, k1, v1)
+        return tuple(g[0, :, 0, :] for g in grads)
+
+    by_head = jax.jit(lambda *xs: jax.lax.map(one, tuple(
+        x.transpose(0, 2, 1, 3).reshape(b * h, l, d) for x in xs)))
+    return tuple(g.reshape(b, h, l, d).transpose(0, 2, 1, 3)
+                 for g in by_head(q, k, v, do))
+
+
+def kernel_flash_backward(*, batch=8, seq=4096, heads=16, head_dim=64):
+    """The local backward (pallas_kernels._flash_local_bwd_call) as
+    flash_attention's gradient runs it, at the blocks _backward_blocks
+    chooses.  The default shape is the benchmark cell's
+    (lm24x1024_s4096_b8), like kernel_flash_forward's."""
+    from horovod_tpu.ops.pallas_kernels import flash_attention
 
     q, k, v, do = _qkv(batch, seq, heads, head_dim)
-    with env_knob("HVDT_FLASH_BWD", "kernel"):
-        got = _attention_grads(flash_attention, q, k, v, do)
-    _close("flash_grad_block", got,
+    _close("flash_attention backward",
+           _attention_grads(flash_attention, q, k, v, do),
+           _reference_grads_by_head(q, k, v, do), rtol=5e-2, atol=5e-2)
+
+
+def kernel_flash_grad_block(*, batch=1, seq=2048, heads=16, head_dim=64):
+    """flash_grad_block's dq and dk/dv pallas_calls: the ring step's
+    backward (parallel/ring_attention.py), here over one whole sequence
+    from the local forward's out and logsumexp."""
+    import jax
+
+    from horovod_tpu.ops.pallas_kernels import (_flash_fwd_core,
+                                                _forward_blocks,
+                                                attention_reference,
+                                                flash_grad_block)
+
+    q, k, v, do = _qkv(batch, seq, heads, head_dim)
+    blocks = _forward_blocks(seq, seq, head_dim, q.dtype)
+
+    @jax.jit
+    def got(q, k, v, do):
+        out, lse = _flash_fwd_core(q, k, v, True, head_dim ** -0.5, *blocks)
+        return flash_grad_block(q, k, v, do, out, lse, causal=True)
+
+    _close("flash_grad_block", got(q, k, v, do),
            _attention_grads(attention_reference, q, k, v, do),
            rtol=5e-2, atol=5e-2)
 
@@ -679,7 +727,8 @@ def kernel_quant_int4(*, size=1 << 24, block=256):
 
 
 KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
-           kernel_flash_backward, kernel_smallseq_forward,
+           kernel_flash_backward, kernel_flash_grad_block,
+           kernel_smallseq_forward,
            kernel_smallseq_backward, kernel_conv_bn_relu,
            kernel_conv_bn_train, kernel_fused_adam, kernel_fused_sgd,
            kernel_quant_int8, kernel_quant_int4)

@@ -613,11 +613,6 @@ KNOBS: Dict[str, Knob] = {
            "leg) — an unmeasured kernel is not a default.  Eligibility: "
            "1x1, stride 1, Cin % 128 == 0 AND Cout % 128 == 0 (SyncBN "
            "via psum'd stat partials when bn_axis is set)."),
-        _k("HVDT_FLASH_BWD", "xla", str,
-           "flash_attention backward: xla (blockwise XLA recompute) or "
-           "kernel (Pallas flash_grad_block passes). Read at TRACE time "
-           "inside the custom_vjp: a grad function jitted before the env "
-           "changed keeps its old backward until re-traced."),
         _k("HVDT_RING_PALLAS", False, _parse_bool,
            "Run ring attention's per-step block update and backward "
            "through the Pallas kernels (when shapes tile)."),

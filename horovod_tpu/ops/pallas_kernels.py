@@ -15,13 +15,18 @@ Two entry points, one tile body (``_online_softmax_update``):
   one self-contained call (``_flash_local_call``): q, k, v in, ``out`` in
   the activation dtype and the f32 logsumexp out; the running
   (acc, row_max, row_sum) lives in VMEM scratch from the first K/V block
-  to the last and never touches HBM.
+  to the last and never touches HBM.  Its backward is one call too
+  (``_flash_local_bwd_call``): q, k, v, dO and the two f32 row statistics
+  in, dq, dk, dv in the activation dtype out, the three accumulators in
+  VMEM scratch.
 * ``flash_block_update(...)`` — one ring-attention step: takes the
   running (acc, row_max, row_sum) online-softmax carry and a K/V block
   (with its global position offset), returns the updated carry
   (``_flash_call``: a ring step has to hand its state to the next one,
   so this call keeps the carry in HBM on both sides).
-  ``parallel/ring_attention.py`` composes it around ``lax.ppermute``.
+  ``parallel/ring_attention.py`` composes it around ``lax.ppermute``, and
+  ``flash_grad_block`` for its backward: global offsets in, f32 partial
+  sums out, for the same reason.
 
 Which of the two runs is decided by which function the caller calls,
 and by nothing a user sets.  Both run in Pallas interpret mode off-TPU,
@@ -308,19 +313,21 @@ def _column_to_row(col):
     return jnp.broadcast_to(col, (n, 128)).T[:1, :]
 
 
-# Scoped VMEM the local forward asks Mosaic for (half the 128 MiB of a
-# v5e or v6e core; Mosaic's default is 16), and what _forward_blocks lets
-# its own estimate reach.
+# Scoped VMEM the local forward and backward ask Mosaic for (half the 128
+# MiB of a v5e or v6e core; Mosaic's default is 16), and what
+# _forward_blocks and _backward_blocks let their own estimates reach.
 _FWD_VMEM_LIMIT = 64 * 1024 * 1024
 _FWD_VMEM_BUDGET = 56 * 1024 * 1024
 
 
-def _chunk_rows(block_q: int) -> int:
-    """Query rows per chunk of a tile.  512 measured best at every tile
-    size (PERF.md, PR 25): fewer rows pay the per-chunk work more often,
-    more rows trim less of the diagonal's masked triangle."""
-    rows = min(512, block_q)
-    while block_q % rows:
+def _chunk_rows(block: int, most: int = 512) -> int:
+    """Query rows per chunk of a forward tile (keys per chunk of a
+    backward tile, ``most`` 256).  512 measured best at every forward
+    tile size (PERF.md, PR 25) and 256 at the backward's (PR 27): fewer
+    pay the per-chunk work more often, more trim less of the diagonal's
+    masked triangle."""
+    rows = min(most, block)
+    while block % rows:
         rows //= 2
     return rows
 
@@ -414,6 +421,208 @@ def _flash_local_call(q, k, v, *, causal, scale, block_q, block_k,
         )(q, k, v)
 
 
+def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
+                      dk_ref, dv_ref, dq_s, dk_s, dv_s, *, causal: bool,
+                      scale: float, fold_scale: bool, keys: int):
+    """The local (non-ring) backward, grid (b, h, ik, iq) with iq
+    innermost: the K/V block stays while q, dO and the two row statistics
+    stream past it.  dk/dv [bk, d] and the whole sequence's dq [Lq, d]
+    accumulate in f32 VMEM scratch and leave once, in the activation
+    dtype: dk/dv after the block's last q tile, dq after the last tile of
+    the (b, h) pair.
+
+    The score tile is worked TRANSPOSED, s^T = k q^T [keys, rows]: the
+    logsumexp and delta = rowsum(dO * out) then enter as lane-dense rows
+    [1, rows] that broadcast along sublanes (no [rows, 1] column is ever
+    built), dv = p^T dO and dk = ds^T q are plain products, and only dq =
+    ds k contracts over the tile's first dimension.  One score product,
+    one exp, five products in all per (rows, keys) pair.
+
+    Causal work is trimmed as in the forward: only tiles the diagonal
+    crosses build a mask, tiles before it (q rows that see none of the
+    block's keys) do nothing, and on a square tile's diagonal a chunk of
+    ``keys`` keys starts at its own first row."""
+    import jax.experimental.pallas as pl
+
+    ik = pl.program_id(2)
+    iq = pl.program_id(3)
+    nk = pl.num_programs(2)
+    nq = pl.num_programs(3)
+    bq = q_ref.shape[2]
+    bk = k_ref.shape[2]
+    nt = (((1,), (1,)), ((), ()))                 # a b^T
+    nn = (((1,), (0,)), ((), ()))                 # a b
+    tn = (((0,), (0,)), ((), ()))                 # a^T b
+    f32 = jnp.float32
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+        @pl.when(ik == 0)
+        def _():
+            dq_s[...] = jnp.zeros_like(dq_s)
+
+    def _tile(straddles: bool):
+        q_all = q_ref[0, 0, :, :]
+        if fold_scale:
+            q_all = (q_all * scale).astype(q_all.dtype)
+        row0 = pl.multiple_of(iq * bq, bq)
+        for c in range(0, bk, keys):
+            # bq == bk puts a straddling tile on the diagonal (iq == ik):
+            # keys c.. are seen by no row before c.
+            r = c if straddles and bq == bk else 0
+            chunk = pl.ds(c, keys)
+            k = k_ref[0, 0, chunk, :]
+            q = q_all[r:]
+            do = do_ref[0, 0, r:, :]
+            st = jax.lax.dot_general(k, q, nt, preferred_element_type=f32)
+            if not fold_scale:
+                st = st * scale
+            if straddles:
+                # True = visible: row position >= key position.
+                mask = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                        - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+                        >= ik * bk + c - iq * bq - r)
+                st = jnp.where(mask, st, _NEG_INF)
+            # The saved logsumexp is finite, so a masked score's exp is an
+            # exact 0 and p needs no second select.
+            pt = jnp.exp(st - lse_ref[0, 0, :, r:])          # [keys, rows]
+            dpt = jax.lax.dot_general(v_ref[0, 0, chunk, :], do, nt,
+                                      preferred_element_type=f32)
+            dst = (pt * (dpt - dl_ref[0, 0, :, r:])).astype(q.dtype)
+            dv_s[chunk, :] += jax.lax.dot_general(
+                pt.astype(do.dtype), do, nn, preferred_element_type=f32)
+            dk_s[chunk, :] += jax.lax.dot_general(
+                dst, q, nn, preferred_element_type=f32)
+            dq_s[pl.ds(row0 + r, bq - r), :] += jax.lax.dot_general(
+                dst, k, tn, preferred_element_type=f32)
+
+    if not causal:
+        # ik >= 0 always: the cond is there for interpret mode under
+        # shard_map (_smallseq_fwd_kernel).
+        pl.when(ik >= 0)(lambda: _tile(False))
+    else:
+        visited = (iq + 1) * bq - 1 >= ik * bk            # else: all masked
+        visible = iq * bq >= (ik + 1) * bk - 1            # nothing masked
+        pl.when(visible)(lambda: _tile(False))
+        pl.when(jnp.logical_and(visited, jnp.logical_not(visible)))(
+            lambda: _tile(True))
+
+    @pl.when(iq == nq - 1)
+    def _flush():
+        # s was computed from q * scale where that folds, so dk = ds^T
+        # (q * scale) carries the factor already; dq = scale * ds k never.
+        dk = dk_s[...] if fold_scale else dk_s[...] * scale
+        dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_s[...].astype(dv_ref.dtype)
+
+        @pl.when(ik == nk - 1)
+        def _():
+            dq_ref[0, 0, :, :] = (dq_s[...] * scale).astype(dq_ref.dtype)
+
+
+# The backward's blocks stop here: at b8 h16 L4096 d64 bf16 on the v5e,
+# ms a call by trace events (PERF.md, PR 27), 512 x 512 10.27, 1024 x 1024
+# 8.51, 2048 x 2048 8.12, 4096 x 4096 9.16, though all four fit in VMEM.
+_BWD_BLOCK_MOST = 2048
+
+
+def _backward_blocks(lq: int, lk: int, head_dim: int, dtype
+                     ) -> Optional[Tuple[int, int]]:
+    """(block_q, block_k) for the local backward, from the shape and VMEM
+    alone; None where the kernel cannot take the shape: the whole
+    sequence's f32 dq, which it keeps in VMEM, does not fit beside the
+    smallest blocks (from seq 131,072 at head_dim 64 in bf16).
+
+    Square, and as large as fits up to ``_BWD_BLOCK_MOST``: a square tile
+    is the one whose diagonal chunks can start at their own first row, and
+    unlike the forward's the tile has no per-chunk column work for a larger
+    block to amortise (the statistics are rows), so past 2048 nothing is
+    saved.
+
+    The estimate is fitted to what Mosaic's own account needed at eleven
+    (seq, head_dim, dtype, block) points, found by halving the limit until
+    the compile for the v5e failed (MiB, seq 4096: d64 bf16 512 / 1024 /
+    2048 / 4096 blocks 4 / 6 / 11 / 17, d128 bf16 1024 / 2048 / 4096 11 /
+    18 / 23, d64 f32 1024 / 2048 7 / 11; d64 bf16 seq 8192 at 2048 13, seq
+    16384 at 1024 12): the dq scratch and its output block twice, and per
+    row of the block 48 x head_dim bytes for the pipelined q/k/v/dO/dk/dv
+    blocks, the dk/dv scratch and the three products, plus two f32 score
+    chunks; 0-2 MiB above each point up to 2048."""
+    itemsize = jnp.dtype(dtype).itemsize
+    whole = lq * head_dim * (4 + 2 * itemsize)
+
+    def need(block):
+        return whole + block * (48 * head_dim
+                                + 8 * _chunk_rows(block, 256))
+
+    block = min(max(lq, lk), _BWD_BLOCK_MOST)
+    while need(block) > _FWD_VMEM_BUDGET and block > 128:
+        block //= 2
+    if need(block) > _FWD_VMEM_BUDGET:
+        return None
+    return _fit_block(lq, block, dtype), _fit_block(lk, block, dtype)
+
+
+def _flash_local_bwd_call(q, k, v, do, lse, delta, *, causal, scale,
+                          block_q, block_k, keys=None):
+    """The self-contained backward: q, dO [B,H,Lq,D], k, v [B,Hkv,Lk,D],
+    lse and delta f32 rows [B,H,1,Lq] -> (dq [B,H,Lq,D], dk, dv
+    [B,H,Lk,D]) in the operands' dtypes, dk/dv per q head (a GQA caller
+    sums its group).  One pallas_call: nothing f32 of the sequence's size
+    and nothing with a trailing dimension of 1 goes in or comes out."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, lq, d = q.shape
+    _, hkv, lk, _ = k.shape
+    group = h // hkv
+    if lq % block_q or lk % block_k:
+        raise ValueError(
+            f"seq lens (q={lq}, k={lk}) must divide block sizes "
+            f"({block_q}, {block_k})")
+
+    def q_tile(kk, qq):
+        if causal:
+            # q tiles before the diagonal are skipped: name the first one
+            # that is not, so nothing is fetched for them.
+            qq = jnp.maximum(qq, jnp.minimum((kk * block_k) // block_q,
+                                             lq // block_q - 1))
+        return qq
+
+    qspec = pl.BlockSpec((1, 1, block_q, d),
+                         lambda bb, hh, kk, qq: (bb, hh, q_tile(kk, qq), 0))
+    row = pl.BlockSpec((1, 1, 1, block_q),
+                       lambda bb, hh, kk, qq: (bb, hh, 0, q_tile(kk, qq)))
+    kvspec = pl.BlockSpec((1, 1, block_k, d),
+                          lambda bb, hh, kk, qq: (bb, hh // group, kk, 0))
+    dqspec = pl.BlockSpec((1, 1, lq, d), lambda bb, hh, kk, qq: (bb, hh, 0, 0))
+    dkvspec = pl.BlockSpec((1, 1, block_k, d),
+                           lambda bb, hh, kk, qq: (bb, hh, kk, 0))
+    kw = _vma_kw(q, k, v, do, lse, delta)
+    with jax.named_scope("hvdt.kernel.flash_bwd"):
+        return pl.pallas_call(
+            functools.partial(
+                _local_bwd_kernel, causal=causal, scale=scale,
+                fold_scale=_scale_folds_exactly(scale, q.dtype),
+                keys=keys or _chunk_rows(block_k, 256)),
+            grid=(b, h, lk // block_k, lq // block_q),
+            in_specs=[qspec, kvspec, kvspec, qspec, row, row],
+            out_specs=[dqspec, dkvspec, dkvspec],
+            out_shape=(jax.ShapeDtypeStruct((b, h, lq, d), q.dtype, **kw),
+                       jax.ShapeDtypeStruct((b, h, lk, d), k.dtype, **kw),
+                       jax.ShapeDtypeStruct((b, h, lk, d), v.dtype, **kw)),
+            scratch_shapes=[pltpu.VMEM((lq, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_FWD_VMEM_LIMIT),
+            interpret=_use_interpret(),
+        )(q, k, v, do, lse, delta)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
@@ -421,13 +630,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Fused flash attention; layouts/API match
     parallel.ring_attention (q,k,v: [B, L, H, D]; GQA via fewer kv heads).
 
-    Differentiable: the forward is one Pallas call, q, k, v -> (out,
-    logsumexp), with nothing around it but the layout moves (pallas_call
-    has no autodiff rule of its own); the backward is the standard flash
-    gradient recomputed BLOCKWISE over K in plain XLA — the saved
-    logsumexp makes the recomputation exact, and the [B,H,Lq,block_k]
-    working set keeps backward memory O(L·block) instead of O(L²)
-    (the property that makes long-context training fit in HBM at all).
+    Differentiable (pallas_call has no autodiff rule of its own): the
+    forward is one Pallas call, q, k, v -> (out, logsumexp), and the
+    backward another, the standard flash gradient with the score tile
+    recomputed from the saved logsumexp, which makes it exact; nothing
+    around either but the layout moves and delta = rowsum(dO * out).  No
+    [B,H,Lq,Lk] array exists on either side (the property that makes
+    long-context training fit in HBM at all).
 
     ``block_q`` / ``block_k`` default to :func:`_forward_blocks`' choice
     for the shape; a test passes its own to meet a given tiling.
@@ -443,47 +652,69 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                             block_k)
 
 
-def _flash_fwd_core(q, k, v, causal, scale, block_q, block_k):
-    """Kernel forward returning (out [B,L,H,D], lse [B,H,Lq])."""
+def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k):
+    """Kernel forward returning (out [B,L,H,D], lse [B,H,1,Lq]): the
+    logsumexp as the row the kernel writes and the backward reads."""
     out, lse = _flash_local_call(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), causal=causal, scale=scale,
         block_q=block_q, block_k=block_k)
-    return out.transpose(0, 2, 1, 3), lse[:, :, 0, :]
+    return out.transpose(0, 2, 1, 3), lse
+
+
+def _flash_fwd_core(q, k, v, causal, scale, block_q, block_k):
+    """Kernel forward returning (out [B,L,H,D], lse [B,H,Lq])."""
+    out, lse = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k)
+    return out, lse[:, :, 0, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_attn_diff(q, k, v, causal, scale, block_q, block_k):
-    out, _ = _flash_fwd_core(q, k, v, causal, scale, block_q, block_k)
+    out, _ = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k)
     return out
 
 
 def _flash_attn_fwd(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _flash_fwd_core(q, k, v, causal, scale, block_q, block_k)
+    out, lse = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k)
     return out, (q, k, v, out, lse)
 
 
 def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
+    """The local backward: one Pallas call (``_flash_local_bwd_call``)
+    between the layout moves, delta = rowsum(dO * out) computed beside it
+    as a row.  A test's own forward blocks bound the backward's too, so a
+    given tiling is met on both sides.  Only a sequence whose f32 dq does
+    not fit in VMEM (``_backward_blocks`` is None) takes the blockwise
+    XLA backward."""
     q, k, v, out, lse = res
-    from ..common import config
+    b, lq, h, d = q.shape
+    lk, hkv = k.shape[1], k.shape[2]
+    blocks = _backward_blocks(lq, lk, d, q.dtype)
+    if blocks is None:
+        return _flash_bwd_blockwise(causal, scale, block_q, block_k,
+                                    (q, k, v, out, lse[:, :, 0, :]), do)
+    delta = jnp.einsum("bqhd,bqhd->bhq", do, out,
+                       preferred_element_type=jnp.float32)[:, :, None, :]
+    dq, dk, dv = _flash_local_bwd_call(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), do.transpose(0, 2, 1, 3), lse, delta,
+        causal=causal, scale=scale,
+        block_q=_fit_block(lq, min(block_q, blocks[0]), q.dtype),
+        block_k=_fit_block(lk, min(block_k, blocks[1]), k.dtype, v.dtype))
+    if h != hkv:
+        # dk/dv leave per q head: sum each kv head's group, in f32.
+        dk, dv = (x.reshape(b, hkv, h // hkv, lk, d).astype(jnp.float32)
+                  .sum(2).astype(x.dtype) for x in (dk, dv))
+    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
+            dv.transpose(0, 2, 1, 3))
 
-    if config.get_str("HVDT_FLASH_BWD").lower() in ("kernel", "pallas"):
-        # Pallas backward passes (flash_grad_block) instead of the
-        # blockwise XLA recompute — A/B with HVDT_FLASH_BWD=kernel.
-        # NOTE: this env read happens at TRACE time (custom_vjp bwd is
-        # traced under jit); flipping the env after a grad function is
-        # compiled does not change its backward until re-trace.  The
-        # caller's forward block sizes are forwarded so the A/B against
-        # the XLA path above is like-for-like (both re-fit internally),
-        # up to the 512 x 1024 these kernels were measured with: their
-        # whole-tile bodies cannot hold the forward's whole-sequence
-        # blocks in VMEM.
-        dq, dk, dv = flash_grad_block(q, k, v, do, out, lse,
-                                      causal=causal, scale=scale,
-                                      block_q=min(block_q, 512),
-                                      block_k=min(block_k, 1024))
-        return (dq.astype(q.dtype), dk.astype(k.dtype),
-                dv.astype(v.dtype))
+
+def _flash_bwd_blockwise(causal, scale, block_q, block_k, res, do):
+    """The flash gradient recomputed BLOCKWISE over K in plain XLA, for
+    the sequence the kernel cannot hold (``_flash_attn_bwd``): two nested
+    scans over <= 512-wide tiles, a whole-sequence f32 dq as the carry.
+    ``res`` is (q, k, v, out [B,L,H,D], lse [B,H,Lq])."""
+    q, k, v, out, lse = res
     b, lq, h, d = q.shape
     lk, hkv = k.shape[1], k.shape[2]
     group = h // hkv

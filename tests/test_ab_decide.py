@@ -53,14 +53,15 @@ def test_smallseq_win_and_loss(tmp_path):
 
 
 def test_two_percent_margin_is_not_a_win(tmp_path):
+    base = {"tokens_per_sec": 10000}
     d = ab_decide.decide(ab_decide.latest_results(_hist(tmp_path, [_run(
-        "t", lm_seq4096_fbwd_kernel={"tokens_per_sec": 10100},
-        lm_seq4096_fbwd_xla={"tokens_per_sec": 10000})])))
-    assert d["flash_bwd"]["verdict"] == "KEEP_XLA"      # 1% < margin
+        "t", lm_base_bs128_remat=base,
+        lm_chunk16384_bs128={"tokens_per_sec": 10100})])))
+    assert d["xent_chunk"]["verdict"] != "DEFAULT_16384"    # 1% < margin
     d = ab_decide.decide(ab_decide.latest_results(_hist(tmp_path, [_run(
-        "t", lm_seq4096_fbwd_kernel={"tokens_per_sec": 10300},
-        lm_seq4096_fbwd_xla={"tokens_per_sec": 10000})])))
-    assert d["flash_bwd"]["verdict"] == "DEFAULT_KERNEL"
+        "t", lm_base_bs128_remat=base,
+        lm_chunk16384_bs128={"tokens_per_sec": 10300})])))
+    assert d["xent_chunk"]["verdict"] == "DEFAULT_16384"
 
 
 def test_ring_needs_both_shards_correctness_margin_and_tpu(tmp_path):

@@ -11,6 +11,7 @@ TOY_KERNELS = dict(
     kernel_flash_forward=dict(batch=1, seq=256, heads=2, head_dim=64),
     kernel_flash_ring_step=dict(batch=1, seq=128, heads=2, head_dim=64),
     kernel_flash_backward=dict(batch=1, seq=256, heads=2, head_dim=64),
+    kernel_flash_grad_block=dict(batch=1, seq=256, heads=2, head_dim=64),
     kernel_smallseq_forward=dict(batch=1, seq=128, heads=4, head_dim=64),
     kernel_smallseq_backward=dict(batch=1, seq=128, heads=4, head_dim=64),
     kernel_conv_bn_relu=dict(batch=2, hw=8, cin=128, cout=128),

@@ -1,5 +1,5 @@
-"""The flash kernels' gradients (custom_vjp, the kernel backward behind its
-knob, the ring's) and the mesh gate that routes attention to them;
+"""The flash kernels' gradients (custom_vjp, the kernel backward beside the
+blockwise one, the ring's) and the mesh gate that routes attention to them;
 interpret mode on the CPU.  Split from tests/test_pallas.py so that
 neither file is a worker's whole share of the run under --dist loadfile."""
 
@@ -44,10 +44,10 @@ class TestFlashGradients:
 
     @pytest.mark.parametrize("causal", [True, False])
     def test_multiblock_backward_matches_reference(self, causal):
-        """lq=512 with 128-blocks: nblk=ntq=4 — exercises the blockwise
-        scan, the causal-pruning cond, cross-block dq accumulation, and
-        dk/dv block reassembly (a single-block run covers none of
-        them)."""
+        """lq=512 with 128-blocks: a 4 x 4 grid of tiles — exercises the
+        backward kernel's skipped, straddling and fully visible tiles,
+        dq accumulated across K blocks and dk/dv across q tiles (a
+        single-block run covers none of them)."""
         from horovod_tpu.ops.pallas_kernels import (attention_reference,
                                                     flash_attention)
 
@@ -201,25 +201,26 @@ class TestFlashMeshGate:
         assert seen["manual"] == "direct"          # fully manual: direct
 
 
-class TestFlashBwdKernelKnob:
-    def test_kernel_backward_matches_xla_backward(self, monkeypatch):
-        """HVDT_FLASH_BWD=kernel swaps the blockwise-XLA backward for the
-        Pallas grad kernels; grads must agree with the default path."""
-        from horovod_tpu.ops.pallas_kernels import flash_attention
+class TestFlashBackwardPaths:
+    def test_kernel_backward_matches_xla_backward(self):
+        """The two backwards of flash_attention's custom_vjp, each called
+        directly on the forward's residuals: the Pallas call every shape
+        takes that fits in VMEM, and the blockwise XLA recompute kept for
+        the one that does not.  GQA, so both sum dk/dv over a group."""
+        from horovod_tpu.ops import pallas_kernels as pk
 
         rng = np.random.RandomState(11)
         q = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
         k = jnp.asarray(rng.randn(1, 256, 1, 16), jnp.float32)
         v = jnp.asarray(rng.randn(1, 256, 1, 16), jnp.float32)
-        w = jnp.asarray(rng.randn(16), jnp.float32)
-
-        def loss(q, k, v):
-            return ((flash_attention(q, k, v, causal=True) * w) ** 2).sum()
-
-        monkeypatch.setenv("HVDT_FLASH_BWD", "xla")
-        ref = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        monkeypatch.setenv("HVDT_FLASH_BWD", "kernel")
-        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        do = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
+        args = (True, 0.25, 128, 128)       # causal, scale, block_q, block_k
+        out, res = pk._flash_attn_fwd(q, k, v, *args)
+        got = jax.jit(lambda res, do: pk._flash_attn_bwd(*args, res, do))(
+            res, do)
+        lse_rows = res[4]
+        ref = jax.jit(lambda res, do: pk._flash_bwd_blockwise(
+            *args, res[:4] + (lse_rows[:, :, 0, :],), do))(res, do)
         for a, b in zip(got, ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-5, rtol=1e-4)

@@ -145,7 +145,10 @@ def _flash(kernel):
         return lambda: pk.flash_block_update(
             q, q, q, q, stat, stat, q_offset=0, k_offset=0, causal=True,
             scale=0.125, block_q=128, block_k=128)
-    if kernel in ("flash_dq", "flash_dkv"):
+    if kernel == "flash_bwd":                   # the local backward
+        return lambda: jax.grad(lambda q: pk.flash_attention(
+            q, q, q, block_q=128, block_k=128).sum())(q)
+    if kernel in ("flash_dq", "flash_dkv"):     # the ring step's two
         lse = jnp.zeros((1, 2, 128), jnp.float32)
         return lambda: pk.flash_grad_block(q, q, q, q, q, lse,
                                            block_q=128, block_k=128)
@@ -195,8 +198,8 @@ def _quant(kernel):
 
 
 KERNEL_SITES = (
-    [(_flash, k) for k in ("flash_fwd", "flash_fwd.ring", "flash_dq",
-                           "flash_dkv", "flash_smallseq_fwd",
+    [(_flash, k) for k in ("flash_fwd", "flash_fwd.ring", "flash_bwd",
+                           "flash_dq", "flash_dkv", "flash_smallseq_fwd",
                            "flash_smallseq_bwd")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
@@ -233,15 +236,20 @@ def test_no_pallas_call_site_is_left_without_a_name():
     assert sorted(named) == sorted(k.split(".")[0] for _, k in KERNEL_SITES)
 
 
-def test_lm_gradient_runs_the_flash_forward_as_two_bare_calls(monkeypatch):
+def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
     """A one-layer LM gradient at a shape that selects the flash kernel,
     lowered for the TPU (the Pallas -> Mosaic lowering is Python and needs
-    no chip): exactly two Mosaic calls per layer, the forward and its
-    recompute, each q, k, v -> (out in the activation dtype, lse as a
-    row).  No running (acc, m, l) goes in or comes out, so no lane-padded
-    ``f32[..., 1]`` is on the call.  The benchmark's ``flash_fwd_ms`` and
-    ``flash_fwd_roofline`` count every Mosaic call of the step: a PR that
-    splits this call or brings a carry back fails here."""
+    no chip): exactly three Mosaic calls per layer.  Two are the forward
+    and its recompute, each q, k, v -> (out in the activation dtype, lse
+    as a row).  One is the backward, q, k, v, dO and the two f32 row
+    statistics (lse, delta) -> dq, dk, dv in the activation dtype.  No
+    running (acc, m, l), no ``[..., 1]`` column and no f32 array of the
+    sequence's size goes in or comes out of any of them, and nothing in
+    the attention backward is a loop or a ``dynamic_update_slice``: the
+    blockwise XLA backward is not there.  The benchmark's ``flash_fwd_ms``
+    and ``flash_fwd_roofline`` count every Mosaic call of the step, the
+    backward's too (PERF.md section 3); ``flash_fwd_named_ms`` reads the
+    forward alone, by the name checked here."""
     from horovod_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
@@ -256,15 +264,28 @@ def test_lm_gradient_runs_the_flash_forward_as_two_bare_calls(monkeypatch):
         lambda p, t: models.transformer_loss(p, t, cfg))).trace(
             params, tokens).lower(lowering_platforms=("tpu",)).as_text(
                 debug_info=True)
-    calls = [line for line in text.splitlines()
-             if "@tpu_custom_call" in line]
-    assert len(calls) == 2 * cfg.layers
-    for line in calls:
-        types = line.rsplit(" : ", 1)[1]
-        assert re.fullmatch(
-            r"\((tensor<2x2x256x64xbf16>, ){2}tensor<2x2x256x64xbf16>\) -> "
-            r"\(tensor<2x2x256x64xbf16>, tensor<2x2x1x256xf32>\).*", types)
+
+    def location(line):
         loc = re.search(r"loc\((#loc\d+)\)$", line).group(1)
-        assert re.search(
-            rf'^{loc} = loc\(".*hvdt\.attention/hvdt\.kernel\.flash_fwd/',
-            text, re.M)
+        return re.search(rf"^{loc} = loc\((.*)$", text, re.M).group(1)
+
+    act, row = "tensor<2x2x256x64xbf16>", "tensor<2x2x1x256xf32>"
+    types = {
+        "flash_fwd": rf"\(({act}, ){{2}}{act}\) -> \({act}, {row}\).*",
+        "flash_bwd": rf"\(({act}, ){{4}}{row}, {row}\) -> "
+                     rf"\(({act}, ){{2}}{act}\).*"}
+    calls = {"flash_fwd": 0, "flash_bwd": 0}
+    for line in text.splitlines():
+        if "@tpu_custom_call" not in line:
+            continue
+        kernel = re.search(r"hvdt\.attention/hvdt\.kernel\.(\w+)/",
+                           location(line)).group(1)
+        calls[kernel] += 1
+        signature = line.rsplit(" : ", 1)[1]
+        assert re.fullmatch(types[kernel], signature), line
+        assert not re.search(r"x1x(f32|bf16)>|x256x64xf32>", signature)
+    assert calls == {"flash_fwd": 2 * cfg.layers, "flash_bwd": cfg.layers}
+    # Every operation's name stack is a location of the text: none under
+    # the attention scope is a loop, inside one, or a dynamic_update_slice.
+    assert not re.search(
+        r'loc\("[^"]*hvdt\.attention/[^"]*(while|dynamic_update_slice)', text)

@@ -6,8 +6,6 @@ explicit verdicts so flipping defaults is mechanical and auditable:
 
   * smallseq   — best lm_smallseq_hb*_bs128 vs lm_base_bs128_remat;
                  win => engage `_smallseq_enabled` auto + default HB.
-  * flash_bwd  — lm_seq4096_fbwd_kernel vs _xla; win => default
-                 HVDT_FLASH_BWD=kernel for 2048 <= seq < 8192.
   * xent_chunk — lm_chunk16384_bs128 vs base; win => default 16384.
   * ring       — ring_ab fwd/bwd Pallas speedups at both local shards;
                  both >1 => default HVDT_RING_PALLAS=1.
@@ -76,21 +74,6 @@ def decide(latest):
                        "record the measured loss in docs/performance.md")}
     else:
         out["smallseq"] = {"verdict": "unmeasured"}
-
-    kern = toks(latest, "lm_seq4096_fbwd_kernel")
-    xla = toks(latest, "lm_seq4096_fbwd_xla")
-    if kern and xla:
-        out["flash_bwd"] = {
-            "kernel_tok_s": kern, "xla_tok_s": xla,
-            "speedup": round(kern / xla, 4),
-            "verdict": ("DEFAULT_KERNEL" if kern >= xla * WIN_MARGIN
-                        else "KEEP_XLA"),
-            "action": ("default HVDT_FLASH_BWD=kernel for "
-                       "2048<=seq<8192 (common/config.py)"
-                       if kern >= xla * WIN_MARGIN else
-                       "keep HVDT_FLASH_BWD=xla; note e2e result")}
-    else:
-        out["flash_bwd"] = {"verdict": "unmeasured"}
 
     chunk = toks(latest, "lm_chunk16384_bs128")
     if chunk and base:

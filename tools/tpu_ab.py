@@ -129,35 +129,16 @@ LEGS = [
     lm_leg("lm_bs64_long", ["--batch", "64", "--steps", "120"],
            timeout=1200),
     # Full-Pallas attention at the flagship shape: round-2 measured XLA
-    # attention ~1.5x faster than kernel-fwd + BLOCKWISE-XLA bwd at
-    # seq 512 — but the round-3 flash_grad_block kernel bwd was never in
-    # that comparison.  If kernel+kernel beats XLA end-to-end here, the
-    # auto gate's 4 GB threshold is wrong and the defaults flip.
-    lm_leg("lm_flash_kernelbwd_bs128", ["--batch", "128"],
-           env={"HVDT_FLASH_ATTENTION": "on", "HVDT_FLASH_BWD": "kernel"}),
-    lm_leg("lm_flash_xlabwd_bs128", ["--batch", "128"],
+    # attention ~1.5x faster than the kernel forward with the blockwise-XLA
+    # backward at seq 512.  Since PR 27 the backward is a Pallas call too
+    # (PERF.md section 6), so this leg is kernel + kernel: if it beats XLA
+    # end to end here, the auto gate's 4 GB threshold is wrong (S1).
+    lm_leg("lm_flash_bs128", ["--batch", "128"],
            env={"HVDT_FLASH_ATTENTION": "on"}),
-    # Flash backward kernel vs XLA blockwise (the knob-flip evidence).
-    json_leg("bwd_ab_seq2048",
-             [PY, os.path.join(REPO, "tools", "bwd_ab.py"),
-              "--seq", "2048", "--batch", "16"], timeout=1500),
-    json_leg("bwd_ab_seq4096",
-             [PY, os.path.join(REPO, "tools", "bwd_ab.py"),
-              "--seq", "4096", "--batch", "8"], timeout=1500),
-    json_leg("bwd_ab_seq8192",
-             [PY, os.path.join(REPO, "tools", "bwd_ab.py"),
-              "--seq", "8192", "--batch", "4"], timeout=1500),
     # Chunked-xent scan granularity: 2 chunks of 16384 vs 4 of 8192 —
     # fewer sequential scan steps vs a 4.3 GB live logits tile.
     lm_leg("lm_chunk16384_bs128", ["--batch", "128",
                                    "--loss-chunk", "16384"]),
-    # e2e confirmation of the bwd_ab seq-4096 kernel win (1.14x
-    # backward-only): long-context config, flash fwd auto-engaged
-    # (score bytes >= 4 GB), backward knob A/B.
-    lm_leg("lm_seq4096_fbwd_kernel", ["--batch", "16", "--seq", "4096"],
-           env={"HVDT_FLASH_BWD": "kernel"}, timeout=1200),
-    lm_leg("lm_seq4096_fbwd_xla", ["--batch", "16", "--seq", "4096"],
-           timeout=1200),
     # Ring attention per-step block primitives, Pallas vs jnp (the
     # HVDT_RING_PALLAS evidence — sp>=2 can't run on one chip, but the
     # ring cost is sp repetitions of exactly these two per-device ops).

@@ -248,23 +248,22 @@ def phase_resnet(mesh, *, per_chip_batch: int, image_size: int = 224,
 def phase_long_seq(mesh, *, seq: int, per_chip_batch: int, steps: int = 2,
                    model=BERT_LARGE, compare_sequences: int = 2,
                    tol: float = 2e-2):
-    """The LM at a length where the flash kernel is what ``_flash_fn``
-    returns for the per-chip shapes, then the forward loss of the trained
-    weights on the batch's first ``compare_sequences`` sequences: Pallas
-    kernel against XLA attention (forward only — XLA attention with its
-    backward does not fit at 4096)."""
+    """The LM at a length where ``ops.attention.kernel_enabled`` selects
+    the flash kernel for the per-chip shapes, then the forward loss of the
+    trained weights on the batch's first ``compare_sequences`` sequences:
+    Pallas kernel against XLA attention (forward only — XLA attention with
+    its backward does not fit at 4096)."""
     import jax
     import numpy as np
 
-    from horovod_tpu.models import transformer as tr
     from horovod_tpu.models import transformer_loss
+    from horovod_tpu.ops.attention import kernel_enabled
     from horovod_tpu.ops.pallas_kernels import _use_interpret
 
     n = mesh.devices.size
     cfg = lm_config(seq, **model)
     name = f"lm seq{seq} b{per_chip_batch}x{n}"
-    if tr._flash_fn(seq, cfg.head_dim, batch=per_chip_batch,
-                    heads=cfg.heads) is None:
+    if not kernel_enabled(seq, batch=per_chip_batch, heads=cfg.heads):
         raise AssertionError(
             f"{name}: the flash kernel was not selected "
             f"(HVDT_FLASH_ATTENTION="
@@ -574,31 +573,6 @@ def kernel_flash_grad_block(*, batch=1, seq=2048, heads=16, head_dim=64):
            rtol=5e-2, atol=5e-2)
 
 
-def kernel_smallseq_forward(*, batch=2, seq=512, heads=16, head_dim=64):
-    """flash_attention_smallseq's forward pallas_call."""
-    import jax
-
-    from horovod_tpu.ops.pallas_kernels import (attention_reference,
-                                                flash_attention_smallseq)
-
-    q, k, v, _ = _qkv(batch, seq, heads, head_dim)
-    _close("flash_attention_smallseq",
-           jax.jit(flash_attention_smallseq)(q, k, v),
-           jax.jit(attention_reference)(q, k, v), rtol=2e-2, atol=2e-2)
-
-
-def kernel_smallseq_backward(*, batch=2, seq=512, heads=16, head_dim=64):
-    """flash_attention_smallseq's backward pallas_call."""
-    from horovod_tpu.ops.pallas_kernels import (attention_reference,
-                                                flash_attention_smallseq)
-
-    q, k, v, do = _qkv(batch, seq, heads, head_dim)
-    _close("flash_attention_smallseq backward",
-           _attention_grads(flash_attention_smallseq, q, k, v, do),
-           _attention_grads(attention_reference, q, k, v, do),
-           rtol=5e-2, atol=5e-2)
-
-
 def _conv_inputs(batch, hw, cin, cout):
     import jax
     import jax.numpy as jnp
@@ -728,10 +702,8 @@ def kernel_quant_int4(*, size=1 << 24, block=256):
 
 KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
            kernel_flash_backward, kernel_flash_grad_block,
-           kernel_smallseq_forward,
-           kernel_smallseq_backward, kernel_conv_bn_relu,
-           kernel_conv_bn_train, kernel_fused_adam, kernel_fused_sgd,
-           kernel_quant_int8, kernel_quant_int4)
+           kernel_conv_bn_relu, kernel_conv_bn_train, kernel_fused_adam,
+           kernel_fused_sgd, kernel_quant_int8, kernel_quant_int4)
 
 
 def phase_kernels(kernels=KERNELS, **sizes):

@@ -169,9 +169,9 @@ def main():
 
     # Open the manual island only over axes with degree > 1: a mesh with
     # pp=sp=ep=1 runs the plain loss under GSPMD-auto sharding, where
-    # the model's own flash shard_map island (over dp/tp) can engage —
+    # the flash kernel's shard_map island (over dp/tp) can engage —
     # nesting it inside a size-1 manual island would force the XLA
-    # attention fallback (models/transformer.py _flash_plan).
+    # attention fallback (ops/attention.py kernel_plan).
     manual_axes = {ax for ax, d in (("pp", args.pp), ("sp", args.sp),
                                     ("ep", args.ep)) if d > 1}
     island = (jax.shard_map(
@@ -194,8 +194,8 @@ def main():
 
     # Single chip defaults to the meshless path (no shard_map island,
     # measured ~5% faster back-to-back).  Flash engages under meshes too
-    # now — the model opens a partial-manual shard_map island over the
-    # GSPMD-auto axes (models/transformer.py _flash_plan) — so
+    # now — attention opens a partial-manual shard_map island over the
+    # GSPMD-auto axes (ops/attention.py kernel_plan) — so
     # HVDT_LM_SINGLE=0/false/off remains only as the A/B knob for
     # meshless-vs-island compilation (example-local, deliberately not in
     # the framework's config registry).
@@ -253,8 +253,8 @@ def main():
                      dtype=np.int64).astype(np.int32), tok_sharding)
 
     # Non-single auto-sharded runs execute under the ambient mesh so the
-    # model's flash shard_map island sees the auto axes
-    # (jax.sharding.get_abstract_mesh in _flash_plan).
+    # flash kernel's shard_map island sees the auto axes
+    # (jax.sharding.get_abstract_mesh in ops/attention.py kernel_plan).
     mesh_ctx = (jax.set_mesh(mesh) if not single and not explicit_dp
                 else contextlib.nullcontext())
     with mesh_ctx:

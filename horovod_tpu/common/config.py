@@ -592,18 +592,6 @@ KNOBS: Dict[str, Knob] = {
         # --- kernels ---
         _k("HVDT_FLASH_ATTENTION", "auto", str,
            "Pallas flash-attention kernel: auto (TPU only), on, off."),
-        _k("HVDT_FLASH_SMALLSEQ", "auto", str,
-           "Head-batched single-block attention kernel "
-           "(flash_attention_smallseq) for short sequences (seq <= "
-           "1024): auto (currently DISENGAGED pending the TPU A/B — an "
-           "unmeasured kernel is not a default), on, off.  "
-           "HVDT_FLASH_ATTENTION=off overrides to off; "
-           "HVDT_FLASH_ATTENTION=on forces the streaming kernel "
-           "instead (A/B semantics)."),
-        _k("HVDT_FLASH_SMALLSEQ_HB", 8, int,
-           "heads_per_block for the smallseq attention kernel (clamped "
-           "to divide the head count; tuning knob for the grid-overhead "
-           "vs VMEM trade)."),
         _k("HVDT_FUSED_CONV1X1", False, _parse_bool,
            "Route eligible ResNet 1x1 conv+BN(+ReLU) blocks through the "
            "fused Pallas kernels (ops/conv_fused.py): train mode emits "

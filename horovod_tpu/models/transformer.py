@@ -25,14 +25,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
+from ..ops.attention import attention
 from ..parallel.moe import moe_dispatch_combine
 from ..parallel.pipeline import pipeline_spmd
 from ..parallel.ring_attention import ring_attention
@@ -211,225 +211,15 @@ def _qkv(p, x, positions, cfg: TransformerConfig):
 
 
 def _attention(p, x, positions, cfg: TransformerConfig):
-    b, l, d = x.shape
-    h, hk, dh = cfg.heads, cfg.kv_heads, cfg.head_dim
+    b, l, _ = x.shape
     q, k, v = _qkv(p, x, positions, cfg)
-    flash_plan = None if cfg.sp > 1 else _flash_plan(b, l, h, hk, dh)
     if cfg.sp > 1:
         # Manual island: the sequence dim is the local sp shard here (the
         # caller's shard_map over {'sp'} has already split it).
         o = ring_attention(q, k, v, axis="sp", causal=True)
-    elif flash_plan == "direct":
-        # Pallas fused attention on TPU: O(L·D) HBM traffic instead of a
-        # materialized [B,H,L,L] score matrix (ops/pallas_kernels.py).
-        o = _flash_fn(l, dh, batch=b, heads=h)(q, k, v)
-    elif flash_plan is not None:
-        # GSPMD-auto mesh: Mosaic kernels can't be auto-partitioned, so
-        # open a manual shard_map island over the batch (dp/fsdp) and
-        # heads (tp) axes and run the kernel on the local shard — the
-        # multi-chip engagement the auto gate alone would refuse (the
-        # role of the reference's in-graph custom-call path, ref:
-        # tensorflow/xla_mpi_ops.cc:165-235 "collectives/kernels live
-        # inside the compiled program").
-        from jax.sharding import PartitionSpec as P
-
-        dp_axes, tp_ax, names = flash_plan
-        dp_size, tp_size = _island_local_sizes(
-            jax.sharding.get_abstract_mesh(), dp_axes, tp_ax)
-        fn = _flash_fn(l, dh, batch=max(1, b // dp_size),
-                       heads=max(1, h // tp_size))
-        spec = P(dp_axes if dp_axes else None, None, tp_ax, None)
-        o = jax.shard_map(
-            fn, in_specs=(spec, spec, spec), out_specs=spec,
-            axis_names=names)(q, k, v)
     else:
-        scale = dh ** -0.5
-        if h != hk:
-            k = jnp.repeat(k, h // hk, axis=2)
-            v = jnp.repeat(v, h // hk, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        mask = jnp.tril(jnp.ones((l, l), bool))
-        s = jnp.where(mask[None, None], s, -1e30)
-        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        o = jnp.einsum("bhqk,bkhd->bqhd", w, v)
-    return _proj(o.reshape(b, l, h * dh), p["wo"])
-
-
-def _flash_enabled(seq_len: int, head_dim: int, *, batch: int = 1,
-                   heads: int = 1) -> bool:
-    """Flash kernel policy: HVDT_FLASH_ATTENTION=auto|on|off.
-
-    'auto' (default) engages the kernel on TPU only when the
-    materialized-score path would be memory-heavy: the f32 score tensor
-    ``batch x heads x L x L`` at or past ~4 GB.  The kernel is a
-    CAPACITY play — measured on v5e (BERT-Large, docs/performance.md):
-    at seq 512 bs 128 (2.1 GB scores) XLA's fused attention is ~1.5x
-    faster than kernel-forward + blockwise backward, while at 4+ GB the
-    kernel admits 2x the batch and past ~8 GB XLA attention doesn't fit
-    at all.  (Those were taken with the forward that passed its running
-    state through HBM; the self-contained call of PR 25 is 2.3x that one
-    at seq 4096 and has not been measured at seq 512, where the XLA
-    backward is most of the kernel path's time anyway.)  'on' forces it
-    whenever shapes tile.
-
-    ``batch``/``heads`` are the sizes the kernel will actually see —
-    pass LOCAL (per-shard) sizes when the call site shards them."""
-    from ..common import config
-
-    mode = config.get_str("HVDT_FLASH_ATTENTION").lower()
-    if mode == "off":
-        return False
-    shapes_ok = seq_len % min(128, seq_len) == 0 and seq_len >= 8
-    if mode == "on":
-        return shapes_ok
-    score_bytes = 4 * batch * heads * seq_len * seq_len
-    return (shapes_ok and score_bytes >= 4 * 1024 ** 3
-            and jax.devices()[0].platform == "tpu")
-
-
-def _island_local_sizes(am, dp_axes, tp_ax) -> Tuple[int, int]:
-    """(dp_size, tp_size) of an island plan under abstract mesh ``am`` —
-    the ONE place this arithmetic lives: _flash_plan gates on the local
-    shapes it implies and _attention picks the kernel with the same
-    numbers, so they cannot diverge."""
-    dp_size = (int(np.prod([am.shape[a] for a in dp_axes]))
-               if dp_axes else 1)
-    tp_size = am.shape[tp_ax] if tp_ax else 1
-    return dp_size, tp_size
-
-
-# 'auto' engagement threshold for the smallseq kernel: minimum number of
-# (batch x head-block) grid programs.  None = auto disengaged: the kernel
-# is correctness-proven (CPU interpret suite) but its TPU A/B
-# (tools/tpu_ab.py lm_smallseq_* legs) hasn't run — an unmeasured kernel
-# must not be a default (round-3 verdict discipline).  Set to the
-# measured break-even once the legs land.
-_SMALLSEQ_AUTO_MIN_PROGRAMS: Optional[int] = None
-
-
-def _smallseq_vmem_ok(seq_len: int, head_dim: int, hb: int) -> bool:
-    """Whether one (batch, head-block) program's working set fits VMEM.
-
-    Models the BACKWARD kernel (the larger of the two): bf16 q/do/out +
-    k/v blocks, f32 dq/dk/dv outputs, plus one head's f32 probability
-    and d-score [L, L] scratch pair.  Budget 12 MiB of the ~16 MiB/core
-    so Mosaic keeps headroom for pipelining.  Assumes hb_kv == hb (no
-    GQA shrink) — conservative: GQA only makes the k/v blocks smaller."""
-    bf16_in = 5 * hb * seq_len * head_dim * 2
-    f32_out = 3 * hb * seq_len * head_dim * 4
-    scratch = 2 * seq_len * seq_len * 4
-    return bf16_in + f32_out + scratch <= 12 * 1024 ** 2
-
-
-def _smallseq_enabled(seq_len: int, head_dim: int, *, batch: int,
-                      heads: int) -> bool:
-    """Head-batched single-block kernel policy: HVDT_FLASH_SMALLSEQ.
-
-    The complement of :func:`_flash_enabled`'s capacity play — the
-    streaming kernel's per-grid-step overhead is ruinous at short
-    sequence / large batch*heads (measured 3x WORSE than XLA end-to-end
-    at BERT-Large bs128 seq512, tools/ab_results.json
-    lm_flash_kernelbwd_bs128), while the profiled XLA path spends
-    ~30% of the step materializing scores there.  'auto' engages
-    flash_attention_smallseq on TPU when the whole sequence fits one
-    VMEM block ('on' honors the same fit — a kernel that cannot lower is
-    never a valid choice) and there are at least
-    ``_SMALLSEQ_AUTO_MIN_PROGRAMS`` (batch x head-block) grid programs
-    to amortize per-program overhead.  ``batch``/``heads`` are LOCAL
-    (per-shard) sizes."""
-    from ..common import config
-
-    mode = config.get_str("HVDT_FLASH_SMALLSEQ").lower()
-    if mode == "off":
-        return False
-    shapes_ok = seq_len % 128 == 0 and seq_len <= 1024
-    if mode == "on":
-        # 'on' is the A/B force switch: it must select the kernel for
-        # every tiling shape, or a forced leg would silently measure the
-        # baseline path.  The VMEM estimate below is a MODEL — only
-        # 'auto' trusts it; a genuinely unlowerable block still fails
-        # loudly in the kernel's own _fit_block.
-        return shapes_ok
-    if _SMALLSEQ_AUTO_MIN_PROGRAMS is None:
-        return False
-    hb = min(config.get_int("HVDT_FLASH_SMALLSEQ_HB"), max(heads, 1))
-    programs = batch * max(heads, 1) // max(hb, 1)
-    return (shapes_ok and _smallseq_vmem_ok(seq_len, head_dim, hb)
-            and programs >= _SMALLSEQ_AUTO_MIN_PROGRAMS
-            and jax.devices()[0].platform == "tpu")
-
-
-def _flash_fn(seq_len: int, head_dim: int, *, batch: int, heads: int):
-    """The attention kernel to use for these LOCAL shapes, or None for
-    XLA attention.  HVDT_FLASH_ATTENTION=off is the master off switch;
-    =on keeps its A/B meaning (force the STREAMING kernel)."""
-    from ..common import config
-    from ..ops.pallas_kernels import (flash_attention,
-                                      flash_attention_smallseq)
-
-    mode = config.get_str("HVDT_FLASH_ATTENTION").lower()
-    if mode == "off":
-        return None
-    if mode != "on" and _smallseq_enabled(seq_len, head_dim, batch=batch,
-                                          heads=heads):
-        return functools.partial(
-            flash_attention_smallseq, causal=True,
-            heads_per_block=config.get_int("HVDT_FLASH_SMALLSEQ_HB"))
-    if _flash_enabled(seq_len, head_dim, batch=batch, heads=heads):
-        return functools.partial(flash_attention, causal=True)
-    return None
-
-
-def _flash_plan(b: int, l: int, h: int, hk: int, dh: int):
-    """Decide how the flash kernel can engage under the ambient mesh.
-
-    Returns "direct" (call the kernel as-is: no mesh, or every mesh axis
-    already manual here), a ``(dp_axes, tp_axis)`` island plan (the mesh
-    has GSPMD-auto axes — run the kernel inside a partial-manual
-    shard_map over those axes; Mosaic kernels cannot be auto-partitioned
-    by GSPMD), or None (fall back to XLA attention).  The memory policy
-    (_flash_enabled) is evaluated on the per-shard shapes the kernel
-    would actually see."""
-    am = jax.sharding.get_abstract_mesh()
-    auto = [n for n, t in zip(am.axis_names, am.axis_types)
-            if t == jax.sharding.AxisType.Auto]
-    manual = [n for n, t in zip(am.axis_names, am.axis_types)
-              if t == jax.sharding.AxisType.Manual]
-    if not auto:
-        return ("direct"
-                if _flash_fn(l, dh, batch=b, heads=h) is not None else None)
-    if manual:
-        # Already inside a shard_map (e.g. the pp/sp/ep pipeline island)
-        # with auto axes remaining: nesting another partial-manual island
-        # here fails shardy lowering on the BACKWARD (the residuals'
-        # dimension shardings mix manual-after-free axes — verified on
-        # jax 0.9: "manual axes must come before free axes").  Fall back
-        # to XLA attention; pure-auto meshes (dp/fsdp/tp) still engage.
-        return None
-    # Shard batch over dp-like axes and heads over tp, where divisible.
-    dp_axes: Tuple[str, ...] = tuple(a for a in ("dp", "fsdp")
-                                     if a in auto)
-    while dp_axes and b % _island_local_sizes(am, dp_axes, None)[0]:
-        dp_axes = dp_axes[:-1]
-    tp_ax = "tp" if "tp" in auto else None
-    if tp_ax and (h % am.shape[tp_ax] or hk % am.shape[tp_ax]):
-        tp_ax = None
-    dp_size, tp_size = _island_local_sizes(am, dp_axes, tp_ax)
-    # Any OTHER size>1 auto axis (e.g. an auto axis sharding the
-    # sequence) means the island's replicated in_specs would force a
-    # full-sequence all-gather per layer — don't engage the kernel there.
-    # Size-1 leftovers are included in the island instead: Mosaic refuses
-    # to lower while ANY auto axis is ambient, even a trivial one.
-    leftover = [a for a in auto if a not in dp_axes and a != tp_ax]
-    if any(am.shape[a] > 1 for a in leftover):
-        return None
-    if _flash_fn(l, dh, batch=max(1, b // dp_size),
-                 heads=max(1, h // tp_size)) is None:
-        return None
-    names = frozenset(dp_axes) | ({tp_ax} if tp_ax else set()) | \
-        frozenset(leftover)
-    return (dp_axes, tp_ax, names)
+        o = attention(q, k, v)
+    return _proj(o.reshape(b, l, cfg.heads * cfg.head_dim), p["wo"])
 
 
 def _mlp(p, x):

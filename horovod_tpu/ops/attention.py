@@ -1,0 +1,172 @@
+"""Local attention: the one way in, and the choice of what runs behind it.
+
+``attention(q, k, v)`` is what a model calls when one device (or one
+shard of a manual island) holds the whole sequence.  Which code runs is
+decided here and nowhere else, from what can be observed at trace time:
+
+* the ambient abstract mesh (:func:`kernel_plan`): a Mosaic kernel
+  cannot be auto-partitioned by GSPMD, so under GSPMD-auto axes it runs
+  inside a manual ``shard_map`` island over (``dp``, ``fsdp``) x ``tp``,
+  and where no such island can be opened it does not run;
+* the policy on the LOCAL shapes (:func:`kernel_enabled`):
+  ``HVDT_FLASH_ATTENTION=auto|on|off``, whether the shapes tile, the
+  bytes of the f32 score tensor the XLA path would materialize, the
+  platform;
+* then ``pallas_kernels.flash_attention`` (its blocks come from the
+  shape), or the XLA path below.
+
+A sequence sharded over ``sp`` is a layout the caller declared, not a
+choice made here: the model calls ``parallel.ring_attention`` for it.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from ..common import config
+from . import pallas_kernels
+
+__all__ = ["attention", "kernel_enabled", "kernel_plan"]
+
+
+def kernel_enabled(seq_len: int, *, batch: int, heads: int) -> bool:
+    """Flash kernel policy: HVDT_FLASH_ATTENTION=auto|on|off.
+
+    'auto' (default) engages the kernel on TPU only when the
+    materialized-score path would be memory-heavy: the f32 score tensor
+    ``batch x heads x L x L`` at or past 4 GiB.  The kernel is a
+    CAPACITY play — measured on v5e at BERT-Large widths (PERF.md): at
+    seq 512 x 128 (2 GiB of scores) XLA attention fits and is 12% faster
+    than the kernel path (section 6, PR 28); at seq 4096 x 8 (8 GiB) XLA
+    attention with its backward does not fit at all.  'on' forces the
+    kernel whenever shapes tile; 'off' is the master switch.
+
+    ``batch``/``heads`` are the sizes the kernel will actually see —
+    pass LOCAL (per-shard) sizes when the call site shards them."""
+    mode = config.get_str("HVDT_FLASH_ATTENTION").lower()
+    if mode == "off":
+        return False
+    shapes_ok = seq_len % min(128, seq_len) == 0 and seq_len >= 8
+    if mode == "on":
+        return shapes_ok
+    score_bytes = 4 * batch * heads * seq_len * seq_len
+    return (shapes_ok and score_bytes >= 4 * 1024 ** 3
+            and jax.devices()[0].platform == "tpu")
+
+
+def _island_local_sizes(am, dp_axes, tp_ax) -> Tuple[int, int]:
+    """(dp_size, tp_size) of an island plan under abstract mesh ``am``:
+    what the island divides batch and heads by, so the policy sees the
+    shapes the kernel would."""
+    dp_size = (int(np.prod([am.shape[a] for a in dp_axes]))
+               if dp_axes else 1)
+    tp_size = am.shape[tp_ax] if tp_ax else 1
+    return dp_size, tp_size
+
+
+def kernel_plan(b: int, l: int, h: int, hk: int):
+    """Decide how the flash kernel can engage under the ambient mesh.
+
+    Returns "direct" (call the kernel as-is: no mesh, or every mesh axis
+    already manual here), a ``(dp_axes, tp_axis, names)`` island plan
+    (the mesh has GSPMD-auto axes — run the kernel inside a
+    partial-manual shard_map over ``names``; Mosaic kernels cannot be
+    auto-partitioned by GSPMD), or None (fall back to XLA attention).
+    The policy (:func:`kernel_enabled`) is evaluated once, on the
+    per-shard shapes the kernel would actually see."""
+    am = jax.sharding.get_abstract_mesh()
+    auto = [n for n, t in zip(am.axis_names, am.axis_types)
+            if t == jax.sharding.AxisType.Auto]
+    manual = [n for n, t in zip(am.axis_names, am.axis_types)
+              if t == jax.sharding.AxisType.Manual]
+    if not auto:
+        return "direct" if kernel_enabled(l, batch=b, heads=h) else None
+    if manual:
+        # Already inside a shard_map (e.g. the pp/sp/ep pipeline island)
+        # with auto axes remaining: nesting another partial-manual island
+        # here fails shardy lowering on the BACKWARD (the residuals'
+        # dimension shardings mix manual-after-free axes — verified on
+        # jax 0.9: "manual axes must come before free axes").  Fall back
+        # to XLA attention; pure-auto meshes (dp/fsdp/tp) still engage.
+        return None
+    # Shard batch over dp-like axes and heads over tp, where divisible.
+    dp_axes: Tuple[str, ...] = tuple(a for a in ("dp", "fsdp")
+                                     if a in auto)
+    while dp_axes and b % _island_local_sizes(am, dp_axes, None)[0]:
+        dp_axes = dp_axes[:-1]
+    tp_ax = "tp" if "tp" in auto else None
+    if tp_ax and (h % am.shape[tp_ax] or hk % am.shape[tp_ax]):
+        tp_ax = None
+    dp_size, tp_size = _island_local_sizes(am, dp_axes, tp_ax)
+    # Any OTHER size>1 auto axis (e.g. an auto axis sharding the
+    # sequence) means the island's replicated in_specs would force a
+    # full-sequence all-gather per layer — don't engage the kernel there.
+    # Size-1 leftovers are included in the island instead: Mosaic refuses
+    # to lower while ANY auto axis is ambient, even a trivial one.
+    leftover = [a for a in auto if a not in dp_axes and a != tp_ax]
+    if any(am.shape[a] > 1 for a in leftover):
+        return None
+    if not kernel_enabled(l, batch=max(1, b // dp_size),
+                          heads=max(1, h // tp_size)):
+        return None
+    names = frozenset(dp_axes) | ({tp_ax} if tp_ax else set()) | \
+        frozenset(leftover)
+    return (dp_axes, tp_ax, names)
+
+
+def _xla_attention(q, k, v, causal: bool):
+    """Attention as XLA fuses it: the ``[B, H, L, L]`` scores exist, in
+    f32 out of bf16 operands.  Not the f32 oracle
+    (``pallas_kernels.attention_reference``) and not the paged-slot
+    softmax of the serving path: this is what training runs wherever the
+    kernel does not."""
+    l, h, dh = q.shape[1], q.shape[2], q.shape[3]
+    hk = k.shape[2]
+    scale = dh ** -0.5
+    if h != hk:
+        k = jnp.repeat(k, h // hk, axis=2)
+        v = jnp.repeat(v, h // hk, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        mask = jnp.tril(jnp.ones((l, l), bool))
+        s = jnp.where(mask[None, None], s, -1e30)
+    w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", w, v)
+
+
+def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+              causal: bool = True) -> jax.Array:
+    """Self-attention of a sequence this device holds whole.
+
+    q: ``[B, L, H, D]``; k, v: ``[B, L, Hkv, D]`` with ``Hkv`` dividing
+    ``H`` (GQA); returns ``[B, L, H, D]``.  Shapes are the global ones
+    under a GSPMD-auto mesh and the local ones inside a manual island."""
+    b, l, h, _ = q.shape
+    plan = kernel_plan(b, l, h, k.shape[2])
+    if plan is None:
+        return _xla_attention(q, k, v, causal)
+    # Pallas fused attention: O(L·D) HBM traffic instead of a
+    # materialized [B,H,L,L] score matrix (ops/pallas_kernels.py).
+    kernel = functools.partial(pallas_kernels.flash_attention,
+                               causal=causal)
+    if plan == "direct":
+        return kernel(q, k, v)
+    # GSPMD-auto mesh: Mosaic kernels can't be auto-partitioned, so
+    # open a manual shard_map island over the batch (dp/fsdp) and
+    # heads (tp) axes and run the kernel on the local shard — the
+    # multi-chip engagement the auto gate alone would refuse (the
+    # role of the reference's in-graph custom-call path, ref:
+    # tensorflow/xla_mpi_ops.cc:165-235 "collectives/kernels live
+    # inside the compiled program").
+    dp_axes, tp_ax, names = plan
+    spec = P(dp_axes if dp_axes else None, None, tp_ax, None)
+    return jax.shard_map(
+        kernel, in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=names)(q, k, v)

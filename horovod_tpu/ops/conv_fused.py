@@ -21,7 +21,7 @@ A/B (tools/resnet_probe.py) shows parity and closes the lever with a
 number; if not, the delta is the banked win.
 
 Runs in Pallas interpret mode off-TPU so the CPU suite exercises the
-same kernel code (tests/test_pallas_smallseq_conv.py).
+same kernel code (tests/test_conv_fused.py).
 """
 
 from __future__ import annotations

@@ -50,8 +50,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention", "flash_attention_smallseq",
-           "flash_block_update", "flash_grad_block",
+__all__ = ["flash_attention", "flash_block_update", "flash_grad_block",
            "attention_reference"]
 
 _NEG_INF = -1e30
@@ -290,7 +289,11 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
         _tile(False)
     elif one_tile:
         # The whole sequence is the diagonal's tile.  ik is 0: the cond is
-        # there for interpret mode under shard_map (_smallseq_fwd_kernel).
+        # there for interpret mode under shard_map (the CPU test path),
+        # where TOP-LEVEL ref reads discharge to dynamic_slice whose vma
+        # rule rejects varying-operand/unvarying-index mixes; inside a
+        # cond the branch vma rule reconciles them (measured; jax 0.9
+        # asks for an upstream issue).  Free on TPU: one true predicate.
         pl.when(ik == 0)(lambda: _tile(True))
     else:
         visited = (iq + 1) * bq - 1 >= ik * bk            # else: all masked
@@ -501,7 +504,7 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
 
     if not causal:
         # ik >= 0 always: the cond is there for interpret mode under
-        # shard_map (_smallseq_fwd_kernel).
+        # shard_map (see _local_kernel's one-tile branch).
         pl.when(ik >= 0)(lambda: _tile(False))
     else:
         visited = (iq + 1) * bq - 1 >= ik * bk            # else: all masked
@@ -1053,228 +1056,6 @@ def flash_grad_block(q, k, v, do, out, lse, *, q_offset=0, k_offset=0,
         dk = dk.reshape(b, lk, hkv, group, d).sum(3)
         dv = dv.reshape(b, lk, hkv, group, d).sum(3)
     return dq, dk, dv
-
-
-def _smallseq_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                         causal: bool, scale: float, group: int):
-    """Grid (b, h//hb): the WHOLE sequence of ``hb`` heads per program.
-
-    The streaming flash kernel (grid b x h x q-blocks x k-blocks) pays a
-    fixed per-grid-step cost that dominates at short sequence / large
-    batch*heads — measured 3x WORSE than XLA attention end-to-end at
-    BERT-Large bs128 seq512 (tools/ab_results.json
-    lm_flash_kernelbwd_bs128).  When the sequence fits one block there
-    is nothing to stream: each (batch, head) is a self-contained
-    softmax(qk')v in VMEM, so batch hb heads per program and skip the
-    online-softmax carry entirely.  HBM traffic is O(L*D) like flash;
-    grid steps drop hb*n_q_blocks*n_k_blocks-fold."""
-    import jax.experimental.pallas as pl
-
-    hb, l = q_ref.shape[1], q_ref.shape[2]
-
-    # Always-true cond: under shard_map + interpret mode (the CPU test
-    # path) TOP-LEVEL ref reads discharge to dynamic_slice whose vma
-    # rule rejects varying-operand/unvarying-index mixes; inside a cond
-    # the branch vma rule reconciles them (measured; jax 0.9 asks for an
-    # upstream issue).  Free on TPU — one trivially-true predicate.
-    @pl.when(pl.program_id(0) >= 0)
-    def _body():
-        if causal:
-            mask = (jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
-                    >= jax.lax.broadcasted_iota(jnp.int32, (l, l), 1))
-        for i in range(hb):
-            qh = q_ref[0, i, :, :]
-            kh = k_ref[0, i // group, :, :]
-            vh = v_ref[0, i // group, :, :]
-            s = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale    # [L, L]
-            if causal:
-                s = jnp.where(mask, s, _NEG_INF)
-            m = s.max(axis=-1, keepdims=True)
-            p = jnp.exp(s - m)
-            if causal:
-                p = jnp.where(mask, p, 0.0)
-            lsum = p.sum(axis=-1, keepdims=True)
-            acc = jax.lax.dot_general(
-                p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            o_ref[0, i, :, :] = (acc / lsum).astype(o_ref.dtype)
-            lse_ref[0, i, :, :] = m + jnp.log(lsum)
-
-
-def _smallseq_bwd_kernel(q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref,
-                         dq_ref, dk_ref, dv_ref, *, causal: bool,
-                         scale: float, group: int):
-    """Grad counterpart of :func:`_smallseq_fwd_kernel`: one program
-    computes dq/dk/dv for ``hb`` heads' full sequence, recomputing the
-    probabilities from the saved logsumexp.  GQA: dk/dv accumulate over
-    the ``group`` q-heads sharing each kv head (heads of a group are
-    adjacent in the loop)."""
-    import jax.experimental.pallas as pl
-
-    hb, l = q_ref.shape[1], q_ref.shape[2]
-    f32 = jnp.float32
-
-    # Always-true cond: see _smallseq_fwd_kernel.
-    @pl.when(pl.program_id(0) >= 0)
-    def _body():
-        if causal:
-            mask = (jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
-                    >= jax.lax.broadcasted_iota(jnp.int32, (l, l), 1))
-        for i in range(hb):
-            qh = q_ref[0, i, :, :]
-            kh = k_ref[0, i // group, :, :]
-            vh = v_ref[0, i // group, :, :]
-            doh = do_ref[0, i, :, :]
-            oh = out_ref[0, i, :, :]
-            s = jax.lax.dot_general(
-                qh, kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32) * scale
-            p = jnp.exp(s - lse_ref[0, i, :, :])
-            if causal:
-                p = jnp.where(mask, p, 0.0)
-            delta = (doh.astype(f32) * oh.astype(f32)).sum(-1,
-                                                           keepdims=True)
-            pb = p.astype(doh.dtype)
-            dv_c = jax.lax.dot_general(
-                pb, doh, (((0,), (0,)), ((), ())),
-                preferred_element_type=f32)                   # [Lk, D]
-            dp = jax.lax.dot_general(
-                doh, vh, (((1,), (1,)), ((), ())),
-                preferred_element_type=f32)                   # [Lq, Lk]
-            ds = p * (dp - delta) * scale
-            dsb = ds.astype(qh.dtype)
-            dq_ref[0, i, :, :] = jax.lax.dot_general(
-                dsb, kh, (((1,), (0,)), ((), ())),
-                preferred_element_type=f32)
-            dk_c = jax.lax.dot_general(
-                dsb, qh, (((0,), (0,)), ((), ())),
-                preferred_element_type=f32)                   # [Lk, D]
-            if group > 1:
-                first = (i % group == 0)
-                dk_ref[0, i // group, :, :] = (
-                    dk_c if first
-                    else dk_ref[0, i // group, :, :] + dk_c)
-                dv_ref[0, i // group, :, :] = (
-                    dv_c if first
-                    else dv_ref[0, i // group, :, :] + dv_c)
-            else:
-                dk_ref[0, i, :, :] = dk_c
-                dv_ref[0, i, :, :] = dv_c
-
-
-def _fit_heads_per_block(h: int, group: int, heads_per_block: int) -> int:
-    """Largest hb <= requested that divides h and is a multiple of the
-    GQA group (so a program's kv heads are whole blocks).  ``group`` is
-    the floor: a request below it (or a nonsense knob value <= 0) clamps
-    up to one whole kv group per program — never 0 (ZeroDivisionError)."""
-    hb = max(min(heads_per_block, h), group)
-    while h % hb or hb % group:
-        hb -= 1
-    return max(hb, group)
-
-
-def _smallseq_call(q, k, v, causal, scale, hb):
-    """Forward pallas_call in [B, H, L, D]; returns (out, lse)."""
-    import jax.experimental.pallas as pl
-
-    b, h, l, d = q.shape
-    hkv = k.shape[1]
-    group = h // hkv
-    hb_kv = hb // group
-    kw = _vma_kw(q, k, v)
-    qspec = pl.BlockSpec((1, hb, l, d), lambda bb, hh: (bb, hh, 0, 0))
-    kvspec = pl.BlockSpec((1, hb_kv, l, d), lambda bb, hh: (bb, hh, 0, 0))
-    col = pl.BlockSpec((1, hb, l, 1), lambda bb, hh: (bb, hh, 0, 0))
-    with jax.named_scope("hvdt.kernel.flash_smallseq_fwd"):
-        return pl.pallas_call(
-            functools.partial(_smallseq_fwd_kernel, causal=causal,
-                              scale=scale, group=group),
-            grid=(b, h // hb),
-            in_specs=[qspec, kvspec, kvspec],
-            out_specs=[qspec, col],
-            out_shape=(jax.ShapeDtypeStruct((b, h, l, d), q.dtype, **kw),
-                       jax.ShapeDtypeStruct((b, h, l, 1), jnp.float32, **kw)),
-            interpret=_use_interpret(),
-        )(q, k, v)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _smallseq_diff(q, k, v, causal, scale, hb):
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out, _ = _smallseq_call(qt, kt, vt, causal, scale, hb)
-    return out.transpose(0, 2, 1, 3)
-
-
-def _smallseq_diff_fwd(q, k, v, causal, scale, hb):
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out, lse = _smallseq_call(qt, kt, vt, causal, scale, hb)
-    return out.transpose(0, 2, 1, 3), (q, k, v, out, lse)
-
-
-def _smallseq_diff_bwd(causal, scale, hb, res, do):
-    import jax.experimental.pallas as pl
-
-    q, k, v, out_t, lse = res                  # out_t/lse in [B,H,L,D/1]
-    b, lq, h, d = q.shape
-    hkv = k.shape[2]
-    group = h // hkv
-    hb_kv = hb // group
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    dot = do.transpose(0, 2, 1, 3)
-    kw = _vma_kw(q, k, v, do)
-    qspec = pl.BlockSpec((1, hb, lq, d), lambda bb, hh: (bb, hh, 0, 0))
-    kvspec = pl.BlockSpec((1, hb_kv, lq, d), lambda bb, hh: (bb, hh, 0, 0))
-    col = pl.BlockSpec((1, hb, lq, 1), lambda bb, hh: (bb, hh, 0, 0))
-    with jax.named_scope("hvdt.kernel.flash_smallseq_bwd"):
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_smallseq_bwd_kernel, causal=causal,
-                              scale=scale, group=group),
-            grid=(b, h // hb),
-            in_specs=[qspec, kvspec, kvspec, qspec, qspec, col],
-            out_specs=[qspec, kvspec, kvspec],
-            out_shape=(
-                jax.ShapeDtypeStruct((b, h, lq, d), jnp.float32, **kw),
-                jax.ShapeDtypeStruct((b, hkv, lq, d), jnp.float32, **kw),
-                jax.ShapeDtypeStruct((b, hkv, lq, d), jnp.float32, **kw)),
-            interpret=_use_interpret(),
-        )(qt, kt, vt, dot, out_t, lse)
-    return (dq.transpose(0, 2, 1, 3).astype(q.dtype),
-            dk.transpose(0, 2, 1, 3).astype(k.dtype),
-            dv.transpose(0, 2, 1, 3).astype(v.dtype))
-
-
-_smallseq_diff.defvjp(_smallseq_diff_fwd, _smallseq_diff_bwd)
-
-
-def flash_attention_smallseq(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                             causal: bool = True,
-                             scale: Optional[float] = None,
-                             heads_per_block: int = 8) -> jax.Array:
-    """Head-batched single-block fused attention for the short-sequence
-    regime (the BERT-Large-shape complement of :func:`flash_attention`).
-
-    Same API/layout as flash_attention (q/k/v: [B, L, H, D], GQA via
-    fewer kv heads, differentiable — the backward is a single Pallas
-    program per (batch, head-block) recomputing probabilities from the
-    saved logsumexp).  Use when the sequence fits one VMEM block
-    (L <= ~1024): HBM never sees a score matrix AND the grid is
-    b*h/hb programs instead of flash's b*h*n_q*n_k.
-    """
-    b, l, h, d = q.shape
-    hkv = k.shape[2]
-    if h % hkv:
-        raise ValueError(f"q heads {h} not divisible by kv heads {hkv}")
-    if k.shape[1] != l:
-        raise ValueError("flash_attention_smallseq needs lq == lk "
-                         f"(got {l} vs {k.shape[1]})")
-    if scale is None:
-        scale = d ** -0.5
-    # Same sublane-tile floor as _fit_block: the [L, D] per-head tile.
-    _fit_block(l, l, q.dtype, k.dtype, v.dtype)
-    hb = _fit_heads_per_block(h, h // hkv, heads_per_block)
-    return _smallseq_diff(q, k, v, causal, float(scale), hb)
 
 
 def attention_reference(q, k, v, *, causal=True, scale=None,

@@ -36,22 +36,6 @@ def test_latest_successful_leg_wins(tmp_path):
     assert latest["lm_base_bs128_remat"]["result"]["tokens_per_sec"] == 200
 
 
-def test_smallseq_win_and_loss(tmp_path):
-    base = {"tokens_per_sec": 29376}
-    win = ab_decide.decide(ab_decide.latest_results(_hist(tmp_path, [_run(
-        "t", lm_base_bs128_remat=base,
-        lm_smallseq_hb8_bs128={"tokens_per_sec": 36000},
-        lm_smallseq_hb16_bs128={"tokens_per_sec": 33000})])))
-    assert win["smallseq"]["verdict"] == "ENGAGE_AUTO"
-    assert win["smallseq"]["best_hb"] == 8
-    assert "HVDT_FLASH_SMALLSEQ_HB=8" in win["smallseq"]["action"]
-
-    loss = ab_decide.decide(ab_decide.latest_results(_hist(tmp_path, [_run(
-        "t", lm_base_bs128_remat=base,
-        lm_smallseq_hb8_bs128={"tokens_per_sec": 29000})])))
-    assert loss["smallseq"]["verdict"] == "KEEP_DISENGAGED"
-
-
 def test_two_percent_margin_is_not_a_win(tmp_path):
     base = {"tokens_per_sec": 10000}
     d = ab_decide.decide(ab_decide.latest_results(_hist(tmp_path, [_run(

@@ -12,8 +12,6 @@ TOY_KERNELS = dict(
     kernel_flash_ring_step=dict(batch=1, seq=128, heads=2, head_dim=64),
     kernel_flash_backward=dict(batch=1, seq=256, heads=2, head_dim=64),
     kernel_flash_grad_block=dict(batch=1, seq=256, heads=2, head_dim=64),
-    kernel_smallseq_forward=dict(batch=1, seq=128, heads=4, head_dim=64),
-    kernel_smallseq_backward=dict(batch=1, seq=128, heads=4, head_dim=64),
     kernel_conv_bn_relu=dict(batch=2, hw=8, cin=128, cout=128),
     kernel_conv_bn_train=dict(batch=2, hw=8, cin=128, cout=128),
     kernel_fused_adam=dict(shape=(2, 64, 128)),

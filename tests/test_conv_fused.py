@@ -1,200 +1,12 @@
-"""The head-batched small-sequence attention kernel with its routing
-policy, and the fused 1x1-conv + BN kernels (ops/conv_fused.py); interpret
-mode on the CPU.  Split from tests/test_pallas.py so that neither file is
-a worker's whole share of the run under --dist loadfile."""
+"""The fused 1x1-conv + BN kernels (ops/conv_fused.py); interpret mode on
+the CPU.  Its own file so that tests/test_pallas.py is not a worker's whole
+share of the run under --dist loadfile."""
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-
-from horovod_tpu.ops.pallas_kernels import attention_reference
-
-
-class TestSmallseqKernel:
-    """flash_attention_smallseq — the head-batched single-block kernel
-    for the short-seq regime (ops/pallas_kernels.py)."""
-
-    def _qkv(self, b=2, l=128, h=4, hkv=None, d=16, dtype=jnp.float32,
-             seed=0):
-        hkv = hkv or h
-        rng = np.random.RandomState(seed)
-        q = jnp.asarray(rng.randn(b, l, h, d), dtype)
-        k = jnp.asarray(rng.randn(b, l, hkv, d), dtype)
-        v = jnp.asarray(rng.randn(b, l, hkv, d), dtype)
-        return q, k, v
-
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_matches_reference(self, causal):
-        from horovod_tpu.ops.pallas_kernels import flash_attention_smallseq
-
-        q, k, v = self._qkv()
-        out = flash_attention_smallseq(q, k, v, causal=causal,
-                                       heads_per_block=2)
-        ref = attention_reference(q, k, v, causal=causal)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_gqa(self):
-        from horovod_tpu.ops.pallas_kernels import flash_attention_smallseq
-
-        q, k, v = self._qkv(h=4, hkv=2)
-        out = flash_attention_smallseq(q, k, v, causal=True,
-                                       heads_per_block=4)
-        ref = attention_reference(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_bf16(self):
-        from horovod_tpu.ops.pallas_kernels import flash_attention_smallseq
-
-        q, k, v = self._qkv(dtype=jnp.bfloat16)
-        out = flash_attention_smallseq(q, k, v, causal=True)
-        ref = attention_reference(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(ref, np.float32),
-                                   rtol=3e-2, atol=3e-2)
-
-    def test_heads_per_block_fits(self):
-        from horovod_tpu.ops.pallas_kernels import _fit_heads_per_block
-
-        assert _fit_heads_per_block(16, 1, 8) == 8
-        assert _fit_heads_per_block(4, 1, 8) == 4
-        assert _fit_heads_per_block(6, 1, 4) == 3   # 4,5 don't divide 6
-        assert _fit_heads_per_block(8, 4, 8) == 8
-        assert _fit_heads_per_block(8, 4, 6) == 4   # must be group multiple
-        # A request below the GQA group clamps UP to one kv group per
-        # program (regression: decremented to 0 -> ZeroDivisionError).
-        assert _fit_heads_per_block(32, 16, 8) == 16
-        assert _fit_heads_per_block(16, 8, 0) == 8  # nonsense knob value
-
-    def test_wide_gqa_group_exceeds_requested_hb(self):
-        # group=4 > heads_per_block=2: clamps up and stays correct.
-        from horovod_tpu.ops.pallas_kernels import flash_attention_smallseq
-
-        q, k, v = self._qkv(h=8, hkv=2, seed=5)
-        out = flash_attention_smallseq(q, k, v, causal=True,
-                                       heads_per_block=2)
-        ref = attention_reference(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_grads_match_reference(self, causal):
-        from horovod_tpu.ops.pallas_kernels import flash_attention_smallseq
-
-        q, k, v = self._qkv(seed=3)
-        w = jnp.cos(jnp.arange(16.0))
-
-        def grads(fn):
-            return jax.grad(
-                lambda q, k, v: ((fn(q, k, v, causal=causal) * w) ** 2
-                                 ).sum(), argnums=(0, 1, 2))(q, k, v)
-
-        got = grads(lambda q, k, v, **kw: flash_attention_smallseq(
-            q, k, v, heads_per_block=2, **kw))
-        ref = grads(attention_reference)
-        for a, b in zip(got, ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-5, rtol=1e-4)
-
-    def test_gqa_grads_accumulate_groups(self):
-        from horovod_tpu.ops.pallas_kernels import flash_attention_smallseq
-
-        q, k, v = self._qkv(h=4, hkv=2, seed=4)
-
-        def grads(fn):
-            return jax.grad(
-                lambda q, k, v: fn(q, k, v, causal=True).sum(),
-                argnums=(0, 1, 2))(q, k, v)
-
-        got = grads(lambda q, k, v, causal: flash_attention_smallseq(
-            q, k, v, causal=causal, heads_per_block=4))
-        ref = grads(attention_reference)
-        for a, b in zip(got, ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-5, rtol=1e-4)
-
-
-class TestSmallseqPolicy:
-    """HVDT_FLASH_SMALLSEQ routing in models/transformer._flash_fn."""
-
-    def _spy(self, monkeypatch):
-        import horovod_tpu.ops.pallas_kernels as pk
-
-        calls = []
-        orig = pk.flash_attention_smallseq
-
-        def spy(*a, **kw):
-            calls.append(1)
-            return orig(*a, **kw)
-
-        monkeypatch.setattr(pk, "flash_attention_smallseq", spy)
-        return calls
-
-    def test_env_on_routes_model_attention(self, monkeypatch):
-        from horovod_tpu.models import (TransformerConfig, transformer_init,
-                                        transformer_apply)
-
-        calls = self._spy(monkeypatch)
-        cfg = TransformerConfig(vocab=64, layers=2, d_model=32, heads=2,
-                                kv_heads=2, d_ff=64, max_seq=128,
-                                dtype=jnp.float32)
-        params = transformer_init(jax.random.PRNGKey(0), cfg)
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 64)
-
-        monkeypatch.setenv("HVDT_FLASH_SMALLSEQ", "off")
-        monkeypatch.setenv("HVDT_FLASH_ATTENTION", "auto")
-        ref = transformer_apply(params, tokens, cfg)
-        assert not calls
-        monkeypatch.setenv("HVDT_FLASH_SMALLSEQ", "on")
-        got = transformer_apply(params, tokens, cfg)
-        assert calls   # the smallseq kernel actually ran
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-4)
-
-    def test_master_off_and_streaming_force_precedence(self, monkeypatch):
-        from horovod_tpu.models.transformer import _flash_fn
-
-        monkeypatch.setenv("HVDT_FLASH_SMALLSEQ", "on")
-        monkeypatch.setenv("HVDT_FLASH_ATTENTION", "off")
-        assert _flash_fn(128, 32, batch=8, heads=8) is None
-        # =on keeps its A/B meaning: force the STREAMING kernel.
-        monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
-        fn = _flash_fn(128, 32, batch=8, heads=8)
-        assert fn is not None
-        assert fn.func.__name__ == "flash_attention"
-        monkeypatch.setenv("HVDT_FLASH_ATTENTION", "auto")
-        fn = _flash_fn(128, 32, batch=8, heads=8)
-        assert fn.func.__name__ == "flash_attention_smallseq"
-
-    def test_on_forces_every_tiling_shape(self, monkeypatch):
-        """'on' is the A/B force switch: it must pick the kernel for any
-        tiling shape — including the lm_smallseq_hb16_bs128 leg's shape,
-        which the auto path's 12 MiB VMEM MODEL would reject (a forced
-        leg silently measuring the baseline corrupts the A/B)."""
-        from horovod_tpu.models.transformer import _smallseq_enabled
-
-        monkeypatch.setenv("HVDT_FLASH_SMALLSEQ", "on")
-        monkeypatch.setenv("HVDT_FLASH_SMALLSEQ_HB", "16")
-        assert _smallseq_enabled(512, 64, batch=128, heads=16)
-        # non-tiling / long shapes still never route to the kernel
-        assert not _smallseq_enabled(2048, 64, batch=128, heads=16)
-        assert not _smallseq_enabled(130, 64, batch=128, heads=16)
-
-    def test_auto_stays_disengaged_and_gates_on_platform(self, monkeypatch):
-        import horovod_tpu.models.transformer as tr
-
-        monkeypatch.setenv("HVDT_FLASH_SMALLSEQ", "auto")
-        assert not tr._smallseq_enabled(512, 64, batch=128, heads=16)
-        # even with a threshold set, the CPU platform must not engage
-        monkeypatch.setattr(tr, "_SMALLSEQ_AUTO_MIN_PROGRAMS", 16)
-        assert not tr._smallseq_enabled(512, 64, batch=128, heads=16)
-        # the VMEM model only constrains auto
-        monkeypatch.setattr(tr, "_SMALLSEQ_AUTO_MIN_PROGRAMS", None)
-        assert not tr._smallseq_vmem_ok(512, 64, hb=16)
-        assert tr._smallseq_vmem_ok(512, 64, hb=4)
 
 
 class TestConvFused:

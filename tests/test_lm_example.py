@@ -42,15 +42,6 @@ def test_meshless_single_device():
 
 
 @pytest.mark.integration
-def test_meshless_smallseq_kernel_on():
-    # The interpret-mode kernel is slow; 2 heads/block over 4 heads still
-    # proves the CLI -> policy -> kernel wiring end to end.
-    assert _run(["--dp", "1", "--tp", "1"],
-                {"HVDT_FLASH_SMALLSEQ": "on",
-                 "HVDT_FLASH_SMALLSEQ_HB": "2"}) > 0
-
-
-@pytest.mark.integration
 def test_dp2_tp2_hybrid_with_remat_and_chunked_loss():
     assert _run(["--dp", "2", "--tp", "2", "--remat",
                  "--loss-chunk", "128"]) > 0

@@ -291,11 +291,11 @@ class TestFlashUnderAutoMesh:
     def test_seq_sharded_auto_axis_refuses(self, devices, monkeypatch):
         """A size>1 auto axis the island cannot absorb (it would gather
         the sequence) falls back to XLA attention."""
-        from horovod_tpu.models.transformer import _flash_plan
+        from horovod_tpu.ops.attention import kernel_plan
         from jax.sharding import AxisType
 
         monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
         mesh = jax.make_mesh((2, 4), ("dp", "seq"),
                              axis_types=(AxisType.Auto, AxisType.Auto))
         with jax.set_mesh(mesh):
-            assert _flash_plan(8, 128, 4, 2, 32) is None
+            assert kernel_plan(8, 128, 4, 2) is None

@@ -85,16 +85,16 @@ class TestFlashGradients:
         monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
         from horovod_tpu.models import (TransformerConfig, transformer_init,
                                         transformer_loss)
-        import horovod_tpu.models.transformer as tr
+        import horovod_tpu.ops.attention as att
 
         gate_args = []
-        orig = tr._flash_enabled
+        orig = att.kernel_enabled
 
-        def spy(l, dh, **kw):
+        def spy(l, **kw):
             gate_args.append(l)
-            return orig(l, dh, **kw)
+            return orig(l, **kw)
 
-        monkeypatch.setattr(tr, "_flash_enabled", spy)
+        monkeypatch.setattr(att, "kernel_enabled", spy)
         cfg = TransformerConfig(vocab=128, layers=1, d_model=32, heads=2,
                                 kv_heads=2, d_ff=64, max_seq=128,
                                 dtype=jnp.float32)
@@ -103,9 +103,7 @@ class TestFlashGradients:
         loss, g = jax.value_and_grad(transformer_loss)(p, toks, cfg)
         assert np.isfinite(float(loss))
         # attention ran on the FULL power-of-two seq -> gate engaged
-        # (evaluated once by _flash_plan and once picking the kernel in
-        # _flash_fn — the count is an implementation detail, the seq the
-        # gate saw is the regression being pinned)
+        # (the seq the gate saw is the regression being pinned)
         assert gate_args and set(gate_args) == {128}, gate_args
         leaves = jax.tree.leaves(g)
         assert all(np.all(np.isfinite(np.asarray(x))) for x in leaves)
@@ -163,17 +161,17 @@ class TestFlashMeshGate:
         fully-manual context the kernel may run directly."""
         from jax.sharding import Mesh, PartitionSpec as P
 
-        import horovod_tpu.models.transformer as tr
+        import horovod_tpu.ops.attention as att
 
         monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
-        assert tr._flash_plan(2, 128, 4, 4, 32) == "direct"   # no mesh
+        assert att.kernel_plan(2, 128, 4, 4) == "direct"      # no mesh
 
         mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
                     ("dp", "sp"))
         seen = {}
 
         def probe(x):
-            seen["plan"] = tr._flash_plan(2, 128, 4, 4, 32)
+            seen["plan"] = att.kernel_plan(2, 128, 4, 4)
             return x
 
         jax.jit(jax.shard_map(probe, mesh=mesh, in_specs=P(),
@@ -186,14 +184,14 @@ class TestFlashMeshGate:
         with jax.set_mesh(jax.make_mesh(
                 (1, 1), ("dp", "tp"),
                 axis_types=(jax.sharding.AxisType.Auto,) * 2)):
-            plan = tr._flash_plan(2, 128, 4, 4, 32)
+            plan = att.kernel_plan(2, 128, 4, 4)
         # Pure-auto mesh: island engages (size-1 axes absorbed).
         assert plan not in (None, "direct")
         dp_axes, tp_ax, names = plan
         assert names == frozenset({"dp", "tp"})
 
         def probe2(x):
-            seen["manual"] = tr._flash_plan(2, 128, 4, 4, 32)
+            seen["manual"] = att.kernel_plan(2, 128, 4, 4)
             return x
 
         jax.jit(jax.shard_map(probe2, mesh=mesh, in_specs=P(),

@@ -148,15 +148,9 @@ def _flash(kernel):
     if kernel == "flash_bwd":                   # the local backward
         return lambda: jax.grad(lambda q: pk.flash_attention(
             q, q, q, block_q=128, block_k=128).sum())(q)
-    if kernel in ("flash_dq", "flash_dkv"):     # the ring step's two
-        lse = jnp.zeros((1, 2, 128), jnp.float32)
-        return lambda: pk.flash_grad_block(q, q, q, q, q, lse,
-                                           block_q=128, block_k=128)
-    small = lambda q: pk.flash_attention_smallseq(  # noqa: E731
-        q, q, q, heads_per_block=2).sum()
-    if kernel == "flash_smallseq_fwd":
-        return lambda: small(q)
-    return lambda: jax.grad(small)(q)
+    lse = jnp.zeros((1, 2, 128), jnp.float32)   # the ring step's two
+    return lambda: pk.flash_grad_block(q, q, q, q, q, lse,
+                                       block_q=128, block_k=128)
 
 
 def _conv(kernel):
@@ -199,8 +193,7 @@ def _quant(kernel):
 
 KERNEL_SITES = (
     [(_flash, k) for k in ("flash_fwd", "flash_fwd.ring", "flash_bwd",
-                           "flash_dq", "flash_dkv", "flash_smallseq_fwd",
-                           "flash_smallseq_bwd")]
+                           "flash_dq", "flash_dkv")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
     + [(_quant, k) for k in ("quantize", "dequantize", "quantize4",

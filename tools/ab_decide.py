@@ -4,8 +4,6 @@ The rules live in docs/performance.md ("Pending at round-4 close" +
 "Round-5 additions"); this tool turns the latest measured runs into
 explicit verdicts so flipping defaults is mechanical and auditable:
 
-  * smallseq   — best lm_smallseq_hb*_bs128 vs lm_base_bs128_remat;
-                 win => engage `_smallseq_enabled` auto + default HB.
   * xent_chunk — lm_chunk16384_bs128 vs base; win => default 16384.
   * ring       — ring_ab fwd/bwd Pallas speedups at both local shards;
                  both >1 => default HVDT_RING_PALLAS=1.
@@ -57,24 +55,6 @@ def decide(latest):
     out = {}
 
     base = toks(latest, "lm_base_bs128_remat")
-    legs = {hb: toks(latest, f"lm_smallseq_hb{hb}_bs128")
-            for hb in (4, 8, 16)}
-    measured = {hb: t for hb, t in legs.items() if t}
-    if base and measured:
-        best_hb, best = max(measured.items(), key=lambda kv: kv[1])
-        out["smallseq"] = {
-            "baseline_tok_s": base, "per_hb": measured,
-            "best_hb": best_hb, "best_tok_s": best,
-            "speedup": round(best / base, 4),
-            "verdict": ("ENGAGE_AUTO" if best >= base * WIN_MARGIN
-                        else "KEEP_DISENGAGED"),
-            "action": ("set _SMALLSEQ_AUTO_MIN_PROGRAMS (transformer.py) "
-                       f"and default HVDT_FLASH_SMALLSEQ_HB={best_hb}"
-                       if best >= base * WIN_MARGIN else
-                       "record the measured loss in docs/performance.md")}
-    else:
-        out["smallseq"] = {"verdict": "unmeasured"}
-
     chunk = toks(latest, "lm_chunk16384_bs128")
     if chunk and base:
         out["xent_chunk"] = {

@@ -102,23 +102,6 @@ LEGS = [
     # ANSWERED — r4 measured OOM at batch>=32, tools/ab_results.json —
     # and removed; remat "full" is the only feasible bs128 config.)
     lm_leg("lm_base_bs128_remat", ["--batch", "128"]),
-    # Smallseq legs IMMEDIATELY after their baseline: tightest window
-    # for the round's highest-value A/B (the ~0.4-0.7 s/step estimate
-    # standing between 41% and >=50% MFU), and first in line if the
-    # chip answers late in a round.  Baseline to beat: 29,374 tok/s.
-    lm_leg("lm_smallseq_hb8_bs128", ["--batch", "128"],
-           env={"HVDT_FLASH_SMALLSEQ": "on"}),
-    lm_leg("lm_smallseq_hb16_bs128", ["--batch", "128"],
-           env={"HVDT_FLASH_SMALLSEQ": "on",
-                "HVDT_FLASH_SMALLSEQ_HB": "16"}),
-    lm_leg("lm_smallseq_hb4_bs128", ["--batch", "128"],
-           env={"HVDT_FLASH_SMALLSEQ": "on",
-                "HVDT_FLASH_SMALLSEQ_HB": "4"}),
-    # Where does the smallseq step go?  (Shows immediately whether the
-    # wrapper's [B,L,H,D]<->[B,H,L,D] transposes matter.)
-    raw_leg("lm_smallseq_profile_bs128",
-            LM + ["--batch", "128", "--steps", "10", "--profile"],
-            timeout=1200, env={"HVDT_FLASH_SMALLSEQ": "on"}),
     # Where do the non-matmul 45% of the bs128 step go?  3-step XPlane
     # per-category breakdown (examples/jax_transformer_lm.py --profile).
     raw_leg("lm_profile_bs128",

@@ -12,8 +12,9 @@ Two entry points, one tile body (``_online_softmax_update``):
 
 * ``flash_attention(q, k, v)`` — fused causal/full attention for the
   non-ring path (one device holds the whole sequence).  Its forward is
-  one self-contained call (``_flash_local_call``): q, k, v in, ``out`` in
-  the activation dtype and the f32 logsumexp out; the running
+  one self-contained call (``_flash_local_call``): q, k, v in as
+  [B, L, H*D] rows, ``out`` in the same layout and the activation dtype
+  and the f32 logsumexp out; the running
   (acc, row_max, row_sum) lives in VMEM scratch from the first K/V block
   to the last and never touches HBM.  Its backward is one call too
   (``_flash_local_bwd_call``): q, k, v, dO and the two f32 row statistics
@@ -33,12 +34,17 @@ and by nothing a user sets.  Both run in Pallas interpret mode off-TPU,
 so the CPU test suite exercises the very same kernel code
 (tests/test_pallas.py compares against the jnp reference).
 
-Layout: kernels work in [B, H, L, D]; wrappers accept the framework's
-[B, L, H, D] and transpose (XLA folds most of those into the producers'
-layouts).  The local forward's logsumexp leaves as lane-dense rows
-[B, H, 1, L], not as a [B, H, L, 1] column, which HBM pads to 128 lanes.
-GQA/MQA is handled in the BlockSpec index maps (kv head = q head //
-group) — K/V are never materially expanded.
+Layout: the local kernels work on the projections' own [B, L, H*D] rows,
+a bitcast from the framework's [B, L, H, D]: a head, or the group of heads
+that fills 128 lanes, is a block index on the last dimension
+(``_heads_per_program``), and nothing is transposed around the calls; only
+a shape with no such block (H odd at head_dim 64, grouped queries under
+head_dim 128) folds its heads into the batch first (``_rows_layout``).
+The ring kernels work in [B, H, L, D] and their wrappers transpose.  The
+local forward's logsumexp leaves as lane-dense rows [B, H, 1, L], not as a
+[B, H, L, 1] column, which HBM pads to 128 lanes.  GQA/MQA is handled in
+the BlockSpec index maps (kv head = q head // group) — K/V are never
+materially expanded.
 """
 
 from __future__ import annotations
@@ -232,14 +238,44 @@ def _flash_call(q, k, v, acc, m, l, q_offset, k_offset, *, causal, scale,
           q, k, v, acc, m, l)
 
 
+def _head_lanes(x, j, heads: int):
+    """True on the lanes of ``x`` [n, heads * d] that hold head ``j`` (a
+    program id) of the ``heads`` whose block this is."""
+    d = x.shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.logical_and(lane >= j * d, lane < (j + 1) * d)
+
+
+def _keep_head(x, j, heads: int):
+    """``x`` [n, heads * d] with every head's lanes but head ``j``'s
+    zeroed: a product that contracts over the lanes then sees head ``j``
+    alone, and one that produces them leaves exact zeros in the others'.
+    ``x`` itself where the block is one head."""
+    if heads == 1:
+        return x
+    return jnp.where(_head_lanes(x, j, heads), x, jnp.zeros_like(x))
+
+
 def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
                   l_s, *, causal: bool, scale: float, fold_scale: bool,
-                  rows: int, one_tile: bool):
-    """The local (non-ring) forward, grid (b, h, iq, ik) with ik innermost:
-    nothing to carry in and nothing to hand on, so (acc, m, l) are born in
-    VMEM scratch at ik == 0 and die in the flush, which normalises, casts
-    and writes ``out`` [1,1,bq,d] in the activation dtype and the
-    logsumexp as one lane-dense row [1,1,1,bq].
+                  rows: int, one_tile: bool, heads: int):
+    """The local (non-ring) forward, grid (b, head group, iq, head, ik)
+    with ik innermost: nothing to carry in and nothing to hand on, so
+    (acc, m, l) are born in VMEM scratch at ik == 0 and die in the flush,
+    which normalises, casts and writes ``out`` [1,bq,W] in the activation
+    dtype and the logsumexp as one lane-dense row [1,1,1,bq].
+
+    The blocks are ``W = heads * head_dim`` lanes of the projections' own
+    [B, L, H*D] rows: one head where head_dim fills the 128 lanes, else
+    the ``heads`` that together do.  A program works on ONE of them, grid
+    axis ``head``, and the group's programs follow each other, so q, out
+    and (one K/V block a sequence) k, v stay in VMEM from one to the next.
+    Its score product contracts over all W lanes of q with the other
+    heads' zeroed (on a 128-deep MXU the pass a 64-deep product costs
+    too); its p @ v is taken W wide, the other heads' columns ride along
+    in ``acc``, and the flush puts its own lanes into the block the group
+    shares.  (Both heads unrolled in one program cost 12% of the forward
+    and 62% of the backward, whatever the form: PERF.md, PR 29.)
 
     Positions start at 0 on both sides, so which tiles the diagonal
     crosses is known from (iq, ik) alone: only those build a mask.  A tile
@@ -249,15 +285,18 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
     import jax.experimental.pallas as pl
 
     iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    nk = pl.num_programs(3)
-    bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
+    j = pl.program_id(3)
+    ik = pl.program_id(4)
+    nk = pl.num_programs(4)
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
 
     @pl.when(ik == 0)
     def _init():
-        q = q_ref[0, 0, :, :]
-        q_s[...] = (q * scale).astype(q_s.dtype) if fold_scale else q
+        q = q_ref[0, :, :]
+        if fold_scale:
+            q = (q * scale).astype(q_s.dtype)
+        q_s[...] = _keep_head(q, j, heads)
         acc_s[...] = jnp.zeros_like(acc_s)
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
@@ -269,7 +308,7 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
             keys = min(bk, r + rows) if straddles and bq == bk else bk
             chunk = pl.ds(r, rows)
             s = jax.lax.dot_general(
-                q_s[chunk, :], k_ref[0, 0, :keys, :],
+                q_s[chunk, :], k_ref[0, :keys, :],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [rows, keys]
             if not fold_scale:
@@ -281,7 +320,7 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
                         >= ik * bk - iq * bq - r)
             # Key 0 is visible to every row and ik == 0 comes first, so no
             # row meets a masked score with its max still at -1e30.
-            _online_softmax_update(s, v_ref[0, 0, :keys, :],
+            _online_softmax_update(s, v_ref[0, :keys, :],
                                    acc_s.at[chunk], m_s.at[chunk],
                                    l_s.at[chunk], mask)
 
@@ -305,7 +344,13 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
     @pl.when(ik == nk - 1)
     def _flush():
         l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_s[...] / l).astype(o_ref.dtype)
+        out = (acc_s[...] / l).astype(o_ref.dtype)
+        if heads > 1:
+            # The group's heads share the block: the first writes it all,
+            # each later one its own lanes.
+            mine = jnp.logical_or(j == 0, _head_lanes(out, j, heads))
+            out = jnp.where(mine, out, o_ref[0, :, :])
+        o_ref[0, :, :] = out
         lse_ref[0, 0, :, :] = _column_to_row(m_s[...] + jnp.log(l))
 
 
@@ -350,11 +395,14 @@ def _forward_blocks(lq: int, lk: int, head_dim: int, dtype
     d64 bf16, ms a call (PERF.md, PR 25): 4096 x 4096 3.95, 2048 x 2048
     4.70, 1024 x 1024 5.25, 1024 x 2048 6.47, 512 x 1024 (no chunks) 6.49.
 
-    The estimate is fitted to what Mosaic's own account needed at seven
+    The estimate was fitted to what Mosaic's own account needed at seven
     (head_dim, dtype, block) points, found by halving the limit until the
     compile failed: per row of the block, 5 KiB + 13 x head_dim x
     itemsize for the pipelined q/k/v/out blocks and the scratch, plus two
-    f32 score chunks; it is 0-4 MiB above each point up to 4096."""
+    f32 score chunks.  On [B, L, H*D] blocks (PR 29; MiB: d64 bf16 1024 /
+    2048 / 4096 / 8192 blocks 9 / 21 / 35 / 77, d128 bf16 2048 / 4096 21
+    / 34, d64 f32 2048 18) it is 0-8 MiB above each head_dim 64 point and
+    up to 15 above head_dim 128's."""
     per_row = 5 * 1024 + 13 * head_dim * jnp.dtype(dtype).itemsize
     block = max(lq, lk)
     while (block * (per_row + 8 * _chunk_rows(block)) > _FWD_VMEM_BUDGET
@@ -371,35 +419,96 @@ def _scale_folds_exactly(scale: float, dtype) -> bool:
             or math.frexp(scale)[0] == 0.5)
 
 
-def _flash_local_call(q, k, v, *, causal, scale, block_q, block_k,
+def _heads_per_program(heads: int, kv_heads: int, head_dim: int
+                       ) -> Optional[int]:
+    """How many heads one program of the local kernels takes from the
+    [B, L, H*D] rows, from the shape alone: its block is that many
+    head_dims wide and has to be whole 128-lane tiles, or the whole row.
+    One where head_dim is a multiple of 128 (grouped queries then pick
+    their kv head in the index map) or there is one head.  Otherwise q and
+    kv heads have to pair one to one (a group's kv lanes are its q lanes):
+    all of them where the row is no wider than 128 lanes, else 128 /
+    head_dim where that is whole and divides the heads.  None for a shape
+    that has no such block (H odd at head_dim 64, head_dim 96, grouped
+    queries under 128): its callers fold the heads into the batch
+    (``_rows_layout``) and come back with one head."""
+    if head_dim % 128 == 0 or heads == kv_heads == 1:
+        return 1
+    if heads != kv_heads:
+        return None
+    if heads * head_dim <= 128:
+        return heads
+    per = 128 // head_dim
+    return per if per * head_dim == 128 and heads % per == 0 else None
+
+
+def _rows_layout(x, fold: bool):
+    """[B, L, H, D] -> the local kernels' operand.  [B, L, H*D], the
+    layout the projections write and a bitcast from here; or, for a shape
+    ``_heads_per_program`` has no block for (``fold``), [B*H, L, D]: the
+    heads transposed into the batch, one head a row."""
+    b, l, h, d = x.shape
+    if fold:
+        return x.transpose(0, 2, 1, 3).reshape(b * h, l, d)
+    return x.reshape(b, l, h * d)
+
+
+def _heads_layout(x, shape, fold: bool):
+    """``_rows_layout`` undone: -> ``shape`` = [B, L, H, D]."""
+    b, l, h, d = shape
+    if fold:
+        return x.reshape(b, h, l, d).transpose(0, 2, 1, 3)
+    return x.reshape(shape)
+
+
+def _head_blocks(q, k, heads: int, block_q: int, block_k: int):
+    """Of a local call's operands q [B,Lq,H*D], k [Bkv,Lk,Hkv*D]: (heads a
+    block holds, the block's width W, q heads a kv head, q batch rows a kv
+    batch row).  The blocks have to tile both sequences."""
+    d = q.shape[2] // heads
+    hkv = k.shape[2] // d
+    per = _heads_per_program(heads, hkv, d)
+    if q.shape[1] % block_q or k.shape[1] % block_k:
+        raise ValueError(
+            f"seq lens (q={q.shape[1]}, k={k.shape[1]}) must divide block "
+            f"sizes ({block_q}, {block_k})")
+    return per, per * d, heads // hkv, q.shape[0] // k.shape[0]
+
+
+def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
                       rows=None):
-    """The self-contained forward: q [B,H,Lq,D], k/v [B,Hkv,Lk,D] ->
-    (out [B,H,Lq,D] in q.dtype, lse [B,H,1,Lq] f32).  One pallas_call and
-    nothing around it: no carry operand or result (``_flash_call`` keeps
-    those, for the ring), statistics leave as a row, never as [..., 1]."""
+    """The self-contained forward: q [B,Lq,H*D], k/v [Bkv,Lk,Hkv*D] (the
+    projections' own rows, ``heads`` = H) -> (out [B,Lq,H*D] in q.dtype,
+    lse [B,H,1,Lq] f32).  One pallas_call and nothing around it: no carry
+    operand or result (``_flash_call`` keeps those, for the ring), no
+    [B,H,L,D] array, statistics leave as a row, never as [..., 1].
+
+    Grid (b, head group, q tile, head of the group, K/V block); the
+    activations' blocks are (1, block, W) at (b, tile, head group), W =
+    ``_heads_per_program`` head_dims: the head is a block index on the
+    last dimension, and the lanes inside it where a block holds several.
+    B is a multiple of Bkv where the caller folded grouped heads into the
+    batch: q row b reads kv row b // (B / Bkv)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, lq, d = q.shape
-    _, hkv, lk, _ = k.shape
-    group = h // hkv
-    if lq % block_q or lk % block_k:
-        raise ValueError(
-            f"seq lens (q={lq}, k={lk}) must divide block sizes "
-            f"({block_q}, {block_k})")
+    b, lq, _ = q.shape
+    lk = k.shape[1]
+    per, w, group, bgroup = _head_blocks(q, k, heads, block_q, block_k)
 
-    def kv_index(bb, hh, qq, kk):
+    def kv_index(bb, hh, qq, jj, kk):
         if causal:
             # A tile past the diagonal is skipped: name the block that is
             # already resident, so nothing is fetched for it.
             kk = jnp.minimum(kk, ((qq + 1) * block_q - 1) // block_k)
-        return (bb, hh // group, kk, 0)
+        return (bb // bgroup, kk, hh // group)
 
-    qspec = pl.BlockSpec((1, 1, block_q, d),
-                         lambda bb, hh, qq, kk: (bb, hh, qq, 0))
-    kvspec = pl.BlockSpec((1, 1, block_k, d), kv_index)
-    lse_spec = pl.BlockSpec((1, 1, 1, block_q),
-                            lambda bb, hh, qq, kk: (bb, hh, 0, qq))
+    qspec = pl.BlockSpec((1, block_q, w),
+                         lambda bb, hh, qq, jj, kk: (bb, qq, hh))
+    kvspec = pl.BlockSpec((1, block_k, w), kv_index)
+    lse_spec = pl.BlockSpec(
+        (1, 1, 1, block_q),
+        lambda bb, hh, qq, jj, kk: (bb, hh * per + jj, 0, qq))
     kw = _vma_kw(q, k, v)
     with jax.named_scope("hvdt.kernel.flash_fwd"):
         return pl.pallas_call(
@@ -407,15 +516,15 @@ def _flash_local_call(q, k, v, *, causal, scale, block_q, block_k,
                 _local_kernel, causal=causal, scale=scale,
                 fold_scale=_scale_folds_exactly(scale, q.dtype),
                 rows=rows or _chunk_rows(block_q),
-                one_tile=(lq, lk) == (block_q, block_k)),
-            grid=(b, h, lq // block_q, lk // block_k),
+                one_tile=(lq, lk) == (block_q, block_k), heads=per),
+            grid=(b, heads // per, lq // block_q, per, lk // block_k),
             in_specs=[qspec, kvspec, kvspec],
             out_specs=[qspec, lse_spec],
-            out_shape=(jax.ShapeDtypeStruct((b, h, lq, d), q.dtype, **kw),
-                       jax.ShapeDtypeStruct((b, h, 1, lq), jnp.float32,
+            out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype, **kw),
+                       jax.ShapeDtypeStruct((b, heads, 1, lq), jnp.float32,
                                             **kw)),
-            scratch_shapes=[pltpu.VMEM((block_q, d), q.dtype),
-                            pltpu.VMEM((block_q, d), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((block_q, w), q.dtype),
+                            pltpu.VMEM((block_q, w), jnp.float32),
                             pltpu.VMEM((block_q, 1), jnp.float32),
                             pltpu.VMEM((block_q, 1), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
@@ -426,13 +535,21 @@ def _flash_local_call(q, k, v, *, causal, scale, block_q, block_k,
 
 def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                       dk_ref, dv_ref, dq_s, dk_s, dv_s, *, causal: bool,
-                      scale: float, fold_scale: bool, keys: int):
-    """The local (non-ring) backward, grid (b, h, ik, iq) with iq
-    innermost: the K/V block stays while q, dO and the two row statistics
-    stream past it.  dk/dv [bk, d] and the whole sequence's dq [Lq, d]
-    accumulate in f32 VMEM scratch and leave once, in the activation
-    dtype: dk/dv after the block's last q tile, dq after the last tile of
-    the (b, h) pair.
+                      scale: float, fold_scale: bool, keys: int, heads: int):
+    """The local (non-ring) backward, grid (b, head group, ik, head, iq)
+    with iq innermost: the K/V block stays while q, dO and the two row
+    statistics stream past it, once for each head of the group.  dk/dv
+    [bk, W] and the whole sequence's dq [Lq, W] accumulate in f32 VMEM
+    scratch and leave once, in the activation dtype: dk/dv after the last
+    q tile of the block's last head, dq after the last tile of the (b,
+    head group) pair.
+
+    The blocks are W = heads * head_dim lanes of the [B, L, H*D] rows, as
+    the forward's, and a program works on one head of them.  One
+    accumulator serves the group: head j's products take q, dO and k with
+    the other heads' lanes zeroed, so the two that contract over the lanes
+    (k q^T, v dO^T) see head j alone and the three that produce them (p^T
+    dO, ds^T q, ds k) add exact zeros to the other heads' columns.
 
     The score tile is worked TRANSPOSED, s^T = k q^T [keys, rows]: the
     logsumexp and delta = rowsum(dO * out) then enter as lane-dense rows
@@ -448,17 +565,18 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     import jax.experimental.pallas as pl
 
     ik = pl.program_id(2)
-    iq = pl.program_id(3)
+    j = pl.program_id(3)
+    iq = pl.program_id(4)
     nk = pl.num_programs(2)
-    nq = pl.num_programs(3)
-    bq = q_ref.shape[2]
-    bk = k_ref.shape[2]
+    nq = pl.num_programs(4)
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
     nt = (((1,), (1,)), ((), ()))                 # a b^T
     nn = (((1,), (0,)), ((), ()))                 # a b
     tn = (((0,), (0,)), ((), ()))                 # a^T b
     f32 = jnp.float32
 
-    @pl.when(iq == 0)
+    @pl.when(jnp.logical_and(iq == 0, j == 0))
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
@@ -468,18 +586,20 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             dq_s[...] = jnp.zeros_like(dq_s)
 
     def _tile(straddles: bool):
-        q_all = q_ref[0, 0, :, :]
+        q_all = q_ref[0, :, :]
         if fold_scale:
             q_all = (q_all * scale).astype(q_all.dtype)
+        q_all = _keep_head(q_all, j, heads)
+        do_all = _keep_head(do_ref[0, :, :], j, heads)
         row0 = pl.multiple_of(iq * bq, bq)
         for c in range(0, bk, keys):
             # bq == bk puts a straddling tile on the diagonal (iq == ik):
             # keys c.. are seen by no row before c.
             r = c if straddles and bq == bk else 0
             chunk = pl.ds(c, keys)
-            k = k_ref[0, 0, chunk, :]
+            k = k_ref[0, chunk, :]
             q = q_all[r:]
-            do = do_ref[0, 0, r:, :]
+            do = do_all[r:]
             st = jax.lax.dot_general(k, q, nt, preferred_element_type=f32)
             if not fold_scale:
                 st = st * scale
@@ -492,7 +612,7 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             # The saved logsumexp is finite, so a masked score's exp is an
             # exact 0 and p needs no second select.
             pt = jnp.exp(st - lse_ref[0, 0, :, r:])          # [keys, rows]
-            dpt = jax.lax.dot_general(v_ref[0, 0, chunk, :], do, nt,
+            dpt = jax.lax.dot_general(v_ref[0, chunk, :], do, nt,
                                       preferred_element_type=f32)
             dst = (pt * (dpt - dl_ref[0, 0, :, r:])).astype(q.dtype)
             dv_s[chunk, :] += jax.lax.dot_general(
@@ -500,7 +620,8 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             dk_s[chunk, :] += jax.lax.dot_general(
                 dst, q, nn, preferred_element_type=f32)
             dq_s[pl.ds(row0 + r, bq - r), :] += jax.lax.dot_general(
-                dst, k, tn, preferred_element_type=f32)
+                dst, _keep_head(k, j, heads), tn,
+                preferred_element_type=f32)
 
     if not causal:
         # ik >= 0 always: the cond is there for interpret mode under
@@ -513,17 +634,17 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         pl.when(jnp.logical_and(visited, jnp.logical_not(visible)))(
             lambda: _tile(True))
 
-    @pl.when(iq == nq - 1)
+    @pl.when(jnp.logical_and(iq == nq - 1, j == heads - 1))
     def _flush():
         # s was computed from q * scale where that folds, so dk = ds^T
         # (q * scale) carries the factor already; dq = scale * ds k never.
         dk = dk_s[...] if fold_scale else dk_s[...] * scale
-        dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_s[...].astype(dv_ref.dtype)
+        dk_ref[0, :, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, :, :] = dv_s[...].astype(dv_ref.dtype)
 
         @pl.when(ik == nk - 1)
         def _():
-            dq_ref[0, 0, :, :] = (dq_s[...] * scale).astype(dq_ref.dtype)
+            dq_ref[0, :, :] = (dq_s[...] * scale).astype(dq_ref.dtype)
 
 
 # The backward's blocks stop here: at b8 h16 L4096 d64 bf16 on the v5e,
@@ -536,8 +657,9 @@ def _backward_blocks(lq: int, lk: int, head_dim: int, dtype
                      ) -> Optional[Tuple[int, int]]:
     """(block_q, block_k) for the local backward, from the shape and VMEM
     alone; None where the kernel cannot take the shape: the whole
-    sequence's f32 dq, which it keeps in VMEM, does not fit beside the
-    smallest blocks (from seq 131,072 at head_dim 64 in bf16).
+    sequence's f32 dq, which it keeps in VMEM a head group wide, does not
+    fit beside the smallest blocks (from seq 65,536 in bf16 up to
+    head_dim 128).
 
     Square, and as large as fits up to ``_BWD_BLOCK_MOST``: a square tile
     is the one whose diagonal chunks can start at their own first row, and
@@ -547,18 +669,21 @@ def _backward_blocks(lq: int, lk: int, head_dim: int, dtype
 
     The estimate is fitted to what Mosaic's own account needed at eleven
     (seq, head_dim, dtype, block) points, found by halving the limit until
-    the compile for the v5e failed (MiB, seq 4096: d64 bf16 512 / 1024 /
-    2048 / 4096 blocks 4 / 6 / 11 / 17, d128 bf16 1024 / 2048 / 4096 11 /
-    18 / 23, d64 f32 1024 / 2048 7 / 11; d64 bf16 seq 8192 at 2048 13, seq
-    16384 at 1024 12): the dq scratch and its output block twice, and per
-    row of the block 48 x head_dim bytes for the pipelined q/k/v/dO/dk/dv
-    blocks, the dk/dv scratch and the three products, plus two f32 score
-    chunks; 0-2 MiB above each point up to 2048."""
+    the compile for the v5e failed (PR 29, on [B, L, H*D] blocks; MiB, seq
+    4096: d64 bf16 512 / 1024 / 2048 / 4096 blocks 7 / 11 / 18 / 30, d128
+    bf16 1024 / 2048 / 4096 11 / 17 / 23, d64 f32 1024 / 2048 16 / 27; d64
+    bf16 seq 8192 at 2048 22, seq 16384 at 1024 23).  With W the block's
+    lanes (head_dim in whole 128s): the dq scratch and its output block
+    twice, and per row of the block (14 x itemsize + 12) x W bytes for the
+    pipelined q/k/v/dO/dk/dv blocks, the dk/dv scratch, the head's q and
+    dO and the products, plus two f32 score chunks; 0-2 MiB above each
+    point (9 at d128's 4096)."""
     itemsize = jnp.dtype(dtype).itemsize
-    whole = lq * head_dim * (4 + 2 * itemsize)
+    lanes = -(-head_dim // 128) * 128
+    whole = lq * lanes * (4 + 2 * itemsize)
 
     def need(block):
-        return whole + block * (48 * head_dim
+        return whole + block * ((14 * itemsize + 12) * lanes
                                 + 8 * _chunk_rows(block, 256))
 
     block = min(max(lq, lk), _BWD_BLOCK_MOST)
@@ -569,23 +694,21 @@ def _backward_blocks(lq: int, lk: int, head_dim: int, dtype
     return _fit_block(lq, block, dtype), _fit_block(lk, block, dtype)
 
 
-def _flash_local_bwd_call(q, k, v, do, lse, delta, *, causal, scale,
+def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
                           block_q, block_k, keys=None):
-    """The self-contained backward: q, dO [B,H,Lq,D], k, v [B,Hkv,Lk,D],
-    lse and delta f32 rows [B,H,1,Lq] -> (dq [B,H,Lq,D], dk, dv
-    [B,H,Lk,D]) in the operands' dtypes, dk/dv per q head (a GQA caller
-    sums its group).  One pallas_call: nothing f32 of the sequence's size
-    and nothing with a trailing dimension of 1 goes in or comes out."""
+    """The self-contained backward: q, dO [B,Lq,H*D], k, v [Bkv,Lk,Hkv*D]
+    (``heads`` = H; the forward's layout and blocks), lse and delta f32
+    rows [B,H,1,Lq] -> (dq [B,Lq,H*D], dk, dv [B,Lk,H*D]) in the operands'
+    dtypes, dk/dv per q head (a GQA caller sums its group).  One
+    pallas_call, grid (b, head group, K/V block, head of the group, q
+    tile): nothing f32 of the sequence's size, nothing with a trailing
+    dimension of 1 and no [B,H,L,D] array goes in or comes out."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, lq, d = q.shape
-    _, hkv, lk, _ = k.shape
-    group = h // hkv
-    if lq % block_q or lk % block_k:
-        raise ValueError(
-            f"seq lens (q={lq}, k={lk}) must divide block sizes "
-            f"({block_q}, {block_k})")
+    b, lq, width = q.shape
+    lk = k.shape[1]
+    per, w, group, bgroup = _head_blocks(q, k, heads, block_q, block_k)
 
     def q_tile(kk, qq):
         if causal:
@@ -595,31 +718,33 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, causal, scale,
                                              lq // block_q - 1))
         return qq
 
-    qspec = pl.BlockSpec((1, 1, block_q, d),
-                         lambda bb, hh, kk, qq: (bb, hh, q_tile(kk, qq), 0))
+    qspec = pl.BlockSpec((1, block_q, w),
+                         lambda bb, hh, kk, jj, qq: (bb, q_tile(kk, qq), hh))
     row = pl.BlockSpec((1, 1, 1, block_q),
-                       lambda bb, hh, kk, qq: (bb, hh, 0, q_tile(kk, qq)))
-    kvspec = pl.BlockSpec((1, 1, block_k, d),
-                          lambda bb, hh, kk, qq: (bb, hh // group, kk, 0))
-    dqspec = pl.BlockSpec((1, 1, lq, d), lambda bb, hh, kk, qq: (bb, hh, 0, 0))
-    dkvspec = pl.BlockSpec((1, 1, block_k, d),
-                           lambda bb, hh, kk, qq: (bb, hh, kk, 0))
+                       lambda bb, hh, kk, jj, qq: (bb, hh * per + jj, 0,
+                                                   q_tile(kk, qq)))
+    kvspec = pl.BlockSpec(
+        (1, block_k, w),
+        lambda bb, hh, kk, jj, qq: (bb // bgroup, kk, hh // group))
+    dqspec = pl.BlockSpec((1, lq, w), lambda bb, hh, kk, jj, qq: (bb, 0, hh))
+    dkvspec = pl.BlockSpec((1, block_k, w),
+                           lambda bb, hh, kk, jj, qq: (bb, kk, hh))
     kw = _vma_kw(q, k, v, do, lse, delta)
     with jax.named_scope("hvdt.kernel.flash_bwd"):
         return pl.pallas_call(
             functools.partial(
                 _local_bwd_kernel, causal=causal, scale=scale,
                 fold_scale=_scale_folds_exactly(scale, q.dtype),
-                keys=keys or _chunk_rows(block_k, 256)),
-            grid=(b, h, lk // block_k, lq // block_q),
+                keys=keys or _chunk_rows(block_k, 256), heads=per),
+            grid=(b, heads // per, lk // block_k, per, lq // block_q),
             in_specs=[qspec, kvspec, kvspec, qspec, row, row],
             out_specs=[dqspec, dkvspec, dkvspec],
-            out_shape=(jax.ShapeDtypeStruct((b, h, lq, d), q.dtype, **kw),
-                       jax.ShapeDtypeStruct((b, h, lk, d), k.dtype, **kw),
-                       jax.ShapeDtypeStruct((b, h, lk, d), v.dtype, **kw)),
-            scratch_shapes=[pltpu.VMEM((lq, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+            out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype, **kw),
+                       jax.ShapeDtypeStruct((b, lk, width), k.dtype, **kw),
+                       jax.ShapeDtypeStruct((b, lk, width), v.dtype, **kw)),
+            scratch_shapes=[pltpu.VMEM((lq, w), jnp.float32),
+                            pltpu.VMEM((block_k, w), jnp.float32),
+                            pltpu.VMEM((block_k, w), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_FWD_VMEM_LIMIT),
             interpret=_use_interpret(),
@@ -636,10 +761,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Differentiable (pallas_call has no autodiff rule of its own): the
     forward is one Pallas call, q, k, v -> (out, logsumexp), and the
     backward another, the standard flash gradient with the score tile
-    recomputed from the saved logsumexp, which makes it exact; nothing
-    around either but the layout moves and delta = rowsum(dO * out).  No
-    [B,H,Lq,Lk] array exists on either side (the property that makes
-    long-context training fit in HBM at all).
+    recomputed from the saved logsumexp, which makes it exact.  Both take
+    and return [B, L, H*D], the layout the projections write and a
+    reshape from here, so nothing stands around either but delta =
+    rowsum(dO * out); only a shape ``_heads_per_program`` has no block for
+    is transposed, heads into the batch.  No [B,H,Lq,Lk] array exists on
+    either side (the property that makes long-context training fit in HBM
+    at all).
 
     ``block_q`` / ``block_k`` default to :func:`_forward_blocks`' choice
     for the shape; a test passes its own to meet a given tiling.
@@ -658,11 +786,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k):
     """Kernel forward returning (out [B,L,H,D], lse [B,H,1,Lq]): the
     logsumexp as the row the kernel writes and the backward reads."""
+    b, lq, h, d = q.shape
+    fold = _heads_per_program(h, k.shape[2], d) is None
     out, lse = _flash_local_call(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=causal, scale=scale,
+        *(_rows_layout(x, fold) for x in (q, k, v)),
+        heads=1 if fold else h, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k)
-    return out.transpose(0, 2, 1, 3), lse
+    return _heads_layout(out, q.shape, fold), lse.reshape(b, h, 1, lq)
 
 
 def _flash_fwd_core(q, k, v, causal, scale, block_q, block_k):
@@ -683,12 +813,12 @@ def _flash_attn_fwd(q, k, v, causal, scale, block_q, block_k):
 
 
 def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
-    """The local backward: one Pallas call (``_flash_local_bwd_call``)
-    between the layout moves, delta = rowsum(dO * out) computed beside it
-    as a row.  A test's own forward blocks bound the backward's too, so a
-    given tiling is met on both sides.  Only a sequence whose f32 dq does
-    not fit in VMEM (``_backward_blocks`` is None) takes the blockwise
-    XLA backward."""
+    """The local backward: one Pallas call (``_flash_local_bwd_call``) on
+    the forward's operand layout, delta = rowsum(dO * out) computed beside
+    it as a row.  A test's own forward blocks bound the backward's too, so
+    a given tiling is met on both sides.  Only a sequence whose f32 dq
+    does not fit in VMEM (``_backward_blocks`` is None) takes the
+    blockwise XLA backward."""
     q, k, v, out, lse = res
     b, lq, h, d = q.shape
     lk, hkv = k.shape[1], k.shape[2]
@@ -698,18 +828,21 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
                                     (q, k, v, out, lse[:, :, 0, :]), do)
     delta = jnp.einsum("bqhd,bqhd->bhq", do, out,
                        preferred_element_type=jnp.float32)[:, :, None, :]
+    fold = _heads_per_program(h, hkv, d) is None
+    heads = 1 if fold else h
     dq, dk, dv = _flash_local_bwd_call(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), do.transpose(0, 2, 1, 3), lse, delta,
-        causal=causal, scale=scale,
+        *(_rows_layout(x, fold) for x in (q, k, v, do)),
+        *(x.reshape(b * h // heads, heads, 1, lq) for x in (lse, delta)),
+        heads=heads, causal=causal, scale=scale,
         block_q=_fit_block(lq, min(block_q, blocks[0]), q.dtype),
         block_k=_fit_block(lk, min(block_k, blocks[1]), k.dtype, v.dtype))
+    dq, dk, dv = (_heads_layout(x, (b, x.shape[1], h, d), fold)
+                  for x in (dq, dk, dv))
     if h != hkv:
         # dk/dv leave per q head: sum each kv head's group, in f32.
-        dk, dv = (x.reshape(b, hkv, h // hkv, lk, d).astype(jnp.float32)
-                  .sum(2).astype(x.dtype) for x in (dk, dv))
-    return (dq.transpose(0, 2, 1, 3), dk.transpose(0, 2, 1, 3),
-            dv.transpose(0, 2, 1, 3))
+        dk, dv = (x.reshape(b, lk, hkv, h // hkv, d).astype(jnp.float32)
+                  .sum(3).astype(x.dtype) for x in (dk, dv))
+    return dq, dk, dv
 
 
 def _flash_bwd_blockwise(causal, scale, block_q, block_k, res, do):

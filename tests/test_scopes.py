@@ -235,9 +235,11 @@ def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
     no chip): exactly three Mosaic calls per layer.  Two are the forward
     and its recompute, each q, k, v -> (out in the activation dtype, lse
     as a row).  One is the backward, q, k, v, dO and the two f32 row
-    statistics (lse, delta) -> dq, dk, dv in the activation dtype.  No
-    running (acc, m, l), no ``[..., 1]`` column and no f32 array of the
-    sequence's size goes in or comes out of any of them, and nothing in
+    statistics (lse, delta) -> dq, dk, dv in the activation dtype.  The
+    activations are the projections' own [B, L, H*D] rows (PR 29): no
+    [B, H, L, D] array, no running (acc, m, l), no ``[..., 1]`` column and
+    no f32 array of the sequence's size goes in or comes out of any of
+    them, and nothing in
     the attention backward is a loop or a ``dynamic_update_slice``: the
     blockwise XLA backward is not there.  The benchmark's ``flash_fwd_ms``
     and ``flash_fwd_roofline`` count every Mosaic call of the step, the
@@ -262,7 +264,7 @@ def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
         loc = re.search(r"loc\((#loc\d+)\)$", line).group(1)
         return re.search(rf"^{loc} = loc\((.*)$", text, re.M).group(1)
 
-    act, row = "tensor<2x2x256x64xbf16>", "tensor<2x2x1x256xf32>"
+    act, row = "tensor<2x256x128xbf16>", "tensor<2x2x1x256xf32>"
     types = {
         "flash_fwd": rf"\(({act}, ){{2}}{act}\) -> \({act}, {row}\).*",
         "flash_bwd": rf"\(({act}, ){{4}}{row}, {row}\) -> "
@@ -276,9 +278,49 @@ def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
         calls[kernel] += 1
         signature = line.rsplit(" : ", 1)[1]
         assert re.fullmatch(types[kernel], signature), line
-        assert not re.search(r"x1x(f32|bf16)>|x256x64xf32>", signature)
+        assert not re.search(r"x1x(f32|bf16)>|x256x\d+xf32>|x256x64x",
+                             signature)
     assert calls == {"flash_fwd": 2 * cfg.layers, "flash_bwd": cfg.layers}
     # Every operation's name stack is a location of the text: none under
     # the attention scope is a loop, inside one, or a dynamic_update_slice.
     assert not re.search(
         r'loc\("[^"]*hvdt\.attention/[^"]*(while|dynamic_update_slice)', text)
+
+
+
+def _primitives_outside_kernels(jaxpr):
+    """Names of the primitives of a jaxpr and of everything it calls, the
+    bodies of its ``pallas_call``s left out (a kernel transposes tiles in
+    VMEM; the question here is what XLA is asked to move in HBM)."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _primitives_outside_kernels(sub)
+    return names
+
+
+@pytest.mark.parametrize("heads, kv_heads, head_dim, transposes", [
+    (4, 4, 64, False), (2, 1, 128, False), (3, 3, 64, True)],
+    ids=["h4_d64_pairs", "h2_d128_gqa", "h3_d64_folded"])
+def test_flash_attention_moves_no_layout_around_its_calls(
+        heads, kv_heads, head_dim, transposes):
+    """The counter that the [B, L, H*D] kernel layout engages (PR 29): at
+    b2 L256 the jaxpr of flash_attention and of its gradient is one
+    forward call and one backward call with reshapes (bitcasts) around
+    them and no ``transpose``, for head_dim 64 in pairs and head_dim 128
+    alike.  A shape with no 128-lane block (H odd at head_dim 64) keeps
+    the transposed route, heads folded into the batch, and shows them."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    q = jnp.ones((2, 256, heads, head_dim), jnp.bfloat16)
+    kv = jnp.ones((2, 256, kv_heads, head_dim), jnp.bfloat16)
+    assert (pk._heads_per_program(heads, kv_heads, head_dim) is None
+            ) == transposes
+    names = _primitives_outside_kernels(jax.make_jaxpr(
+        lambda q, k, v, do: jax.vjp(pk.flash_attention, q, k, v)[1](do))(
+            q, kv, kv, q).jaxpr)
+    assert names.count("pallas_call") == 2
+    assert ("transpose" in names) == transposes

@@ -381,6 +381,18 @@ def peak_hbm_bytes(memory) -> int:
             + memory.temp_size_in_bytes - 2 * memory.alias_size_in_bytes)
 
 
+def fullest_device_bytes(devices) -> Dict[str, int]:
+    """``bytes_in_use``, ``bytes_reserved`` and the process's
+    ``peak_bytes_in_use`` so far on the device that has most of the first
+    two together, as the runtime accounts them now ({} where the backend
+    keeps no such account)."""
+    stats = [d.memory_stats() or {} for d in devices]
+    fullest = max(stats, key=lambda s: s.get("bytes_in_use", 0)
+                  + s.get("bytes_reserved", 0))
+    return {k: fullest[k] for k in ("bytes_in_use", "bytes_reserved",
+                                    "peak_bytes_in_use") if k in fullest}
+
+
 def run_cell(cell: Dict[str, Any], devices, *, seed: int, seconds: float,
              trace: bool, started_at: float, trace_dir: str
              ) -> Dict[str, Any]:
@@ -417,9 +429,16 @@ def run_cell(cell: Dict[str, Any], devices, *, seed: int, seconds: float,
     k_init, k_pool, k_sample = jax.random.split(key, 3)
     params = jax.block_until_ready(training.init_params(k_init))
     phase("weights")
+    # What the runtime accounts on the fullest device when the reference
+    # check starts (the weights; the loaded step program) and once it is
+    # over, the peak so far with it: what a larger configuration's check
+    # is sized against.
+    before_check = fullest_device_bytes(devices)
     checks: Dict[str, Any] = {
         "reference": reference_check(family, params, k_sample, devices[0],
                                      mosaic)}
+    checks["reference"]["device_bytes"] = {
+        "before": before_check, "after": fullest_device_bytes(devices)}
     phase("reference check")
     if "dp_check" in cell:
         checks["dp"] = dp_check(family, devices, cell["dp_check"], seed)

@@ -1,12 +1,12 @@
 """Kernels: device time per step in the flash-attention forward's Mosaic
-custom call (device trace).  Today the forward is the step's only Mosaic
-call, so every Mosaic event is counted (PERF.md section 3); the split by
-kernel name waits for the `tracing` issue.  Moves ``tokens_per_s_chip``
-where the kernel is selected."""
+calls, found by the name the program gives them (``hvdt.kernel.flash_fwd``:
+the forward and the recompute of every layer; device trace joined to the
+compiled step's ``op_name``s, ``benchmark/phase_split.py``).  Moves
+``tokens_per_s_chip`` where the kernel is selected."""
 
-from benchmark import trace_reduce
-from benchmark.layer_metrics import per_step
+from benchmark.phase_split import scope_calls
+from benchmark.trace_reduce import is_mosaic
 
 
 def read(ctx):
-    return per_step(ctx, trace_reduce.is_mosaic)[0]
+    return scope_calls(ctx, "hvdt.kernel.flash_fwd", is_mosaic)[0]

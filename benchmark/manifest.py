@@ -4,8 +4,9 @@ use it before any device is touched.
 
     BENCHMARK.json                      workloads[], configs[], metrics
     benchmark/workloads/<cell>.json     one cell: chips, traffic, warm-up,
-                                        traced steps, its per-layer metrics
-    <configs[].file>                    one configuration: family + sizes
+                                        traced steps
+    <configs[].file>                    one configuration: family + sizes,
+                                        what was cut from the source
     benchmark/families/<family>.py      build(config, traffic) -> Family
     benchmark/layer_metrics/<reader>.py read(ctx) -> number | None
     benchmark/peaks.json                device_kind -> published peaks
@@ -37,11 +38,55 @@ def load_manifest(root: str = ROOT) -> dict:
     return _read_json(os.path.join(root, "BENCHMARK.json"), "the manifest")
 
 
+def check_config(entry: dict, data: dict) -> None:
+    """The configuration contract (``benchmark/README.md``), between a
+    ``configs`` entry of the manifest and its file's contents: one
+    ``source``; one ``reduced``, a list of distinct keys of the file;
+    where something is cut, the source's value of each cut key under
+    ``published`` (those keys and no others) and the deployment the cut
+    stands for, in one line, under ``deployment``."""
+    name = entry["name"]
+
+    def refuse(what: str):
+        raise ManifestError(f"config {name!r} ({entry['file']}): {what}")
+
+    if data.get("source") != entry["source"]:
+        refuse(f"source is {data.get('source')!r} in its file and "
+               f"{entry['source']!r} in BENCHMARK.json")
+    reduced = data.get("reduced")
+    if reduced != entry["reduced"]:
+        refuse(f"reduced is {reduced!r} in its file and "
+               f"{entry['reduced']!r} in BENCHMARK.json")
+    if len(set(reduced)) != len(reduced):
+        refuse(f"reduced names a key twice: {reduced}")
+    for key in ("family", "assumed"):
+        if key not in data:
+            refuse(f"no {key!r} in its file")
+    absent = [k for k in reduced if k not in data]
+    if absent:
+        refuse(f"reduced names keys the file does not have: {absent}")
+    if not reduced:
+        return
+    published = data.get("published", {})
+    if sorted(published) != sorted(reduced):
+        refuse("published must give the source's value of exactly the "
+               f"keys under reduced ({sorted(reduced)}); it has "
+               f"{sorted(published)}")
+    deployment = data.get("deployment", "")
+    if not (isinstance(deployment, str) and 1 <= len(deployment) <= 200
+            and "\n" not in deployment and "\t" not in deployment):
+        refuse("a cut configuration states its deployment (over how many "
+               "chips each layer is divided, and how) in one line of at "
+               "most 200 characters")
+
+
 def load_cell(name: str, root: str = ROOT) -> dict:
     """The cell ``name``: its manifest entry merged over its own file,
-    with its configuration under ``config_data``, the end-to-end metrics
-    it reports under ``end_to_end`` (names; its per-layer metrics are its
-    file's ``layer_metrics``) and every metric's unit under ``units``."""
+    with its configuration under ``config_data``, and what it reports, in
+    the manifest's order: the end-to-end metrics under ``end_to_end`` and
+    the per-layer metrics under ``layer_metrics`` (names: the entries
+    whose ``workloads`` holds the cell, or that have no ``workloads``),
+    every metric's unit under ``units``."""
     manifest = load_manifest(root)
     entries = [w for w in manifest["workloads"] if w["name"] == name]
     if not entries:
@@ -69,18 +114,15 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     cell["config_data"] = _read_json(
         os.path.join(root, configs[0]["file"]),
         f"config {cell['config']!r}")
+    check_config(configs[0], cell["config_data"])
 
     def reported(metric):
         return name in metric.get("workloads", [name])
 
     cell["end_to_end"] = [m["name"] for m in manifest["end_to_end"]
                           if reported(m)]
-    declared = {m["name"]: m for m in manifest["per_layer"] if reported(m)}
-    missing = [m for m in cell["layer_metrics"] if m not in declared]
-    if missing:
-        raise ManifestError(
-            f"workload {name!r} lists per-layer metrics that BENCHMARK.json "
-            f"does not give it: {missing}")
+    cell["layer_metrics"] = [m["name"] for m in manifest["per_layer"]
+                             if reported(m)]
     cell["units"] = {m["name"]: m["unit"] for m in
                      manifest["end_to_end"] + manifest["per_layer"]}
     return cell

@@ -45,7 +45,7 @@ from __future__ import annotations
 import collections
 import functools
 import re
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from benchmark import trace_reduce
 from benchmark.layer_metrics import devices, steps_on
@@ -189,13 +189,17 @@ def split(dev: trace_reduce.DeviceTrace, names: Dict[str, str]
     return {which: 1e3 * seconds / steps for which, seconds in out.items()}
 
 
-def scope_ms(dev: trace_reduce.DeviceTrace, names: Dict[str, str],
-             scope: str, pick=lambda op: True) -> Optional[float]:
-    """Milliseconds per step of the leaf events under ``scope`` that
-    ``pick`` accepts, in whatever phase; None where there are none."""
+def scope_per_step(dev: trace_reduce.DeviceTrace, names: Dict[str, str],
+                   scope: str, pick=lambda op: True
+                   ) -> Tuple[Optional[float], Optional[float]]:
+    """(milliseconds, events) per step of the leaf events under ``scope``
+    that ``pick`` accepts, in whatever phase; (None, None) where there
+    are none."""
     picked = [op.seconds for op in dev.leaves
               if pick(op) and has_scope(names.get(op.name, ""), scope)]
-    return 1e3 * sum(picked) / steps_on(dev) if picked else None
+    if not picked:
+        return None, None
+    return 1e3 * sum(picked) / steps_on(dev), len(picked) / steps_on(dev)
 
 
 # ---------------------------------------------------------------------------
@@ -226,8 +230,16 @@ def phase_ms(ctx, which: str) -> Optional[float]:
     return split(dev, names)[which] if dev else None
 
 
-def scope_metric(ctx, scope: str, pick=lambda op: True) -> Optional[float]:
+def scope_calls(ctx, scope: str, pick=lambda op: True
+                ) -> Tuple[Optional[float], Optional[float]]:
+    """(milliseconds, events) per step under ``scope`` on that same
+    device: what ``layer_metrics.per_step`` gives for a predicate, for a
+    name the program gave."""
+    dev, names = _slowest(ctx)
+    return scope_per_step(dev, names, scope, pick) if dev else (None, None)
+
+
+def scope_metric(ctx, scope: str) -> Optional[float]:
     """Per-layer metric: milliseconds per step under ``scope`` on that
     same device."""
-    dev, names = _slowest(ctx)
-    return scope_ms(dev, names, scope, pick) if dev else None
+    return scope_calls(ctx, scope)[0]

@@ -14,7 +14,8 @@ sys.path.insert(0, REPO)
 from benchmark import manifest  # noqa: E402
 from benchmark.families import resnet, transformer_lm  # noqa: E402
 from benchmark.layer_metrics import (allreduce_bytes, conv_roofline,  # noqa: E402
-                                     flash_fwd_roofline, roofline)
+                                     flash_bwd_call_cost,
+                                     flash_fwd_call_cost, roofline)
 
 
 def _config(name):
@@ -58,13 +59,24 @@ def test_resnet50_has_53_convolutions_and_4_09_gmacs_forward():
     assert resnet.flops_per_image(cfg) == 2 * (3 * macs - stem)
 
 
-def test_flash_forward_call_cost_at_the_cells_shape():
-    ops, nbytes = flash_fwd_roofline.call_cost(8, 4096, 16, 64)
-    assert ops == 2 * 8 * 16 * 4096 * 4096 * 64        # 275 GFLOP
-    assert nbytes == 4 * 8 * 4096 * 1024 * 2           # 268 MB
+# The seq-4096 cell's calls: b8 h16 L4096 d64, bf16, causal.  A product
+# over the causal half of the score square is 8*16*4096*4096*64 = 137.4
+# GFLOP; a [B, L, H*D] tensor is 67.1 MB.
+@pytest.mark.parametrize("call_cost, products, tensors, gflop, mb, ms", [
+    (flash_fwd_call_cost, 2, 4, 275, 268, 1.395),   # s, p.v; q k v | o
+    (flash_bwd_call_cost, 5, 7, 687, 470, 3.488),   # s, dp, dv, dq, dk;
+])                                                  # q k v dO | dq dk dv
+def test_flash_call_cost_at_the_cells_shape(call_cost, products, tensors,
+                                            gflop, mb, ms):
+    ops, nbytes = call_cost(8, 4096, 16, 64)
+    assert ops == products * 8 * 16 * 4096 * 4096 * 64
+    assert nbytes == tensors * 8 * 4096 * 1024 * 2
+    assert ops / 1e9 == pytest.approx(gflop, abs=0.5)
+    assert nbytes / 1e6 == pytest.approx(mb, abs=0.5)
     least, bound = roofline(ops, nbytes, manifest.load_peaks("TPU v5 lite"))
     assert bound == "compute"
     assert least == pytest.approx(ops / 197e12)
+    assert 1e3 * least == pytest.approx(ms, abs=1e-3)
 
 
 def test_conv_roofline_least_time_is_hbm_bound_overall():

@@ -148,10 +148,31 @@ def test_run_cell_at_toy_size(hvd, devices, v5e_peaks, cell, unit, tmp_path):
     assert all(math.isfinite(m["value"]) and m["value"] > 0
                for m in result["metrics"].values())
     assert result["device"]["platform"] == "cpu"
+    # What the reference check found on the device and what it left (the
+    # CPU keeps no such account: the readings are there and empty).
+    assert set(result["checks"]["reference"]["device_bytes"]) == {
+        "before", "after"}
     if cell.endswith("dp4"):
         assert result["checks"]["dp"]["ok"]
         assert len(result["checks"]["dp"]["dp"]) == 4
     json.dumps(result)                  # the line is serialisable
+
+
+def test_the_fullest_devices_bytes_are_the_ones_recorded():
+    class Device:
+        def __init__(self, **stats):
+            self.stats = stats or None
+
+        def memory_stats(self):
+            return self.stats
+
+    full = dict(bytes_in_use=6, bytes_reserved=5, peak_bytes_in_use=9,
+                bytes_limit=16)
+    devices = [Device(bytes_in_use=7, bytes_reserved=1, peak_bytes_in_use=8),
+               Device(**full), Device()]
+    assert harness.fullest_device_bytes(devices) == {
+        "bytes_in_use": 6, "bytes_reserved": 5, "peak_bytes_in_use": 9}
+    assert harness.fullest_device_bytes([Device()]) == {}
 
 
 def test_run_window_keeps_the_host_two_steps_ahead_at_most():

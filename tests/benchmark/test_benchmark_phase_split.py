@@ -1,22 +1,22 @@
-"""The phase split (``benchmark/phase_split.py``) and the nine readers
-over it, on a second small recorded trace with a matching, hand-written
-HLO text (``data/phase_trace.json``, ``data/phase_step.hlo.txt``).  CPU
-only; no profiler and no device is touched.
+"""The phase split (``benchmark/phase_split.py``) and the readers over it
+(the six phases, two model scopes, the two flash kernels with their
+roofline shares), on a second small recorded trace with a matching,
+hand-written HLO text (``data/phase_trace.json``,
+``data/phase_step.hlo.txt``).  CPU only; no profiler and no device is
+touched.
 
 Per step of 100 ms on device 0 (device 1 runs the same step in 90): a
-forward ``while`` (attention matmul 12, flash kernel 8, MLP matmul 10),
-the loss forward 5, a backward ``while`` (attention recompute 10, the
-kernel's recompute 8, an attention weight-gradient fusion with no name of
-its own 12, a nameless copy 3, MLP backward 8, attention backward 4), the
+forward ``while`` (attention matmul 12, flash forward kernel 8, MLP matmul
+10), the loss forward 5, a backward ``while`` (attention recompute 10, the
+forward kernel's recompute 8, the flash backward kernel 5, an attention
+weight-gradient fusion with no name of its own 7, a nameless copy 3, MLP
+backward 8, attention backward 4), the
 loss backward 4, the exchange (packing 2, the gradients' all-reduce 6,
 the loss's all-reduce 1), an optimizer fusion whose root is the caller's
 ``apply_updates`` add 4, a bare ``apply_updates`` fusion 1, a nameless
 copy in the entry computation 1, idle 1."""
 
-import json
 import os
-import re
-import subprocess
 import sys
 
 import pytest
@@ -25,15 +25,25 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark import manifest, phase_report  # noqa: E402
+from benchmark import manifest  # noqa: E402
+from benchmark import layer_metrics as lm  # noqa: E402
 from benchmark import phase_split as ps  # noqa: E402
 from benchmark import trace_reduce as tr  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 DEV0, DEV1 = "/device:TPU:0", "/device:TPU:1"
+# One forward call of the fixture's shape takes 1.6 ms at the fixture's
+# peak, one backward call (2.5 x the operations) 4: two forward calls a
+# step in 16 ms are at 20% of their roofline, one backward in 5 at 80%.
+TRAFFIC = {"seq": 64, "per_chip_batch": 1}
+CONFIG = {"heads": 4, "d_model": 64}
+PEAKS = {"bf16_flops_per_s": 2 * 4 * 64 * 64 * 16 / 1.6e-3,
+         "hbm_bytes_per_s": 1e12}
 READERS = {"fwd_ms": 35.0, "remat_ms": 18.0, "bwd_ms": 31.0,
            "optimizer_ms": 4.0, "exchange_ms": 9.0, "unscoped_ms": 2.0,
-           "attention_ms": 57.0, "loss_ms": 9.0, "flash_fwd_named_ms": 16.0}
+           "attention_ms": 57.0, "loss_ms": 9.0,
+           "flash_fwd_ms": 16.0, "flash_fwd_roofline": 20.0,
+           "flash_bwd_ms": 5.0, "flash_bwd_roofline": 80.0}
 PHASE_READERS = ["fwd_ms", "remat_ms", "bwd_ms", "optimizer_ms",
                  "exchange_ms", "unscoped_ms"]
 
@@ -44,6 +54,8 @@ def _read(name):
 
 
 class Ctx:
+    traffic, config, peaks = TRAFFIC, CONFIG, PEAKS
+
     def __init__(self, trace, hlo_text):
         self.trace, self.hlo_text = trace, hlo_text
 
@@ -79,6 +91,9 @@ FUSION, ALL_REDUCE = ("fusion", "kOutput"), ("all-reduce", "")
      ("custom-call", "tpu_custom_call"), "remat"),
     (S + "transpose(jvp(hvdt.loss))/while/body/closed_call/checkpoint/"
      "rematted_computation/dot_general", FUSION, "remat"),
+    (J + "transpose(jvp())/while/body/closed_call/checkpoint/"
+     "hvdt.attention/hvdt.kernel.flash_bwd/pallas_call",
+     ("custom-call", "tpu_custom_call"), "backward"),
     (J + "transpose(jvp())/while/body/closed_call/checkpoint/hvdt.mlp/"
      "dot_general", FUSION, "backward"),
     (J + "transpose(jvp())/while/body/closed_call/checkpoint/"
@@ -115,6 +130,10 @@ def test_phase_of_an_op_name(op_name, kind, phase):
     (S + "hvdt.exchange/hvdt.fused_allreduce.b0/div", "hvdt.fused_allreduce",
      False),
     (S + "hvdt.attention_v2/mul", "hvdt.attention", False),
+    (J + "transpose(jvp())/while/body/closed_call/checkpoint/hvdt.attention/"
+     "hvdt.kernel.flash_bwd/pallas_call", "hvdt.kernel.flash_bwd", True),
+    (J + "transpose(jvp())/while/body/closed_call/checkpoint/hvdt.attention/"
+     "hvdt.kernel.flash_bwd/pallas_call", "hvdt.kernel.flash_fwd", False),
     ("", "hvdt.loss", False),
 ])
 def test_a_scope_is_a_whole_segment_of_the_path(op_name, scope, there):
@@ -144,8 +163,7 @@ def test_where_an_instruction_gets_its_op_name(names, instruction, ends,
 
 
 @pytest.mark.parametrize("metric", list(READERS) + [
-    "fwd_ms.images", "bwd_ms.images", "optimizer_ms.images",
-    "unscoped_ms.images"])
+    "fwd_ms.images", "bwd_ms.images", "unscoped_ms.images"])
 def test_reader_on_the_recorded_trace(ctx, metric):
     # The slowest device's reading (device 1 would give nine tenths).
     got = manifest.load_layer_metric(metric)(ctx)
@@ -167,11 +185,32 @@ def test_the_phase_readers_sum_to_the_slowest_devices_leaf_time(ctx):
     assert total == pytest.approx(99.0)
 
 
-def test_the_named_kernel_reader_agrees_with_the_unnamed_one(ctx):
-    # flash_fwd_ms counts every Mosaic call; here the flash forward is
-    # the only one, so both read the same events.
-    assert manifest.load_layer_metric("flash_fwd_named_ms")(ctx) == \
-        pytest.approx(manifest.load_layer_metric("flash_fwd_ms")(ctx))
+def test_the_kernels_are_told_apart_by_name_not_by_being_mosaic(ctx):
+    # Every Mosaic event of the step is one of the two kernels; each
+    # reader counts its own calls (PR 27 to 29: flash_fwd_ms counted all).
+    every_ms, every = lm.per_step(ctx, tr.is_mosaic)
+    fwd_ms, fwd = ps.scope_calls(ctx, "hvdt.kernel.flash_fwd", tr.is_mosaic)
+    bwd_ms, bwd = ps.scope_calls(ctx, "hvdt.kernel.flash_bwd", tr.is_mosaic)
+    assert (fwd, bwd, every) == (2, 1, 3)
+    assert fwd_ms + bwd_ms == pytest.approx(every_ms) == pytest.approx(21.0)
+    assert ps.scope_calls(ctx, "hvdt.kernel.flash_dq",
+                          tr.is_mosaic) == (None, None)
+    # Without the predicate a scope gives everything under it.
+    assert ps.scope_calls(ctx, "hvdt.loss") == (pytest.approx(9.0), 2)
+
+
+def test_a_kernels_roofline_share_is_its_calls_least_time_over_its_own(ctx):
+    ops, nbytes = lm.flash_bwd_call_cost(1, 64, 4, 16)
+    least, bound = lm.roofline(ops, nbytes, PEAKS)
+    assert (bound, least) == ("compute", pytest.approx(4e-3))
+    assert lm.flash_roofline_pct(ctx, 5.0, 1, lm.flash_bwd_call_cost) == \
+        pytest.approx(80.0)
+    assert lm.flash_roofline_pct(ctx, None, None,
+                                 lm.flash_bwd_call_cost) is None
+    images = Ctx(ctx.trace, ctx.hlo_text)
+    images.traffic = {"per_chip_batch": 128}       # no sequence: no share
+    assert lm.flash_roofline_pct(images, 5.0, 1,
+                                 lm.flash_fwd_call_cost) is None
 
 
 @pytest.mark.parametrize("metric", list(READERS))
@@ -192,56 +231,79 @@ def test_no_trace_nothing_to_read(ctx, metric):
 
 def test_a_scope_with_no_event_under_it_reads_none(ctx):
     dev = ctx.trace.devices[DEV0]
-    assert ps.scope_ms(dev, ps.op_names(ctx.hlo_text),
-                       "hvdt.kernel.flash_dq") is None
+    assert ps.scope_per_step(dev, ps.op_names(ctx.hlo_text),
+                             "hvdt.kernel.flash_dq") == (None, None)
+    assert ps.scope_per_step(dev, ps.op_names(ctx.hlo_text),
+                             "hvdt.loss") == (pytest.approx(9.0), 2)
 
 
 # ---------------------------------------------------------------------------
-# The entries a `benchmark` PR appends to BENCHMARK.json, and the report
-# that reads them until then.
+# The manifest's entries that read the program's own names: the ones PR 30
+# wrote in are looked for by name, among whatever a later PR appends.
 # ---------------------------------------------------------------------------
 
 MANIFEST = manifest.load_manifest()
-with open(os.path.join(REPO, "benchmark", "phase_metrics.json")) as f:
-    ENTRIES = json.load(f)["per_layer"]
+SPANS = {m["name"]: m for m in MANIFEST["per_layer"]
+         if m["source"] == "program_span"}
+SPLIT = ["fwd_ms", "fwd_ms.images", "remat_ms", "bwd_ms", "bwd_ms.images",
+         "attention_ms", "loss_ms", "optimizer_ms", "exchange_ms",
+         "unscoped_ms", "unscoped_ms.images"]
+KERNELS = ["flash_fwd_ms", "flash_fwd_roofline", "flash_bwd_ms",
+           "flash_bwd_roofline"]
 
 
-@pytest.mark.parametrize("entry", ENTRIES, ids=[e["name"] for e in ENTRIES])
-def test_a_phase_metric_entry_is_ready_for_the_manifest(entry):
+def test_the_phase_split_is_in_the_manifest():
+    # Every metric that picks trace events by a name the program gives is
+    # marked program_span, the kernels by scope too.
+    assert set(SPLIT + KERNELS) <= set(SPANS)
+    assert not {"flash_fwd_named_ms", "optimizer_ms.images"} & set(SPANS)
+
+
+@pytest.mark.parametrize("name", SPLIT + KERNELS)
+def test_a_phase_metric_entry_is_read_where_it_says(name):
+    entry = SPANS[name]
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    assert re.match(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$", entry["name"])
-    assert (entry["unit"], entry["better"], entry["source"]) == (
-        "ms", "lower", "program_span")
-    assert entry["layer"] in {m["layer"] for m in MANIFEST["per_layer"]}
-    assert entry["name"] not in {m["name"] for m in MANIFEST["per_layer"]}
-    assert callable(manifest.load_layer_metric(entry["name"]))
+    if name in SPLIT:
+        assert (entry["unit"], entry["better"]) == ("ms", "lower")
+        assert entry["layer"] in ("models", "optimizer + exchange")
+    else:
+        assert entry["layer"] == "kernels"
+        assert (entry["unit"], entry["better"]) == (
+            ("%", "higher") if name.endswith("_roofline") else ("ms", "lower"))
+    assert name.split(".")[0] in READERS
+    assert callable(manifest.load_layer_metric(name))
     assert entry["workloads"]
     for cell in entry["workloads"]:
+        loaded = manifest.load_cell(cell)
+        assert name in loaded["layer_metrics"]
         # A per-layer metric is reported only where the metric it moves is.
-        assert entry["moves"] in manifest.load_cell(cell)["end_to_end"]
+        assert entry["moves"] in loaded["end_to_end"]
 
 
-@pytest.mark.parametrize("cell, count", [
-    ("lm24x1024_s512_b128", 7), ("lm24x1024_s4096_b8", 8),
-    ("lm24x1024_s512_dp4", 8), ("resnet50_train", 4)])
-def test_the_report_adds_a_cells_phase_metrics_to_its_list(cell, count):
-    before = manifest.load_cell(cell)
-    after = phase_report.with_phase_metrics(before)
-    added = after["layer_metrics"][len(before["layer_metrics"]):]
-    assert after["layer_metrics"][:len(before["layer_metrics"])] == \
-        before["layer_metrics"]
-    assert len(added) == count == len(set(added))
-    assert all(after["units"][m] == "ms" for m in added)
-    assert before["layer_metrics"] == manifest.load_cell(
-        cell)["layer_metrics"]          # the loaded cell is not edited
+@pytest.mark.parametrize("cell, phases", [
+    ("lm24x1024_s512_b128", ["fwd_ms", "remat_ms", "bwd_ms", "optimizer_ms",
+                             "unscoped_ms"]),
+    ("lm24x1024_s4096_b8", ["fwd_ms", "remat_ms", "bwd_ms", "optimizer_ms",
+                            "unscoped_ms"]),
+    ("lm24x1024_s512_dp4", ["fwd_ms", "remat_ms", "bwd_ms", "optimizer_ms",
+                            "exchange_ms", "unscoped_ms"]),
+    ("resnet50_train", ["fwd_ms.images", "bwd_ms.images",
+                        "unscoped_ms.images"])])
+def test_a_cell_reports_the_phases_its_step_has(cell, phases):
+    """The phases a cell leaves out read 0 or next to it there (no
+    exchange on one chip; no remat in ResNet, and its SGD update fused
+    into the weight-gradient convolutions)."""
+    assert all(cell in SPANS[phase]["workloads"] for phase in phases)
+    assert {p.split(".")[0] for p in phases} <= set(PHASE_READERS)
 
 
-def test_the_report_prints_no_result_without_a_tpu():
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "phase_report.py"),
-         "--workload", "resnet50_train", "--seed", "1", "--seconds", "1"],
-        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=300)
-    assert r.returncode == 1
-    assert "no TPU" in r.stderr and "{" not in r.stdout
+def test_the_report_is_run_pys_traced_run(monkeypatch):
+    # benchmark/phase_report.py stays, as this and no more, until the
+    # operator's notes that name it may be corrected.
+    from benchmark import phase_report, run
+    seen = []
+    monkeypatch.setattr(run, "main", lambda argv: seen.append(argv) or 0)
+    asked = ["--workload", "resnet50_train", "--seed", "1", "--seconds", "26"]
+    assert phase_report.main(asked) == 0
+    assert seen == [asked + ["--trace", "1"]]

@@ -28,6 +28,7 @@ CPU simulator.
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import re
@@ -511,10 +512,11 @@ def _attention_grads(fn, q, k, v, do):
         argnums=(0, 1, 2)))(q, k, v)
 
 
-def _reference_grads_by_head(q, k, v, do):
+def _reference_grads_by_head(q, k, v, do, window=None, with_out=False):
     """dq, dk, dv of sum(attention_reference * do), one (sequence, head)
     at a time: a head's f32 score square is 64 MiB at seq 4096 and its
-    gradient holds a handful of them."""
+    gradient holds a handful of them.  ``with_out`` puts the reference's
+    output before them."""
     import jax
     import jax.numpy as jnp
 
@@ -524,10 +526,13 @@ def _reference_grads_by_head(q, k, v, do):
 
     def one(qkvdo):
         q1, k1, v1, do1 = (x[None, :, None, :] for x in qkvdo)
+        ref = functools.partial(attention_reference, window=window)
         grads = jax.grad(
             lambda q, k, v: jnp.sum(
-                attention_reference(q, k, v).astype(jnp.float32)
+                ref(q, k, v).astype(jnp.float32)
                 * do1.astype(jnp.float32)), argnums=(0, 1, 2))(q1, k1, v1)
+        if with_out:
+            grads = (ref(q1, k1, v1),) + grads
         return tuple(g[0, :, 0, :] for g in grads)
 
     by_head = jax.jit(lambda *xs: jax.lax.map(one, tuple(
@@ -547,6 +552,54 @@ def kernel_flash_backward(*, batch=8, seq=4096, heads=16, head_dim=64):
     _close("flash_attention backward",
            _attention_grads(flash_attention, q, k, v, do),
            _reference_grads_by_head(q, k, v, do), rtol=5e-2, atol=5e-2)
+
+
+def _flash_grouped_case(name, *, batch, seq, heads, kv_heads, head_dim,
+                        window):
+    """flash_attention forward and backward with grouped queries (the kv
+    head picked in the index map, dk / dv per query head summed by the
+    caller) and an optional window, against attention_reference head by
+    head with each head's own copy of its group's k and v."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.pallas_kernels import flash_attention
+
+    q, _, _, do = _qkv(batch, seq, heads, head_dim)
+    _, k, v, _ = _qkv(batch, seq, kv_heads, head_dim, seed=1)
+    group = heads // kv_heads
+    fn = functools.partial(flash_attention, window=window)
+    out = jax.jit(fn)(q, k, v)
+    grads = _attention_grads(fn, q, k, v, do)
+    want_out, dq, dk, dv = _reference_grads_by_head(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2), do,
+        window, with_out=True)
+    dk, dv = (x.reshape(batch, seq, kv_heads, group, head_dim).sum(3)
+              for x in (dk.astype(jnp.float32), dv.astype(jnp.float32)))
+    _close(name, out, want_out, rtol=2e-2, atol=2e-2)
+    # dk / dv are sums over a group of bf16 per-head results.
+    _close(f"{name} backward", grads, (dq, dk, dv), rtol=5e-2,
+           atol=5e-2 * group ** 0.5)
+
+
+def kernel_flash_gqa128(*, batch=2, seq=8192, heads=48, kv_heads=8,
+                        head_dim=128):
+    """The local kernels full-causal at head_dim 128 with grouped queries:
+    the full-attention layers' calls of laguna_xs2_s8192 (blocks 4096
+    forward, 2048 backward)."""
+    _flash_grouped_case("flash_attention gqa128", batch=batch, seq=seq,
+                        heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+                        window=None)
+
+
+def kernel_flash_window(*, batch=2, seq=8192, heads=64, kv_heads=8,
+                        head_dim=128, window=512):
+    """The windowed local kernels (hvdt.kernel.flash_win_fwd / _bwd): the
+    sliding-window layers' calls of laguna_xs2_s8192, blocks of 512 from
+    the window."""
+    _flash_grouped_case("flash_attention window", batch=batch, seq=seq,
+                        heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+                        window=window)
 
 
 def kernel_flash_grad_block(*, batch=1, seq=2048, heads=16, head_dim=64):
@@ -701,7 +754,8 @@ def kernel_quant_int4(*, size=1 << 24, block=256):
 
 
 KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
-           kernel_flash_backward, kernel_flash_grad_block,
+           kernel_flash_backward, kernel_flash_gqa128, kernel_flash_window,
+           kernel_flash_grad_block,
            kernel_conv_bn_relu, kernel_conv_bn_train, kernel_fused_adam,
            kernel_fused_sgd, kernel_quant_int8, kernel_quant_int4)
 

@@ -14,6 +14,7 @@ CPU simulation of an 8-chip slice:
 
 import argparse
 import contextlib
+import json
 import os
 import time
 
@@ -27,6 +28,17 @@ PRESETS = {
     # 24x1024x16 scale — is objective-agnostic.
     "bert-large": dict(layers=24, d_model=1024, heads=16, d_ff=4096,
                        seq=512, vocab=30528, remat=True, loss_chunk=8192),
+    # Laguna-XS.2 (poolside, 33.4B-A3B) on one chip's share of an 8-chip
+    # layer: the benchmark's configuration laguna_xs2 (cell
+    # laguna_xs2_s8192).  Its file holds the model's published config.json
+    # keys and, under layers / experts / experts_first / vocab, what this
+    # device holds of them: 692M parameters, 10.3 GiB of training state.
+    "laguna-xs2": dict(
+        published=os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               os.pardir, "benchmark", "configs",
+                               "laguna_xs2.json"),
+        seq=8192, batch=2, dtype="bfloat16", remat=True, loss_chunk=8192,
+        dp=1, tp=1),
 }
 
 
@@ -49,6 +61,15 @@ def main():
     p.add_argument("--lr", type=float, default=3e-4)
     p.add_argument("--preset", choices=sorted(PRESETS), default=None,
                    help="named model scale (overrides size flags)")
+    p.add_argument("--published", default=None, metavar="CONFIG_JSON",
+                   help="train the layer-pattern model a published "
+                        "config.json describes (models.config_from_"
+                        "published): per-kind heads, windows and rotary "
+                        "settings, dense and sparse feed-forwards.  The "
+                        "file's own layers / experts / experts_first / "
+                        "vocab keys, where present, cut it to this "
+                        "device's share; the size flags are ignored; "
+                        "data-parallel layouts only")
     p.add_argument("--dtype", choices=["float32", "bfloat16"],
                    default="float32",
                    help="activation/compute dtype (bfloat16 on TPU)")
@@ -93,7 +114,9 @@ def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import horovod_tpu as hvd
-    from horovod_tpu.models import (TransformerConfig, transformer_init,
+    from horovod_tpu.models import (TransformerConfig,
+                                    config_from_published,
+                                    transformer_init,
                                     transformer_logical_axes,
                                     transformer_loss,
                                     transformer_flops_per_token)
@@ -121,13 +144,29 @@ def main():
     mesh = make_mesh(devices=devs[:need], dp=args.dp, tp=args.tp,
                      pp=args.pp, sp=args.sp, ep=args.ep)
 
-    cfg = TransformerConfig(
-        vocab=args.vocab, layers=args.layers, d_model=args.d_model,
-        heads=args.heads, kv_heads=args.heads, d_ff=args.d_ff,
-        max_seq=args.seq, dtype=getattr(jnp, args.dtype),
-        num_experts=2 * args.ep if args.ep > 1 else 0,
-        sp=args.sp, ep=args.ep, pp=args.pp, remat=args.remat,
-        remat_policy=args.remat_policy, loss_chunk=args.loss_chunk)
+    if args.published:
+        assert args.pp == args.sp == args.ep == 1, \
+            "--published runs data-parallel layouts (pp = sp = ep = 1)"
+        with open(args.published) as f:
+            published = json.load(f)
+        cfg = config_from_published(
+            published, layers=published.get("layers"),
+            experts=published.get("experts"),
+            experts_first=published.get("experts_first", 0),
+            vocab=published.get("vocab"),
+            router_score=published.get("router_score", "sigmoid"),
+            max_seq=args.seq, dtype=getattr(jnp, args.dtype),
+            remat=args.remat, remat_policy=args.remat_policy,
+            loss_chunk=args.loss_chunk)
+        args.vocab = cfg.vocab          # token ids from the held slice
+    else:
+        cfg = TransformerConfig(
+            vocab=args.vocab, layers=args.layers, d_model=args.d_model,
+            heads=args.heads, kv_heads=args.heads, d_ff=args.d_ff,
+            max_seq=args.seq, dtype=getattr(jnp, args.dtype),
+            num_experts=2 * args.ep if args.ep > 1 else 0,
+            sp=args.sp, ep=args.ep, pp=args.pp, remat=args.remat,
+            remat_policy=args.remat_policy, loss_chunk=args.loss_chunk)
     params = transformer_init(jax.random.PRNGKey(0), cfg)
     rules = transformer_rules()
     axes = transformer_logical_axes(cfg)

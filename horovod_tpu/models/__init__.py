@@ -11,6 +11,10 @@ attention over ``sp``, MoE over ``ep``, pipeline stacking over ``pp``.
 
 from .transformer import (  # noqa: F401
     TransformerConfig,
+    LayerKind,
+    Rope,
+    Experts,
+    config_from_published,
     transformer_init,
     transformer_apply,
     transformer_loss,

@@ -25,21 +25,24 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+import math
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..ops.attention import attention
-from ..parallel.moe import moe_dispatch_combine
+from ..parallel.moe import moe_dispatch_combine, moe_held_experts
 from ..parallel.pipeline import pipeline_spmd
 from ..parallel.ring_attention import ring_attention
 from ..quant import fp8 as _fp8
 
 __all__ = [
-    "TransformerConfig", "transformer_init", "transformer_apply",
+    "TransformerConfig", "LayerKind", "Rope", "Experts",
+    "config_from_published", "transformer_init", "transformer_apply",
     "transformer_loss", "transformer_logical_axes",
     "transformer_flops_per_token", "remat_from_env", "checkpoint_policy",
     "transformer_decode_paged", "transformer_prefill_paged",
@@ -48,7 +51,64 @@ __all__ = [
 
 
 @dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary settings of one kind of layer.  ``dim`` is how many of each
+    head's dimensions rotate (0 = all of them; the rest pass through);
+    ``yarn_factor`` > 0 blends interpolated and extrapolated frequencies
+    as YaRN does, static in the sequence length; ``attention_factor``
+    multiplies cos and sin."""
+    theta: float = 10000.0
+    dim: int = 0
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    @property
+    def plain(self) -> bool:
+        return (not self.dim and not self.yarn_factor
+                and self.attention_factor == 1.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One kind of layer of a pattern: its mixer (attention with its own
+    head counts, window and rotary settings) and its feed-forward (dense
+    SwiGLU of width ``d_ff``, or with ``sparse`` the configuration's expert
+    layer, ``TransformerConfig.moe``)."""
+    heads: int
+    kv_heads: int
+    d_ff: int = 0
+    window: Optional[int] = None     # query i sees keys i - window < j <= i
+    rope: Rope = Rope()
+    sparse: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """The expert layer of a configuration: ``held`` experts of width
+    ``d_ff`` live here, experts ``first .. first + held - 1`` of the
+    ``routed`` the router scores (0 = the held ones are all there are);
+    a token picks ``per_token``; see ``parallel.moe.moe_held_experts``."""
+    held: int
+    d_ff: int
+    routed: int = 0
+    per_token: int = 1
+    first: int = 0
+    score: str = "sigmoid"           # or "softmax"
+    normalize: bool = True           # picked scores divided by their sum
+    scale: float = 1.0
+    gated: bool = True               # SwiGLU experts (else silu(x W_up) W_down)
+    shared_d_ff: int = 0             # > 0: a shared expert of this width
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
+    """One block repeated ``layers`` times (the uniform configuration: the
+    fields down to ``loss_chunk``), or, with ``period`` set, a PATTERN of
+    layers: ``leading`` layers, each of its own kind, then the kinds of
+    ``period`` in turn, repeated to ``layers`` in all."""
     vocab: int = 32000
     layers: int = 4
     d_model: int = 512
@@ -84,10 +144,71 @@ class TransformerConfig:
     # bs128 config.)
     remat_policy: str = "full"
     loss_chunk: int = 0          # >0: chunked-vocab cross entropy
+    head_dim: int = 0            # 0: d_model // heads
+    # A pattern of layers (period non-empty).  Leading layers are unrolled;
+    # inside a period, neighbours of one kind are scanned together (one
+    # compiled body, one set of kernel call sites), and the periods are
+    # scanned.  ``heads`` / ``kv_heads`` / ``d_ff`` / ``rope_theta`` /
+    # ``num_experts`` above are the uniform configuration's and unused here.
+    leading: Tuple[LayerKind, ...] = ()
+    period: Tuple[LayerKind, ...] = ()
+    moe: Optional[Experts] = None    # the expert layer of ``sparse`` kinds
+    out_gate: bool = False       # per-head sigmoid gate on attention's output
+    tie_head: bool = True        # False: an output matrix of its own, "head"
+
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.d_model // self.heads)
+        if self.period:
+            repeated = self.layers - len(self.leading)
+            if repeated <= 0 or repeated % len(self.period):
+                raise ValueError(
+                    f"layers={self.layers} is not {len(self.leading)} "
+                    f"leading layers plus whole periods of "
+                    f"{len(self.period)}")
+            if self.sp > 1 or self.ep > 1 or self.pp > 1:
+                raise ValueError(
+                    "a layer-pattern configuration runs with sp = ep = pp "
+                    "= 1 (the manual islands take uniform configurations)")
+            if any(k.sparse for k in self.leading + self.period) \
+                    and self.moe is None:
+                raise ValueError("a sparse layer kind needs cfg.moe")
+        elif self.leading:
+            raise ValueError("leading layers come before a period")
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.heads
+    def uniform_kind(self) -> LayerKind:
+        """The uniform configuration's one block as a kind."""
+        return LayerKind(heads=self.heads, kv_heads=self.kv_heads,
+                         d_ff=self.d_ff, rope=Rope(theta=self.rope_theta),
+                         sparse=bool(self.num_experts))
+
+    @property
+    def experts(self) -> Optional[Experts]:
+        """The expert layer's settings: ``moe``, or what the uniform
+        configuration's ``num_experts`` has always meant on one device
+        (softmax scores, one pick weighted by its score, every expert
+        held, two matrices an expert)."""
+        if self.moe is not None or not self.num_experts:
+            return self.moe
+        return Experts(held=self.num_experts, d_ff=self.d_ff,
+                       score="softmax", normalize=False, gated=False)
+
+    @property
+    def period_runs(self) -> Tuple[Tuple[LayerKind, int], ...]:
+        """The period as runs of equal neighbours: ((kind, count), ...)."""
+        runs = []
+        for kind in self.period:
+            if runs and runs[-1][0] == kind:
+                runs[-1][1] += 1
+            else:
+                runs.append([kind, 1])
+        return tuple((k, n) for k, n in runs)
+
+    @property
+    def periods(self) -> int:
+        return ((self.layers - len(self.leading)) // len(self.period)
+                if self.period else 0)
 
     @property
     def layers_per_stage(self) -> int:
@@ -95,14 +216,174 @@ class TransformerConfig:
         return self.layers // max(self.pp, 1)
 
 
+def config_from_published(published: Dict[str, Any], *,
+                          layers: Optional[int] = None,
+                          experts: Optional[int] = None,
+                          experts_first: int = 0,
+                          vocab: Optional[int] = None,
+                          router_score: str = "sigmoid",
+                          **fields) -> TransformerConfig:
+    """A pattern configuration from a model's published settings, cut to
+    this device's share of it.
+
+    ``published`` holds the keys of the model's ``config.json`` under their
+    own names: ``hidden_size``, ``head_dim``, ``num_attention_heads`` or
+    ``num_attention_heads_per_layer``, ``num_key_value_heads``,
+    ``layer_types`` (``full_attention`` / ``sliding_attention``, with
+    ``sliding_window``), ``rope_parameters`` (by layer type, or one group),
+    ``mlp_layer_types`` (``dense`` of ``intermediate_size`` / ``sparse``:
+    ``num_experts`` of ``moe_intermediate_size``, ``num_experts_per_tok``
+    picked, ``moe_routed_scaling_factor``, a shared expert of
+    ``shared_expert_intermediate_size``), ``gating``,
+    ``tie_word_embeddings``, ``vocab_size``, ``num_hidden_layers``.  No
+    width is an argument.  The cut: the first ``layers`` layers (the
+    leading ones and at least a period), ``experts`` of each sparse layer's
+    experts from ``experts_first`` on (the router keeps its width), the
+    first ``vocab`` rows of the vocabulary.  ``router_score`` is what
+    ``config.json`` leaves to modelling code; ``fields`` are further
+    ``TransformerConfig`` fields (``max_seq``, ``dtype``, ``remat``, ...).
+    """
+    c = published
+    depth = c["num_hidden_layers"]
+    head_dim = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
+    heads = c.get("num_attention_heads_per_layer") or \
+        [c["num_attention_heads"]] * depth
+    layer_types = c.get("layer_types") or ["full_attention"] * depth
+    mlp_types = c.get("mlp_layer_types") or \
+        ["sparse" if c.get("num_experts") else "dense"] * depth
+    ropes = c.get("rope_parameters") or {"rope_theta": c["rope_theta"]}
+
+    def rope_of(layer_type: str) -> Rope:
+        r = ropes.get(layer_type, ropes)
+        rotated = int(head_dim * r.get("partial_rotary_factor", 1))
+        yarn = r.get("rope_type", "default") == "yarn"
+        return Rope(
+            theta=float(r["rope_theta"]),
+            dim=0 if rotated == head_dim else rotated,
+            yarn_factor=float(r["factor"]) if yarn else 0.0,
+            yarn_original_max=r.get("original_max_position_embeddings", 0)
+            if yarn else 0,
+            yarn_beta_fast=float(r.get("beta_fast", 32)),
+            yarn_beta_slow=float(r.get("beta_slow", 1)),
+            attention_factor=float(r.get(
+                "attention_factor",
+                0.1 * math.log(r["factor"]) + 1.0 if yarn else 1.0)))
+
+    kinds = [LayerKind(
+        heads=heads[i], kv_heads=c["num_key_value_heads"],
+        d_ff=0 if mlp_types[i] == "sparse" else c["intermediate_size"],
+        window=c["sliding_window"]
+        if layer_types[i] == "sliding_attention" else None,
+        rope=rope_of(layer_types[i]), sparse=mlp_types[i] == "sparse")
+        for i in range(depth)]
+    # The fewest leading layers after which the published stack repeats
+    # (at least twice), and its shortest period.
+    lead, period = next(
+        (n, p) for n in range(depth) for p in range(1, (depth - n) // 2 + 1)
+        if all(kinds[i] == kinds[i + p] for i in range(n, depth - p)))
+    layers = layers or depth
+    # Whole periods only: what does not fill one goes to the leading layers
+    # (the whole stack of 1 + 39 layers at period 4 is 4 leading + 9 x 4).
+    lead += (layers - lead) % period
+    if layers - lead < period:
+        raise ValueError(
+            f"layers={layers}: keep the {lead} leading layers and at least "
+            f"one whole period of {period} after them")
+    moe = None
+    if any(k.sparse for k in kinds[:layers]):
+        routed = c["num_experts"]
+        moe = Experts(
+            held=experts or routed, d_ff=c["moe_intermediate_size"],
+            routed=routed, per_token=c["num_experts_per_tok"],
+            first=experts_first, score=router_score,
+            scale=float(c.get("moe_routed_scaling_factor", 1.0)),
+            shared_d_ff=c.get("shared_expert_intermediate_size", 0))
+    return TransformerConfig(
+        vocab=vocab or c["vocab_size"], layers=layers,
+        d_model=c["hidden_size"], head_dim=head_dim,
+        leading=tuple(kinds[:lead]), period=tuple(kinds[lead:lead + period]),
+        moe=moe, out_gate=bool(c.get("gating", False)),
+        tie_head=bool(c.get("tie_word_embeddings", True)), **fields)
+
+
 def _init_linear(key, fan_in, shape, dtype):
     return (jax.random.normal(key, shape) * (fan_in ** -0.5)).astype(dtype)
+
+
+def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
+    """One layer of a pattern: attention of ``kind``'s sizes (with ``wg``
+    where the output is gated) and its dense or sparse feed-forward."""
+    d, dh, pd = cfg.d_model, cfg.head_dim, cfg.param_dtype
+    h, hk = kind.heads, kind.kv_heads
+    ks = iter(jax.random.split(key, 12))
+    p = {
+        "ln1": jnp.ones((d,), pd), "ln2": jnp.ones((d,), pd),
+        "wq": _init_linear(next(ks), d, (d, h * dh), pd),
+        "wk": _init_linear(next(ks), d, (d, hk * dh), pd),
+        "wv": _init_linear(next(ks), d, (d, hk * dh), pd),
+        "wo": _init_linear(next(ks), h * dh, (h * dh, d), pd),
+    }
+    if cfg.out_gate:
+        p["wg"] = _init_linear(next(ks), d, (d, h), pd)
+    if not kind.sparse:
+        f = kind.d_ff
+        p["w_up"] = _init_linear(next(ks), d, (d, f), pd)
+        p["w_gate"] = _init_linear(next(ks), d, (d, f), pd)
+        p["w_down"] = _init_linear(next(ks), f, (f, d), pd)
+        return p
+    moe = cfg.moe
+    e, f = moe.held, moe.d_ff
+    p["w_router"] = _init_linear(next(ks), d, (d, moe.routed or e), pd)
+    p["w_up"] = _init_linear(next(ks), d, (e, d, f), pd)
+    if moe.gated:
+        p["w_gate"] = _init_linear(next(ks), d, (e, d, f), pd)
+    p["w_down"] = _init_linear(next(ks), f, (e, f, d), pd)
+    if moe.shared_d_ff:
+        fs = moe.shared_d_ff
+        p["ws_up"] = _init_linear(next(ks), d, (d, fs), pd)
+        p["ws_gate"] = _init_linear(next(ks), d, (d, fs), pd)
+        p["ws_down"] = _init_linear(next(ks), fs, (fs, d), pd)
+    return p
+
+
+def _pattern_init(key: jax.Array, cfg: TransformerConfig) -> Dict:
+    """A pattern's parameters: ``lead/<i>`` one layer each, ``period/<r>``
+    run r of the period with its layers stacked ``[periods, run length,
+    ...]`` (string keys, so a leaf has a path), ``embed``, ``ln_f`` and,
+    untied, ``head``."""
+    d, pd = cfg.d_model, cfg.param_dtype
+    k_embed, k_head, k_lead, k_period = jax.random.split(key, 4)
+    params = {
+        "embed": (jax.random.normal(k_embed, (cfg.vocab, d)) * 0.02
+                  ).astype(pd),
+        "ln_f": jnp.ones((d,), pd),
+        "lead": {str(i): _init_layer(k, cfg, kind) for i, (k, kind) in
+                 enumerate(zip(jax.random.split(k_lead,
+                                                max(len(cfg.leading), 1)),
+                               cfg.leading))},
+        "period": {},
+    }
+    if not cfg.tie_head:
+        params["head"] = (jax.random.normal(k_head, (cfg.vocab, d)) * 0.02
+                          ).astype(pd)
+    runs = cfg.period_runs
+    for r, (k_run, (kind, count)) in enumerate(
+            zip(jax.random.split(k_period, len(runs)), runs)):
+        layers = [_init_layer(k, cfg, kind)
+                  for k in jax.random.split(k_run, cfg.periods * count)]
+        params["period"][str(r)] = jax.tree.map(
+            lambda *xs: jnp.stack(xs).reshape(
+                (cfg.periods, count) + xs[0].shape), *layers)
+    return params
 
 
 def transformer_init(key: jax.Array, cfg: TransformerConfig) -> Dict:
     """Parameter pytree. Block params are stacked [layers, ...] for scan;
     under pp they are reshaped to [pp, layers_per_stage, ...] at apply time
-    (same memory layout, stage-major)."""
+    (same memory layout, stage-major).  A pattern configuration's tree is
+    :func:`_pattern_init`'s."""
+    if cfg.period:
+        return _pattern_init(key, cfg)
     keys = jax.random.split(key, 8)
     d, h, hk, dh, f = (cfg.d_model, cfg.heads, cfg.kv_heads, cfg.head_dim,
                        cfg.d_ff)
@@ -147,7 +428,10 @@ def transformer_init(key: jax.Array, cfg: TransformerConfig) -> Dict:
 def transformer_logical_axes(cfg: TransformerConfig) -> Dict:
     """Same-structure pytree of logical axis names (None = replicated dim)
     for ``parallel.sharding.logical_to_mesh``. Leading stacked-layers dim
-    maps to "stages" so pp shards it when the mesh has a pp axis."""
+    maps to "stages" so pp shards it when the mesh has a pp axis.  A
+    pattern's stacking dims are replicated (it runs no pipeline island)."""
+    if cfg.period:
+        return _pattern_logical_axes(cfg)
     block = {
         "ln1": ("stages", None),
         "ln2": ("stages", None),
@@ -167,6 +451,38 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict:
     return {"embed": ("vocab", "embed"), "ln_f": (None,), "block": block}
 
 
+def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
+    def layer(kind: LayerKind, stacked: int):
+        lead = (None,) * stacked
+        axes = {"ln1": (None,), "ln2": (None,),
+                "wq": ("embed", "heads"), "wk": ("embed", "kv"),
+                "wv": ("embed", "kv"), "wo": ("heads", "embed")}
+        if cfg.out_gate:
+            axes["wg"] = ("embed", "heads")
+        if kind.sparse:
+            axes.update(w_router=("embed", None),
+                        w_up=("experts", "embed", "mlp"),
+                        w_down=("experts", "mlp", "embed"))
+            if cfg.moe.gated:
+                axes["w_gate"] = ("experts", "embed", "mlp")
+            if cfg.moe.shared_d_ff:
+                axes.update(ws_up=("embed", "mlp"), ws_gate=("embed", "mlp"),
+                            ws_down=("mlp", "embed"))
+        else:
+            axes.update(w_up=("embed", "mlp"), w_gate=("embed", "mlp"),
+                        w_down=("mlp", "embed"))
+        return {name: lead + ax for name, ax in axes.items()}
+
+    out = {"embed": ("vocab", "embed"), "ln_f": (None,),
+           "lead": {str(i): layer(kind, 0)
+                    for i, kind in enumerate(cfg.leading)},
+           "period": {str(r): layer(kind, 2)
+                      for r, (kind, _) in enumerate(cfg.period_runs)}}
+    if not cfg.tie_head:
+        out["head"] = ("vocab", "embed")
+    return out
+
+
 def _rmsnorm(x, g):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)).astype(
@@ -184,6 +500,48 @@ def _rope(x, positions, theta):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(x.dtype)
 
 
+def _rope_frequencies(rope: Rope, head_dim: int) -> np.ndarray:
+    """The rotary frequencies of ``rope`` [dim / 2], float32.  Plain:
+    theta^(-2i/dim).  YaRN, as ``transformers`` computes it: interpolated
+    (/ factor) below rotation count ``beta_slow`` over the original
+    context, extrapolated (unchanged) above ``beta_fast``, a linear ramp
+    over the dimensions between."""
+    dim = rope.dim or head_dim
+    i = np.arange(dim // 2, dtype=np.float64)
+    extrapolated = rope.theta ** (-2.0 * i / dim)
+    if not rope.yarn_factor:
+        return extrapolated.astype(np.float32)
+
+    def correction(rotations):
+        return (dim * math.log(rope.yarn_original_max
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(correction(rope.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction(rope.yarn_beta_slow)), dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (extrapolated / rope.yarn_factor * ramp
+            + extrapolated * (1.0 - ramp)).astype(np.float32)
+
+
+def _rope_of(x, positions, rope: Rope):
+    """:func:`_rope` under ``rope``'s settings: the first ``rope.dim``
+    dimensions of each head rotate (rotate-half inside them), the rest
+    pass through."""
+    if rope.plain:
+        return _rope(x, positions, rope.theta)
+    dim = rope.dim or x.shape[-1]
+    d2 = dim // 2
+    ang = (positions[..., None].astype(jnp.float32)
+           * jnp.asarray(_rope_frequencies(rope, x.shape[-1])))
+    cos = (jnp.cos(ang) * rope.attention_factor)[..., None, :]
+    sin = (jnp.sin(ang) * rope.attention_factor)[..., None, :]
+    x1, x2 = x[..., :d2], x[..., d2:dim]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., dim:]],
+        -1).astype(x.dtype)
+
+
 def _proj(x, w):
     """Dense projection ``x @ w`` in the activation dtype — rides the
     per-tensor-scaled fp8 (e4m3) convert-dot when ``HVDT_FP8=matmul``
@@ -195,31 +553,40 @@ def _proj(x, w):
     return x @ w.astype(x.dtype)
 
 
-def _qkv(p, x, positions, cfg: TransformerConfig):
+def _qkv(p, x, positions, cfg: TransformerConfig,
+         kind: Optional[LayerKind] = None):
     """Rotated q/k/v projections — the one place the projection + RoPE
     recipe lives, shared by training attention (:func:`_attention`) and
     the serving paged-KV prefill/decode paths, so the cache can never
-    hold keys rotated differently from the ones training computed."""
+    hold keys rotated differently from the ones training computed.
+    ``kind`` is the layer's (None: the uniform configuration's)."""
+    kind = kind or cfg.uniform_kind
     b, l, _ = x.shape
-    h, hk, dh = cfg.heads, cfg.kv_heads, cfg.head_dim
+    h, hk, dh = kind.heads, kind.kv_heads, cfg.head_dim
     q = _proj(x, p["wq"]).reshape(b, l, h, dh)
     k = _proj(x, p["wk"]).reshape(b, l, hk, dh)
     v = _proj(x, p["wv"]).reshape(b, l, hk, dh)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    q = _rope_of(q, positions, kind.rope)
+    k = _rope_of(k, positions, kind.rope)
     return q, k, v
 
 
-def _attention(p, x, positions, cfg: TransformerConfig):
+def _attention(p, x, positions, cfg: TransformerConfig,
+               kind: Optional[LayerKind] = None):
+    kind = kind or cfg.uniform_kind
     b, l, _ = x.shape
-    q, k, v = _qkv(p, x, positions, cfg)
+    q, k, v = _qkv(p, x, positions, cfg, kind)
     if cfg.sp > 1:
         # Manual island: the sequence dim is the local sp shard here (the
         # caller's shard_map over {'sp'} has already split it).
         o = ring_attention(q, k, v, axis="sp", causal=True)
     else:
-        o = attention(q, k, v)
-    return _proj(o.reshape(b, l, cfg.heads * cfg.head_dim), p["wo"])
+        o = attention(q, k, v, window=kind.window)
+    if cfg.out_gate:
+        # A gate a head, from the layer's normed input.
+        gate = jax.nn.sigmoid(_proj(x, p["wg"]).astype(jnp.float32))
+        o = o * gate.astype(o.dtype)[..., None]
+    return _proj(o.reshape(b, l, kind.heads * cfg.head_dim), p["wo"])
 
 
 def _mlp(p, x):
@@ -229,11 +596,16 @@ def _mlp(p, x):
 
 
 def _moe_mlp(p, x, cfg: TransformerConfig):
+    """The sparse feed-forward.  On one device (``ep == 1``) the dropless
+    layer over the experts held here (``parallel.moe.moe_held_experts``,
+    under ``hvdt.moe``); across an ``ep`` axis the capacity dispatcher."""
     b, l, d = x.shape
     tokens = x.reshape(b * l, d)
-    logits = tokens @ p["w_router"].astype(x.dtype)
-    w_up, w_down = p["w_up"].astype(x.dtype), p["w_down"].astype(x.dtype)
     if cfg.ep > 1:
+        logits = tokens @ p["w_router"].astype(x.dtype)
+        w_up, w_down = (p["w_up"].astype(x.dtype),
+                        p["w_down"].astype(x.dtype))
+
         # w_up/w_down enter the island sharded over ep on the expert dim.
         def expert_fn(toks):   # [E_local, N, D]
             hmid = jax.nn.silu(jnp.einsum("end,edf->enf", toks, w_up))
@@ -242,17 +614,18 @@ def _moe_mlp(p, x, cfg: TransformerConfig):
             tokens, logits, expert_fn, axis="ep",
             experts_per_rank=cfg.num_experts // cfg.ep,
             capacity_factor=cfg.capacity_factor)
-    else:
-        # Dense (einsum-over-experts) fallback: exact, no capacity drops.
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
-        top = jnp.argmax(probs, -1)
-        gate = jnp.take_along_axis(probs, top[:, None], 1)[:, 0]
-        hmid = jax.nn.silu(jnp.einsum("nd,edf->enf", tokens, w_up))
-        all_out = jnp.einsum("enf,efd->end", hmid, w_down)
-        sel = jnp.take_along_axis(
-            all_out, top[None, :, None], 0)[0]
-        out = sel * gate[:, None].astype(x.dtype)
-        aux = None
+        return out.reshape(b, l, d), aux
+    moe = cfg.experts
+    shared = None
+    if moe.shared_d_ff:
+        def shared(h):
+            return _mlp({"w_up": p["ws_up"], "w_gate": p["ws_gate"],
+                         "w_down": p["ws_down"]}, h)
+    with jax.named_scope("hvdt.moe"):
+        out, aux = moe_held_experts(
+            tokens, p["w_router"], p["w_up"], p["w_down"], p.get("w_gate"),
+            top_k=moe.per_token, experts_first=moe.first, score=moe.score,
+            normalize=moe.normalize, scale=moe.scale, shared_fn=shared)
     return out.reshape(b, l, d), aux
 
 
@@ -313,22 +686,28 @@ def remat_from_env(cfg: TransformerConfig,
     return dataclasses.replace(cfg, remat=True, remat_policy=policy_name)
 
 
-def _block(p, x, positions, cfg: TransformerConfig):
+def _block(p, x, positions, cfg: TransformerConfig,
+           kind: Optional[LayerKind] = None):
+    """One layer of ``kind`` (None: the uniform configuration's)."""
+    kind = kind or cfg.uniform_kind
     # Each sublayer with its pre-norm under one name, for the profiler
     # and the benchmark's phase split (docs/observability.md).
     with jax.named_scope("hvdt.attention"):
-        a = _attention(p, _rmsnorm(x, p["ln1"]), positions, cfg)
+        a = _attention(p, _rmsnorm(x, p["ln1"]), positions, cfg, kind)
     x = x + a
     with jax.named_scope("hvdt.mlp"):
-        if cfg.num_experts:
+        if kind.sparse:
             y, _ = _moe_mlp(p, _rmsnorm(x, p["ln2"]), cfg)
         else:
             y = _mlp(p, _rmsnorm(x, p["ln2"]))
     return x + y
 
 
-def _scan_blocks(block_params, x, positions, cfg: TransformerConfig):
-    body = functools.partial(_block, positions=positions, cfg=cfg)
+def _layer_fn(positions, cfg: TransformerConfig,
+              kind: Optional[LayerKind] = None):
+    """``(layer params, x) -> x`` of one layer of ``kind`` under the
+    configuration's rematerialization policy."""
+    body = functools.partial(_block, positions=positions, cfg=cfg, kind=kind)
     if cfg.remat:
         if cfg.remat_policy == "dots":
             pol = _dots_policy()
@@ -350,12 +729,33 @@ def _scan_blocks(block_params, x, positions, cfg: TransformerConfig):
             raise ValueError(
                 f"unknown remat_policy {cfg.remat_policy!r} "
                 "(expected 'full' or 'dots')")
+    return body
+
+
+def _scan_blocks(block_params, x, positions, cfg: TransformerConfig,
+                 kind: Optional[LayerKind] = None):
+    body = _layer_fn(positions, cfg, kind)
 
     def step(h, layer_p):
         return body(layer_p, h), None
 
     out, _ = lax.scan(step, x, block_params)
     return out
+
+
+def _pattern_blocks(params, x, positions, cfg: TransformerConfig):
+    """The layers of a pattern: the leading ones one by one, then a scan
+    over the periods whose body scans each run of equal neighbours."""
+    for i, kind in enumerate(cfg.leading):
+        x = _layer_fn(positions, cfg, kind)(params["lead"][str(i)], x)
+
+    def one_period(h, period_p):
+        for r, (kind, _) in enumerate(cfg.period_runs):
+            h = _scan_blocks(period_p[str(r)], h, positions, cfg, kind)
+        return h, None
+
+    x, _ = lax.scan(one_period, x, params["period"])
+    return x
 
 
 def transformer_hidden(params: Dict, tokens: jax.Array,
@@ -383,7 +783,11 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
                                      ("ep", cfg.ep > 1 and cfg.num_experts))
                    if on]
     x = pcast_to_union(x, extra=tuple(manual_axes))
-    if cfg.pp > 1:
+    if cfg.period:
+        x = pcast_to_union(x, *jax.tree.leaves(
+            (params["lead"], params["period"])))
+        x = _pattern_blocks(params, x, positions, cfg)
+    elif cfg.pp > 1:
         # Inside a shard_map over {'pp'} the stacked-layers dim of the
         # block params is the sharded "stages" logical axis, so the local
         # slice is already this rank's [layers_per_stage, ...] stage.
@@ -415,12 +819,21 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
 def transformer_apply(params: Dict, tokens: jax.Array,
                       cfg: TransformerConfig) -> jax.Array:
     """Logits for next-token prediction (see transformer_hidden)."""
-    return _head(params, transformer_hidden(params, tokens, cfg))
+    return _head(params, transformer_hidden(params, tokens, cfg), cfg)
 
 
-def _head(params: Dict, x: jax.Array) -> jax.Array:
-    """The tied output projection: hidden states to f32 logits."""
-    return (x @ params["embed"].astype(x.dtype).T).astype(jnp.float32)
+def _head_matrix(params: Dict, cfg: TransformerConfig) -> jax.Array:
+    """The output projection's [vocab, d_model] matrix: the embedding
+    where the head is tied, else the tree's own ``head``.  Over a slice of
+    the vocabulary both have the slice's rows, and logits and loss are
+    over the slice."""
+    return params["embed"] if cfg.tie_head else params["head"]
+
+
+def _head(params: Dict, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """The output projection: hidden states to f32 logits."""
+    return (x @ _head_matrix(params, cfg).astype(x.dtype).T
+            ).astype(jnp.float32)
 
 
 def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
@@ -489,12 +902,12 @@ def transformer_loss(params: Dict, tokens: jax.Array,
     (no [tokens, vocab] logits tensor)."""
     targets = tokens[:, 1:]
     x = transformer_hidden(params, tokens, cfg)
-    # The tied head's matmul is inside the scope on both branches.
+    # The head's matmul is inside the scope on both branches.
     with jax.named_scope("hvdt.loss"):
         if cfg.loss_chunk:
-            return _chunked_xent(x[:, :-1], params["embed"], targets,
-                                 cfg.loss_chunk)
-        logits = _head(params, x)[:, :-1]
+            return _chunked_xent(x[:, :-1], _head_matrix(params, cfg),
+                                 targets, cfg.loss_chunk)
+        logits = _head(params, x, cfg)[:, :-1]
         logp = jax.nn.log_softmax(logits, -1)
         ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
         return -ll.mean()
@@ -516,6 +929,18 @@ def transformer_loss(params: Dict, tokens: jax.Array,
 # points (the allocator never hands block 0 out), so masked lanes stay
 # harmless without a single dynamic shape.
 # ---------------------------------------------------------------------------
+
+
+def _uniform_only(cfg: TransformerConfig, what: str) -> None:
+    """The paged serving functions scan ``params["block"]`` with one kind
+    of layer and one cache shape: a layer pattern (kinds with their own
+    heads, windows, experts) is refused here until serving learns it."""
+    if cfg.period or cfg.out_gate or not cfg.tie_head:
+        raise NotImplementedError(
+            f"{what} takes uniform configurations only: serving a "
+            "layer-pattern model (per-kind heads and windows, an output "
+            "gate, an untied head) is not built yet; train it with "
+            "transformer_loss")
 
 
 def _masked_softmax_attn(q, keys, vals, mask):
@@ -558,6 +983,7 @@ def transformer_decode_paged(params, tokens, block_tables, seq_lens,
     ``(next_tokens [S] int32, kc, vc)`` — greedy argmax stays in-graph
     so the host transfer per iteration is S ints, not S×vocab logits.
     """
+    _uniform_only(cfg, "transformer_decode_paged")
     s_slots = tokens.shape[0]
     maxb = block_tables.shape[1]
     active = seq_lens > 0
@@ -612,6 +1038,7 @@ def transformer_prefill_paged(params, tokens, ctx_start, n_valid,
     prompt token is deliberately NOT prefilled — it enters through the
     decode step, which produces the first generated token.
     """
+    _uniform_only(cfg, "transformer_prefill_paged")
     c = tokens.shape[0]
     maxb = block_table.shape[0]
     pos = ctx_start + jnp.arange(c)                            # [C]
@@ -657,6 +1084,7 @@ def transformer_prefill_collect(params, tokens, cfg: TransformerConfig):
     engine scatters into the paged cache in one shot.  tokens
     [B, S_local] int32.  Returns ``(k_all, v_all)``.
     """
+    _uniform_only(cfg, "transformer_prefill_collect")
     b, l = tokens.shape
     if cfg.sp > 1:
         offset = lax.axis_index("sp") * l
@@ -688,10 +1116,29 @@ def transformer_prefill_collect(params, tokens, cfg: TransformerConfig):
 
 
 def transformer_flops_per_token(cfg: TransformerConfig) -> float:
-    """Approximate forward-pass matmul FLOPs per token (for MFU metrics)."""
-    d, f, l = cfg.d_model, cfg.d_ff, cfg.layers
-    h, hk, dh = cfg.heads, cfg.kv_heads, cfg.head_dim
-    attn_proj = 2 * d * (h * dh + 2 * hk * dh + h * dh)
-    attn_scores = 2 * 2 * cfg.max_seq * h * dh          # per token, approx
-    mlp = 2 * d * f * (3 if not cfg.num_experts else 2)
-    return l * (attn_proj + attn_scores + mlp) + 2 * d * cfg.vocab
+    """Approximate forward-pass matmul FLOPs per token (for MFU metrics):
+    the full score square, a window at its width, of a sparse layer the
+    router, a token's picks that land on held experts in expectation and
+    the shared expert."""
+    d, dh = cfg.d_model, cfg.head_dim
+
+    def layer(kind: LayerKind) -> float:
+        h, hk = kind.heads, kind.kv_heads
+        attn_proj = 2 * d * (h * dh + 2 * hk * dh + h * dh)
+        attn_scores = 2 * 2 * min(kind.window or cfg.max_seq,
+                                  cfg.max_seq) * h * dh     # approx
+        if not kind.sparse:
+            return attn_proj + attn_scores + 2 * d * kind.d_ff * 3
+        moe = cfg.experts
+        routed = moe.routed or moe.held
+        mats = 3 if moe.gated else 2
+        return (attn_proj + attn_scores + 2 * d * routed
+                + 2 * d * moe.d_ff * mats * moe.per_token * moe.held / routed
+                + 2 * d * moe.shared_d_ff * 3)
+
+    if cfg.period:
+        layers = (sum(map(layer, cfg.leading))
+                  + cfg.periods * sum(map(layer, cfg.period)))
+    else:
+        layers = cfg.layers * layer(cfg.uniform_kind)
+    return layers + 2 * d * cfg.vocab

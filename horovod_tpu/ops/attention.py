@@ -22,7 +22,7 @@ choice made here: the model calls ``parallel.ring_attention`` for it.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -120,12 +120,13 @@ def kernel_plan(b: int, l: int, h: int, hk: int):
     return (dp_axes, tp_ax, names)
 
 
-def _xla_attention(q, k, v, causal: bool):
+def _xla_attention(q, k, v, causal: bool, window: Optional[int] = None):
     """Attention as XLA fuses it: the ``[B, H, L, L]`` scores exist, in
     f32 out of bf16 operands.  Not the f32 oracle
     (``pallas_kernels.attention_reference``) and not the paged-slot
     softmax of the serving path: this is what training runs wherever the
-    kernel does not."""
+    kernel does not.  ``window`` is the kernels' mask: query i sees keys
+    i - window < j <= i."""
     l, h, dh = q.shape[1], q.shape[2], q.shape[3]
     hk = k.shape[2]
     scale = dh ** -0.5
@@ -136,26 +137,34 @@ def _xla_attention(q, k, v, causal: bool):
                    preferred_element_type=jnp.float32) * scale
     if causal:
         mask = jnp.tril(jnp.ones((l, l), bool))
+        if window is not None:
+            mask = jnp.logical_and(mask, jnp.logical_not(
+                jnp.tril(jnp.ones((l, l), bool), -window)))
         s = jnp.where(mask[None, None], s, -1e30)
     w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool = True) -> jax.Array:
+              causal: bool = True, window: Optional[int] = None
+              ) -> jax.Array:
     """Self-attention of a sequence this device holds whole.
 
     q: ``[B, L, H, D]``; k, v: ``[B, L, Hkv, D]`` with ``Hkv`` dividing
     ``H`` (GQA); returns ``[B, L, H, D]``.  Shapes are the global ones
-    under a GSPMD-auto mesh and the local ones inside a manual island."""
+    under a GSPMD-auto mesh and the local ones inside a manual island.
+    ``window`` (causal only): a sliding window, query i sees the
+    ``window`` keys i - window < j <= i; the same mask on every path."""
+    if window is not None and not causal:
+        raise ValueError("a window is a causal mask's: pass causal=True")
     b, l, h, _ = q.shape
     plan = kernel_plan(b, l, h, k.shape[2])
     if plan is None:
-        return _xla_attention(q, k, v, causal)
+        return _xla_attention(q, k, v, causal, window)
     # Pallas fused attention: O(L·D) HBM traffic instead of a
     # materialized [B,H,L,L] score matrix (ops/pallas_kernels.py).
     kernel = functools.partial(pallas_kernels.flash_attention,
-                               causal=causal)
+                               causal=causal, window=window)
     if plan == "direct":
         return kernel(q, k, v)
     # GSPMD-auto mesh: Mosaic kernels can't be auto-partitioned, so

@@ -238,6 +238,51 @@ def _flash_call(q, k, v, acc, m, l, q_offset, k_offset, *, causal, scale,
           q, k, v, acc, m, l)
 
 
+# ---------------------------------------------------------------------------
+# What a query may see.  The local kernels take their mask as static
+# arguments: ``causal`` (key j <= query i) and ``window`` (also i - j <
+# window, the query's own position counted; None = no window).  Three
+# functions say what that means, for the forward and the backward alike,
+# and are the one place a further static or per-token condition joins
+# (segment ids of packed documents: a term of ``_visible_mask``, and every
+# tile "straddles"): which pairs of a tile are visible, whether a tile has
+# any or only such pairs, and where along the other axis a tile's visible
+# range starts.  Positions start at 0 on both sides.
+# ---------------------------------------------------------------------------
+
+
+def _visible_mask(diff, offset, window: Optional[int]):
+    """True where a pair is visible.  ``diff`` is row minus key inside the
+    tile and ``offset`` the tile's first key position minus its first
+    row's: the pair is ``diff - offset`` positions apart."""
+    mask = diff >= offset
+    if window is not None:
+        mask = jnp.logical_and(mask, diff < offset + window)
+    return mask
+
+
+def _tile_kind(q0, bq: int, k0, bk: int, window: int):
+    """(visited, visible) of the tile of rows ``q0 .. q0 + bq - 1`` and keys
+    ``k0 .. k0 + bk - 1`` under a causal window: whether any pair of it is
+    visible, and whether all are (no mask needed)."""
+    q1, k1 = q0 + (bq - 1), k0 + (bk - 1)
+    visited = jnp.logical_and(k0 <= q1, k1 > q0 - window)
+    visible = jnp.logical_and(k1 <= q0, k0 > q1 - window)
+    return visited, visible
+
+
+def _first_key_block(iq, bq: int, bk: int, window: int):
+    """The K/V block that holds the oldest key q tile ``iq`` sees."""
+    return jnp.maximum(iq * bq - (window - 1), 0) // bk
+
+
+def _window_block(window: int) -> int:
+    """The largest block a windowed call takes: the largest power of two
+    within the window (a row then computes at most two windows of keys),
+    and no less than the 128 lanes the statistics' row needs."""
+    return max(128, 1 << (window.bit_length() - 1))
+
+
 def _head_lanes(x, j, heads: int):
     """True on the lanes of ``x`` [n, heads * d] that hold head ``j`` (a
     program id) of the ``heads`` whose block this is."""
@@ -258,7 +303,8 @@ def _keep_head(x, j, heads: int):
 
 def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
                   l_s, *, causal: bool, scale: float, fold_scale: bool,
-                  rows: int, one_tile: bool, heads: int):
+                  rows: int, one_tile: bool, heads: int,
+                  window: Optional[int] = None, key_blocks: int = 0):
     """The local (non-ring) forward, grid (b, head group, iq, head, ik)
     with ik innermost: nothing to carry in and nothing to hand on, so
     (acc, m, l) are born in VMEM scratch at ik == 0 and die in the flush,
@@ -281,7 +327,14 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
     crosses is known from (iq, ik) alone: only those build a mask.  A tile
     is worked through in chunks of ``rows`` query rows; on a square tile's
     diagonal a chunk stops at its own last key, so the masked triangle
-    above it costs neither matmul nor exp."""
+    above it costs neither matmul nor exp.
+
+    With a ``window`` the K/V axis of the grid is only as long as the
+    blocks one q tile's window reaches (``key_blocks`` is the sequence's
+    count): step ik works on block ``first + ik``, the first being the one
+    that holds the tile's oldest visible key, and a step past the diagonal
+    does nothing.  A tile the window's edge crosses is masked like one the
+    diagonal crosses."""
     import jax.experimental.pallas as pl
 
     iq = pl.program_id(2)
@@ -290,6 +343,8 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
     nk = pl.num_programs(4)
     bq = q_ref.shape[1]
     bk = k_ref.shape[1]
+    # The K/V block this step works on.
+    kb = ik if window is None else ik + _first_key_block(iq, bq, bk, window)
 
     @pl.when(ik == 0)
     def _init():
@@ -305,7 +360,8 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
         for r in range(0, bq, rows):
             # bq == bk puts a straddling tile on the diagonal (iq == ik):
             # rows r.. see no key past r + rows.
-            keys = min(bk, r + rows) if straddles and bq == bk else bk
+            keys = (min(bk, r + rows)
+                    if straddles and bq == bk and window is None else bk)
             chunk = pl.ds(r, rows)
             s = jax.lax.dot_general(
                 q_s[chunk, :], k_ref[0, :keys, :],
@@ -315,17 +371,26 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
                 s = s * scale
             mask = None
             if straddles:
-                mask = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                        >= ik * bk - iq * bq - r)
+                mask = _visible_mask(
+                    jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                    - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1),
+                    kb * bk - iq * bq - r, window)
             # Key 0 is visible to every row and ik == 0 comes first, so no
-            # row meets a masked score with its max still at -1e30.
+            # row meets a masked score with its max still at -1e30.  Under
+            # a window a row's first block may hold none of its keys.
             _online_softmax_update(s, v_ref[0, :keys, :],
                                    acc_s.at[chunk], m_s.at[chunk],
-                                   l_s.at[chunk], mask)
+                                   l_s.at[chunk], mask,
+                                   rows_may_be_empty=window is not None)
 
     if not causal:
         _tile(False)
+    elif window is not None:
+        visited, visible = _tile_kind(iq * bq, bq, kb * bk, bk, window)
+        visited = jnp.logical_and(visited, kb < key_blocks)
+        pl.when(visible)(lambda: _tile(False))
+        pl.when(jnp.logical_and(visited, jnp.logical_not(visible)))(
+            lambda: _tile(True))
     elif one_tile:
         # The whole sequence is the diagonal's tile.  ik is 0: the cond is
         # there for interpret mode under shard_map (the CPU test path),
@@ -476,7 +541,7 @@ def _head_blocks(q, k, heads: int, block_q: int, block_k: int):
 
 
 def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
-                      rows=None):
+                      rows=None, window=None):
     """The self-contained forward: q [B,Lq,H*D], k/v [Bkv,Lk,Hkv*D] (the
     projections' own rows, ``heads`` = H) -> (out [B,Lq,H*D] in q.dtype,
     lse [B,H,1,Lq] f32).  One pallas_call and nothing around it: no carry
@@ -488,15 +553,30 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
     ``_heads_per_program`` head_dims: the head is a block index on the
     last dimension, and the lanes inside it where a block holds several.
     B is a multiple of Bkv where the caller folded grouped heads into the
-    batch: q row b reads kv row b // (B / Bkv)."""
+    batch: q row b reads kv row b // (B / Bkv).
+
+    Under a ``window`` the last grid axis is as long as the most blocks one
+    q tile's window reaches, and the call's name is
+    ``hvdt.kernel.flash_win_fwd``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, lq, _ = q.shape
     lk = k.shape[1]
     per, w, group, bgroup = _head_blocks(q, k, heads, block_q, block_k)
+    kv_steps = lk // block_k
+    if window is not None:
+        # Blocks from a tile's oldest visible key to its diagonal, at most.
+        kv_steps = max(
+            ((i + 1) * block_q - 1) // block_k
+            - max(i * block_q - (window - 1), 0) // block_k + 1
+            for i in range(lq // block_q))
 
     def kv_index(bb, hh, qq, jj, kk):
+        if window is not None:
+            kk = jnp.minimum(
+                kk + _first_key_block(qq, block_q, block_k, window),
+                lk // block_k - 1)
         if causal:
             # A tile past the diagonal is skipped: name the block that is
             # already resident, so nothing is fetched for it.
@@ -510,14 +590,18 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
         (1, 1, 1, block_q),
         lambda bb, hh, qq, jj, kk: (bb, hh * per + jj, 0, qq))
     kw = _vma_kw(q, k, v)
-    with jax.named_scope("hvdt.kernel.flash_fwd"):
+    windowed = {} if window is None else dict(
+        window=window, key_blocks=lk // block_k)
+    with jax.named_scope("hvdt.kernel.flash_fwd" if window is None
+                         else "hvdt.kernel.flash_win_fwd"):
         return pl.pallas_call(
             functools.partial(
                 _local_kernel, causal=causal, scale=scale,
                 fold_scale=_scale_folds_exactly(scale, q.dtype),
                 rows=rows or _chunk_rows(block_q),
-                one_tile=(lq, lk) == (block_q, block_k), heads=per),
-            grid=(b, heads // per, lq // block_q, per, lk // block_k),
+                one_tile=(lq, lk) == (block_q, block_k), heads=per,
+                **windowed),
+            grid=(b, heads // per, lq // block_q, per, kv_steps),
             in_specs=[qspec, kvspec, kvspec],
             out_specs=[qspec, lse_spec],
             out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype, **kw),
@@ -535,7 +619,8 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
 
 def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                       dk_ref, dv_ref, dq_s, dk_s, dv_s, *, causal: bool,
-                      scale: float, fold_scale: bool, keys: int, heads: int):
+                      scale: float, fold_scale: bool, keys: int, heads: int,
+                      window: Optional[int] = None):
     """The local (non-ring) backward, grid (b, head group, ik, head, iq)
     with iq innermost: the K/V block stays while q, dO and the two row
     statistics stream past it, once for each head of the group.  dk/dv
@@ -561,7 +646,12 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     Causal work is trimmed as in the forward: only tiles the diagonal
     crosses build a mask, tiles before it (q rows that see none of the
     block's keys) do nothing, and on a square tile's diagonal a chunk of
-    ``keys`` keys starts at its own first row."""
+    ``keys`` keys starts at its own first row.
+
+    With a ``window`` the q axis of the grid is only as long as the tiles
+    whose rows can see one K/V block: step iq works on q tile ``first +
+    iq``, the first being the one the block's diagonal starts in, and a
+    step past the window's edge does nothing."""
     import jax.experimental.pallas as pl
 
     ik = pl.program_id(2)
@@ -571,6 +661,8 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     nq = pl.num_programs(4)
     bq = q_ref.shape[1]
     bk = k_ref.shape[1]
+    # The q tile this step works on.
+    qt = iq if window is None else iq + (ik * bk) // bq
     nt = (((1,), (1,)), ((), ()))                 # a b^T
     nn = (((1,), (0,)), ((), ()))                 # a b
     tn = (((0,), (0,)), ((), ()))                 # a^T b
@@ -591,11 +683,11 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             q_all = (q_all * scale).astype(q_all.dtype)
         q_all = _keep_head(q_all, j, heads)
         do_all = _keep_head(do_ref[0, :, :], j, heads)
-        row0 = pl.multiple_of(iq * bq, bq)
+        row0 = pl.multiple_of(qt * bq, bq)
         for c in range(0, bk, keys):
             # bq == bk puts a straddling tile on the diagonal (iq == ik):
             # keys c.. are seen by no row before c.
-            r = c if straddles and bq == bk else 0
+            r = c if straddles and bq == bk and window is None else 0
             chunk = pl.ds(c, keys)
             k = k_ref[0, chunk, :]
             q = q_all[r:]
@@ -605,9 +697,10 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                 st = st * scale
             if straddles:
                 # True = visible: row position >= key position.
-                mask = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-                        - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
-                        >= ik * bk + c - iq * bq - r)
+                mask = _visible_mask(
+                    jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+                    - jax.lax.broadcasted_iota(jnp.int32, st.shape, 0),
+                    ik * bk + c - qt * bq - r, window)
                 st = jnp.where(mask, st, _NEG_INF)
             # The saved logsumexp is finite, so a masked score's exp is an
             # exact 0 and p needs no second select.
@@ -627,6 +720,12 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         # ik >= 0 always: the cond is there for interpret mode under
         # shard_map (see _local_kernel's one-tile branch).
         pl.when(ik >= 0)(lambda: _tile(False))
+    elif window is not None:
+        visited, visible = _tile_kind(qt * bq, bq, ik * bk, bk, window)
+        visited = jnp.logical_and(visited, qt < dq_s.shape[0] // bq)
+        pl.when(visible)(lambda: _tile(False))
+        pl.when(jnp.logical_and(visited, jnp.logical_not(visible)))(
+            lambda: _tile(True))
     else:
         visited = (iq + 1) * bq - 1 >= ik * bk            # else: all masked
         visible = iq * bq >= (ik + 1) * bk - 1            # nothing masked
@@ -695,22 +794,39 @@ def _backward_blocks(lq: int, lk: int, head_dim: int, dtype
 
 
 def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
-                          block_q, block_k, keys=None):
+                          block_q, block_k, keys=None, window=None):
     """The self-contained backward: q, dO [B,Lq,H*D], k, v [Bkv,Lk,Hkv*D]
     (``heads`` = H; the forward's layout and blocks), lse and delta f32
     rows [B,H,1,Lq] -> (dq [B,Lq,H*D], dk, dv [B,Lk,H*D]) in the operands'
     dtypes, dk/dv per q head (a GQA caller sums its group).  One
     pallas_call, grid (b, head group, K/V block, head of the group, q
     tile): nothing f32 of the sequence's size, nothing with a trailing
-    dimension of 1 and no [B,H,L,D] array goes in or comes out."""
+    dimension of 1 and no [B,H,L,D] array goes in or comes out.
+
+    Under a ``window`` the last grid axis is as long as the most q tiles
+    that see one K/V block, and the call's name is
+    ``hvdt.kernel.flash_win_bwd``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, lq, width = q.shape
     lk = k.shape[1]
     per, w, group, bgroup = _head_blocks(q, k, heads, block_q, block_k)
+    q_steps = lq // block_q
+    if window is not None:
+        # Tiles from a block's diagonal to its last key's window edge.
+        q_steps = max(
+            min(((i + 1) * block_k + window - 2) // block_q,
+                lq // block_q - 1) - (i * block_k) // block_q + 1
+            for i in range(lk // block_k))
 
     def q_tile(kk, qq):
+        if window is not None:
+            # Past the window's edge: the tile that is already resident.
+            return jnp.minimum(
+                qq + (kk * block_k) // block_q,
+                jnp.minimum(((kk + 1) * block_k + window - 2) // block_q,
+                            lq // block_q - 1))
         if causal:
             # q tiles before the diagonal are skipped: name the first one
             # that is not, so nothing is fetched for them.
@@ -730,13 +846,16 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
     dkvspec = pl.BlockSpec((1, block_k, w),
                            lambda bb, hh, kk, jj, qq: (bb, kk, hh))
     kw = _vma_kw(q, k, v, do, lse, delta)
-    with jax.named_scope("hvdt.kernel.flash_bwd"):
+    windowed = {} if window is None else dict(window=window)
+    with jax.named_scope("hvdt.kernel.flash_bwd" if window is None
+                         else "hvdt.kernel.flash_win_bwd"):
         return pl.pallas_call(
             functools.partial(
                 _local_bwd_kernel, causal=causal, scale=scale,
                 fold_scale=_scale_folds_exactly(scale, q.dtype),
-                keys=keys or _chunk_rows(block_k, 256), heads=per),
-            grid=(b, heads // per, lk // block_k, per, lq // block_q),
+                keys=keys or _chunk_rows(block_k, 256), heads=per,
+                **windowed),
+            grid=(b, heads // per, lk // block_k, per, q_steps),
             in_specs=[qspec, kvspec, kvspec, qspec, row, row],
             out_specs=[dqspec, dkvspec, dkvspec],
             out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype, **kw),
@@ -752,7 +871,8 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, scale: Optional[float] = None,
+                    causal: bool = True, window: Optional[int] = None,
+                    scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None) -> jax.Array:
     """Fused flash attention; layouts/API match
@@ -769,21 +889,41 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     either side (the property that makes long-context training fit in HBM
     at all).
 
+    ``window`` (with ``causal``): query i sees keys i - window < j <= i,
+    its own position counted.  Both calls then skip, by index map and
+    ``pl.when``, every tile that lies wholly outside the band, as they skip
+    the tiles above the diagonal, and take their blocks from the window
+    (:func:`_window_block`), so a row computes at most two windows of keys;
+    a window that reaches the whole sequence is no window.
+
     ``block_q`` / ``block_k`` default to :func:`_forward_blocks`' choice
     for the shape; a test passes its own to meet a given tiling.
     """
     b, lq, h, d = q.shape
+    lk = k.shape[1]
     if scale is None:
         scale = d ** -0.5
-    auto_q, auto_k = _forward_blocks(lq, k.shape[1], d, q.dtype)
+    auto_q, auto_k = _forward_blocks(lq, lk, d, q.dtype)
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is a causal mask's: causal=True and "
+                             f"window >= 1 (got causal={causal}, "
+                             f"window={window})")
+        if window >= lk:
+            window = None
+        else:
+            auto_q = _fit_block(lq, min(auto_q, _window_block(window)),
+                                q.dtype)
+            auto_k = _fit_block(lk, min(auto_k, _window_block(window)),
+                                k.dtype, v.dtype)
     block_q = _fit_block(lq, block_q, q.dtype) if block_q else auto_q
-    block_k = (_fit_block(k.shape[1], block_k, k.dtype, v.dtype)
+    block_k = (_fit_block(lk, block_k, k.dtype, v.dtype)
                if block_k else auto_k)
     return _flash_attn_diff(q, k, v, causal, float(scale), block_q,
-                            block_k)
+                            block_k, window)
 
 
-def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k, window=None):
     """Kernel forward returning (out [B,L,H,D], lse [B,H,1,Lq]): the
     logsumexp as the row the kernel writes and the backward reads."""
     b, lq, h, d = q.shape
@@ -791,28 +931,31 @@ def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k):
     out, lse = _flash_local_call(
         *(_rows_layout(x, fold) for x in (q, k, v)),
         heads=1 if fold else h, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, window=window)
     return _heads_layout(out, q.shape, fold), lse.reshape(b, h, 1, lq)
 
 
-def _flash_fwd_core(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd_core(q, k, v, causal, scale, block_q, block_k, window=None):
     """Kernel forward returning (out [B,L,H,D], lse [B,H,Lq])."""
-    out, lse = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k)
+    out, lse = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k,
+                               window)
     return out, lse[:, :, 0, :]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attn_diff(q, k, v, causal, scale, block_q, block_k):
-    out, _ = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attn_diff(q, k, v, causal, scale, block_q, block_k, window):
+    out, _ = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k,
+                             window)
     return out
 
 
-def _flash_attn_fwd(q, k, v, causal, scale, block_q, block_k):
-    out, lse = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k)
+def _flash_attn_fwd(q, k, v, causal, scale, block_q, block_k, window):
+    out, lse = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k,
+                               window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
+def _flash_attn_bwd(causal, scale, block_q, block_k, window, res, do):
     """The local backward: one Pallas call (``_flash_local_bwd_call``) on
     the forward's operand layout, delta = rowsum(dO * out) computed beside
     it as a row.  A test's own forward blocks bound the backward's too, so
@@ -825,7 +968,8 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
     blocks = _backward_blocks(lq, lk, d, q.dtype)
     if blocks is None:
         return _flash_bwd_blockwise(causal, scale, block_q, block_k,
-                                    (q, k, v, out, lse[:, :, 0, :]), do)
+                                    (q, k, v, out, lse[:, :, 0, :]), do,
+                                    window)
     delta = jnp.einsum("bqhd,bqhd->bhq", do, out,
                        preferred_element_type=jnp.float32)[:, :, None, :]
     fold = _heads_per_program(h, hkv, d) is None
@@ -835,7 +979,8 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
         *(x.reshape(b * h // heads, heads, 1, lq) for x in (lse, delta)),
         heads=heads, causal=causal, scale=scale,
         block_q=_fit_block(lq, min(block_q, blocks[0]), q.dtype),
-        block_k=_fit_block(lk, min(block_k, blocks[1]), k.dtype, v.dtype))
+        block_k=_fit_block(lk, min(block_k, blocks[1]), k.dtype, v.dtype),
+        window=window)
     dq, dk, dv = (_heads_layout(x, (b, x.shape[1], h, d), fold)
                   for x in (dq, dk, dv))
     if h != hkv:
@@ -845,7 +990,8 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, res, do):
     return dq, dk, dv
 
 
-def _flash_bwd_blockwise(causal, scale, block_q, block_k, res, do):
+def _flash_bwd_blockwise(causal, scale, block_q, block_k, res, do,
+                         window=None):
     """The flash gradient recomputed BLOCKWISE over K in plain XLA, for
     the sequence the kernel cannot hold (``_flash_attn_bwd``): two nested
     scans over <= 512-wide tiles, a whole-sequence f32 dq as the carry.
@@ -898,7 +1044,7 @@ def _flash_bwd_blockwise(causal, scale, block_q, block_k, res, do):
         if causal:
             q_pos = j * tq + jnp.arange(tq)
             k_pos = i * blk + jnp.arange(blk)
-            mask = q_pos[:, None] >= k_pos[None, :]
+            mask = _visible_mask(q_pos[:, None] - k_pos[None, :], 0, window)
             s = jnp.where(mask[None, None], s, _NEG_INF)
         p = jnp.exp(s - lse_t[..., None])                # [B,H,tq,blk]
         dv_b = jnp.einsum("bhqk,bqhd->bkhd", p, do_t)
@@ -1192,10 +1338,11 @@ def flash_grad_block(q, k, v, do, out, lse, *, q_offset=0, k_offset=0,
 
 
 def attention_reference(q, k, v, *, causal=True, scale=None,
-                        with_lse=False):
+                        with_lse=False, window=None):
     """Naive jnp attention (materializes scores) — the correctness oracle.
     ``with_lse`` also returns the f32 logsumexp of the scaled, masked
-    scores, [B, H, Lq]: the statistic the flash forwards save."""
+    scores, [B, H, Lq]: the statistic the flash forwards save.  ``window``
+    as :func:`flash_attention`'s."""
     b, lq, h, d = q.shape
     hkv = k.shape[2]
     if scale is None:
@@ -1207,7 +1354,8 @@ def attention_reference(q, k, v, *, causal=True, scale=None,
                    k.astype(jnp.float32)) * scale
     if causal:
         lk = k.shape[1]
-        mask = jnp.arange(lq)[:, None] >= jnp.arange(lk)[None, :]
+        mask = _visible_mask(
+            jnp.arange(lq)[:, None] - jnp.arange(lk)[None, :], 0, window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p,

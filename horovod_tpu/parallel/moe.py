@@ -1,4 +1,14 @@
-"""Expert parallelism: capacity-factor top-k MoE with alltoall token routing.
+"""Expert layers: a dropless top-k layer over the experts one chip holds,
+and a capacity-factor top-k MoE with alltoall token routing.
+
+:func:`moe_held_experts` is the layer a model calls where this device holds
+a range of a layer's experts and sees all of its own tokens (one chip's
+share of an expert-parallel layer, or every expert of a small model): it
+routes over ALL routed experts, keeps the picks that land on held ones,
+sorts them by expert and runs grouped matrix products over those rows.  No
+capacity, no dropped pick.  :func:`moe_dispatch_combine` below is the older
+exchange across an ``ep`` axis, with a static capacity that drops.
+
 
 The reference exposes the raw alltoall primitive that makes user-level MoE
 possible (ref: operations.cc:1642-1725, ops/collective_operations.h:195
@@ -27,13 +37,16 @@ from jax import lax
 
 from ..ops.device import _axis_size_static
 
-__all__ = ["moe_dispatch_combine", "MoEAux", "moe_capacity",
-           "report_moe_aux"]
+__all__ = ["moe_dispatch_combine", "moe_held_experts", "moe_route", "MoEAux",
+           "moe_capacity", "report_moe_aux"]
 
 
 class MoEAux(NamedTuple):
     load_balance_loss: jax.Array   # switch-transformer aux loss (scalar)
     dropped_fraction: jax.Array    # fraction of tokens over capacity (scalar)
+    # The dropless layer's load (None from the capacity dispatcher):
+    held_rows: Optional[jax.Array] = None         # picks that landed here
+    max_expert_rows: Optional[jax.Array] = None   # the fullest held expert's
 
 
 def _env_float(name: str, default: float) -> float:
@@ -61,6 +74,176 @@ def moe_capacity(tokens_per_rank: int, num_experts: int, *,
     shape; tokens beyond it are dropped (residual passthrough)."""
     want = tokens_per_rank * top_k * capacity_factor
     return max(1, int(-(-want // num_experts)))
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer over held experts.
+# ---------------------------------------------------------------------------
+
+
+# The layer's two moves: tokens' rows into the buffer sorted by expert, and
+# the experts' rows back to their tokens.  Both are gathers, and each is the
+# other's transpose (``order`` is a permutation of the picks and ``inverse``
+# its inverse), which is what their custom VJPs say: the backward holds no
+# scatter-add.  ``held`` [T, k] says which picks landed here: exactly those
+# sit in the rows the grouped products compute, so what the products leave
+# in the other rows is selected away where rows enter a token's sum.  Their
+# cost is the buffer's (all T x k rows move, whatever landed) and so does
+# not change with the routing: measured on the v5e at 131,072 rows of 2048
+# (PERF.md, PR 31), a gather from the [T, D] activations 0.83 ms, from the
+# [T x k, D] buffer 4.5 ms; a scatter-add that walks only the rows that
+# landed 4.2 ms for 16,384 rows and more or less with the load.
+
+
+def _sum_held_rows(rows, inverse, held):
+    """[T * k, D] -> [T, D]: each token the float32 sum of its held picks'
+    rows; a pick's row is ``rows[inverse[pick]]``."""
+    t, k = held.shape
+    picks = jnp.where(held[:, :, None], rows[inverse].reshape(t, k, -1), 0)
+    return picks.sum(1, dtype=jnp.float32).astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _rows_of_tokens(x, order, inverse, held):
+    """x [T, D] -> [T * k, D]: row r is the token of pick ``order[r]``."""
+    return x[order // held.shape[1]]
+
+
+def _rows_of_tokens_fwd(x, order, inverse, held):
+    return _rows_of_tokens(x, order, inverse, held), (inverse, held)
+
+
+def _rows_of_tokens_bwd(res, g):
+    return _sum_held_rows(g, *res), None, None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _tokens_of_rows(rows, order, inverse, held):
+    """rows [T * k, D] -> [T, D]: each token the sum of its held picks'
+    rows."""
+    return _sum_held_rows(rows, inverse, held)
+
+
+def _tokens_of_rows_fwd(rows, order, inverse, held):
+    return _tokens_of_rows(rows, order, inverse, held), (order, held)
+
+
+def _tokens_of_rows_bwd(res, g):
+    order, held = res
+    # The rows past those that landed get their token's too: no product
+    # reads them.
+    return g[order // held.shape[1]], None, None, None
+
+
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+
+
+def moe_route(x: jax.Array, w_router: jax.Array, *, top_k: int,
+              score: str = "sigmoid", normalize: bool = True,
+              scale: float = 1.0):
+    """Scores over every routed expert and each token's picks, in float32
+    (the router's product at ``HIGHEST`` precision: a pick is a
+    comparison of scores, and a bf16 product decides thousands of them
+    otherwise).  ``x`` [T, D], ``w_router`` [D, E] -> (scores [T, E],
+    picked experts [T, k], their weights [T, k]).  ``score`` is
+    ``"sigmoid"`` or ``"softmax"``; ``normalize`` divides the picked scores
+    by their sum; ``scale`` multiplies the weights."""
+    z = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(z)
+    elif score == "softmax":
+        scores = jax.nn.softmax(z, axis=-1)
+    else:
+        raise ValueError(f"unknown router score {score!r} "
+                         "(expected 'sigmoid' or 'softmax')")
+    picked, experts = lax.top_k(scores, top_k)
+    if normalize:
+        picked = picked / jnp.maximum(picked.sum(-1, keepdims=True), 1e-20)
+    return scores, experts, picked * scale
+
+
+def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
+                     w_down: jax.Array, w_gate: Optional[jax.Array] = None,
+                     *, top_k: int, experts_first: int = 0,
+                     score: str = "sigmoid", normalize: bool = True,
+                     scale: float = 1.0,
+                     shared_fn: Optional[Callable[[jax.Array], jax.Array]]
+                     = None) -> Tuple[jax.Array, MoEAux]:
+    """A top-k expert layer over the experts held here, without drops.
+
+    ``x`` [T, D] are this device's tokens; ``w_router`` [D, E] scores ALL
+    ``E`` routed experts; ``w_up`` / ``w_gate`` [H, D, F] and ``w_down``
+    [H, F, D] are the ``H`` held ones, experts ``experts_first ..
+    experts_first + H - 1`` of the layer (``w_gate`` None: ``silu(x W_up)
+    W_down``, else SwiGLU ``(silu(x W_gate) * x W_up) W_down``).  Returns
+    ``sum over a token's picks that are held of weight * expert(x)`` plus
+    ``shared_fn(x)``: with ``H < E`` that is this share's PART of the
+    layer (what the absent experts would add is another device's to
+    compute; nothing here stands in for it or for the exchange), with
+    ``H == E`` the whole layer.
+
+    No capacity: the picks that land here are sorted by expert into a row
+    buffer and ``jax.lax.ragged_dot`` multiplies each expert's rows by its
+    matrices.  The buffer's static bound is ``T * top_k`` rows (every
+    pick of every token may be a held expert's, and none is dropped); the
+    products' time follows the rows that did land (``MoEAux.held_rows``),
+    since ``ragged_dot`` visits only the tiles its ``group_sizes`` cover;
+    the two moves around them cost what the buffer costs, whatever landed
+    (``_rows_of_tokens``, ``_tokens_of_rows``).
+    """
+    t, d = x.shape
+    e_held = w_up.shape[0]
+    k = int(top_k)
+    m = t * k
+    with jax.named_scope("hvdt.moe.route"):
+        scores, experts, weights = moe_route(
+            x, w_router, top_k=k, score=score, normalize=normalize,
+            scale=scale)
+        local = experts.reshape(m) - experts_first            # pick t*k + i
+        held = jnp.logical_and(local >= 0, local < e_held)
+        # Held picks first, by expert; the others behind them.
+        order = jnp.argsort(jnp.where(held, local, e_held), stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(
+            local[:, None] == jnp.arange(e_held, dtype=local.dtype)[None],
+            axis=0, dtype=jnp.int32)
+        # A row's weight: its pick's, 0 behind the rows that landed.
+        weight_of_row = jnp.where(held, weights.reshape(m), 0.0)[order]
+        held = held.reshape(t, k)
+    with jax.named_scope("hvdt.moe.dispatch"):
+        xs = _rows_of_tokens(x, order, inverse, held)
+    with jax.named_scope("hvdt.moe.experts"):
+        up = lax.ragged_dot(xs, w_up.astype(x.dtype), group_sizes)
+        if w_gate is None:
+            mid = jax.nn.silu(up)
+        else:
+            mid = jax.nn.silu(lax.ragged_dot(
+                xs, w_gate.astype(x.dtype), group_sizes)) * up
+        # w (mid W_down) = (w mid) W_down: the weight rides the narrow
+        # side of the last product, inside the activation's fusion.
+        mid = mid * weight_of_row[:, None].astype(mid.dtype)
+        ys = lax.ragged_dot(mid, w_down.astype(x.dtype), group_sizes)
+    with jax.named_scope("hvdt.moe.dispatch"):
+        out = _tokens_of_rows(ys, order, inverse, held)
+    if shared_fn is not None:
+        with jax.named_scope("hvdt.moe.shared"):
+            out = out + shared_fn(x)
+
+    e_routed = scores.shape[-1]
+    frac = jnp.sum(jax.nn.one_hot(experts, e_routed, dtype=jnp.float32),
+                   axis=(0, 1)) / m
+    mean_score = (scores / jnp.maximum(scores.sum(-1, keepdims=True), 1e-20)
+                  ).mean(0)
+    aux = MoEAux(
+        load_balance_loss=e_routed * jnp.sum(frac * mean_score),
+        dropped_fraction=jnp.zeros((), jnp.float32),   # by construction
+        held_rows=group_sizes.sum().astype(jnp.float32),
+        max_expert_rows=group_sizes.max().astype(jnp.float32))
+    return out, aux
 
 
 def _a2a_transport(block: jax.Array, axis: str, name: str):
@@ -286,3 +469,13 @@ def report_moe_aux(aux: MoEAux, *, step: Optional[int] = None) -> None:
         "Fraction of routed token assignments dropped over expert "
         "capacity in the last reported step").set(
         float(jax.device_get(aux.dropped_fraction)))
+    if aux.held_rows is not None:
+        _rec.registry.gauge(
+            "hvdt_moe_held_rows",
+            "Token-expert picks that landed on the experts held here in "
+            "the last reported step (rows of the dropless layer's grouped "
+            "products)").set(float(jax.device_get(aux.held_rows)))
+        _rec.registry.gauge(
+            "hvdt_moe_max_expert_rows",
+            "Rows of the fullest held expert in the last reported "
+            "step").set(float(jax.device_get(aux.max_expert_rows)))

@@ -585,6 +585,13 @@ CATALOG: Dict[str, MetricSpec] = {
         _m("hvdt_moe_dropped_fraction", "gauge", (),
            "Fraction of routed token assignments dropped over expert "
            "capacity in the last reported step (report_moe_aux)"),
+        _m("hvdt_moe_held_rows", "gauge", (),
+           "Token-expert picks that landed on the experts held here in "
+           "the last reported step: the rows of the dropless layer's "
+           "grouped products (moe_held_experts; report_moe_aux)"),
+        _m("hvdt_moe_max_expert_rows", "gauge", (),
+           "Rows of the fullest held expert in the last reported step "
+           "(moe_held_experts; report_moe_aux)"),
         _m("hvdt_pipeline_mfu", "gauge", (),
            "Model FLOPs utilization of the last reported pipeline step "
            "(achieved model FLOP/s / peak; report_pipeline_mfu)"),

@@ -45,3 +45,35 @@ def test_meshless_single_device():
 def test_dp2_tp2_hybrid_with_remat_and_chunked_loss():
     assert _run(["--dp", "2", "--tp", "2", "--remat",
                  "--loss-chunk", "128"]) > 0
+
+
+@pytest.mark.integration
+def test_a_published_layer_pattern_model_by_the_same_path(tmp_path):
+    """--published (the route the laguna-xs2 preset takes): the benchmark's
+    Laguna-XS.2 configuration file at toy widths, five layers of three
+    kinds with 4 of 16 experts held, through the example's single-device
+    step."""
+    import json
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "laguna_xs2.json")) as f:
+        published = json.load(f)
+    published.update(
+        hidden_size=64, head_dim=32, num_key_value_heads=2,
+        num_attention_heads_per_layer=[
+            6 if h == 48 else 8
+            for h in published["num_attention_heads_per_layer"]],
+        sliding_window=16, intermediate_size=128, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, num_experts=16,
+        num_experts_per_tok=2, vocab_size=512, experts=4, experts_first=4,
+        vocab=256)
+    path = tmp_path / "toy.json"
+    path.write_text(json.dumps(published))
+    out = subprocess.run(
+        [sys.executable, SCRIPT, "--published", str(path), "--dp", "1",
+         "--tp", "1", "--seq", "64", "--batch", "4", "--steps", "3",
+         "--remat", "--loss-chunk", "96"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO),
+        capture_output=True, text=True, timeout=200, cwd=REPO)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    assert TOKS.search(out.stdout) and "done." in out.stdout

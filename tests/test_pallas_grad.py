@@ -200,11 +200,13 @@ class TestFlashMeshGate:
 
 
 class TestFlashBackwardPaths:
-    def test_kernel_backward_matches_xla_backward(self):
+    @pytest.mark.parametrize("window", [None, 100], ids=["causal", "window"])
+    def test_kernel_backward_matches_xla_backward(self, window):
         """The two backwards of flash_attention's custom_vjp, each called
         directly on the forward's residuals: the Pallas call every shape
         takes that fits in VMEM, and the blockwise XLA recompute kept for
-        the one that does not.  GQA, so both sum dk/dv over a group."""
+        the one that does not.  GQA, so both sum dk/dv over a group; with
+        and without a sliding window, which both take."""
         from horovod_tpu.ops import pallas_kernels as pk
 
         rng = np.random.RandomState(11)
@@ -213,12 +215,12 @@ class TestFlashBackwardPaths:
         v = jnp.asarray(rng.randn(1, 256, 1, 16), jnp.float32)
         do = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
         args = (True, 0.25, 128, 128)       # causal, scale, block_q, block_k
-        out, res = pk._flash_attn_fwd(q, k, v, *args)
-        got = jax.jit(lambda res, do: pk._flash_attn_bwd(*args, res, do))(
-            res, do)
+        out, res = pk._flash_attn_fwd(q, k, v, *args, window)
+        got = jax.jit(lambda res, do: pk._flash_attn_bwd(
+            *args, window, res, do))(res, do)
         lse_rows = res[4]
         ref = jax.jit(lambda res, do: pk._flash_bwd_blockwise(
-            *args, res[:4] + (lse_rows[:, :, 0, :],), do))(res, do)
+            *args, res[:4] + (lse_rows[:, :, 0, :],), do, window))(res, do)
         for a, b in zip(got, ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-5, rtol=1e-4)

@@ -148,6 +148,11 @@ def _flash(kernel):
     if kernel == "flash_bwd":                   # the local backward
         return lambda: jax.grad(lambda q: pk.flash_attention(
             q, q, q, block_q=128, block_k=128).sum())(q)
+    if kernel == "flash_win_fwd":               # the same two, windowed
+        return lambda: pk.flash_attention(q, q, q, window=32)
+    if kernel == "flash_win_bwd":
+        return lambda: jax.grad(lambda q: pk.flash_attention(
+            q, q, q, window=32).sum())(q)
     lse = jnp.zeros((1, 2, 128), jnp.float32)   # the ring step's two
     return lambda: pk.flash_grad_block(q, q, q, q, q, lse,
                                        block_q=128, block_k=128)
@@ -192,8 +197,9 @@ def _quant(kernel):
 
 
 KERNEL_SITES = (
-    [(_flash, k) for k in ("flash_fwd", "flash_fwd.ring", "flash_bwd",
-                           "flash_dq", "flash_dkv")]
+    [(_flash, k) for k in ("flash_fwd", "flash_win_fwd", "flash_fwd.ring",
+                           "flash_bwd", "flash_win_bwd", "flash_dq",
+                           "flash_dkv")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
     + [(_quant, k) for k in ("quantize", "dequantize", "quantize4",
@@ -210,8 +216,10 @@ def test_every_pallas_call_site_lowers_under_its_name(build, kernel):
 
 
 def test_no_pallas_call_site_is_left_without_a_name():
-    """A new ``pl.pallas_call`` arrives with a ``hvdt.kernel.`` scope on
-    the line above it, and with a case in KERNEL_SITES."""
+    """A new ``pl.pallas_call`` arrives with a ``hvdt.kernel.`` scope in
+    the ``with`` statement above it (a site that lowers under one of two
+    names, as the local flash calls do with and without a window, names
+    both there), and with a case in KERNEL_SITES for each name."""
     import inspect
 
     from horovod_tpu.ops import conv_fused, optim_kernels, pallas_kernels
@@ -222,10 +230,14 @@ def test_no_pallas_call_site_is_left_without_a_name():
         lines = inspect.getsource(mod).splitlines()
         for i, line in enumerate(lines):
             if re.search(r"(=|return) pl\.pallas_call\($", line):
-                m = re.search(r'named_scope\("hvdt\.kernel\.(\w+)"\)',
-                              lines[i - 1])
-                assert m, f"{mod.__name__}:{i + 1} has no kernel scope"
-                named.append(m.group(1))
+                start = i - 1
+                while not lines[start].lstrip().startswith("with "):
+                    start -= 1
+                statement = " ".join(lines[start:i])
+                assert "named_scope(" in statement and i - start <= 2
+                names = re.findall(r'"hvdt\.kernel\.(\w+)"', statement)
+                assert names, f"{mod.__name__}:{i + 1} has no kernel scope"
+                named.extend(names)
     assert sorted(named) == sorted(k.split(".")[0] for _, k in KERNEL_SITES)
 
 
@@ -242,9 +254,9 @@ def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
     them, and nothing in
     the attention backward is a loop or a ``dynamic_update_slice``: the
     blockwise XLA backward is not there.  The benchmark's ``flash_fwd_ms``
-    and ``flash_fwd_roofline`` count every Mosaic call of the step, the
-    backward's too (PERF.md section 3); ``flash_fwd_named_ms`` reads the
-    forward alone, by the name checked here."""
+    / ``flash_fwd_roofline`` and ``flash_bwd_ms`` / ``flash_bwd_roofline``
+    pick their Mosaic events by the two names checked here (PERF.md
+    section 3; since PR 30)."""
     from horovod_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
@@ -324,3 +336,90 @@ def test_flash_attention_moves_no_layout_around_its_calls(
             q, kv, kv, q).jaxpr)
     assert names.count("pallas_call") == 2
     assert ("transpose" in names) == transposes
+
+
+# ---------------------------------------------------------------------------
+# A layer-pattern model: the sparse feed-forward's scopes under hvdt.mlp,
+# and the windowed kernels' names beside the full-causal ones.
+# ---------------------------------------------------------------------------
+
+
+def pattern_config(seq):
+    full = models.LayerKind(heads=2, kv_heads=1, d_ff=256)
+    sliding = models.LayerKind(heads=2, kv_heads=1, window=128, sparse=True)
+    return models.TransformerConfig(
+        vocab=256, d_model=128, head_dim=128, layers=3, leading=(full,),
+        period=(sliding, sliding), max_seq=seq, remat=True, loss_chunk=0,
+        out_gate=True, tie_head=False,
+        moe=models.Experts(held=2, d_ff=128, routed=4, per_token=2,
+                           shared_d_ff=128))
+
+
+@pytest.fixture(scope="module")
+def pattern_grad_text():
+    cfg = pattern_config(32)
+    params = jax.eval_shape(
+        lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    return compiled_text(jax.value_and_grad(
+        lambda p, t: models.transformer_loss(p, t, cfg)), params, tokens)
+
+
+@pytest.mark.parametrize("scope", [
+    "hvdt.moe/hvdt.moe.route/", "hvdt.moe/hvdt.moe.dispatch/",
+    "hvdt.moe/hvdt.moe.experts/", "hvdt.moe/hvdt.moe.shared/"])
+@pytest.mark.parametrize("wrapper", [
+    "jvp()/while/body/closed_call/while/body/closed_call/hvdt.mlp/",
+    "checkpoint/rematted_computation/hvdt.mlp/",
+    "closed_call/checkpoint/hvdt.mlp/"])
+def test_the_sparse_feed_forward_carries_its_scopes_under_mlp(
+        pattern_grad_text, wrapper, scope):
+    """``hvdt.moe`` around the whole sparse feed-forward, under
+    ``hvdt.mlp`` (so the phase split's mlp remainder still holds it), and
+    inside it the four parts the benchmark's moe readers sum; forward,
+    recompute and backward, in the scan of the period's run of layers."""
+    assert wrapper + scope in pattern_grad_text
+    # the leading dense layer has an mlp and no moe
+    assert re.search(r"hvdt\.mlp/(?!hvdt\.moe)", pattern_grad_text)
+
+
+def test_a_pattern_lm_names_windowed_and_full_kernels_apart(monkeypatch):
+    """Lowered for the TPU: the leading full-causal layer's three Mosaic
+    calls under ``flash_fwd`` / ``flash_bwd``, the scanned sliding
+    layers' three under ``flash_win_fwd`` / ``flash_win_bwd`` (one call
+    site each for the run of two layers), grouped queries at head_dim 128
+    by the index map (k, v enter at their own width, nothing repeated)."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    cfg = pattern_config(256)
+    params = jax.eval_shape(
+        lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    text = jax.jit(jax.value_and_grad(
+        lambda p, t: models.transformer_loss(p, t, cfg))).trace(
+            params, tokens).lower(lowering_platforms=("tpu",)).as_text(
+                debug_info=True)
+
+    def location(line):
+        loc = re.search(r"loc\((#loc\d+)\)$", line).group(1)
+        return re.search(rf"^{loc} = loc\((.*)$", text, re.M).group(1)
+
+    calls = {}
+    for line in text.splitlines():
+        if "@tpu_custom_call" not in line:
+            continue
+        kernel = re.search(r"hvdt\.attention\)?/hvdt\.kernel\.(\w+)/",
+                           location(line)).group(1)
+        calls[kernel] = calls.get(kernel, 0) + 1
+        if kernel.endswith("fwd"):      # q [2,256,256], k, v [2,256,128]
+            assert "(tensor<2x256x256xbf16>, tensor<2x256x128xbf16>, " \
+                "tensor<2x256x128xbf16>)" in line
+    assert calls == {"flash_fwd": 2, "flash_bwd": 1, "flash_win_fwd": 2,
+                     "flash_win_bwd": 1}
+    # The grouped products are ragged dots, all under the experts' scope.
+    ragged = [location(line) for line in text.splitlines()
+              if "chlo.ragged_dot" in line]
+    assert len(ragged) >= 9             # 3 forward, 3 recompute, 6 backward
+    assert all("hvdt.moe/hvdt.moe.experts/" in loc for loc in ragged)

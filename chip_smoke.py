@@ -10,8 +10,9 @@ models the repo has on-chip history for, and checks what comes out:
 
 * the ``bert-large`` LM (seq 512) and ResNet-50 (bs 128 per chip): one
   compile, one warm-up, three steps, loss finite and falling;
-* the same LM at seq 4096, where ``auto`` must pick the streaming Pallas
-  attention kernel with no knob set, against XLA attention on the forward;
+* the same LM at seq 4096, where ``auto`` must pick the Pallas attention
+  kernels with no knob set (it does from seq 512 up, so the seq-512 LM
+  above runs them too), against XLA attention on the forward;
 * every ``pallas_call`` the package ships, compiled through Mosaic once
   and compared with the ``jnp`` reference beside it;
 * on more than one chip: the work and the memory are spread over all of

@@ -591,7 +591,8 @@ KNOBS: Dict[str, Knob] = {
            "Disable jax.profiler TraceAnnotation ranges around eager ops."),
         # --- kernels ---
         _k("HVDT_FLASH_ATTENTION", "auto", str,
-           "Pallas flash-attention kernel: auto (TPU only), on, off."),
+           "Pallas flash-attention kernel: auto (on a TPU, from the "
+           "measured crossover: sequences of 512 and longer), on, off."),
         _k("HVDT_FUSED_CONV1X1", False, _parse_bool,
            "Route eligible ResNet 1x1 conv+BN(+ReLU) blocks through the "
            "fused Pallas kernels (ops/conv_fused.py): train mode emits "

@@ -10,8 +10,7 @@ decided here and nowhere else, from what can be observed at trace time:
   and where no such island can be opened it does not run;
 * the policy on the LOCAL shapes (:func:`kernel_enabled`):
   ``HVDT_FLASH_ATTENTION=auto|on|off``, whether the shapes tile, the
-  bytes of the f32 score tensor the XLA path would materialize, the
-  platform;
+  sequence length against the measured crossover, the platform;
 * then ``pallas_kernels.flash_attention`` (its blocks come from the
   shape), or the XLA path below.
 
@@ -35,28 +34,40 @@ from . import pallas_kernels
 __all__ = ["attention", "kernel_enabled", "kernel_plan"]
 
 
+# The shortest sequence at which the kernel path is no slower than XLA
+# attention.  Measured on a v5e (PERF.md section 6, PR 32, the crossover
+# table): the whole 24-layer step at BERT-Large widths (16 heads of 64,
+# bf16, full remat), kernels against XLA attention at sequence 128 to
+# 2048, at 16,384 and at 65,536 tokens a step.  At either token count
+# the kernels are 19-21% ahead at 512 (58% at 1024 x 16, 116% at 2048 x
+# 8), level to 3% behind at 256 and 11-13% behind at 128; they are the
+# lighter path at every point, and from 1024 x 64 the only one that fits.
+_CROSSOVER_SEQ = 512
+
+
 def kernel_enabled(seq_len: int, *, batch: int, heads: int) -> bool:
     """Flash kernel policy: HVDT_FLASH_ATTENTION=auto|on|off.
 
-    'auto' (default) engages the kernel on TPU only when the
-    materialized-score path would be memory-heavy: the f32 score tensor
-    ``batch x heads x L x L`` at or past 4 GiB.  The kernel is a
-    CAPACITY play — measured on v5e at BERT-Large widths (PERF.md): at
-    seq 512 x 128 (2 GiB of scores) XLA attention fits and is 12% faster
-    than the kernel path (section 6, PR 28); at seq 4096 x 8 (8 GiB) XLA
-    attention with its backward does not fit at all.  'on' forces the
-    kernel whenever shapes tile; 'off' is the master switch.
+    'auto' (default) engages the kernel on TPU wherever the shapes tile
+    and the sequence is at least ``_CROSSOVER_SEQ`` long.  The rule is a
+    length, not a size: XLA attention's cost a token grows with L
+    (``heads x L`` f32 scores a token, written and read several times a
+    pass) and the kernels' cost a call does not, so where the two paths
+    cross depends on L and hardly on the batch; the bytes of the
+    ``[batch, heads, L, L]`` scores mix capacity with speed and could
+    not separate the measured points.  'on' forces the kernel whenever
+    shapes tile; 'off' is the master switch.
 
     ``batch``/``heads`` are the sizes the kernel will actually see —
-    pass LOCAL (per-shard) sizes when the call site shards them."""
+    pass LOCAL (per-shard) sizes when the call site shards them.  The
+    measured rule reads neither."""
     mode = config.get_str("HVDT_FLASH_ATTENTION").lower()
     if mode == "off":
         return False
     shapes_ok = seq_len % min(128, seq_len) == 0 and seq_len >= 8
     if mode == "on":
         return shapes_ok
-    score_bytes = 4 * batch * heads * seq_len * seq_len
-    return (shapes_ok and score_bytes >= 4 * 1024 ** 3
+    return (shapes_ok and seq_len >= _CROSSOVER_SEQ
             and jax.devices()[0].platform == "tpu")
 
 

@@ -15,15 +15,21 @@ import horovod_tpu.ops.pallas_kernels as pk
 KERNEL, XLA = True, False
 
 # (mode, platform, batch, heads, seq) -> kernel or XLA; batch and heads
-# are the LOCAL sizes.  The f32 score tensor is 4 * b * h * L * L bytes.
+# are the LOCAL sizes.  `auto` on a TPU is a sequence length
+# (att._CROSSOVER_SEQ, 512: PERF.md section 6, PR 32).
 POLICY = [
-    # the three LM cells of BENCHMARK.json, per chip
-    ("auto", "tpu", 128, 16, 512, XLA),       # s512_b128: 2 GiB of scores
-    ("auto", "tpu", 8, 16, 4096, KERNEL),     # s4096_b8: 8 GiB
-    ("auto", "tpu", 32, 16, 512, XLA),        # s512_dp4: 0.5 GiB
+    # the LM cells of BENCHMARK.json, per chip
+    ("auto", "tpu", 128, 16, 512, KERNEL),    # lm24x1024_s512_b128
+    ("auto", "tpu", 8, 16, 4096, KERNEL),     # lm24x1024_s4096_b8
+    ("auto", "tpu", 32, 16, 512, KERNEL),     # lm24x1024_s512_dp4
+    ("auto", "tpu", 2, 48, 8192, KERNEL),     # laguna_xs2_s8192
     ("auto", "cpu", 8, 16, 4096, XLA),        # auto never off the TPU
-    ("auto", "tpu", 4, 16, 4096, KERNEL),     # 4 GiB: the edge, inside
-    ("auto", "tpu", 3, 16, 4096, XLA),        # 3 GiB: the edge, outside
+    ("auto", "cpu", 128, 16, 512, XLA),
+    # the edge is a length, whatever the batch (384 tiles; 512 x 16 x
+    # 384 x 384 f32 scores are 4.5 GiB)
+    ("auto", "tpu", 1, 16, 512, KERNEL),      # inside
+    ("auto", "tpu", 512, 16, 384, XLA),       # outside
+    ("auto", "tpu", 256, 16, 256, XLA),       # under the crossover
     ("on", "cpu", 2, 4, 128, KERNEL),         # forced wherever shapes tile
     ("on", "tpu", 128, 16, 512, KERNEL),
     ("on", "tpu", 2, 4, 130, XLA),            # 130 % 128 != 0

@@ -1,0 +1,13 @@
+"""Linear mixer: device time per step in the Gated DeltaNet sublayer
+(``hvdt.gdn``: pre-norm, the two input projections, the convolution, the
+chunked scan, the gated norm, the output projection), forward, recompute
+and backward together (device trace joined to the compiled step's
+``op_name``s, ``benchmark/phase_split.py``).  A sibling of
+``attention_ms``, which keeps meaning softmax attention.  Moves
+``tokens_per_s_chip``."""
+
+from benchmark.phase_split import scope_metric
+
+
+def read(ctx):
+    return scope_metric(ctx, "hvdt.gdn")

@@ -593,6 +593,16 @@ def kernel_flash_gqa128(*, batch=2, seq=8192, heads=48, kv_heads=8,
                         window=None)
 
 
+def kernel_flash_gqa256(*, batch=1, seq=16384, heads=16, kv_heads=2,
+                        head_dim=256):
+    """The local kernels full-causal at head_dim 256, groups of 8: the
+    full-attention layer's calls of qwen3_next_s16384 (a block is one head
+    of 256 lanes; blocks of 2048 forward and backward at this length)."""
+    _flash_grouped_case("flash_attention gqa256", batch=batch, seq=seq,
+                        heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+                        window=None)
+
+
 def kernel_flash_window(*, batch=2, seq=8192, heads=64, kv_heads=8,
                         head_dim=128, window=512):
     """The windowed local kernels (hvdt.kernel.flash_win_fwd / _bwd): the
@@ -755,8 +765,8 @@ def kernel_quant_int4(*, size=1 << 24, block=256):
 
 
 KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
-           kernel_flash_backward, kernel_flash_gqa128, kernel_flash_window,
-           kernel_flash_grad_block,
+           kernel_flash_backward, kernel_flash_gqa128, kernel_flash_gqa256,
+           kernel_flash_window, kernel_flash_grad_block,
            kernel_conv_bn_relu, kernel_conv_bn_train, kernel_fused_adam,
            kernel_fused_sgd, kernel_quant_int8, kernel_quant_int4)
 

@@ -39,6 +39,16 @@ PRESETS = {
                                "laguna_xs2.json"),
         seq=8192, batch=2, dtype="bfloat16", remat=True, loss_chunk=8192,
         dp=1, tp=1),
+    # Qwen3-Next-80B-A3B on one chip's share of a 16-chip layer: the
+    # benchmark's configuration qwen3_next_80b (cell qwen3_next_s16384):
+    # three Gated DeltaNet layers to one gated full-attention layer, 32 of
+    # 512 experts held; 626M parameters, 9.3 GiB of training state.
+    "qwen3-next": dict(
+        published=os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               os.pardir, "benchmark", "configs",
+                               "qwen3_next_80b.json"),
+        seq=16384, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
+        dp=1, tp=1),
 }
 
 
@@ -155,6 +165,13 @@ def main():
             experts_first=published.get("experts_first", 0),
             vocab=published.get("vocab"),
             router_score=published.get("router_score", "sigmoid"),
+            # what the model's code does and its config.json has no key
+            # for, where the file states it
+            shared_gate=published.get("shared_expert_gate", False),
+            **{field: published[key] for field, key in (
+                ("out_gate", "attn_output_gate"), ("qk_norm", "qk_norm"),
+                ("zero_centered_norm", "zero_centered_norm"))
+               if key in published},
             max_seq=args.seq, dtype=getattr(jnp, args.dtype),
             remat=args.remat, remat_policy=args.remat_policy,
             loss_chunk=args.loss_chunk)

@@ -12,6 +12,7 @@ attention over ``sp``, MoE over ``ep``, pipeline stacking over ``pp``.
 from .transformer import (  # noqa: F401
     TransformerConfig,
     LayerKind,
+    LinearMixer,
     Rope,
     Experts,
     config_from_published,

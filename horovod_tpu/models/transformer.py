@@ -35,13 +35,14 @@ import numpy as np
 from jax import lax
 
 from ..ops.attention import attention
+from ..ops.gated_delta import gated_delta_net, scan_macs_per_token
 from ..parallel.moe import moe_dispatch_combine, moe_held_experts
 from ..parallel.pipeline import pipeline_spmd
 from ..parallel.ring_attention import ring_attention
 from ..quant import fp8 as _fp8
 
 __all__ = [
-    "TransformerConfig", "LayerKind", "Rope", "Experts",
+    "TransformerConfig", "LayerKind", "LinearMixer", "Rope", "Experts",
     "config_from_published", "transformer_init", "transformer_apply",
     "transformer_loss", "transformer_logical_axes",
     "transformer_flops_per_token", "remat_from_env", "checkpoint_policy",
@@ -72,17 +73,48 @@ class Rope:
 
 
 @dataclasses.dataclass(frozen=True)
+class LinearMixer:
+    """The sizes of a Gated DeltaNet mixer (``ops/gated_delta.py``):
+    ``key_heads`` query / key heads of ``key_dim``, ``value_heads`` value
+    heads of ``value_dim`` (value head n reads key head n // (value_heads
+    / key_heads)), a causal depthwise convolution of ``conv`` taps over
+    the q, k and v channels."""
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv: int = 4
+
+    @property
+    def key_width(self) -> int:
+        return self.key_heads * self.key_dim
+
+    @property
+    def value_width(self) -> int:
+        return self.value_heads * self.value_dim
+
+    @property
+    def sizes(self) -> Dict[str, int]:
+        """The head counts and sizes, as ``ops.gated_delta`` names them."""
+        return dict(key_heads=self.key_heads, value_heads=self.value_heads,
+                    key_dim=self.key_dim, value_dim=self.value_dim)
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerKind:
-    """One kind of layer of a pattern: its mixer (attention with its own
-    head counts, window and rotary settings) and its feed-forward (dense
-    SwiGLU of width ``d_ff``, or with ``sparse`` the configuration's expert
-    layer, ``TransformerConfig.moe``)."""
+    """One kind of layer of a pattern: its mixer (softmax attention with
+    its own head counts, window and rotary settings; or, with ``linear``
+    set, the Gated DeltaNet of those sizes, which reads none of the
+    attention fields) and its feed-forward (dense SwiGLU of width
+    ``d_ff``, or with ``sparse`` the configuration's expert layer,
+    ``TransformerConfig.moe``)."""
     heads: int
     kv_heads: int
     d_ff: int = 0
     window: Optional[int] = None     # query i sees keys i - window < j <= i
     rope: Rope = Rope()
     sparse: bool = False
+    linear: Optional[LinearMixer] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +133,7 @@ class Experts:
     scale: float = 1.0
     gated: bool = True               # SwiGLU experts (else silu(x W_up) W_down)
     shared_d_ff: int = 0             # > 0: a shared expert of this width
+    shared_gate: bool = False        # its output times sigmoid(x . ws_sg)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,10 +186,25 @@ class TransformerConfig:
     leading: Tuple[LayerKind, ...] = ()
     period: Tuple[LayerKind, ...] = ()
     moe: Optional[Experts] = None    # the expert layer of ``sparse`` kinds
-    out_gate: bool = False       # per-head sigmoid gate on attention's output
+    # A sigmoid gate on attention's output, from the layer's normed input:
+    # "" none; "head" one a head, from a matrix of its own (``wg`` [d,
+    # heads]); "elementwise" one a dimension, from the query projection's
+    # second half (``wq`` [d, heads x 2 head_dim], a head's columns its
+    # query then its gate).  True / False read as "head" / "".
+    out_gate: str = ""
     tie_head: bool = True        # False: an output matrix of its own, "head"
+    qk_norm: bool = False        # q and k RMS-normed a head before RoPE
+    # Every RMSNorm but the linear mixer's gated one scales by 1 + gain,
+    # the gain initialised 0 (else by the gain, initialised 1).
+    zero_centered_norm: bool = False
 
     def __post_init__(self):
+        if isinstance(self.out_gate, bool):
+            object.__setattr__(self, "out_gate",
+                               "head" if self.out_gate else "")
+        if self.out_gate not in ("", "head", "elementwise"):
+            raise ValueError(
+                f"out_gate={self.out_gate!r}: '', 'head' or 'elementwise'")
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.heads)
         if self.period:
@@ -222,6 +270,7 @@ def config_from_published(published: Dict[str, Any], *,
                           experts_first: int = 0,
                           vocab: Optional[int] = None,
                           router_score: str = "sigmoid",
+                          shared_gate: bool = False,
                           **fields) -> TransformerConfig:
     """A pattern configuration from a model's published settings, cut to
     this device's share of it.
@@ -230,28 +279,50 @@ def config_from_published(published: Dict[str, Any], *,
     own names: ``hidden_size``, ``head_dim``, ``num_attention_heads`` or
     ``num_attention_heads_per_layer``, ``num_key_value_heads``,
     ``layer_types`` (``full_attention`` / ``sliding_attention``, with
-    ``sliding_window``), ``rope_parameters`` (by layer type, or one group),
-    ``mlp_layer_types`` (``dense`` of ``intermediate_size`` / ``sparse``:
-    ``num_experts`` of ``moe_intermediate_size``, ``num_experts_per_tok``
-    picked, ``moe_routed_scaling_factor``, a shared expert of
+    ``sliding_window`` / ``linear_attention``, a Gated DeltaNet of
+    ``linear_num_key_heads``, ``linear_num_value_heads``,
+    ``linear_key_head_dim``, ``linear_value_head_dim``,
+    ``linear_conv_kernel_dim``) or ``full_attention_interval`` (every
+    n-th layer full attention, the others linear, as ``transformers``
+    derives ``layer_types`` from it), ``rope_parameters`` (by layer type,
+    or one group) or ``rope_theta`` and ``partial_rotary_factor`` at top
+    level, ``mlp_layer_types`` (``dense`` of ``intermediate_size`` /
+    ``sparse``: ``num_experts`` of ``moe_intermediate_size``,
+    ``num_experts_per_tok`` picked, ``norm_topk_prob``,
+    ``moe_routed_scaling_factor``, a shared expert of
     ``shared_expert_intermediate_size``), ``gating``,
     ``tie_word_embeddings``, ``vocab_size``, ``num_hidden_layers``.  No
     width is an argument.  The cut: the first ``layers`` layers (the
     leading ones and at least a period), ``experts`` of each sparse layer's
     experts from ``experts_first`` on (the router keeps its width), the
-    first ``vocab`` rows of the vocabulary.  ``router_score`` is what
-    ``config.json`` leaves to modelling code; ``fields`` are further
-    ``TransformerConfig`` fields (``max_seq``, ``dtype``, ``remat``, ...).
+    first ``vocab`` rows of the vocabulary.  ``router_score`` and
+    ``shared_gate`` (the shared expert's sigmoid gate) are what
+    ``config.json`` leaves to modelling code, as are the ``fields``
+    ``out_gate``, ``qk_norm`` and ``zero_centered_norm``; ``fields`` are
+    further ``TransformerConfig`` fields (``max_seq``, ``dtype``,
+    ``remat``, ...).
     """
     c = published
     depth = c["num_hidden_layers"]
     head_dim = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
     heads = c.get("num_attention_heads_per_layer") or \
         [c["num_attention_heads"]] * depth
-    layer_types = c.get("layer_types") or ["full_attention"] * depth
+    interval = c.get("full_attention_interval")
+    layer_types = c.get("layer_types") or [
+        "linear_attention" if interval and (i + 1) % interval
+        else "full_attention" for i in range(depth)]
     mlp_types = c.get("mlp_layer_types") or \
         ["sparse" if c.get("num_experts") else "dense"] * depth
-    ropes = c.get("rope_parameters") or {"rope_theta": c["rope_theta"]}
+    ropes = c.get("rope_parameters") or {
+        "rope_theta": c["rope_theta"],
+        "partial_rotary_factor": c.get("partial_rotary_factor", 1)}
+    linear = LinearMixer(
+        key_heads=c["linear_num_key_heads"],
+        value_heads=c["linear_num_value_heads"],
+        key_dim=c["linear_key_head_dim"],
+        value_dim=c["linear_value_head_dim"],
+        conv=c["linear_conv_kernel_dim"]) \
+        if "linear_attention" in layer_types else None
 
     def rope_of(layer_type: str) -> Rope:
         r = ropes.get(layer_type, ropes)
@@ -269,13 +340,20 @@ def config_from_published(published: Dict[str, Any], *,
                 "attention_factor",
                 0.1 * math.log(r["factor"]) + 1.0 if yarn else 1.0)))
 
-    kinds = [LayerKind(
-        heads=heads[i], kv_heads=c["num_key_value_heads"],
-        d_ff=0 if mlp_types[i] == "sparse" else c["intermediate_size"],
-        window=c["sliding_window"]
-        if layer_types[i] == "sliding_attention" else None,
-        rope=rope_of(layer_types[i]), sparse=mlp_types[i] == "sparse")
-        for i in range(depth)]
+    def kind_of(i: int) -> LayerKind:
+        feed_forward = dict(
+            d_ff=0 if mlp_types[i] == "sparse" else c["intermediate_size"],
+            sparse=mlp_types[i] == "sparse")
+        if layer_types[i] == "linear_attention":
+            return LayerKind(heads=0, kv_heads=0, linear=linear,
+                             **feed_forward)
+        return LayerKind(
+            heads=heads[i], kv_heads=c["num_key_value_heads"],
+            window=c["sliding_window"]
+            if layer_types[i] == "sliding_attention" else None,
+            rope=rope_of(layer_types[i]), **feed_forward)
+
+    kinds = [kind_of(i) for i in range(depth)]
     # The fewest leading layers after which the published stack repeats
     # (at least twice), and its shortest period.
     lead, period = next(
@@ -296,13 +374,16 @@ def config_from_published(published: Dict[str, Any], *,
             held=experts or routed, d_ff=c["moe_intermediate_size"],
             routed=routed, per_token=c["num_experts_per_tok"],
             first=experts_first, score=router_score,
+            normalize=bool(c.get("norm_topk_prob", True)),
             scale=float(c.get("moe_routed_scaling_factor", 1.0)),
-            shared_d_ff=c.get("shared_expert_intermediate_size", 0))
+            shared_d_ff=c.get("shared_expert_intermediate_size", 0),
+            shared_gate=shared_gate)
+    fields.setdefault("out_gate", "head" if c.get("gating") else "")
     return TransformerConfig(
         vocab=vocab or c["vocab_size"], layers=layers,
         d_model=c["hidden_size"], head_dim=head_dim,
         leading=tuple(kinds[:lead]), period=tuple(kinds[lead:lead + period]),
-        moe=moe, out_gate=bool(c.get("gating", False)),
+        moe=moe,
         tie_head=bool(c.get("tie_word_embeddings", True)), **fields)
 
 
@@ -310,21 +391,58 @@ def _init_linear(key, fan_in, shape, dtype):
     return (jax.random.normal(key, shape) * (fan_in ** -0.5)).astype(dtype)
 
 
+def _norm_gain(cfg: TransformerConfig, shape) -> jax.Array:
+    """A norm's gain as initialised: 1, or 0 where the norm scales by
+    1 + gain."""
+    return (jnp.zeros if cfg.zero_centered_norm else jnp.ones)(
+        shape, cfg.param_dtype)
+
+
+def _init_linear_mixer(ks, cfg: TransformerConfig, m: LinearMixer) -> Dict:
+    """The Gated DeltaNet's leaves (``ops.gated_delta.gated_delta_net``
+    says what each is).  ``a_log`` = log(u), u uniform in [1e-3, 16), and
+    ``dt_bias`` = 1, as the published model initialises them."""
+    d, pd = cfg.d_model, cfg.param_dtype
+    kw, vw = m.key_width, m.value_width
+    return {
+        "w_qkvz": _init_linear(next(ks), d, (d, 2 * kw + 2 * vw), pd),
+        "w_ba": _init_linear(next(ks), d, (d, 2 * m.value_heads), pd),
+        "conv": _init_linear(next(ks), m.conv, (m.conv, 2 * kw + vw), pd),
+        "a_log": jnp.log(jax.random.uniform(
+            next(ks), (m.value_heads,), minval=1e-3, maxval=16.0)
+        ).astype(pd),
+        "dt_bias": jnp.ones((m.value_heads,), pd),
+        "gdn_norm": jnp.ones((m.value_dim,), pd),
+        "w_out": _init_linear(next(ks), vw, (vw, d), pd),
+    }
+
+
 def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
-    """One layer of a pattern: attention of ``kind``'s sizes (with ``wg``
-    where the output is gated) and its dense or sparse feed-forward."""
+    """One layer of a pattern: its mixer (attention of ``kind``'s sizes,
+    with ``wg`` or a query projection twice as wide where the output is
+    gated and ``q_norm`` / ``k_norm`` where q and k are normed; or the
+    linear mixer's leaves) and its dense or sparse feed-forward."""
     d, dh, pd = cfg.d_model, cfg.head_dim, cfg.param_dtype
     h, hk = kind.heads, kind.kv_heads
-    ks = iter(jax.random.split(key, 12))
-    p = {
-        "ln1": jnp.ones((d,), pd), "ln2": jnp.ones((d,), pd),
-        "wq": _init_linear(next(ks), d, (d, h * dh), pd),
-        "wk": _init_linear(next(ks), d, (d, hk * dh), pd),
-        "wv": _init_linear(next(ks), d, (d, hk * dh), pd),
-        "wo": _init_linear(next(ks), h * dh, (h * dh, d), pd),
-    }
-    if cfg.out_gate:
-        p["wg"] = _init_linear(next(ks), d, (d, h), pd)
+    # Twelve keys serve every layer from before the shared expert's gate;
+    # the further ones are drawn apart, so that those layers' trees stay.
+    ks = iter((*jax.random.split(key, 12),
+               *jax.random.split(jax.random.fold_in(key, 12), 4)))
+    p = {"ln1": _norm_gain(cfg, (d,)), "ln2": _norm_gain(cfg, (d,))}
+    if kind.linear is not None:
+        p.update(_init_linear_mixer(ks, cfg, kind.linear))
+    else:
+        wide = 2 if cfg.out_gate == "elementwise" else 1
+        p.update(
+            wq=_init_linear(next(ks), d, (d, h * dh * wide), pd),
+            wk=_init_linear(next(ks), d, (d, hk * dh), pd),
+            wv=_init_linear(next(ks), d, (d, hk * dh), pd),
+            wo=_init_linear(next(ks), h * dh, (h * dh, d), pd))
+        if cfg.out_gate == "head":
+            p["wg"] = _init_linear(next(ks), d, (d, h), pd)
+        if cfg.qk_norm:
+            p["q_norm"] = _norm_gain(cfg, (dh,))
+            p["k_norm"] = _norm_gain(cfg, (dh,))
     if not kind.sparse:
         f = kind.d_ff
         p["w_up"] = _init_linear(next(ks), d, (d, f), pd)
@@ -343,6 +461,8 @@ def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
         p["ws_up"] = _init_linear(next(ks), d, (d, fs), pd)
         p["ws_gate"] = _init_linear(next(ks), d, (d, fs), pd)
         p["ws_down"] = _init_linear(next(ks), fs, (fs, d), pd)
+        if moe.shared_gate:
+            p["ws_sg"] = _init_linear(next(ks), d, (d,), pd)
     return p
 
 
@@ -356,7 +476,7 @@ def _pattern_init(key: jax.Array, cfg: TransformerConfig) -> Dict:
     params = {
         "embed": (jax.random.normal(k_embed, (cfg.vocab, d)) * 0.02
                   ).astype(pd),
-        "ln_f": jnp.ones((d,), pd),
+        "ln_f": _norm_gain(cfg, (d,)),
         "lead": {str(i): _init_layer(k, cfg, kind) for i, (k, kind) in
                  enumerate(zip(jax.random.split(k_lead,
                                                 max(len(cfg.leading), 1)),
@@ -454,11 +574,19 @@ def transformer_logical_axes(cfg: TransformerConfig) -> Dict:
 def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
     def layer(kind: LayerKind, stacked: int):
         lead = (None,) * stacked
-        axes = {"ln1": (None,), "ln2": (None,),
-                "wq": ("embed", "heads"), "wk": ("embed", "kv"),
-                "wv": ("embed", "kv"), "wo": ("heads", "embed")}
-        if cfg.out_gate:
-            axes["wg"] = ("embed", "heads")
+        axes = {"ln1": (None,), "ln2": (None,)}
+        if kind.linear is not None:
+            axes.update(w_qkvz=("embed", "heads"), w_ba=("embed", None),
+                        conv=(None, "heads"), a_log=(None,),
+                        dt_bias=(None,), gdn_norm=(None,),
+                        w_out=("heads", "embed"))
+        else:
+            axes.update(wq=("embed", "heads"), wk=("embed", "kv"),
+                        wv=("embed", "kv"), wo=("heads", "embed"))
+            if cfg.out_gate == "head":
+                axes["wg"] = ("embed", "heads")
+            if cfg.qk_norm:
+                axes.update(q_norm=(None,), k_norm=(None,))
         if kind.sparse:
             axes.update(w_router=("embed", None),
                         w_up=("experts", "embed", "mlp"),
@@ -468,6 +596,8 @@ def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
             if cfg.moe.shared_d_ff:
                 axes.update(ws_up=("embed", "mlp"), ws_gate=("embed", "mlp"),
                             ws_down=("mlp", "embed"))
+                if cfg.moe.shared_gate:
+                    axes["ws_sg"] = ("embed",)
         else:
             axes.update(w_up=("embed", "mlp"), w_gate=("embed", "mlp"),
                         w_down=("mlp", "embed"))
@@ -487,6 +617,12 @@ def _rmsnorm(x, g):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)).astype(
         x.dtype) * g.astype(x.dtype)
+
+
+def _norm(x, g, cfg: TransformerConfig):
+    """The configuration's RMSNorm: scaled by the gain, or by 1 + gain."""
+    return _rmsnorm(x, 1.0 + g.astype(jnp.float32)
+                    if cfg.zero_centered_norm else g)
 
 
 def _rope(x, positions, theta):
@@ -553,39 +689,54 @@ def _proj(x, w):
     return x @ w.astype(x.dtype)
 
 
-def _qkv(p, x, positions, cfg: TransformerConfig,
-         kind: Optional[LayerKind] = None):
+def _qkv_gate(p, x, positions, cfg: TransformerConfig,
+              kind: Optional[LayerKind] = None):
     """Rotated q/k/v projections — the one place the projection + RoPE
     recipe lives, shared by training attention (:func:`_attention`) and
     the serving paged-KV prefill/decode paths, so the cache can never
-    hold keys rotated differently from the ones training computed.
-    ``kind`` is the layer's (None: the uniform configuration's)."""
+    hold keys rotated differently from the ones training computed —
+    and, where the output gate is the query projection's second half, the
+    gate's pre-activation [B, L, H, D] (else None).  ``kind`` is the
+    layer's (None: the uniform configuration's)."""
     kind = kind or cfg.uniform_kind
     b, l, _ = x.shape
     h, hk, dh = kind.heads, kind.kv_heads, cfg.head_dim
-    q = _proj(x, p["wq"]).reshape(b, l, h, dh)
+    q, gate = _proj(x, p["wq"]), None
+    if cfg.out_gate == "elementwise":
+        q, gate = jnp.split(q.reshape(b, l, h, 2 * dh), 2, axis=-1)
+    q = q.reshape(b, l, h, dh)
     k = _proj(x, p["wk"]).reshape(b, l, hk, dh)
     v = _proj(x, p["wv"]).reshape(b, l, hk, dh)
+    if cfg.qk_norm:
+        q, k = _norm(q, p["q_norm"], cfg), _norm(k, p["k_norm"], cfg)
     q = _rope_of(q, positions, kind.rope)
     k = _rope_of(k, positions, kind.rope)
-    return q, k, v
+    return q, k, v, gate
+
+
+def _qkv(p, x, positions, cfg: TransformerConfig,
+         kind: Optional[LayerKind] = None):
+    """:func:`_qkv_gate`'s q, k, v (the serving paths': no gate there)."""
+    return _qkv_gate(p, x, positions, cfg, kind)[:3]
 
 
 def _attention(p, x, positions, cfg: TransformerConfig,
                kind: Optional[LayerKind] = None):
     kind = kind or cfg.uniform_kind
     b, l, _ = x.shape
-    q, k, v = _qkv(p, x, positions, cfg, kind)
+    q, k, v, gate = _qkv_gate(p, x, positions, cfg, kind)
     if cfg.sp > 1:
         # Manual island: the sequence dim is the local sp shard here (the
         # caller's shard_map over {'sp'} has already split it).
         o = ring_attention(q, k, v, axis="sp", causal=True)
     else:
         o = attention(q, k, v, window=kind.window)
-    if cfg.out_gate:
+    if cfg.out_gate == "head":
         # A gate a head, from the layer's normed input.
         gate = jax.nn.sigmoid(_proj(x, p["wg"]).astype(jnp.float32))
         o = o * gate.astype(o.dtype)[..., None]
+    elif gate is not None:
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
     return _proj(o.reshape(b, l, kind.heads * cfg.head_dim), p["wo"])
 
 
@@ -619,8 +770,13 @@ def _moe_mlp(p, x, cfg: TransformerConfig):
     shared = None
     if moe.shared_d_ff:
         def shared(h):
-            return _mlp({"w_up": p["ws_up"], "w_gate": p["ws_gate"],
-                         "w_down": p["ws_down"]}, h)
+            y = _mlp({"w_up": p["ws_up"], "w_gate": p["ws_gate"],
+                      "w_down": p["ws_down"]}, h)
+            if moe.shared_gate:
+                y = y * jax.nn.sigmoid(
+                    h.astype(jnp.float32) @ p["ws_sg"].astype(jnp.float32)
+                )[:, None].astype(y.dtype)
+            return y
     with jax.named_scope("hvdt.moe"):
         out, aux = moe_held_experts(
             tokens, p["w_router"], p["w_up"], p["w_down"], p.get("w_gate"),
@@ -692,14 +848,21 @@ def _block(p, x, positions, cfg: TransformerConfig,
     kind = kind or cfg.uniform_kind
     # Each sublayer with its pre-norm under one name, for the profiler
     # and the benchmark's phase split (docs/observability.md).
-    with jax.named_scope("hvdt.attention"):
-        a = _attention(p, _rmsnorm(x, p["ln1"]), positions, cfg, kind)
+    if kind.linear is not None:
+        # A sibling of hvdt.attention, so that attention_ms keeps meaning
+        # softmax attention.
+        with jax.named_scope("hvdt.gdn"):
+            a = gated_delta_net(_norm(x, p["ln1"], cfg), p, proj=_proj,
+                                **kind.linear.sizes)
+    else:
+        with jax.named_scope("hvdt.attention"):
+            a = _attention(p, _norm(x, p["ln1"], cfg), positions, cfg, kind)
     x = x + a
     with jax.named_scope("hvdt.mlp"):
         if kind.sparse:
-            y, _ = _moe_mlp(p, _rmsnorm(x, p["ln2"]), cfg)
+            y, _ = _moe_mlp(p, _norm(x, p["ln2"], cfg), cfg)
         else:
-            y = _mlp(p, _rmsnorm(x, p["ln2"]))
+            y = _mlp(p, _norm(x, p["ln2"], cfg))
     return x + y
 
 
@@ -813,7 +976,7 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
 
         x = pcast_to_union(x, *jax.tree.leaves(params["block"]))
         x = _scan_blocks(params["block"], x, positions, cfg)
-    return _rmsnorm(x, params["ln_f"])
+    return _norm(x, params["ln_f"], cfg)
 
 
 def transformer_apply(params: Dict, tokens: jax.Array,
@@ -1117,24 +1280,35 @@ def transformer_prefill_collect(params, tokens, cfg: TransformerConfig):
 
 def transformer_flops_per_token(cfg: TransformerConfig) -> float:
     """Approximate forward-pass matmul FLOPs per token (for MFU metrics):
-    the full score square, a window at its width, of a sparse layer the
-    router, a token's picks that land on held experts in expectation and
-    the shared expert."""
+    the full score square, a window at its width, the output gate's
+    projection, a linear mixer's projections, convolution and chunked
+    scan; of a sparse layer the router, a token's picks that land on held
+    experts in expectation and the shared expert with its gate."""
     d, dh = cfg.d_model, cfg.head_dim
 
-    def layer(kind: LayerKind) -> float:
+    def mixer(kind: LayerKind) -> float:
+        m = kind.linear
+        if m is not None:
+            kw, vw = m.key_width, m.value_width
+            return 2 * (d * (2 * kw + 2 * vw + 2 * m.value_heads)
+                        + m.conv * (2 * kw + vw) + vw * d
+                        + scan_macs_per_token(**m.sizes))
         h, hk = kind.heads, kind.kv_heads
-        attn_proj = 2 * d * (h * dh + 2 * hk * dh + h * dh)
+        gate = {"": 0, "head": h, "elementwise": h * dh}[cfg.out_gate]
+        attn_proj = 2 * d * (h * dh + 2 * hk * dh + h * dh + gate)
         attn_scores = 2 * 2 * min(kind.window or cfg.max_seq,
                                   cfg.max_seq) * h * dh     # approx
+        return attn_proj + attn_scores
+
+    def layer(kind: LayerKind) -> float:
         if not kind.sparse:
-            return attn_proj + attn_scores + 2 * d * kind.d_ff * 3
+            return mixer(kind) + 2 * d * kind.d_ff * 3
         moe = cfg.experts
         routed = moe.routed or moe.held
         mats = 3 if moe.gated else 2
-        return (attn_proj + attn_scores + 2 * d * routed
+        return (mixer(kind) + 2 * d * routed
                 + 2 * d * moe.d_ff * mats * moe.per_token * moe.held / routed
-                + 2 * d * moe.shared_d_ff * 3)
+                + 2 * d * moe.shared_d_ff * 3 + 2 * d * moe.shared_gate)
 
     if cfg.period:
         layers = (sum(map(layer, cfg.leading))

@@ -1,0 +1,283 @@
+"""The Gated DeltaNet mixer: a linear-attention layer whose state obeys
+the gated delta rule, computed in chunks.
+
+Per value head, with a state ``S`` [key_dim, value_dim] that starts at 0,
+a decay ``exp(g_t)`` in (0, 1] and a write strength ``beta_t`` in (0, 1):
+
+    S' = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'^T k_t)
+    S_t = S' + k_t u_t^T;   o_t = S_t^T q_t
+
+:func:`gated_delta_rule` runs the same in chunks of ``CHUNK`` tokens, so
+that the work inside a chunk is batched matrix products over (batch,
+head, chunk) and only the state's pass from chunk to chunk is sequential
+(``gamma_i = exp(sum_{j<=i} g_j)`` inside the chunk, ``S_0`` the state
+entering it):
+
+    A_ij = beta_i (gamma_i / gamma_j) (k_i . k_j) for j < i, else 0
+    T    = (I + A)^-1
+    U    = T (beta * V) - T (beta * gamma * K) S_0
+    O    = gamma * (Q S_0) + tril((gamma_i / gamma_j) (q_i . k_j)) U
+    S_C  = gamma_C S_0 + ((gamma_C / gamma) * K)^T U
+
+What stays float32 whatever the compute dtype: the decays and their
+cumulative sum (an exponent: its absolute error is the result's relative
+one), every ratio of decays (taken as exp of a difference, never as a
+quotient, so nothing overflows where gamma underflows), ``A`` and its
+inverse ``T`` (forward substitution row by row: exact where the powers of
+``A`` that a Neumann series would sum grow combinatorially, for which
+equal neighbouring keys are enough), and the
+state ``S`` carried from chunk to chunk (256 updates a sequence of
+16,384 would each round it).  The products take operands in the compute
+dtype and accumulate in float32.
+
+:func:`gated_delta_net` is the whole mixer as the model calls it (the
+input projections, a causal depthwise convolution, the rule, the gated
+norm a head, the output projection), each part under its own scope
+(``hvdt.gdn.proj`` / ``.conv`` / ``.scan`` / ``.norm``; the model opens
+``hvdt.gdn`` around the call and its pre-norm).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Callable, Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+__all__ = ["CHUNK", "causal_conv", "gated_delta_rule", "gated_rmsnorm",
+           "gated_delta_net", "scan_macs_per_token"]
+
+CHUNK = 64
+
+
+def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
+    """A causal depthwise convolution, no bias: x [B, L, C], w [taps, C];
+    ``y_t = sum_i w[i] x[t - (taps - 1) + i]`` with zeros left of the
+    sequence.  Shifted multiply-adds: each tap's slice of the padded input
+    is widened to float32 inside the one fusion that sums them.  Measured
+    on the v5e at [1, 16384, 8192] bf16 with the silu, ms forward /
+    forward + backward (PERF.md, PR 33): this form 2.00 / 7.03; the padded
+    input widened to float32 first 5.29 / 11.36; rolls and a mask 7.06 /
+    17.68; ``lax.conv_general_dilated`` with one channel a group 6.55
+    forward."""
+    taps, l = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    w = w.astype(jnp.float32)
+    return sum(padded[:, i:i + l].astype(jnp.float32) * w[i]
+               for i in range(taps)).astype(x.dtype)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + a)^-1`` for strictly lower triangular ``a`` [..., C, C] in
+    float32, by forward substitution, row by row: row i of the inverse is
+    ``e_i - sum_k a[i, k] (row k)`` over the rows before it (the later
+    ones are still 0, and ``a[i, k]`` is 0 there anyway).  Exact where a
+    Neumann series is not: the powers of ``a`` grow combinatorially where
+    neighbouring keys are alike (a strict lower triangle of ones reaches
+    1e18 by the 32nd) and cancel to nothing in float32.  The matrices are
+    laid [row, column, matrix] for it, so that a row of all of them is one
+    slab whose last dimension is the matrices (whole vector lanes, where a
+    64-wide row fills half of them): a step is one multiply-and-sum over
+    the slabs and one slab written; no product goes to the MXU.  Not
+    differentiated: the inverse's own cotangent rule is two products
+    (``_unit_lower_inverse_bwd``)."""
+    c = a.shape[-1]
+    cols = jnp.transpose(a.reshape((-1, c, c)), (1, 2, 0))  # [i, k, m]
+    eye = jnp.eye(c, dtype=a.dtype)
+
+    def row(i, t, done):                # t [k, j, m]: rows < i are final
+        a_i = lax.dynamic_index_in_dim(cols, i, 0, keepdims=False)
+        e_i = lax.dynamic_index_in_dim(eye, i, 0, keepdims=False)
+        new = e_i[:, None] - (a_i[:done, None, :] * t[:done]).sum(0)
+        return lax.dynamic_update_index_in_dim(t, new, i, 0)
+
+    # cols * 0: zeros that vary over a shard_map's axes as the rows will.
+    # In stages, so that a row reads the rows up to its stage's end only.
+    t, stage = cols * 0.0, min(c, 16)
+    for start in range(0, c, stage):
+        end = min(start + stage, c)
+        t = lax.fori_loop(start, end, functools.partial(row, done=end), t)
+    return jnp.transpose(t, (2, 0, 1)).reshape(a.shape)
+
+
+def _unit_lower_inverse_fwd(a):
+    t = _unit_lower_inverse(a)
+    return t, t
+
+
+def _unit_lower_inverse_bwd(t, ct):
+    # d(T) = -T d(a) T, so <ct, dT> = <-T^T ct T^T, da>, on the strict
+    # lower triangle a lives on.
+    c = t.shape[-1]
+    tt = jnp.swapaxes(t, -1, -2)
+    grad = -jnp.matmul(jnp.matmul(tt, ct, precision=lax.Precision.HIGHEST),
+                       tt, precision=lax.Precision.HIGHEST)
+    return (jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), grad, 0.0),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _l2norm(x):
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
+                     g: jax.Array, beta: jax.Array, *,
+                     chunk: int = CHUNK,
+                     carry_dtype=jnp.float32) -> jax.Array:
+    """The gated delta rule over whole sequences, in chunks.
+
+    q, k: [B, L, Hk, dk] (L2-normalised a head here, q also divided by
+    sqrt(dk)); v: [B, L, Hv, dv] with ``Hk`` dividing ``Hv`` (value head n
+    reads key head ``n // (Hv / Hk)``); g (the log of the decay, <= 0)
+    and beta: [B, L, Hv] float32.  Returns o [B, L, Hv, dv] in float32.
+    A length that is not whole chunks is padded at its end with tokens
+    that write nothing (k = v = beta = g = 0) and whose outputs are
+    dropped.  ``carry_dtype`` is the dtype of what is carried along the
+    sequence, the cumulative sum of the log-decays inside a chunk and the
+    state from chunk to chunk: float32; the benchmark's control passes
+    bfloat16 to show what its reference check tells apart."""
+    b, l, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r = hv // hk
+    dt = v.dtype
+    f32 = jnp.float32
+    pad = (-l) % chunk
+    n = (l + pad) // chunk
+
+    def chunks(x, *tail):
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return x.reshape((b, n, chunk) + tail)
+
+    qn = chunks((_l2norm(q) * dk ** -0.5).astype(dt), hk, dk)
+    kn = chunks(_l2norm(k).astype(dt), hk, dk)
+    v = chunks(v, hk, r, dv)
+    beta = chunks(beta.astype(f32), hk, r)
+    # gamma inside the chunk, as its log: [B, N, C, Hk, R]
+    gc = jnp.cumsum(chunks(g.astype(f32), hk, r).astype(carry_dtype),
+                    axis=2).astype(f32)
+    gamma = jnp.exp(gc)
+    # gamma_i / gamma_j for j <= i, 0 above the diagonal: [B,N,Hk,R,C,C]
+    gc_rows = jnp.moveaxis(gc, 2, -1)                   # [B,N,Hk,R,C]
+    diff = gc_rows[..., :, None] - gc_rows[..., None, :]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    ratio = jnp.exp(jnp.where(lower, diff, -jnp.inf))
+
+    def pairs(x, y):                    # x_i . y_j a key head: [B,N,Hk,C,C]
+        return jnp.einsum("bnihd,bnjhd->bnhij", x, y,
+                          preferred_element_type=f32)
+
+    beta_rows = jnp.moveaxis(beta, 2, -1)[..., None]    # [B,N,Hk,R,C,1]
+    a = jnp.where(jnp.tril(lower, -1),
+                  beta_rows * ratio * pairs(kn, kn)[:, :, :, None], 0.0)
+    t = _unit_lower_inverse(a).astype(dt)
+    attn = (ratio * pairs(qn, kn)[:, :, :, None]).astype(dt)
+
+    def rows(m, x):                     # m [B,N,Hk,R,C,C] @ x [B,N,C,Hk,R,D]
+        return jnp.einsum("bnhrij,bnjhrd->bnhrid", m, x,
+                          preferred_element_type=f32)
+
+    kv = kn[:, :, :, :, None]                           # [B,N,C,Hk,1,dk]
+    u_own = rows(t, (beta[..., None] * v).astype(dt))   # T (beta V)
+    w = rows(t, ((beta * gamma)[..., None] * kv).astype(dt)).astype(dt)
+    # (gamma_C / gamma) K, and gamma_C
+    k_out = (jnp.exp(gc[:, :, -1:] - gc)[..., None] * kv).astype(dt)
+    gamma_end = gamma[:, :, -1]                         # [B, N, Hk, R]
+
+    def step(s, xs):                    # s [B, Hk, R, dk, dv], carry_dtype
+        w_n, u_n, k_n, g_n = xs
+        u = (u_n - jnp.einsum("bhrid,bhrde->bhrie", w_n, s.astype(dt),
+                              preferred_element_type=f32)).astype(dt)
+        s_next = (g_n[..., None, None] * s.astype(f32)
+                  + jnp.einsum("bihrd,bhrie->bhrde", k_n, u,
+                               preferred_element_type=f32))
+        return s_next.astype(carry_dtype), (s.astype(dt), u)
+
+    first = lambda x: jnp.moveaxis(x, 1, 0)             # noqa: E731
+    xs = (first(w), first(u_own), first(k_out), first(gamma_end))
+    s0 = jnp.zeros((b, hk, r, dk, dv), carry_dtype)
+    # Inside a shard_map the operands are varying over its axes and so is
+    # the state the body returns: the initial state has to match.
+    vma = tuple(set().union(*(jax.typeof(x).vma for x in xs)))
+    if vma:
+        s0 = lax.pcast(s0, vma, to="varying")
+    _, (s_in, u) = lax.scan(step, s0, xs)
+    s_in, u = jnp.moveaxis(s_in, 0, 1), jnp.moveaxis(u, 0, 1)
+    q_in = (gamma[..., None] * qn[:, :, :, :, None]).astype(dt)
+    # The first product leaves in the compute dtype (the MXU accumulates
+    # in float32 all the same; the CPU's runtime has no bf16 x bf16 = f32
+    # product in this transposed form) and is added in float32.
+    o = (jnp.einsum("bnihrd,bnhrde->bnihre", q_in, s_in).astype(f32)
+         + jnp.einsum("bnhrij,bnhrje->bnihre", attn, u,
+                      preferred_element_type=f32))
+    return o.reshape(b, n * chunk, hv, dv)[:, :l]
+
+
+def gated_rmsnorm(o: jax.Array, z: jax.Array, w: jax.Array) -> jax.Array:
+    """``RMS(o) * w * silu(z)`` over the last dimension (a head), in
+    float32; eps 1e-6."""
+    o, z = o.astype(jnp.float32), z.astype(jnp.float32)
+    o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + 1e-6)
+    return o * w.astype(jnp.float32) * jax.nn.silu(z)
+
+
+def gated_delta_net(x: jax.Array, p: Dict[str, jax.Array], *,
+                    key_heads: int, value_heads: int, key_dim: int,
+                    value_dim: int,
+                    proj: Callable[[jax.Array, jax.Array], jax.Array]
+                    ) -> jax.Array:
+    """The Gated DeltaNet mixer on x [B, L, d] (already normed).
+
+    ``p``: ``w_qkvz`` [d, 2 Kd + 2 Vd] with the columns [q | k | v | z]
+    (Kd = key_heads x key_dim, Vd = value_heads x value_dim), ``w_ba`` [d,
+    2 value_heads] with the columns [b | a], ``conv`` [taps, 2 Kd + Vd]
+    over the channels [q | k | v], ``a_log`` and ``dt_bias``
+    [value_heads], ``gdn_norm`` [value_dim], ``w_out`` [Vd, d].  ``proj``
+    is the model's dense projection (``x @ w`` in the compute dtype)."""
+    b, l, _ = x.shape
+    kd, vd = key_heads * key_dim, value_heads * value_dim
+    f32 = jnp.float32
+    with jax.named_scope("hvdt.gdn.proj"):
+        # Two products on the matrix's column blocks, so that no [q|k|v|z]
+        # row exists: the convolution's and the gate's cotangents would
+        # each be padded to its width, in float32.
+        qkv = proj(x, p["w_qkvz"][:, :2 * kd + vd])
+        z = proj(x, p["w_qkvz"][:, 2 * kd + vd:])
+        # b and a feed a sigmoid and an exponent's argument: float32 out
+        ba = x.astype(f32) @ p["w_ba"].astype(f32)
+    with jax.named_scope("hvdt.gdn.conv"):
+        qkv = jax.nn.silu(causal_conv(qkv, p["conv"]))
+    with jax.named_scope("hvdt.gdn.scan"):
+        beta = jax.nn.sigmoid(ba[..., :value_heads])
+        g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., value_heads:] + p["dt_bias"].astype(f32))
+        o = gated_delta_rule(
+            qkv[..., :kd].reshape(b, l, key_heads, key_dim),
+            qkv[..., kd:2 * kd].reshape(b, l, key_heads, key_dim),
+            qkv[..., 2 * kd:].reshape(b, l, value_heads, value_dim),
+            g, beta)
+    with jax.named_scope("hvdt.gdn.norm"):
+        y = gated_rmsnorm(o, z.reshape(b, l, value_heads, value_dim),
+                          p["gdn_norm"]).astype(x.dtype)
+    with jax.named_scope("hvdt.gdn.proj"):
+        return proj(y.reshape(b, l, vd), p["w_out"])
+
+
+def scan_macs_per_token(*, key_heads: int, value_heads: int, key_dim: int,
+                        value_dim: int, chunk: int = CHUNK) -> float:
+    """Forward multiply-adds a token of :func:`gated_delta_rule`: a key
+    head's two pair products (k.k, q.k), a value head's three chunk
+    products (T beta V, T beta gamma K, the pairs times U), its three
+    products with the state (W S_0, Q S_0, K^T U) and the inverse's
+    C^3 / 3, over the chunk's tokens."""
+    c = chunk
+    return (key_heads * 2 * c * c * key_dim
+            + value_heads * (c * c * (key_dim + 2 * value_dim)
+                             + 3 * c * key_dim * value_dim
+                             + c ** 3 / 3)) / c

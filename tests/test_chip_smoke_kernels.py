@@ -13,6 +13,8 @@ TOY_KERNELS = dict(
     kernel_flash_backward=dict(batch=1, seq=256, heads=2, head_dim=64),
     kernel_flash_gqa128=dict(batch=1, seq=256, heads=4, kv_heads=2,
                              head_dim=128),
+    kernel_flash_gqa256=dict(batch=1, seq=256, heads=8, kv_heads=1,
+                             head_dim=256),
     kernel_flash_window=dict(batch=1, seq=512, heads=4, kv_heads=2,
                              head_dim=128, window=128),
     kernel_flash_grad_block=dict(batch=1, seq=256, heads=2, head_dim=64),

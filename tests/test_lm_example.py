@@ -47,18 +47,8 @@ def test_dp2_tp2_hybrid_with_remat_and_chunked_loss():
                  "--loss-chunk", "128"]) > 0
 
 
-@pytest.mark.integration
-def test_a_published_layer_pattern_model_by_the_same_path(tmp_path):
-    """--published (the route the laguna-xs2 preset takes): the benchmark's
-    Laguna-XS.2 configuration file at toy widths, five layers of three
-    kinds with 4 of 16 experts held, through the example's single-device
-    step."""
-    import json
-
-    with open(os.path.join(REPO, "benchmark", "configs",
-                           "laguna_xs2.json")) as f:
-        published = json.load(f)
-    published.update(
+TOY_PUBLISHED = {
+    "laguna_xs2": lambda published: dict(
         hidden_size=64, head_dim=32, num_key_value_heads=2,
         num_attention_heads_per_layer=[
             6 if h == 48 else 8
@@ -66,7 +56,32 @@ def test_a_published_layer_pattern_model_by_the_same_path(tmp_path):
         sliding_window=16, intermediate_size=128, moe_intermediate_size=16,
         shared_expert_intermediate_size=16, num_experts=16,
         num_experts_per_tok=2, vocab_size=512, experts=4, experts_first=4,
-        vocab=256)
+        vocab=256),
+    "qwen3_next_80b": lambda published: dict(
+        hidden_size=64, head_dim=32, num_attention_heads=4,
+        num_key_value_heads=2, num_attention_heads_per_layer=[4] * 48,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=16, linear_value_head_dim=16,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        num_experts=16, num_experts_per_tok=3, vocab_size=512, experts=4,
+        experts_first=4, vocab=256),
+}
+
+
+@pytest.mark.integration
+@pytest.mark.parametrize("name", list(TOY_PUBLISHED))
+def test_a_published_layer_pattern_model_by_the_same_path(tmp_path, name):
+    """--published (the route the laguna-xs2 and qwen3-next presets take):
+    the benchmark's configuration file at toy widths (Laguna-XS.2: five
+    layers of three kinds; Qwen3-Next: three Gated DeltaNet layers and a
+    gated full-attention one; 4 of 16 experts held), through the example's
+    single-device step."""
+    import json
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           name + ".json")) as f:
+        published = json.load(f)
+    published.update(TOY_PUBLISHED[name](published))
     path = tmp_path / "toy.json"
     path.write_text(json.dumps(published))
     out = subprocess.run(
@@ -77,3 +92,18 @@ def test_a_published_layer_pattern_model_by_the_same_path(tmp_path):
         capture_output=True, text=True, timeout=200, cwd=REPO)
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     assert TOKS.search(out.stdout) and "done." in out.stdout
+
+
+def test_the_presets_of_published_models_name_the_benchmarks_files():
+    sys.path.insert(0, os.path.dirname(SCRIPT))
+    try:
+        import jax_transformer_lm as example
+    finally:
+        sys.path.pop(0)
+    for preset, name, seq, batch in (("laguna-xs2", "laguna_xs2", 8192, 2),
+                                     ("qwen3-next", "qwen3_next_80b", 16384,
+                                      1)):
+        got = example.PRESETS[preset]
+        assert os.path.samefile(got["published"], os.path.join(
+            REPO, "benchmark", "configs", name + ".json"))
+        assert (got["seq"], got["batch"]) == (seq, batch)

@@ -87,27 +87,41 @@ def test_the_held_part_of_the_routed_sum_and_its_gradients(first, held,
                                    atol=2e-4, err_msg=name)
 
 
-def test_the_shares_add_up_to_the_uncut_reference_layer():
-    """Four shares of four experts each, the shared expert counted once:
-    their parts sum to what benchmark/reference/laguna.py gives for the
-    whole layer (all 16 experts held)."""
-    from benchmark.reference import laguna as reference
+@pytest.mark.parametrize("model, shares, route", [
+    ("laguna", 4, dict(top_k=K, score="sigmoid", scale=2.5)),
+    ("qwen3_next", 16, dict(top_k=3, score="softmax"))])
+def test_the_shares_add_up_to_the_uncut_reference_layer(model, shares, route):
+    """The shares of a layer (four of four experts each as Laguna's eight
+    chips would hold them; sixteen of one each as Qwen3-Next's sixteen),
+    the shared expert counted once (Qwen3-Next's times its sigmoid gate):
+    their parts sum to what the model's plain reference under
+    benchmark/reference gives for the whole layer (all 16 experts held)."""
+    import importlib
 
+    reference = importlib.import_module(f"benchmark.reference.{model}")
     w = _weights(3)
-    route = dict(top_k=K, score="sigmoid", scale=2.5)
-
-    def shared(h):
-        return (jax.nn.silu(h @ w["ws_gate"]) * (h @ w["ws_up"])
-                ) @ w["ws_down"]
-
-    parts = [jax.jit(lambda w, first=first: _layer(
-        w, first, 4, shared_fn=shared if first == 0 else None,
-        **route)[0])(w) for first in (0, 4, 8, 12)]
+    held = E // shares
     p = {k: w[k] for k in ("w_router", "w_up", "w_gate", "w_down", "ws_up",
                            "ws_gate", "ws_down")}
+    if model == "qwen3_next":
+        p["ws_sg"] = jax.random.normal(jax.random.PRNGKey(9), (D,))
+
+    def shared(h):
+        y = (jax.nn.silu(h @ w["ws_gate"]) * (h @ w["ws_up"])) @ w["ws_down"]
+        if "ws_sg" in p:
+            y = y * jax.nn.sigmoid(h @ p["ws_sg"])[:, None]
+        return y
+
+    parts = [jax.jit(lambda w, first=first: _layer(
+        w, first, held, shared_fn=shared if first == 0 else None,
+        **route)[0])(w) for first in range(0, E, held)]
     with jax.default_matmul_precision("highest"):
-        whole = reference.sparse(w["x"], p, per_token=K, scaling=2.5,
-                                 first=0)
+        if model == "laguna":
+            whole = reference.sparse(w["x"], p, per_token=K, scaling=2.5,
+                                     first=0)
+        else:
+            whole = reference.sparse(w["x"], p, per_token=3, first=0,
+                                     normalise=True)
     np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=2e-5)
     # and one share alone is not the layer
     assert float(jnp.abs(parts[0] - whole).max()) > 1e-2

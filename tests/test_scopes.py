@@ -423,3 +423,59 @@ def test_a_pattern_lm_names_windowed_and_full_kernels_apart(monkeypatch):
               if "chlo.ragged_dot" in line]
     assert len(ragged) >= 9             # 3 forward, 3 recompute, 6 backward
     assert all("hvdt.moe/hvdt.moe.experts/" in loc for loc in ragged)
+
+
+# ---------------------------------------------------------------------------
+# The linear mixer: hvdt.gdn beside hvdt.attention, and its four parts.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def hybrid_grad_text():
+    linear = models.LayerKind(
+        heads=0, kv_heads=0, sparse=True, linear=models.LinearMixer(
+            key_heads=1, value_heads=2, key_dim=16, value_dim=16))
+    full = models.LayerKind(heads=2, kv_heads=1, sparse=True)
+    cfg = models.TransformerConfig(
+        vocab=256, d_model=32, head_dim=16, layers=3,
+        period=(linear, linear, full), max_seq=64, remat=True,
+        out_gate="elementwise", qk_norm=True, zero_centered_norm=True,
+        tie_head=False, moe=models.Experts(
+            held=2, d_ff=16, routed=4, per_token=2, score="softmax",
+            shared_d_ff=16, shared_gate=True))
+    params = jax.eval_shape(
+        lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    return compiled_text(jax.value_and_grad(
+        lambda p, t: models.transformer_loss(p, t, cfg)), params, tokens)
+
+
+@pytest.mark.parametrize("scope", [
+    "hvdt.gdn/hvdt.gdn.proj/", "hvdt.gdn/hvdt.gdn.conv/",
+    "hvdt.gdn/hvdt.gdn.scan/", "hvdt.gdn/hvdt.gdn.norm/"])
+@pytest.mark.parametrize("wrapper", [
+    "jvp()/while/body/closed_call/while/body/closed_call/",
+    "checkpoint/rematted_computation/",
+    "closed_call/checkpoint/"])
+def test_the_linear_mixer_carries_its_scopes(hybrid_grad_text, wrapper,
+                                             scope):
+    """``hvdt.gdn`` around the whole Gated DeltaNet sublayer and inside it
+    the four parts the benchmark's ``gdn_*`` readers sum; forward,
+    recompute and backward, in the scan of the period's run of layers."""
+    assert wrapper + scope in hybrid_grad_text
+
+
+def test_the_linear_mixer_is_a_sibling_of_attention(hybrid_grad_text):
+    """Nothing of the linear mixer is under ``hvdt.attention`` (so
+    ``attention_ms`` keeps meaning softmax attention), the full layer of the
+    same period keeps ``hvdt.attention``, the state's loop is a ``while``
+    under ``hvdt.gdn.scan``, and the shared expert's gate is inside
+    ``hvdt.moe.shared``."""
+    assert "hvdt.attention/hvdt.gdn" not in hybrid_grad_text
+    assert "hvdt.gdn/hvdt.attention" not in hybrid_grad_text
+    assert re.search(r"while/body/closed_call/hvdt\.attention/",
+                     hybrid_grad_text)
+    assert "hvdt.gdn/hvdt.gdn.scan/while/body/" in hybrid_grad_text
+    # the gate's sigmoid, beside the shared expert's own silu
+    assert re.search(r"hvdt\.moe/hvdt\.moe\.shared/(exp|logistic)\b",
+                     hybrid_grad_text)

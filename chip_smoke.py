@@ -716,6 +716,25 @@ def kernel_fused_sgd(*, shape=(3, 3, 512, 512)):
            want, rtol=1e-5, atol=1e-7)
 
 
+def kernel_gdn_inverse(*, matrices=8192, chunk=64):
+    """ops/pallas_kernels unit_lower_inverse_slabs against XLA's loop of the
+    same substitution, on the [C, C, matrices] slabs of one call of
+    qwen3_next_s16384's scan (256 chunks x 32 value heads)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import gated_delta as gd
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    if not pk.unit_lower_inverse_tiles(chunk):
+        raise AssertionError(f"chunk {chunk} does not tile the kernel")
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool), -1)[:, :, None]
+    cols = jnp.where(lower, 0.3 * jax.random.normal(
+        jax.random.PRNGKey(0), (chunk, chunk, matrices)), 0.0)
+    _close("gdn_inverse", jax.jit(pk.unit_lower_inverse_slabs)(cols),
+           jax.jit(gd._inverse_slabs_loop)(cols), rtol=1e-4, atol=1e-4)
+
+
 def _quant_roundtrip(name, quant, dequant, eligible, size, block):
     """One quantize/dequantize pair on a flat gradient bucket, Pallas
     against the XLA lowering.  A code may differ by one where the two
@@ -767,8 +786,9 @@ def kernel_quant_int4(*, size=1 << 24, block=256):
 KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
            kernel_flash_backward, kernel_flash_gqa128, kernel_flash_gqa256,
            kernel_flash_window, kernel_flash_grad_block,
-           kernel_conv_bn_relu, kernel_conv_bn_train, kernel_fused_adam,
-           kernel_fused_sgd, kernel_quant_int8, kernel_quant_int4)
+           kernel_conv_bn_relu, kernel_conv_bn_train, kernel_gdn_inverse,
+           kernel_fused_adam, kernel_fused_sgd, kernel_quant_int8,
+           kernel_quant_int4)
 
 
 def phase_kernels(kernels=KERNELS, **sizes):

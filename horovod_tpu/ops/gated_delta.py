@@ -25,7 +25,9 @@ one), every ratio of decays (taken as exp of a difference, never as a
 quotient, so nothing overflows where gamma underflows), ``A`` and its
 inverse ``T`` (forward substitution row by row: exact where the powers of
 ``A`` that a Neumann series would sum grow combinatorially, for which
-equal neighbouring keys are enough), and the
+equal neighbouring keys are enough; on a TPU the 64 row steps run in one
+Mosaic kernel on blocks of 128 matrices in VMEM, elsewhere as XLA's loop
+over the whole array: :func:`_unit_lower_inverse`), and the
 state ``S`` carried from chunk to chunk (256 updates a sequence of
 16,384 would each round it).  The products take operands in the compute
 dtype and accumulate in float32.
@@ -45,6 +47,8 @@ from typing import Callable, Dict
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from . import pallas_kernels
 
 __all__ = ["CHUNK", "causal_conv", "gated_delta_rule", "gated_rmsnorm",
            "gated_delta_net", "scan_macs_per_token"]
@@ -69,24 +73,27 @@ def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
                for i in range(taps)).astype(x.dtype)
 
 
-@jax.custom_vjp
-def _unit_lower_inverse(a: jax.Array) -> jax.Array:
-    """``(I + a)^-1`` for strictly lower triangular ``a`` [..., C, C] in
-    float32, by forward substitution, row by row: row i of the inverse is
-    ``e_i - sum_k a[i, k] (row k)`` over the rows before it (the later
-    ones are still 0, and ``a[i, k]`` is 0 there anyway).  Exact where a
-    Neumann series is not: the powers of ``a`` grow combinatorially where
-    neighbouring keys are alike (a strict lower triangle of ones reaches
-    1e18 by the 32nd) and cancel to nothing in float32.  The matrices are
-    laid [row, column, matrix] for it, so that a row of all of them is one
-    slab whose last dimension is the matrices (whole vector lanes, where a
-    64-wide row fills half of them): a step is one multiply-and-sum over
-    the slabs and one slab written; no product goes to the MXU.  Not
-    differentiated: the inverse's own cotangent rule is two products
-    (``_unit_lower_inverse_bwd``)."""
-    c = a.shape[-1]
-    cols = jnp.transpose(a.reshape((-1, c, c)), (1, 2, 0))  # [i, k, m]
-    eye = jnp.eye(c, dtype=a.dtype)
+def _on_tpu() -> bool:
+    """The kernels' own reading of the platform, so that a rehearsal that
+    lowers them through Mosaic also chooses as the chip does."""
+    return not pallas_kernels._use_interpret()
+
+
+def _inverse_on_kernel(c: int) -> bool:
+    """Which schedule of the substitution computes ``(I + a)^-1``: the
+    Mosaic kernel on a TPU for a chunk it tiles, XLA's loop otherwise (off
+    the TPU the kernel would run in the Pallas interpreter: every CPU test
+    of the model would pay it).  Read from the platform and the shape; no
+    knob."""
+    return _on_tpu() and pallas_kernels.unit_lower_inverse_tiles(c)
+
+
+def _inverse_slabs_loop(cols: jax.Array) -> jax.Array:
+    """XLA's schedule: a ``fori_loop`` of C row steps over the whole
+    [row, column, matrix] array, a step one multiply-and-sum over the
+    finished slabs and one slab written."""
+    c = cols.shape[0]
+    eye = jnp.eye(c, dtype=cols.dtype)
 
     def row(i, t, done):                # t [k, j, m]: rows < i are final
         a_i = lax.dynamic_index_in_dim(cols, i, 0, keepdims=False)
@@ -100,7 +107,42 @@ def _unit_lower_inverse(a: jax.Array) -> jax.Array:
     for start in range(0, c, stage):
         end = min(start + stage, c)
         t = lax.fori_loop(start, end, functools.partial(row, done=end), t)
-    return jnp.transpose(t, (2, 0, 1)).reshape(a.shape)
+    return t
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + a)^-1`` for strictly lower triangular ``a`` [..., C, C] in
+    float32, by forward substitution, row by row: row i of the inverse is
+    ``e_i - sum_k a[i, k] (row k)`` over the rows before it (the later
+    ones are still 0, and ``a[i, k]`` is 0 there anyway).  Exact where a
+    Neumann series is not: the powers of ``a`` grow combinatorially where
+    neighbouring keys are alike (a strict lower triangle of ones reaches
+    1e18 by the 32nd) and cancel to nothing in float32.  The matrices are
+    laid [row, column, matrix] for it, so that a row of all of them is one
+    slab whose last dimension is the matrices (whole vector lanes, where a
+    64-wide row fills half of them); no product goes to the MXU.
+
+    One algorithm, two schedules of it (:func:`_inverse_on_kernel`
+    chooses).  On a TPU, one Mosaic call
+    (``pallas_kernels.unit_lower_inverse_slabs``, under
+    ``hvdt.kernel.gdn_inverse``): a block of 128 matrices makes all C row
+    steps in VMEM, ``a`` is read once and the inverse written once.
+    Elsewhere, and for a C the kernel does not tile, XLA's ``fori_loop``
+    in four stages (:func:`_inverse_slabs_loop`), whose every row step
+    re-reads the finished rows from HBM.  On the v5e for the 8,192
+    matrices of 64 x 64 of one call of ``qwen3_next_s16384`` (device
+    events; my chip runs, PR 34, PERF.md section 6): the kernel 0.48 ms, at
+    the bound of its 268 MB, the loop 8.04; six calls a step, 47 of its
+    826 ms.
+
+    Not differentiated: the inverse's own cotangent rule is two products
+    (``_unit_lower_inverse_bwd``)."""
+    c = a.shape[-1]
+    cols = jnp.transpose(a.reshape((-1, c, c)), (1, 2, 0))  # [i, k, m]
+    slabs = (pallas_kernels.unit_lower_inverse_slabs
+             if _inverse_on_kernel(c) else _inverse_slabs_loop)
+    return jnp.transpose(slabs(cols), (2, 0, 1)).reshape(a.shape)
 
 
 def _unit_lower_inverse_fwd(a):

@@ -29,6 +29,11 @@ Two entry points, one tile body (``_online_softmax_update``):
   ``flash_grad_block`` for its backward: global offsets in, f32 partial
   sums out, for the same reason.
 
+A third kernel is not attention's: ``unit_lower_inverse_slabs(cols)``,
+the inverse of I + A for the Gated DeltaNet scan's chunks
+(``ops/gated_delta.py``), whose 64 sequential row steps XLA can only run
+as 64 passes over HBM and a program here runs on a block in VMEM.
+
 Which of the two runs is decided by which function the caller calls,
 and by nothing a user sets.  Both run in Pallas interpret mode off-TPU,
 so the CPU test suite exercises the very same kernel code
@@ -1335,6 +1340,115 @@ def flash_grad_block(q, k, v, do, out, lse, *, q_offset=0, k_offset=0,
         dk = dk.reshape(b, lk, hkv, group, d).sum(3)
         dv = dv.reshape(b, lk, hkv, group, d).sum(3)
     return dq, dk, dv
+
+
+_SUBLANES = 8                   # rows of a float32 vector register
+_INVERSE_LANES = 128            # matrices a program: one register's lanes
+_INVERSE_MOST = 64              # [C, C, lanes] in and out, double-buffered
+
+
+def unit_lower_inverse_tiles(c: int) -> bool:
+    """Whether :func:`unit_lower_inverse_slabs` takes matrices of c x c:
+    whole groups of 8 rows, and blocks that fit VMEM twice over (C = 64 is
+    2 MiB a block, 8 MiB in all)."""
+    return c % _SUBLANES == 0 and 0 < c <= _INVERSE_MOST
+
+
+def _inverse_kernel(a_ref, t_ref, *, guarded: bool):
+    """``(I + a)^-1`` for ``lanes`` strictly lower triangular matrices,
+    ``a_ref`` [row i, column k, matrix] -> ``t_ref`` [row k, column j,
+    matrix]: row i = e_i - sum over k < i of a[i, k] (row k), all C row
+    steps on the block in VMEM.  A row is a [C, lanes] slab, its columns
+    on the sublanes, so a[i, k] of all the matrices is one sublane spread
+    over the columns and a step is a multiply and a subtract on whole
+    registers; no product goes to the MXU, everything is float32.
+
+    Rows go in groups of 8.  A row of group s is 0 right of column
+    8 (s + 1), and so is every row it reads: it keeps 8 (s + 1) columns
+    and takes as many of each row before it, which is exact (what is
+    skipped is 0).  A group's rows are zeroed before its first row step
+    and the sum runs to the group's end, 8 steps a trip of its loop: a row
+    then reads its group's later rows as 0 times the 0 that ``a`` holds
+    there, and no bound depends on the row.
+
+    The steps are on whole [8 (s + 1), lanes] slabs, which Mosaic splits
+    into registers, and the sum is a loop: some 500 operations to trace
+    and lower.  Unrolled register by register, each row taking from row k
+    only the column groups up to k's own (half the multiplies), it was
+    3,300, hardly faster (0.40 ms a call against 0.48: the call is bound
+    by its bytes) and, with the branch below, 29 s more of set-up a run
+    (PERF.md, PR 34).
+
+    ``guarded`` puts all of it in a branch that is always taken, for
+    interpret mode under shard_map (the CPU test path): a loop that
+    carries a ref was typed when the kernel was traced, without the
+    operands' varying axes, and bound bare it is refused on them; inside
+    a branch it is not bound again (as ``_local_kernel``'s one_tile case).
+    Not on the TPU, where nothing is bound again and the branch costs
+    seconds to lower."""
+    from jax.experimental import pallas as pl
+
+    c, _, lanes = a_ref.shape
+    g = _SUBLANES
+
+    def group(s):
+        width = (s + 1) * g
+        col = jax.lax.broadcasted_iota(jnp.int32, (width, lanes), 0)
+        t_ref[s * g:width] = jnp.zeros((g, c, lanes), jnp.float32)
+
+        def row(i, carry):
+            def steps(trip, acc):
+                for step in range(g):
+                    k = trip * g + step
+                    acc = acc - a_ref[i, pl.ds(k, 1), :] * t_ref[
+                        k, :width, :]
+                return acc
+
+            t_ref[i, :width, :] = jax.lax.fori_loop(
+                0, s + 1, steps, jnp.where(col == i, 1.0, 0.0))     # e_i
+            return carry
+
+        jax.lax.fori_loop(s * g, width, row, 0)
+
+    def groups():
+        for s in range(c // g):
+            group(s)
+
+    if guarded:
+        pl.when(pl.program_id(0) >= 0)(groups)
+    else:
+        groups()
+
+
+def unit_lower_inverse_slabs(cols: jax.Array) -> jax.Array:
+    """``(I + a)^-1`` by forward substitution, one Mosaic call: ``cols``
+    [row, column, matrix] float32, strictly lower triangular a matrix,
+    any count of matrices (padded here to whole blocks of
+    ``_INVERSE_LANES``; a padded matrix is 0 and its inverse I) -> the
+    inverses in the same layout.  ``a`` is read once and the inverse
+    written once; the rows between never leave VMEM.  The caller checks
+    :func:`unit_lower_inverse_tiles` first."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c, _, m = cols.shape
+    lanes = _INVERSE_LANES
+    pad = (-m) % lanes
+    if pad:
+        cols = jnp.pad(cols, ((0, 0), (0, 0), (0, pad)))
+    spec = pl.BlockSpec((c, c, lanes), lambda mm: (0, 0, mm))
+    with jax.named_scope("hvdt.kernel.gdn_inverse"):
+        t = pl.pallas_call(
+            functools.partial(_inverse_kernel, guarded=_use_interpret()),
+            grid=((m + pad) // lanes,),
+            in_specs=[spec], out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct(cols.shape, jnp.float32,
+                                           **_vma_kw(cols)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            interpret=_use_interpret(),
+        )(cols)
+    return t[:, :, :m] if pad else t
 
 
 def attention_reference(q, k, v, *, causal=True, scale=None,

@@ -2,10 +2,11 @@
 scan against the delta rule run token by token (the recurrence of
 ``benchmark/reference/qwen3_next.py``: another derivation), values and the
 gradients of q, k, v, g and beta, at lengths that are and are not whole
-chunks (a ragged tail is padded, not refused); the inverse of I + A where
-a Neumann series would not survive; the convolution against XLA's own; the
-scan under a ``shard_map``; the operation count against hand-worked
-numbers.  CPU only."""
+chunks (a ragged tail is padded, not refused); the convolution against
+XLA's own; the scan under a ``shard_map`` and at another chunk length, on
+both schedules of the inverse of I + A (``tests/test_gated_delta_inverse.py``
+has the inverse itself and the ``schedule`` fixture); the operation count
+against hand-worked numbers.  CPU only."""
 
 import functools
 import os
@@ -23,6 +24,7 @@ sys.path.insert(0, REPO)
 
 from benchmark.reference import qwen3_next as reference  # noqa: E402
 from horovod_tpu.ops import gated_delta as gd  # noqa: E402
+from test_gated_delta_inverse import choose, jit, schedule  # noqa: E402,F401
 
 B, HK, HV, DK, DV = 2, 2, 4, 16, 8
 
@@ -71,11 +73,13 @@ def test_the_chunked_scan_is_the_recurrence(length):
                                    err_msg=name)
 
 
-def test_a_chunk_size_is_only_a_schedule():
+def test_a_chunk_size_is_only_a_schedule(schedule):
+    """Chunks of 16 against 64, each on either schedule of the inverse: the
+    whole rule through the kernel."""
     args = operands(96, seed=3)
     np.testing.assert_allclose(
-        jax.jit(functools.partial(gd.gated_delta_rule, chunk=16))(*args),
-        jax.jit(gd.gated_delta_rule)(*args), rtol=2e-5, atol=2e-6)
+        jit(functools.partial(gd.gated_delta_rule, chunk=16))(*args),
+        jit(gd.gated_delta_rule)(*args), rtol=2e-5, atol=2e-6)
 
 
 def test_strong_decays_underflow_to_zero_and_nothing_overflows():
@@ -105,38 +109,6 @@ def test_bfloat16_operands_keep_a_float32_state():
     assert err(rounded) >= err(got)
 
 
-@pytest.mark.parametrize("case", ["random", "equal_keys"])
-def test_the_inverse_of_i_plus_a(case):
-    """Against numpy's inverse in float64.  ``equal_keys``: a = the strict
-    lower triangle of ones (equal unit keys, beta 1, no decay), whose
-    powers reach 1e18 before they cancel; forward substitution gives the
-    bidiagonal inverse exactly."""
-    c = gd.CHUNK
-    if case == "random":
-        a = np.tril(np.random.default_rng(0).normal(size=(3, c, c)) * 0.3,
-                    -1)
-    else:
-        a = np.tril(np.ones((1, c, c)), -1)
-    got = jax.jit(gd._unit_lower_inverse)(jnp.asarray(a, jnp.float32))
-    want = np.linalg.inv(np.eye(c) + a)
-    np.testing.assert_allclose(got, want, rtol=1e-4,
-                               atol=1e-5 * np.abs(want).max())
-    # its cotangent rule against differentiating the substitution itself,
-    # on the leading 16 x 16 (the rule reads no size)
-    c = 16
-    a = a[:, :c, :c]
-    ct = jnp.asarray(np.random.default_rng(1).normal(size=a.shape),
-                     jnp.float32)
-    a32 = jnp.asarray(a, jnp.float32)
-    rule = jax.jit(jax.grad(
-        lambda x: jnp.sum(gd._unit_lower_inverse(x) * ct)))(a32)
-    plain = jax.jit(jax.grad(lambda x: jnp.sum(
-        gd._unit_lower_inverse.__wrapped__(x) * ct)))(a32)
-    mask = np.tril(np.ones((c, c), bool), -1)
-    np.testing.assert_allclose(rule, np.where(mask, plain, 0.0), rtol=2e-4,
-                               atol=2e-4 * float(jnp.abs(plain).max()))
-
-
 def test_the_convolution_is_xlas_depthwise_one():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 37, 12))
     w = jax.random.normal(jax.random.PRNGKey(1), (4, 12))
@@ -164,9 +136,10 @@ def test_the_gated_norm():
     np.testing.assert_allclose(gd.gated_rmsnorm(o, z, w), want, rtol=1e-5)
 
 
-def test_the_scan_runs_inside_a_shard_map(devices):
+def test_the_scan_runs_inside_a_shard_map(devices, schedule):
     """The state's initial value takes the operands' varying axes (the
-    benchmark's step is a shard_map over dp with every axis manual)."""
+    benchmark's step is a shard_map over dp with every axis manual), and
+    so does what the inverse's kernel returns."""
     mesh = Mesh(np.asarray(devices[:2]), ("dp",))
     args = operands(64)
     got = jax.jit(jax.shard_map(
@@ -174,6 +147,13 @@ def test_the_scan_runs_inside_a_shard_map(devices):
         out_specs=P("dp")))(*args)
     np.testing.assert_allclose(got, jax.jit(gd.gated_delta_rule)(*args),
                                rtol=1e-5, atol=1e-6)
+    # and the gradient, through the inverse's own cotangent rule
+    grad = lambda f: jax.jit(jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(f(*a) ** 2), argnums=(1, 4)))(*args)
+    for a, b in zip(grad(jax.shard_map(
+            gd.gated_delta_rule, mesh=mesh, in_specs=(P("dp"),) * 5,
+            out_specs=P("dp"))), grad(gd.gated_delta_rule)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
 def test_the_scans_operations_by_hand():

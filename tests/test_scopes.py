@@ -196,11 +196,19 @@ def _quant(kernel):
         use_kernels=True)
 
 
+def _gdn(kernel):
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    cols = jnp.zeros((16, 16, 128), jnp.float32)
+    return lambda: pk.unit_lower_inverse_slabs(cols)
+
+
 KERNEL_SITES = (
     [(_flash, k) for k in ("flash_fwd", "flash_win_fwd", "flash_fwd.ring",
                            "flash_bwd", "flash_win_bwd", "flash_dq",
                            "flash_dkv")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
+    + [(_gdn, "gdn_inverse")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
     + [(_quant, k) for k in ("quantize", "dequantize", "quantize4",
                              "dequantize4")])
@@ -430,8 +438,7 @@ def test_a_pattern_lm_names_windowed_and_full_kernels_apart(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture(scope="module")
-def hybrid_grad_text():
+def hybrid_config():
     linear = models.LayerKind(
         heads=0, kv_heads=0, sparse=True, linear=models.LinearMixer(
             key_heads=1, value_heads=2, key_dim=16, value_dim=16))
@@ -446,8 +453,13 @@ def hybrid_grad_text():
     params = jax.eval_shape(
         lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
     tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
-    return compiled_text(jax.value_and_grad(
-        lambda p, t: models.transformer_loss(p, t, cfg)), params, tokens)
+    return jax.value_and_grad(
+        lambda p, t: models.transformer_loss(p, t, cfg)), params, tokens
+
+
+@pytest.fixture(scope="module")
+def hybrid_grad_text():
+    return compiled_text(*hybrid_config())
 
 
 @pytest.mark.parametrize("scope", [
@@ -479,3 +491,46 @@ def test_the_linear_mixer_is_a_sibling_of_attention(hybrid_grad_text):
     # the gate's sigmoid, beside the shared expert's own silu
     assert re.search(r"hvdt\.moe/hvdt\.moe\.shared/(exp|logistic)\b",
                      hybrid_grad_text)
+
+
+def test_the_inverses_kernel_is_in_the_forward_and_the_recompute(
+        monkeypatch, hybrid_grad_text):
+    """With the kernels lowered through Mosaic as on a TPU (the lowering is
+    Python; the inverse's chooser reads the same switch): the run of two linear layers
+    holds the Mosaic call of ``(I + A)^-1`` twice, in the forward and in
+    ``rematted_computation``, each under ``hvdt.gdn/hvdt.gdn.scan/
+    hvdt.kernel.gdn_inverse`` (what ``gdn_scan_ms`` sums and what a later
+    ``gdn_inverse_ms`` picks by ``phase_split.scope_calls(ctx,
+    "hvdt.kernel.gdn_inverse", trace_reduce.is_mosaic)``), float32
+    ``[C, C, matrices]`` slabs in and out, and never under ``transpose(``:
+    the backward is the inverse's own two-product cotangent rule.  The
+    four-stage loop of XLA's schedule is gone from that text and is what
+    the CPU compiles (no kernel scope there)."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    assert "hvdt.kernel.gdn_inverse" not in hybrid_grad_text
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    fn, params, tokens = hybrid_config()
+    text = jax.jit(fn).trace(params, tokens).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+    def location(line):
+        loc = re.search(r"loc\((#loc\d+)\)$", line).group(1)
+        return re.search(rf"^{loc} = loc\((.*)$", text, re.M).group(1)
+
+    calls = [(location(line), line) for line in text.splitlines()
+             if "@tpu_custom_call" in line]
+    inverse = [(loc, line) for loc, line in calls
+               if "hvdt.kernel.gdn_inverse" in loc]
+    assert len(inverse) == 2
+    for loc, line in inverse:
+        assert re.search(r"hvdt\.gdn\)?/hvdt\.gdn\.scan\)?/"
+                         r"hvdt\.kernel\.gdn_inverse/", loc)
+        assert "hvdt.attention" not in loc and "transpose(" not in loc
+        # 2 sequences x 1 chunk x 2 value heads, padded to a block
+        assert "(tensor<64x64x128xf32>) -> tensor<64x64x128xf32>" in line
+    # inside the scanned body a name stack is relative to the call: the
+    # forward's is bare, the recompute's starts at its checkpoint
+    assert sorted(loc.split("hvdt.gdn/")[0].strip('"')
+                  for loc, _ in inverse) == [
+                      "", "checkpoint/rematted_computation/"]

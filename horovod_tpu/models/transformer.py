@@ -701,16 +701,18 @@ def _qkv_gate(p, x, positions, cfg: TransformerConfig,
     kind = kind or cfg.uniform_kind
     b, l, _ = x.shape
     h, hk, dh = kind.heads, kind.kv_heads, cfg.head_dim
-    q, gate = _proj(x, p["wq"]), None
-    if cfg.out_gate == "elementwise":
-        q, gate = jnp.split(q.reshape(b, l, h, 2 * dh), 2, axis=-1)
-    q = q.reshape(b, l, h, dh)
-    k = _proj(x, p["wk"]).reshape(b, l, hk, dh)
-    v = _proj(x, p["wv"]).reshape(b, l, hk, dh)
-    if cfg.qk_norm:
-        q, k = _norm(q, p["q_norm"], cfg), _norm(k, p["k_norm"], cfg)
-    q = _rope_of(q, positions, kind.rope)
-    k = _rope_of(k, positions, kind.rope)
+    with jax.named_scope("hvdt.attention.qkv"):
+        q, gate = _proj(x, p["wq"]), None
+        if cfg.out_gate == "elementwise":
+            q, gate = jnp.split(q.reshape(b, l, h, 2 * dh), 2, axis=-1)
+        q = q.reshape(b, l, h, dh)
+        k = _proj(x, p["wk"]).reshape(b, l, hk, dh)
+        v = _proj(x, p["wv"]).reshape(b, l, hk, dh)
+    with jax.named_scope("hvdt.attention.rope"):
+        if cfg.qk_norm:
+            q, k = _norm(q, p["q_norm"], cfg), _norm(k, p["k_norm"], cfg)
+        q = _rope_of(q, positions, kind.rope)
+        k = _rope_of(k, positions, kind.rope)
     return q, k, v, gate
 
 
@@ -725,19 +727,24 @@ def _attention(p, x, positions, cfg: TransformerConfig,
     kind = kind or cfg.uniform_kind
     b, l, _ = x.shape
     q, k, v, gate = _qkv_gate(p, x, positions, cfg, kind)
-    if cfg.sp > 1:
-        # Manual island: the sequence dim is the local sp shard here (the
-        # caller's shard_map over {'sp'} has already split it).
-        o = ring_attention(q, k, v, axis="sp", causal=True)
-    else:
-        o = attention(q, k, v, window=kind.window)
-    if cfg.out_gate == "head":
-        # A gate a head, from the layer's normed input.
-        gate = jax.nn.sigmoid(_proj(x, p["wg"]).astype(jnp.float32))
-        o = o * gate.astype(o.dtype)[..., None]
-    elif gate is not None:
-        o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
-    return _proj(o.reshape(b, l, kind.heads * cfg.head_dim), p["wo"])
+    # The kernels and all that ops/attention.py puts around them.
+    with jax.named_scope("hvdt.attention.core"):
+        if cfg.sp > 1:
+            # Manual island: the sequence dim is the local sp shard here
+            # (the caller's shard_map over {'sp'} has already split it).
+            o = ring_attention(q, k, v, axis="sp", causal=True)
+        else:
+            o = attention(q, k, v, window=kind.window)
+    with jax.named_scope("hvdt.attention.gate"):
+        if cfg.out_gate == "head":
+            # A gate a head, from the layer's normed input.
+            gate = jax.nn.sigmoid(_proj(x, p["wg"]).astype(jnp.float32))
+            o = o * gate.astype(o.dtype)[..., None]
+        elif gate is not None:
+            o = o * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(o.dtype)
+    with jax.named_scope("hvdt.attention.out"):
+        return _proj(o.reshape(b, l, kind.heads * cfg.head_dim), p["wo"])
 
 
 def _mlp(p, x):
@@ -937,7 +944,8 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
     else:
         offset = 0
     positions = offset + jnp.broadcast_to(jnp.arange(l), (b, l))
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    with jax.named_scope("hvdt.embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
     # Manual-island axes make activations varying (e.g. the MoE alltoall);
     # pre-cast so the scan-over-layers carry is type-stable under vma.
     from ..parallel.sharding import pcast_to_union

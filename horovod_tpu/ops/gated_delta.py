@@ -197,40 +197,13 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
             x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
         return x.reshape((b, n, chunk) + tail)
 
-    qn = chunks((_l2norm(q) * dk ** -0.5).astype(dt), hk, dk)
-    kn = chunks(_l2norm(k).astype(dt), hk, dk)
-    v = chunks(v, hk, r, dv)
-    beta = chunks(beta.astype(f32), hk, r)
-    # gamma inside the chunk, as its log: [B, N, C, Hk, R]
-    gc = jnp.cumsum(chunks(g.astype(f32), hk, r).astype(carry_dtype),
-                    axis=2).astype(f32)
-    gamma = jnp.exp(gc)
-    # gamma_i / gamma_j for j <= i, 0 above the diagonal: [B,N,Hk,R,C,C]
-    gc_rows = jnp.moveaxis(gc, 2, -1)                   # [B,N,Hk,R,C]
-    diff = gc_rows[..., :, None] - gc_rows[..., None, :]
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    ratio = jnp.exp(jnp.where(lower, diff, -jnp.inf))
-
     def pairs(x, y):                    # x_i . y_j a key head: [B,N,Hk,C,C]
         return jnp.einsum("bnihd,bnjhd->bnhij", x, y,
                           preferred_element_type=f32)
 
-    beta_rows = jnp.moveaxis(beta, 2, -1)[..., None]    # [B,N,Hk,R,C,1]
-    a = jnp.where(jnp.tril(lower, -1),
-                  beta_rows * ratio * pairs(kn, kn)[:, :, :, None], 0.0)
-    t = _unit_lower_inverse(a).astype(dt)
-    attn = (ratio * pairs(qn, kn)[:, :, :, None]).astype(dt)
-
     def rows(m, x):                     # m [B,N,Hk,R,C,C] @ x [B,N,C,Hk,R,D]
         return jnp.einsum("bnhrij,bnjhrd->bnhrid", m, x,
                           preferred_element_type=f32)
-
-    kv = kn[:, :, :, :, None]                           # [B,N,C,Hk,1,dk]
-    u_own = rows(t, (beta[..., None] * v).astype(dt))   # T (beta V)
-    w = rows(t, ((beta * gamma)[..., None] * kv).astype(dt)).astype(dt)
-    # (gamma_C / gamma) K, and gamma_C
-    k_out = (jnp.exp(gc[:, :, -1:] - gc)[..., None] * kv).astype(dt)
-    gamma_end = gamma[:, :, -1]                         # [B, N, Hk, R]
 
     def step(s, xs):                    # s [B, Hk, R, dk, dv], carry_dtype
         w_n, u_n, k_n, g_n = xs
@@ -241,24 +214,57 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                                preferred_element_type=f32))
         return s_next.astype(carry_dtype), (s.astype(dt), u)
 
-    first = lambda x: jnp.moveaxis(x, 1, 0)             # noqa: E731
-    xs = (first(w), first(u_own), first(k_out), first(gamma_end))
-    s0 = jnp.zeros((b, hk, r, dk, dv), carry_dtype)
-    # Inside a shard_map the operands are varying over its axes and so is
-    # the state the body returns: the initial state has to match.
-    vma = tuple(set().union(*(jax.typeof(x).vma for x in xs)))
-    if vma:
-        s0 = lax.pcast(s0, vma, to="varying")
-    _, (s_in, u) = lax.scan(step, s0, xs)
-    s_in, u = jnp.moveaxis(s_in, 0, 1), jnp.moveaxis(u, 0, 1)
-    q_in = (gamma[..., None] * qn[:, :, :, :, None]).astype(dt)
-    # The first product leaves in the compute dtype (the MXU accumulates
-    # in float32 all the same; the CPU's runtime has no bf16 x bf16 = f32
-    # product in this transposed form) and is added in float32.
-    o = (jnp.einsum("bnihrd,bnhrde->bnihre", q_in, s_in).astype(f32)
-         + jnp.einsum("bnhrij,bnhrje->bnihre", attn, u,
-                      preferred_element_type=f32))
-    return o.reshape(b, n * chunk, hv, dv)[:, :l]
+    # Three parts under the caller's hvdt.gdn.scan, one reader of the
+    # benchmark each: what is batched over the chunks, the sequential
+    # loop over them, and O from both.
+    with jax.named_scope("hvdt.gdn.scan.chunk"):
+        qn = chunks((_l2norm(q) * dk ** -0.5).astype(dt), hk, dk)
+        kn = chunks(_l2norm(k).astype(dt), hk, dk)
+        v = chunks(v, hk, r, dv)
+        beta = chunks(beta.astype(f32), hk, r)
+        # gamma inside the chunk, as its log: [B, N, C, Hk, R]
+        gc = jnp.cumsum(chunks(g.astype(f32), hk, r).astype(carry_dtype),
+                        axis=2).astype(f32)
+        gamma = jnp.exp(gc)
+        # gamma_i / gamma_j for j <= i, 0 above the diagonal:
+        # [B,N,Hk,R,C,C]
+        gc_rows = jnp.moveaxis(gc, 2, -1)               # [B,N,Hk,R,C]
+        diff = gc_rows[..., :, None] - gc_rows[..., None, :]
+        lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+        ratio = jnp.exp(jnp.where(lower, diff, -jnp.inf))
+        beta_rows = jnp.moveaxis(beta, 2, -1)[..., None]    # [B,N,Hk,R,C,1]
+        a = jnp.where(jnp.tril(lower, -1),
+                      beta_rows * ratio * pairs(kn, kn)[:, :, :, None], 0.0)
+        t = _unit_lower_inverse(a).astype(dt)
+        attn = (ratio * pairs(qn, kn)[:, :, :, None]).astype(dt)
+        kv = kn[:, :, :, :, None]                       # [B,N,C,Hk,1,dk]
+        u_own = rows(t, (beta[..., None] * v).astype(dt))   # T (beta V)
+        w = rows(t, ((beta * gamma)[..., None] * kv).astype(dt)).astype(dt)
+        # (gamma_C / gamma) K, and gamma_C
+        k_out = (jnp.exp(gc[:, :, -1:] - gc)[..., None] * kv).astype(dt)
+        gamma_end = gamma[:, :, -1]                     # [B, N, Hk, R]
+        first = lambda x: jnp.moveaxis(x, 1, 0)         # noqa: E731
+        xs = (first(w), first(u_own), first(k_out), first(gamma_end))
+        s0 = jnp.zeros((b, hk, r, dk, dv), carry_dtype)
+        # Inside a shard_map the operands are varying over its axes and
+        # so is the state the body returns: the initial state has to
+        # match.
+        vma = tuple(set().union(*(jax.typeof(x).vma for x in xs)))
+        if vma:
+            s0 = lax.pcast(s0, vma, to="varying")
+    with jax.named_scope("hvdt.gdn.scan.state"):
+        _, (s_in, u) = lax.scan(step, s0, xs)
+    with jax.named_scope("hvdt.gdn.scan.out"):
+        s_in, u = jnp.moveaxis(s_in, 0, 1), jnp.moveaxis(u, 0, 1)
+        q_in = (gamma[..., None] * qn[:, :, :, :, None]).astype(dt)
+        # The first product leaves in the compute dtype (the MXU
+        # accumulates in float32 all the same; the CPU's runtime has no
+        # bf16 x bf16 = f32 product in this transposed form) and is added
+        # in float32.
+        o = (jnp.einsum("bnihrd,bnhrde->bnihre", q_in, s_in).astype(f32)
+             + jnp.einsum("bnhrij,bnhrje->bnihre", attn, u,
+                          preferred_element_type=f32))
+        return o.reshape(b, n * chunk, hv, dv)[:, :l]
 
 
 def gated_rmsnorm(o: jax.Array, z: jax.Array, w: jax.Array) -> jax.Array:
@@ -296,14 +302,16 @@ def gated_delta_net(x: jax.Array, p: Dict[str, jax.Array], *,
     with jax.named_scope("hvdt.gdn.conv"):
         qkv = jax.nn.silu(causal_conv(qkv, p["conv"]))
     with jax.named_scope("hvdt.gdn.scan"):
-        beta = jax.nn.sigmoid(ba[..., :value_heads])
-        g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
-            ba[..., value_heads:] + p["dt_bias"].astype(f32))
-        o = gated_delta_rule(
-            qkv[..., :kd].reshape(b, l, key_heads, key_dim),
-            qkv[..., kd:2 * kd].reshape(b, l, key_heads, key_dim),
-            qkv[..., 2 * kd:].reshape(b, l, value_heads, value_dim),
-            g, beta)
+        # with the rule's own work before its loop, so that the three
+        # children account for all of hvdt.gdn.scan
+        with jax.named_scope("hvdt.gdn.scan.chunk"):
+            beta = jax.nn.sigmoid(ba[..., :value_heads])
+            g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., value_heads:] + p["dt_bias"].astype(f32))
+            q = qkv[..., :kd].reshape(b, l, key_heads, key_dim)
+            k = qkv[..., kd:2 * kd].reshape(b, l, key_heads, key_dim)
+            v = qkv[..., 2 * kd:].reshape(b, l, value_heads, value_dim)
+        o = gated_delta_rule(q, k, v, g, beta)
     with jax.named_scope("hvdt.gdn.norm"):
         y = gated_rmsnorm(o, z.reshape(b, l, value_heads, value_dim),
                           p["gdn_norm"]).astype(x.dtype)

@@ -214,7 +214,8 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
         # A row's weight: its pick's, 0 behind the rows that landed.
         weight_of_row = jnp.where(held, weights.reshape(m), 0.0)[order]
         held = held.reshape(t, k)
-    with jax.named_scope("hvdt.moe.dispatch"):
+    with (jax.named_scope("hvdt.moe.dispatch"),
+          jax.named_scope("hvdt.moe.dispatch.rows")):
         xs = _rows_of_tokens(x, order, inverse, held)
     with jax.named_scope("hvdt.moe.experts"):
         up = lax.ragged_dot(xs, w_up.astype(x.dtype), group_sizes)
@@ -227,7 +228,8 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
         # side of the last product, inside the activation's fusion.
         mid = mid * weight_of_row[:, None].astype(mid.dtype)
         ys = lax.ragged_dot(mid, w_down.astype(x.dtype), group_sizes)
-    with jax.named_scope("hvdt.moe.dispatch"):
+    with (jax.named_scope("hvdt.moe.dispatch"),
+          jax.named_scope("hvdt.moe.dispatch.tokens")):
         out = _tokens_of_rows(ys, order, inverse, held)
     if shared_fn is not None:
         with jax.named_scope("hvdt.moe.shared"):

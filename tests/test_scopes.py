@@ -293,8 +293,9 @@ def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
     for line in text.splitlines():
         if "@tpu_custom_call" not in line:
             continue
-        kernel = re.search(r"hvdt\.attention/hvdt\.kernel\.(\w+)/",
-                           location(line)).group(1)
+        kernel = re.search(
+            r"hvdt\.attention/hvdt\.attention\.core/hvdt\.kernel\.(\w+)/",
+            location(line)).group(1)
         calls[kernel] += 1
         signature = line.rsplit(" : ", 1)[1]
         assert re.fullmatch(types[kernel], signature), line
@@ -418,7 +419,8 @@ def test_a_pattern_lm_names_windowed_and_full_kernels_apart(monkeypatch):
     for line in text.splitlines():
         if "@tpu_custom_call" not in line:
             continue
-        kernel = re.search(r"hvdt\.attention\)?/hvdt\.kernel\.(\w+)/",
+        kernel = re.search(r"hvdt\.attention\)?/hvdt\.attention\.core\)?/"
+                           r"hvdt\.kernel\.(\w+)/",
                            location(line)).group(1)
         calls[kernel] = calls.get(kernel, 0) + 1
         if kernel.endswith("fwd"):      # q [2,256,256], k, v [2,256,128]
@@ -487,7 +489,8 @@ def test_the_linear_mixer_is_a_sibling_of_attention(hybrid_grad_text):
     assert "hvdt.gdn/hvdt.attention" not in hybrid_grad_text
     assert re.search(r"while/body/closed_call/hvdt\.attention/",
                      hybrid_grad_text)
-    assert "hvdt.gdn/hvdt.gdn.scan/while/body/" in hybrid_grad_text
+    assert ("hvdt.gdn/hvdt.gdn.scan/hvdt.gdn.scan.state/while/body/"
+            in hybrid_grad_text)
     # the gate's sigmoid, beside the shared expert's own silu
     assert re.search(r"hvdt\.moe/hvdt\.moe\.shared/(exp|logistic)\b",
                      hybrid_grad_text)
@@ -499,7 +502,8 @@ def test_the_inverses_kernel_is_in_the_forward_and_the_recompute(
     Python; the inverse's chooser reads the same switch): the run of two linear layers
     holds the Mosaic call of ``(I + A)^-1`` twice, in the forward and in
     ``rematted_computation``, each under ``hvdt.gdn/hvdt.gdn.scan/
-    hvdt.kernel.gdn_inverse`` (what ``gdn_scan_ms`` sums and what a later
+    hvdt.gdn.scan.chunk/hvdt.kernel.gdn_inverse`` (what ``gdn_scan_ms``
+    and ``gdn_chunk_ms`` sum and what a later
     ``gdn_inverse_ms`` picks by ``phase_split.scope_calls(ctx,
     "hvdt.kernel.gdn_inverse", trace_reduce.is_mosaic)``), float32
     ``[C, C, matrices]`` slabs in and out, and never under ``transpose(``:
@@ -525,6 +529,7 @@ def test_the_inverses_kernel_is_in_the_forward_and_the_recompute(
     assert len(inverse) == 2
     for loc, line in inverse:
         assert re.search(r"hvdt\.gdn\)?/hvdt\.gdn\.scan\)?/"
+                         r"hvdt\.gdn\.scan\.chunk\)?/"
                          r"hvdt\.kernel\.gdn_inverse/", loc)
         assert "hvdt.attention" not in loc and "transpose(" not in loc
         # 2 sequences x 1 chunk x 2 value heads, padded to a block
@@ -534,3 +539,104 @@ def test_the_inverses_kernel_is_in_the_forward_and_the_recompute(
     assert sorted(loc.split("hvdt.gdn/")[0].strip('"')
                   for loc, _ in inverse) == [
                       "", "checkpoint/rematted_computation/"]
+
+
+# ---------------------------------------------------------------------------
+# The second level (PR 35): the children of hvdt.attention, hvdt.gdn.scan
+# and hvdt.moe.dispatch, and hvdt.embed.  One reader of the benchmark reads
+# each (PERF.md section 3).
+# ---------------------------------------------------------------------------
+
+FLAT = ("jvp()/while/body/closed_call/", "checkpoint/rematted_computation/",
+        "transpose(jvp())/while/body/closed_call/checkpoint/")
+NESTED = ("jvp()/while/body/closed_call/while/body/closed_call/",
+          "checkpoint/rematted_computation/", "closed_call/checkpoint/")
+ROWS = "hvdt.mlp/hvdt.moe/hvdt.moe.dispatch/hvdt.moe.dispatch.rows/"
+TOKENS = "hvdt.mlp/hvdt.moe/hvdt.moe.dispatch/hvdt.moe.dispatch.tokens/"
+
+
+def _under(wrappers, parent, children):
+    """Each wrapper, the path ``parent``, each child of its last scope."""
+    scope = parent.split("/")[-1]
+    return [f"{w}{parent}/{scope}{c}/" for c in children for w in wrappers]
+
+
+UNIFORM_PATHS = _under(FLAT, "hvdt.attention",
+                       (".qkv", ".rope", ".core", ".out")) + [
+    "jvp(hvdt.embed)/", "transpose(jvp(hvdt.embed))/"]
+CHILD_PATHS = (
+    # forward, recompute and backward of every child, in each fixture
+    # whose configuration has it
+    [("pattern_grad_text", path) for path in _under(
+        NESTED, "hvdt.attention", (".qkv", ".rope", ".core", ".gate",
+                                    ".out"))]
+    + [("pattern_grad_text", w + ROWS) for w in NESTED]
+    # the rows' way back is not needed again for the backward: no recompute
+    + [("pattern_grad_text", w + TOKENS) for w in NESTED[::2]]
+    + [("hybrid_grad_text", path) for path in _under(
+        NESTED, "hvdt.gdn/hvdt.gdn.scan", (".chunk", ".state", ".out"))]
+    + [("hybrid_grad_text", path) for path in _under(
+        NESTED, "hvdt.attention", (".rope", ".gate"))]
+    + [("hybrid_grad_text", w + ROWS) for w in NESTED]
+    + [("hybrid_grad_text", w + TOKENS) for w in NESTED[::2]]
+    + [("hybrid_grad_text", "jvp(hvdt.embed)/")])
+
+
+@pytest.mark.parametrize("path", UNIFORM_PATHS)
+def test_the_uniform_lm_carries_the_child_scopes(lm_grad_text, path):
+    assert path in lm_grad_text
+
+
+@pytest.mark.parametrize("fixture, path", CHILD_PATHS)
+def test_a_child_scope_is_on_the_path_under_its_parent(request, fixture,
+                                                       path):
+    assert path in request.getfixturevalue(fixture)
+
+
+def _children_are_siblings(text):
+    """No child is under another (a reader's time would count twice), and
+    a child is nowhere but under its parent.  (XLA joins the names of two
+    operations it merges with a semicolon: one path ends there.)"""
+    for parent, children in [
+            ("hvdt.attention", ("qkv", "rope", "core", "gate", "out")),
+            ("hvdt.gdn.scan", ("chunk", "state", "out")),
+            ("hvdt.moe.dispatch", ("rows", "tokens"))]:
+        family = "|".join(children)
+        dotted = re.escape(parent)
+        assert not re.search(
+            rf"{dotted}\.({family})/[^\";]*{dotted}\.({family})/", text)
+        # bare, or closing a wrapper: jvp(hvdt.attention)/hvdt.attention.qkv/
+        for found in re.finditer(rf"([\w.]*)\)*/{dotted}\.({family})/", text):
+            assert found.group(1) == parent, found.group(0)
+    assert not re.search(r"hvdt\.\w+/[^\"]*hvdt\.embed", text)
+    return True
+
+
+def test_the_uniform_lms_children_are_siblings(lm_grad_text):
+    assert _children_are_siblings(lm_grad_text)
+
+
+@pytest.mark.parametrize("fixture", ["pattern_grad_text",
+                                     "hybrid_grad_text"])
+def test_the_children_of_a_scope_are_siblings(request, fixture):
+    assert _children_are_siblings(request.getfixturevalue(fixture))
+
+
+def test_the_states_loop_is_under_state_and_nothing_else_of_the_scan_is(
+        hybrid_grad_text):
+    """The two products of a trip of the state's loop (their einsums name
+    them) are in a ``while`` body under ``hvdt.gdn.scan.state``, forward,
+    recompute and backward; no product of the chunks or of ``O`` is."""
+    in_loop = ("bhrid,bhrde->bhrie", "bihrd,bhrie->bhrde")
+    names = re.findall(r'op_name="([^"]*hvdt\.gdn\.scan[^"]*)"',
+                       hybrid_grad_text)
+    trips = [n for n in names if any(e in n for e in in_loop)]
+    assert trips and all(
+        "hvdt.gdn.scan/hvdt.gdn.scan.state/while/body/" in n
+        or "hvdt.gdn.scan/hvdt.gdn.scan.state/closed_call/while/body/" in n
+        for n in trips)
+    others = [n for n in names if "hvdt.gdn.scan.state/" in n]
+    assert not [n for n in others
+                if "bnihd,bnjhd->bnhij" in n or "bnhrij,bnhrje->bnihre" in n]
+    assert any("transpose(" in n for n in others)
+    assert any("rematted_computation" in n for n in others)

@@ -735,6 +735,41 @@ def kernel_gdn_inverse(*, matrices=8192, chunk=64):
            jax.jit(gd._inverse_slabs_loop)(cols), rtol=1e-4, atol=1e-4)
 
 
+def kernel_rope(*, batch=8, seq=4096, heads=16, head_dim=64):
+    """ops/pallas_kernels rope on the [B, L, H*D] rows of
+    lm24x1024_s4096_b8's q: the Mosaic call and its backward (the same
+    call at the negated angle) against the function on heads [B, L, H, D],
+    XLA's half-slicing form with JAX's own transpose."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    q, _, _, do = _qkv(batch, seq, heads, head_dim)
+    positions = jnp.broadcast_to(jnp.arange(seq), (batch, seq))
+    cos, sin, half = tfm._rope_tables(positions, tfm.Rope(), head_dim)
+
+    def rows(x):
+        return x.reshape(batch, seq, heads * head_dim)
+
+    # off the TPU (the toy rehearsal) the chooser has no block: one here
+    block = pk._rope_block(rows(q), head_dim) or (
+        1, seq, max(head_dim, 128))
+
+    def both(f, x, g):
+        y, pull = jax.vjp(f, x)
+        return y, pull(g)[0]
+
+    got = jax.jit(lambda x, g: both(
+        lambda x: pk._rope_rows(x, cos, sin, half, block), x, g))(
+            rows(q), rows(do))
+    want = jax.jit(lambda x, g: both(
+        lambda x: pk.rope(x, cos, sin, half), x, g))(q, do)
+    _close("rope", got, tuple(rows(w) for w in want), rtol=2 ** -7,
+           atol=2 ** -6)
+
+
 def _quant_roundtrip(name, quant, dequant, eligible, size, block):
     """One quantize/dequantize pair on a flat gradient bucket, Pallas
     against the XLA lowering.  A code may differ by one where the two
@@ -787,6 +822,7 @@ KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
            kernel_flash_backward, kernel_flash_gqa128, kernel_flash_gqa256,
            kernel_flash_window, kernel_flash_grad_block,
            kernel_conv_bn_relu, kernel_conv_bn_train, kernel_gdn_inverse,
+           kernel_rope,
            kernel_fused_adam, kernel_fused_sgd, kernel_quant_int8,
            kernel_quant_int4)
 

@@ -36,6 +36,7 @@ from jax import lax
 
 from ..ops.attention import attention
 from ..ops.gated_delta import gated_delta_net, scan_macs_per_token
+from ..ops.pallas_kernels import rope
 from ..parallel.moe import moe_dispatch_combine, moe_held_experts
 from ..parallel.pipeline import pipeline_spmd
 from ..parallel.ring_attention import ring_attention
@@ -65,11 +66,6 @@ class Rope:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     attention_factor: float = 1.0
-
-    @property
-    def plain(self) -> bool:
-        return (not self.dim and not self.yarn_factor
-                and self.attention_factor == 1.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -625,17 +621,6 @@ def _norm(x, g, cfg: TransformerConfig):
                     if cfg.zero_centered_norm else g)
 
 
-def _rope(x, positions, theta):
-    """x: [B, L, H, D]; positions: [B, L] global token positions."""
-    d2 = x.shape[-1] // 2
-    freqs = (1.0 / theta) ** (jnp.arange(d2, dtype=jnp.float32) / d2)
-    ang = positions[..., None].astype(jnp.float32) * freqs   # [B, L, d2]
-    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
-    x1, x2 = x[..., :d2], x[..., d2:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(x.dtype)
-
-
 def _rope_frequencies(rope: Rope, head_dim: int) -> np.ndarray:
     """The rotary frequencies of ``rope`` [dim / 2], float32.  Plain:
     theta^(-2i/dim).  YaRN, as ``transformers`` computes it: interpolated
@@ -660,22 +645,22 @@ def _rope_frequencies(rope: Rope, head_dim: int) -> np.ndarray:
             + extrapolated * (1.0 - ramp)).astype(np.float32)
 
 
-def _rope_of(x, positions, rope: Rope):
-    """:func:`_rope` under ``rope``'s settings: the first ``rope.dim``
-    dimensions of each head rotate (rotate-half inside them), the rest
-    pass through."""
-    if rope.plain:
-        return _rope(x, positions, rope.theta)
-    dim = rope.dim or x.shape[-1]
-    d2 = dim // 2
+def _rope_tables(positions, rope: Rope, head_dim: int):
+    """One head's tables under ``rope``'s settings at ``positions`` [B, L]
+    (global token positions), as ``ops.pallas_kernels.rope`` takes them:
+    (cos, sin) float32 [B, L, head_dim], and the distance between the
+    lanes of a pair.  The first ``rope.dim`` dimensions of a head rotate
+    (rotate-half inside them: both lanes of a pair take the pair's cos,
+    and its sin with a minus on the lower one), the rest pass through
+    (1 and 0); cos and sin times ``attention_factor``."""
+    dim = rope.dim or head_dim
     ang = (positions[..., None].astype(jnp.float32)
-           * jnp.asarray(_rope_frequencies(rope, x.shape[-1])))
-    cos = (jnp.cos(ang) * rope.attention_factor)[..., None, :]
-    sin = (jnp.sin(ang) * rope.attention_factor)[..., None, :]
-    x1, x2 = x[..., :d2], x[..., d2:dim]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., dim:]],
-        -1).astype(x.dtype)
+           * jnp.asarray(_rope_frequencies(rope, head_dim)))  # [B, L, dim/2]
+    cos = jnp.cos(ang) * rope.attention_factor
+    sin = jnp.sin(ang) * rope.attention_factor
+    rest = jnp.zeros(ang.shape[:-1] + (head_dim - dim,), jnp.float32)
+    return (jnp.concatenate([cos, cos, rest + 1.0], -1),
+            jnp.concatenate([-sin, sin, rest], -1), dim // 2)
 
 
 def _proj(x, w):
@@ -705,14 +690,17 @@ def _qkv_gate(p, x, positions, cfg: TransformerConfig,
         q, gate = _proj(x, p["wq"]), None
         if cfg.out_gate == "elementwise":
             q, gate = jnp.split(q.reshape(b, l, h, 2 * dh), 2, axis=-1)
-        q = q.reshape(b, l, h, dh)
-        k = _proj(x, p["wk"]).reshape(b, l, hk, dh)
+        k = _proj(x, p["wk"])
         v = _proj(x, p["wv"]).reshape(b, l, hk, dh)
     with jax.named_scope("hvdt.attention.rope"):
+        # RoPE on the projections' own rows [B, L, H * D], or on the heads
+        # a q / k norm leaves: ``rope`` reads which from the shape.
         if cfg.qk_norm:
-            q, k = _norm(q, p["q_norm"], cfg), _norm(k, p["k_norm"], cfg)
-        q = _rope_of(q, positions, kind.rope)
-        k = _rope_of(k, positions, kind.rope)
+            q = _norm(q.reshape(b, l, h, dh), p["q_norm"], cfg)
+            k = _norm(k.reshape(b, l, hk, dh), p["k_norm"], cfg)
+        tables = _rope_tables(positions, kind.rope, dh)
+        q = rope(q, *tables).reshape(b, l, h, dh)
+        k = rope(k, *tables).reshape(b, l, hk, dh)
     return q, k, v, gate
 
 

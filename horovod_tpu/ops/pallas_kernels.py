@@ -29,10 +29,15 @@ Two entry points, one tile body (``_online_softmax_update``):
   ``flash_grad_block`` for its backward: global offsets in, f32 partial
   sums out, for the same reason.
 
-A third kernel is not attention's: ``unit_lower_inverse_slabs(cols)``,
-the inverse of I + A for the Gated DeltaNet scan's chunks
-(``ops/gated_delta.py``), whose 64 sequential row steps XLA can only run
-as 64 passes over HBM and a program here runs on a block in VMEM.
+Two more kernels are not flash attention.  ``rope(x, cos, sin, half)`` is
+the rotary embedding on the same [B, L, H*D] rows, between the projections
+and the flash calls: elementwise, and a kernel only because XLA, asked to
+slice a head in halves narrower than a lane tile, lays the whole attention
+block sequence-minor and copies it to and from the flash calls.
+``unit_lower_inverse_slabs(cols)`` is the inverse of I + A for the Gated
+DeltaNet scan's chunks (``ops/gated_delta.py``), whose 64 sequential row
+steps XLA can only run as 64 passes over HBM and a program here runs on a
+block in VMEM.
 
 Which of the two runs is decided by which function the caller calls,
 and by nothing a user sets.  Both run in Pallas interpret mode off-TPU,
@@ -62,7 +67,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["flash_attention", "flash_block_update", "flash_grad_block",
-           "attention_reference"]
+           "rope", "attention_reference"]
 
 _NEG_INF = -1e30
 
@@ -82,6 +87,13 @@ def _vma_kw(*ops) -> dict:
     return {"vma": vma}
 
 
+def _sublane_tile(*dtypes) -> int:
+    """Rows of the largest sublane tile among ``dtypes``: 8 for 4-byte
+    types, 16 for 2-byte, 32 for 1-byte."""
+    return max({4: 8, 2: 16, 1: 32}.get(jnp.dtype(d).itemsize, 8)
+               for d in dtypes)
+
+
 def _fit_block(n: int, block: int, *dtypes) -> int:
     """Largest power-of-2 reduction of ``block`` that divides ``n`` (the
     defaults are tuned upper bounds, not divisibility requirements —
@@ -97,8 +109,7 @@ def _fit_block(n: int, block: int, *dtypes) -> int:
     while n % fitted:
         fitted //= 2
     fitted = max(fitted, 1)
-    floor = max({4: 8, 2: 16, 1: 32}.get(jnp.dtype(d).itemsize, 8)
-                for d in dtypes)
+    floor = _sublane_tile(*dtypes)
     if fitted < floor and not _use_interpret():
         names = "/".join(jnp.dtype(d).name for d in dtypes)
         raise ValueError(
@@ -1449,6 +1460,151 @@ def unit_lower_inverse_slabs(cols: jax.Array) -> jax.Array:
             interpret=_use_interpret(),
         )(cols)
     return t[:, :, :m] if pad else t
+
+
+_ROPE_BLOCK_BYTES = 1 << 21     # a program's block as a float32 slab
+
+
+def _rope_block(x, head_dim: int) -> Optional[Tuple[int, int, int]]:
+    """(batch rows, sequence rows, lanes) of a block of :func:`_rope_call`
+    on rows ``x`` [B, L, H*D], or None where the kernel has none and XLA
+    computes :func:`_rope_heads_xla`: off the TPU (there the kernel would
+    run in the Pallas interpreter: every CPU test of the model would pay
+    it), for a shape whose ``_heads_per_program`` block is not whole
+    128-lane tiles, and for a sequence that has no block of whole sublane
+    tiles (a decode step's L = 1, an odd L).  Read from the platform and
+    the shape.  A block is as many rows as make a 2 MiB float32 slab (the
+    power of two that divides L; 0.107 ms a call at [8, 4096, 1024] against
+    0.245 at 256 rows, PERF.md PR 36): of one sequence, or, where a
+    sequence is shorter, of several."""
+    b, l, width = x.shape
+    per = _heads_per_program(width // head_dim, width // head_dim, head_dim)
+    if _use_interpret() or per is None or (per * head_dim) % 128:
+        return None
+    lanes = per * head_dim
+    most = _ROPE_BLOCK_BYTES // (4 * lanes)
+    rows = math.gcd(l, most)            # most is a power of two
+    if rows % _sublane_tile(x.dtype):
+        return None
+    return (math.gcd(b, most // rows) if rows == l else 1), rows, lanes
+
+
+def _rope_heads_xla(x, cos, sin, half: int):
+    """:func:`rope` in plain ``jnp`` on heads x [B, L, H, D], each head
+    sliced in its halves: the form XLA fuses onto a q / k norm (two rolls
+    and a select it does not: the described-v5e compile of
+    ``qwen3_next_s16384`` reads 25 relayouts under ``hvdt.attention`` and
+    1.1% more memory with them, 16 and the parent's bytes with this; a
+    Mosaic call after the norm 20 and the same 1.1%)."""
+    c, s = cos[:, :, None, :half], sin[:, :, None, half:2 * half]
+    x1, x2 = x[..., :half], x[..., half:2 * half]
+    return jnp.concatenate(
+        [x1 * c - x2 * s, x1 * s + x2 * c, x[..., 2 * half:]],
+        -1).astype(x.dtype)
+
+
+def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim: int, half: int,
+                 conj: bool):
+    """One block of :func:`rope`, [batch rows x sequence rows, W] with W
+    whole heads: two lane rolls of the block and a select between them by
+    the lane's place in its head; a lane a roll brings in around the
+    block's end meets a 0 of ``sin``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    w = x_ref.shape[-1]
+    x = x_ref[...].reshape(-1, w).astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    if w != head_dim:                   # W = 128 lanes of heads of 2^n
+        lane = lane & (head_dim - 1)
+    pair = jnp.where(lane < half, pltpu.roll(x, w - half, 1),
+                     pltpu.roll(x, half, 1)) * sin_ref[...].reshape(-1, w)
+    o_ref[...] = (x * cos_ref[...].reshape(-1, w)
+                  + (-pair if conj else pair)).astype(o_ref.dtype).reshape(
+                      o_ref.shape)
+
+
+def _rope_call(x, cos, sin, *, half: int, conj: bool, block):
+    """:func:`rope` as one Mosaic call on x [B, L, H*D]: grid (batch
+    block, sequence block, head block), the activations' blocks ``block``
+    = (batch rows, sequence rows, lanes), the lanes as the flash calls
+    take them, the tables' the same at (batch block, sequence block):
+    their index does not change along the last grid axis, so a table block
+    is fetched once for all the heads."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..parallel.sharding import pcast_to_union
+
+    b, l, width = x.shape
+    batch, rows, lanes = block
+    head_dim = cos.shape[-1]
+    # One head's tables to a block's lanes; under shard_map the tables
+    # come from positions no axis varies over.
+    cos, sin = (pcast_to_union(
+        jnp.concatenate([t] * (lanes // head_dim), -1), x)
+        for t in (cos, sin))
+    xspec = pl.BlockSpec(block, lambda bb, ll, hh: (bb, ll, hh))
+    tspec = pl.BlockSpec(block, lambda bb, ll, hh: (bb, ll, 0))
+    with jax.named_scope("hvdt.kernel.rope"):
+        return pl.pallas_call(
+            functools.partial(_rope_kernel, head_dim=head_dim, half=half,
+                              conj=conj),
+            grid=(b // batch, l // rows, width // lanes),
+            in_specs=[xspec, tspec, tspec], out_specs=xspec,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           **_vma_kw(x, cos, sin)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            cost_estimate=pl.CostEstimate(
+                flops=4 * x.size, transcendentals=0,
+                bytes_accessed=2 * x.size * x.dtype.itemsize
+                + 8 * b * l * lanes),
+            interpret=_use_interpret(),
+        )(x, cos, sin)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _rope_rows(x, cos, sin, half: int, block):
+    return _rope_call(x, cos, sin, half=half, conj=False, block=block)
+
+
+def _rope_rows_fwd(x, cos, sin, half, block):
+    return _rope_rows(x, cos, sin, half, block), (cos, sin)
+
+
+def _rope_rows_bwd(half, block, tables, g):
+    # The transpose of a rotation is the rotation by the negated angle.
+    return (_rope_call(g, *tables, half=half, conj=True, block=block),
+            None, None)
+
+
+_rope_rows.defvjp(_rope_rows_fwd, _rope_rows_bwd)
+
+
+def rope(x, cos, sin, half: int):
+    """The rotary embedding.  ``cos``, ``sin``: one head's tables [B, L, D]
+    float32, ``sin`` signed (minus on the lower lane of a pair, 0 on a
+    lane that does not rotate, where ``cos`` is 1); ``half``: the distance
+    between the lanes of a pair.  Lane i of a head takes ``x[i] cos[i] +
+    x[pair of i] sin[i]``, the pair being lane i + half for the lower
+    lanes and i - half for the upper.  Products in float32, one rounding
+    to ``x``'s dtype.
+
+    x is either the rows a projection wrote, [B, L, H*D], whole heads
+    side by side: on a TPU one Mosaic call on the rows' own lanes where
+    :func:`_rope_block` has a block (two lane rolls of a block and a
+    select between them, in place of slices of each head), so that
+    nothing between the projections and the flash kernels asks XLA for
+    another layout than theirs: sliced in halves of 32 or 64 lanes, XLA
+    lays the whole attention block sequence-minor and copies q, k, dq and
+    dk to and from the kernels.  Or heads [B, L, H, D], what a q / k norm
+    or the split of an elementwise gate leaves, and rows with no block:
+    the same products from slices of each head in plain ``jnp``, which XLA
+    fuses onto its neighbours and JAX transposes itself."""
+    block = None if x.ndim == 4 else _rope_block(x, cos.shape[-1])
+    if block is not None:
+        return _rope_rows(x, cos, sin, half, block)
+    heads = x.reshape(x.shape[:2] + (-1, cos.shape[-1]))
+    return _rope_heads_xla(heads, cos, sin, half).reshape(x.shape)
 
 
 def attention_reference(q, k, v, *, causal=True, scale=None,

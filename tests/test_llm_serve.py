@@ -270,6 +270,55 @@ class TestSyntheticTraffic:
 # Prefix sharing (CoW fork on identical live prompt)
 # ---------------------------------------------------------------------------
 
+class TestPagedKeysAreTrainingsKeys:
+    def test_prefill_and_decode_write_the_keys_training_computes(
+            self, params):
+        """The paged cache holds keys rotated exactly as training rotates
+        them: what ``transformer_prefill_paged`` scatters for a prompt's
+        tokens, and what ``transformer_decode_paged`` appends for the
+        next token at its position, are ``_qkv_gate``'s k and v of the
+        training forward at the same positions, layer by layer."""
+        from horovod_tpu.models import transformer as tfm
+
+        n, block, maxb = 11, 4, 4
+        tokens = np.asarray(
+            jax.random.randint(jax.random.PRNGKey(3), (n,), 0, CFG.vocab))
+        positions = jnp.arange(n)[None]
+
+        def training(params):           # every layer's k, v: [L, n, Hkv, D]
+            x = params["embed"].astype(CFG.dtype)[tokens][None]
+
+            def layer(h, p):
+                _, k, v, _ = tfm._qkv_gate(
+                    p, tfm._rmsnorm(h, p["ln1"]), positions, CFG)
+                return tfm._block(p, h, positions, CFG), (k[0], v[0])
+            return jax.lax.scan(layer, x, params["block"])[1]
+
+        k_want, v_want = jax.jit(training)(params)
+        table = jnp.arange(1, maxb + 1, dtype=jnp.int32)
+        cache = jnp.zeros((CFG.layers, maxb + 1, block, CFG.kv_heads,
+                           CFG.head_dim), CFG.dtype)
+        chunk = np.zeros(12, np.int32)
+        chunk[:n - 1] = tokens[:n - 1]
+        kc, vc = jax.jit(lambda *a: tfm.transformer_prefill_paged(
+            *a, CFG, block))(params, jnp.asarray(chunk), jnp.int32(0),
+                             jnp.int32(n - 1), table, cache, cache)
+        _, kc, vc = jax.jit(lambda *a: tfm.transformer_decode_paged(
+            *a, CFG, block))(params, jnp.asarray(tokens[-1:]), table[None],
+                             jnp.asarray([n], jnp.int32), kc, vc)
+        for got, want in ((kc, k_want), (vc, v_want)):
+            rows = got[:, 1:].reshape(CFG.layers, maxb * block,
+                                      CFG.kv_heads, CFG.head_dim)
+            np.testing.assert_allclose(rows[:, :n], want, rtol=1e-5,
+                                       atol=1e-6)
+            assert not np.asarray(rows[:, n:]).any()    # nothing past it
+        # layer 0 sees the same input on all three paths: one function,
+        # to the rounding of a projection at another batch shape
+        np.testing.assert_allclose(
+            kc[0, 1:].reshape(-1, CFG.kv_heads, CFG.head_dim)[:n], k_want[0],
+            rtol=2e-6, atol=1e-6)
+
+
 class TestPrefixSharing:
     def test_duplicate_prompt_forks_blocks(self, params):
         eng = ContinuousLLMEngine(params, CFG, auto_start=False,

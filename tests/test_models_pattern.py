@@ -21,6 +21,7 @@ from horovod_tpu.models import (TransformerConfig, config_from_published,  # noq
                                 transformer_init, transformer_logical_axes,
                                 transformer_loss)
 from horovod_tpu.models import transformer as tfm  # noqa: E402
+from test_models_rope import _half_slicing, _rope_of  # noqa: E402
 
 with open(os.path.join(REPO, "benchmark", "configs", "laguna_xs2.json")) as f:
     PUBLISHED = json.load(f)
@@ -117,7 +118,7 @@ def test_the_published_configuration_cut_to_its_share():
     # call sites), the full sparse layer the other.
     assert [(k.heads, k.window, k.sparse, n) for k, n in runs] == [
         (64, 512, True, 3), (48, None, True, 1)]
-    assert runs[0][0].rope.plain and runs[0][0].rope.theta == 10000
+    assert runs[0][0].rope == tfm.Rope(theta=10000.0)      # plain
     moe = cfg.moe
     assert (moe.held, moe.routed, moe.per_token, moe.scale, moe.d_ff,
             moe.shared_d_ff) == (32, 256, 8, 2.5, 512, 512)
@@ -210,9 +211,10 @@ def test_partial_rotation_leaves_the_other_dimensions_alone():
     rope = tfm.Rope(theta=500000.0, dim=8, attention_factor=1.25)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 6, 2, 16))
     pos = jnp.arange(6)[None]
-    y = tfm._rope_of(x, pos, rope)
+    y = _rope_of(x, pos, rope)
     np.testing.assert_array_equal(y[..., 8:], x[..., 8:])
     np.testing.assert_allclose(y[:, 0, :, :8], 1.25 * x[:, 0, :, :8],
                                rtol=1e-6)      # position 0: cos 1, sin 0
-    plain = tfm._rope_of(x, pos, tfm.Rope(theta=123.0))
-    np.testing.assert_array_equal(plain, tfm._rope(x, pos, 123.0))
+    plain = _rope_of(x, pos, tfm.Rope(theta=123.0))
+    np.testing.assert_array_equal(
+        plain, _half_slicing(x, pos, tfm.Rope(theta=123.0)))

@@ -8,6 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.ops import pallas_kernels as pk
 from horovod_tpu.ops.pallas_kernels import (attention_reference,
                                             flash_attention,
                                             flash_block_update)
@@ -147,3 +148,81 @@ def test_flash_non_power_of_two_seq():
     ref = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-3, rtol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# hvdt.kernel.rope: the rotary embedding on [B, L, H*D] rows.
+# ---------------------------------------------------------------------------
+
+# (head_dim, heads, rotated dimensions, the call's block): a block of 128
+# lanes that holds two heads of 64, one head of 128, one head of 256 with
+# 64 rotated, blocks over several sequences' rows.
+ROPE_CASES = {
+    "two_heads_of_64": (64, 4, 64, (1, 32, 128)),
+    "a_head_of_128": (128, 3, 64, (2, 64, 128)),
+    "a_head_of_256": (256, 2, 64, (1, 64, 256)),
+    "plain_128_batch_rows": (128, 2, 128, (4, 64, 128)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(ROPE_CASES))
+def test_rope_kernel_is_the_jnp_form_bit_for_bit(case, dtype):
+    """The Mosaic call (in the interpreter here) against ``rope``'s plain
+    ``jnp`` form, which is what a CPU and a shape with no block run:
+    forward, and the ``custom_vjp`` backward (the same call at the negated
+    angle) against JAX's own transpose of the ``jnp`` form.  x, the
+    cotangent and the tables hold bf16 values, so each product is exact in
+    float32 and a sum of two is rounded once however the backend
+    contracts it: the two agree to the last bit."""
+    from horovod_tpu.models import transformer as tfm
+
+    d, heads, dim, block = ROPE_CASES[case]
+    b, l = 4, 64
+    x, w = (jax.random.normal(jax.random.PRNGKey(i), (b, l, heads * d),
+                              jnp.bfloat16).astype(dtype) for i in (0, 1))
+    pos = jnp.stack([jnp.arange(l) + 37 * i for i in range(b)])
+    cos, sin, half = tfm._rope_tables(
+        pos, tfm.Rope(theta=1e4, dim=dim % d, attention_factor=1.5), d)
+    cos, sin = (t.astype(jnp.bfloat16).astype(jnp.float32)
+                for t in (cos, sin))
+    assert half == dim // 2 and pk._rope_block(x, d) is None   # off the TPU
+
+    def both(f):
+        y, pull = jax.vjp(f, x)
+        return y, pull(w)[0]
+
+    y, g = jax.jit(lambda: both(
+        lambda x: pk._rope_rows(x, cos, sin, half, block)))()
+    y_want, g_want = jax.jit(lambda: both(
+        lambda x: pk.rope(x, cos, sin, half)))()
+    assert y.dtype == g.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(y, np.float32),
+                                  np.asarray(y_want, np.float32))
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(g, g_want)
+    else:       # JAX's transpose rounds a lane's two cotangents apart
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(g_want, np.float32),
+            rtol=2 ** -7, atol=2 ** -5)
+
+
+def test_rope_block_reads_the_platform_and_the_shape(monkeypatch):
+    """On a TPU: blocks of 128 lanes (two heads of 64) or a head, as many
+    rows (a power of two) as make a 2 MiB float32 slab, of several
+    sequences where one is shorter; no block for a decode step's L = 1, an odd L, or heads with
+    no 128-lane block, which keep the ``jnp`` form."""
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    rows = lambda b, l, h, d, t=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (b, l, h * d), t)
+    assert pk._rope_block(rows(8, 4096, 16, 64), 64) == (1, 4096, 128)
+    assert pk._rope_block(rows(128, 512, 16, 64), 64) == (8, 512, 128)
+    assert pk._rope_block(rows(2, 8192, 48, 128), 128) == (1, 4096, 128)
+    assert pk._rope_block(rows(1, 16384, 16, 256), 256) == (1, 2048, 256)
+    assert pk._rope_block(rows(3, 48, 2, 64), 64) == (1, 16, 128)
+    for shape in ((4, 1, 16, 64), (4, 7, 16, 64), (2, 512, 3, 64),
+                  (2, 512, 4, 96), (2, 24, 2, 64)):
+        assert pk._rope_block(rows(*shape), shape[3]) is None
+    assert pk._rope_block(rows(2, 24, 2, 64, jnp.float32), 64) == (
+        1, 8, 128)

@@ -203,12 +203,21 @@ def _gdn(kernel):
     return lambda: pk.unit_lower_inverse_slabs(cols)
 
 
+def _rope(kernel):
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    x, table = jnp.ones((1, 16, 128)), jnp.ones((1, 16, 64))
+    return lambda: pk._rope_call(x, table, table, half=32, conj=False,
+                                 block=(1, 16, 128))
+
+
 KERNEL_SITES = (
     [(_flash, k) for k in ("flash_fwd", "flash_win_fwd", "flash_fwd.ring",
                            "flash_bwd", "flash_win_bwd", "flash_dq",
                            "flash_dkv")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_gdn, "gdn_inverse")]
+    + [(_rope, "rope")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
     + [(_quant, k) for k in ("quantize", "dequantize", "quantize4",
                              "dequantize4")])
@@ -252,7 +261,10 @@ def test_no_pallas_call_site_is_left_without_a_name():
 def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
     """A one-layer LM gradient at a shape that selects the flash kernel,
     lowered for the TPU (the Pallas -> Mosaic lowering is Python and needs
-    no chip): exactly three Mosaic calls per layer.  Two are the forward
+    no chip): exactly three Mosaic calls of flash attention per layer, fed
+    by RoPE's six (PR 36: q and k on the projections' rows, the rows and
+    two float32 tables of a block's 128 lanes in, the rows out, under
+    ``hvdt.attention.rope/hvdt.kernel.rope``).  Two are the forward
     and its recompute, each q, k, v -> (out in the activation dtype, lse
     as a row).  One is the backward, q, k, v, dO and the two f32 row
     statistics (lse, delta) -> dq, dk, dv in the activation dtype.  The
@@ -285,23 +297,32 @@ def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
         return re.search(rf"^{loc} = loc\((.*)$", text, re.M).group(1)
 
     act, row = "tensor<2x256x128xbf16>", "tensor<2x2x1x256xf32>"
+    table = "tensor<2x256x128xf32>"
     types = {
+        "rope": rf"\({act}, {table}, {table}\) -> {act}.*",
         "flash_fwd": rf"\(({act}, ){{2}}{act}\) -> \({act}, {row}\).*",
         "flash_bwd": rf"\(({act}, ){{4}}{row}, {row}\) -> "
                      rf"\(({act}, ){{2}}{act}\).*"}
-    calls = {"flash_fwd": 0, "flash_bwd": 0}
+    calls = {"rope": 0, "flash_fwd": 0, "flash_bwd": 0}
     for line in text.splitlines():
         if "@tpu_custom_call" not in line:
             continue
-        kernel = re.search(
-            r"hvdt\.attention/hvdt\.attention\.core/hvdt\.kernel\.(\w+)/",
-            location(line)).group(1)
+        child, kernel = re.search(
+            r"hvdt\.attention/hvdt\.attention\.(\w+)/hvdt\.kernel\.(\w+)/",
+            location(line)).groups()
+        # RoPE's call under .rope (attn_rope_ms reads it, attn_surround_ms,
+        # the events under .core that are no Mosaic calls, does not), the
+        # flash calls under .core.
+        assert child == ("rope" if kernel == "rope" else "core")
         calls[kernel] += 1
         signature = line.rsplit(" : ", 1)[1]
         assert re.fullmatch(types[kernel], signature), line
-        assert not re.search(r"x1x(f32|bf16)>|x256x\d+xf32>|x256x64x",
-                             signature)
-    assert calls == {"flash_fwd": 2 * cfg.layers, "flash_bwd": cfg.layers}
+        if kernel != "rope":
+            assert not re.search(r"x1x(f32|bf16)>|x256x\d+xf32>|x256x64x",
+                                 signature)
+    # q and k through RoPE in the forward, its recompute and the backward
+    assert calls == {"rope": 6 * cfg.layers, "flash_fwd": 2 * cfg.layers,
+                     "flash_bwd": cfg.layers}
     # Every operation's name stack is a location of the text: none under
     # the attention scope is a loop, inside one, or a dynamic_update_slice.
     assert not re.search(
@@ -419,15 +440,18 @@ def test_a_pattern_lm_names_windowed_and_full_kernels_apart(monkeypatch):
     for line in text.splitlines():
         if "@tpu_custom_call" not in line:
             continue
-        kernel = re.search(r"hvdt\.attention\)?/hvdt\.attention\.core\)?/"
-                           r"hvdt\.kernel\.(\w+)/",
-                           location(line)).group(1)
+        child, kernel = re.search(
+            r"hvdt\.attention\)?/hvdt\.attention\.(\w+)\)?/"
+            r"hvdt\.kernel\.(\w+)/", location(line)).groups()
+        assert child == ("rope" if kernel == "rope" else "core")
         calls[kernel] = calls.get(kernel, 0) + 1
         if kernel.endswith("fwd"):      # q [2,256,256], k, v [2,256,128]
             assert "(tensor<2x256x256xbf16>, tensor<2x256x128xbf16>, " \
                 "tensor<2x256x128xbf16>)" in line
+    # RoPE: q and k, forward, recompute and backward, of the leading layer
+    # and of the run's one call site
     assert calls == {"flash_fwd": 2, "flash_bwd": 1, "flash_win_fwd": 2,
-                     "flash_win_bwd": 1}
+                     "flash_win_bwd": 1, "rope": 12}
     # The grouped products are ragged dots, all under the experts' scope.
     ragged = [location(line) for line in text.splitlines()
               if "chlo.ragged_dot" in line]

@@ -1,0 +1,13 @@
+"""Kernels: device time per step in the block-mask flash-attention
+forward's Mosaic calls, found by the name the program gives them
+(``hvdt.kernel.flash_bd_fwd``: the forward and the recompute of every
+layer trained by diffusion over blocks; device trace joined to the compiled
+step's ``op_name``s, ``benchmark/phase_split.py``).  Moves
+``tokens_per_s_chip``."""
+
+from benchmark.phase_split import scope_calls
+from benchmark.trace_reduce import is_mosaic
+
+
+def read(ctx):
+    return scope_calls(ctx, "hvdt.kernel.flash_bd_fwd", is_mosaic)[0]

@@ -613,6 +613,45 @@ def kernel_flash_window(*, batch=2, seq=8192, heads=64, kv_heads=8,
                         window=window)
 
 
+def kernel_flash_block_diffusion(*, batch=1, seq=8192, heads=32, kv_heads=4,
+                                 head_dim=128, block=4):
+    """The local kernels under the block mask (hvdt.kernel.flash_bd_fwd /
+    _bwd): the calls of sdar_30b_s8192, on the 2 x seq rows [noisy ;
+    clean] of a sequence, against attention_reference under the same mask
+    head by head."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.pallas_kernels import (attention_reference,
+                                                flash_attention)
+
+    q, _, _, do = _qkv(batch, 2 * seq, heads, head_dim)
+    _, k, v, _ = _qkv(batch, 2 * seq, kv_heads, head_dim, seed=1)
+    group = heads // kv_heads
+    fn = functools.partial(flash_attention, block_diffusion=block)
+    out = jax.jit(fn)(q, k, v)
+    grads = _attention_grads(fn, q, k, v, do)
+
+    @jax.jit
+    def one_head(q, k, v, do):          # [B, 2L, 1, D] each
+        ref = functools.partial(attention_reference, block_diffusion=block)
+        out, vjp = jax.vjp(ref, *(x.astype(jnp.float32)
+                                  for x in (q, k, v)))
+        return (out,) + vjp(do.astype(jnp.float32))
+
+    want = [one_head(q[:, :, h:h + 1], k[:, :, h // group:h // group + 1],
+                     v[:, :, h // group:h // group + 1], do[:, :, h:h + 1])
+            for h in range(heads)]
+    want_out, dq, dk, dv = (jnp.concatenate(xs, axis=2)
+                            for xs in zip(*want))
+    dk, dv = (x.reshape(batch, 2 * seq, kv_heads, group, head_dim).sum(3)
+              for x in (dk, dv))
+    _close("flash_attention block diffusion", out, want_out, rtol=2e-2,
+           atol=2e-2)
+    _close("flash_attention block diffusion backward", grads, (dq, dk, dv),
+           rtol=5e-2, atol=5e-2 * group ** 0.5)
+
+
 def kernel_flash_grad_block(*, batch=1, seq=2048, heads=16, head_dim=64):
     """flash_grad_block's dq and dk/dv pallas_calls: the ring step's
     backward (parallel/ring_attention.py), here over one whole sequence
@@ -820,7 +859,8 @@ def kernel_quant_int4(*, size=1 << 24, block=256):
 
 KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
            kernel_flash_backward, kernel_flash_gqa128, kernel_flash_gqa256,
-           kernel_flash_window, kernel_flash_grad_block,
+           kernel_flash_window, kernel_flash_block_diffusion,
+           kernel_flash_grad_block,
            kernel_conv_bn_relu, kernel_conv_bn_train, kernel_gdn_inverse,
            kernel_rope,
            kernel_fused_adam, kernel_fused_sgd, kernel_quant_int8,

@@ -49,6 +49,17 @@ PRESETS = {
                                "qwen3_next_80b.json"),
         seq=16384, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
         dp=1, tp=1),
+    # SDAR-30B-A3B-Chat on one chip's share of an 8-chip layer: the
+    # benchmark's configuration sdar_30b_a3b (cell sdar_30b_s8192): six
+    # GQA-128 layers with 16 of 128 experts held, trained by diffusion over
+    # blocks of 4 tokens (a noisy and a clean stream, 16,384 rows for 8,192
+    # tokens); 646M parameters, 9.6 GiB of training state.
+    "sdar-30b-a3b": dict(
+        published=os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               os.pardir, "benchmark", "configs",
+                               "sdar_30b_a3b.json"),
+        seq=8192, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
+        dp=1, tp=1),
 }
 
 
@@ -125,7 +136,9 @@ def main():
 
     import horovod_tpu as hvd
     from horovod_tpu.models import (TransformerConfig,
+                                    block_diffusion_corrupt,
                                     config_from_published,
+                                    transformer_block_diffusion_loss,
                                     transformer_init,
                                     transformer_logical_axes,
                                     transformer_loss,
@@ -170,7 +183,8 @@ def main():
             shared_gate=published.get("shared_expert_gate", False),
             **{field: published[key] for field, key in (
                 ("out_gate", "attn_output_gate"), ("qk_norm", "qk_norm"),
-                ("zero_centered_norm", "zero_centered_norm"))
+                ("zero_centered_norm", "zero_centered_norm"),
+                ("diffusion_block", "block_length"))
                if key in published},
             max_seq=args.seq, dtype=getattr(jnp, args.dtype),
             remat=args.remat, remat_policy=args.remat_policy,
@@ -184,6 +198,19 @@ def main():
             num_experts=2 * args.ep if args.ep > 1 else 0,
             sp=args.sp, ep=args.ep, pp=args.pp, remat=args.remat,
             remat_policy=args.remat_policy, loss_chunk=args.loss_chunk)
+    lm_loss = transformer_loss
+    if cfg.diffusion_block:
+        # Diffusion over blocks: the batch is fixed, and so is its noise
+        # (one key, drawn on the tokens the loss is given).
+        args.vocab = cfg.vocab - 1      # the last held row is the mask token
+
+        def lm_loss(p, tokens, cfg):
+            _, t, masked = block_diffusion_corrupt(
+                jax.random.PRNGKey(1), tokens, block=cfg.diffusion_block,
+                mask_id=cfg.vocab - 1)
+            return transformer_block_diffusion_loss(p, tokens, t, masked,
+                                                    cfg)
+
     params = transformer_init(jax.random.PRNGKey(0), cfg)
     rules = transformer_rules()
     axes = transformer_logical_axes(cfg)
@@ -219,7 +246,7 @@ def main():
                 isinstance(e, (str, type(None))) for e in x))
 
     def _local_loss(p, t):
-        l = transformer_loss(p, t, cfg)
+        l = lm_loss(p, t, cfg)
         varying = tuple(set(jax.typeof(l).vma) & {"pp", "sp", "ep"})
         return lax.pmean(l, varying) if varying else l
 
@@ -236,7 +263,7 @@ def main():
                   P(None, "sp") if args.sp > 1 else P()),
         out_specs=P(), axis_names=manual_axes)
         if manual_axes else
-        (lambda p, t: transformer_loss(p, t, cfg)))
+        (lambda p, t: lm_loss(p, t, cfg)))
 
     # Single chip uses the plain loss (no shard_map island) so the
     # Pallas flash path can engage; the hybrid layout differentiates
@@ -270,7 +297,7 @@ def main():
     if explicit_dp:
         def local_step(params, opt_state, tokens):
             def loss_fn(p):
-                return transformer_loss(p, tokens, cfg)
+                return lm_loss(p, tokens, cfg)
 
             # Differentiate w.r.t. VARYING params so AD keeps per-rank
             # gradients and the optimizer's own fused allreduce (with
@@ -292,7 +319,7 @@ def main():
             out_specs=(P(), P(), P())), donate_argnums=(0, 1))
     elif single:
         step = hvd.donated_step(
-            make_step(lambda p, t: transformer_loss(p, t, cfg)),
+            make_step(lambda p, t: lm_loss(p, t, cfg)),
             donate_argnums=(0, 1))
     else:
         step = hvd.donated_step(make_step(island), donate_argnums=(0, 1))

@@ -19,6 +19,8 @@ from .transformer import (  # noqa: F401
     transformer_init,
     transformer_apply,
     transformer_loss,
+    transformer_block_diffusion_loss,
+    block_diffusion_corrupt,
     transformer_logical_axes,
     transformer_flops_per_token,
     remat_from_env,

@@ -45,7 +45,8 @@ from ..quant import fp8 as _fp8
 __all__ = [
     "TransformerConfig", "LayerKind", "LinearMixer", "Rope", "Experts",
     "config_from_published", "transformer_init", "transformer_apply",
-    "transformer_loss", "transformer_logical_axes",
+    "transformer_loss", "transformer_block_diffusion_loss",
+    "block_diffusion_corrupt", "transformer_logical_axes",
     "transformer_flops_per_token", "remat_from_env", "checkpoint_policy",
     "transformer_decode_paged", "transformer_prefill_paged",
     "transformer_prefill_collect",
@@ -193,6 +194,13 @@ class TransformerConfig:
     # Every RMSNorm but the linear mixer's gated one scales by 1 + gain,
     # the gain initialised 0 (else by the gain, initialised 1).
     zero_centered_norm: bool = False
+    # The objective.  0: next-token cross entropy under a causal mask
+    # (``transformer_loss``).  B > 0: diffusion over blocks of B tokens
+    # (``transformer_block_diffusion_loss``): the model runs on the 2 L
+    # rows [noisy ; clean] of a sequence of L tokens, both streams at
+    # positions 0 .. L-1, under ``ops.pallas_kernels.block_diffusion_mask``;
+    # the mask token is the last held row of the vocabulary.
+    diffusion_block: int = 0
 
     def __post_init__(self):
         if isinstance(self.out_gate, bool):
@@ -203,6 +211,14 @@ class TransformerConfig:
                 f"out_gate={self.out_gate!r}: '', 'head' or 'elementwise'")
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.heads)
+        if self.diffusion_block and (
+                self.sp > 1 or self.pp > 1 or any(
+                    k.window or k.linear
+                    for k in self.leading + self.period)):
+            raise ValueError(
+                "diffusion over blocks runs full softmax attention with sp "
+                "= pp = 1 (the block mask has no window, and a recurrent "
+                "mixer would carry the noisy stream into the clean one)")
         if self.period:
             repeated = self.layers - len(self.leading)
             if repeated <= 0 or repeated % len(self.period):
@@ -294,8 +310,10 @@ def config_from_published(published: Dict[str, Any], *,
     first ``vocab`` rows of the vocabulary.  ``router_score`` and
     ``shared_gate`` (the shared expert's sigmoid gate) are what
     ``config.json`` leaves to modelling code, as are the ``fields``
-    ``out_gate``, ``qk_norm`` and ``zero_centered_norm``; ``fields`` are
-    further ``TransformerConfig`` fields (``max_seq``, ``dtype``,
+    ``out_gate``, ``qk_norm``, ``zero_centered_norm`` and
+    ``diffusion_block`` (the objective: a model trained by diffusion over
+    blocks publishes the network of its autoregressive parent); ``fields``
+    are further ``TransformerConfig`` fields (``max_seq``, ``dtype``,
     ``remat``, ...).
     """
     c = published
@@ -722,7 +740,8 @@ def _attention(p, x, positions, cfg: TransformerConfig,
             # (the caller's shard_map over {'sp'} has already split it).
             o = ring_attention(q, k, v, axis="sp", causal=True)
         else:
-            o = attention(q, k, v, window=kind.window)
+            o = attention(q, k, v, window=kind.window,
+                          block_diffusion=cfg.diffusion_block or None)
     with jax.named_scope("hvdt.attention.gate"):
         if cfg.out_gate == "head":
             # A gate a head, from the layer's normed input.
@@ -924,7 +943,9 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
 
     tokens: [batch, seq] int32 — the *local* sp shard of the sequence when
     called inside a shard_map over {'sp'} (positions are globalized with
-    the sp rank), the full sequence otherwise.
+    the sp rank), the full sequence otherwise.  Under
+    ``cfg.diffusion_block`` the rows are the two streams [noisy ; clean]
+    of seq / 2 tokens, each at positions 0 .. seq / 2 - 1.
     """
     b, l = tokens.shape
     if cfg.sp > 1:
@@ -932,6 +953,9 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
     else:
         offset = 0
     positions = offset + jnp.broadcast_to(jnp.arange(l), (b, l))
+    if cfg.diffusion_block:
+        # RoPE sees a token's place in its sequence, not its row.
+        positions = positions % (l // 2)
     with jax.named_scope("hvdt.embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
     # Manual-island axes make activations varying (e.g. the MoE alltoall);
@@ -996,14 +1020,17 @@ def _head(params: Dict, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
 
 
 def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
-                  chunk: int) -> jax.Array:
+                  chunk: int, weights: Optional[jax.Array] = None
+                  ) -> jax.Array:
     """Cross entropy without the [tokens, vocab] logits: scan over vocab
     chunks with an online logsumexp, checkpointed so the backward pass
     recomputes each chunk's logits instead of saving them.  Peak memory
     per step drops from O(tokens x vocab) f32 to O(tokens x chunk) —
     the lever that lets BERT-Large-scale batches fit in HBM (measured:
     dense f32 logits at batch 128 x seq 512 x 30k vocab are 8 GB alone).
-    Numerics match the dense path up to fp reassociation."""
+    Numerics match the dense path up to fp reassociation.  The mean over
+    the tokens, or with ``weights`` [b, t] the sum of each token's term
+    times its weight over their number."""
     b, t, d = x.shape
     vocab = embed.shape[0]
     n_chunks = -(-vocab // chunk)
@@ -1043,6 +1070,8 @@ def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
         init = jax.tree.map(lambda a: lax.pcast(a, vma, to="varying"), init)
     (m, s, tl), _ = lax.scan(jax.checkpoint(body), init,
                              (w, jnp.arange(n_chunks)))
+    if weights is not None:
+        return ((jnp.log(s) + m - tl) * weights.reshape(b * t)).mean()
     return (jnp.log(s) + m - tl).mean()
 
 
@@ -1070,6 +1099,64 @@ def transformer_loss(params: Dict, tokens: jax.Array,
         logp = jax.nn.log_softmax(logits, -1)
         ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
         return -ll.mean()
+
+
+def block_diffusion_corrupt(key: jax.Array, tokens: jax.Array, *,
+                            block: int, mask_id: int, eps: float = 1e-3):
+    """The forward process of diffusion over blocks on ``tokens`` [batch,
+    L]: (x_t, t, masked).  Each block of ``block`` tokens gets its own
+    noise level t in [eps, 1) (the linear schedule: a token survives with
+    probability 1 - t) and each of its tokens is replaced by ``mask_id``
+    independently with probability t.  t is stratified over the blocks of
+    a sequence: t_b = eps + (1 - eps) ((u + b / n) mod 1) with one u a
+    sequence, so every sequence holds every noise level once and each
+    block's t is still uniform.  A block's loss term reads its own t and
+    the clean tokens before it alone, so how the blocks' t are coupled
+    moves no expectation.  t: float32 [batch, L / block]; masked: bool
+    [batch, L]."""
+    b, l = tokens.shape
+    n = l // block
+    if l != n * block:
+        raise ValueError(f"{l} tokens are not whole blocks of {block}")
+    k_t, k_mask = jax.random.split(key)
+    u = jax.random.uniform(k_t, (b, 1))
+    t = eps + (1.0 - eps) * ((u + jnp.arange(n) / n) % 1.0)
+    masked = jax.random.uniform(k_mask, (b, l)) < jnp.repeat(t, block, 1)
+    return jnp.where(masked, mask_id, tokens), t, masked
+
+
+def transformer_block_diffusion_loss(params: Dict, tokens: jax.Array,
+                                     t: jax.Array, masked: jax.Array,
+                                     cfg: TransformerConfig) -> jax.Array:
+    """The loss of diffusion over blocks of ``cfg.diffusion_block`` tokens
+    (BD3-LM, arXiv:2503.09573, as SDAR, arXiv:2510.06303, trains) on
+    ``tokens`` [batch, L] corrupted as ``block_diffusion_corrupt`` draws
+    it (``t`` [batch, L / block], ``masked`` [batch, L]; the mask token is
+    the last held row of the vocabulary, ``cfg.vocab - 1``):
+
+        (1 / (batch L)) sum_b (1 / t_b) sum_{i in b, masked}
+            -log softmax(head(h_i))[tokens_i]
+
+    ONE pass over the 2 L rows [x_t ; tokens]: under the block mask row i
+    of the noisy half is what a pass over [tokens of the blocks before
+    its own ; x_t of its own block] would give, for every block at once.
+    The loss reads the noisy half's rows, each at its own position (no
+    shift by one: a masked row predicts its own token)."""
+    l = tokens.shape[1]
+    block = cfg.diffusion_block
+    with jax.named_scope("hvdt.embed"):
+        rows = jnp.concatenate(
+            [jnp.where(masked, cfg.vocab - 1, tokens), tokens], axis=1)
+    x = transformer_hidden(params, rows, cfg)
+    with jax.named_scope("hvdt.loss"):
+        x = x[:, :l]
+        weights = masked / jnp.repeat(t, block, axis=1)
+        if cfg.loss_chunk:
+            return _chunked_xent(x, _head_matrix(params, cfg), tokens,
+                                 cfg.loss_chunk, weights)
+        logp = jax.nn.log_softmax(_head(params, x, cfg), -1)
+        ll = jnp.take_along_axis(logp, tokens[..., None], -1)[..., 0]
+        return -(ll * weights).mean()
 
 
 # ---------------------------------------------------------------------------
@@ -1279,7 +1366,9 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
     the full score square, a window at its width, the output gate's
     projection, a linear mixer's projections, convolution and chunked
     scan; of a sparse layer the router, a token's picks that land on held
-    experts in expectation and the shared expert with its gate."""
+    experts in expectation and the shared expert with its gate.  Under
+    diffusion over blocks a token is two rows of every layer (the noisy
+    and the clean stream) and one of the head."""
     d, dh = cfg.d_model, cfg.head_dim
 
     def mixer(kind: LayerKind) -> float:
@@ -1311,4 +1400,4 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
                   + cfg.periods * sum(map(layer, cfg.period)))
     else:
         layers = cfg.layers * layer(cfg.uniform_kind)
-    return layers + 2 * d * cfg.vocab
+    return (2 if cfg.diffusion_block else 1) * layers + 2 * d * cfg.vocab

@@ -131,13 +131,15 @@ def kernel_plan(b: int, l: int, h: int, hk: int):
     return (dp_axes, tp_ax, names)
 
 
-def _xla_attention(q, k, v, causal: bool, window: Optional[int] = None):
+def _xla_attention(q, k, v, causal: bool, window: Optional[int] = None,
+                   block_diffusion: Optional[int] = None):
     """Attention as XLA fuses it: the ``[B, H, L, L]`` scores exist, in
     f32 out of bf16 operands.  Not the f32 oracle
     (``pallas_kernels.attention_reference``) and not the paged-slot
     softmax of the serving path: this is what training runs wherever the
     kernel does not.  ``window`` is the kernels' mask: query i sees keys
-    i - window < j <= i."""
+    i - window < j <= i; ``block_diffusion`` theirs too
+    (``pallas_kernels.block_diffusion_mask``)."""
     l, h, dh = q.shape[1], q.shape[2], q.shape[3]
     hk = k.shape[2]
     scale = dh ** -0.5
@@ -146,7 +148,10 @@ def _xla_attention(q, k, v, causal: bool, window: Optional[int] = None):
         v = jnp.repeat(v, h // hk, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
-    if causal:
+    if block_diffusion is not None:
+        mask = pallas_kernels.block_diffusion_mask(l, block_diffusion)
+        s = jnp.where(mask[None, None], s, -1e30)
+    elif causal:
         mask = jnp.tril(jnp.ones((l, l), bool))
         if window is not None:
             mask = jnp.logical_and(mask, jnp.logical_not(
@@ -157,25 +162,34 @@ def _xla_attention(q, k, v, causal: bool, window: Optional[int] = None):
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool = True, window: Optional[int] = None
-              ) -> jax.Array:
+              causal: bool = True, window: Optional[int] = None,
+              block_diffusion: Optional[int] = None) -> jax.Array:
     """Self-attention of a sequence this device holds whole.
 
     q: ``[B, L, H, D]``; k, v: ``[B, L, Hkv, D]`` with ``Hkv`` dividing
     ``H`` (GQA); returns ``[B, L, H, D]``.  Shapes are the global ones
     under a GSPMD-auto mesh and the local ones inside a manual island.
     ``window`` (causal only): a sliding window, query i sees the
-    ``window`` keys i - window < j <= i; the same mask on every path."""
+    ``window`` keys i - window < j <= i; the same mask on every path.
+    ``block_diffusion`` (a block length, in place of both): the L rows
+    are two streams of one sequence, [noisy ; clean], under
+    ``pallas_kernels.block_diffusion_mask``, on every path; the policy
+    is asked of the L rows the call sees."""
     if window is not None and not causal:
         raise ValueError("a window is a causal mask's: pass causal=True")
+    if block_diffusion is not None and window is not None:
+        raise ValueError("block diffusion has no window")
     b, l, h, _ = q.shape
     plan = kernel_plan(b, l, h, k.shape[2])
-    if plan is None:
-        return _xla_attention(q, k, v, causal, window)
+    if plan is None or (block_diffusion is not None and not
+                        pallas_kernels.block_diffusion_tiles(
+                            l, block_diffusion)):
+        return _xla_attention(q, k, v, causal, window, block_diffusion)
     # Pallas fused attention: O(L·D) HBM traffic instead of a
     # materialized [B,H,L,L] score matrix (ops/pallas_kernels.py).
     kernel = functools.partial(pallas_kernels.flash_attention,
-                               causal=causal, window=window)
+                               causal=causal, window=window,
+                               block_diffusion=block_diffusion)
     if plan == "direct":
         return kernel(q, k, v)
     # GSPMD-auto mesh: Mosaic kernels can't be auto-partitioned, so

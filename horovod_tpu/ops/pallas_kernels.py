@@ -264,6 +264,18 @@ def _flash_call(q, k, v, acc, m, l, q_offset, k_offset, *, causal, scale,
 # tile "straddles"): which pairs of a tile are visible, whether a tile has
 # any or only such pairs, and where along the other axis a tile's visible
 # range starts.  Positions start at 0 on both sides.
+#
+# The third member is not causal: ``block_diffusion`` (a block length B)
+# says the rows are two streams of one sequence, [noisy ; clean], each at
+# positions 0 .. L-1 cut into blocks of B.  A noisy row sees the noisy rows
+# of its own block and the clean rows of earlier blocks; a clean row the
+# clean rows of its own and earlier blocks; no clean row sees a noisy one.
+# Its four functions are below the causal three: the dense mask
+# (``block_diffusion_mask``, the definition every path is held to), the
+# three kinds of tile the streams' diagonals cross (``_block_mask``), and
+# the order in which a q tile's K/V tiles (``_bd_key_tile``) and a K/V
+# tile's q tiles (``_bd_query_tile``) are visited, none without a visible
+# pair.
 # ---------------------------------------------------------------------------
 
 
@@ -290,6 +302,105 @@ def _tile_kind(q0, bq: int, k0, bk: int, window: int):
 def _first_key_block(iq, bq: int, bk: int, window: int):
     """The K/V block that holds the oldest key q tile ``iq`` sees."""
     return jnp.maximum(iq * bq - (window - 1), 0) // bk
+
+
+def block_diffusion_mask(rows: int, block: int):
+    """[rows, rows] bool, True where query row i sees key row j, for
+    ``rows`` = 2 L rows [noisy ; clean] of L positions in blocks of
+    ``block``: block-diagonal noisy on noisy, strictly earlier blocks noisy
+    on clean, block-causal clean on clean, nothing clean on noisy."""
+    half = rows // 2
+    if rows != 2 * half or half % block:
+        raise ValueError(
+            f"block diffusion takes 2 L rows, L whole blocks of {block} "
+            f"(got {rows} rows)")
+    row = jnp.arange(rows)
+    noisy = row < half
+    blk = (row % half) // block
+    q_noisy, k_noisy = noisy[:, None], noisy[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return jnp.where(
+        k_noisy, jnp.logical_and(q_noisy, qb == kb),
+        jnp.where(q_noisy, kb < qb, kb <= qb))
+
+
+def block_diffusion_tiles(rows: int, block: int) -> bool:
+    """Whether the local kernels take ``rows`` = 2 L rows under blocks of
+    ``block``: a block has to divide every chunk of a tile (powers of two
+    from 128 down) and be smaller than the smallest tile (128: a tile of
+    one block would have no earlier block for its noisy rows to see, and
+    the kernels' order would still visit it), and L has to tile like any
+    sequence."""
+    half = rows // 2
+    return (rows == 2 * half and block >= 1 and 64 % block == 0
+            and half % min(128, half) == 0 and half % block == 0
+            and half >= 8)
+
+
+def _block_mask(shape, row0: int, key0: int, block: int, kind: str,
+                transposed: bool = False):
+    """True where a pair of a tile on a stream's diagonal is visible.  The
+    tile holds rows ``row0 ..`` and keys ``key0 ..`` of one stretch of
+    positions (static offsets inside it); ``kind`` is "same" (noisy on
+    noisy: the key's block is the row's), "le" (clean on clean: not a
+    later block) or "lt" (noisy on clean: an earlier block).  ``shape`` is
+    [rows, keys], or [keys, rows] ``transposed`` (the backward's)."""
+    rows_axis, keys_axis = (1, 0) if transposed else (0, 1)
+    shift = block.bit_length() - 1              # block is a power of two
+
+    def block_of(axis, first):
+        pos = jax.lax.broadcasted_iota(jnp.int32, shape, axis) + first
+        return jax.lax.shift_right_logical(pos, shift) if shift else pos
+
+    rb, kb = block_of(rows_axis, row0), block_of(keys_axis, key0)
+    return {"same": rb == kb, "le": kb <= rb, "lt": kb < rb}[kind]
+
+
+def _bd_key_tile(iq, ik, n: int):
+    """The forward's order under block diffusion, for q tile ``iq`` of the
+    2 n square tiles [noisy ; clean] at step ``ik`` of its n + 1: (the
+    K/V tile, whether the step has work, the q tile's place c in its
+    stream, whether it is clean).  Step 0 is the tile's own diagonal tile
+    (every row sees itself there, so no later step meets a row without a
+    finite max); steps 1 .. c the clean tiles before it, wholly visible;
+    step c + 1, for a noisy tile, clean tile c ("lt").  Steps past the
+    last name the tile that is already resident."""
+    clean = iq >= n
+    c = jnp.where(clean, iq - n, iq)
+    last = jnp.where(clean, c, c + 1)
+    tile = jnp.where(ik == 0, iq,
+                     n + jnp.maximum(jnp.minimum(ik, last) - 1, 0))
+    return tile, ik <= last, c, clean
+
+
+def _bd_query_tile(ik, iq, n: int):
+    """The backward's order under block diffusion, for K/V tile ``ik`` of
+    the 2 n at step ``iq`` of its 2 n: (the q tile, whether the step has
+    work, how many q tiles a stream m holds from the K/V tile's place on,
+    whether the K/V tile is clean).  A noisy K/V tile is seen by its own q
+    tile alone (step 0, "same").  Clean tile c is seen by the noisy q
+    tiles c .. n - 1 (steps 0 .. m - 1, the first "lt") and the clean q
+    tiles c .. n - 1 (steps m .. 2 m - 1, the first "le").  Steps past the
+    last name the tile that is already resident."""
+    clean = ik >= n
+    c = jnp.where(clean, ik - n, ik)
+    m = n - c
+    step = jnp.minimum(iq, 2 * m - 1)
+    tile = jnp.where(clean,
+                     jnp.where(step < m, c + step, n + c + step - m), c)
+    return tile, jnp.where(clean, iq < 2 * m, iq == 0), m, clean
+
+
+# The largest square tiles the local kernels take under block diffusion.
+# Each call unrolls four kinds of tile (three diagonal kinds and the
+# unmasked one), a chunk body for every 512 rows (forward) or 256 keys
+# (backward) of each, and past these sizes that costs more than the fewer
+# grid steps save.  Measured on the v5e at [1, 16384, 32 over 4, 128] bf16,
+# blocks of 4, ms a call by device events (PERF.md, PR 39): forward 4096 /
+# 2048 / 1024 / 512 tiles 24.17 / 9.38 / 10.32 / 17.26; backward 2048 /
+# 1024 / 512 tiles 68.53 / 17.95 / 23.85.
+_BD_FWD_TILE = 2048
+_BD_BWD_TILE = 1024
 
 
 def _window_block(window: int) -> int:
@@ -320,7 +431,8 @@ def _keep_head(x, j, heads: int):
 def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
                   l_s, *, causal: bool, scale: float, fold_scale: bool,
                   rows: int, one_tile: bool, heads: int,
-                  window: Optional[int] = None, key_blocks: int = 0):
+                  window: Optional[int] = None, key_blocks: int = 0,
+                  bd: Optional[int] = None):
     """The local (non-ring) forward, grid (b, head group, iq, head, ik)
     with ik innermost: nothing to carry in and nothing to hand on, so
     (acc, m, l) are born in VMEM scratch at ik == 0 and die in the flush,
@@ -350,7 +462,14 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
     count): step ik works on block ``first + ik``, the first being the one
     that holds the tile's oldest visible key, and a step past the diagonal
     does nothing.  A tile the window's edge crosses is masked like one the
-    diagonal crosses."""
+    diagonal crosses.
+
+    Under block diffusion (``bd`` the block length, ``key_blocks`` the
+    square tiles a stream has) the K/V axis is one step longer than a
+    stream's tiles and ``_bd_key_tile`` says which tile a step works on:
+    the q tile's own diagonal tile first, then the clean tiles it sees.  A
+    chunk of rows on a diagonal takes the keys up to its own last row (of
+    its own rows alone, noisy on noisy) and masks by blocks."""
     import jax.experimental.pallas as pl
 
     iq = pl.program_id(2)
@@ -372,34 +491,48 @@ def _local_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_s, acc_s, m_s,
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    def _tile(straddles: bool):
+    def _tile(straddles: bool, kind: Optional[str] = None):
         for r in range(0, bq, rows):
             # bq == bk puts a straddling tile on the diagonal (iq == ik):
             # rows r.. see no key past r + rows.
             keys = (min(bk, r + rows)
                     if straddles and bq == bk and window is None else bk)
+            # Noisy on noisy: nor any key before r.
+            key0 = r if kind == "same" else 0
             chunk = pl.ds(r, rows)
             s = jax.lax.dot_general(
-                q_s[chunk, :], k_ref[0, :keys, :],
+                q_s[chunk, :], k_ref[0, key0:keys, :],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [rows, keys]
             if not fold_scale:
                 s = s * scale
             mask = None
-            if straddles:
+            if kind is not None:
+                mask = _block_mask(s.shape, r, key0, bd, kind)
+            elif straddles:
                 mask = _visible_mask(
                     jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                     - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1),
                     kb * bk - iq * bq - r, window)
             # Key 0 is visible to every row and ik == 0 comes first, so no
-            # row meets a masked score with its max still at -1e30.  Under
+            # row meets a masked score with its max still at -1e30 (under
+            # block diffusion a row's own key, in its first tile).  Under
             # a window a row's first block may hold none of its keys.
-            _online_softmax_update(s, v_ref[0, :keys, :],
+            _online_softmax_update(s, v_ref[0, key0:keys, :],
                                    acc_s.at[chunk], m_s.at[chunk],
                                    l_s.at[chunk], mask,
                                    rows_may_be_empty=window is not None)
 
-    if not causal:
+    if bd is not None:
+        _, _, c, clean = _bd_key_tile(iq, ik, key_blocks)
+        noisy = jnp.logical_not(clean)
+        first = ik == 0
+        pl.when(jnp.logical_and(first, noisy))(lambda: _tile(True, "same"))
+        pl.when(jnp.logical_and(first, clean))(lambda: _tile(True, "le"))
+        pl.when(jnp.logical_and(ik >= 1, ik <= c))(lambda: _tile(False))
+        pl.when(jnp.logical_and(ik == c + 1, noisy))(
+            lambda: _tile(True, "lt"))
+    elif not causal:
         _tile(False)
     elif window is not None:
         visited, visible = _tile_kind(iq * bq, bq, kb * bk, bk, window)
@@ -557,7 +690,7 @@ def _head_blocks(q, k, heads: int, block_q: int, block_k: int):
 
 
 def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
-                      rows=None, window=None):
+                      rows=None, window=None, bd=None):
     """The self-contained forward: q [B,Lq,H*D], k/v [Bkv,Lk,Hkv*D] (the
     projections' own rows, ``heads`` = H) -> (out [B,Lq,H*D] in q.dtype,
     lse [B,H,1,Lq] f32).  One pallas_call and nothing around it: no carry
@@ -573,7 +706,9 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
 
     Under a ``window`` the last grid axis is as long as the most blocks one
     q tile's window reaches, and the call's name is
-    ``hvdt.kernel.flash_win_fwd``."""
+    ``hvdt.kernel.flash_win_fwd``.  Under block diffusion (``bd``; square
+    tiles that tile a stream) it is a stream's tiles and one, and the name
+    ``hvdt.kernel.flash_bd_fwd``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -587,8 +722,14 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
             ((i + 1) * block_q - 1) // block_k
             - max(i * block_q - (window - 1), 0) // block_k + 1
             for i in range(lq // block_q))
+    if bd is not None:
+        stream = lq // 2 // block_q             # square tiles a stream has
+        kv_steps = stream + 1
 
     def kv_index(bb, hh, qq, jj, kk):
+        if bd is not None:
+            return (bb // bgroup, _bd_key_tile(qq, kk, stream)[0],
+                    hh // group)
         if window is not None:
             kk = jnp.minimum(
                 kk + _first_key_block(qq, block_q, block_k, window),
@@ -608,7 +749,10 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
     kw = _vma_kw(q, k, v)
     windowed = {} if window is None else dict(
         window=window, key_blocks=lk // block_k)
-    with jax.named_scope("hvdt.kernel.flash_fwd" if window is None
+    if bd is not None:
+        windowed = dict(bd=bd, key_blocks=stream)
+    with jax.named_scope("hvdt.kernel.flash_bd_fwd" if bd is not None
+                         else "hvdt.kernel.flash_fwd" if window is None
                          else "hvdt.kernel.flash_win_fwd"):
         return pl.pallas_call(
             functools.partial(
@@ -636,7 +780,8 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
 def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                       dk_ref, dv_ref, dq_s, dk_s, dv_s, *, causal: bool,
                       scale: float, fold_scale: bool, keys: int, heads: int,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None,
+                      bd: Optional[int] = None, stream: int = 0):
     """The local (non-ring) backward, grid (b, head group, ik, head, iq)
     with iq innermost: the K/V block stays while q, dO and the two row
     statistics stream past it, once for each head of the group.  dk/dv
@@ -667,7 +812,13 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     With a ``window`` the q axis of the grid is only as long as the tiles
     whose rows can see one K/V block: step iq works on q tile ``first +
     iq``, the first being the one the block's diagonal starts in, and a
-    step past the window's edge does nothing."""
+    step past the window's edge does nothing.
+
+    Under block diffusion (``bd`` the block length, ``stream`` the square
+    tiles a stream has) the q axis is as long as the q tiles of both
+    streams and ``_bd_query_tile`` says which a step works on; a chunk of
+    keys on a diagonal takes the rows from its own first key on (its own
+    rows alone, noisy on noisy) and masks by blocks."""
     import jax.experimental.pallas as pl
 
     ik = pl.program_id(2)
@@ -679,6 +830,8 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
     bk = k_ref.shape[1]
     # The q tile this step works on.
     qt = iq if window is None else iq + (ik * bk) // bq
+    if bd is not None:
+        qt, _, seen, clean = _bd_query_tile(ik, iq, stream)
     nt = (((1,), (1,)), ((), ()))                 # a b^T
     nn = (((1,), (0,)), ((), ()))                 # a b
     tn = (((0,), (0,)), ((), ()))                 # a^T b
@@ -693,7 +846,7 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
         def _():
             dq_s[...] = jnp.zeros_like(dq_s)
 
-    def _tile(straddles: bool):
+    def _tile(straddles: bool, kind: Optional[str] = None):
         q_all = q_ref[0, :, :]
         if fold_scale:
             q_all = (q_all * scale).astype(q_all.dtype)
@@ -704,14 +857,19 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
             # bq == bk puts a straddling tile on the diagonal (iq == ik):
             # keys c.. are seen by no row before c.
             r = c if straddles and bq == bk and window is None else 0
+            # Noisy on noisy: nor by any row from c + keys on.
+            end = c + keys if kind == "same" else None
             chunk = pl.ds(c, keys)
             k = k_ref[0, chunk, :]
-            q = q_all[r:]
-            do = do_all[r:]
+            q = q_all[r:end]
+            do = do_all[r:end]
             st = jax.lax.dot_general(k, q, nt, preferred_element_type=f32)
             if not fold_scale:
                 st = st * scale
-            if straddles:
+            if kind is not None:
+                st = jnp.where(_block_mask(st.shape, r, c, bd, kind, True),
+                               st, _NEG_INF)
+            elif straddles:
                 # True = visible: row position >= key position.
                 mask = _visible_mask(
                     jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
@@ -720,19 +878,29 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref,
                 st = jnp.where(mask, st, _NEG_INF)
             # The saved logsumexp is finite, so a masked score's exp is an
             # exact 0 and p needs no second select.
-            pt = jnp.exp(st - lse_ref[0, 0, :, r:])          # [keys, rows]
+            pt = jnp.exp(st - lse_ref[0, 0, :, r:end])       # [keys, rows]
             dpt = jax.lax.dot_general(v_ref[0, chunk, :], do, nt,
                                       preferred_element_type=f32)
-            dst = (pt * (dpt - dl_ref[0, 0, :, r:])).astype(q.dtype)
+            dst = (pt * (dpt - dl_ref[0, 0, :, r:end])).astype(q.dtype)
             dv_s[chunk, :] += jax.lax.dot_general(
                 pt.astype(do.dtype), do, nn, preferred_element_type=f32)
             dk_s[chunk, :] += jax.lax.dot_general(
                 dst, q, nn, preferred_element_type=f32)
-            dq_s[pl.ds(row0 + r, bq - r), :] += jax.lax.dot_general(
+            dq_s[pl.ds(row0 + r, (end or bq) - r), :] += jax.lax.dot_general(
                 dst, _keep_head(k, j, heads), tn,
                 preferred_element_type=f32)
 
-    if not causal:
+    if bd is not None:
+        noisy = jnp.logical_not(clean)
+        pl.when(jnp.logical_and(noisy, iq == 0))(
+            lambda: _tile(True, "same"))
+        pl.when(jnp.logical_and(clean, iq == 0))(lambda: _tile(True, "lt"))
+        pl.when(jnp.logical_and(clean, iq == seen))(
+            lambda: _tile(True, "le"))
+        pl.when(jnp.logical_and(
+            jnp.logical_and(clean, iq < 2 * seen),
+            jnp.logical_and(iq != 0, iq != seen)))(lambda: _tile(False))
+    elif not causal:
         # ik >= 0 always: the cond is there for interpret mode under
         # shard_map (see _local_kernel's one-tile branch).
         pl.when(ik >= 0)(lambda: _tile(False))
@@ -810,7 +978,8 @@ def _backward_blocks(lq: int, lk: int, head_dim: int, dtype
 
 
 def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
-                          block_q, block_k, keys=None, window=None):
+                          block_q, block_k, keys=None, window=None,
+                          bd=None):
     """The self-contained backward: q, dO [B,Lq,H*D], k, v [Bkv,Lk,Hkv*D]
     (``heads`` = H; the forward's layout and blocks), lse and delta f32
     rows [B,H,1,Lq] -> (dq [B,Lq,H*D], dk, dv [B,Lk,H*D]) in the operands'
@@ -821,7 +990,9 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
 
     Under a ``window`` the last grid axis is as long as the most q tiles
     that see one K/V block, and the call's name is
-    ``hvdt.kernel.flash_win_bwd``."""
+    ``hvdt.kernel.flash_win_bwd``.  Under block diffusion (``bd``; square
+    tiles that tile a stream) it is the q tiles of both streams, and the
+    name ``hvdt.kernel.flash_bd_bwd``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -836,7 +1007,11 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
                 lq // block_q - 1) - (i * block_k) // block_q + 1
             for i in range(lk // block_k))
 
+    stream = lq // 2 // block_q                 # square tiles a stream has
+
     def q_tile(kk, qq):
+        if bd is not None:
+            return _bd_query_tile(kk, qq, stream)[0]
         if window is not None:
             # Past the window's edge: the tile that is already resident.
             return jnp.minimum(
@@ -863,7 +1038,10 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
                            lambda bb, hh, kk, jj, qq: (bb, kk, hh))
     kw = _vma_kw(q, k, v, do, lse, delta)
     windowed = {} if window is None else dict(window=window)
-    with jax.named_scope("hvdt.kernel.flash_bwd" if window is None
+    if bd is not None:
+        windowed = dict(bd=bd, stream=stream)
+    with jax.named_scope("hvdt.kernel.flash_bd_bwd" if bd is not None
+                         else "hvdt.kernel.flash_bwd" if window is None
                          else "hvdt.kernel.flash_win_bwd"):
         return pl.pallas_call(
             functools.partial(
@@ -888,6 +1066,7 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None) -> jax.Array:
@@ -912,6 +1091,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     (:func:`_window_block`), so a row computes at most two windows of keys;
     a window that reaches the whole sequence is no window.
 
+    ``block_diffusion`` (a block length; in place of ``causal``): the rows
+    are two streams of one sequence, [noisy ; clean], under
+    :func:`block_diffusion_mask`.  Both calls visit only tiles with a
+    visible pair, in square tiles that tile a stream
+    (:func:`block_diffusion_tiles` says which shapes they take).
+
     ``block_q`` / ``block_k`` default to :func:`_forward_blocks`' choice
     for the shape; a test passes its own to meet a given tiling.
     """
@@ -920,6 +1105,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if scale is None:
         scale = d ** -0.5
     auto_q, auto_k = _forward_blocks(lq, lk, d, q.dtype)
+    if block_diffusion is not None:
+        if window is not None or lq != lk or not block_diffusion_tiles(
+                lq, block_diffusion) or _backward_blocks(
+                    lq, lk, d, q.dtype) is None:
+            raise ValueError(
+                "block diffusion takes 2 L query and key rows, L whole 128s "
+                f"and blocks of a power of two up to 64 (got {lq} and {lk} "
+                f"rows, blocks of {block_diffusion}, window={window})")
+        # Square tiles that tile a stream.
+        tile = _fit_block(lq // 2, min(block_q or _BD_FWD_TILE,
+                                       block_k or _BD_FWD_TILE, auto_q,
+                                       auto_k), q.dtype, k.dtype, v.dtype)
+        return _flash_attn_diff(q, k, v, False, float(scale), tile, tile,
+                                None, block_diffusion)
     if window is not None:
         if not causal or window < 1:
             raise ValueError("a window is a causal mask's: causal=True and "
@@ -936,10 +1135,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_k = (_fit_block(lk, block_k, k.dtype, v.dtype)
                if block_k else auto_k)
     return _flash_attn_diff(q, k, v, causal, float(scale), block_q,
-                            block_k, window)
+                            block_k, window, None)
 
 
-def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k, window=None):
+def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k, window=None,
+                    bd=None):
     """Kernel forward returning (out [B,L,H,D], lse [B,H,1,Lq]): the
     logsumexp as the row the kernel writes and the backward reads."""
     b, lq, h, d = q.shape
@@ -947,7 +1147,7 @@ def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k, window=None):
     out, lse = _flash_local_call(
         *(_rows_layout(x, fold) for x in (q, k, v)),
         heads=1 if fold else h, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, window=window)
+        block_q=block_q, block_k=block_k, window=window, bd=bd)
     return _heads_layout(out, q.shape, fold), lse.reshape(b, h, 1, lq)
 
 
@@ -958,26 +1158,27 @@ def _flash_fwd_core(q, k, v, causal, scale, block_q, block_k, window=None):
     return out, lse[:, :, 0, :]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attn_diff(q, k, v, causal, scale, block_q, block_k, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attn_diff(q, k, v, causal, scale, block_q, block_k, window, bd):
     out, _ = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k,
-                             window)
+                             window, bd)
     return out
 
 
-def _flash_attn_fwd(q, k, v, causal, scale, block_q, block_k, window):
+def _flash_attn_fwd(q, k, v, causal, scale, block_q, block_k, window, bd):
     out, lse = _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k,
-                               window)
+                               window, bd)
     return out, (q, k, v, out, lse)
 
 
-def _flash_attn_bwd(causal, scale, block_q, block_k, window, res, do):
+def _flash_attn_bwd(causal, scale, block_q, block_k, window, bd, res, do):
     """The local backward: one Pallas call (``_flash_local_bwd_call``) on
     the forward's operand layout, delta = rowsum(dO * out) computed beside
     it as a row.  A test's own forward blocks bound the backward's too, so
     a given tiling is met on both sides.  Only a sequence whose f32 dq
     does not fit in VMEM (``_backward_blocks`` is None) takes the
-    blockwise XLA backward."""
+    blockwise XLA backward (under block diffusion ``flash_attention`` has
+    refused it)."""
     q, k, v, out, lse = res
     b, lq, h, d = q.shape
     lk, hkv = k.shape[1], k.shape[2]
@@ -990,13 +1191,18 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, window, res, do):
                        preferred_element_type=jnp.float32)[:, :, None, :]
     fold = _heads_per_program(h, hkv, d) is None
     heads = 1 if fold else h
+    # Under block diffusion the (square) tiles have to tile a stream.
+    streams = 1 if bd is None else 2
+    if bd is not None:
+        blocks = tuple(min(x, _BD_BWD_TILE) for x in blocks)
     dq, dk, dv = _flash_local_bwd_call(
         *(_rows_layout(x, fold) for x in (q, k, v, do)),
         *(x.reshape(b * h // heads, heads, 1, lq) for x in (lse, delta)),
         heads=heads, causal=causal, scale=scale,
-        block_q=_fit_block(lq, min(block_q, blocks[0]), q.dtype),
-        block_k=_fit_block(lk, min(block_k, blocks[1]), k.dtype, v.dtype),
-        window=window)
+        block_q=_fit_block(lq // streams, min(block_q, blocks[0]), q.dtype),
+        block_k=_fit_block(lk // streams, min(block_k, blocks[1]), k.dtype,
+                           v.dtype),
+        window=window, bd=bd)
     dq, dk, dv = (_heads_layout(x, (b, x.shape[1], h, d), fold)
                   for x in (dq, dk, dv))
     if h != hkv:
@@ -1608,11 +1814,11 @@ def rope(x, cos, sin, half: int):
 
 
 def attention_reference(q, k, v, *, causal=True, scale=None,
-                        with_lse=False, window=None):
+                        with_lse=False, window=None, block_diffusion=None):
     """Naive jnp attention (materializes scores) — the correctness oracle.
     ``with_lse`` also returns the f32 logsumexp of the scaled, masked
     scores, [B, H, Lq]: the statistic the flash forwards save.  ``window``
-    as :func:`flash_attention`'s."""
+    and ``block_diffusion`` as :func:`flash_attention`'s."""
     b, lq, h, d = q.shape
     hkv = k.shape[2]
     if scale is None:
@@ -1622,7 +1828,10 @@ def attention_reference(q, k, v, *, causal=True, scale=None,
         v = jnp.repeat(v, h // hkv, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    if causal:
+    if block_diffusion is not None:
+        s = jnp.where(block_diffusion_mask(lq, block_diffusion)[None, None],
+                      s, _NEG_INF)
+    elif causal:
         lk = k.shape[1]
         mask = _visible_mask(
             jnp.arange(lq)[:, None] - jnp.arange(lk)[None, :], 0, window)

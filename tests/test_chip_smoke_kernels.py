@@ -17,6 +17,8 @@ TOY_KERNELS = dict(
                              head_dim=256),
     kernel_flash_window=dict(batch=1, seq=512, heads=4, kv_heads=2,
                              head_dim=128, window=128),
+    kernel_flash_block_diffusion=dict(batch=1, seq=128, heads=4, kv_heads=2,
+                                      head_dim=128, block=4),
     kernel_flash_grad_block=dict(batch=1, seq=256, heads=2, head_dim=64),
     kernel_conv_bn_relu=dict(batch=2, hw=8, cin=128, cout=128),
     kernel_conv_bn_train=dict(batch=2, hw=8, cin=128, cout=128),

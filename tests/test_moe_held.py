@@ -89,13 +89,15 @@ def test_the_held_part_of_the_routed_sum_and_its_gradients(first, held,
 
 @pytest.mark.parametrize("model, shares, route", [
     ("laguna", 4, dict(top_k=K, score="sigmoid", scale=2.5)),
-    ("qwen3_next", 16, dict(top_k=3, score="softmax"))])
+    ("qwen3_next", 16, dict(top_k=3, score="softmax")),
+    ("sdar", 8, dict(top_k=4, score="softmax"))])
 def test_the_shares_add_up_to_the_uncut_reference_layer(model, shares, route):
     """The shares of a layer (four of four experts each as Laguna's eight
-    chips would hold them; sixteen of one each as Qwen3-Next's sixteen),
-    the shared expert counted once (Qwen3-Next's times its sigmoid gate):
-    their parts sum to what the model's plain reference under
-    benchmark/reference gives for the whole layer (all 16 experts held)."""
+    chips would hold them; sixteen of one each as Qwen3-Next's sixteen;
+    eight of two each as SDAR's eight), the shared expert counted once
+    (Qwen3-Next's times its sigmoid gate; SDAR has none to count): their
+    parts sum to what the model's plain reference under benchmark/reference
+    gives for the whole layer (all 16 experts held)."""
     import importlib
 
     reference = importlib.import_module(f"benchmark.reference.{model}")
@@ -113,15 +115,16 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(model, shares, route):
         return y
 
     parts = [jax.jit(lambda w, first=first: _layer(
-        w, first, held, shared_fn=shared if first == 0 else None,
+        w, first, held,
+        shared_fn=shared if first == 0 and model != "sdar" else None,
         **route)[0])(w) for first in range(0, E, held)]
     with jax.default_matmul_precision("highest"):
         if model == "laguna":
             whole = reference.sparse(w["x"], p, per_token=K, scaling=2.5,
                                      first=0)
         else:
-            whole = reference.sparse(w["x"], p, per_token=3, first=0,
-                                     normalise=True)
+            whole = reference.sparse(w["x"], p, per_token=route["top_k"],
+                                     first=0, normalise=True)
     np.testing.assert_allclose(sum(parts), whole, rtol=2e-5, atol=2e-5)
     # and one share alone is not the layer
     assert float(jnp.abs(parts[0] - whole).max()) > 1e-2

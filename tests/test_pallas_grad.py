@@ -215,9 +215,9 @@ class TestFlashBackwardPaths:
         v = jnp.asarray(rng.randn(1, 256, 1, 16), jnp.float32)
         do = jnp.asarray(rng.randn(1, 256, 2, 16), jnp.float32)
         args = (True, 0.25, 128, 128)       # causal, scale, block_q, block_k
-        out, res = pk._flash_attn_fwd(q, k, v, *args, window)
+        out, res = pk._flash_attn_fwd(q, k, v, *args, window, None)
         got = jax.jit(lambda res, do: pk._flash_attn_bwd(
-            *args, window, res, do))(res, do)
+            *args, window, None, res, do))(res, do)
         lse_rows = res[4]
         ref = jax.jit(lambda res, do: pk._flash_bwd_blockwise(
             *args, res[:4] + (lse_rows[:, :, 0, :],), do, window))(res, do)

@@ -153,6 +153,11 @@ def _flash(kernel):
     if kernel == "flash_win_bwd":
         return lambda: jax.grad(lambda q: pk.flash_attention(
             q, q, q, window=32).sum())(q)
+    if kernel == "flash_bd_fwd":                # and under the block mask
+        return lambda: pk.flash_attention(q, q, q, block_diffusion=4)
+    if kernel == "flash_bd_bwd":
+        return lambda: jax.grad(lambda q: pk.flash_attention(
+            q, q, q, block_diffusion=4).sum())(q)
     lse = jnp.zeros((1, 2, 128), jnp.float32)   # the ring step's two
     return lambda: pk.flash_grad_block(q, q, q, q, q, lse,
                                        block_q=128, block_k=128)
@@ -212,9 +217,9 @@ def _rope(kernel):
 
 
 KERNEL_SITES = (
-    [(_flash, k) for k in ("flash_fwd", "flash_win_fwd", "flash_fwd.ring",
-                           "flash_bwd", "flash_win_bwd", "flash_dq",
-                           "flash_dkv")]
+    [(_flash, k) for k in ("flash_fwd", "flash_win_fwd", "flash_bd_fwd",
+                           "flash_fwd.ring", "flash_bwd", "flash_win_bwd",
+                           "flash_bd_bwd", "flash_dq", "flash_dkv")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_gdn, "gdn_inverse")]
     + [(_rope, "rope")]
@@ -234,9 +239,10 @@ def test_every_pallas_call_site_lowers_under_its_name(build, kernel):
 
 def test_no_pallas_call_site_is_left_without_a_name():
     """A new ``pl.pallas_call`` arrives with a ``hvdt.kernel.`` scope in
-    the ``with`` statement above it (a site that lowers under one of two
-    names, as the local flash calls do with and without a window, names
-    both there), and with a case in KERNEL_SITES for each name."""
+    the ``with`` statement above it (a site that lowers under one of
+    several names, as the local flash calls do under a window, under the
+    block mask and under neither, names them all there), and with a case in
+    KERNEL_SITES for each name."""
     import inspect
 
     from horovod_tpu.ops import conv_fused, optim_kernels, pallas_kernels
@@ -251,7 +257,7 @@ def test_no_pallas_call_site_is_left_without_a_name():
                 while not lines[start].lstrip().startswith("with "):
                     start -= 1
                 statement = " ".join(lines[start:i])
-                assert "named_scope(" in statement and i - start <= 2
+                assert "named_scope(" in statement and i - start <= 3
                 names = re.findall(r'"hvdt\.kernel\.(\w+)"', statement)
                 assert names, f"{mod.__name__}:{i + 1} has no kernel scope"
                 named.extend(names)
@@ -457,6 +463,47 @@ def test_a_pattern_lm_names_windowed_and_full_kernels_apart(monkeypatch):
               if "chlo.ragged_dot" in line]
     assert len(ragged) >= 9             # 3 forward, 3 recompute, 6 backward
     assert all("hvdt.moe/hvdt.moe.experts/" in loc for loc in ragged)
+
+
+def test_a_block_diffusion_lm_names_its_kernels_loss_and_lookup(monkeypatch):
+    """Lowered for the TPU, the second objective's gradient: the block-mask
+    kernels under ``hvdt.attention.core`` as ``flash_bd_fwd`` (forward and
+    ``rematted_computation``) and ``flash_bd_bwd`` (``transpose(``), no
+    causal or windowed call; the noisy half's slice and the weighted loss
+    under ``hvdt.loss``; the two streams' rows and their lookup under
+    ``hvdt.embed``."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("HVDT_FLASH_ATTENTION", "on")
+    monkeypatch.setattr(pk, "_use_interpret", lambda: False)
+    cfg = models.TransformerConfig(
+        vocab=128, layers=2, d_model=256, heads=2, kv_heads=2, head_dim=128,
+        d_ff=256, max_seq=256, remat=True, loss_chunk=64, diffusion_block=4)
+    params = jax.eval_shape(
+        lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
+    batch = (jax.ShapeDtypeStruct((2, 128), jnp.int32),
+             jax.ShapeDtypeStruct((2, 32), jnp.float32),
+             jax.ShapeDtypeStruct((2, 128), jnp.bool_))
+    text = jax.jit(jax.value_and_grad(
+        lambda p, *b: models.transformer_block_diffusion_loss(
+            p, *b, cfg))).trace(params, *batch).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+    flash = set(re.findall(r'loc\("([^"]*hvdt\.kernel\.flash[^"]*)"', text))
+    assert flash == {
+        "hvdt.attention/hvdt.attention.core/hvdt.kernel.flash_bd_fwd/"
+        "pallas_call",
+        "checkpoint/rematted_computation/hvdt.attention/"
+        "hvdt.attention.core/hvdt.kernel.flash_bd_fwd/pallas_call",
+        "checkpoint/hvdt.attention/hvdt.attention.core/"
+        "hvdt.kernel.flash_bd_bwd/pallas_call"}
+    # the rows [x_t ; x_0] and their lookup; its backward the scatter-add
+    for name in ("jvp(hvdt.embed)/select_n", "jvp(hvdt.embed)/concatenate",
+                 "jvp(hvdt.embed)/gather",
+                 "transpose(jvp(hvdt.embed))/scatter-add",
+                 # the noisy half, a row's weight masked / t, the weighted sum
+                 "jvp(hvdt.loss)/slice", "jvp(hvdt.loss)/div",
+                 "jvp(hvdt.loss)/mul", "transpose(jvp(hvdt.loss))/pad"):
+        assert f'/{name}"' in text, name
 
 
 # ---------------------------------------------------------------------------

@@ -774,6 +774,40 @@ def kernel_gdn_inverse(*, matrices=8192, chunk=64):
            jax.jit(gd._inverse_slabs_loop)(cols), rtol=1e-4, atol=1e-4)
 
 
+def kernel_moe_sum_rows(*, tokens=16384, picks=10, width=2048, routed=512,
+                        held=32):
+    """ops/pallas_kernels moe_sum_rows against XLA's gather and sum
+    (parallel/moe._sum_held_rows_xla) on the sorted buffer of one sparse
+    layer of qwen3_next_s16384 (163,840 rows of 2,048, one pick in sixteen
+    held), the rows behind those that landed NaN: nothing of them may
+    reach a token."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import pallas_kernels as pk
+    from horovod_tpu.parallel import moe
+
+    _, experts = jax.lax.top_k(jax.random.uniform(
+        jax.random.PRNGKey(0), (tokens, routed)), picks)
+    local = experts.reshape(-1)
+    landed = local < held
+    segment = jnp.where(landed, local, held)
+    inverse = jnp.argsort(jnp.argsort(segment, stable=True))
+    rows = jax.random.normal(jax.random.PRNGKey(1), (tokens * picks, width),
+                             jnp.bfloat16)
+    rows = jnp.where((jnp.arange(tokens * picks) < landed.sum())[:, None],
+                     rows, jnp.nan)
+    landed = landed.reshape(tokens, picks)
+    if pk._moe_sum_rows_blocks(tokens, picks, width, held + 1,
+                               rows.dtype) is None:
+        raise AssertionError("the kernel has no blocks for this shape")
+    got = jax.jit(lambda r, i, h, s: moe._sum_held_rows(
+        r, i, h, s, held + 1))(rows, inverse, landed, segment)
+    _close("moe_sum_rows", got,
+           jax.jit(moe._sum_held_rows_xla)(rows, inverse, landed),
+           rtol=1e-2, atol=1e-2)
+
+
 def kernel_rope(*, batch=8, seq=4096, heads=16, head_dim=64):
     """ops/pallas_kernels rope on the [B, L, H*D] rows of
     lm24x1024_s4096_b8's q: the Mosaic call and its backward (the same
@@ -862,7 +896,7 @@ KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
            kernel_flash_window, kernel_flash_block_diffusion,
            kernel_flash_grad_block,
            kernel_conv_bn_relu, kernel_conv_bn_train, kernel_gdn_inverse,
-           kernel_rope,
+           kernel_rope, kernel_moe_sum_rows,
            kernel_fused_adam, kernel_fused_sgd, kernel_quant_int8,
            kernel_quant_int4)
 

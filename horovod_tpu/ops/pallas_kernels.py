@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["flash_attention", "flash_block_update", "flash_grad_block",
-           "rope", "attention_reference"]
+           "rope", "moe_sum_rows", "attention_reference"]
 
 _NEG_INF = -1e30
 
@@ -1811,6 +1811,259 @@ def rope(x, cos, sin, half: int):
         return _rope_rows(x, cos, sin, half, block)
     heads = x.reshape(x.shape[:2] + (-1, cos.shape[-1]))
     return _rope_heads_xla(heads, cos, sin, half).reshape(x.shape)
+
+
+# A third kernel that is not flash attention (the module's first lines name
+# two): ``moe_sum_rows`` returns the experts' rows to their tokens
+# (``parallel/moe.py``), the runs of the sorted buffer that a tile of tokens
+# needs DMA'd into VMEM and summed there by a 0/1 product, where XLA writes
+# the gathered copy and reads it back.
+
+_SUM_ROWS_TILE = 128            # tokens a 0/1 product returns at once
+_SUM_ROWS_PROGRAM = 1024        # tokens a program: 8 tiles, two in flight
+_SUM_ROWS_GROUP = 8             # rows a DMA: a tile of a tiled dimension
+_SUM_ROWS_WORDS = 128           # a tile's share of a rank-1 SMEM block
+_SUM_ROWS_SLAB = 512            # lanes of the product's result at once
+_SUM_ROWS_SPARE = 16 << 20      # VMEM for the product's operands and result
+_SUM_ROWS_VMEM = 100 << 20
+
+
+def _moe_sum_rows_blocks(t: int, k: int, d: int, segments: int, dtype
+                         ) -> Optional[Tuple[int, int]]:
+    """(staged groups a tile, bytes of VMEM) of :func:`moe_sum_rows` on
+    ``t`` tokens of ``k`` picks, rows of ``d`` in ``segments`` runs of the
+    sorted buffer (the held experts and, last, the picks that are not
+    held), or None where the kernel has no blocks and the caller keeps
+    XLA's gather and sum: the tokens have to be whole programs of 1,024 (a
+    rank-1 SMEM block is whole tiles of 1,024 words), a row whole lanes,
+    and the ends of the runs, which the kernel fetches in whole groups of
+    8 rows, at most as many rows again as the picks (so many experts that
+    a tile of tokens sends most of them less than a group each are better
+    served by a gather).  Read from the shapes alone."""
+    g, tile = _SUM_ROWS_GROUP, _SUM_ROWS_TILE
+    ends = (segments - 1) * (2 * g - 2)
+    if t % _SUM_ROWS_PROGRAM or d % 128 or k < 1 or ends > tile * k:
+        return None
+    staged = -(-(tile * k + ends) // 128) * 128
+    row = d * jnp.dtype(dtype).itemsize
+    vmem = ((2 * staged + 8 * g + 2 * _SUM_ROWS_PROGRAM) * row
+            + _SUM_ROWS_SPARE)
+    return (staged // g, vmem) if vmem <= _SUM_ROWS_VMEM else None
+
+
+def _moe_sum_rows_plan(inverse, held, segment, segments: int, groups: int):
+    """What a tile of 128 tokens fetches and where its picks' rows then
+    lie, from the picks' places in the sorted buffer: (``place`` [k up to
+    8s, T], ``fetch`` [tiles * stride], ``counts`` [tiles * 128]).
+
+    The buffer is sorted by segment and, inside one, by pick, so the picks
+    a tile sends to a segment are ONE run of consecutive rows: run s of
+    tile i starts where segment s starts plus what the tiles before sent
+    there.  The tile stages each held segment's run in whole groups of 8
+    rows, one behind the other: ``fetch`` lists the buffer's group for
+    each staged group, ``place`` is a held pick's row among the staged
+    ones (-1 for a pick that is not held).  ``counts`` holds, a tile, the
+    staged groups, the first group and the groups of the run that is not
+    held (moved too, to where nothing reads it), and which staged group
+    holds the end of the rows that landed with how many of its rows to
+    keep (-1: none does): behind them lies what the products left there,
+    which may be anything."""
+    t, k = held.shape
+    g, r = _SUM_ROWS_GROUP, _SUM_ROWS_TILE * k
+    n = t // _SUM_ROWS_TILE
+    ids = jnp.arange(segments, dtype=jnp.int32)
+    mine = segment.reshape(n, r, 1) == ids                      # [n, r, S]
+    count = mine.sum(1, dtype=jnp.int32)                        # [n, S]
+    whole = count.sum(0)
+    start = (jnp.cumsum(whole) - whole)[None] + jnp.cumsum(count, 0) - count
+    first = start - start % g                   # the run's first group's row
+    fetched = jnp.where(count > 0, (start - first + count + g - 1) // g, 0)
+    staged = fetched.at[:, -1].set(0)
+    end = jnp.cumsum(staged, 1)
+    at = end - staged                           # the run's first staged group
+    of_pick = jnp.where(mine, (at * g - first)[:, None, :], 0).sum(-1)
+    place = jnp.where(held.reshape(n, r), of_pick + inverse.reshape(n, r), -1)
+    u = jnp.arange(groups, dtype=jnp.int32)
+    run = (u[None, :, None] >= end[:, None, :]).sum(-1)         # [n, groups]
+    fetch = jnp.where(run[..., None] == ids, (first // g - at)[:, None, :],
+                      0).sum(-1) + u
+    landed = whole[:-1].sum()
+    keep = landed % g
+    last = (fetch == landed // g) & (u < end[:, -1:]) & (keep > 0)
+    counts = jnp.stack(
+        [end[:, -1], first[:, -1] // g, fetched[:, -1],
+         jnp.where(last.any(1), jnp.argmax(last, 1), -1),
+         jnp.broadcast_to(keep, (n,))], 1).astype(jnp.int32)
+    words = _SUM_ROWS_WORDS
+    # Tokens on the lanes: [T, k] would be padded to 128 lanes in HBM.
+    place = jnp.pad(place.reshape(t, k).T.astype(jnp.int32),
+                    ((0, -k % 8), (0, 0)), constant_values=-1)
+    return (place,
+            jnp.pad(fetch.astype(jnp.int32),
+                    ((0, 0), (0, -groups % words))).reshape(-1),
+            jnp.pad(counts, ((0, 0), (0, words - 5))).reshape(-1))
+
+
+def _moe_sum_rows_kernel(fetch_ref, counts_ref, place_ref, rows_hbm, out_ref,
+                         stage, aside, sem, *, k: int, groups: int,
+                         precision):
+    """One program of :func:`moe_sum_rows`: 8 tiles of 128 tokens, the
+    next tile's groups on their way while this one's rows are summed.
+    ``fetch_ref`` / ``counts_ref`` (SMEM) and ``place_ref`` [k, tokens] are
+    :func:`_moe_sum_rows_plan`'s; ``rows_hbm`` [rows, D] stays in HBM;
+    ``stage`` [2, staged rows, D] takes a tile's held runs, ``aside`` the
+    groups of the run that is not held: EVERY pick's row is moved, so the
+    time is the buffer's and not the routing's, and nothing reads those.
+    A token's row is then a 0/1 product: row t of ``sel`` [128, staged
+    rows] is 1 at the token's held picks' places, so ``sel @ stage`` is
+    the float32 sum of exactly those rows (a product by 1 is exact), with
+    one rounding.  A staged row no pick of the tile points at meets only
+    zeros; it has to be finite, and is: another tile's row, or zero (the
+    stage starts as zeros, and the rows behind the last that landed are
+    zeroed where a group brings them)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    g, tile, words = _SUM_ROWS_GROUP, _SUM_ROWS_TILE, _SUM_ROWS_WORDS
+    d = out_ref.shape[1]
+    staged = groups * g
+    stride = -(-groups // words) * words
+
+    def group(at, dst, slot):
+        return pltpu.make_async_copy(
+            rows_hbm.at[pl.ds(pl.multiple_of(at * g, g), g), :], dst,
+            sem.at[slot])
+
+    def start(c, slot):
+        def held(u, carry):
+            group(fetch_ref[c * stride + u],
+                  stage.at[slot, pl.ds(pl.multiple_of(u * g, g), g), :],
+                  slot).start()
+            return carry
+
+        def rest(u, carry):
+            group(counts_ref[c * words + 1] + u,
+                  aside.at[u % aside.shape[0]], slot).start()
+            return carry
+
+        jax.lax.fori_loop(0, counts_ref[c * words], held, 0)
+        jax.lax.fori_loop(0, counts_ref[c * words + 2], rest, 0)
+
+    def wait(c, slot):
+        def one(u, carry):
+            group(0, aside.at[0], slot).wait()
+            return carry
+
+        jax.lax.fori_loop(
+            0, counts_ref[c * words] + counts_ref[c * words + 2], one, 0)
+
+    def total(c, slot):
+        last, keep = counts_ref[c * words + 3], counts_ref[c * words + 4]
+
+        @pl.when(last >= 0)
+        def _():
+            base = pl.multiple_of(last * g // 16 * 16, 16)
+            rows = stage[slot, pl.ds(base, 16), :]
+            at = base + jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+            stage[slot, pl.ds(base, 16), :] = jnp.where(
+                (at >= last * g + keep) & (at < (last + 1) * g),
+                jnp.zeros_like(rows), rows)
+
+        row0 = pl.multiple_of(c * tile, tile)
+        place = place_ref[:, pl.ds(row0, tile)].T               # [tile, k]
+        at = jax.lax.broadcasted_iota(jnp.int32, (tile, staged), 1)
+        sel = at == place[:, 0:1]
+        for j in range(1, k):
+            sel = sel | (at == place[:, j:j + 1])
+        sel = sel.astype(stage.dtype)
+        for d0 in range(0, d, min(d, _SUM_ROWS_SLAB)):
+            lanes = slice(d0, d0 + min(d, _SUM_ROWS_SLAB))
+            out_ref[pl.ds(row0, tile), lanes] = jnp.dot(
+                sel, stage[slot, :, lanes],
+                preferred_element_type=jnp.float32,
+                precision=precision).astype(out_ref.dtype)
+
+    stage[...] = jnp.zeros_like(stage)
+    start(0, 0)
+
+    def body(c, carry):
+        @pl.when(c + 1 < out_ref.shape[0] // tile)
+        def _():
+            start(c + 1, 1 - c % 2)
+
+        wait(c, c % 2)
+        total(c, c % 2)
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[0] // tile, body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("segments",))
+def moe_sum_rows(rows, inverse, held, segment, *, segments: int):
+    """``rows`` [T * k, D], the buffer sorted by ``segment`` and then by
+    pick; ``inverse`` [T * k] a pick's row, ``held`` [T, k], ``segment``
+    [T * k] a pick's segment of ``segments`` (the picks that are not held
+    in the last) -> [T, D]: each token the float32 sum of its held picks'
+    rows, rounded once to ``rows.dtype``; what
+    ``parallel/moe._sum_held_rows_xla`` computes, as one Mosaic call.
+
+    Mosaic moves no less than 8 rows of the buffer at once (a row is a
+    sublane of D / 128 tiles, and a slice of a tiled dimension is whole
+    tiles), and the sort is what makes that enough: a tile of 128 tokens
+    needs ``segments`` runs of consecutive rows, fetched in
+    ``tile * k / 8 + segments`` copies of 8 rows (one copy costs 17 cycles
+    whatever its size: a copy a row on the buffer laid ``[T * k, D / 128,
+    128]`` took 2.44 ms for 131,072 rows, and XLA 1.63 more to lay it so),
+    and the rows reach their tokens through the MXU.  So the gathered
+    ``[T * k, D]`` copy, its re-read by the sum and, where k does not fill
+    a sublane tile, its relayout are never made: 1.42 ms a call at 131,072
+    rows of 2,048 on the v5e against 5.20 (1.76 against 9.72 at top-10),
+    the same to 1.4% at one pick in eight held and at all held (PERF.md,
+    PR 40).  The caller has asked :func:`_moe_sum_rows_blocks` whether the
+    shapes have blocks.  Jitted, so that the forward's and the backward's
+    calls of every run of layers share one trace (each cost about a second
+    of set-up in every run, warm or cold)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ..parallel.sharding import pcast_to_union
+
+    t, k = held.shape
+    d = rows.shape[1]
+    groups, vmem = _moe_sum_rows_blocks(t, k, d, segments, rows.dtype)
+    g, program = _SUM_ROWS_GROUP, _SUM_ROWS_PROGRAM
+    tiles = program // _SUM_ROWS_TILE
+    place, fetch, counts = (
+        pcast_to_union(a, rows, inverse, held, segment)
+        for a in _moe_sum_rows_plan(inverse.reshape(-1), held,
+                                    segment.reshape(-1), segments, groups))
+    rows = pcast_to_union(rows, place)
+    smem = functools.partial(pl.BlockSpec, index_map=lambda i: (i,),
+                             memory_space=pltpu.SMEM)
+    with jax.named_scope("hvdt.kernel.moe_sum_rows"):
+        return pl.pallas_call(
+            functools.partial(
+                _moe_sum_rows_kernel, k=k, groups=groups,
+                precision=(jax.lax.Precision.HIGHEST
+                           if rows.dtype == jnp.float32 else None)),
+            grid=(t // program,),
+            in_specs=[smem((fetch.size // (t // program),)),
+                      smem((tiles * _SUM_ROWS_WORDS,)),
+                      pl.BlockSpec((place.shape[0], program),
+                                   lambda i: (0, i)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((program, d), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((t, d), rows.dtype,
+                                           **_vma_kw(rows)),
+            scratch_shapes=[pltpu.VMEM((2, groups * g, d), rows.dtype),
+                            pltpu.VMEM((8, g, d), rows.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",), vmem_limit_bytes=vmem),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * t * groups * g * d, transcendentals=0,
+                bytes_accessed=(rows.size + t * d) * rows.dtype.itemsize),
+            interpret=_use_interpret(),
+        )(fetch, counts, place, rows)
 
 
 def attention_reference(q, k, v, *, causal=True, scale=None,

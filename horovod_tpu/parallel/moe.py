@@ -28,6 +28,7 @@ series and desync forensics cover expert routing with no extra wiring.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
@@ -90,52 +91,76 @@ def moe_capacity(tokens_per_rank: int, num_experts: int, *,
 # in the other rows is selected away where rows enter a token's sum.  Their
 # cost is the buffer's (all T x k rows move, whatever landed) and so does
 # not change with the routing: measured on the v5e at 131,072 rows of 2048
-# (PERF.md, PR 31), a gather from the [T, D] activations 0.83 ms, from the
-# [T x k, D] buffer 4.5 ms; a scatter-add that walks only the rows that
-# landed 4.2 ms for 16,384 rows and more or less with the load.
+# (PERF.md, PR 31 and PR 40), a gather from the [T, D] activations 0.83 ms;
+# the rows back to their tokens 1.42 ms as one Mosaic call that DMAs the
+# runs of the sort a tile of tokens needs and sums them in VMEM, 5.2 ms as
+# XLA's gather from the [T x k, D] buffer and the sum over its copy (1.76
+# against 9.7 at top-10, where XLA also lays the copy out again); a
+# scatter-add that walks only the rows that landed 4.2 ms for 16,384 rows
+# and more or less with the load.
 
 
-def _sum_held_rows(rows, inverse, held):
-    """[T * k, D] -> [T, D]: each token the float32 sum of its held picks'
-    rows; a pick's row is ``rows[inverse[pick]]``."""
+def _sum_held_rows_xla(rows, inverse, held):
+    """:func:`_sum_held_rows` in plain ``jnp``: XLA writes the gathered
+    ``[T * k, D]`` rows, reads them back for the sum and, where k does not
+    fill a sublane tile (top-10), lays them out again between the two."""
     t, k = held.shape
     picks = jnp.where(held[:, :, None], rows[inverse].reshape(t, k, -1), 0)
     return picks.sum(1, dtype=jnp.float32).astype(rows.dtype)
 
 
-@jax.custom_vjp
-def _rows_of_tokens(x, order, inverse, held):
+def _sum_held_rows(rows, inverse, held, segment, segments):
+    """[T * k, D] -> [T, D]: each token the float32 sum of its held picks'
+    rows; a pick's row is ``rows[inverse[pick]]``.  One Mosaic call
+    (``ops.pallas_kernels.moe_sum_rows``, which reads the rows by the runs
+    of the sort: ``segment`` [T * k] is a pick's key, one of ``segments``)
+    where the shapes have blocks for it, the tokens whole programs of 1,024
+    and a row whole lanes (training and long prefills), XLA's form
+    elsewhere (a decode step, short prefills, toy widths): the same sum of
+    the same rows either way."""
+    from ..ops.pallas_kernels import _moe_sum_rows_blocks, moe_sum_rows
+
+    if _moe_sum_rows_blocks(*held.shape, rows.shape[1], segments,
+                            rows.dtype) is None:
+        return _sum_held_rows_xla(rows, inverse, held)
+    return moe_sum_rows(rows, inverse, held, segment, segments=segments)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rows_of_tokens(x, order, inverse, held, segment, segments):
     """x [T, D] -> [T * k, D]: row r is the token of pick ``order[r]``."""
     return x[order // held.shape[1]]
 
 
-def _rows_of_tokens_fwd(x, order, inverse, held):
-    return _rows_of_tokens(x, order, inverse, held), (inverse, held)
+def _rows_of_tokens_fwd(x, order, inverse, held, segment, segments):
+    return (_rows_of_tokens(x, order, inverse, held, segment, segments),
+            (inverse, held, segment))
 
 
-def _rows_of_tokens_bwd(res, g):
-    return _sum_held_rows(g, *res), None, None, None
+def _rows_of_tokens_bwd(segments, res, g):
+    return (_sum_held_rows(g, *res, segments),) + (None,) * 4
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
-@jax.custom_vjp
-def _tokens_of_rows(rows, order, inverse, held):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _tokens_of_rows(rows, order, inverse, held, segment, segments):
     """rows [T * k, D] -> [T, D]: each token the sum of its held picks'
     rows."""
-    return _sum_held_rows(rows, inverse, held)
+    return _sum_held_rows(rows, inverse, held, segment, segments)
 
 
-def _tokens_of_rows_fwd(rows, order, inverse, held):
-    return _tokens_of_rows(rows, order, inverse, held), (order, held)
+def _tokens_of_rows_fwd(rows, order, inverse, held, segment, segments):
+    return (_tokens_of_rows(rows, order, inverse, held, segment, segments),
+            (order, held))
 
 
-def _tokens_of_rows_bwd(res, g):
+def _tokens_of_rows_bwd(segments, res, g):
     order, held = res
     # The rows past those that landed get their token's too: no product
     # reads them.
-    return g[order // held.shape[1]], None, None, None
+    return (g[order // held.shape[1]],) + (None,) * 4
 
 
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -206,7 +231,8 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
         local = experts.reshape(m) - experts_first            # pick t*k + i
         held = jnp.logical_and(local >= 0, local < e_held)
         # Held picks first, by expert; the others behind them.
-        order = jnp.argsort(jnp.where(held, local, e_held), stable=True)
+        segment = jnp.where(held, local, e_held)
+        order = jnp.argsort(segment, stable=True)
         inverse = jnp.argsort(order)
         group_sizes = jnp.sum(
             local[:, None] == jnp.arange(e_held, dtype=local.dtype)[None],
@@ -216,7 +242,7 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
         held = held.reshape(t, k)
     with (jax.named_scope("hvdt.moe.dispatch"),
           jax.named_scope("hvdt.moe.dispatch.rows")):
-        xs = _rows_of_tokens(x, order, inverse, held)
+        xs = _rows_of_tokens(x, order, inverse, held, segment, e_held + 1)
     with jax.named_scope("hvdt.moe.experts"):
         up = lax.ragged_dot(xs, w_up.astype(x.dtype), group_sizes)
         if w_gate is None:
@@ -230,7 +256,7 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
         ys = lax.ragged_dot(mid, w_down.astype(x.dtype), group_sizes)
     with (jax.named_scope("hvdt.moe.dispatch"),
           jax.named_scope("hvdt.moe.dispatch.tokens")):
-        out = _tokens_of_rows(ys, order, inverse, held)
+        out = _tokens_of_rows(ys, order, inverse, held, segment, e_held + 1)
     if shared_fn is not None:
         with jax.named_scope("hvdt.moe.shared"):
             out = out + shared_fn(x)

@@ -24,6 +24,8 @@ TOY_KERNELS = dict(
     kernel_conv_bn_train=dict(batch=2, hw=8, cin=128, cout=128),
     kernel_gdn_inverse=dict(matrices=130, chunk=16),
     kernel_rope=dict(batch=2, seq=32, heads=4, head_dim=64),
+    kernel_moe_sum_rows=dict(tokens=1024, picks=3, width=128, routed=16,
+                             held=4),
     kernel_fused_adam=dict(shape=(2, 64, 128)),
     kernel_fused_sgd=dict(shape=(3, 3, 16, 128)),
     kernel_quant_int8=dict(size=1 << 14, block=256),

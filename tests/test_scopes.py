@@ -216,6 +216,15 @@ def _rope(kernel):
                                  block=(1, 16, 128))
 
 
+def _moe_rows(kernel):
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    rows = jnp.ones((2048, 128), jnp.bfloat16)
+    picks = jnp.arange(2048, dtype=jnp.int32)
+    return lambda: pk.moe_sum_rows(
+        rows, picks, jnp.ones((1024, 2), bool), picks // 1024, segments=3)
+
+
 KERNEL_SITES = (
     [(_flash, k) for k in ("flash_fwd", "flash_win_fwd", "flash_bd_fwd",
                            "flash_fwd.ring", "flash_bwd", "flash_win_bwd",
@@ -223,6 +232,7 @@ KERNEL_SITES = (
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_gdn, "gdn_inverse")]
     + [(_rope, "rope")]
+    + [(_moe_rows, "moe_sum_rows")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
     + [(_quant, k) for k in ("quantize", "dequantize", "quantize4",
                              "dequantize4")])
@@ -233,8 +243,9 @@ KERNEL_SITES = (
 def test_every_pallas_call_site_lowers_under_its_name(build, kernel):
     text = lowered_text(build(kernel))
     scope = kernel.split(".")[0]        # two sites share flash_fwd's name
-    # A whole segment of the path, bare or inside jvp(..)/transpose(..).
-    assert re.search(rf"[/(]hvdt\.kernel\.{scope}[/)]", text)
+    # A whole segment of the path, bare or inside jvp(..)/transpose(..),
+    # or the first of a jitted function's own (moe_sum_rows).
+    assert re.search(rf"[\"/(]hvdt\.kernel\.{scope}[/)]", text)
 
 
 def test_no_pallas_call_site_is_left_without_a_name():

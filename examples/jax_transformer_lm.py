@@ -60,6 +60,18 @@ PRESETS = {
                                "sdar_30b_a3b.json"),
         seq=8192, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
         dp=1, tp=1),
+    # EvaByte (6.5B, byte-level) on one chip's share of a 4-chip layer: the
+    # benchmark's configuration evabyte (cell evabyte_s32768): four layers
+    # of EVA attention (exact softmax inside aligned 2,048-byte windows
+    # joined with a learned 16-byte chunk summary of every earlier window)
+    # with 8 of 32 heads held and the feed-forward whole, 8 prediction
+    # heads over 320 ids; 620M parameters, 9.2 GiB of training state.
+    "evabyte": dict(
+        published=os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               os.pardir, "benchmark", "configs",
+                               "evabyte.json"),
+        seq=32768, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
+        dp=1, tp=1),
 }
 
 
@@ -86,11 +98,12 @@ def main():
                    help="train the layer-pattern model a published "
                         "config.json describes (models.config_from_"
                         "published): per-kind heads, windows and rotary "
-                        "settings, dense and sparse feed-forwards.  The "
+                        "settings, dense and sparse feed-forwards, EVA "
+                        "attention, several prediction heads.  The "
                         "file's own layers / experts / experts_first / "
-                        "vocab keys, where present, cut it to this "
-                        "device's share; the size flags are ignored; "
-                        "data-parallel layouts only")
+                        "vocab / heads / heads_first keys, where present, "
+                        "cut it to this device's share; the size flags "
+                        "are ignored; data-parallel layouts only")
     p.add_argument("--dtype", choices=["float32", "bfloat16"],
                    default="float32",
                    help="activation/compute dtype (bfloat16 on TPU)")
@@ -177,6 +190,8 @@ def main():
             experts=published.get("experts"),
             experts_first=published.get("experts_first", 0),
             vocab=published.get("vocab"),
+            heads=published.get("heads"),
+            heads_first=published.get("heads_first", 0),
             router_score=published.get("router_score", "sigmoid"),
             # what the model's code does and its config.json has no key
             # for, where the file states it

@@ -13,6 +13,7 @@ from .transformer import (  # noqa: F401
     TransformerConfig,
     LayerKind,
     LinearMixer,
+    Eva,
     Rope,
     Experts,
     config_from_published,

@@ -35,6 +35,7 @@ import numpy as np
 from jax import lax
 
 from ..ops.attention import attention
+from ..ops.eva import eva_attention, eva_visible_pairs
 from ..ops.gated_delta import gated_delta_net, scan_macs_per_token
 from ..ops.pallas_kernels import rope
 from ..parallel.moe import moe_dispatch_combine, moe_held_experts
@@ -43,7 +44,7 @@ from ..parallel.ring_attention import ring_attention
 from ..quant import fp8 as _fp8
 
 __all__ = [
-    "TransformerConfig", "LayerKind", "LinearMixer", "Rope", "Experts",
+    "TransformerConfig", "LayerKind", "LinearMixer", "Eva", "Rope", "Experts",
     "config_from_published", "transformer_init", "transformer_apply",
     "transformer_loss", "transformer_block_diffusion_loss",
     "block_diffusion_corrupt", "transformer_logical_axes",
@@ -98,13 +99,29 @@ class LinearMixer:
 
 
 @dataclasses.dataclass(frozen=True)
+class Eva:
+    """The sizes of EVA attention (``ops/eva.py``): exact softmax inside
+    aligned windows of ``window`` positions, joined in one softmax with a
+    learned summary of every ``chunk`` positions of every earlier window.
+    A head's two learned vectors start N(0, 1) clipped to +-1, times
+    ``init_std``."""
+    window: int
+    chunk: int
+    init_std: float = 0.02
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerKind:
     """One kind of layer of a pattern: its mixer (softmax attention with
-    its own head counts, window and rotary settings; or, with ``linear``
-    set, the Gated DeltaNet of those sizes, which reads none of the
-    attention fields) and its feed-forward (dense SwiGLU of width
-    ``d_ff``, or with ``sparse`` the configuration's expert layer,
-    ``TransformerConfig.moe``)."""
+    its own head counts, window and rotary settings, with ``eva`` set under
+    EVA's two masks in place of ``window``'s; or, with ``linear`` set, the
+    Gated DeltaNet of those sizes, which reads none of the attention
+    fields) and its feed-forward (dense SwiGLU of width ``d_ff``, or with
+    ``sparse`` the configuration's expert layer, ``TransformerConfig.moe``).
+    ``heads`` / ``kv_heads`` are the heads held here: heads ``heads_first
+    .. heads_first + heads - 1`` of the model's where this is one chip's
+    share of a layer whose attention is divided by heads (no head's
+    arithmetic reads its index, so the field only says which they are)."""
     heads: int
     kv_heads: int
     d_ff: int = 0
@@ -112,6 +129,8 @@ class LayerKind:
     rope: Rope = Rope()
     sparse: bool = False
     linear: Optional[LinearMixer] = None
+    eva: Optional[Eva] = None
+    heads_first: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,6 +220,13 @@ class TransformerConfig:
     # positions 0 .. L-1, under ``ops.pallas_kernels.block_diffusion_mask``;
     # the mask token is the last held row of the vocabulary.
     diffusion_block: int = 0
+    # Prediction heads a row: head n of row i predicts token i + 1 + n, all
+    # from one output matrix [pred_heads x vocab, d_model] (untied), the
+    # loss the mean over every (row, head) pair that has a target.  With
+    # more than one, ``loss_chunk`` counts ROWS a chunk (the logits of all
+    # the heads of a chunk of rows exist at a time), not vocabulary rows.
+    pred_heads: int = 1
+    norm_eps: float = 1e-6       # every RMSNorm's epsilon
 
     def __post_init__(self):
         if isinstance(self.out_gate, bool):
@@ -213,12 +239,16 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.heads)
         if self.diffusion_block and (
                 self.sp > 1 or self.pp > 1 or any(
-                    k.window or k.linear
+                    k.window or k.linear or k.eva
                     for k in self.leading + self.period)):
             raise ValueError(
                 "diffusion over blocks runs full softmax attention with sp "
                 "= pp = 1 (the block mask has no window, and a recurrent "
                 "mixer would carry the noisy stream into the clean one)")
+        if self.pred_heads > 1 and (self.tie_head or self.diffusion_block):
+            raise ValueError(
+                "several prediction heads take an output matrix of their "
+                "own (tie_head=False) under the next-token objective")
         if self.period:
             repeated = self.layers - len(self.leading)
             if repeated <= 0 or repeated % len(self.period):
@@ -281,6 +311,8 @@ def config_from_published(published: Dict[str, Any], *,
                           experts: Optional[int] = None,
                           experts_first: int = 0,
                           vocab: Optional[int] = None,
+                          heads: Optional[int] = None,
+                          heads_first: int = 0,
                           router_score: str = "sigmoid",
                           shared_gate: bool = False,
                           **fields) -> TransformerConfig:
@@ -303,11 +335,19 @@ def config_from_published(published: Dict[str, Any], *,
     ``num_experts_per_tok`` picked, ``norm_topk_prob``,
     ``moe_routed_scaling_factor``, a shared expert of
     ``shared_expert_intermediate_size``), ``gating``,
-    ``tie_word_embeddings``, ``vocab_size``, ``num_hidden_layers``.  No
-    width is an argument.  The cut: the first ``layers`` layers (the
-    leading ones and at least a period), ``experts`` of each sparse layer's
-    experts from ``experts_first`` on (the router keeps its width), the
-    first ``vocab`` rows of the vocabulary.  ``router_score`` and
+    ``tie_word_embeddings``, ``vocab_size``, ``num_hidden_layers``;
+    ``attention_class`` (``eva``: every attention layer is EVA attention of
+    ``window_size`` and ``chunk_size``, its learned vectors started at
+    ``init_std``), ``num_pred_heads`` (prediction heads a row),
+    ``norm_add_unit_offset`` (every RMSNorm scales by 1 + gain),
+    ``rms_norm_eps``.  No width is an argument.  The cut: the first
+    ``layers`` layers (the leading ones and at least a period), ``experts``
+    of each sparse layer's experts from ``experts_first`` on (the router
+    keeps its width), the first ``vocab`` rows of the vocabulary, ``heads``
+    of each attention layer's query heads from ``heads_first`` on with
+    their share of the key heads (attention divided by heads: wq, wk, wv
+    by columns, wo by rows; what the absent heads would add to the
+    sublayer's output is left out).  ``router_score`` and
     ``shared_gate`` (the shared expert's sigmoid gate) are what
     ``config.json`` leaves to modelling code, as are the ``fields``
     ``out_gate``, ``qk_norm``, ``zero_centered_norm`` and
@@ -319,8 +359,11 @@ def config_from_published(published: Dict[str, Any], *,
     c = published
     depth = c["num_hidden_layers"]
     head_dim = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
-    heads = c.get("num_attention_heads_per_layer") or \
+    published_heads = c.get("num_attention_heads_per_layer") or \
         [c["num_attention_heads"]] * depth
+    eva = Eva(window=c["window_size"], chunk=c["chunk_size"],
+              init_std=float(c.get("init_std", 0.02))) \
+        if c.get("attention_class") == "eva" else None
     interval = c.get("full_attention_interval")
     layer_types = c.get("layer_types") or [
         "linear_attention" if interval and (i + 1) % interval
@@ -361,11 +404,19 @@ def config_from_published(published: Dict[str, Any], *,
         if layer_types[i] == "linear_attention":
             return LayerKind(heads=0, kv_heads=0, linear=linear,
                              **feed_forward)
+        held = heads or published_heads[i]
+        kv_held, ragged = divmod(c["num_key_value_heads"] * held,
+                                 published_heads[i])
+        if ragged or not kv_held:
+            raise ValueError(
+                f"heads={held} of {published_heads[i]} is no whole share of "
+                f"the {c['num_key_value_heads']} key heads")
         return LayerKind(
-            heads=heads[i], kv_heads=c["num_key_value_heads"],
+            heads=held, kv_heads=kv_held,
             window=c["sliding_window"]
             if layer_types[i] == "sliding_attention" else None,
-            rope=rope_of(layer_types[i]), **feed_forward)
+            rope=rope_of(layer_types[i]), eva=eva,
+            heads_first=heads_first if heads else 0, **feed_forward)
 
     kinds = [kind_of(i) for i in range(depth)]
     # The fewest leading layers after which the published stack repeats
@@ -393,6 +444,10 @@ def config_from_published(published: Dict[str, Any], *,
             shared_d_ff=c.get("shared_expert_intermediate_size", 0),
             shared_gate=shared_gate)
     fields.setdefault("out_gate", "head" if c.get("gating") else "")
+    fields.setdefault("pred_heads", c.get("num_pred_heads", 1))
+    fields.setdefault("norm_eps", float(c.get("rms_norm_eps", 1e-6)))
+    if c.get("norm_add_unit_offset"):
+        fields.setdefault("zero_centered_norm", True)
     return TransformerConfig(
         vocab=vocab or c["vocab_size"], layers=layers,
         d_model=c["hidden_size"], head_dim=head_dim,
@@ -457,6 +512,11 @@ def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
         if cfg.qk_norm:
             p["q_norm"] = _norm_gain(cfg, (dh,))
             p["k_norm"] = _norm_gain(cfg, (dh,))
+        if kind.eva is not None:
+            for name in ("phi", "mu"):
+                p[name] = (jnp.clip(jax.random.normal(next(ks), (h, dh)),
+                                    -1.0, 1.0) * kind.eva.init_std
+                           ).astype(pd)
     if not kind.sparse:
         f = kind.d_ff
         p["w_up"] = _init_linear(next(ks), d, (d, f), pd)
@@ -498,8 +558,8 @@ def _pattern_init(key: jax.Array, cfg: TransformerConfig) -> Dict:
         "period": {},
     }
     if not cfg.tie_head:
-        params["head"] = (jax.random.normal(k_head, (cfg.vocab, d)) * 0.02
-                          ).astype(pd)
+        params["head"] = (jax.random.normal(
+            k_head, (cfg.pred_heads * cfg.vocab, d)) * 0.02).astype(pd)
     runs = cfg.period_runs
     for r, (k_run, (kind, count)) in enumerate(
             zip(jax.random.split(k_period, len(runs)), runs)):
@@ -601,6 +661,8 @@ def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
                 axes["wg"] = ("embed", "heads")
             if cfg.qk_norm:
                 axes.update(q_norm=(None,), k_norm=(None,))
+            if kind.eva is not None:
+                axes.update(phi=("heads", None), mu=("heads", None))
         if kind.sparse:
             axes.update(w_router=("embed", None),
                         w_up=("experts", "embed", "mlp"),
@@ -627,16 +689,16 @@ def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
     return out
 
 
-def _rmsnorm(x, g):
+def _rmsnorm(x, g, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)).astype(
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)).astype(
         x.dtype) * g.astype(x.dtype)
 
 
 def _norm(x, g, cfg: TransformerConfig):
     """The configuration's RMSNorm: scaled by the gain, or by 1 + gain."""
     return _rmsnorm(x, 1.0 + g.astype(jnp.float32)
-                    if cfg.zero_centered_norm else g)
+                    if cfg.zero_centered_norm else g, cfg.norm_eps)
 
 
 def _rope_frequencies(rope: Rope, head_dim: int) -> np.ndarray:
@@ -739,6 +801,9 @@ def _attention(p, x, positions, cfg: TransformerConfig,
             # Manual island: the sequence dim is the local sp shard here
             # (the caller's shard_map over {'sp'} has already split it).
             o = ring_attention(q, k, v, axis="sp", causal=True)
+        elif kind.eva is not None:
+            o = eva_attention(q, k, v, p["phi"], p["mu"],
+                              window=kind.eva.window, chunk=kind.eva.chunk)
         else:
             o = attention(q, k, v, window=kind.window,
                           block_diffusion=cfg.diffusion_block or None)
@@ -1001,13 +1066,16 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
 
 def transformer_apply(params: Dict, tokens: jax.Array,
                       cfg: TransformerConfig) -> jax.Array:
-    """Logits for next-token prediction (see transformer_hidden)."""
+    """Logits for next-token prediction (see transformer_hidden); with
+    ``cfg.pred_heads`` n > 1, [batch, seq, n x vocab]: columns m vocab ..
+    (m + 1) vocab - 1 of row i are head m's, for token i + 1 + m."""
     return _head(params, transformer_hidden(params, tokens, cfg), cfg)
 
 
 def _head_matrix(params: Dict, cfg: TransformerConfig) -> jax.Array:
     """The output projection's [vocab, d_model] matrix: the embedding
-    where the head is tied, else the tree's own ``head``.  Over a slice of
+    where the head is tied, else the tree's own ``head``, [pred_heads x
+    vocab, d_model] (head m's rows after head m - 1's).  Over a slice of
     the vocabulary both have the slice's rows, and logits and loss are
     over the slice."""
     return params["embed"] if cfg.tie_head else params["head"]
@@ -1075,6 +1143,46 @@ def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
     return (jnp.log(s) + m - tl).mean()
 
 
+def _multi_target_xent(x: jax.Array, head: jax.Array, tokens: jax.Array,
+                       heads: int, chunk: int) -> jax.Array:
+    """The loss of ``heads`` prediction heads a row: x [b, l, d] the final
+    hidden rows, head [heads x vocab, d], tokens [b, l].  Head m of row i
+    is scored against token i + 1 + m; the mean of -log softmax over every
+    (row, head) pair that has a target, the heads weighted alike.  Rows in
+    chunks of ``chunk`` (0: all at once) under a checkpoint, so that only
+    a chunk's float32 logits [chunk, heads x vocab] exist at a time, in
+    the forward and in the backward."""
+    b, l, d = x.shape
+    vocab = head.shape[0] // heads
+    ahead = jnp.pad(tokens, ((0, 0), (0, heads)))
+    targets = jnp.stack([ahead[:, 1 + m:1 + m + l] for m in range(heads)],
+                        -1)                                 # [b, l, heads]
+    valid = jnp.broadcast_to(
+        jnp.arange(l)[:, None] + jnp.arange(heads) + 1 < l, (b, l, heads))
+    rows = b * l
+    size = next(c for c in range(min(chunk or rows, rows), 0, -1)
+                if rows % c == 0)
+    w = head.astype(x.dtype)
+
+    def body(total, chunk_of):
+        xc, tc, vc = chunk_of
+        logp = jax.nn.log_softmax(
+            (xc @ w.T).astype(jnp.float32).reshape(size, heads, vocab), -1)
+        ll = jnp.take_along_axis(logp, tc[..., None], -1)[..., 0]
+        return total - jnp.where(vc, ll, 0.0).sum(), None
+
+    total = jnp.zeros((), jnp.float32)
+    # As _chunked_xent: the carry takes the body's varying axes.
+    vma = tuple(set(jax.typeof(x).vma) | set(jax.typeof(tokens).vma))
+    if vma:
+        total = lax.pcast(total, vma, to="varying")
+    total, _ = lax.scan(
+        jax.checkpoint(body), total,
+        (x.reshape(-1, size, d), targets.reshape(-1, size, heads),
+         valid.reshape(-1, size, heads)))
+    return total / (b * sum(max(l - 1 - m, 0) for m in range(heads)))
+
+
 def transformer_loss(params: Dict, tokens: jax.Array,
                      cfg: TransformerConfig) -> jax.Array:
     """Causal LM loss (next-token cross entropy) over the local shard.
@@ -1087,11 +1195,16 @@ def transformer_loss(params: Dict, tokens: jax.Array,
     (block-divisibility gate) engage on the training path.
 
     ``cfg.loss_chunk > 0`` switches to the chunked-vocab logsumexp path
-    (no [tokens, vocab] logits tensor)."""
+    (no [tokens, vocab] logits tensor).  ``cfg.pred_heads`` n > 1: n
+    targets a row, head m of row i against token i + 1 + m
+    (:func:`_multi_target_xent`, chunked over rows)."""
     targets = tokens[:, 1:]
     x = transformer_hidden(params, tokens, cfg)
     # The head's matmul is inside the scope on both branches.
     with jax.named_scope("hvdt.loss"):
+        if cfg.pred_heads > 1:
+            return _multi_target_xent(x, _head_matrix(params, cfg), tokens,
+                                      cfg.pred_heads, cfg.loss_chunk)
         if cfg.loss_chunk:
             return _chunked_xent(x[:, :-1], _head_matrix(params, cfg),
                                  targets, cfg.loss_chunk)
@@ -1368,7 +1481,9 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
     scan; of a sparse layer the router, a token's picks that land on held
     experts in expectation and the shared expert with its gate.  Under
     diffusion over blocks a token is two rows of every layer (the noisy
-    and the clean stream) and one of the head."""
+    and the clean stream) and one of the head.  An EVA layer: the pairs
+    its two masks show a row of a ``max_seq`` sequence, and the pooling.
+    The head at its own width, ``pred_heads`` x vocab columns."""
     d, dh = cfg.d_model, cfg.head_dim
 
     def mixer(kind: LayerKind) -> float:
@@ -1383,6 +1498,11 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
         attn_proj = 2 * d * (h * dh + 2 * hk * dh + h * dh + gate)
         attn_scores = 2 * 2 * min(kind.window or cfg.max_seq,
                                   cfg.max_seq) * h * dh     # approx
+        if kind.eva is not None:
+            pairs = sum(eva_visible_pairs(cfg.max_seq, kind.eva.window,
+                                          kind.eva.chunk)) / cfg.max_seq
+            # q.k and p.v over the visible pairs; k.phi and the two sums
+            attn_scores = 2 * 2 * pairs * h * dh + 2 * 3 * h * dh
         return attn_proj + attn_scores
 
     def layer(kind: LayerKind) -> float:
@@ -1400,4 +1520,5 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
                   + cfg.periods * sum(map(layer, cfg.period)))
     else:
         layers = cfg.layers * layer(cfg.uniform_kind)
-    return (2 if cfg.diffusion_block else 1) * layers + 2 * d * cfg.vocab
+    return ((2 if cfg.diffusion_block else 1) * layers
+            + 2 * d * cfg.vocab * cfg.pred_heads)

@@ -66,8 +66,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention", "flash_block_update", "flash_grad_block",
-           "rope", "moe_sum_rows", "attention_reference"]
+__all__ = ["flash_attention", "flash_attention_stats", "flash_block_update",
+           "flash_grad_block", "rope", "moe_sum_rows", "eva_summary_tiles",
+           "eva_summary_attention", "attention_reference"]
 
 _NEG_INF = -1e30
 
@@ -690,7 +691,7 @@ def _head_blocks(q, k, heads: int, block_q: int, block_k: int):
 
 
 def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
-                      rows=None, window=None, bd=None):
+                      rows=None, window=None, bd=None, eva=False):
     """The self-contained forward: q [B,Lq,H*D], k/v [Bkv,Lk,Hkv*D] (the
     projections' own rows, ``heads`` = H) -> (out [B,Lq,H*D] in q.dtype,
     lse [B,H,1,Lq] f32).  One pallas_call and nothing around it: no carry
@@ -708,7 +709,9 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
     q tile's window reaches, and the call's name is
     ``hvdt.kernel.flash_win_fwd``.  Under block diffusion (``bd``; square
     tiles that tile a stream) it is a stream's tiles and one, and the name
-    ``hvdt.kernel.flash_bd_fwd``."""
+    ``hvdt.kernel.flash_bd_fwd``.  ``eva``: the causal call on the aligned
+    windows of an EVA layer (``flash_attention_stats``), the same program
+    under the name ``hvdt.kernel.eva_win_fwd``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -751,7 +754,8 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
         window=window, key_blocks=lk // block_k)
     if bd is not None:
         windowed = dict(bd=bd, key_blocks=stream)
-    with jax.named_scope("hvdt.kernel.flash_bd_fwd" if bd is not None
+    with jax.named_scope("hvdt.kernel.eva_win_fwd" if eva
+                         else "hvdt.kernel.flash_bd_fwd" if bd is not None
                          else "hvdt.kernel.flash_fwd" if window is None
                          else "hvdt.kernel.flash_win_fwd"):
         return pl.pallas_call(
@@ -979,7 +983,7 @@ def _backward_blocks(lq: int, lk: int, head_dim: int, dtype
 
 def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
                           block_q, block_k, keys=None, window=None,
-                          bd=None):
+                          bd=None, eva=False):
     """The self-contained backward: q, dO [B,Lq,H*D], k, v [Bkv,Lk,Hkv*D]
     (``heads`` = H; the forward's layout and blocks), lse and delta f32
     rows [B,H,1,Lq] -> (dq [B,Lq,H*D], dk, dv [B,Lk,H*D]) in the operands'
@@ -992,7 +996,8 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
     that see one K/V block, and the call's name is
     ``hvdt.kernel.flash_win_bwd``.  Under block diffusion (``bd``; square
     tiles that tile a stream) it is the q tiles of both streams, and the
-    name ``hvdt.kernel.flash_bd_bwd``."""
+    name ``hvdt.kernel.flash_bd_bwd``; with ``eva`` (the forward's) it is
+    ``hvdt.kernel.eva_win_bwd``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1040,7 +1045,8 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
     windowed = {} if window is None else dict(window=window)
     if bd is not None:
         windowed = dict(bd=bd, stream=stream)
-    with jax.named_scope("hvdt.kernel.flash_bd_bwd" if bd is not None
+    with jax.named_scope("hvdt.kernel.eva_win_bwd" if eva
+                         else "hvdt.kernel.flash_bd_bwd" if bd is not None
                          else "hvdt.kernel.flash_bwd" if window is None
                          else "hvdt.kernel.flash_win_bwd"):
         return pl.pallas_call(
@@ -1139,7 +1145,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k, window=None,
-                    bd=None):
+                    bd=None, eva=False):
     """Kernel forward returning (out [B,L,H,D], lse [B,H,1,Lq]): the
     logsumexp as the row the kernel writes and the backward reads."""
     b, lq, h, d = q.shape
@@ -1147,7 +1153,7 @@ def _flash_fwd_rows(q, k, v, causal, scale, block_q, block_k, window=None,
     out, lse = _flash_local_call(
         *(_rows_layout(x, fold) for x in (q, k, v)),
         heads=1 if fold else h, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, window=window, bd=bd)
+        block_q=block_q, block_k=block_k, window=window, bd=bd, eva=eva)
     return _heads_layout(out, q.shape, fold), lse.reshape(b, h, 1, lq)
 
 
@@ -1171,14 +1177,17 @@ def _flash_attn_fwd(q, k, v, causal, scale, block_q, block_k, window, bd):
     return out, (q, k, v, out, lse)
 
 
-def _flash_attn_bwd(causal, scale, block_q, block_k, window, bd, res, do):
+def _flash_attn_bwd(causal, scale, block_q, block_k, window, bd, res, do,
+                    dlse=None, eva=False):
     """The local backward: one Pallas call (``_flash_local_bwd_call``) on
     the forward's operand layout, delta = rowsum(dO * out) computed beside
     it as a row.  A test's own forward blocks bound the backward's too, so
     a given tiling is met on both sides.  Only a sequence whose f32 dq
     does not fit in VMEM (``_backward_blocks`` is None) takes the
     blockwise XLA backward (under block diffusion ``flash_attention`` has
-    refused it)."""
+    refused it).  ``dlse`` [B,H,1,Lq] is the logsumexp's cotangent where
+    that is an output too (``flash_attention_stats``): d lse / d s = p, so
+    the score's cotangent p (dp - delta) takes delta - dlse for delta."""
     q, k, v, out, lse = res
     b, lq, h, d = q.shape
     lk, hkv = k.shape[1], k.shape[2]
@@ -1189,6 +1198,8 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, window, bd, res, do):
                                     window)
     delta = jnp.einsum("bqhd,bqhd->bhq", do, out,
                        preferred_element_type=jnp.float32)[:, :, None, :]
+    if dlse is not None:
+        delta = delta - dlse
     fold = _heads_per_program(h, hkv, d) is None
     heads = 1 if fold else h
     # Under block diffusion the (square) tiles have to tile a stream.
@@ -1202,7 +1213,7 @@ def _flash_attn_bwd(causal, scale, block_q, block_k, window, bd, res, do):
         block_q=_fit_block(lq // streams, min(block_q, blocks[0]), q.dtype),
         block_k=_fit_block(lk // streams, min(block_k, blocks[1]), k.dtype,
                            v.dtype),
-        window=window, bd=bd)
+        window=window, bd=bd, eva=eva)
     dq, dk, dv = (_heads_layout(x, (b, x.shape[1], h, d), fold)
                   for x in (dq, dk, dv))
     if h != hkv:
@@ -1324,6 +1335,44 @@ def _flash_bwd_blockwise(causal, scale, block_q, block_k, res, do,
 
 
 _flash_attn_diff.defvjp(_flash_attn_fwd, _flash_attn_bwd)
+
+
+def flash_attention_stats(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                          scale: Optional[float] = None):
+    """Causal flash attention that also returns its logsumexp, both
+    differentiable: ``(out [B,L,H,D], lse [B,H,L] f32)``.  What a caller
+    needs to join this softmax with another over further keys (EVA's
+    summaries, ``ops/eva.py``: the rows are an EVA layer's aligned windows,
+    one a batch row).  The two local calls of :func:`flash_attention`, with
+    their blocks, under ``hvdt.kernel.eva_win_fwd`` / ``eva_win_bwd``."""
+    d = q.shape[-1]
+    block_q, block_k = _forward_blocks(q.shape[1], k.shape[1], d, q.dtype)
+    if _backward_blocks(q.shape[1], k.shape[1], d, q.dtype) is None:
+        raise ValueError(f"no backward blocks for {q.shape[1]} rows")
+    out, lse = _flash_attn_stats(
+        q, k, v, float(d ** -0.5 if scale is None else scale), block_q,
+        block_k)
+    return out, lse[:, :, 0, :]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attn_stats(q, k, v, scale, block_q, block_k):
+    return _flash_fwd_rows(q, k, v, True, scale, block_q, block_k, eva=True)
+
+
+def _flash_attn_stats_fwd(q, k, v, scale, block_q, block_k):
+    out, lse = _flash_fwd_rows(q, k, v, True, scale, block_q, block_k,
+                               eva=True)
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _flash_attn_stats_bwd(scale, block_q, block_k, res, cotangents):
+    do, dlse = cotangents
+    return _flash_attn_bwd(True, scale, block_q, block_k, None, None, res,
+                           do, dlse, eva=True)
+
+
+_flash_attn_stats.defvjp(_flash_attn_stats_fwd, _flash_attn_stats_bwd)
 
 
 def flash_block_update(q: jax.Array, k_blk: jax.Array, v_blk: jax.Array,
@@ -2064,6 +2113,347 @@ def moe_sum_rows(rows, inverse, held, segment, *, segments: int):
                 bytes_accessed=(rows.size + t * d) * rows.dtype.itemsize),
             interpret=_use_interpret(),
         )(fetch, counts, place, rows)
+
+
+# ---------------------------------------------------------------------------
+# EVA's summaries (``ops/eva.py``): every row of window w attends over the
+# ``per`` x w chunk summaries of the windows before its own.  The mask is by
+# whole windows, "the summary's window is before the row's", so a q tile is
+# one window and sees a prefix of the summaries: tiles of ``_EVA_FWD_TILE``
+# / ``_EVA_BWD_TILE`` summaries, the last of a prefix masked by its columns.  Three calls on the
+# projections' [B, L, H*D] rows and the summaries' [B, N, H*D], a head a
+# program (head_dim whole 128-lane tiles): the forward (out and logsumexp,
+# which joins this softmax to the window's), dq, and dk~ / dv~.  The
+# backward is two calls of three and four products, not one of five: the
+# second score product is a quarter of a small part (the summaries are
+# under half of EVA's pairs at 32,768 rows), and neither call needs the
+# sequence's dq in VMEM.
+# ---------------------------------------------------------------------------
+
+# Summaries a step, the prefix's granule.  On the v5e at [1, 32768, 8, 128]
+# bf16, windows of 2,048 and 128 summaries a window, ms a call by the host
+# clock over 20 calls (PERF.md, PR 41), tiles of 256 / 512 / 1024 / 2048:
+# forward 3.81 / 2.74 / 2.03 / 2.39, forward + dq + dk~dv~ 8.51 / 7.15 / 6.82 /
+# 8.24: a larger tile rescales ``acc`` less often and computes more masked
+# pairs; the backward's two calls (the difference) are best at 512.
+_EVA_FWD_TILE = 1024
+_EVA_BWD_TILE = 512
+
+
+def eva_summary_tiles(rows: int, window: int, per: int, head_dim: int,
+                      dtype) -> bool:
+    """Whether :func:`eva_summary_attention` has blocks for ``rows`` rows
+    in windows of ``window`` with ``per`` summaries a window: a head whole
+    lane tiles, a window a block of whole lane tiles (its logsumexp leaves
+    as a row), the summaries whole tiles.  Interpret mode has no floor."""
+    summaries = rows // window * per
+    tiles = [min(t, summaries) for t in (_EVA_FWD_TILE, _EVA_BWD_TILE)]
+    if rows % window or any(summaries % t for t in tiles):
+        return False
+    return _use_interpret() or (
+        head_dim % 128 == 0 and window % 128 == 0 and window <= 4096
+        and all(t % _sublane_tile(dtype) == 0 for t in tiles))
+
+
+def _eva_prefix(step, tile: int, seen):
+    """(active, whole) of summaries ``step * tile ..`` for rows that see
+    the first ``seen``: whether any is seen, and whether all are."""
+    return step * tile < seen, (step + 1) * tile <= seen
+
+
+def _eva_columns_seen(shape, *, axis: int, first, seen):
+    """True where the summary, ``first`` + the index along ``axis``, is
+    one of the first ``seen``."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis) + first < seen
+
+
+def _eva_summary_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_s, m_s, l_s,
+                        *, scale: float, per: int, rows: int):
+    """Forward, grid (b, head, window, summary tile), the tile innermost:
+    online softmax over the window's prefix of the summaries.  A row of
+    the first window sees none: 0 and a logsumexp of -1e30."""
+    import jax.experimental.pallas as pl
+
+    iq, it = pl.program_id(2), pl.program_id(3)
+    tile = k_ref.shape[1]
+    seen = iq * per
+
+    @pl.when(it == 0)
+    def _init():
+        acc_s[...] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+
+    def _tile(whole: bool):
+        for r in range(0, q_ref.shape[1], rows):
+            chunk = pl.ds(r, rows)
+            s = jax.lax.dot_general(
+                q_ref[0, chunk, :], k_ref[0, :, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            # Tile 0 comes first and every row sees its first summaries.
+            _online_softmax_update(
+                s, v_ref[0, :, :], acc_s.at[chunk], m_s.at[chunk],
+                l_s.at[chunk], None if whole else _eva_columns_seen(
+                    s.shape, axis=1, first=it * tile, seen=seen))
+
+    active, whole = _eva_prefix(it, tile, seen)
+    pl.when(whole)(lambda: _tile(True))
+    pl.when(jnp.logical_and(active, jnp.logical_not(whole)))(
+        lambda: _tile(False))
+
+    @pl.when(it == pl.num_programs(3) - 1)
+    def _flush():
+        l = jnp.maximum(l_s[...], 1e-30)
+        o_ref[0, :, :] = (acc_s[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0, :, :] = _column_to_row(m_s[...] + jnp.log(l))
+
+
+def _eva_summary_cotangents(k, v, q, do, lse, delta, scale: float, seen):
+    """(p^T, ds^T) [summaries, rows] of one tile of summaries ``k``, ``v``
+    against a chunk of rows: the score tile transposed as the local
+    backward's, so that the two row statistics ``lse`` and ``delta`` [1,
+    rows] enter as lane-dense rows.  ``seen`` masks the summaries (axis 0)
+    a prefix ends before; None where the tile is whole."""
+    nt = (((1,), (1,)), ((), ()))
+    st = jax.lax.dot_general(k, q, nt,
+                             preferred_element_type=jnp.float32) * scale
+    if seen is not None:
+        st = jnp.where(seen(st.shape), st, _NEG_INF)
+    pt = jnp.exp(st - lse)
+    dpt = jax.lax.dot_general(v, do, nt, preferred_element_type=jnp.float32)
+    return pt, (pt * (dpt - delta)).astype(q.dtype)
+
+
+def _eva_summary_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+                           dq_ref, dq_s, *, scale: float, per: int,
+                           rows: int):
+    """dq, on the forward's grid: the score tile transposed [summaries,
+    rows] as the local backward's, so the two row statistics enter as
+    lane-dense rows."""
+    import jax.experimental.pallas as pl
+
+    iq, it = pl.program_id(2), pl.program_id(3)
+    tile = k_ref.shape[1]
+    seen = iq * per
+
+    @pl.when(it == 0)
+    def _init():
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+    def _tile(whole: bool):
+        k = k_ref[0, :, :]
+        for r in range(0, q_ref.shape[1], rows):
+            chunk = pl.ds(r, rows)
+            _, dst = _eva_summary_cotangents(
+                k, v_ref[0, :, :], q_ref[0, chunk, :], do_ref[0, chunk, :],
+                lse_ref[0, 0, :, r:r + rows], dl_ref[0, 0, :, r:r + rows],
+                scale, None if whole else functools.partial(
+                    _eva_columns_seen, axis=0, first=it * tile, seen=seen))
+            dq_s[chunk, :] += jax.lax.dot_general(
+                dst, k, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    active, whole = _eva_prefix(it, tile, seen)
+    pl.when(whole)(lambda: _tile(True))
+    pl.when(jnp.logical_and(active, jnp.logical_not(whole)))(
+        lambda: _tile(False))
+
+    @pl.when(it == pl.num_programs(3) - 1)
+    def _flush():
+        dq_ref[0, :, :] = (dq_s[...] * scale).astype(dq_ref.dtype)
+
+
+def _eva_summary_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
+                            dk_ref, dv_ref, dk_s, dv_s, *, scale: float,
+                            per: int, rows: int, windows: int):
+    """dk~ and dv~, grid (b, head, summary tile, window), the window
+    innermost: the tile stays while the windows that see any of it stream
+    past, from the one after its first summary's on."""
+    import jax.experimental.pallas as pl
+
+    it, step = pl.program_id(2), pl.program_id(3)
+    tile = k_ref.shape[1]
+    iq = (it * tile) // per + 1 + step
+    nn = (((1,), (0,)), ((), ()))
+
+    @pl.when(step == 0)
+    def _init():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    def _tile(whole: bool):
+        for r in range(0, q_ref.shape[1], rows):
+            chunk = pl.ds(r, rows)
+            q, do = q_ref[0, chunk, :], do_ref[0, chunk, :]
+            pt, dst = _eva_summary_cotangents(
+                k_ref[0, :, :], v_ref[0, :, :], q, do,
+                lse_ref[0, 0, :, r:r + rows], dl_ref[0, 0, :, r:r + rows],
+                scale, None if whole else functools.partial(
+                    _eva_columns_seen, axis=0, first=it * tile,
+                    seen=iq * per))
+            dv_s[...] += jax.lax.dot_general(
+                pt.astype(do.dtype), do, nn,
+                preferred_element_type=jnp.float32)
+            dk_s[...] += jax.lax.dot_general(
+                dst, q, nn, preferred_element_type=jnp.float32)
+
+    whole = (it + 1) * tile <= iq * per
+    inside = iq < windows
+    pl.when(jnp.logical_and(inside, whole))(lambda: _tile(True))
+    pl.when(jnp.logical_and(inside, jnp.logical_not(whole)))(
+        lambda: _tile(False))
+
+    @pl.when(step == pl.num_programs(3) - 1)
+    def _flush():
+        dk_ref[0, :, :] = (dk_s[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, :, :] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _eva_summary_plan(q, ks, window: int, per: int, heads: int, most: int):
+    """What the calls on the forward's grid (b, head, window, summary tile)
+    share: (the grid, the block specs of a window's rows, of a summary tile
+    and of a row statistic, the summary tile, the kernels' settings)."""
+    import jax.experimental.pallas as pl
+
+    d = q.shape[2] // heads
+    windows = q.shape[1] // window
+    tile = min(most, ks.shape[1])
+    steps = max(-(-(windows - 1) * per // tile), 1)
+
+    def prefix_tile(bb, hh, qq, tt):
+        # Past the window's prefix: the tile that is already resident.
+        return (bb, jnp.minimum(tt, jnp.maximum(qq * per - 1, 0) // tile),
+                hh)
+
+    rows = pl.BlockSpec((1, window, d), lambda bb, hh, qq, tt: (bb, qq, hh))
+    stat = pl.BlockSpec((1, 1, 1, window),
+                        lambda bb, hh, qq, tt: (bb, hh, 0, qq))
+    return ((q.shape[0], heads, windows, steps), rows,
+            pl.BlockSpec((1, tile, d), prefix_tile), stat, tile,
+            dict(scale=d ** -0.5, per=per, rows=_chunk_rows(window)))
+
+
+def _eva_summary_fwd_call(q, ks, vs, *, heads: int, window: int, per: int):
+    """q [B, L, H*D], ks / vs [B, N, H*D] -> (out [B, L, H*D] in q's dtype,
+    lse [B, H, 1, L] f32)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    grid, rows, summaries, stat, _, settings = _eva_summary_plan(
+        q, ks, window, per, heads, _EVA_FWD_TILE)
+    d = q.shape[2] // heads
+    kw = _vma_kw(q, ks, vs)
+    with jax.named_scope("hvdt.kernel.eva_sum_fwd"):
+        return pl.pallas_call(
+            functools.partial(_eva_summary_kernel, **settings),
+            grid=grid, in_specs=[rows, summaries, summaries],
+            out_specs=[rows, stat],
+            out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype, **kw),
+                       jax.ShapeDtypeStruct(
+                           (q.shape[0], heads, 1, q.shape[1]), jnp.float32,
+                           **kw)),
+            scratch_shapes=[pltpu.VMEM((window, d), jnp.float32),
+                            pltpu.VMEM((window, 1), jnp.float32),
+                            pltpu.VMEM((window, 1), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_FWD_VMEM_LIMIT),
+            interpret=_use_interpret(),
+        )(q, ks, vs)
+
+
+def _eva_summary_bwd_calls(q, ks, vs, do, lse, delta, *, heads: int,
+                           window: int, per: int):
+    """-> (dq [B, L, H*D], dk~, dv~ [B, N, H*D]) in the operands' dtypes;
+    lse and delta f32 rows [B, H, 1, L]."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    grid, rows, summaries, stat, tile, settings = _eva_summary_plan(
+        q, ks, window, per, heads, _EVA_BWD_TILE)
+    b, _, windows, _ = grid
+    d = q.shape[2] // heads
+    kw = _vma_kw(q, ks, vs, do, lse, delta)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_FWD_VMEM_LIMIT)
+    with jax.named_scope("hvdt.kernel.eva_sum_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_eva_summary_dq_kernel, **settings),
+            grid=grid,
+            in_specs=[rows, summaries, summaries, rows, stat, stat],
+            out_specs=rows,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype, **kw),
+            scratch_shapes=[pltpu.VMEM((window, d), jnp.float32)],
+            compiler_params=params, interpret=_use_interpret(),
+        )(q, ks, vs, do, lse, delta)
+
+    def window_seen(tt, ss):
+        # Past the last window: the one that is already resident.
+        return jnp.minimum((tt * tile) // per + 1 + ss, windows - 1)
+
+    rows = pl.BlockSpec(
+        (1, window, d), lambda bb, hh, tt, ss: (bb, window_seen(tt, ss), hh))
+    stat = pl.BlockSpec(
+        (1, 1, 1, window),
+        lambda bb, hh, tt, ss: (bb, hh, 0, window_seen(tt, ss)))
+    summaries = pl.BlockSpec((1, tile, d),
+                             lambda bb, hh, tt, ss: (bb, tt, hh))
+    with jax.named_scope("hvdt.kernel.eva_sum_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_eva_summary_dkv_kernel, windows=windows,
+                              **settings),
+            grid=(b, heads, ks.shape[1] // tile, max(windows - 1, 1)),
+            in_specs=[rows, summaries, summaries, rows, stat, stat],
+            out_specs=[summaries, summaries],
+            out_shape=(jax.ShapeDtypeStruct(ks.shape, ks.dtype, **kw),
+                       jax.ShapeDtypeStruct(vs.shape, vs.dtype, **kw)),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
+                            pltpu.VMEM((tile, d), jnp.float32)],
+            compiler_params=params, interpret=_use_interpret(),
+        )(q, ks, vs, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _eva_summary_diff(q, ks, vs, window, per):
+    b, l, h, d = q.shape
+    out, lse = _eva_summary_fwd_call(
+        *(x.reshape(x.shape[0], x.shape[1], h * d) for x in (q, ks, vs)),
+        heads=h, window=window, per=per)
+    return out.reshape(q.shape), lse
+
+
+def _eva_summary_diff_fwd(q, ks, vs, window, per):
+    out, lse = _eva_summary_diff(q, ks, vs, window, per)
+    return (out, lse), (q, ks, vs, out, lse)
+
+
+def _eva_summary_diff_bwd(window, per, res, cotangents):
+    q, ks, vs, out, lse = res
+    do, dlse = cotangents
+    b, l, h, d = q.shape
+    # d lse / d s = p: the score's cotangent p (dp - delta) takes delta -
+    # dlse for delta = rowsum(dO * out).
+    delta = jnp.einsum("bqhd,bqhd->bhq", do, out,
+                       preferred_element_type=jnp.float32)[:, :, None, :] \
+        - dlse
+    dq, dk, dv = _eva_summary_bwd_calls(
+        *(x.reshape(x.shape[0], x.shape[1], h * d) for x in (q, ks, vs, do)),
+        lse, delta, heads=h, window=window, per=per)
+    return dq.reshape(q.shape), dk.reshape(ks.shape), dv.reshape(vs.shape)
+
+
+_eva_summary_diff.defvjp(_eva_summary_diff_fwd, _eva_summary_diff_bwd)
+
+
+def eva_summary_attention(q: jax.Array, ks: jax.Array, vs: jax.Array, *,
+                          window: int, per: int):
+    """Softmax attention of every row of q [B, L, H, D] over the summaries
+    ks / vs [B, N, H, D] of the windows before its own (``per`` a window of
+    ``window`` rows), and its logsumexp, both differentiable: ``(out [B, L,
+    H, D] in q's dtype, lse [B, H, L] f32)``; the first window's rows see
+    nothing and get 0 and -1e30.  For shapes :func:`eva_summary_tiles`
+    takes."""
+    out, lse = _eva_summary_diff(q, ks, vs, window, per)
+    return out, lse[:, :, 0, :]
 
 
 def attention_reference(q, k, v, *, causal=True, scale=None,

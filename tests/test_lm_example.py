@@ -65,6 +65,10 @@ TOY_PUBLISHED = {
         moe_intermediate_size=16, shared_expert_intermediate_size=16,
         num_experts=16, num_experts_per_tok=3, vocab_size=512, experts=4,
         experts_first=4, vocab=256),
+    "evabyte": lambda published: dict(
+        hidden_size=64, num_attention_heads=8, num_key_value_heads=8,
+        intermediate_size=96, window_size=16, chunk_size=4,
+        num_pred_heads=3, vocab_size=64, layers=2, heads=4, heads_first=4),
 }
 
 
@@ -74,8 +78,9 @@ def test_a_published_layer_pattern_model_by_the_same_path(tmp_path, name):
     """--published (the route the laguna-xs2 and qwen3-next presets take):
     the benchmark's configuration file at toy widths (Laguna-XS.2: five
     layers of three kinds; Qwen3-Next: three Gated DeltaNet layers and a
-    gated full-attention one; 4 of 16 experts held), through the example's
-    single-device step."""
+    gated full-attention one; 4 of 16 experts held; EvaByte: EVA attention
+    over four windows with 4 of 8 heads held, three prediction heads),
+    through the example's single-device step."""
     import json
 
     with open(os.path.join(REPO, "benchmark", "configs",
@@ -102,7 +107,8 @@ def test_the_presets_of_published_models_name_the_benchmarks_files():
         sys.path.pop(0)
     for preset, name, seq, batch in (("laguna-xs2", "laguna_xs2", 8192, 2),
                                      ("qwen3-next", "qwen3_next_80b", 16384,
-                                      1)):
+                                      1),
+                                     ("evabyte", "evabyte", 32768, 1)):
         got = example.PRESETS[preset]
         assert os.path.samefile(got["published"], os.path.join(
             REPO, "benchmark", "configs", name + ".json"))

@@ -6,6 +6,7 @@ XProf trace (docs/observability.md) read these names; a refactor that
 drops one fails here, on the CPU.  Names are metadata: nothing here runs
 a kernel for its result."""
 
+import functools
 import re
 
 import jax
@@ -201,6 +202,24 @@ def _quant(kernel):
         use_kernels=True)
 
 
+def _eva(kernel):
+    """EVA's five: the causal calls on the aligned windows with the
+    logsumexp as an output, and the summaries' three."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    if kernel.startswith("eva_win"):
+        fn = pk.flash_attention_stats
+        args = (q, q, q)
+    else:
+        fn = functools.partial(pk.eva_summary_attention, window=64, per=4)
+        args = (q, q[:, :8], q[:, :8])
+    if kernel.endswith("fwd"):
+        return lambda: fn(*args)
+    return lambda: jax.grad(lambda *a: fn(*a)[0].sum(), argnums=(0, 1, 2))(
+        *args)
+
+
 def _gdn(kernel):
     from horovod_tpu.ops import pallas_kernels as pk
 
@@ -229,6 +248,8 @@ KERNEL_SITES = (
     [(_flash, k) for k in ("flash_fwd", "flash_win_fwd", "flash_bd_fwd",
                            "flash_fwd.ring", "flash_bwd", "flash_win_bwd",
                            "flash_bd_bwd", "flash_dq", "flash_dkv")]
+    + [(_eva, k) for k in ("eva_win_fwd", "eva_win_bwd", "eva_sum_fwd",
+                           "eva_sum_dq", "eva_sum_dkv")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_gdn, "gdn_inverse")]
     + [(_rope, "rope")]
@@ -252,7 +273,8 @@ def test_no_pallas_call_site_is_left_without_a_name():
     """A new ``pl.pallas_call`` arrives with a ``hvdt.kernel.`` scope in
     the ``with`` statement above it (a site that lowers under one of
     several names, as the local flash calls do under a window, under the
-    block mask and under neither, names them all there), and with a case in
+    block mask, on EVA's windows and under none, names them all there), and
+    with a case in
     KERNEL_SITES for each name."""
     import inspect
 
@@ -268,7 +290,7 @@ def test_no_pallas_call_site_is_left_without_a_name():
                 while not lines[start].lstrip().startswith("with "):
                     start -= 1
                 statement = " ".join(lines[start:i])
-                assert "named_scope(" in statement and i - start <= 3
+                assert "named_scope(" in statement and i - start <= 4
                 names = re.findall(r'"hvdt\.kernel\.(\w+)"', statement)
                 assert names, f"{mod.__name__}:{i + 1} has no kernel scope"
                 named.extend(names)

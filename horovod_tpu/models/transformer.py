@@ -38,7 +38,8 @@ from ..ops.attention import attention
 from ..ops.eva import eva_attention, eva_visible_pairs
 from ..ops.gated_delta import gated_delta_net, scan_macs_per_token
 from ..ops.pallas_kernels import rope
-from ..parallel.moe import moe_dispatch_combine, moe_held_experts
+from ..parallel.moe import (ROUTE_SAVED, moe_dispatch_combine,
+                            moe_held_experts)
 from ..parallel.pipeline import pipeline_spmd
 from ..parallel.ring_attention import ring_attention
 from ..quant import fp8 as _fp8
@@ -179,13 +180,20 @@ class TransformerConfig:
     pp: int = 1                  # pipeline stages (layers % pp == 0)
     remat: bool = False          # jax.checkpoint each block
     # Rematerialization policy when remat=True:
-    #   "full" — save only block inputs, recompute everything (min HBM,
+    #   "full" — save block inputs and recompute the rest (min HBM,
     #            +1/3 FLOPs — the classic trade);
     #   "dots" — jax.checkpoint_policies.dots_with_no_batch_dims_saveable:
     #            save non-batched matmul outputs (projections, FF), so
     #            the backward recomputes only cheap elementwise work and
     #            attention scores.  ~MXU-free recompute at the cost of
     #            O(layers * 6*b*l*d + b*l*4d) extra HBM residency.
+    # Under either, a sparse layer also keeps what its expert layer names
+    # ``hvdt_moe_route`` (parallel/moe.py ROUTE_SAVED: the picks, their
+    # scores, the sort by expert and the rows' weights; about 25 bytes a
+    # pick, 4 MB a layer at 163,840 picks beside a [T, D] input of 67 MB),
+    # so the recompute runs no top-k, no sort and no router product.  A
+    # layer without an expert layer names nothing and saves what the bare
+    # policy saves.
     # (An "attn" policy saving each block's attention output was measured
     # and REMOVED: saving attention's output cannot skip recomputing its
     # internals — the VJP still needs q/k/v/scores — so it bought 1.3%
@@ -951,6 +959,10 @@ def _layer_fn(positions, cfg: TransformerConfig,
     configuration's rematerialization policy."""
     body = functools.partial(_block, positions=positions, cfg=cfg, kind=kind)
     if cfg.remat:
+        # Either policy also keeps the expert layer's route
+        # (TransformerConfig.remat_policy); nothing more without one.
+        policies = jax.checkpoint_policies
+        keep = policies.save_only_these_names(ROUTE_SAVED)
         if cfg.remat_policy == "dots":
             pol = _dots_policy()
             if pol is None:
@@ -962,15 +974,13 @@ def _layer_fn(positions, cfg: TransformerConfig,
                 get_logger(__name__).warning(
                     "remat_policy='dots' unavailable on this jax "
                     "build; using 'full'")
-                body = jax.checkpoint(body)
             else:
-                body = jax.checkpoint(body, policy=pol)
-        elif cfg.remat_policy == "full":
-            body = jax.checkpoint(body)
-        else:
+                keep = policies.save_from_both_policies(pol, keep)
+        elif cfg.remat_policy != "full":
             raise ValueError(
                 f"unknown remat_policy {cfg.remat_policy!r} "
                 "(expected 'full' or 'dots')")
+        body = jax.checkpoint(body, policy=keep)
     return body
 
 

@@ -35,11 +35,12 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.device import _axis_size_static
 
 __all__ = ["moe_dispatch_combine", "moe_held_experts", "moe_route", "MoEAux",
-           "moe_capacity", "report_moe_aux"]
+           "moe_capacity", "report_moe_aux", "ROUTE_SAVED"]
 
 
 class MoEAux(NamedTuple):
@@ -166,6 +167,74 @@ def _tokens_of_rows_bwd(segments, res, g):
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 
 
+# What the route hands on, under one ``checkpoint_name``: a layer's
+# ``jax.checkpoint`` that saves this name (``models/transformer._layer_fn``)
+# keeps the picks, the picked scores, the sort and the rows' weights from
+# its forward, and its backward body holds no top-k, no sort and no router
+# product.  Seven [T * k] vectors and a count an expert, about 25 bytes a
+# pick (4 MB at 163,840 picks) against the layer's [T, D] input that a
+# checkpoint saves anyway; every one is a residual of the backward (the
+# moves' rules, the grouped products' transposes, ``mid``'s weight, the
+# router's own cotangent), so recomputing them bought nothing.  Outside a
+# checkpoint, and under one with another policy, the name is an identity.
+ROUTE_SAVED = "hvdt_moe_route"
+
+
+def _saved(x):
+    return checkpoint_name(x, ROUTE_SAVED)
+
+
+def _scores(z, score: str):
+    if score == "sigmoid":
+        return jax.nn.sigmoid(z)
+    if score == "softmax":
+        return jax.nn.softmax(z, axis=-1)
+    raise ValueError(f"unknown router score {score!r} "
+                     "(expected 'sigmoid' or 'softmax')")
+
+
+def _weights_of_picked(picked, normalize: bool, scale: float):
+    if normalize:
+        picked = picked / jnp.maximum(picked.sum(-1, keepdims=True), 1e-20)
+    return picked * scale
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _picks_of_logits(z, score, top_k, normalize, scale):
+    """Logits [T, E] -> (picked experts [T, k], their weights [T, k]) where
+    the cotangent needs a token's k picked scores and no other: ``sigmoid``
+    scores, or ``softmax`` scores divided by their sum over the picks."""
+    return _picks_of_logits_fwd(z, score, top_k, normalize, scale)[0]
+
+
+def _picks_of_logits_fwd(z, score, top_k, normalize, scale):
+    picked, experts = map(_saved, lax.top_k(_scores(z, score), top_k))
+    lanes = jnp.arange(z.shape[-1], dtype=experts.dtype)
+    return ((experts, _weights_of_picked(picked, normalize, scale)),
+            (experts, picked, lanes))
+
+
+def _picks_of_logits_bwd(score, top_k, normalize, scale, res, g):
+    experts, picked, lanes = res
+    _, weights_vjp = jax.vjp(
+        lambda p: _weights_of_picked(p, normalize, scale), picked)
+    d_picked, = weights_vjp(g[1])
+    if score == "sigmoid":
+        dz = d_picked * picked * (1.0 - picked)
+    else:
+        # softmax: dz_e = s_e (ds_e - sum_j ds_j s_j).  The weights are
+        # homogeneous of degree 0 in the picked scores, so the sum is 0
+        # (the 1e-20 floor never binds: the largest of E softmax scores is
+        # at least 1 / E) and dz is 0 off the picks.
+        dz = d_picked * picked
+    # [T, k] -> [T, E] by comparison, one fused pass: no scatter.
+    return (jnp.sum(jnp.where(experts[..., None] == lanes, dz[..., None],
+                              0.0), axis=1),)
+
+
+_picks_of_logits.defvjp(_picks_of_logits_fwd, _picks_of_logits_bwd)
+
+
 def moe_route(x: jax.Array, w_router: jax.Array, *, top_k: int,
               score: str = "sigmoid", normalize: bool = True,
               scale: float = 1.0):
@@ -175,20 +244,22 @@ def moe_route(x: jax.Array, w_router: jax.Array, *, top_k: int,
     otherwise).  ``x`` [T, D], ``w_router`` [D, E] -> (scores [T, E],
     picked experts [T, k], their weights [T, k]).  ``score`` is
     ``"sigmoid"`` or ``"softmax"``; ``normalize`` divides the picked scores
-    by their sum; ``scale`` multiplies the weights."""
+    by their sum; ``scale`` multiplies the weights.
+
+    The picks and the picked scores carry :data:`ROUTE_SAVED`.  The
+    weights' cotangent reaches the logits from the k picked scores of a
+    token alone (:func:`_picks_of_logits`), except for ``softmax`` scores
+    that are not normalised over the picks, whose rule reads the whole row:
+    there it is autodiff's, through the picked scores gathered by the named
+    picks (``lax.top_k``'s own rule gathers by an index nothing names)."""
     z = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST)
-    if score == "sigmoid":
-        scores = jax.nn.sigmoid(z)
-    elif score == "softmax":
-        scores = jax.nn.softmax(z, axis=-1)
-    else:
-        raise ValueError(f"unknown router score {score!r} "
-                         "(expected 'sigmoid' or 'softmax')")
-    picked, experts = lax.top_k(scores, top_k)
-    if normalize:
-        picked = picked / jnp.maximum(picked.sum(-1, keepdims=True), 1e-20)
-    return scores, experts, picked * scale
+    scores = _scores(z, score)
+    if score == "softmax" and not normalize:
+        experts = _saved(lax.top_k(lax.stop_gradient(scores), top_k)[1])
+        picked = _saved(jnp.take_along_axis(scores, experts, axis=-1))
+        return scores, experts, _weights_of_picked(picked, normalize, scale)
+    return (scores,) + _picks_of_logits(z, score, top_k, normalize, scale)
 
 
 def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
@@ -219,6 +290,13 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
     since ``ragged_dot`` visits only the tiles its ``group_sizes`` cover;
     the two moves around them cost what the buffer costs, whatever landed
     (``_rows_of_tokens``, ``_tokens_of_rows``).
+
+    Everything the route hands on (the picks, their scores, ``held``,
+    ``segment``, ``order``, ``inverse``, ``group_sizes``, the rows'
+    weights) carries :data:`ROUTE_SAVED`: each is a residual of this
+    layer's backward, and together they are a few [T * k] vectors, so a
+    checkpoint around the layer that saves the name runs the route once a
+    step and not again in its recompute.
     """
     t, d = x.shape
     e_held = w_up.shape[0]
@@ -229,16 +307,17 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
             x, w_router, top_k=k, score=score, normalize=normalize,
             scale=scale)
         local = experts.reshape(m) - experts_first            # pick t*k + i
-        held = jnp.logical_and(local >= 0, local < e_held)
+        held = _saved(jnp.logical_and(local >= 0, local < e_held))
         # Held picks first, by expert; the others behind them.
-        segment = jnp.where(held, local, e_held)
-        order = jnp.argsort(segment, stable=True)
-        inverse = jnp.argsort(order)
-        group_sizes = jnp.sum(
+        segment = _saved(jnp.where(held, local, e_held))
+        order = _saved(jnp.argsort(segment, stable=True))
+        inverse = _saved(jnp.argsort(order))
+        group_sizes = _saved(jnp.sum(
             local[:, None] == jnp.arange(e_held, dtype=local.dtype)[None],
-            axis=0, dtype=jnp.int32)
+            axis=0, dtype=jnp.int32))
         # A row's weight: its pick's, 0 behind the rows that landed.
-        weight_of_row = jnp.where(held, weights.reshape(m), 0.0)[order]
+        weight_of_row = _saved(
+            jnp.where(held, weights.reshape(m), 0.0)[order])
         held = held.reshape(t, k)
     with (jax.named_scope("hvdt.moe.dispatch"),
           jax.named_scope("hvdt.moe.dispatch.rows")):

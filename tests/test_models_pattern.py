@@ -6,6 +6,7 @@ the same model to rounding, on the XLA path and on the kernels'."""
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -16,6 +17,8 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+from benchmark.layer_metrics import moe_route_sorts  # noqa: E402
+from benchmark.phase_split import op_names  # noqa: E402
 from benchmark.reference import laguna as reference  # noqa: E402
 from horovod_tpu.models import (TransformerConfig, config_from_published,  # noqa: E402
                                 transformer_init, transformer_logical_axes,
@@ -103,6 +106,56 @@ def test_a_wrong_model_fails_the_comparison(model, wrong):
                            {k: grad_r[k] for k in grad_s})
     assert (abs(float(loss_s) - float(loss_r)) > 1e-4 * float(loss_r)
             or worst > 1e-2)
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+def test_a_sparse_layers_checkpoint_keeps_its_route(model, policy):
+    """Under either policy the layers' checkpoints save what the expert
+    layer names: the gradient program sorts and picks in the forward bodies
+    alone (a top-k and two sorts for each of the pattern's two kinds of
+    sparse layer), and its numbers are the ones the reference was held to."""
+    cfg, params, tokens, (loss_r, grad_r) = model
+    cfg = TransformerConfig(**{**cfg.__dict__, "remat_policy": policy})
+    step = jax.jit(jax.value_and_grad(
+        lambda p: transformer_loss(p, tokens, cfg)))
+    text = step.lower(params).compile().as_text()
+    names = op_names(text)
+    sorts = [names[name] for name in moe_route_sorts.sorts(text)]
+    assert len(sorts) == 6
+    assert not any("transpose(" in n or "rematted" in n for n in sorts)
+    loss_s, grad_s = step(params)
+    assert abs(float(loss_s) - float(loss_r)) < 2e-6 * float(loss_r)
+    worst, where = _worst_leaf(grad_s, grad_r)
+    assert worst < 5e-5, (worst, where)
+
+
+def test_a_dense_layers_checkpoint_saves_what_a_bare_one_does(monkeypatch):
+    """No expert layer, no name in the layer: the gradient program under
+    ``remat_policy="full"`` is a bare ``jax.checkpoint``'s, instruction for
+    instruction."""
+    cfg = TransformerConfig(vocab=64, layers=3, d_model=32, heads=2,
+                            kv_heads=2, d_ff=64, max_seq=SEQ,
+                            dtype=jnp.float32, remat=True)
+    params = transformer_init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ), 0, 64)
+
+    def text():
+        compiled = jax.jit(jax.grad(
+            lambda p: transformer_loss(p, tokens, cfg))).lower(
+                params).compile().as_text()
+        # the instructions without where they came from: no metadata, no
+        # tables of files and stack frames
+        lines = compiled.splitlines()
+        first = next(i for i, line in enumerate(lines) if line.endswith("{"))
+        return [re.sub(r", metadata=\{[^}]*\}", "", line)
+                for line in lines[first:]]
+
+    kept = text()
+    checkpoint = jax.checkpoint
+    monkeypatch.setattr(jax, "checkpoint",
+                        lambda fn, policy=None: checkpoint(fn))
+    assert len(kept) > 500 and "checkpoint" not in "".join(kept)
+    assert kept == text()
 
 
 def test_the_published_configuration_cut_to_its_share():

@@ -3,9 +3,12 @@
 token and masked by the picks; the shares of a layer add up to the uncut
 layer of ``benchmark/reference/laguna.py``; no pick is dropped under any
 imbalance; what ``TransformerConfig.num_experts`` has always meant on one
-device still holds; the two new gauges."""
+device still holds; the two new gauges; the route saved across a layer's
+recompute (PR 42)."""
 
+import functools
 import os
+import re
 import sys
 
 import jax
@@ -219,3 +222,195 @@ def test_an_unknown_score_function_is_refused():
     w = _weights()
     with pytest.raises(ValueError, match="sigmoid"):
         moe.moe_route(w["x"], w["w_router"], top_k=2, score="tanh")
+
+
+# ---------------------------------------------------------------------------
+# The route across a layer's recompute (PR 42): what a checkpoint that saves
+# ``moe.ROUTE_SAVED`` keeps, and the cotangent from the picked scores alone.
+# ---------------------------------------------------------------------------
+
+ROUTES = [(score, normalize) for score in ("sigmoid", "softmax")
+          for normalize in (True, False)]
+KEEP_ROUTE = jax.checkpoint_policies.save_only_these_names(moe.ROUTE_SAVED)
+WRAPS = {"none": lambda f: f, "bare": jax.checkpoint,
+         "keep": functools.partial(jax.checkpoint, policy=KEEP_ROUTE)}
+# Sizes that tell the router's product [TS, ES] from its cotangents
+# [TS, DS] and [DS, ES].
+LS, TS, DS, ES = 3, 64, 24, 16
+
+
+def _stack(seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    held = ES // 2
+    return dict(
+        x=jax.random.normal(ks[0], (TS, DS)),
+        w_router=jax.random.normal(ks[1], (LS, DS, ES)),
+        w_up=jax.random.normal(ks[2], (LS, held, DS, F)) * 0.3,
+        w_gate=jax.random.normal(ks[3], (LS, held, DS, F)) * 0.3,
+        w_down=jax.random.normal(ks[4], (LS, held, F, DS)) * 0.3)
+
+
+def _scanned(w, wrap, **route):
+    """Three sparse layers with residuals under ``lax.scan``, each under
+    ``wrap``, holding experts 4 .. 11 of 16."""
+    def layer(p, h):
+        y, _ = moe.moe_held_experts(
+            h, p["w_router"], p["w_up"], p["w_down"], p["w_gate"],
+            experts_first=4, **route)
+        return h + y
+
+    body = wrap(layer)
+    layers = {k: v for k, v in w.items() if k != "x"}
+    out, _ = jax.lax.scan(lambda h, p: (body(p, h), None), w["x"], layers)
+    return jnp.sum(out ** 2)
+
+
+def _route_of(score, normalize):
+    return dict(top_k=3, score=score, normalize=normalize, scale=2.5)
+
+
+def _plain_route(x, w_router, *, top_k, score, normalize, scale):
+    """The route as autodiff alone differentiates it."""
+    z = jnp.dot(x, w_router, precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(z) if score == "sigmoid" else \
+        jax.nn.softmax(z, -1)
+    picked, experts = jax.lax.top_k(scores, top_k)
+    if normalize:
+        picked = picked / picked.sum(-1, keepdims=True)
+    return scores, experts, picked * scale
+
+
+@pytest.mark.parametrize("score, normalize", ROUTES)
+@pytest.mark.parametrize("wrap", ["bare", "keep"])
+def test_the_checkpointed_layers_gradients_are_the_plain_layers(
+        score, normalize, wrap):
+    """Saving the route changes what the backward reads, not what it
+    computes: every leaf bit-equal in float32 to the layers without a
+    checkpoint (and a bare checkpoint's are too)."""
+    w = _stack()
+    route = _route_of(score, normalize)
+    want = jax.jit(jax.grad(functools.partial(
+        _scanned, wrap=WRAPS["none"], **route)))(w)
+    got = jax.jit(jax.grad(functools.partial(
+        _scanned, wrap=WRAPS[wrap], **route)))(w)
+    for name in want:
+        assert float(jnp.abs(want[name]).max()) > 0, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+@pytest.mark.parametrize("score, normalize", ROUTES)
+def test_the_routes_cotangent_from_the_picked_scores_is_autodiffs(
+        score, normalize, monkeypatch):
+    """The rule that reads a token's k picked scores against the one that
+    reads the whole row: the router's gradient and the tokens' to 1e-6 of
+    the leaf's largest entry through the route alone; to 1e-5 through
+    three layers with residuals, which carry a last-bit difference in a
+    weight on through two more layers' products (the plain route stands in
+    for ``moe.moe_route`` there)."""
+    route = _route_of(score, normalize)
+    w = _stack(1)
+    c = jax.random.normal(jax.random.PRNGKey(7), (TS, 3))
+
+    def through(fn):
+        return jax.jit(jax.grad(lambda x, wr: jnp.sum(
+            fn(x, wr, **route)[2] * c), argnums=(0, 1)))(
+                w["x"], w["w_router"][0])
+
+    for got, want in zip(through(moe.moe_route), through(_plain_route)):
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=1e-6 * float(jnp.abs(want).max()))
+    got = jax.jit(jax.grad(functools.partial(
+        _scanned, wrap=WRAPS["keep"], **route)))(w)
+    monkeypatch.setattr(moe, "moe_route", _plain_route)
+    want = jax.jit(jax.grad(functools.partial(
+        _scanned, wrap=WRAPS["none"], **route)))(w)
+    for name in want:
+        np.testing.assert_allclose(
+            got[name], want[name], rtol=0, err_msg=name,
+            atol=1e-5 * float(jnp.abs(want[name]).max()))
+
+
+@pytest.mark.parametrize("score, normalize", ROUTES)
+def test_which_routes_take_the_rule_follows_their_own_arguments(
+        score, normalize):
+    """softmax scores that are not normalised over the picks need the
+    row's other scores: autodiff's path.  Every other route takes the
+    rule."""
+    w = _stack()
+    jaxpr = str(jax.make_jaxpr(lambda x, wr: moe.moe_route(
+        x, wr, **_route_of(score, normalize)))(w["x"], w["w_router"][0]))
+    general = score == "softmax" and not normalize
+    assert ("custom_vjp_call" in jaxpr) == (not general)
+    assert jaxpr.count(f"name={moe.ROUTE_SAVED}") == 2
+
+
+def _route_instructions(hlo_text):
+    """(op_name, the instruction's text, whether the benchmark's counter
+    ``moe_route_sorts`` counts it) of the compiled program's instructions
+    under ``hvdt.moe.route``."""
+    from benchmark.layer_metrics import moe_route_sorts
+
+    sorting = set(moe_route_sorts.sorts(hlo_text))
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and "hvdt.moe.route" in m.group(1):
+            name = line.split(" = ")[0].split("%")[-1]
+            found.append((m.group(1), line.strip(), name in sorting))
+    return found
+
+
+@pytest.mark.parametrize("score, normalize", ROUTES)
+def test_the_backward_body_holds_no_sort_no_top_k_and_no_router_product(
+        score, normalize):
+    """The compiled gradient of three checkpointed layers.  Under a bare
+    checkpoint the backward body runs the route again (two sorts, a top-k
+    and the router's product more); under one that keeps the route's name
+    the only sorts, the only top-k and the only product of the router's
+    shape [T, E] are the forward's, and what the backward holds under
+    ``hvdt.moe.route`` is the cotangent's two products (and, where the
+    score's rule reads the whole row, the product that remakes it)."""
+    w = _stack()
+    route = _route_of(score, normalize)
+    general = score == "softmax" and not normalize
+
+    def compiled(wrap):
+        return jax.jit(jax.grad(functools.partial(
+            _scanned, wrap=WRAPS[wrap], **route))).lower(w).compile(
+                ).as_text()
+
+    def counts(text):
+        route_ops = _route_instructions(text)
+        backward = [op for op in route_ops if "transpose(" in op[0]]
+        product = f"f32[{TS},{ES}]"
+        return (sum(sorts for _, _, sorts in route_ops),
+                sum(sorts for _, _, sorts in backward),
+                sum(" dot(" in t and t.split(" = ")[1].startswith(product)
+                    for _, t, _ in route_ops),
+                sum(" dot(" in t for _, t, _ in backward))
+
+    assert counts(compiled("bare")) == (6, 3, 2, 3)
+    # forward: a top-k, two sorts, the product; backward: dx and dw
+    assert counts(compiled("keep")) == (3, 0, 2 if general else 1,
+                                        3 if general else 2)
+
+
+def test_the_routes_cotangent_rule_stays_under_the_routes_scope():
+    """``moe_dispatch_ms`` and ``moe_route_ms`` read ``hvdt.moe.route``:
+    the rule's own instructions (the compare-and-sum that spreads the
+    picks' cotangent over the experts, the two products) carry it in the
+    backward as the forward's do."""
+    w = _stack()
+    text = jax.jit(jax.grad(functools.partial(
+        _scanned, wrap=WRAPS["keep"], **_route_of("sigmoid", True)))
+        ).lower(w).compile().as_text()
+    backward = [(n, t) for n, t, _ in _route_instructions(text)
+                if "transpose(" in n]
+    assert sum(" dot(" in t for _, t in backward) == 2
+    assert any("reduce" in t for _, t in backward)
+    assert all(re.search(r"(?:^|[/(])hvdt\.moe\.route(?:$|[/)])", n)
+               for n, _ in backward)
+    # what the recompute still holds of the route is index arithmetic
+    assert not any(sorts or " dot(" in t
+                   for n, t, sorts in _route_instructions(text)
+                   if "rematted_computation" in n)

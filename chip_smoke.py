@@ -774,6 +774,58 @@ def kernel_gdn_inverse(*, matrices=8192, chunk=64):
            jax.jit(gd._inverse_slabs_loop)(cols), rtol=1e-4, atol=1e-4)
 
 
+def kernel_gdn_chunk(*, seq=16384, key_heads=16, value_heads=32,
+                     head_dim=128):
+    """ops/pallas_kernels gdn_chunk_forward / gdn_chunk_backward (the
+    Mosaic schedule of the scan's chunk-local passes and of their rule)
+    against XLA's schedule of the same rule (ops/gated_delta
+    _chunk_fwd_jax / _chunk_bwd_jax) on the rows of one linear layer of
+    qwen3_next_s16384: the five outputs, the inverses and the three
+    cotangents, each within a hundredth of its largest entry (bf16
+    outputs of float32 sums in two orders)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import gated_delta as gd
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    chunk = gd.CHUNK
+    dims = (key_heads, value_heads, head_dim, head_dim)
+    if not pk.gdn_chunk_tiles(seq, dims, chunk):
+        raise AssertionError(f"rows {seq} x {dims} do not tile the kernels")
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    width = (2 * key_heads + value_heads) * head_dim
+    qkv = jax.nn.silu(jax.random.normal(ks[0], (1, seq, width))
+                      ).astype(jnp.bfloat16)
+    g = -0.1 * jnp.exp(jax.random.normal(ks[1], (1, seq, value_heads)))
+    gc = gd._log_decay(g, seq // chunk, chunk, dims, jnp.float32)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[2], gc.shape))
+
+    def close(name, got, want):
+        for i, (a, b) in enumerate(zip(jax.tree.leaves(got),
+                                       jax.tree.leaves(want))):
+            if a.shape != b.shape or a.dtype != b.dtype:
+                raise AssertionError(f"{name}[{i}]: {a.shape} {a.dtype} "
+                                     f"against {b.shape} {b.dtype}")
+            top = float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+            _close(f"{name}[{i}]", a, b, rtol=1e-2, atol=1e-2 * top)
+
+    out, t = jax.jit(lambda *a: gd._chunk_fwd_jax(*a, dims, chunk))(
+        qkv, gc, beta)
+    # the kernels leave a key head's R inverses side by side, [C, R C]
+    packed = jnp.moveaxis(t, 3, 4).reshape(t.shape[:3] + (chunk, -1))
+    close("gdn_chunk_fwd", jax.jit(
+        lambda *a: pk.gdn_chunk_forward(*a, dims, chunk))(qkv, gc, beta),
+        (out, packed))
+    cts = tuple((0.1 * jax.random.normal(k, x.shape)).astype(x.dtype)
+                for k, x in zip(ks[3:], out))
+    close("gdn_chunk_bwd", jax.jit(
+        lambda *a: pk.gdn_chunk_backward(*a, dims, chunk))(
+            qkv, gc, beta, packed, cts),
+        jax.jit(lambda *a: gd._chunk_bwd_jax(*a, dims, chunk))(
+            qkv, gc, beta, t, cts))
+
+
 def kernel_moe_sum_rows(*, tokens=16384, picks=10, width=2048, routed=512,
                         held=32):
     """ops/pallas_kernels moe_sum_rows against XLA's gather and sum
@@ -896,6 +948,7 @@ KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
            kernel_flash_window, kernel_flash_block_diffusion,
            kernel_flash_grad_block,
            kernel_conv_bn_relu, kernel_conv_bn_train, kernel_gdn_inverse,
+           kernel_gdn_chunk,
            kernel_rope, kernel_moe_sum_rows,
            kernel_fused_adam, kernel_fused_sgd, kernel_quant_int8,
            kernel_quant_int4)

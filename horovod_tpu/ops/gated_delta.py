@@ -32,6 +32,17 @@ state ``S`` carried from chunk to chunk (256 updates a sequence of
 16,384 would each round it).  The products take operands in the compute
 dtype and accumulate in float32.
 
+Three parts, one scope each under ``hvdt.gdn.scan``.  ``.chunk``: what
+is computed for all the chunks at once (the norms, the ratios, ``A``,
+``T``, ``T (beta V)``, ``T (beta gamma K)``, ``(gamma_C / gamma) K`` and
+the pairs' matrix of ``O``) is one function with a differentiation rule
+of its own, :func:`_chunk_passes`: its backward is written by hand from
+the inputs and ``T`` (:func:`_chunk_bwd_jax` states it), not derived pass
+by pass, and the same rule has two schedules, XLA's everywhere and Mosaic
+kernels on a TPU for the shapes they tile (:func:`_chunk_on_kernels`).
+``.state``: the ``lax.scan`` over the chunks that carries ``S``.
+``.out``: ``O`` from both.
+
 :func:`gated_delta_net` is the whole mixer as the model calls it (the
 input projections, a causal depthwise convolution, the rule, the gated
 norm a head, the output projection), each part under its own scope
@@ -168,6 +179,220 @@ def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
 
+def _log_decay(g, n: int, chunk: int, dims, carry_dtype):
+    """The log of gamma inside each chunk: the cumulative sum of the
+    log-decays g [B, N C, Hv], float32 (``carry_dtype`` for the
+    benchmark's control): [B, N, C, Hk, R]."""
+    hk, hv = dims[0], dims[1]
+    g = g.astype(jnp.float32).reshape(g.shape[0], n, chunk, hk, hv // hk)
+    return jnp.cumsum(g.astype(carry_dtype), axis=2).astype(jnp.float32)
+
+
+def _split_rows(qkv, n: int, chunk: int, dims):
+    """The rows [B, N C, 2 Kd + Vd] as q, k [B, N, C, Hk, dk] and
+    v [B, N, C, Hk, R, dv]."""
+    hk, hv, dk, dv = dims
+    b, kd = qkv.shape[0], hk * dk
+    return (qkv[..., :kd].reshape(b, n, chunk, hk, dk),
+            qkv[..., kd:2 * kd].reshape(b, n, chunk, hk, dk),
+            qkv[..., 2 * kd:].reshape(b, n, chunk, hk, hv // hk, dv))
+
+
+def _pairs(x, y):                       # x_i . y_j a key head: [B,N,Hk,C,C]
+    return jnp.einsum("bnihd,bnjhd->bnhij", x, y,
+                      preferred_element_type=jnp.float32)
+
+
+def _decay_ratios(gc):
+    """gamma_i / gamma_j for j <= i, 0 above the diagonal, as the exp of
+    a difference: gc [B,N,C,Hk,R] -> [B,N,Hk,R,C,C]."""
+    c = gc.shape[2]
+    gc_rows = jnp.moveaxis(gc, 2, -1)                   # [B,N,Hk,R,C]
+    diff = gc_rows[..., :, None] - gc_rows[..., None, :]
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    return jnp.exp(jnp.where(lower, diff, -jnp.inf))
+
+
+def _chunk_factors(qkv, gc, beta, dims, chunk):
+    """What both directions of the rule derive from its inputs: v, the
+    float32 rows of q and k and their unit rows, the normed q and k in the
+    compute dtype, ``K K^T`` and ``Q K^T`` a key head, gamma, the decay
+    ratios, beta a row, and the normed k a value head."""
+    dt = qkv.dtype
+    q, k, v = _split_rows(qkv, qkv.shape[1] // chunk, chunk, dims)
+    qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+    qy, ky = _l2norm(qf), _l2norm(kf)
+    qn, kn = (qy * dims[2] ** -0.5).astype(dt), ky.astype(dt)
+    return (v, qf, kf, qy, ky, qn, kn,
+            _pairs(kn, kn)[:, :, :, None],              # [B,N,Hk,1,C,C]
+            _pairs(qn, kn)[:, :, :, None],
+            jnp.exp(gc), _decay_ratios(gc),
+            jnp.moveaxis(beta, 2, -1)[..., None],       # [B,N,Hk,R,C,1]
+            kn[:, :, :, :, None])                       # [B,N,C,Hk,1,dk]
+
+
+def _chunk_fwd_jax(qkv, gc, beta, dims, chunk):
+    """XLA's schedule of the chunk-local passes: every product a batched
+    einsum over (batch, chunk, head), every pass between them XLA's to
+    fuse.  Returns the outputs of :func:`_chunk_passes` and ``T``
+    [B,N,Hk,R,C,C] in float32 for its backward."""
+    f32, dt = jnp.float32, qkv.dtype
+    (v, _, _, _, _, qn, _, kk, qk, gamma, ratio, beta_rows,
+     kv) = _chunk_factors(qkv, gc, beta, dims, chunk)
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    t = _unit_lower_inverse(jnp.where(strict, beta_rows * ratio * kk, 0.0))
+    attn = (ratio * qk).astype(dt)
+
+    def rows(m, x):                     # m [B,N,Hk,R,C,C] @ x [B,N,C,Hk,R,D]
+        return jnp.einsum("bnhrij,bnjhrd->bnhrid", m, x,
+                          preferred_element_type=f32)
+
+    tb = t.astype(dt)
+    u_own = rows(tb, (beta[..., None] * v).astype(dt))  # T (beta V)
+    w = rows(tb, ((beta * gamma)[..., None] * kv).astype(dt)).astype(dt)
+    # (gamma_C / gamma) K
+    k_out = (jnp.exp(gc[:, :, -1:] - gc)[..., None] * kv).astype(dt)
+    return (qn, w, u_own, jnp.moveaxis(k_out, 2, 4), attn), t
+
+
+def _l2norm_bwd(x, y, ct):
+    """The cotangent of ``x`` under ``y = x rsqrt(sum x^2 + eps)``, all
+    float32: r (ct - y (y . ct))."""
+    r = lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+    return r * (ct - y * jnp.sum(y * ct, -1, keepdims=True))
+
+
+def _chunk_bwd_jax(qkv, gc, beta, t, cts, dims, chunk):
+    """XLA's schedule of the backward rule: the cotangents of (qkv, gc,
+    beta) from those of the five outputs.  Residuals are the inputs, the
+    2 MB of ``gc`` and ``T``; the norms, the ratios, ``A``'s factors and
+    the casts are derived again here.  Products take operands in the
+    compute dtype and accumulate in float32; the inverse's cotangent is
+    ``-T^T ct T^T`` on the strict lower triangle, in float32 at
+    ``HIGHEST``; every sum over a chunk's tokens is float32."""
+    f32, dt = jnp.float32, qkv.dtype
+    ct_qn, ct_w, ct_u, ct_kout, ct_attn = cts
+    ct_kout = jnp.moveaxis(ct_kout, 4, 2).astype(f32)
+    (v, qf, kf, qy, ky, qn, kn, kk, qk, gamma, ratio, beta_rows,
+     kv) = _chunk_factors(qkv, gc, beta, dims, chunk)
+    bv = (beta[..., None] * v).astype(dt)
+    bg = beta * gamma
+    bgk = (bg[..., None] * kv).astype(dt)
+    tb = t.astype(dt)
+
+    def outer(x, y):                    # x_i . y_j a value head
+        return jnp.einsum("bnhrid,bnjhrd->bnhrij", x, y,
+                          preferred_element_type=f32)
+
+    def rows_t(m, x):                   # m^T x: [B,N,C,Hk,R,D]
+        return jnp.einsum("bnhrij,bnhrid->bnjhrd", m, x,
+                          preferred_element_type=f32)
+
+    ct_ub = ct_u.astype(dt)
+    d_t = outer(ct_ub, bv) + outer(ct_w, bgk)
+    d_bv, d_bgk = rows_t(tb, ct_ub), rows_t(tb, ct_w)
+    d_a, = _unit_lower_inverse_bwd(t, d_t)
+    # A = beta_i ratio_ij (k_i . k_j);  attn = ratio_ij (q_i . k_j)
+    d_a_ratio = d_a * ratio
+    ct_attn_ratio = ct_attn.astype(f32) * ratio
+    d_kk = (d_a_ratio * beta_rows).sum(3).astype(dt)    # [B,N,Hk,C,C]
+    d_qk = ct_attn_ratio.sum(3).astype(dt)
+    d_beta_rows = (d_a_ratio * kk).sum(-1)              # [B,N,Hk,R,C]
+    # ratio = exp(gc_i - gc_j): what reaches gc through it
+    m = d_a_ratio * beta_rows * kk + ct_attn_ratio * qk
+    d_gc_rows = m.sum(-1) - m.sum(-2)                   # [B,N,Hk,R,C]
+    # K_out = exp(gc_C - gc) K;  W's operand beta gamma K;  gamma itself
+    e = jnp.exp(gc[:, :, -1:] - gc)                     # [B,N,C,Hk,R]
+    d_e = (ct_kout * kv).sum(-1) * e
+    d_bg = (d_bgk * kv).sum(-1)
+    d_gc = jnp.moveaxis(d_gc_rows, -1, 2) + beta * d_bg * gamma - d_e
+    d_gc = d_gc.at[:, :, -1].add(d_e.sum(2))
+    d_beta = (jnp.moveaxis(d_beta_rows, -1, 2) + (d_bv * v).sum(-1)
+              + gamma * d_bg)
+    # the normed rows, then the rows themselves
+    d_kn = (jnp.einsum("bnhij,bnjhd->bnihd", d_kk, kn,
+                       preferred_element_type=f32)
+            + jnp.einsum("bnhij,bnihd->bnjhd", d_kk, kn,
+                         preferred_element_type=f32)
+            + jnp.einsum("bnhij,bnihd->bnjhd", d_qk, qn,
+                         preferred_element_type=f32)
+            + (d_bgk * bg[..., None]).sum(4)
+            + (ct_kout * e[..., None]).sum(4))
+    d_qn = ct_qn.astype(f32) + jnp.einsum(
+        "bnhij,bnjhd->bnihd", d_qk, kn, preferred_element_type=f32)
+    d_q = _l2norm_bwd(qf, qy, d_qn * dims[2] ** -0.5).astype(dt)
+    d_k = _l2norm_bwd(kf, ky, d_kn).astype(dt)
+    d_v = (d_bv * beta[..., None]).astype(dt)
+    flat = lambda x: x.reshape(qkv.shape[:2] + (-1,))   # noqa: E731
+    return (jnp.concatenate([flat(d_q), flat(d_k), flat(d_v)], -1), d_gc,
+            d_beta)
+
+
+def _chunk_on_kernels(qkv, dims, chunk: int) -> bool:
+    """Which schedule of the chunk-local passes and of their rule runs: on
+    a TPU, for rows the kernels tile (``pallas_kernels.gdn_chunk_tiles``:
+    chunks of 64, heads of whole lane tiles), three Mosaic calls around
+    PR 34's solve; elsewhere XLA's.  Read from the platform and the shapes,
+    as :func:`_inverse_on_kernel`; no knob."""
+    return _on_tpu() and pallas_kernels.gdn_chunk_tiles(qkv.shape[1], dims,
+                                                        chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _chunk_passes(qkv, gc, beta, dims, chunk):
+    """Everything :func:`gated_delta_rows` computes for all the chunks at
+    once, before the state's loop, as one function with a differentiation
+    rule of its own: from the rows ``qkv`` [B, N C, 2 Kd + Vd] (columns
+    [q | k | v], the compute dtype), the chunks' cumulative log-decays
+    ``gc`` and ``beta`` [B, N, C, Hk, R] (float32) to
+
+        qn    [B,N,C,Hk,dk]     q, L2-normed a head, / sqrt(dk)
+        w     [B,N,Hk,R,C,dk]   T (beta gamma K)
+        u_own [B,N,Hk,R,C,dv]   T (beta V), float32
+        k_out [B,N,Hk,R,C,dk]   (gamma_C / gamma) K
+        attn  [B,N,Hk,R,C,C]    tril((gamma_i / gamma_j) (q_i . k_j))
+
+    (``dims`` = (Hk, Hv, dk, dv); the compute dtype where none is named;
+    ``qn`` on q's own rows, the others head-major, a chunk's [C, d] or
+    [C, C] matrix of a head whole tiles).
+
+    The rule is written by hand (:func:`_chunk_bwd_jax` states it): its
+    residuals are the three inputs and ``T`` (float32, laid as the
+    schedule that made it leaves it), not the two dozen [C, C]
+    and [C, d] arrays between them that differentiating the passes one by
+    one would keep and transpose.  One rule, two schedules of it
+    (:func:`_chunk_on_kernels` chooses): XLA's (:func:`_chunk_fwd_jax`,
+    :func:`_chunk_bwd_jax`) and Mosaic's (``pallas_kernels.
+    gdn_chunk_forward`` / ``gdn_chunk_backward``: a program holds a block
+    of chunks of one key head in VMEM, reads q, k, v from the rows' column
+    blocks once and writes each output once).  On the v5e for one layer of
+    ``qwen3_next_s16384`` (device events; my chip runs, PR 43, PERF.md
+    section 6): Mosaic's schedule 4.7 ms forward and 7.3 backward, XLA's
+    13.1 and 28.6 (and 3.4% more of the step's memory)."""
+    return _chunk_passes_fwd(qkv, gc, beta, dims, chunk)[0]
+
+
+def _chunk_passes_fwd(qkv, gc, beta, dims, chunk):
+    if _chunk_on_kernels(qkv, dims, chunk):
+        out, t = pallas_kernels.gdn_chunk_forward(qkv, gc, beta, dims, chunk)
+    else:
+        out, t = _chunk_fwd_jax(qkv, gc, beta, dims, chunk)
+    return out, (qkv, gc, beta, t)
+
+
+def _chunk_passes_bwd(dims, chunk, res, cts):
+    qkv = res[0]
+    bwd = (pallas_kernels.gdn_chunk_backward
+           if _chunk_on_kernels(qkv, dims, chunk) else _chunk_bwd_jax)
+    # JAX names the rule's equations after the forward's call
+    # (transpose(jvp(.. hvdt.gdn.scan.chunk))): a scope opened here would
+    # nest the same name inside itself.
+    return bwd(*res, cts, dims, chunk)
+
+
+_chunk_passes.defvjp(_chunk_passes_fwd, _chunk_passes_bwd)
+
+
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                      g: jax.Array, beta: jax.Array, *,
                      chunk: int = CHUNK,
@@ -184,26 +409,32 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     sequence, the cumulative sum of the log-decays inside a chunk and the
     state from chunk to chunk: float32; the benchmark's control passes
     bfloat16 to show what its reference check tells apart."""
-    b, l, hk, dk = q.shape
-    hv, dv = v.shape[2], v.shape[3]
+    b, l = q.shape[:2]
+    dims = (q.shape[2], v.shape[2], q.shape[3], v.shape[3])
+    with jax.named_scope("hvdt.gdn.scan.chunk"):
+        qkv = jnp.concatenate([q.reshape(b, l, -1), k.reshape(b, l, -1),
+                               v.reshape(b, l, -1)], -1)
+    return gated_delta_rows(qkv, g, beta, dims, chunk=chunk,
+                            carry_dtype=carry_dtype)
+
+
+def gated_delta_rows(qkv: jax.Array, g: jax.Array, beta: jax.Array,
+                     dims, *, chunk: int = CHUNK,
+                     carry_dtype=jnp.float32) -> jax.Array:
+    """:func:`gated_delta_rule` on the projection's own rows: ``qkv``
+    [B, L, 2 Kd + Vd] with the columns [q | k | v], a head a column block,
+    ``dims`` = (Hk, Hv, dk, dv).  The mixer calls this, so that no copy of
+    q, k or v is made in front of the chunk-local passes."""
+    hk, hv, dk, dv = dims
+    b, l, _ = qkv.shape
     r = hv // hk
-    dt = v.dtype
+    dt = qkv.dtype
     f32 = jnp.float32
     pad = (-l) % chunk
     n = (l + pad) // chunk
 
-    def chunks(x, *tail):
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        return x.reshape((b, n, chunk) + tail)
-
-    def pairs(x, y):                    # x_i . y_j a key head: [B,N,Hk,C,C]
-        return jnp.einsum("bnihd,bnjhd->bnhij", x, y,
-                          preferred_element_type=f32)
-
-    def rows(m, x):                     # m [B,N,Hk,R,C,C] @ x [B,N,C,Hk,R,D]
-        return jnp.einsum("bnhrij,bnjhrd->bnhrid", m, x,
-                          preferred_element_type=f32)
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
     def step(s, xs):                    # s [B, Hk, R, dk, dv], carry_dtype
         w_n, u_n, k_n, g_n = xs
@@ -218,30 +449,13 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     # benchmark each: what is batched over the chunks, the sequential
     # loop over them, and O from both.
     with jax.named_scope("hvdt.gdn.scan.chunk"):
-        qn = chunks((_l2norm(q) * dk ** -0.5).astype(dt), hk, dk)
-        kn = chunks(_l2norm(k).astype(dt), hk, dk)
-        v = chunks(v, hk, r, dv)
-        beta = chunks(beta.astype(f32), hk, r)
-        # gamma inside the chunk, as its log: [B, N, C, Hk, R]
-        gc = jnp.cumsum(chunks(g.astype(f32), hk, r).astype(carry_dtype),
-                        axis=2).astype(f32)
+        gc = _log_decay(padded(g), n, chunk, dims, carry_dtype)
+        beta = padded(beta.astype(f32)).reshape(b, n, chunk, hk, r)
+        qn, w, u_own, k_out, attn = _chunk_passes(padded(qkv), gc, beta,
+                                                  dims, chunk)
+        # as the state's loop and O name them
+        k_out = jnp.moveaxis(k_out, 4, 2)
         gamma = jnp.exp(gc)
-        # gamma_i / gamma_j for j <= i, 0 above the diagonal:
-        # [B,N,Hk,R,C,C]
-        gc_rows = jnp.moveaxis(gc, 2, -1)               # [B,N,Hk,R,C]
-        diff = gc_rows[..., :, None] - gc_rows[..., None, :]
-        lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-        ratio = jnp.exp(jnp.where(lower, diff, -jnp.inf))
-        beta_rows = jnp.moveaxis(beta, 2, -1)[..., None]    # [B,N,Hk,R,C,1]
-        a = jnp.where(jnp.tril(lower, -1),
-                      beta_rows * ratio * pairs(kn, kn)[:, :, :, None], 0.0)
-        t = _unit_lower_inverse(a).astype(dt)
-        attn = (ratio * pairs(qn, kn)[:, :, :, None]).astype(dt)
-        kv = kn[:, :, :, :, None]                       # [B,N,C,Hk,1,dk]
-        u_own = rows(t, (beta[..., None] * v).astype(dt))   # T (beta V)
-        w = rows(t, ((beta * gamma)[..., None] * kv).astype(dt)).astype(dt)
-        # (gamma_C / gamma) K, and gamma_C
-        k_out = (jnp.exp(gc[:, :, -1:] - gc)[..., None] * kv).astype(dt)
         gamma_end = gamma[:, :, -1]                     # [B, N, Hk, R]
         first = lambda x: jnp.moveaxis(x, 1, 0)         # noqa: E731
         xs = (first(w), first(u_own), first(k_out), first(gamma_end))
@@ -308,10 +522,8 @@ def gated_delta_net(x: jax.Array, p: Dict[str, jax.Array], *,
             beta = jax.nn.sigmoid(ba[..., :value_heads])
             g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
                 ba[..., value_heads:] + p["dt_bias"].astype(f32))
-            q = qkv[..., :kd].reshape(b, l, key_heads, key_dim)
-            k = qkv[..., kd:2 * kd].reshape(b, l, key_heads, key_dim)
-            v = qkv[..., 2 * kd:].reshape(b, l, value_heads, value_dim)
-        o = gated_delta_rule(q, k, v, g, beta)
+        o = gated_delta_rows(qkv, g, beta, (key_heads, value_heads, key_dim,
+                                            value_dim))
     with jax.named_scope("hvdt.gdn.norm"):
         y = gated_rmsnorm(o, z.reshape(b, l, value_heads, value_dim),
                           p["gdn_norm"]).astype(x.dtype)

@@ -29,7 +29,7 @@ Two entry points, one tile body (``_online_softmax_update``):
   ``flash_grad_block`` for its backward: global offsets in, f32 partial
   sums out, for the same reason.
 
-Two more kernels are not flash attention.  ``rope(x, cos, sin, half)`` is
+Other kernels are not flash attention.  ``rope(x, cos, sin, half)`` is
 the rotary embedding on the same [B, L, H*D] rows, between the projections
 and the flash calls: elementwise, and a kernel only because XLA, asked to
 slice a head in halves narrower than a lane tile, lays the whole attention
@@ -37,7 +37,15 @@ block sequence-minor and copies it to and from the flash calls.
 ``unit_lower_inverse_slabs(cols)`` is the inverse of I + A for the Gated
 DeltaNet scan's chunks (``ops/gated_delta.py``), whose 64 sequential row
 steps XLA can only run as 64 passes over HBM and a program here runs on a
-block in VMEM.
+block in VMEM.  ``gdn_chunk_forward`` / ``gdn_chunk_backward`` are the
+Mosaic schedule of that scan's other chunk-local passes and of their
+hand-written differentiation rule (``ops/gated_delta._chunk_passes``): a
+``before`` call (the norms, ``K K^T``, ``Q K^T``, the decay ratios, ``A``,
+``attn``), the solve's call between two transposes that XLA makes, an
+``after`` call (``T (beta V)``, ``T (beta gamma K)``, ``(gamma_C / gamma)
+K``) and one call for the whole backward; each takes q, k, v as column
+blocks of the projection's own [B, L, 2 Kd + Vd] rows and holds a block
+of chunks of one key head in VMEM.
 
 Which of the two runs is decided by which function the caller calls,
 and by nothing a user sets.  Both run in Pallas interpret mode off-TPU,
@@ -1691,30 +1699,415 @@ def unit_lower_inverse_slabs(cols: jax.Array) -> jax.Array:
     [row, column, matrix] float32, strictly lower triangular a matrix,
     any count of matrices (padded here to whole blocks of
     ``_INVERSE_LANES``; a padded matrix is 0 and its inverse I) -> the
-    inverses in the same layout.  ``a`` is read once and the inverse
+    inverses in the same layout.  Also [row, group, column, matrix] (the
+    chunk kernels' slabs: a group is a value head of its key head), each
+    group a set of slabs of its own.  ``a`` is read once and the inverse
     written once; the rows between never leave VMEM.  The caller checks
     :func:`unit_lower_inverse_tiles` first."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    c, _, m = cols.shape
+    shape = cols.shape
+    c, m = shape[0], shape[-1]
+    cols = cols.reshape(c, -1, c, m)
     lanes = _INVERSE_LANES
     pad = (-m) % lanes
     if pad:
-        cols = jnp.pad(cols, ((0, 0), (0, 0), (0, pad)))
-    spec = pl.BlockSpec((c, c, lanes), lambda mm: (0, 0, mm))
+        cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, pad),))
+    spec = pl.BlockSpec((c, None, c, lanes), lambda gg, mm: (0, gg, 0, mm))
     with jax.named_scope("hvdt.kernel.gdn_inverse"):
         t = pl.pallas_call(
             functools.partial(_inverse_kernel, guarded=_use_interpret()),
-            grid=((m + pad) // lanes,),
+            grid=(cols.shape[1], (m + pad) // lanes),
             in_specs=[spec], out_specs=spec,
             out_shape=jax.ShapeDtypeStruct(cols.shape, jnp.float32,
                                            **_vma_kw(cols)),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",)),
+                dimension_semantics=("parallel", "parallel")),
             interpret=_use_interpret(),
         )(cols)
-    return t[:, :, :m] if pad else t
+    return (t[..., :m] if pad else t).reshape(shape)
+
+
+# The Gated DeltaNet scan's chunk-local passes (ops/gated_delta.py states
+# the mathematics and the rule; these are its Mosaic schedule).  A program
+# is (sequence, block of chunks, key head): q and k of the head and v of its
+# R value heads are column blocks of the projection's own [B, L, 2 Kd + Vd]
+# rows, a chunk's [C, C] matrices of the R value heads lie side by side on
+# the lanes ([C, R C]: whole lane tiles at C 64, R 2), and the per-token
+# scalars (the cumulative log-decay and beta of each value head) come as
+# rows [8, C] a (chunk, key head) that a program turns into columns itself.
+
+_GDN_CHUNKS = 16                # chunks a program, at most
+_GDN_TRIP = 4                   # of them a trip of the program's loop
+_GDN_VMEM = 48 << 20
+
+
+def gdn_chunk_tiles(rows: int, dims, chunk: int) -> bool:
+    """Whether the chunk kernels take rows of ``rows`` tokens with ``dims``
+    = (Hk, Hv, dk, dv): whole chunks of 64, heads of whole 128-lane tiles
+    with dk = dv (so that a key head's R value heads are one column block),
+    R value heads a key head whose [C, R C] matrices fill whole lane tiles
+    and whose 2 R scalar rows fit 8 sublanes."""
+    hk, hv, dk, dv = dims
+    r = hv // hk
+    return (chunk == 64 and rows % chunk == 0 and hv == hk * r
+            and dk % 128 == 0 and dv == dk and (r * chunk) % 128 == 0
+            and 2 * r <= _SUBLANES)
+
+
+def _gdn_blocks(qkv, dims, chunk: int):
+    """For the grid (B, N / nb, Hk): the chunks a program nb;
+    ``column(width, first)``, the block spec of a program's tokens by
+    ``width`` columns of token-major rows, column block ``first + h`` for
+    key head h; and q, k and v of a key head so on the rows ``qkv``
+    [B, L, 2 Kd + Vd] (v its R value heads wide)."""
+    import jax.experimental.pallas as pl
+
+    hk, hv, dk, dv = dims
+    r = hv // hk
+    nb = math.gcd(qkv.shape[1] // chunk, _GDN_CHUNKS)
+
+    def column(width, first=0):
+        return pl.BlockSpec((None, nb * chunk, width),
+                            lambda b, j, h: (b, j, first + h))
+
+    return nb, column, (column(dk), column(dk, hk),
+                        column(r * dv, 2 * hk * dk // (r * dv)))
+
+
+def _gdn_per_chunk(nb: int):
+    """A BlockSpec maker for arrays [B, N, Hk, ...]: ``nb`` chunks of one
+    key head a program."""
+    import jax.experimental.pallas as pl
+
+    def spec(*tail):
+        zeros = (0,) * len(tail)
+        return pl.BlockSpec((None, nb, None) + tail,
+                            lambda b, j, h: (b, j, h) + zeros)
+    return spec
+
+
+def _gdn_call(kernel, grid, in_specs, out_specs, out_shape, operands, part):
+    """One of the three calls (``part``: before, after, bwd), all on the
+    grid (B, N / nb, Hk) with every program independent."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    vma = _vma_kw(*operands)
+    out_shape = [jax.ShapeDtypeStruct(s, d, **vma) for s, d in out_shape]
+    with jax.named_scope("hvdt.kernel.gdn_chunk_before" if part == "before"
+                         else "hvdt.kernel.gdn_chunk_after" if part == "after"
+                         else "hvdt.kernel.gdn_chunk_bwd"):
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",) * len(grid),
+                vmem_limit_bytes=_GDN_VMEM),
+            interpret=_use_interpret(),
+        )(*operands)
+
+
+def _gdn_unit_rows(x):
+    """x [C, d] -> (x / |x| in float32, 1 / |x| [C, 1]), eps 1e-6 under
+    the root."""
+    x = x.astype(jnp.float32)
+    r = jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+    return x * r, r
+
+
+def _gdn_nt(x, y):                      # x y^T, float32 out
+    return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _gdn_tn(x, y):                      # x^T y, float32 out
+    return jax.lax.dot_general(x, y, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _gdn_ratio(gc_col, gc_row):
+    """gamma_i / gamma_j for j <= i and 0 above the diagonal, [C, C], and
+    the strict lower triangle's mask."""
+    c = gc_col.shape[0]
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    ratio = jnp.exp(jnp.where(j <= i, gc_col - gc_row, -jnp.inf))
+    return ratio, j < i
+
+
+def _gdn_chunks_loop(nb: int, body):
+    """``body(c)`` for the program's chunks, ``_GDN_TRIP`` of them a trip
+    of the loop: a chunk is one serial chain of small products, lane sums
+    and transposes, the chunks' chains are independent, and Mosaic
+    interleaves those it finds in one trip (Pallas' ``fori_loop`` unrolls
+    by 1 or wholly, so the trip is written out).  On the v5e, ms a call of
+    before / after / backward at qwen3_next_s16384's shape (my chip runs,
+    PR 43): one chunk a trip 1.98 / 2.26 / 6.93, two 1.59 / 1.91 / 6.10,
+    four 1.51 / 1.47 / 5.77.  In interpret mode the loop is inside a
+    branch that is always taken (as ``_inverse_kernel``'s)."""
+    import jax.experimental.pallas as pl
+
+    per = math.gcd(nb, _GDN_TRIP)
+
+    def trip(t, carry):
+        for i in range(per):
+            body(t * per + i)
+        return carry
+
+    def run():
+        jax.lax.fori_loop(0, nb // per, trip, 0)
+
+    if _use_interpret():
+        pl.when(pl.program_id(0) >= 0)(run)
+    else:
+        run()
+
+
+def _gdn_before_kernel(q_ref, k_ref, rows_ref, qn_ref, a_ref, attn_ref, *,
+                       r: int, scale: float):
+    """Before the solve: the L2 norms, ``K K^T`` and ``Q K^T`` a key head,
+    the decay ratios, ``A`` (float32) and ``attn`` a value head."""
+    import jax.experimental.pallas as pl
+
+    nb, c, _ = a_ref.shape
+    dt = qn_ref.dtype                   # qn on q's own rows, token-major
+
+    def chunk(n):
+        tok = pl.ds(pl.multiple_of(n * c, c), c)
+        qn = (_gdn_unit_rows(q_ref[tok, :])[0] * scale).astype(dt)
+        kn = _gdn_unit_rows(k_ref[tok, :])[0].astype(dt)
+        kk, qk = _gdn_nt(kn, kn), _gdn_nt(qn, kn)
+        rows = rows_ref[n]                                  # [8, C]
+        cols = rows.T
+        a, attn = [], []
+        for v in range(r):
+            ratio, strict = _gdn_ratio(cols[:, v:v + 1], rows[v:v + 1])
+            beta = cols[:, r + v:r + v + 1]
+            a.append(jnp.where(strict, beta * ratio * kk, 0.0))
+            attn.append((ratio * qk).astype(dt))
+        qn_ref[tok, :] = qn
+        a_ref[n] = jnp.concatenate(a, axis=1)
+        for v in range(r):
+            attn_ref[n, v] = attn[v]
+
+    _gdn_chunks_loop(nb, chunk)
+
+
+def _gdn_after_kernel(k_ref, v_ref, t_ref, rows_ref, w_ref, u_ref, ko_ref, *,
+                      r: int):
+    """After the solve: ``U = T (beta V)`` (float32), ``W = T (beta gamma
+    K)`` and ``K_out = (gamma_C / gamma) K`` a value head."""
+    import jax.experimental.pallas as pl
+
+    nb, c, _ = t_ref.shape
+    dt, f32 = w_ref.dtype, jnp.float32
+    d = k_ref.shape[-1]
+
+    def chunk(n):
+        tok = pl.ds(pl.multiple_of(n * c, c), c)
+        kn = _gdn_unit_rows(k_ref[tok, :])[0].astype(dt).astype(f32)
+        cols = rows_ref[n].T                                # [C, 8]
+        t = t_ref[n].astype(dt)                             # [C, R C]
+        for v in range(r):
+            gc, beta = cols[:, v:v + 1], cols[:, r + v:r + v + 1]
+            gamma = jnp.exp(gc)
+            t_v = t[:, v * c:(v + 1) * c]
+            bv = (beta * v_ref[tok, v * d:(v + 1) * d].astype(f32)
+                  ).astype(dt)
+            bgk = ((beta * gamma) * kn).astype(dt)
+            u_ref[n, v] = jnp.dot(t_v, bv, preferred_element_type=f32)
+            w_ref[n, v] = jnp.dot(t_v, bgk,
+                                  preferred_element_type=f32).astype(dt)
+            ko_ref[n, v] = (jnp.exp(gc[c - 1:c] - gc) * kn).astype(dt)
+
+    _gdn_chunks_loop(nb, chunk)
+
+
+def _gdn_small_rows(gc, beta):
+    """The per-token scalars as the kernels read them: gc and beta
+    [B, N, C, Hk, R] float32 -> [B, N, Hk, 8, C], rows (gc of the R value
+    heads, beta of the R value heads, zeros)."""
+    b, n, c, hk, r = gc.shape
+    rows = jnp.concatenate([gc, beta], -1)                  # [B,N,C,Hk,2R]
+    rows = jnp.moveaxis(rows, 2, -1)                        # [B,N,Hk,2R,C]
+    return jnp.pad(rows, ((0, 0),) * 3 + ((0, _SUBLANES - 2 * r), (0, 0)))
+
+
+def _gdn_to_slabs(a):
+    """[B, N, Hk, C, R C] (a chunk's R matrices side by side) -> the
+    solve's slabs [row, value head, column, (sequence, chunk, key head)]:
+    one transpose of the 128 lanes with the matrices."""
+    c = a.shape[3]
+    slabs = jnp.transpose(a.reshape(-1, c, a.shape[4]), (1, 2, 0))
+    return slabs.reshape(c, -1, c, slabs.shape[-1])
+
+
+def _gdn_from_slabs(slabs, shape):
+    """The slabs back side by side, [B, N, Hk, C, R C]."""
+    c = slabs.shape[0]
+    return jnp.transpose(slabs.reshape(c, -1, slabs.shape[-1]),
+                         (2, 0, 1)).reshape(shape)
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "chunk"))
+def gdn_chunk_forward(qkv, gc, beta, dims, chunk: int):
+    """The chunk-local passes forward as Mosaic calls: ``before``, PR 34's
+    solve on the slabs, ``after``.  qkv [B, N C, 2 Kd + Vd]; gc, beta
+    [B, N, C, Hk, R] float32.  Returns (qn [B,N,C,Hk,dk], w, u_own, k_out
+    [B,N,Hk,R,C,d], attn [B,N,Hk,R,C,C]) and ``T`` [B,N,Hk,C,R C] in
+    float32, the backward's residual.  The caller checks
+    :func:`gdn_chunk_tiles` first."""
+    hk, hv, dk, dv = dims
+    r = hv // hk
+    b, l, _ = qkv.shape
+    n = l // chunk
+    dt, f32 = qkv.dtype, jnp.float32
+    nb, _, (q_spec, k_spec, v_spec) = _gdn_blocks(qkv, dims, chunk)
+    per_chunk = _gdn_per_chunk(nb)
+    grid = (b, n // nb, hk)
+    rows = _gdn_small_rows(gc, beta)
+    packed = (b, n, hk, chunk, r * chunk)
+    qn, a, attn = _gdn_call(
+        functools.partial(_gdn_before_kernel, r=r, scale=dk ** -0.5), grid,
+        [q_spec, k_spec, per_chunk(_SUBLANES, chunk)],
+        [q_spec, per_chunk(chunk, r * chunk), per_chunk(r, chunk, chunk)],
+        [((b, l, hk * dk), dt), (packed, f32),
+         ((b, n, hk, r, chunk, chunk), dt)],
+        (qkv, qkv, rows), "before")
+    qn = qn.reshape(b, n, chunk, hk, dk)
+    t = _gdn_from_slabs(unit_lower_inverse_slabs(_gdn_to_slabs(a)), packed)
+    heads = (b, n, hk, r, chunk)
+    w, u_own, k_out = _gdn_call(
+        functools.partial(_gdn_after_kernel, r=r), grid,
+        [k_spec, v_spec, per_chunk(chunk, r * chunk),
+         per_chunk(_SUBLANES, chunk)],
+        [per_chunk(r, chunk, dk), per_chunk(r, chunk, dv),
+         per_chunk(r, chunk, dk)],
+        [(heads + (dk,), dt), (heads + (dv,), f32), (heads + (dk,), dt)],
+        (qkv, qkv, t, rows), "after")
+    return (qn, w, u_own, k_out, attn), t
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, t_ref, rows_ref, cqn_ref, cw_ref,
+                    cu_ref, cko_ref, cattn_ref, dq_ref, dk_ref, dv_ref,
+                    drows_ref, *, r: int, scale: float):
+    """The rule's backward for a program's chunks (``gated_delta.
+    _chunk_bwd_jax`` states it): the norms, the ratios, ``K K^T``,
+    ``Q K^T`` and the casts derived again from q, k, v and the scalar rows,
+    ``t_ref`` the inverses ([C, R C] float32, transposed here once a chunk
+    so that ``T^T x`` and ``T^T ct T^T`` are plain products), the five
+    cotangents in; dq,
+    dk, dv on the rows' column blocks and the scalars' cotangents as rows
+    [8, C] (d gc and d beta of the R value heads) out."""
+    import jax.experimental.pallas as pl
+
+    nb, c, _ = t_ref.shape
+    dt, f32 = dq_ref.dtype, jnp.float32
+    d = k_ref.shape[-1]
+    hi = jax.lax.Precision.HIGHEST
+    last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+
+    def lanes(x):                       # a sum over the lanes: [C, 1]
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    def chunk(n):
+        tok = pl.ds(pl.multiple_of(n * c, c), c)
+        qy, rq = _gdn_unit_rows(q_ref[tok, :])
+        ky, rk = _gdn_unit_rows(k_ref[tok, :])
+        qn, kn = (qy * scale).astype(dt), ky.astype(dt)
+        knf = kn.astype(f32)
+        kk, qk = _gdn_nt(kn, kn), _gdn_nt(qn, kn)
+        rows = rows_ref[n]                                  # [8, C]
+        cols = rows.T
+        s_all = t_ref[n].T                                  # [R C, C]
+        d_kn = jnp.zeros((c, d), f32)
+        d_kk = jnp.zeros((c, c), f32)
+        d_qk = jnp.zeros((c, c), f32)
+        out_cols, out_rows = [], []
+        for v in range(r):
+            gc, beta = cols[:, v:v + 1], cols[:, r + v:r + v + 1]
+            gamma = jnp.exp(gc)
+            bg = beta * gamma
+            e = jnp.exp(gc[c - 1:c] - gc)
+            ratio, strict = _gdn_ratio(gc, rows[v:v + 1])
+            vf = v_ref[tok, v * d:(v + 1) * d].astype(f32)
+            bv, bgk = (beta * vf).astype(dt), (bg * knf).astype(dt)
+            s32 = s_all[v * c:(v + 1) * c]
+            sb = s32.astype(dt)
+            cu, cw = cu_ref[n, v].astype(dt), cw_ref[n, v]
+            d_t = _gdn_nt(cu, bv) + _gdn_nt(cw, bgk)
+            d_bv = jnp.dot(sb, cu, preferred_element_type=f32)
+            d_bgk = jnp.dot(sb, cw, preferred_element_type=f32)
+            # the inverse: -T^T d_t T^T on the strict lower triangle
+            d_a = jnp.dot(jnp.dot(s32, d_t, preferred_element_type=f32,
+                                  precision=hi),
+                          s32, preferred_element_type=f32, precision=hi)
+            dar = jnp.where(strict, -d_a, 0.0) * ratio
+            ca = cattn_ref[n, v].astype(f32) * ratio
+            d_kk = d_kk + dar * beta
+            d_qk = d_qk + ca
+            m = dar * beta * kk + ca * qk
+            cko = cko_ref[n, v].astype(f32)
+            d_e = lanes(cko * knf) * e
+            d_bg = lanes(d_bgk * knf)
+            d_gc = (lanes(m) + beta * d_bg * gamma - d_e
+                    + jnp.where(last, jnp.sum(d_e, axis=0, keepdims=True),
+                                0.0))
+            d_beta = lanes(dar * kk) + lanes(d_bv * vf) + gamma * d_bg
+            d_kn = d_kn + d_bgk * bg + cko * e
+            dv_ref[tok, v * d:(v + 1) * d] = (d_bv * beta).astype(dt)
+            out_cols.append((d_gc, d_beta))
+            out_rows.append(-jnp.sum(m, axis=0, keepdims=True))
+        d_kkb, d_qkb = d_kk.astype(dt), d_qk.astype(dt)
+        d_kn = (d_kn + jnp.dot(d_kkb, kn, preferred_element_type=f32)
+                + _gdn_tn(d_kkb, kn) + _gdn_tn(d_qkb, qn))
+        d_qn = (cqn_ref[tok, :].astype(f32)
+                + jnp.dot(d_qkb, kn, preferred_element_type=f32)) * scale
+        dq_ref[tok, :] = (rq * (d_qn - qy * lanes(qy * d_qn))).astype(dt)
+        dk_ref[tok, :] = (rk * (d_kn - ky * lanes(ky * d_kn))).astype(dt)
+        pad = [jnp.zeros((c, 1), f32)] * (_SUBLANES - 2 * r)
+        as_cols = jnp.concatenate([g for g, _ in out_cols]
+                                  + [b for _, b in out_cols] + pad, axis=1)
+        as_rows = jnp.concatenate(
+            out_rows + [jnp.zeros((_SUBLANES - r, c), f32)], axis=0)
+        drows_ref[n] = as_cols.T + as_rows
+
+    _gdn_chunks_loop(nb, chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "chunk"))
+def gdn_chunk_backward(qkv, gc, beta, t, cts, dims, chunk: int):
+    """The rule's backward as one Mosaic call: the cotangents of (qkv, gc,
+    beta) from the residuals of :func:`gdn_chunk_forward` and the
+    cotangents ``cts`` of its five outputs."""
+    hk, hv, dk, dv = dims
+    r = hv // hk
+    b, l, _ = qkv.shape
+    n = l // chunk
+    dt, f32 = qkv.dtype, jnp.float32
+    nb, column, (q_spec, k_spec, v_spec) = _gdn_blocks(qkv, dims, chunk)
+    per_chunk = _gdn_per_chunk(nb)
+    ct_qn, ct_w, ct_u, ct_kout, ct_attn = cts
+    rows_spec = per_chunk(_SUBLANES, chunk)
+    d_q, d_k, d_v, d_rows = _gdn_call(
+        functools.partial(_gdn_bwd_kernel, r=r, scale=dk ** -0.5),
+        (b, n // nb, hk),
+        [q_spec, k_spec, v_spec, per_chunk(chunk, r * chunk), rows_spec,
+         q_spec, per_chunk(r, chunk, dk),
+         per_chunk(r, chunk, dv), per_chunk(r, chunk, dk),
+         per_chunk(r, chunk, chunk)],
+        # dq, dk and dv each on rows of their own: column block h
+        [q_spec, q_spec, column(r * dv), rows_spec],
+        [((b, l, hk * dk), dt), ((b, l, hk * dk), dt), ((b, l, hv * dv), dt),
+         ((b, n, hk, _SUBLANES, chunk), f32)],
+        (qkv, qkv, qkv, t, _gdn_small_rows(gc, beta),
+         ct_qn.reshape(b, l, hk * dk), ct_w, ct_u, ct_kout, ct_attn),
+        "bwd")
+    d_small = jnp.moveaxis(d_rows[:, :, :, :2 * r], -1, 2)  # [B,N,C,Hk,2R]
+    return (jnp.concatenate([d_q, d_k, d_v], -1), d_small[..., :r],
+            d_small[..., r:])
 
 
 _ROPE_BLOCK_BYTES = 1 << 21     # a program's block as a float32 slab

@@ -221,10 +221,23 @@ def _eva(kernel):
 
 
 def _gdn(kernel):
+    """The solve's call on its slabs, and the three calls of the
+    chunk-local passes around it (one chunk of one key head)."""
     from horovod_tpu.ops import pallas_kernels as pk
 
-    cols = jnp.zeros((16, 16, 128), jnp.float32)
-    return lambda: pk.unit_lower_inverse_slabs(cols)
+    if kernel == "gdn_inverse":
+        cols = jnp.zeros((16, 16, 128), jnp.float32)
+        return lambda: pk.unit_lower_inverse_slabs(cols)
+    dims, chunk = (1, 2, 128, 128), 64
+    qkv = jnp.ones((1, chunk, 512), jnp.float32)
+    small = jnp.zeros((1, 1, chunk, 1, 2), jnp.float32)
+    forward = functools.partial(pk.gdn_chunk_forward, dims=dims, chunk=chunk)
+    if kernel != "gdn_chunk_bwd":
+        return lambda: forward(qkv, small, small)
+    out, t = jax.eval_shape(forward, qkv, small, small)
+    zeros = lambda x: jnp.zeros(x.shape, x.dtype)       # noqa: E731
+    return lambda: pk.gdn_chunk_backward(
+        qkv, small, small, zeros(t), tuple(map(zeros, out)), dims, chunk)
 
 
 def _rope(kernel):
@@ -251,7 +264,8 @@ KERNEL_SITES = (
     + [(_eva, k) for k in ("eva_win_fwd", "eva_win_bwd", "eva_sum_fwd",
                            "eva_sum_dq", "eva_sum_dkv")]
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
-    + [(_gdn, "gdn_inverse")]
+    + [(_gdn, k) for k in ("gdn_inverse", "gdn_chunk_before",
+                           "gdn_chunk_after", "gdn_chunk_bwd")]
     + [(_rope, "rope")]
     + [(_moe_rows, "moe_sum_rows")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
@@ -610,7 +624,7 @@ def test_the_inverses_kernel_is_in_the_forward_and_the_recompute(
     and ``gdn_chunk_ms`` sum and what a later
     ``gdn_inverse_ms`` picks by ``phase_split.scope_calls(ctx,
     "hvdt.kernel.gdn_inverse", trace_reduce.is_mosaic)``), float32
-    ``[C, C, matrices]`` slabs in and out, and never under ``transpose(``:
+    ``[C, 1, C, matrices]`` slabs in and out, and never under ``transpose(``:
     the backward is the inverse's own two-product cotangent rule.  The
     four-stage loop of XLA's schedule is gone from that text and is what
     the CPU compiles (no kernel scope there)."""
@@ -636,8 +650,10 @@ def test_the_inverses_kernel_is_in_the_forward_and_the_recompute(
                          r"hvdt\.gdn\.scan\.chunk\)?/"
                          r"hvdt\.kernel\.gdn_inverse/", loc)
         assert "hvdt.attention" not in loc and "transpose(" not in loc
-        # 2 sequences x 1 chunk x 2 value heads, padded to a block
-        assert "(tensor<64x64x128xf32>) -> tensor<64x64x128xf32>" in line
+        # 2 sequences x 1 chunk x 2 value heads, padded to a block; one
+        # group of slabs (the chunk kernels give a group a value head)
+        assert ("(tensor<64x1x64x128xf32>) -> tensor<64x1x64x128xf32>"
+                in line)
     # inside the scanned body a name stack is relative to the call: the
     # forward's is bare, the recompute's starts at its checkpoint
     assert sorted(loc.split("hvdt.gdn/")[0].strip('"')
@@ -744,3 +760,56 @@ def test_the_states_loop_is_under_state_and_nothing_else_of_the_scan_is(
                 if "bnihd,bnjhd->bnhij" in n or "bnhrij,bnhrje->bnihre" in n]
     assert any("transpose(" in n for n in others)
     assert any("rematted_computation" in n for n in others)
+
+
+def _name_stacks(jaxpr, outer=()):
+    """(primitive, whole name stack) of every equation of ``jaxpr`` and of
+    the jaxprs its equations call (a kernel's body is the kernel's)."""
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        stack = outer + (str(eqn.source_info.name_stack),)
+        inner = [] if eqn.primitive.name == "pallas_call" else list(
+            core.jaxprs_in_params(eqn.params))
+        for sub in inner:
+            yield from _name_stacks(sub, stack)
+        if not inner:
+            yield eqn.primitive.name, "/".join(s for s in stack if s)
+
+
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["xla", "mosaic"])
+def test_the_chunk_rule_is_under_chunk_forward_and_backward(monkeypatch,
+                                                            on_tpu):
+    """Every equation of ``gated_delta_rule`` under ``jax.grad`` is under
+    one of the scan's three children, and those of the chunk-local
+    passes' hand-written rule (everything that is not the state's loop or
+    ``O``), the backward's among them, under ``hvdt.gdn.scan.chunk``: what
+    ``gdn_chunk_ms`` sums, so that no time of the rule slides to
+    ``unscoped_ms``.  In both schedules; in Mosaic's the four calls are
+    the forward's three and the backward's one."""
+    from horovod_tpu.ops import gated_delta as gd
+
+    monkeypatch.setattr(gd, "_on_tpu", lambda: on_tpu)
+    jax.clear_caches()
+    try:
+        x = jnp.ones((1, 128, 1, 128), jnp.float32)
+        v, small = jnp.ones((1, 128, 2, 128)), jnp.ones((1, 128, 2)) * 0.5
+        grad = jax.grad(lambda *a: gd.gated_delta_rule(*a).sum(),
+                        argnums=(0, 1, 2, 3, 4))
+        stacks = [(p, s) for p, s in _name_stacks(
+            jax.make_jaxpr(grad)(x, x, v, -small, small).jaxpr)
+            if "hvdt." in s or p == "pallas_call"]
+    finally:
+        jax.clear_caches()
+    children = [re.findall(r"hvdt\.gdn\.scan\.(\w+)", s) for _, s in stacks]
+    assert all(c and set(c) <= {"chunk", "state", "out"} and len(set(c)) == 1
+               for c in children)
+    chunk = [(p, s) for (p, s), c in zip(stacks, children) if "chunk" in c]
+    backward = [s for _, s in chunk if s.startswith("transpose(")]
+    assert len(backward) > 10 and len(chunk) - len(backward) > 10
+    calls = [s.split("hvdt.kernel.")[1] for p, s in chunk
+             if p == "pallas_call"]
+    assert calls == (["gdn_chunk_before", "gdn_inverse", "gdn_chunk_after",
+                      "gdn_chunk_bwd"] if on_tpu else [])
+    assert not [p for (p, _), c in zip(stacks, children)
+                if p == "pallas_call" and "chunk" not in c]

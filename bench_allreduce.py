@@ -1,4 +1,4 @@
-"""Allreduce bus-bandwidth microbenchmark — BASELINE.md's primary metric.
+"""Allreduce bus-bandwidth microbenchmark: the reference's primary metric.
 
 The reference's headline numbers are allreduce scaling efficiency measured
 with dedicated benchmark harnesses (ref: docs/benchmarks.rst:8-43; the
@@ -25,8 +25,8 @@ or the block-scaled quantized two-stage collective for int8/int4
 (``Compression.int8`` / ``.int4`` — horovod_tpu/quant; int4 packs two
 4-bit lanes per byte on the wire).  Non-f32 wires also time
 the f32 leg and report ``speedup_vs_f32``; ``--json-out FILE`` writes
-the sweep (bytes_on_wire, GB/s, speedup) as a JSON result file for the
-BENCH trajectory, like bench.py does.
+the sweep (bytes_on_wire, GB/s, speedup) as a JSON result file
+(what ``tools/fit_costmodel.py`` and the autotune seeds read).
 
 ``--hierarchical`` measures the transport-policy data plane
 (horovod_tpu/transport) on a two-level (outer × inner) mesh: per size

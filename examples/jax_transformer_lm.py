@@ -22,7 +22,7 @@ import numpy as np
 
 
 PRESETS = {
-    # BASELINE.md config 4: BERT-Large-scale pretraining (340M params).
+    # BERT-Large-scale pretraining (340M params).
     # The LM objective here is causal rather than MLM; the capability
     # under test — Adasum + wire compression + fused dp allreduce at
     # 24x1024x16 scale — is objective-agnostic.
@@ -126,10 +126,6 @@ def main():
     p.add_argument("--bf16-allreduce", action="store_true",
                    help="bf16 wire compression for the dp allreduce "
                         "(dp-only layout)")
-    p.add_argument("--profile", action="store_true",
-                   help="after timing, capture a 3-step XPlane trace and "
-                        "print the per-op/per-category breakdown "
-                        "(tools/profile_step.py aggregation)")
     args = p.parse_args()
     if args.preset:
         # Preset fills in only what the user left at parser defaults, so
@@ -358,7 +354,7 @@ def main():
     with mesh_ctx:
         # Warmup/compile
         params, opt_state, loss = step(params, opt_state, tokens)
-        first = float(loss)   # host fetch, not block_until_ready: bench.py
+        first = float(loss)   # host fetch ends the compile + first step
 
         t0 = time.perf_counter()
         for _ in range(args.steps):
@@ -373,35 +369,6 @@ def main():
         print(f"loss: {first:.4f} -> {last:.4f}")
         print(f"{tokens_sec:.0f} tokens/sec, ~{tflops:.3f} model TFLOP/s")
         assert last < first, "loss should decrease"
-
-    if args.profile:
-        import sys
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.abspath(__file__)), os.pardir, "tools"))
-        from profile_step import aggregate, capture, report
-
-        state = [params, opt_state]
-
-        def one():
-            p, o, loss = step(state[0], state[1], tokens)
-            state[0], state[1] = p, o
-            float(loss)   # host fetch keeps device work inside the window
-
-        prof_ctx = (jax.set_mesh(mesh) if not single and not explicit_dp
-                    else contextlib.nullcontext())
-        # Every rank runs the extra steps (a rank-0-only step() would
-        # deadlock multi-process collectives); only rank 0 traces and
-        # prints the breakdown.
-        with prof_ctx:
-            if hvd.rank() == 0:
-                path = capture(one, 3)
-            else:
-                for _ in range(3):
-                    one()
-        if hvd.rank() == 0:
-            print(f"xplane: {path}", file=sys.stderr)
-            per_op, per_cat, busy, span = aggregate(path)
-            report(per_op, per_cat, busy, span, 3)
 
     if hvd.rank() == 0:
         print("done.")

@@ -116,8 +116,8 @@ DEFAULT_QUANT_GAMMA_S_PER_BYTE: Dict[str, float] = {
 
 
 # Reference per-chip step workload for scaling curves (ResNet-50 at
-# the BENCH batch size: 25.6M f32 params -> ~102 MB of gradients, and
-# the XLA cost-analysis flops bench.py reports).  Living here keeps the
+# batch 128: 25.6M f32 params -> ~102 MB of gradients, and
+# XLA's cost-analysis flops of that step).  Living here keeps the
 # curve's magnitudes out of the magic-peak-flops window elsewhere.
 REFERENCE_STEP_WORKLOAD: Dict[str, float] = {
     "grad_bytes": 102.4e6,

@@ -255,8 +255,7 @@ class ParameterManager:
         # pricing, so they are searched jointly.  Hot-swappable: the
         # capacity changes the dispatch layout (a re-jit), never
         # optimizer state.  The starting leg is the explicit
-        # HVDT_MOE_CAPACITY_FACTOR, the MEASURED
-        # HVDT_AUTOTUNE_MOE_SEED verdict, or the cost model's a2a-wire
+        # HVDT_MOE_CAPACITY_FACTOR or the cost model's a2a-wire
         # ordering.
         self.tune_moe = (tune_moe if tune_moe is not None
                          else config.get_bool("HVDT_AUTOTUNE_MOE"))
@@ -598,9 +597,7 @@ def _env_transport() -> bool:
 def _env_capacity_factor() -> float:
     """The environment's expert capacity-factor default (the MoE
     dimension's starting leg): an explicitly set
-    HVDT_MOE_CAPACITY_FACTOR wins; else the MEASURED verdict of a
-    bench.py --moe sweep named by HVDT_AUTOTUNE_MOE_SEED
-    (capacity_factor_at_peak); else the cost model may order the leg
+    HVDT_MOE_CAPACITY_FACTOR wins; else the cost model may order the leg
     (HVDT_AUTOTUNE_MODEL_SEED — a True 'moe' verdict means the
     quantized dispatch wire wins, so capacity headroom is cheap: start
     at the registry default 1.25; False starts tight at 1.0 to keep
@@ -609,18 +606,6 @@ def _env_capacity_factor() -> float:
 
     if os.environ.get("HVDT_MOE_CAPACITY_FACTOR", "").strip():
         return config.get_float("HVDT_MOE_CAPACITY_FACTOR")
-    seed = config.get_str("HVDT_AUTOTUNE_MOE_SEED").strip()
-    if seed:
-        import json
-
-        try:
-            with open(seed) as fh:
-                doc = json.load(fh)
-            v = float(doc.get("capacity_factor_at_peak", 0.0))
-            if v > 0:
-                return v
-        except (OSError, ValueError, TypeError) as e:
-            log.warning("moe autotune seed %s unreadable: %s", seed, e)
     ms = _model_seed("moe")
     if ms is not None:
         return 1.25 if ms else 1.0
@@ -630,9 +615,7 @@ def _env_capacity_factor() -> float:
 def _env_microbatches() -> int:
     """The environment's 1F1B microbatch-count default (the pipeline
     dimension's starting leg): an explicitly set
-    HVDT_PIPELINE_MICROBATCHES wins; else the MEASURED verdict of a
-    bench.py --pipeline sweep named by HVDT_AUTOTUNE_PIPELINE_SEED
-    (microbatches_at_peak); else the cost model may order the leg
+    HVDT_PIPELINE_MICROBATCHES wins; else the cost model may order the leg
     (HVDT_AUTOTUNE_MODEL_SEED — a True 'pipeline' verdict means the
     tick is bandwidth-dominated, so halving per-tick payload is free
     bubble shrink: start at the high end 16; False starts at the
@@ -641,19 +624,6 @@ def _env_microbatches() -> int:
 
     if os.environ.get("HVDT_PIPELINE_MICROBATCHES", "").strip():
         return max(1, config.get_int("HVDT_PIPELINE_MICROBATCHES"))
-    seed = config.get_str("HVDT_AUTOTUNE_PIPELINE_SEED").strip()
-    if seed:
-        import json
-
-        try:
-            with open(seed) as fh:
-                doc = json.load(fh)
-            v = int(doc.get("microbatches_at_peak", 0))
-            if v > 0:
-                return v
-        except (OSError, ValueError, TypeError) as e:
-            log.warning("pipeline autotune seed %s unreadable: %s",
-                        seed, e)
     ms = _model_seed("pipeline")
     if ms is not None:
         return 16 if ms else max(1, config.get_int(
@@ -846,9 +816,8 @@ class AutotunedStep:
     payload vs dropped-token fraction, priced jointly with the wire
     legs; hot-swappable because capacity changes the dispatch layout
     (a re-jit), never optimizer state.  Starting leg: explicit
-    ``HVDT_MOE_CAPACITY_FACTOR``, the measured
-    ``HVDT_AUTOTUNE_MOE_SEED`` bench verdict, or the cost model's
-    a2a-wire ordering (``HVDT_AUTOTUNE_MODEL_SEED``).
+    ``HVDT_MOE_CAPACITY_FACTOR``, or the cost model's a2a-wire
+    ordering (``HVDT_AUTOTUNE_MODEL_SEED``).
 
     With ``HVDT_AUTOTUNE_PIPELINE=1`` the space gains a 1F1B
     microbatch-count dimension (parallel/pipeline.py): builders
@@ -856,9 +825,8 @@ class AutotunedStep:
     ``builder(threshold_bytes, microbatches=int)`` — bubble fraction
     vs per-tick ppermute payload; hot-swappable because the clock
     changes lowering, never state.  Starting leg: explicit
-    ``HVDT_PIPELINE_MICROBATCHES``, the measured
-    ``HVDT_AUTOTUNE_PIPELINE_SEED`` bench verdict, or the cost model's
-    ppermute ordering.
+    ``HVDT_PIPELINE_MICROBATCHES``, or the cost model's ppermute
+    ordering.
 
     Args:
       builder: ``builder(threshold_bytes | None) -> step_callable``

@@ -76,7 +76,7 @@ KNOBS: Dict[str, Knob] = {
            "flags (ridden via LIBTPU_INIT_ARGS, read once at TPU "
            "backend init; inert off-TPU): auto (skip when JAX_PLATFORMS "
            "pins a non-TPU backend), on, off.  Engaged by hvd.init() "
-           "and bench.py --overlap — this is what turns the overlap "
+           "— this is what turns the overlap "
            "schedule's dependency freedom into overlapped execution on "
            "hardware."),
         _k("HVDT_AUTOTUNE_OVERLAP", False, _parse_bool,
@@ -180,7 +180,7 @@ KNOBS: Dict[str, Knob] = {
            "hvdt_pipeline_mfu gauge carries the result."),
         _k("HVDT_PIPELINE_MICROBATCHES", 8, int,
            "Default 1F1B microbatch count (the pipeline autotune "
-           "dimension's starting point; bench.py --pipeline default). "
+           "dimension's starting point). "
            "More microbatches shrink the bubble fraction "
            "(p-1)/(m+p-1) at the cost of smaller per-tick payloads."),
         _k("HVDT_AUTOTUNE_MOE", False, _parse_bool,
@@ -190,28 +190,16 @@ KNOBS: Dict[str, Knob] = {
            "(autotune.AutotunedStep), hot-swappable because capacity "
            "changes the dispatch layout, never optimizer state.  "
            "Starting point: HVDT_MOE_CAPACITY_FACTOR set explicitly, "
-           "the measured HVDT_AUTOTUNE_MOE_SEED verdict, or the cost "
-           "model's a2a-wire ordering (HVDT_AUTOTUNE_MODEL_SEED)."),
-        _k("HVDT_AUTOTUNE_MOE_SEED", "", str,
-           "Path to a bench.py --moe --json-out file; its measured "
-           "capacity_factor_at_peak becomes the autotuner's MoE "
-           "dimension starting point — policies are seeded from "
-           "measurements, not guesses (mirrors "
-           "HVDT_AUTOTUNE_TRANSPORT_SEED)."),
+           "or the cost model's a2a-wire ordering "
+           "(HVDT_AUTOTUNE_MODEL_SEED)."),
         _k("HVDT_AUTOTUNE_PIPELINE", False, _parse_bool,
            "Add a 1F1B microbatch-count dimension to the autotune "
            "search space; the step builder is rebuilt with "
            "microbatches=... at each knob change "
            "(autotune.AutotunedStep), hot-swappable because the "
            "microbatch clock changes lowering, never state.  Starting "
-           "point: HVDT_PIPELINE_MICROBATCHES set explicitly, the "
-           "measured HVDT_AUTOTUNE_PIPELINE_SEED verdict, or the "
+           "point: HVDT_PIPELINE_MICROBATCHES set explicitly, or the "
            "cost model's ppermute ordering (HVDT_AUTOTUNE_MODEL_SEED)."),
-        _k("HVDT_AUTOTUNE_PIPELINE_SEED", "", str,
-           "Path to a bench.py --pipeline --json-out file; its "
-           "measured microbatches_at_peak becomes the autotuner's "
-           "pipeline dimension starting point (mirrors "
-           "HVDT_AUTOTUNE_MOE_SEED)."),
         # --- activation rematerialization (models/: jax.checkpoint
         #     policy on the transformer block — the second half of the
         #     memory-for-MFU trade next to HVDT_ZERO) ---
@@ -222,8 +210,8 @@ KNOBS: Dict[str, Knob] = {
            "jax.checkpoint_policies.dots_with_no_batch_dims_saveable "
            "(save matmul outputs, recompute elementwise+attention — "
            "falls back to 'full' with a warning on jax builds without "
-           "the policy).  Consumed by models.remat_from_env / bench.py "
-           "--remat; unknown values raise with the valid list."),
+           "the policy).  Consumed by models.remat_from_env; "
+           "unknown values raise with the valid list."),
         # --- cache (ref: HOROVOD_CACHE_CAPACITY common.h:114) ---
         _k("HVDT_CACHE_CAPACITY", 1024, int,
            "Response-cache capacity (negotiated-collective descriptors)."),
@@ -597,9 +585,10 @@ KNOBS: Dict[str, Knob] = {
            "Route eligible ResNet 1x1 conv+BN(+ReLU) blocks through the "
            "fused Pallas kernels (ops/conv_fused.py): train mode emits "
            "conv output + batch-stat partials in one pass, eval mode "
-           "fuses the folded affine into the matmul epilogue.  Default "
-           "OFF pending the TPU A/B (tools/tpu_ab.py resnet_bench_fused "
-           "leg) — an unmeasured kernel is not a default.  Eligibility: "
+           "fuses the folded affine into the matmul epilogue.  Off "
+           "until a run of the resnet50_train cell with the variable "
+           "set decides it — an unmeasured kernel is not a default.  "
+           "Eligibility: "
            "1x1, stride 1, Cin % 128 == 0 AND Cout % 128 == 0 (SyncBN "
            "via psum'd stat partials when bn_axis is set)."),
         _k("HVDT_RING_PALLAS", False, _parse_bool,
@@ -609,15 +598,15 @@ KNOBS: Dict[str, Knob] = {
            "Route optimizer updates through the fused Pallas kernels "
            "(ops/optim_kernels.fused_adam/fused_sgd) where leaves are "
            "tile-eligible; ineligible leaves fall back to the identical "
-           "XLA math.  Default OFF pending the TPU A/B (bench.py "
-           "--fused-optimizer exports this; the autotuner's fused "
-           "dimension reads it as the starting point)."),
+           "XLA math.  Off until a run of a benchmark cell with the "
+           "variable set decides it (the autotuner's fused dimension "
+           "reads it as the starting point)."),
         # --- step pipeline ---
         _k("HVDT_COMPILATION_CACHE", "", str,
            "Directory for JAX's persistent XLA compilation cache "
            "(step_pipeline.enable_compilation_cache; engaged inside "
            "hvd.init()).  JAX_COMPILATION_CACHE_DIR, where set, wins "
-           "over it; the root scripts (chip_smoke.py, bench.py, "
+           "over it; the root scripts (chip_smoke.py, "
            "bench_allreduce.py) default to <checkout>/.xla_cache below "
            "it.  Empty = unset; off = disabled."),
         _k("HVDT_COMPILATION_CACHE_MIN_COMPILE_SECS", 1.0, float,

@@ -21,7 +21,7 @@ where goodput_fraction is productive training chip-time over allocated
 training chip-time (restart charges per world change, the sub-30s
 recovery budget) and slo_compliance is the fraction of ticks with
 simulated p99 inside the trace's SLO.  ``hvdtrun fleet`` is this
-module's CLI; ``bench.py --fleet`` wraps the same entry point, and
+module's CLI, and
 ``--event-log`` threads every ``fleet_decision`` into the JSONL that
 ``analysis --report`` and ``hvdtrun top`` render.
 """

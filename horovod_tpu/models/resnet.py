@@ -4,7 +4,8 @@ The reference benchmarks Horovod with torchvision/Keras ResNet-50
 (ref: examples/pytorch/pytorch_synthetic_benchmark.py:17-26,
 examples/tensorflow2/tensorflow2_synthetic_benchmark.py; docs/benchmarks.rst
 headline numbers — SURVEY.md §6).  This is the equivalent model for this
-framework's synthetic benchmark and scaling-efficiency harness (bench.py).
+framework's synthetic benchmark (``benchmark/``, cell ``resnet50_train``;
+``examples/jax_synthetic_benchmark.py``).
 
 TPU-first choices: NHWC layout (XLA-TPU native), bf16 compute with f32
 batch-norm statistics, ``(params, batch_stats)`` as explicit pytrees so

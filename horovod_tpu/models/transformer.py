@@ -13,7 +13,7 @@ TPU-first design decisions:
 * **Hybrid parallelism**: dp/fsdp/tp are expressed with logical-axis
   sharding rules (GSPMD auto-partitioning inserts the collectives); sp
   (ring attention) and ep (MoE alltoall) are manual ``shard_map`` islands;
-  pp wraps the block stack in ``pipeline_spmd``.
+  pp wraps the block stack in ``pipeline_1f1b``.
 
 The reference has no model layer — its examples lean on torchvision/Keras
 (ref: examples/pytorch/pytorch_synthetic_benchmark.py:17-26).  This module
@@ -40,7 +40,7 @@ from ..ops.gated_delta import gated_delta_net, scan_macs_per_token
 from ..ops.pallas_kernels import rope
 from ..parallel.moe import (ROUTE_SAVED, moe_dispatch_combine,
                             moe_held_experts)
-from ..parallel.pipeline import pipeline_spmd
+from ..parallel.pipeline import pipeline_1f1b
 from ..parallel.ring_attention import ring_attention
 from ..quant import fp8 as _fp8
 
@@ -918,7 +918,7 @@ def checkpoint_policy(mode: Optional[str] = None):
 def remat_from_env(cfg: TransformerConfig,
                    mode: Optional[str] = None) -> TransformerConfig:
     """Apply the ``HVDT_REMAT`` knob (``none|full|dots``) to a config —
-    the memory-for-MFU trade surfaced as ``bench.py --remat`` /
+    the memory-for-MFU trade surfaced as
     ``hvdtrun --remat``.  Returns ``cfg`` unchanged for ``none`` (and
     the ``dots``→``full`` fallback is resolved here so the config names
     the policy that will actually run)."""
@@ -1060,7 +1060,7 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
             # positions are identical across microbatches in this layout
             return _scan_blocks(stage_p, a, pos_mb[0], cfg)
 
-        x = pipeline_spmd(stage_fn, params["block"], acts, axis="pp")
+        x = pipeline_1f1b(stage_fn, params["block"], acts, axis="pp")
         x = x.reshape(b, l, cfg.d_model)
     else:
         # Block params may still be varying on manual axes the config

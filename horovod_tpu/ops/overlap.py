@@ -144,7 +144,7 @@ def reset() -> None:
 # ---------------------------------------------------------------------------
 # Overlap accounting: collective bytes issued with compute left to hide
 # under vs. total — the trace-time feed for the hvdt_overlap_fraction
-# gauge and bench.py --overlap's JSON.  Recorded at TRACE time (under jit
+# gauge.  Recorded at TRACE time (under jit
 # the compiled program, not this host code, runs the schedule), same
 # path=jit convention as the per-collective instrumentation.
 # ---------------------------------------------------------------------------
@@ -708,7 +708,7 @@ def enable_latency_hiding(mode: Optional[str] = None) -> Optional[str]:
     already present are never duplicated.  Returns the resulting
     ``LIBTPU_INIT_ARGS`` string, or ``None`` when nothing was engaged.
 
-    Called by ``hvd.init()`` and ``bench.py --overlap``; call it before
+    Called by ``hvd.init()``; call it before
     the first jax computation — libtpu reads the env once at backend
     init, so flags added later apply to the NEXT process (warned).
     """

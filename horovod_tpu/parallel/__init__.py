@@ -45,7 +45,6 @@ from .ring_attention import ring_attention  # noqa: F401
 from .pipeline import (  # noqa: F401
     bubble_fraction,
     pipeline_1f1b,
-    pipeline_spmd,
     report_pipeline_mfu,
 )
 from .moe import (  # noqa: F401

@@ -35,8 +35,7 @@ from jax import lax
 
 from ..ops.device import _axis_size_static
 
-__all__ = ["pipeline_1f1b", "pipeline_spmd", "bubble_fraction",
-           "report_pipeline_mfu"]
+__all__ = ["pipeline_1f1b", "bubble_fraction", "report_pipeline_mfu"]
 
 
 def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
@@ -184,19 +183,6 @@ def pipeline_1f1b(stage_fn: Callable[[Any, jax.Array], jax.Array],
         # Only the last stage wrote non-zeros; psum = broadcast from it.
         out = lax.psum(jnp.where(me == p - 1, out, jnp.zeros_like(out)), axis)
     return out
-
-
-def pipeline_spmd(stage_fn: Callable[[Any, jax.Array], jax.Array],
-                  stage_params: Any,
-                  microbatches: jax.Array,
-                  *,
-                  axis: str = "pp",
-                  broadcast_out: bool = True) -> jax.Array:
-    """Compatibility alias for :func:`pipeline_1f1b` (the GPipe-ish
-    single-scan schedule this name used to carry was replaced by the
-    segmented 1F1B clock; same contract, same outputs)."""
-    return pipeline_1f1b(stage_fn, stage_params, microbatches,
-                         axis=axis, broadcast_out=broadcast_out)
 
 
 def report_pipeline_mfu(flops_per_step: float, step_seconds: float,

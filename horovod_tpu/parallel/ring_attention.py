@@ -63,10 +63,7 @@ def _bwd_block_grads(qf, dof, k_blk, v_blk, lse, delta_bhq, mask, scale,
     qf/dof: f32 ``[B, Lq, H, D]``; k_blk/v_blk: raw ``[B, Lk, Hkv, D]``;
     lse: ``[B, H, Lq]``; delta_bhq: ``[B, H, Lq]``; mask: broadcastable
     to ``[B, H, Lq, Lk]`` or None (fully visible); group = H // Hkv.
-
-    Factored out of :func:`_ring_diff_bwd`'s scan body so A/B harnesses
-    (tools/ring_ab.py) time the PRODUCTION step math by import instead
-    of an inline copy that could silently drift.
+    Called from :func:`_ring_diff_bwd`'s scan body.
     """
     f32 = jnp.float32
     ks = k_blk.astype(f32)

@@ -33,7 +33,7 @@ from millions of users").  Layering, bottom up:
 Entry points: ``python -m horovod_tpu.serve`` and ``hvdtrun serve``
 (:func:`main`; ``--replicas``/``--autoscale`` switch to the elastic
 control plane); in-process embedding via :class:`ModelServer` directly
-(the test rig and bench.py --serve do this).
+(the test rig does this).
 """
 
 from .batcher import (BackpressureError, DispatcherDied,  # noqa: F401

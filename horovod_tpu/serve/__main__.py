@@ -125,7 +125,7 @@ def strip_control_flags(argv):
 
 def build_server(args):
     """Assemble (server, feature_shape) from parsed args — split out so
-    tests and bench.py can drive the exact CLI path in-process."""
+    tests can drive the exact CLI path in-process."""
     import jax
     import numpy as np
 

@@ -1,7 +1,6 @@
 """Step-pipeline layer: buffer donation + persistent compilation cache.
 
-Two cheap, always-correct levers that BENCH_r05 showed the framework was
-leaving on the table:
+Two cheap, always-correct levers:
 
 * **Donation** — a train step is a pipeline ``(params, opt_state) ->
   (params, opt_state)``; without ``donate_argnums`` XLA double-buffers
@@ -9,11 +8,11 @@ leaving on the table:
   residents) and inserts defensive copies between steps.
   :func:`donated_step` is ``jax.jit`` with the params/opt-state
   positions donated by default — the call-shape every train step in
-  bench.py and examples/ uses.
+  benchmark/ and examples/ uses.
 
-* **Persistent compilation cache** — the measured bench run pays
-  ~15.8 s compile + ~14.7 s warmup on EVERY invocation for a program
-  that hasn't changed.  :func:`enable_compilation_cache` points JAX's
+* **Persistent compilation cache** — without one, every invocation
+  compiles again a program that hasn't changed (set-up time, ``setup_s``
+  of a benchmark cell).  :func:`enable_compilation_cache` points JAX's
   persistent cache at a directory so the second run of the same program
   skips XLA entirely.  ``JAX_COMPILATION_CACHE_DIR`` places it from
   outside and wins; below it the ``HVDT_COMPILATION_CACHE`` knob
@@ -54,7 +53,7 @@ def enable_compilation_cache(path: Optional[str] = None, *,
        from outside the program is the one every process uses.
     2. ``path``, else the ``HVDT_COMPILATION_CACHE`` knob (what
        ``hvdtrun --compilation-cache-dir`` forwards).  "off" disables.
-    3. ``default`` — the root entry points (chip_smoke.py, bench.py,
+    3. ``default`` — the root entry points (chip_smoke.py,
        bench_allreduce.py) pass ``<checkout>/.xla_cache``: a fixed path,
        because the path is part of what a later run must find again.
 

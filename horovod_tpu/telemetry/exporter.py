@@ -47,7 +47,7 @@ KV_PREFIX = "/telemetry/"
 def snapshot_dict(registry: Optional[MetricsRegistry] = None
                   ) -> Dict[str, Any]:
     """Compact, JSON-able roll-up of the headline training metrics — what
-    workers publish to the driver and bench.py embeds in its output."""
+    workers publish to the driver."""
     reg = registry if registry is not None else default_registry()
     out: Dict[str, Any] = {}
     # Snapshot schema v2 (tolerant): wall_ts + the current step id let
@@ -414,7 +414,7 @@ def bind_process_gauges(registry: Optional[MetricsRegistry] = None) -> None:
         "hvdt_optimizer_state_bytes to see the ZeRO/remat headroom"
     ).set_function(_hbm_peak)
     # Memory-accounting gauges (fed by step_stats.record_memory_
-    # accounting — ops/zero.py and bench.py report per-rank
+    # accounting — ops/zero.py reports per-rank
     # post-sharding bytes): registered here so they exist on /metrics
     # from init, NaN until the training loop reports.
     from .step_stats import _MEMORY_GAUGE_DOCS

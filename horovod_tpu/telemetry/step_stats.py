@@ -3,13 +3,13 @@
 The aggregate layer above per-collective instrumentation — the numbers
 the TPU-pod scaling study says are binding at scale (goodput, MFU,
 straggler ranks) rather than per-op traces.  A :class:`StepTimer` wraps
-the training loop (bench.py, ``step_pipeline.donated_step`` consumers,
+the training loop (``step_pipeline.donated_step`` consumers,
 user loops) and publishes:
 
 * ``hvdt_step_time_seconds``  — host-fenced step duration summary
 * ``hvdt_examples_per_sec``   — windowed throughput gauge
 * ``hvdt_mfu``                — model-flops utilization gauge, from the
-  caller's flops-per-step (bench.py reuses its XLA cost-analysis flops)
+  caller's flops-per-step
   against the device generation's peak (:func:`peak_flops_for`)
 * ``hvdt_steps_total``        — monotonic step counter
 
@@ -42,8 +42,7 @@ __all__ = ["StepTimer", "GoodputLedger", "peak_flops_for",
            "expected_vs_observed_doc"]
 
 # bf16 peak FLOP/s and HBM byte/s by TPU generation (device_kind
-# substring, lowercase) — promoted from bench.py so MFU math has one
-# home (bench imports this table).
+# substring, lowercase), so MFU math has one home.
 PEAK_BY_DEVICE_KIND = (
     ("v6", 918e12, 1640e9), ("trillium", 918e12, 1640e9),
     ("v5p", 459e12, 2765e9),
@@ -76,7 +75,7 @@ def peak_flops_for(device_kind: str):
 class StepTimer:
     """Times training steps and publishes throughput/MFU metrics.
 
-    Usage (bench.py / custom loops)::
+    Usage (custom loops)::
 
         timer = StepTimer(examples_per_step=batch,
                           flops_per_step=cost["flops"],
@@ -85,9 +84,9 @@ class StepTimer:
             with timer.step():
                 run_one_step(batch)   # must end with a host fence
 
-    or call :meth:`observe` with externally measured durations (bench
-    times whole iters and divides).  ``straggler`` optionally chains a
-    :class:`~horovod_tpu.telemetry.straggler.StragglerMonitor` so the
+    or call :meth:`observe` with externally measured durations (a loop
+    that times whole iters and divides).  ``straggler`` optionally chains
+    a :class:`~horovod_tpu.telemetry.straggler.StragglerMonitor` so the
     cross-rank skew check rides the same observation stream.
     """
 
@@ -676,10 +675,9 @@ def maybe_publish_expected_cost(**kwargs) -> Optional[PerfExpectation]:
 
 def expected_vs_observed_doc(registry: Optional[MetricsRegistry] = None
                              ) -> Optional[Dict[str, object]]:
-    """The compact predicted-vs-observed roll-up bench.py embeds in its
-    telemetry JSON: predicted comm seconds, observed comm-exposed
-    seconds, the deviation ratio, and per-kind anomaly counts.  None
-    when no expectation was published."""
+    """The compact predicted-vs-observed roll-up: predicted comm
+    seconds, observed comm-exposed seconds, the deviation ratio, and
+    per-kind anomaly counts.  None when no expectation was published."""
     exp = get_expectation()
     if exp is None:
         return None

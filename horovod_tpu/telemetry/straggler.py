@@ -211,7 +211,7 @@ class StragglerMonitor:
             return None
         # Size 1 still rides the controller (single-rank collectives are
         # the identity): the probe's own wire accounting stays visible
-        # in the registry, and single-process harnesses (bench.py)
+        # in the registry, and single-process harnesses
         # exercise the full instrumented path.
         import numpy as np
 

@@ -642,19 +642,6 @@ class TestSimulate:
         assert doc["trace"] == "step_function"
         assert "goodput_fraction" in doc and "decisions" not in doc
 
-    def test_bench_fleet_flag_emits_acceptance_numbers(self, capsys):
-        import argparse
-        import importlib
-
-        bench = importlib.import_module("bench")
-        bench._run_fleet_bench(argparse.Namespace(
-            fleet="step_function", fleet_pods=4, fleet_fault_plan=None))
-        doc = json.loads(capsys.readouterr().out.strip())
-        assert doc["metric"] == "fleet_trace_replay"
-        for key in ("goodput_fraction", "slo_compliance", "reclaims",
-                    "drains", "dropped_requests"):
-            assert key in doc
-
 
 # ---------------------------------------------------------------------------
 # Engagement + CLI/config/metrics/report wiring

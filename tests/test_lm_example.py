@@ -1,6 +1,5 @@
-"""Smoke tests for the flagship LM benchmark CLI
-(examples/jax_transformer_lm.py) — the perf-evidence driver
-(tools/tpu_ab.py legs) should not be the only thing exercising it.
+"""Smoke tests for the flagship LM example's CLI
+(examples/jax_transformer_lm.py), which no benchmark cell runs.
 Analog of the reference CI running its example scripts as smoke tests
 (ref: .buildkite/gen-pipeline.sh:157-189)."""
 

@@ -263,7 +263,7 @@ class TestResNet101AndVGG:
     """The reference's published benchmark trio (docs/benchmarks.rst:8-43)
     is ResNet-101 / VGG-16 / Inception — depth-101 layouts and VGG-16
     here complete the zoo's benchmark parity (ResNet-101 is the model
-    behind BASELINE.md's 1656.82 img/s number)."""
+    behind the reference's headline images/s figure there)."""
 
     def test_resnet101_forward_and_param_count(self):
         from horovod_tpu.models import (ResNetConfig, resnet101_init,

@@ -250,22 +250,3 @@ class TestRingPallasEnvKnob:
             mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
             check_vma=False))(q)
         assert calls   # the per-step kernel actually ran
-
-
-def test_ring_ab_tool_correctness_gate(capsys):
-    """tools/ring_ab.py re-states the jnp ring-step math inline (so the
-    A/B times exactly what ring_attention runs); if that copy drifts
-    from the kernels, its correctness gate must catch it — and this test
-    catches the drift at suite time."""
-    import importlib
-    import json
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    ring_ab = importlib.import_module("tools.ring_ab")
-    ring_ab.run_shape(1, 128, 2, 16, iters=1)
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    rec = json.loads(line)
-    assert rec["bwd_correctness_ok"], rec
-    assert rec["fwd_pallas_ms"] > 0 and rec["bwd_jnp_ms"] > 0

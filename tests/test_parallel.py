@@ -41,7 +41,7 @@ from horovod_tpu.parallel import (
     logical_to_mesh,
     transformer_rules,
     ring_attention,
-    pipeline_spmd,
+    pipeline_1f1b,
     moe_dispatch_combine,
 )
 
@@ -189,7 +189,7 @@ class TestPipeline:
 
         mesh = make_mesh(pp=4, devices=jax.devices()[:4])
         out = shard_map(
-            lambda w, x: pipeline_spmd(
+            lambda w, x: pipeline_1f1b(
                 lambda wp, xp: stage(wp[0], xp), w, x),
             mesh=mesh, in_specs=(P("pp"), P(None)), out_specs=P(None))(ws, xs)
 
@@ -207,7 +207,7 @@ class TestPipeline:
 
         def loss(ws):
             out = shard_map(
-                lambda w, x: pipeline_spmd(lambda wp, xp: xp @ wp[0], w, x),
+                lambda w, x: pipeline_1f1b(lambda wp, xp: xp @ wp[0], w, x),
                 mesh=mesh, in_specs=(P("pp"), P(None)),
                 out_specs=P(None))(ws, xs)
             return jnp.sum(out ** 2)

@@ -349,25 +349,3 @@ class TestPricing4D:
     def test_capacity_floor(self):
         assert moe_capacity(8, 2, top_k=1, capacity_factor=1.0) == 4
         assert moe_capacity(1, 64, top_k=1, capacity_factor=1.0) == 1
-
-
-class TestBenchLegs4D:
-    """The --moe/--pipeline bench legs parse and feed the autotune
-    seeds (the fast in-process smoke — the full sweep rides bench.py)."""
-
-    def test_autotune_seed_keys_round_trip(self, tmp_path, monkeypatch):
-        import json
-
-        from horovod_tpu.autotune import (_env_capacity_factor,
-                                          _env_microbatches)
-
-        moe = tmp_path / "moe.json"
-        moe.write_text(json.dumps({"capacity_factor_at_peak": 1.5}))
-        pipe = tmp_path / "pipe.json"
-        pipe.write_text(json.dumps({"microbatches_at_peak": 16}))
-        monkeypatch.delenv("HVDT_MOE_CAPACITY_FACTOR", raising=False)
-        monkeypatch.delenv("HVDT_PIPELINE_MICROBATCHES", raising=False)
-        monkeypatch.setenv("HVDT_AUTOTUNE_MOE_SEED", str(moe))
-        monkeypatch.setenv("HVDT_AUTOTUNE_PIPELINE_SEED", str(pipe))
-        assert _env_capacity_factor() == 1.5
-        assert _env_microbatches() == 16

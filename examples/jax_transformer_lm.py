@@ -72,6 +72,18 @@ PRESETS = {
                                "evabyte.json"),
         seq=32768, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
         dp=1, tp=1),
+    # granite-4.0-h-micro (IBM, 3B dense hybrid) as one whole period on one
+    # chip: the benchmark's configuration granite_4_0_h_micro (cell
+    # granite_h_micro_s8192): nine Mamba-2 state-space layers to one
+    # attention layer without a position term, Granite's four multipliers,
+    # a tied head over 12,544 of 100,352 rows; 772M parameters, 11.5 GiB of
+    # training state.
+    "granite_h_micro": dict(
+        published=os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               os.pardir, "benchmark", "configs",
+                               "granite_4_0_h_micro.json"),
+        seq=8192, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
+        dp=1, tp=1),
 }
 
 
@@ -99,7 +111,8 @@ def main():
                         "config.json describes (models.config_from_"
                         "published): per-kind heads, windows and rotary "
                         "settings, dense and sparse feed-forwards, EVA "
-                        "attention, several prediction heads.  The "
+                        "attention, Mamba-2 layers, several prediction "
+                        "heads.  The "
                         "file's own layers / experts / experts_first / "
                         "vocab / heads / heads_first keys, where present, "
                         "cut it to this device's share; the size flags "

@@ -13,6 +13,7 @@ from .transformer import (  # noqa: F401
     TransformerConfig,
     LayerKind,
     LinearMixer,
+    StateSpaceMixer,
     Eva,
     Rope,
     Experts,

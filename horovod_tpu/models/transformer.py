@@ -38,6 +38,8 @@ from ..ops.attention import attention
 from ..ops.eva import eva_attention, eva_visible_pairs
 from ..ops.gated_delta import gated_delta_net, scan_macs_per_token
 from ..ops.pallas_kernels import rope
+from ..ops.ssd import mamba2_mixer
+from ..ops.ssd import scan_macs_per_token as ssd_macs_per_token
 from ..parallel.moe import (ROUTE_SAVED, moe_dispatch_combine,
                             moe_held_experts)
 from ..parallel.pipeline import pipeline_1f1b
@@ -45,7 +47,8 @@ from ..parallel.ring_attention import ring_attention
 from ..quant import fp8 as _fp8
 
 __all__ = [
-    "TransformerConfig", "LayerKind", "LinearMixer", "Eva", "Rope", "Experts",
+    "TransformerConfig", "LayerKind", "LinearMixer", "StateSpaceMixer", "Eva",
+    "Rope", "Experts",
     "config_from_published", "transformer_init", "transformer_apply",
     "transformer_loss", "transformer_block_diffusion_loss",
     "block_diffusion_corrupt", "transformer_logical_axes",
@@ -100,6 +103,36 @@ class LinearMixer:
 
 
 @dataclasses.dataclass(frozen=True)
+class StateSpaceMixer:
+    """The sizes of a Mamba-2 mixer (``ops/ssd.py``): ``heads`` heads of
+    ``head_dim`` over a state of ``state``, B and C shared by the heads of
+    each of ``groups`` groups, a causal depthwise convolution of ``conv``
+    taps (with a bias where ``conv_bias``) over the x, B and C channels,
+    the scan in chunks of ``chunk`` tokens."""
+    heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    conv: int = 4
+    conv_bias: bool = True
+    chunk: int = 256
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        return self.inner + 2 * self.groups * self.state
+
+    @property
+    def sizes(self) -> Dict[str, int]:
+        """The head counts and sizes, as ``ops.ssd`` names them."""
+        return dict(heads=self.heads, head_dim=self.head_dim,
+                    state=self.state, groups=self.groups, chunk=self.chunk)
+
+
+@dataclasses.dataclass(frozen=True)
 class Eva:
     """The sizes of EVA attention (``ops/eva.py``): exact softmax inside
     aligned windows of ``window`` positions, joined in one softmax with a
@@ -115,10 +148,12 @@ class Eva:
 class LayerKind:
     """One kind of layer of a pattern: its mixer (softmax attention with
     its own head counts, window and rotary settings, with ``eva`` set under
-    EVA's two masks in place of ``window``'s; or, with ``linear`` set, the
-    Gated DeltaNet of those sizes, which reads none of the attention
-    fields) and its feed-forward (dense SwiGLU of width ``d_ff``, or with
-    ``sparse`` the configuration's expert layer, ``TransformerConfig.moe``).
+    EVA's two masks in place of ``window``'s, with ``rope`` None without any
+    position term; or, with ``linear`` set, the Gated DeltaNet of those
+    sizes, or with ``ssm`` the Mamba-2 mixer of those, which read none of
+    the attention fields) and its feed-forward (dense SwiGLU of width
+    ``d_ff``, or with ``sparse`` the configuration's expert layer,
+    ``TransformerConfig.moe``).
     ``heads`` / ``kv_heads`` are the heads held here: heads ``heads_first
     .. heads_first + heads - 1`` of the model's where this is one chip's
     share of a layer whose attention is divided by heads (no head's
@@ -127,10 +162,11 @@ class LayerKind:
     kv_heads: int
     d_ff: int = 0
     window: Optional[int] = None     # query i sees keys i - window < j <= i
-    rope: Rope = Rope()
+    rope: Optional[Rope] = Rope()
     sparse: bool = False
     linear: Optional[LinearMixer] = None
     eva: Optional[Eva] = None
+    ssm: Optional[StateSpaceMixer] = None
     heads_first: int = 0
 
 
@@ -235,6 +271,12 @@ class TransformerConfig:
     # the heads of a chunk of rows exist at a time), not vocabulary rows.
     pred_heads: int = 1
     norm_eps: float = 1e-6       # every RMSNorm's epsilon
+    # Constants some published models scale by (Granite's four).  At these
+    # defaults none of them is an operation of the program.
+    embedding_multiplier: float = 1.0    # the embedded rows times it
+    residual_multiplier: float = 1.0     # each sublayer's output times it
+    attention_multiplier: float = 0.0    # the score scale; 0: head_dim^-0.5
+    logits_scaling: float = 1.0          # the logits divided by it
 
     def __post_init__(self):
         if isinstance(self.out_gate, bool):
@@ -247,7 +289,7 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.heads)
         if self.diffusion_block and (
                 self.sp > 1 or self.pp > 1 or any(
-                    k.window or k.linear or k.eva
+                    k.window or k.linear or k.eva or k.ssm
                     for k in self.leading + self.period)):
             raise ValueError(
                 "diffusion over blocks runs full softmax attention with sp "
@@ -330,15 +372,21 @@ def config_from_published(published: Dict[str, Any], *,
     ``published`` holds the keys of the model's ``config.json`` under their
     own names: ``hidden_size``, ``head_dim``, ``num_attention_heads`` or
     ``num_attention_heads_per_layer``, ``num_key_value_heads``,
-    ``layer_types`` (``full_attention`` / ``sliding_attention``, with
-    ``sliding_window`` / ``linear_attention``, a Gated DeltaNet of
-    ``linear_num_key_heads``, ``linear_num_value_heads``,
-    ``linear_key_head_dim``, ``linear_value_head_dim``,
-    ``linear_conv_kernel_dim``) or ``full_attention_interval`` (every
+    ``layer_types`` (``full_attention`` or ``attention`` /
+    ``sliding_attention``, with ``sliding_window`` / ``linear_attention``,
+    a Gated DeltaNet of ``linear_num_key_heads``,
+    ``linear_num_value_heads``, ``linear_key_head_dim``,
+    ``linear_value_head_dim``, ``linear_conv_kernel_dim`` / ``mamba``, a
+    Mamba-2 mixer of ``mamba_n_heads``, ``mamba_d_head``,
+    ``mamba_d_state``, ``mamba_n_groups``, ``mamba_d_conv``,
+    ``mamba_conv_bias``, ``mamba_chunk_size``; any other entry is refused)
+    or ``full_attention_interval`` (every
     n-th layer full attention, the others linear, as ``transformers``
     derives ``layer_types`` from it), ``rope_parameters`` (by layer type,
     or one group) or ``rope_theta`` and ``partial_rotary_factor`` at top
-    level, ``mlp_layer_types`` (``dense`` of ``intermediate_size`` /
+    level, ``position_embedding_type`` (``rope``, or ``nope``: attention
+    without a position term), ``mlp_layer_types`` (``dense`` of
+    ``shared_intermediate_size``, else of ``intermediate_size`` /
     ``sparse``: ``num_experts`` of ``moe_intermediate_size``,
     ``num_experts_per_tok`` picked, ``norm_topk_prob``,
     ``moe_routed_scaling_factor``, a shared expert of
@@ -348,7 +396,10 @@ def config_from_published(published: Dict[str, Any], *,
     ``window_size`` and ``chunk_size``, its learned vectors started at
     ``init_std``), ``num_pred_heads`` (prediction heads a row),
     ``norm_add_unit_offset`` (every RMSNorm scales by 1 + gain),
-    ``rms_norm_eps``.  No width is an argument.  The cut: the first
+    ``rms_norm_eps``, and the four constants ``embedding_multiplier``,
+    ``residual_multiplier``, ``attention_multiplier`` (the score scale)
+    and ``logits_scaling`` (the logits' divisor).  No width is an
+    argument.  The cut: the first
     ``layers`` layers (the leading ones and at least a period), ``experts``
     of each sparse layer's experts from ``experts_first`` on (the router
     keeps its width), the first ``vocab`` rows of the vocabulary, ``heads``
@@ -376,8 +427,23 @@ def config_from_published(published: Dict[str, Any], *,
     layer_types = c.get("layer_types") or [
         "linear_attention" if interval and (i + 1) % interval
         else "full_attention" for i in range(depth)]
+    known = ("full_attention", "attention", "sliding_attention",
+             "linear_attention", "mamba")
+    unknown = sorted(set(layer_types) - set(known))
+    if unknown:
+        raise ValueError(f"layer_types holds {unknown}: one of {known}")
+    positions = c.get("position_embedding_type", "rope")
+    if positions not in ("rope", "nope"):
+        raise ValueError(
+            f"position_embedding_type={positions!r}: 'rope' or 'nope'")
+    for unbuilt in ("num_local_experts", "mamba_proj_bias"):
+        # experts beside the shared feed-forward; a bias on w_in and w_out
+        if c.get(unbuilt):
+            raise ValueError(f"{unbuilt}={c[unbuilt]!r} is not built yet")
     mlp_types = c.get("mlp_layer_types") or \
         ["sparse" if c.get("num_experts") else "dense"] * depth
+    dense_width = c.get("shared_intermediate_size") or \
+        c.get("intermediate_size")
     ropes = c.get("rope_parameters") or {
         "rope_theta": c["rope_theta"],
         "partial_rotary_factor": c.get("partial_rotary_factor", 1)}
@@ -388,8 +454,15 @@ def config_from_published(published: Dict[str, Any], *,
         value_dim=c["linear_value_head_dim"],
         conv=c["linear_conv_kernel_dim"]) \
         if "linear_attention" in layer_types else None
+    ssm = StateSpaceMixer(
+        heads=c["mamba_n_heads"], head_dim=c["mamba_d_head"],
+        state=c["mamba_d_state"], groups=c["mamba_n_groups"],
+        conv=c["mamba_d_conv"], conv_bias=bool(c["mamba_conv_bias"]),
+        chunk=c["mamba_chunk_size"]) if "mamba" in layer_types else None
 
-    def rope_of(layer_type: str) -> Rope:
+    def rope_of(layer_type: str) -> Optional[Rope]:
+        if positions == "nope":
+            return None
         r = ropes.get(layer_type, ropes)
         rotated = int(head_dim * r.get("partial_rotary_factor", 1))
         yarn = r.get("rope_type", "default") == "yarn"
@@ -407,11 +480,13 @@ def config_from_published(published: Dict[str, Any], *,
 
     def kind_of(i: int) -> LayerKind:
         feed_forward = dict(
-            d_ff=0 if mlp_types[i] == "sparse" else c["intermediate_size"],
+            d_ff=0 if mlp_types[i] == "sparse" else dense_width,
             sparse=mlp_types[i] == "sparse")
         if layer_types[i] == "linear_attention":
             return LayerKind(heads=0, kv_heads=0, linear=linear,
                              **feed_forward)
+        if layer_types[i] == "mamba":
+            return LayerKind(heads=0, kv_heads=0, ssm=ssm, **feed_forward)
         held = heads or published_heads[i]
         kv_held, ragged = divmod(c["num_key_value_heads"] * held,
                                  published_heads[i])
@@ -454,6 +529,10 @@ def config_from_published(published: Dict[str, Any], *,
     fields.setdefault("out_gate", "head" if c.get("gating") else "")
     fields.setdefault("pred_heads", c.get("num_pred_heads", 1))
     fields.setdefault("norm_eps", float(c.get("rms_norm_eps", 1e-6)))
+    for constant in ("embedding_multiplier", "residual_multiplier",
+                     "attention_multiplier", "logits_scaling"):
+        if constant in c:
+            fields.setdefault(constant, float(c[constant]))
     if c.get("norm_add_unit_offset"):
         fields.setdefault("zero_centered_norm", True)
     return TransformerConfig(
@@ -494,11 +573,48 @@ def _init_linear_mixer(ks, cfg: TransformerConfig, m: LinearMixer) -> Dict:
     }
 
 
+def _init_state_space_mixer(ks, cfg: TransformerConfig,
+                            m: StateSpaceMixer) -> Dict:
+    """The Mamba-2 mixer's leaves (``ops.ssd.mamba2_mixer`` says what each
+    is).  ``a_log`` = log(1 .. heads), ``d_skip`` = 1, the convolution's
+    taps and bias uniform in +-taps^-0.5, as the published model's code
+    initialises them; ``dt_bias`` the inverse softplus of a time step
+    drawn log-uniform in [1e-3, 1e-1], as the Mamba-2 code draws it
+    (arXiv:2405.21060), so that a head's state lives for tens to
+    thousands of tokens and not for one."""
+    d, pd = cfg.d_model, cfg.param_dtype
+    bound = m.conv ** -0.5
+    lo, hi = math.log(1e-3), math.log(1e-1)
+
+    def uniform(shape):
+        return jax.random.uniform(next(ks), shape, minval=-bound,
+                                  maxval=bound).astype(pd)
+
+    p = {
+        "w_in": _init_linear(next(ks), d,
+                             (d, m.inner + m.conv_width + m.heads), pd),
+        "conv": uniform((m.conv, m.conv_width)),
+        "a_log": jnp.log(jnp.arange(1, m.heads + 1, dtype=jnp.float32)
+                         ).astype(pd),
+        "d_skip": jnp.ones((m.heads,), pd),
+        "ssd_norm": jnp.ones((m.inner,), pd),
+        "w_out": _init_linear(next(ks), m.inner, (m.inner, d), pd),
+    }
+    if m.conv_bias:
+        p["conv_bias"] = uniform((m.conv_width,))
+    step = jnp.exp(jax.random.uniform(next(ks), (m.heads,), minval=lo,
+                                      maxval=hi))
+    # softplus(dt_bias) = step
+    p["dt_bias"] = (step + jnp.log(-jnp.expm1(-step))).astype(pd)
+    return p
+
+
 def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
     """One layer of a pattern: its mixer (attention of ``kind``'s sizes,
     with ``wg`` or a query projection twice as wide where the output is
     gated and ``q_norm`` / ``k_norm`` where q and k are normed; or the
-    linear mixer's leaves) and its dense or sparse feed-forward."""
+    linear or the state-space mixer's leaves) and its dense or sparse
+    feed-forward."""
     d, dh, pd = cfg.d_model, cfg.head_dim, cfg.param_dtype
     h, hk = kind.heads, kind.kv_heads
     # Twelve keys serve every layer from before the shared expert's gate;
@@ -508,6 +624,8 @@ def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
     p = {"ln1": _norm_gain(cfg, (d,)), "ln2": _norm_gain(cfg, (d,))}
     if kind.linear is not None:
         p.update(_init_linear_mixer(ks, cfg, kind.linear))
+    elif kind.ssm is not None:
+        p.update(_init_state_space_mixer(ks, cfg, kind.ssm))
     else:
         wide = 2 if cfg.out_gate == "elementwise" else 1
         p.update(
@@ -662,6 +780,13 @@ def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
                         conv=(None, "heads"), a_log=(None,),
                         dt_bias=(None,), gdn_norm=(None,),
                         w_out=("heads", "embed"))
+        elif kind.ssm is not None:
+            # Whole on every tp rank: the gated norm spans the heads.
+            axes.update(w_in=("embed", None), conv=(None, None),
+                        a_log=(None,), d_skip=(None,), dt_bias=(None,),
+                        ssd_norm=(None,), w_out=(None, "embed"))
+            if kind.ssm.conv_bias:
+                axes["conv_bias"] = (None,)
         else:
             axes.update(wq=("embed", "heads"), wk=("embed", "kv"),
                         wv=("embed", "kv"), wo=("heads", "embed"))
@@ -786,9 +911,12 @@ def _qkv_gate(p, x, positions, cfg: TransformerConfig,
         if cfg.qk_norm:
             q = _norm(q.reshape(b, l, h, dh), p["q_norm"], cfg)
             k = _norm(k.reshape(b, l, hk, dh), p["k_norm"], cfg)
-        tables = _rope_tables(positions, kind.rope, dh)
-        q = rope(q, *tables).reshape(b, l, h, dh)
-        k = rope(k, *tables).reshape(b, l, hk, dh)
+        if kind.rope is None:           # no position term
+            q, k = q.reshape(b, l, h, dh), k.reshape(b, l, hk, dh)
+        else:
+            tables = _rope_tables(positions, kind.rope, dh)
+            q = rope(q, *tables).reshape(b, l, h, dh)
+            k = rope(k, *tables).reshape(b, l, hk, dh)
     return q, k, v, gate
 
 
@@ -814,7 +942,8 @@ def _attention(p, x, positions, cfg: TransformerConfig,
                               window=kind.eva.window, chunk=kind.eva.chunk)
         else:
             o = attention(q, k, v, window=kind.window,
-                          block_diffusion=cfg.diffusion_block or None)
+                          block_diffusion=cfg.diffusion_block or None,
+                          scale=cfg.attention_multiplier or None)
     with jax.named_scope("hvdt.attention.gate"):
         if cfg.out_gate == "head":
             # A gate a head, from the layer's normed input.
@@ -929,6 +1058,14 @@ def remat_from_env(cfg: TransformerConfig,
     return dataclasses.replace(cfg, remat=True, remat_policy=policy_name)
 
 
+def _residual(x, y, cfg: TransformerConfig):
+    """The residual stream plus a sublayer's output, times the
+    configuration's ``residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
 def _block(p, x, positions, cfg: TransformerConfig,
            kind: Optional[LayerKind] = None):
     """One layer of ``kind`` (None: the uniform configuration's)."""
@@ -941,16 +1078,20 @@ def _block(p, x, positions, cfg: TransformerConfig,
         with jax.named_scope("hvdt.gdn"):
             a = gated_delta_net(_norm(x, p["ln1"], cfg), p, proj=_proj,
                                 **kind.linear.sizes)
+    elif kind.ssm is not None:
+        with jax.named_scope("hvdt.ssd"):
+            a = mamba2_mixer(_norm(x, p["ln1"], cfg), p, proj=_proj,
+                             eps=cfg.norm_eps, **kind.ssm.sizes)
     else:
         with jax.named_scope("hvdt.attention"):
             a = _attention(p, _norm(x, p["ln1"], cfg), positions, cfg, kind)
-    x = x + a
+    x = _residual(x, a, cfg)
     with jax.named_scope("hvdt.mlp"):
         if kind.sparse:
             y, _ = _moe_mlp(p, _norm(x, p["ln2"], cfg), cfg)
         else:
             y = _mlp(p, _norm(x, p["ln2"], cfg))
-    return x + y
+    return _residual(x, y, cfg)
 
 
 def _layer_fn(positions, cfg: TransformerConfig,
@@ -1033,6 +1174,8 @@ def transformer_hidden(params: Dict, tokens: jax.Array,
         positions = positions % (l // 2)
     with jax.named_scope("hvdt.embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
     # Manual-island axes make activations varying (e.g. the MoE alltoall);
     # pre-cast so the scan-over-layers carry is type-stable under vma.
     from ..parallel.sharding import pcast_to_union
@@ -1092,14 +1235,18 @@ def _head_matrix(params: Dict, cfg: TransformerConfig) -> jax.Array:
 
 
 def _head(params: Dict, x: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    """The output projection: hidden states to f32 logits."""
-    return (x @ _head_matrix(params, cfg).astype(x.dtype).T
-            ).astype(jnp.float32)
+    """The output projection: hidden states to f32 logits, over the
+    configuration's ``logits_scaling``."""
+    logits = (x @ _head_matrix(params, cfg).astype(x.dtype).T
+              ).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
-                  chunk: int, weights: Optional[jax.Array] = None
-                  ) -> jax.Array:
+                  chunk: int, weights: Optional[jax.Array] = None,
+                  divisor: float = 1.0) -> jax.Array:
     """Cross entropy without the [tokens, vocab] logits: scan over vocab
     chunks with an online logsumexp, checkpointed so the backward pass
     recomputes each chunk's logits instead of saving them.  Peak memory
@@ -1108,7 +1255,8 @@ def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
     dense f32 logits at batch 128 x seq 512 x 30k vocab are 8 GB alone).
     Numerics match the dense path up to fp reassociation.  The mean over
     the tokens, or with ``weights`` [b, t] the sum of each token's term
-    times its weight over their number."""
+    times its weight over their number.  The logits are divided by
+    ``divisor`` (a configuration's ``logits_scaling``)."""
     b, t, d = x.shape
     vocab = embed.shape[0]
     n_chunks = -(-vocab // chunk)
@@ -1124,6 +1272,8 @@ def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
         m, s, tl = carry
         wc, ci = wc_ci
         logits = (xf @ wc.T).astype(jnp.float32)        # [N, chunk]
+        if divisor != 1.0:
+            logits = logits / divisor
         base = ci * chunk
         valid = (jnp.arange(chunk) + base) < vocab
         logits = jnp.where(valid[None, :], logits, -jnp.inf)
@@ -1154,7 +1304,8 @@ def _chunked_xent(x: jax.Array, embed: jax.Array, targets: jax.Array,
 
 
 def _multi_target_xent(x: jax.Array, head: jax.Array, tokens: jax.Array,
-                       heads: int, chunk: int) -> jax.Array:
+                       heads: int, chunk: int,
+                       divisor: float = 1.0) -> jax.Array:
     """The loss of ``heads`` prediction heads a row: x [b, l, d] the final
     hidden rows, head [heads x vocab, d], tokens [b, l].  Head m of row i
     is scored against token i + 1 + m; the mean of -log softmax over every
@@ -1176,8 +1327,10 @@ def _multi_target_xent(x: jax.Array, head: jax.Array, tokens: jax.Array,
 
     def body(total, chunk_of):
         xc, tc, vc = chunk_of
-        logp = jax.nn.log_softmax(
-            (xc @ w.T).astype(jnp.float32).reshape(size, heads, vocab), -1)
+        logits = (xc @ w.T).astype(jnp.float32).reshape(size, heads, vocab)
+        if divisor != 1.0:
+            logits = logits / divisor
+        logp = jax.nn.log_softmax(logits, -1)
         ll = jnp.take_along_axis(logp, tc[..., None], -1)[..., 0]
         return total - jnp.where(vc, ll, 0.0).sum(), None
 
@@ -1214,10 +1367,12 @@ def transformer_loss(params: Dict, tokens: jax.Array,
     with jax.named_scope("hvdt.loss"):
         if cfg.pred_heads > 1:
             return _multi_target_xent(x, _head_matrix(params, cfg), tokens,
-                                      cfg.pred_heads, cfg.loss_chunk)
+                                      cfg.pred_heads, cfg.loss_chunk,
+                                      cfg.logits_scaling)
         if cfg.loss_chunk:
             return _chunked_xent(x[:, :-1], _head_matrix(params, cfg),
-                                 targets, cfg.loss_chunk)
+                                 targets, cfg.loss_chunk,
+                                 divisor=cfg.logits_scaling)
         logits = _head(params, x, cfg)[:, :-1]
         logp = jax.nn.log_softmax(logits, -1)
         ll = jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
@@ -1276,7 +1431,8 @@ def transformer_block_diffusion_loss(params: Dict, tokens: jax.Array,
         weights = masked / jnp.repeat(t, block, axis=1)
         if cfg.loss_chunk:
             return _chunked_xent(x, _head_matrix(params, cfg), tokens,
-                                 cfg.loss_chunk, weights)
+                                 cfg.loss_chunk, weights,
+                                 cfg.logits_scaling)
         logp = jax.nn.log_softmax(_head(params, x, cfg), -1)
         ll = jnp.take_along_axis(logp, tokens[..., None], -1)[..., 0]
         return -(ll * weights).mean()
@@ -1304,12 +1460,14 @@ def _uniform_only(cfg: TransformerConfig, what: str) -> None:
     """The paged serving functions scan ``params["block"]`` with one kind
     of layer and one cache shape: a layer pattern (kinds with their own
     heads, windows, experts) is refused here until serving learns it."""
-    if cfg.period or cfg.out_gate or not cfg.tie_head:
+    if cfg.period or cfg.out_gate or not cfg.tie_head or (
+            cfg.embedding_multiplier, cfg.residual_multiplier,
+            cfg.attention_multiplier, cfg.logits_scaling) != (1, 1, 0, 1):
         raise NotImplementedError(
             f"{what} takes uniform configurations only: serving a "
             "layer-pattern model (per-kind heads and windows, an output "
-            "gate, an untied head) is not built yet; train it with "
-            "transformer_loss")
+            "gate, an untied head, a state-space mixer, multipliers of its "
+            "own) is not built yet; train it with transformer_loss")
 
 
 def _masked_softmax_attn(q, keys, vals, mask):
@@ -1488,8 +1646,9 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
     """Approximate forward-pass matmul FLOPs per token (for MFU metrics):
     the full score square, a window at its width, the output gate's
     projection, a linear mixer's projections, convolution and chunked
-    scan; of a sparse layer the router, a token's picks that land on held
-    experts in expectation and the shared expert with its gate.  Under
+    scan, a state-space mixer's likewise; of a sparse layer the router, a
+    token's picks that land on held experts in expectation and the shared
+    expert with its gate.  Under
     diffusion over blocks a token is two rows of every layer (the noisy
     and the clean stream) and one of the head.  An EVA layer: the pairs
     its two masks show a row of a ``max_seq`` sequence, and the pooling.
@@ -1503,6 +1662,11 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
             return 2 * (d * (2 * kw + 2 * vw + 2 * m.value_heads)
                         + m.conv * (2 * kw + vw) + vw * d
                         + scan_macs_per_token(**m.sizes))
+        m = kind.ssm
+        if m is not None:
+            return 2 * (d * (m.inner + m.conv_width + m.heads)
+                        + m.conv * m.conv_width + m.inner * d
+                        + ssd_macs_per_token(**m.sizes))
         h, hk = kind.heads, kind.kv_heads
         gate = {"": 0, "head": h, "elementwise": h * dh}[cfg.out_gate]
         attn_proj = 2 * d * (h * dh + 2 * hk * dh + h * dh + gate)

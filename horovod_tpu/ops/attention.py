@@ -132,17 +132,19 @@ def kernel_plan(b: int, l: int, h: int, hk: int):
 
 
 def _xla_attention(q, k, v, causal: bool, window: Optional[int] = None,
-                   block_diffusion: Optional[int] = None):
+                   block_diffusion: Optional[int] = None,
+                   scale: Optional[float] = None):
     """Attention as XLA fuses it: the ``[B, H, L, L]`` scores exist, in
     f32 out of bf16 operands.  Not the f32 oracle
     (``pallas_kernels.attention_reference``) and not the paged-slot
     softmax of the serving path: this is what training runs wherever the
     kernel does not.  ``window`` is the kernels' mask: query i sees keys
     i - window < j <= i; ``block_diffusion`` theirs too
-    (``pallas_kernels.block_diffusion_mask``)."""
+    (``pallas_kernels.block_diffusion_mask``); ``scale`` theirs too."""
     l, h, dh = q.shape[1], q.shape[2], q.shape[3]
     hk = k.shape[2]
-    scale = dh ** -0.5
+    if scale is None:
+        scale = dh ** -0.5
     if h != hk:
         k = jnp.repeat(k, h // hk, axis=2)
         v = jnp.repeat(v, h // hk, axis=2)
@@ -163,7 +165,8 @@ def _xla_attention(q, k, v, causal: bool, window: Optional[int] = None,
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
-              block_diffusion: Optional[int] = None) -> jax.Array:
+              block_diffusion: Optional[int] = None,
+              scale: Optional[float] = None) -> jax.Array:
     """Self-attention of a sequence this device holds whole.
 
     q: ``[B, L, H, D]``; k, v: ``[B, L, Hkv, D]`` with ``Hkv`` dividing
@@ -174,7 +177,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``block_diffusion`` (a block length, in place of both): the L rows
     are two streams of one sequence, [noisy ; clean], under
     ``pallas_kernels.block_diffusion_mask``, on every path; the policy
-    is asked of the L rows the call sees."""
+    is asked of the L rows the call sees.  ``scale`` multiplies the scores
+    (None: ``D ** -0.5``)."""
     if window is not None and not causal:
         raise ValueError("a window is a causal mask's: pass causal=True")
     if block_diffusion is not None and window is not None:
@@ -184,12 +188,13 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if plan is None or (block_diffusion is not None and not
                         pallas_kernels.block_diffusion_tiles(
                             l, block_diffusion)):
-        return _xla_attention(q, k, v, causal, window, block_diffusion)
+        return _xla_attention(q, k, v, causal, window, block_diffusion,
+                              scale)
     # Pallas fused attention: O(L·D) HBM traffic instead of a
     # materialized [B,H,L,L] score matrix (ops/pallas_kernels.py).
     kernel = functools.partial(pallas_kernels.flash_attention,
                                causal=causal, window=window,
-                               block_diffusion=block_diffusion)
+                               block_diffusion=block_diffusion, scale=scale)
     if plan == "direct":
         return kernel(q, k, v)
     # GSPMD-auto mesh: Mosaic kernels can't be auto-partitioned, so

@@ -53,7 +53,7 @@ norm a head, the output projection), each part under its own scope
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -67,21 +67,25 @@ __all__ = ["CHUNK", "causal_conv", "gated_delta_rule", "gated_rmsnorm",
 CHUNK = 64
 
 
-def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """A causal depthwise convolution, no bias: x [B, L, C], w [taps, C];
-    ``y_t = sum_i w[i] x[t - (taps - 1) + i]`` with zeros left of the
-    sequence.  Shifted multiply-adds: each tap's slice of the padded input
-    is widened to float32 inside the one fusion that sums them.  Measured
-    on the v5e at [1, 16384, 8192] bf16 with the silu, ms forward /
-    forward + backward (PERF.md, PR 33): this form 2.00 / 7.03; the padded
-    input widened to float32 first 5.29 / 11.36; rolls and a mask 7.06 /
-    17.68; ``lax.conv_general_dilated`` with one channel a group 6.55
-    forward."""
+def causal_conv(x: jax.Array, w: jax.Array,
+                bias: Optional[jax.Array] = None) -> jax.Array:
+    """A causal depthwise convolution: x [B, L, C], w [taps, C], ``bias``
+    [C] or none; ``y_t = sum_i w[i] x[t - (taps - 1) + i] + bias`` with
+    zeros left of the sequence.  Shifted multiply-adds: each tap's slice of
+    the padded input is widened to float32 inside the one fusion that sums
+    them.  Measured on the v5e at [1, 16384, 8192] bf16 with the silu, ms
+    forward / forward + backward (PERF.md, PR 33): this form 2.00 / 7.03;
+    the padded input widened to float32 first 5.29 / 11.36; rolls and a
+    mask 7.06 / 17.68; ``lax.conv_general_dilated`` with one channel a
+    group 6.55 forward."""
     taps, l = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     w = w.astype(jnp.float32)
-    return sum(padded[:, i:i + l].astype(jnp.float32) * w[i]
-               for i in range(taps)).astype(x.dtype)
+    y = sum(padded[:, i:i + l].astype(jnp.float32) * w[i]
+            for i in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(x.dtype)
 
 
 def _on_tpu() -> bool:
@@ -481,12 +485,17 @@ def gated_delta_rows(qkv: jax.Array, g: jax.Array, beta: jax.Array,
         return o.reshape(b, n * chunk, hv, dv)[:, :l]
 
 
-def gated_rmsnorm(o: jax.Array, z: jax.Array, w: jax.Array) -> jax.Array:
-    """``RMS(o) * w * silu(z)`` over the last dimension (a head), in
-    float32; eps 1e-6."""
+def gated_rmsnorm(o: jax.Array, z: jax.Array, w: jax.Array, *,
+                  eps: float = 1e-6, gate_first: bool = False) -> jax.Array:
+    """``RMS(o) * w * silu(z)`` over the last dimension (a head, or a
+    group of channels), in float32; with ``gate_first`` the gate is inside
+    the norm, ``RMS(o * silu(z)) * w``."""
     o, z = o.astype(jnp.float32), z.astype(jnp.float32)
-    o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + 1e-6)
-    return o * w.astype(jnp.float32) * jax.nn.silu(z)
+    if gate_first:
+        o = o * jax.nn.silu(z)
+    o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
+    o = o * w.astype(jnp.float32)
+    return o if gate_first else o * jax.nn.silu(z)
 
 
 def gated_delta_net(x: jax.Array, p: Dict[str, jax.Array], *,

@@ -68,6 +68,11 @@ TOY_PUBLISHED = {
         hidden_size=64, num_attention_heads=8, num_key_value_heads=8,
         intermediate_size=96, window_size=16, chunk_size=4,
         num_pred_heads=3, vocab_size=64, layers=2, heads=4, heads_first=4),
+    "granite_4_0_h_micro": lambda published: dict(
+        hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+        shared_intermediate_size=96, intermediate_size=96, mamba_n_heads=8,
+        mamba_d_head=8, mamba_d_state=16, mamba_chunk_size=16,
+        vocab_size=512, vocab=128),
 }
 
 
@@ -78,8 +83,9 @@ def test_a_published_layer_pattern_model_by_the_same_path(tmp_path, name):
     the benchmark's configuration file at toy widths (Laguna-XS.2: five
     layers of three kinds; Qwen3-Next: three Gated DeltaNet layers and a
     gated full-attention one; 4 of 16 experts held; EvaByte: EVA attention
-    over four windows with 4 of 8 heads held, three prediction heads),
-    through the example's single-device step."""
+    over four windows with 4 of 8 heads held, three prediction heads;
+    granite-4.0-h-micro: nine Mamba-2 layers and an attention layer without
+    a position term), through the example's single-device step."""
     import json
 
     with open(os.path.join(REPO, "benchmark", "configs",
@@ -107,7 +113,9 @@ def test_the_presets_of_published_models_name_the_benchmarks_files():
     for preset, name, seq, batch in (("laguna-xs2", "laguna_xs2", 8192, 2),
                                      ("qwen3-next", "qwen3_next_80b", 16384,
                                       1),
-                                     ("evabyte", "evabyte", 32768, 1)):
+                                     ("evabyte", "evabyte", 32768, 1),
+                                     ("granite_h_micro",
+                                      "granite_4_0_h_micro", 8192, 1)):
         got = example.PRESETS[preset]
         assert os.path.samefile(got["published"], os.path.join(
             REPO, "benchmark", "configs", name + ".json"))

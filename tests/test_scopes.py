@@ -813,3 +813,88 @@ def test_the_chunk_rule_is_under_chunk_forward_and_backward(monkeypatch,
                       "gdn_chunk_bwd"] if on_tpu else [])
     assert not [p for (p, _), c in zip(stacks, children)
                 if p == "pallas_call" and "chunk" not in c]
+
+
+# ---------------------------------------------------------------------------
+# The state-space mixer (PR 45): hvdt.ssd beside hvdt.attention and
+# hvdt.gdn, its four parts, and the three children of its scan.
+# ---------------------------------------------------------------------------
+
+
+def state_space_config():
+    mamba = models.LayerKind(
+        heads=0, kv_heads=0, d_ff=32, ssm=models.StateSpaceMixer(
+            heads=4, head_dim=8, state=16, chunk=16))
+    full = models.LayerKind(heads=2, kv_heads=1, d_ff=32, rope=None)
+    cfg = models.TransformerConfig(
+        vocab=256, d_model=32, head_dim=16, layers=3,
+        period=(mamba, mamba, full), max_seq=64, remat=True, loss_chunk=128,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.0625, logits_scaling=8.0)
+    params = jax.eval_shape(
+        lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    return jax.value_and_grad(
+        lambda p, t: models.transformer_loss(p, t, cfg)), params, tokens
+
+
+@pytest.fixture(scope="module")
+def state_space_grad_text():
+    return compiled_text(*state_space_config())
+
+
+@pytest.mark.parametrize("path", _under(
+    NESTED, "hvdt.ssd", (".proj", ".conv", ".scan", ".norm")) + _under(
+    NESTED, "hvdt.ssd/hvdt.ssd.scan", (".chunk", ".state", ".out")))
+def test_the_state_space_mixer_carries_its_scopes(state_space_grad_text,
+                                                  path):
+    """``hvdt.ssd`` around the whole Mamba-2 sublayer, inside it the four
+    parts and under its scan the three children the benchmark's ``ssd_*``
+    readers sum; forward, recompute and backward, in the scan of the
+    period's run of layers."""
+    assert path in state_space_grad_text
+
+
+def test_the_state_space_mixer_is_a_sibling_of_attention(
+        state_space_grad_text):
+    """Nothing of the mixer is under ``hvdt.attention`` or ``hvdt.gdn``
+    (``attention_ms`` keeps meaning softmax attention), the attention layer
+    of the same period keeps ``hvdt.attention``, and the state's loop is a
+    ``while`` under ``hvdt.ssd.scan.state``."""
+    text = state_space_grad_text
+    assert "hvdt.attention/hvdt.ssd" not in text
+    assert "hvdt.ssd/hvdt.attention" not in text and "hvdt.gdn" not in text
+    assert re.search(r"while/body/closed_call/hvdt\.attention/", text)
+    assert "hvdt.ssd/hvdt.ssd.scan/hvdt.ssd.scan.state/while/body/" in text
+
+
+def test_the_scans_children_account_for_all_of_it():
+    """Every equation of the mixer under ``jax.grad`` that is under
+    ``hvdt.ssd.scan`` is under exactly one of ``.chunk``, ``.state`` and
+    ``.out`` (so ``ssd_chunk_ms + ssd_state_ms + ssd_out_ms`` is
+    ``ssd_scan_ms``), and the rest of it under ``.proj``, ``.conv`` or
+    ``.norm``."""
+    from horovod_tpu.ops.ssd import mamba2_mixer
+
+    cfg = models.TransformerConfig(layers=1, d_model=32, period=(
+        models.LayerKind(heads=0, kv_heads=0, d_ff=32,
+                         ssm=models.StateSpaceMixer(
+                             heads=4, head_dim=8, state=16, chunk=16)),))
+    p = jax.eval_shape(lambda k: models.transformer_init(k, cfg),
+                       jax.random.PRNGKey(0))["period"]["0"]
+    p = jax.tree.map(lambda a: jnp.ones(a.shape[2:], a.dtype), p)
+    grad = jax.grad(lambda x, p: mamba2_mixer(
+        x, p, proj=lambda a, w: a @ w, eps=1e-5,
+        **cfg.period[0].ssm.sizes).sum(), argnums=(0, 1))
+    stacks = [s for _, s in _name_stacks(jax.make_jaxpr(grad)(
+        jnp.ones((1, 64, 32)), p).jaxpr)]
+    scan = [re.findall(r"hvdt\.ssd\.scan\.(\w+)", s) for s in stacks
+            if "hvdt.ssd.scan" in s]
+    assert len(scan) > 50 and all(
+        len(set(c)) == 1 and set(c) <= {"chunk", "state", "out"}
+        for c in scan)
+    assert {c[0] for c in scan} == {"chunk", "state", "out"}
+    rest = [s for s in stacks if "hvdt.ssd.scan" not in s
+            and "hvdt.ssd" in s]
+    assert rest and all(re.search(r"hvdt\.ssd\.(proj|conv|norm)", s)
+                        for s in rest)

@@ -826,6 +826,60 @@ def kernel_gdn_chunk(*, seq=16384, key_heads=16, value_heads=32,
             qkv, gc, beta, t, cts))
 
 
+def kernel_ssd_chunk(*, seq=8192, heads=64, head_dim=64, state=128,
+                     chunk=256):
+    """ops/pallas_kernels ssd_chunk_state / ssd_chunk_out and their
+    backwards (the Mosaic schedule of the Mamba-2 scan's chunk passes and
+    of their rules) against XLA's schedule of the same rules (ops/ssd
+    _state_fwd_jax / _out_fwd_jax / _state_bwd_jax / _out_bwd_jax) on the
+    rows of one state-space layer of granite_h_micro_s8192: each output and
+    every cotangent within a hundredth of its largest entry (bf16 operands
+    of float32 sums in two orders)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import pallas_kernels as pk
+    from horovod_tpu.ops import ssd
+
+    dims = (heads, head_dim, 1, state)
+    if not pk.ssd_chunk_tiles(seq, dims, chunk):
+        raise AssertionError(f"rows {seq} x {dims} do not tile the kernels")
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    inner = heads * head_dim
+    xbc = jax.nn.silu(jax.random.normal(ks[0], (1, seq, inner + 2 * state))
+                      ).astype(jnp.bfloat16)
+    # time steps log-uniform in [1e-3, 1e-1], as the model draws them
+    delta = jnp.exp(jax.random.uniform(
+        ks[1], (1, seq, heads), minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+    a = -jnp.arange(1.0, heads + 1)
+    l = jnp.cumsum((delta * a).reshape(1, -1, chunk, heads), 2).reshape(
+        delta.shape)
+    s_in = jax.random.normal(
+        ks[2], (seq // chunk, 1, inner, state)).astype(jnp.bfloat16)
+    skip = jax.random.normal(ks[3], (1, inner))
+
+    def both(kernel, plain, *ins):
+        got = jax.jit(lambda *t: kernel(*t, dims, chunk))(*ins)
+        want = jax.jit(lambda *t: plain(*t, dims, chunk))(*ins)
+        for i, (g, w) in enumerate(zip(jax.tree.leaves(got),
+                                       jax.tree.leaves(want))):
+            if g.shape != w.shape or g.dtype != w.dtype:
+                raise AssertionError(
+                    f"{kernel.__name__}[{i}]: {g.shape} {g.dtype} "
+                    f"against {w.shape} {w.dtype}")
+            top = float(jnp.max(jnp.abs(w.astype(jnp.float32))))
+            _close(f"{kernel.__name__}[{i}]", g, w, rtol=1e-2,
+                   atol=1e-2 * top)
+        return got
+
+    own = both(pk.ssd_chunk_state, ssd._state_fwd_jax, xbc, delta, l)
+    both(pk.ssd_chunk_state_bwd, ssd._state_bwd_jax, xbc, delta, l,
+         0.1 * jax.random.normal(ks[4], own.shape))
+    y = both(pk.ssd_chunk_out, ssd._out_fwd_jax, xbc, delta, l, s_in, skip)
+    both(pk.ssd_chunk_out_bwd, ssd._out_bwd_jax, xbc, delta, l, s_in, skip,
+         0.1 * jax.random.normal(ks[5], y.shape))
+
+
 def kernel_moe_sum_rows(*, tokens=16384, picks=10, width=2048, routed=512,
                         held=32):
     """ops/pallas_kernels moe_sum_rows against XLA's gather and sum
@@ -948,7 +1002,7 @@ KERNELS = (kernel_flash_forward, kernel_flash_ring_step,
            kernel_flash_window, kernel_flash_block_diffusion,
            kernel_flash_grad_block,
            kernel_conv_bn_relu, kernel_conv_bn_train, kernel_gdn_inverse,
-           kernel_gdn_chunk,
+           kernel_gdn_chunk, kernel_ssd_chunk,
            kernel_rope, kernel_moe_sum_rows,
            kernel_fused_adam, kernel_fused_sgd, kernel_quant_int8,
            kernel_quant_int4)

@@ -45,7 +45,13 @@ hand-written differentiation rule (``ops/gated_delta._chunk_passes``): a
 ``after`` call (``T (beta V)``, ``T (beta gamma K)``, ``(gamma_C / gamma)
 K``) and one call for the whole backward; each takes q, k, v as column
 blocks of the projection's own [B, L, 2 Kd + Vd] rows and holds a block
-of chunks of one key head in VMEM.
+of chunks of one key head in VMEM.  ``ssd_chunk_state`` / ``ssd_chunk_out``
+and their two backwards are the Mosaic schedule of the Mamba-2 scan's
+chunk passes on both sides of its state's loop and of their hand-written
+rules (``ops/ssd._chunk_state`` / ``_chunk_out``): a program holds a chunk
+and 8 heads, reads x, B and C as column blocks of the convolution's own
+[B, L, I + 2 S] rows, keeps a head's [C, C] masked pairs in VMEM and
+writes y once, token-major.
 
 Which of the two runs is decided by which function the caller calls,
 and by nothing a user sets.  Both run in Pallas interpret mode off-TPU,
@@ -1817,12 +1823,12 @@ def _gdn_unit_rows(x):
     return x * r, r
 
 
-def _gdn_nt(x, y):                      # x y^T, float32 out
+def _dot_nt(x, y):                      # x y^T, float32 out
     return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
 
-def _gdn_tn(x, y):                      # x^T y, float32 out
+def _dot_tn(x, y):                      # x^T y, float32 out
     return jax.lax.dot_general(x, y, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
@@ -1837,6 +1843,21 @@ def _gdn_ratio(gc_col, gc_row):
     return ratio, j < i
 
 
+def _in_a_taken_branch(run):
+    """``run()``, in interpret mode inside a branch that is always taken:
+    under shard_map a kernel's reads of its refs were typed when it was
+    traced, without the operands' varying axes, and bound bare they are
+    refused on them; inside a branch they are not bound again (as
+    ``_inverse_kernel``'s ``guarded``).  Not on the TPU, where nothing is
+    bound again and the branch costs seconds to lower."""
+    import jax.experimental.pallas as pl
+
+    if _use_interpret():
+        pl.when(pl.program_id(0) >= 0)(run)
+    else:
+        run()
+
+
 def _gdn_chunks_loop(nb: int, body):
     """``body(c)`` for the program's chunks, ``_GDN_TRIP`` of them a trip
     of the loop: a chunk is one serial chain of small products, lane sums
@@ -1845,10 +1866,7 @@ def _gdn_chunks_loop(nb: int, body):
     by 1 or wholly, so the trip is written out).  On the v5e, ms a call of
     before / after / backward at qwen3_next_s16384's shape (my chip runs,
     PR 43): one chunk a trip 1.98 / 2.26 / 6.93, two 1.59 / 1.91 / 6.10,
-    four 1.51 / 1.47 / 5.77.  In interpret mode the loop is inside a
-    branch that is always taken (as ``_inverse_kernel``'s)."""
-    import jax.experimental.pallas as pl
-
+    four 1.51 / 1.47 / 5.77."""
     per = math.gcd(nb, _GDN_TRIP)
 
     def trip(t, carry):
@@ -1859,10 +1877,7 @@ def _gdn_chunks_loop(nb: int, body):
     def run():
         jax.lax.fori_loop(0, nb // per, trip, 0)
 
-    if _use_interpret():
-        pl.when(pl.program_id(0) >= 0)(run)
-    else:
-        run()
+    _in_a_taken_branch(run)
 
 
 def _gdn_before_kernel(q_ref, k_ref, rows_ref, qn_ref, a_ref, attn_ref, *,
@@ -1878,7 +1893,7 @@ def _gdn_before_kernel(q_ref, k_ref, rows_ref, qn_ref, a_ref, attn_ref, *,
         tok = pl.ds(pl.multiple_of(n * c, c), c)
         qn = (_gdn_unit_rows(q_ref[tok, :])[0] * scale).astype(dt)
         kn = _gdn_unit_rows(k_ref[tok, :])[0].astype(dt)
-        kk, qk = _gdn_nt(kn, kn), _gdn_nt(qn, kn)
+        kk, qk = _dot_nt(kn, kn), _dot_nt(qn, kn)
         rows = rows_ref[n]                                  # [8, C]
         cols = rows.T
         a, attn = [], []
@@ -2018,7 +2033,7 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, t_ref, rows_ref, cqn_ref, cw_ref,
         ky, rk = _gdn_unit_rows(k_ref[tok, :])
         qn, kn = (qy * scale).astype(dt), ky.astype(dt)
         knf = kn.astype(f32)
-        kk, qk = _gdn_nt(kn, kn), _gdn_nt(qn, kn)
+        kk, qk = _dot_nt(kn, kn), _dot_nt(qn, kn)
         rows = rows_ref[n]                                  # [8, C]
         cols = rows.T
         s_all = t_ref[n].T                                  # [R C, C]
@@ -2037,7 +2052,7 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, t_ref, rows_ref, cqn_ref, cw_ref,
             s32 = s_all[v * c:(v + 1) * c]
             sb = s32.astype(dt)
             cu, cw = cu_ref[n, v].astype(dt), cw_ref[n, v]
-            d_t = _gdn_nt(cu, bv) + _gdn_nt(cw, bgk)
+            d_t = _dot_nt(cu, bv) + _dot_nt(cw, bgk)
             d_bv = jnp.dot(sb, cu, preferred_element_type=f32)
             d_bgk = jnp.dot(sb, cw, preferred_element_type=f32)
             # the inverse: -T^T d_t T^T on the strict lower triangle
@@ -2062,7 +2077,7 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, t_ref, rows_ref, cqn_ref, cw_ref,
             out_rows.append(-jnp.sum(m, axis=0, keepdims=True))
         d_kkb, d_qkb = d_kk.astype(dt), d_qk.astype(dt)
         d_kn = (d_kn + jnp.dot(d_kkb, kn, preferred_element_type=f32)
-                + _gdn_tn(d_kkb, kn) + _gdn_tn(d_qkb, qn))
+                + _dot_tn(d_kkb, kn) + _dot_tn(d_qkb, qn))
         d_qn = (cqn_ref[tok, :].astype(f32)
                 + jnp.dot(d_qkb, kn, preferred_element_type=f32)) * scale
         dq_ref[tok, :] = (rq * (d_qn - qy * lanes(qy * d_qn))).astype(dt)
@@ -2108,6 +2123,414 @@ def gdn_chunk_backward(qkv, gc, beta, t, cts, dims, chunk: int):
     d_small = jnp.moveaxis(d_rows[:, :, :, :2 * r], -1, 2)  # [B,N,C,Hk,2R]
     return (jnp.concatenate([d_q, d_k, d_v], -1), d_small[..., :r],
             d_small[..., r:])
+
+
+# The Mamba-2 scan's chunk passes (ops/ssd.py states the mathematics and
+# the rule; these are its Mosaic schedule).  A program is (sequence, chunk,
+# block of 8 heads): x of the heads, B and C are column blocks of the
+# convolution's own [B, L, I + 2 S] rows, the chunk's [C, C] pairs of a head
+# are made, used and dropped in VMEM, and the per-token scalars (the running
+# log-decay and delta of each head) come as rows [8, C] that a program turns
+# into columns itself.  Products are made a unit of lanes at a time: one
+# head of whole lane tiles, or the 128 // P heads that fill one tile, each
+# product then on all of the tile's lanes and the head's own kept
+# (``_keep_head``): the MXU is 128 wide whatever the head.
+
+_SSD_VMEM = 48 << 20
+_SSD_STATE, _SSD_OUT, _SSD_STATE_BWD, _SSD_OUT_BWD = range(4)
+
+
+def ssd_chunk_tiles(rows: int, dims, chunk: int) -> bool:
+    """Whether the chunk kernels take rows of ``rows`` tokens with ``dims``
+    = (H, P, G, S): whole chunks of whole lane tiles, one group (a
+    chunk's ``C B^T`` serves every head of a program), heads in blocks of
+    8 (the scalars' sublanes) whose lanes are whole units of one head or
+    of the heads that fill a tile, a state of whole lane tiles whose
+    columns start on one of its own blocks."""
+    h, p, g, s = dims
+    unit = max(p, 128)
+    return (g == 1 and chunk % 128 == 0 and rows % chunk == 0
+            and h % _SUBLANES == 0 and unit % p == 0 and unit % 128 == 0
+            and (_SUBLANES * p) % unit == 0 and s % 128 == 0
+            and (h * p) % s == 0)
+
+
+def _ssd_specs(dims, chunk: int):
+    """The block specs of a program of the grid (B, N, H / 8): ``x``, its
+    heads' lanes of token-major rows; ``b`` and ``c`` on the rows
+    [B, L, I + 2 S]; ``rows``, the scalars [B, N, H / 8, 2, 8, C];
+    ``states``, its heads' of [N, B, H P, S]; ``lanes``, one row of its
+    lanes [B, N, 1, H P]; ``skip``, the same of [1, H P]; ``b_sum`` and
+    ``bc_sum``, the chunk's rows of [B, L, S] and [B, L, 2 S] (every block
+    of heads the same block)."""
+    import jax.experimental.pallas as pl
+
+    h, p, _, s = dims
+    lanes, first = _SUBLANES * p, h * p // s
+    return dict(
+        x=pl.BlockSpec((None, chunk, lanes), lambda b, j, k: (b, j, k)),
+        b=pl.BlockSpec((None, chunk, s), lambda b, j, k: (b, j, first)),
+        c=pl.BlockSpec((None, chunk, s), lambda b, j, k: (b, j, first + 1)),
+        rows=pl.BlockSpec((None, None, None, 2, _SUBLANES, chunk),
+                          lambda b, j, k: (b, j, k, 0, 0, 0)),
+        states=pl.BlockSpec((None, None, lanes, s),
+                            lambda b, j, k: (j, b, k, 0)),
+        lanes=pl.BlockSpec((None, None, 1, lanes),
+                           lambda b, j, k: (b, j, 0, k)),
+        skip=pl.BlockSpec((1, lanes), lambda b, j, k: (0, k)),
+        b_sum=pl.BlockSpec((None, chunk, s), lambda b, j, k: (b, j, 0)),
+        bc_sum=pl.BlockSpec((None, chunk, 2 * s), lambda b, j, k: (b, j, 0)))
+
+
+def _ssd_call(kernel, grid, in_specs, out_specs, out_shape, operands, part):
+    """One of the four calls (``part``), on the grid (B, N, H / 8).  The
+    blocks of heads are taken in turn: B's and C's cotangents are summed
+    over them in a block that stays in VMEM."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    vma = _vma_kw(*operands)
+    out_shape = [jax.ShapeDtypeStruct(s, d, **vma) for s, d in out_shape]
+    with jax.named_scope(("hvdt.kernel.ssd_chunk_state",
+                          "hvdt.kernel.ssd_chunk_out",
+                          "hvdt.kernel.ssd_chunk_state_bwd",
+                          "hvdt.kernel.ssd_chunk_out_bwd")[part]):
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_SSD_VMEM),
+            interpret=_use_interpret(),
+        )(*operands)
+
+
+def _ssd_small_rows(l, delta, chunk: int):
+    """The per-token scalars as the kernels read them: the running
+    log-decay and delta [B, L, H] float32 -> [B, N, H / 8, 2, 8, C]."""
+    b, length, h = l.shape
+    rows = jnp.stack([l, delta], 2).reshape(
+        b, length // chunk, chunk, 2, h // _SUBLANES, _SUBLANES)
+    return jnp.transpose(rows, (0, 1, 4, 3, 5, 2))
+
+
+def _ssd_from_small_rows(rows):
+    """The cotangents' rows [B, N, H / 8, 2, 8, C] back token-major: two
+    arrays [B, L, H]."""
+    b, n, _, _, _, c = rows.shape
+    cols = jnp.transpose(rows, (3, 0, 1, 5, 2, 4)).reshape(2, b, n * c, -1)
+    return cols[0], cols[1]
+
+
+def _ssd_spread(cols, first: int, shape, p: int):
+    """Per-token columns [C, 8] on a unit's lanes ``shape`` = [C, U]:
+    column ``first + i`` on the lanes of the unit's head i."""
+    out = cols[:, first:first + 1]
+    if shape[1] == p:
+        return out                      # one head: the product broadcasts
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    for i in range(1, shape[1] // p):
+        out = jnp.where(lane >= i * p, cols[:, first + i:first + i + 1], out)
+    return out
+
+
+def _ssd_units(lanes: int, p: int):
+    """The units a program's ``lanes`` are worked in: (a unit's lanes, its
+    first head) each, and the heads a unit."""
+    unit = max(p, 128)
+    return [(slice(u, u + unit), u // p)
+            for u in range(0, lanes, unit)], unit // p
+
+
+def _ssd_head_sums(x, p: int):
+    """The sums over each head's lanes of a unit x [C, U]: U // P columns
+    [C, 1]."""
+    heads = x.shape[1] // p
+    return [jnp.sum(_keep_head(x, i, heads), axis=1, keepdims=True)
+            for i in range(heads)]
+
+
+def _ssd_scalars(rows_ref):
+    """The running log-decay and delta of the program's heads as rows
+    [8, C] and as columns [C, 8]."""
+    l_rows, d_rows = rows_ref[0], rows_ref[1]
+    return l_rows, l_rows.T, d_rows.T
+
+
+def _ssd_to_end(l_cols, d_cols):
+    """``exp(l_C - l_j)`` and delta_j times it, columns [C, 8]."""
+    c = l_cols.shape[0]
+    w = jnp.exp(l_cols[c - 1:c] - l_cols)
+    return w, d_cols * w
+
+
+def _ssd_kernel(body):
+    """A kernel of the four from its body: the program's place among the
+    blocks of heads is read here, outside the branch the interpreter
+    needs around the body (:func:`_in_a_taken_branch`)."""
+    @functools.wraps(body)
+    def kernel(*refs, p: int):
+        import jax.experimental.pallas as pl
+
+        block = pl.program_id(2)
+        _in_a_taken_branch(lambda: body(*refs, p=p, block=block))
+    return kernel
+
+
+@_ssd_kernel
+def _ssd_state_kernel(x_ref, b_ref, rows_ref, s_ref, *, p: int, block):
+    """Each head's own state of the chunk: ``sum_j exp(l_C - l_j) delta_j
+    x_j B_j^T`` [8 P, S] in float32."""
+    del block
+    dt, f32 = x_ref.dtype, jnp.float32
+    _, l_cols, d_cols = _ssd_scalars(rows_ref)
+    _, to_end = _ssd_to_end(l_cols, d_cols)
+    bm = b_ref[...]
+    for at, first in _ssd_units(x_ref.shape[1], p)[0]:
+        x = x_ref[:, at].astype(f32)
+        xw = (x * _ssd_spread(to_end, first, x.shape, p)).astype(dt)
+        s_ref[at, :] = _dot_tn(xw, bm)
+
+
+def _ssd_sum_over_blocks(ref, value, block):
+    """``value`` written by the first block of heads and added by the
+    others, in the block of ``ref`` that all of them share."""
+    import jax.experimental.pallas as pl
+
+    @pl.when(block == 0)
+    def _():
+        ref[...] = value
+
+    @pl.when(block > 0)
+    def _():
+        ref[...] += value
+
+
+@_ssd_kernel
+def _ssd_state_bwd_kernel(x_ref, b_ref, rows_ref, ds_ref, dx_ref, db_ref,
+                          drows_ref, *, p: int, block):
+    """The backward of :func:`_ssd_state_kernel` (``ssd._state_bwd_jax``
+    states it): x's cotangent on its rows, B's summed over the blocks of
+    heads in float32, the scalars' as rows."""
+    c = x_ref.shape[0]
+    dt, f32 = x_ref.dtype, jnp.float32
+    _, l_cols, d_cols = _ssd_scalars(rows_ref)
+    w, to_end = _ssd_to_end(l_cols, d_cols)
+    bm = b_ref[...]
+    dsb = ds_ref[...].astype(dt)
+    d_xw = _dot_nt(bm, dsb)                              # [C, 8 P]
+    d_b = jnp.zeros(bm.shape, f32)
+    d_te = []
+    for at, first in _ssd_units(x_ref.shape[1], p)[0]:
+        x = x_ref[:, at].astype(f32)
+        spread = _ssd_spread(to_end, first, x.shape, p)
+        d_b = d_b + jnp.dot((x * spread).astype(dt), dsb[at],
+                            preferred_element_type=f32)
+        dx_ref[:, at] = (d_xw[:, at] * spread).astype(dt)
+        d_te += _ssd_head_sums(d_xw[:, at] * x, p)
+    d_te = jnp.concatenate(d_te, axis=1)                 # [C, 8]
+    g = d_te * to_end
+    last = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0) == c - 1
+    d_l = jnp.where(last, jnp.sum(g, axis=0, keepdims=True), 0.0) - g
+    drows_ref[0] = d_l.T
+    drows_ref[1] = (d_te * w).T
+    _ssd_sum_over_blocks(db_ref, d_b, block)
+
+
+def _ssd_scores(c, b):
+    """``C B^T`` of the chunk [C, C] in float32, once for the program's
+    heads, and the mask of the pairs j <= i."""
+    scores = _dot_nt(c, b)
+    i = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    return scores, j <= i
+
+
+def _ssd_pairs(scores, lower, l_rows, l_cols, head: int, dt):
+    """A head's masked pairs ``(C_i . B_j) exp(l_i - l_j)`` [C, C] in the
+    compute dtype, and the ratios in float32: exactly 0 above the
+    diagonal."""
+    ratio = jnp.exp(jnp.where(
+        lower, l_cols[:, head:head + 1] - l_rows[head:head + 1], -jnp.inf))
+    return (scores * ratio).astype(dt), ratio
+
+
+@_ssd_kernel
+def _ssd_out_kernel(x_ref, b_ref, c_ref, rows_ref, s_ref, skip_ref, y_ref,
+                    *, p: int, block):
+    """y of the program's heads for the chunk's tokens: the masked pairs'
+    product with ``delta x``, what the entering states add, and ``D x``,
+    summed in VMEM and written once."""
+    del block
+    units, heads = _ssd_units(x_ref.shape[1], p)
+    dt, f32 = x_ref.dtype, jnp.float32
+    l_rows, l_cols, d_cols = _ssd_scalars(rows_ref)
+    e_cols = jnp.exp(l_cols)
+    cm = c_ref[...]
+    scores, lower = _ssd_scores(cm, b_ref[...])
+    y_in = _dot_nt(cm, s_ref[...])                       # [C, 8 P]
+    for at, first in units:
+        x = x_ref[:, at].astype(f32)
+        xd = (x * _ssd_spread(d_cols, first, x.shape, p)).astype(dt)
+        y = None
+        for i in range(heads):
+            m, _ = _ssd_pairs(scores, lower, l_rows, l_cols, first + i, dt)
+            own = jnp.dot(m, xd, preferred_element_type=f32)
+            y = own if y is None else jnp.where(
+                _head_lanes(own, i, heads), own, y)
+        y_ref[:, at] = (y + _ssd_spread(e_cols, first, x.shape, p)
+                        * y_in[:, at] + skip_ref[:, at] * x)
+
+
+@_ssd_kernel
+def _ssd_out_bwd_kernel(x_ref, b_ref, c_ref, rows_ref, s_ref, skip_ref,
+                        dy_ref, dx_ref, dbc_ref, ds_ref, drows_ref,
+                        dskip_ref, *, p: int, block):
+    """The backward of :func:`_ssd_out_kernel` (``ssd._out_bwd_jax``
+    states it), the pairs made again in VMEM: x's cotangent on its rows,
+    B's and C's [C, 2 S] summed over the blocks of heads in float32, the
+    entering states', the scalars' as rows (a token's share of l's
+    through its row of the pairs is ``dy . y_own``, through its column
+    ``(delta x) . d(delta x)``: no sum over a [C, C] array), and ``D``'s a
+    lane, summed over the chunk's tokens."""
+    units, heads = _ssd_units(x_ref.shape[1], p)
+    dt, f32 = x_ref.dtype, jnp.float32
+    l_rows, l_cols, d_cols = _ssd_scalars(rows_ref)
+    e_cols = jnp.exp(l_cols)
+    bm, cm = b_ref[...], c_ref[...]
+    scores, lower = _ssd_scores(cm, bm)
+    y_in = _dot_nt(cm, s_ref[...])
+    d_scores = jnp.zeros(scores.shape, f32)
+    d_c = jnp.zeros(cm.shape, f32)
+    d_l, d_delta = [], []
+    for at, first in units:
+        x, dy = x_ref[:, at].astype(f32), dy_ref[:, at]
+        dyb = dy.astype(dt)
+        delta = _ssd_spread(d_cols, first, x.shape, p)
+        e = _ssd_spread(e_cols, first, x.shape, p)
+        xd = (x * delta).astype(dt)
+        y = d_xd = None
+        for i in range(heads):
+            m, ratio = _ssd_pairs(scores, lower, l_rows, l_cols, first + i,
+                                  dt)
+            own = jnp.dot(m, xd, preferred_element_type=f32)
+            d_own = _dot_tn(m, dyb)
+            if i:
+                mine = _head_lanes(own, i, heads)
+                y, d_xd = jnp.where(mine, own, y), jnp.where(mine, d_own,
+                                                             d_xd)
+            else:
+                y, d_xd = own, d_own
+            d_scores = d_scores + _dot_nt(_keep_head(dyb, i, heads),
+                                          xd) * ratio
+        # the pairs' share of l's cotangent, rows less columns, from the
+        # products' own operands: the same terms summed two ways, so that
+        # a chunk's shares cancel as the pairs' ratios say they must
+        d_l += _ssd_head_sums(dyb.astype(f32) * y + dy * e * y_in[:, at]
+                              - xd.astype(f32) * d_xd, p)
+        d_delta += _ssd_head_sums(x * d_xd, p)
+        dx_ref[:, at] = (d_xd * delta + skip_ref[:, at] * dy).astype(dt)
+        dskip_ref[:, at] = jnp.sum(dy * x, axis=0, keepdims=True)
+        eyb = (dy * e).astype(dt)
+        ds_ref[at, :] = _dot_tn(eyb, cm).astype(dt)
+        d_c = d_c + jnp.dot(eyb, s_ref[at, :], preferred_element_type=f32)
+    drows_ref[0] = jnp.concatenate(d_l, axis=1).T        # [C, 8] -> rows
+    drows_ref[1] = jnp.concatenate(d_delta, axis=1).T
+    d_sb = d_scores.astype(dt)
+    d_c = d_c + jnp.dot(d_sb, bm, preferred_element_type=f32)
+    _ssd_sum_over_blocks(
+        dbc_ref, jnp.concatenate([_dot_tn(d_sb, cm), d_c], axis=1), block)
+
+
+def _ssd_grid(xbc, dims, chunk: int):
+    """The grid (B, N, H / 8) of the rows ``xbc``."""
+    return (xbc.shape[0], xbc.shape[1] // chunk, dims[0] // _SUBLANES)
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "chunk"))
+def ssd_chunk_state(xbc, delta, l, dims, chunk: int):
+    """Each chunk's own contribution to the state, ``ssd._state_fwd_jax``
+    as one Mosaic call: xbc [B, L, I + 2 S] (columns [x | B | C]), delta
+    and the running log-decay ``l`` [B, L, H] float32 -> [N, B, H P, S]
+    float32.  The caller checks :func:`ssd_chunk_tiles` first."""
+    h, p, _, s = dims
+    rows = _ssd_small_rows(l, delta, chunk)
+    grid = _ssd_grid(xbc, dims, chunk)
+    spec = _ssd_specs(dims, chunk)
+    own, = _ssd_call(
+        functools.partial(_ssd_state_kernel, p=p), grid,
+        [spec["x"], spec["b"], spec["rows"]], [spec["states"]],
+        [((grid[1], grid[0], h * p, s), jnp.float32)], (xbc, xbc, rows),
+        _SSD_STATE)
+    return own
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "chunk"))
+def ssd_chunk_state_bwd(xbc, delta, l, d_own, dims, chunk: int):
+    """The backward of :func:`ssd_chunk_state` as one Mosaic call: the
+    cotangents of the rows ``xbc`` (C's columns zeros), of delta and of
+    the running log-decay [B, L, H]."""
+    h, p, _, s = dims
+    rows = _ssd_small_rows(l, delta, chunk)
+    b, length, _ = xbc.shape
+    grid = _ssd_grid(xbc, dims, chunk)
+    spec = _ssd_specs(dims, chunk)
+    d_x, d_b, d_rows = _ssd_call(
+        functools.partial(_ssd_state_bwd_kernel, p=p), grid,
+        [spec["x"], spec["b"], spec["rows"], spec["states"]],
+        [spec["x"], spec["b_sum"], spec["rows"]],
+        [((b, length, h * p), xbc.dtype), ((b, length, s), jnp.float32),
+         (rows.shape, jnp.float32)],
+        (xbc, xbc, rows, d_own), _SSD_STATE_BWD)
+    d_l, d_delta = _ssd_from_small_rows(d_rows)
+    d_xbc = jnp.concatenate(
+        [d_x, d_b.astype(xbc.dtype), jnp.zeros((b, length, s), xbc.dtype)],
+        -1)
+    return d_xbc, d_delta, d_l
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "chunk"))
+def ssd_chunk_out(xbc, delta, l, s_in, skip, dims, chunk: int):
+    """y [B, L, H P] float32 from the rows, the scalars, the states
+    entering the chunks ``s_in`` [N, B, H P, S] (the compute dtype) and
+    ``D`` a lane ``skip`` [1, H P] float32: ``ssd._out_fwd_jax`` as one
+    Mosaic call."""
+    h, p, _, _ = dims
+    rows = _ssd_small_rows(l, delta, chunk)
+    grid = _ssd_grid(xbc, dims, chunk)
+    spec = _ssd_specs(dims, chunk)
+    y, = _ssd_call(
+        functools.partial(_ssd_out_kernel, p=p), grid,
+        [spec["x"], spec["b"], spec["c"], spec["rows"], spec["states"],
+         spec["skip"]], [spec["x"]],
+        [(xbc.shape[:2] + (h * p,), jnp.float32)],
+        (xbc, xbc, xbc, rows, s_in, skip), _SSD_OUT)
+    return y
+
+
+@functools.partial(jax.jit, static_argnames=("dims", "chunk"))
+def ssd_chunk_out_bwd(xbc, delta, l, s_in, skip, d_y, dims, chunk: int):
+    """The backward of :func:`ssd_chunk_out` as one Mosaic call: the
+    cotangents of the rows ``xbc``, of delta and the running log-decay
+    [B, L, H], of ``s_in`` and of ``skip``."""
+    h, p, _, s = dims
+    rows = _ssd_small_rows(l, delta, chunk)
+    b, length, _ = xbc.shape
+    grid = _ssd_grid(xbc, dims, chunk)
+    spec = _ssd_specs(dims, chunk)
+    d_x, d_bc, d_s, d_rows, d_skip = _ssd_call(
+        functools.partial(_ssd_out_bwd_kernel, p=p), grid,
+        [spec["x"], spec["b"], spec["c"], spec["rows"], spec["states"],
+         spec["skip"], spec["x"]],
+        [spec["x"], spec["bc_sum"], spec["states"], spec["rows"],
+         spec["lanes"]],
+        [((b, length, h * p), xbc.dtype), ((b, length, 2 * s), jnp.float32),
+         (s_in.shape, s_in.dtype), (rows.shape, jnp.float32),
+         ((b, grid[1], 1, h * p), jnp.float32)],
+        (xbc, xbc, xbc, rows, s_in, skip, d_y), _SSD_OUT_BWD)
+    d_l, d_delta = _ssd_from_small_rows(d_rows)
+    return (jnp.concatenate([d_x, d_bc.astype(xbc.dtype)], -1), d_delta, d_l,
+            d_s, d_skip.sum((0, 1)))
 
 
 _ROPE_BLOCK_BYTES = 1 << 21     # a program's block as a float32 slab

@@ -24,6 +24,8 @@ TOY_KERNELS = dict(
     kernel_conv_bn_train=dict(batch=2, hw=8, cin=128, cout=128),
     kernel_gdn_inverse=dict(matrices=130, chunk=16),
     kernel_gdn_chunk=dict(seq=128, key_heads=1, value_heads=2, head_dim=128),
+    kernel_ssd_chunk=dict(seq=256, heads=8, head_dim=64, state=128,
+                          chunk=128),
     kernel_rope=dict(batch=2, seq=32, heads=4, head_dim=64),
     kernel_moe_sum_rows=dict(tokens=1024, picks=3, width=128, routed=16,
                              held=4),

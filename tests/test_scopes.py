@@ -240,6 +240,23 @@ def _gdn(kernel):
         qkv, small, small, zeros(t), tuple(map(zeros, out)), dims, chunk)
 
 
+def _ssd(kernel):
+    """The four calls of the Mamba-2 scan's chunk passes (one chunk of 128
+    tokens, 8 heads of 16 over a state of 128)."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    dims, chunk = (8, 16, 1, 128), 128
+    xbc = jnp.ones((1, chunk, 128 + 256), jnp.float32)
+    small = jnp.zeros((1, chunk, 8), jnp.float32)
+    states, skip = jnp.ones((1, 1, 128, 128)), jnp.ones((1, 128))
+    args = {"ssd_chunk_state": (xbc, small, small),
+            "ssd_chunk_state_bwd": (xbc, small, small, states),
+            "ssd_chunk_out": (xbc, small, small, states, skip),
+            "ssd_chunk_out_bwd": (xbc, small, small, states, skip,
+                                  jnp.ones((1, chunk, 128)))}[kernel]
+    return lambda: getattr(pk, kernel)(*args, dims, chunk)
+
+
 def _rope(kernel):
     from horovod_tpu.ops import pallas_kernels as pk
 
@@ -266,6 +283,8 @@ KERNEL_SITES = (
     + [(_conv, k) for k in ("conv1x1_bn", "conv1x1_bn_stats")]
     + [(_gdn, k) for k in ("gdn_inverse", "gdn_chunk_before",
                            "gdn_chunk_after", "gdn_chunk_bwd")]
+    + [(_ssd, k) for k in ("ssd_chunk_state", "ssd_chunk_out",
+                           "ssd_chunk_state_bwd", "ssd_chunk_out_bwd")]
     + [(_rope, "rope")]
     + [(_moe_rows, "moe_sum_rows")]
     + [(_optim, k) for k in ("fused_adam", "fused_sgd")]
@@ -868,26 +887,39 @@ def test_the_state_space_mixer_is_a_sibling_of_attention(
     assert "hvdt.ssd/hvdt.ssd.scan/hvdt.ssd.scan.state/while/body/" in text
 
 
-def test_the_scans_children_account_for_all_of_it():
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["xla", "mosaic"])
+def test_the_scans_children_account_for_all_of_it(monkeypatch, on_tpu):
     """Every equation of the mixer under ``jax.grad`` that is under
     ``hvdt.ssd.scan`` is under exactly one of ``.chunk``, ``.state`` and
     ``.out`` (so ``ssd_chunk_ms + ssd_state_ms + ssd_out_ms`` is
-    ``ssd_scan_ms``), and the rest of it under ``.proj``, ``.conv`` or
-    ``.norm``."""
+    ``ssd_scan_ms``), the backwards of the two hand-written rules among
+    them (they carry the forwards' scopes), and the rest of it under
+    ``.proj``, ``.conv`` or ``.norm``.  In both schedules; in Mosaic's the
+    calls are the chunk's own state under ``.chunk`` and y under ``.out``,
+    forward and backward, each under its ``hvdt.kernel.ssd_*`` name."""
+    from horovod_tpu.ops import gated_delta as gd
     from horovod_tpu.ops.ssd import mamba2_mixer
 
+    # sizes the kernels tile where the platform is answered as a TPU
+    sizes = dict(heads=8, head_dim=16, state=128, chunk=128) if on_tpu \
+        else dict(heads=4, head_dim=8, state=16, chunk=16)
     cfg = models.TransformerConfig(layers=1, d_model=32, period=(
         models.LayerKind(heads=0, kv_heads=0, d_ff=32,
-                         ssm=models.StateSpaceMixer(
-                             heads=4, head_dim=8, state=16, chunk=16)),))
+                         ssm=models.StateSpaceMixer(**sizes)),))
     p = jax.eval_shape(lambda k: models.transformer_init(k, cfg),
                        jax.random.PRNGKey(0))["period"]["0"]
     p = jax.tree.map(lambda a: jnp.ones(a.shape[2:], a.dtype), p)
     grad = jax.grad(lambda x, p: mamba2_mixer(
         x, p, proj=lambda a, w: a @ w, eps=1e-5,
         **cfg.period[0].ssm.sizes).sum(), argnums=(0, 1))
-    stacks = [s for _, s in _name_stacks(jax.make_jaxpr(grad)(
-        jnp.ones((1, 64, 32)), p).jaxpr)]
+    monkeypatch.setattr(gd, "_on_tpu", lambda: on_tpu)
+    jax.clear_caches()
+    try:
+        found = list(_name_stacks(jax.make_jaxpr(grad)(
+            jnp.ones((1, 4 * sizes["chunk"], 32)), p).jaxpr))
+    finally:
+        jax.clear_caches()
+    stacks = [s for _, s in found]
     scan = [re.findall(r"hvdt\.ssd\.scan\.(\w+)", s) for s in stacks
             if "hvdt.ssd.scan" in s]
     assert len(scan) > 50 and all(
@@ -898,3 +930,11 @@ def test_the_scans_children_account_for_all_of_it():
             and "hvdt.ssd" in s]
     assert rest and all(re.search(r"hvdt\.ssd\.(proj|conv|norm)", s)
                         for s in rest)
+    calls = [(re.search(r"hvdt\.ssd\.scan\.(\w+)", s).group(1),
+              s.split("hvdt.kernel.")[1].strip("/)"),
+              s.startswith("transpose(")) for prim, s in found
+             if prim == "pallas_call"]
+    assert sorted(calls) == (sorted([
+        ("chunk", "ssd_chunk_state", False), ("out", "ssd_chunk_out", False),
+        ("chunk", "ssd_chunk_state_bwd", True),
+        ("out", "ssd_chunk_out_bwd", True)]) if on_tpu else [])

@@ -52,7 +52,11 @@ KNOBS: Dict[str, Knob] = {
         # --- fusion / cycle (ref: HOROVOD_FUSION_THRESHOLD common.h:112,
         #     HOROVOD_CYCLE_TIME :113) ---
         _k("HVDT_FUSION_THRESHOLD", 64 * 1024 * 1024, int,
-           "Tensor-fusion bucket size in bytes for fused collectives. "
+           "Tensor-fusion bucket size in bytes for fused collectives: "
+           "how many leaves one collective carries, not a buffer's size "
+           "(on the exact wire a bucket is one psum over its leaves' own "
+           "shapes, which XLA combines into a variadic all-reduce; only "
+           "the quantized, hierarchical and Adasum wires pack it flat). "
            "64 MiB default (TPU HBM-friendly; ref default 128 MiB)."),
         _k("HVDT_CYCLE_TIME", 0.0, float,
            "Background-loop cycle time in ms for the eager path. 0 = run "

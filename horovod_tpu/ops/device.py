@@ -398,9 +398,12 @@ def alltoall(x, axis: AxisName = "dp", split_axis: int = 0, concat_axis: int = 0
 # Tensor fusion: bucketed fused allreduce over a pytree of gradients.
 # (ref: FusionBufferManager common/fusion_buffer_manager.{h,cc};
 #  FuseResponses controller.cc:808; fused memcpy collective_operations.cc.)
-# On TPU the "fusion buffer" is a flat concatenated array per (dtype, bucket)
-# — XLA emits a single all-reduce per bucket, the concat/split melt into
-# copies that fuse with neighbours.
+# On TPU a bucket is a collective, not a buffer: NCCL wants one pointer, XLA
+# takes several operands in one all-reduce (its combiner builds it from the
+# per-leaf all_reduces one psum of a list lowers to), so the leaves of a
+# (dtype, bucket) go in their own shapes.  Only a wire that cuts the payload
+# into blocks or shards (or Adasum, whose dot products span it) packs a flat
+# vector.
 # ---------------------------------------------------------------------------
 
 _threshold_warned = False
@@ -475,8 +478,20 @@ def fused_allreduce(tree, axis: AxisName = "dp", op: ReduceOp = ReduceOp.AVERAGE
                     prescale_factor: float = 1.0,
                     postscale_factor: float = 1.0,
                     wire_dtype: Optional[Any] = None):
-    """Allreduce a pytree as few fused flat collectives (the hot path of
+    """Allreduce a pytree as few fused collectives (the hot path of
     DistributedOptimizer — ref call stack SURVEY.md §3.2).
+
+    A bucket (``fused_allreduce_buckets``: same dtype, up to
+    ``threshold_bytes``) is one collective, not a buffer.  What it
+    carries follows the wire, which the code can observe, and no knob: an
+    elementwise reduction on the exact or a cast wire hands the bucket's
+    leaves to one ``psum`` in their own shapes (XLA combines the
+    operands into a variadic all-reduce) and returns the results as they
+    come (no ravel / concatenate before, no slice / reshape after: on a
+    TPU those were physical relayouts of every tiled leaf); the
+    block-scaled, hierarchical and Adasum reductions below work on the
+    payload's whole extent and keep one flat vector per bucket.  Same
+    sums either way, bit for bit.
 
     ``wire_dtype`` optionally casts buckets for the reduction (bf16 wire
     compression — ref: tensorflow/compression.py:141) and casts back.
@@ -540,27 +555,36 @@ def fused_allreduce(tree, axis: AxisName = "dp", op: ReduceOp = ReduceOp.AVERAGE
     out_leaves: List[Optional[jax.Array]] = [None] * len(leaves)
     for bi, bucket in enumerate(buckets):
         parts = [leaves[i] for i in bucket]
-        shapes = [p.shape for p in parts]
-        sizes = [p.size for p in parts]
-        flat = jnp.concatenate([jnp.ravel(p) for p in parts]) if len(parts) > 1 \
-            else jnp.ravel(parts[0])
-        orig_dtype = flat.dtype
+        orig_dtype = jnp.result_type(parts[0])
         float_bucket = jnp.issubdtype(orig_dtype, jnp.floating)
         hier_bucket = hier and float_bucket
-        if wire_dtype is not None and flat.dtype != wire_dtype \
+        quant_bucket = quant_wire and float_bucket
+        # The payload's form follows the wire.  An elementwise reduction
+        # takes the leaves as they are; the block-scaled and
+        # reduce-scatter wires cut the payload into blocks / shards, and
+        # Adasum's dot products run over its whole extent, so those three
+        # keep one flat vector per bucket.
+        as_leaves = not (hier_bucket or quant_bucket
+                         or op == ReduceOp.ADASUM)
+        sent = parts if as_leaves else [
+            jnp.concatenate([jnp.ravel(p) for p in parts])
+            if len(parts) > 1 else jnp.ravel(parts[0])]
+        if wire_dtype is not None and orig_dtype != wire_dtype \
                 and not hier_bucket:
-            flat = flat.astype(wire_dtype)
+            sent = [p.astype(wire_dtype) for p in sent]
         if _rec is not None or _flight is not None:
-            bucket_bytes = int(flat.size) * jnp.dtype(flat.dtype).itemsize
-            quant_bucket = quant_wire and float_bucket
+            wire_name = jnp.dtype(sent[0].dtype).name
+            bucket_size = sum(int(p.size) for p in sent)
+            bucket_bytes = bucket_size * jnp.dtype(sent[0].dtype).itemsize
+            payload = "leaves" if as_leaves else "flat"
             if _rec is not None:
                 _rec.observe_fusion_fill(
                     bucket_bytes / float(threshold_bytes))
                 if not quant_bucket and not hier_bucket:
                     _rec.record_collective(
                         "allreduce", jnp.dtype(orig_dtype).name,
-                        jnp.dtype(flat.dtype).name, bucket_bytes,
-                        count=len(parts), path="jit", axis=_axis_label)
+                        wire_name, bucket_bytes, count=len(bucket),
+                        path="jit", axis=_axis_label, payload=payload)
             if _flight is not None and not quant_bucket:
                 # One traced event per compiled bucket program (under jit
                 # the program, not this host code, runs the collective).
@@ -568,11 +592,11 @@ def fused_allreduce(tree, axis: AxisName = "dp", op: ReduceOp = ReduceOp.AVERAGE
                     op="allreduce",
                     name=f"hier.b{bi}" if hier_bucket else f"fused.b{bi}",
                     dtype=jnp.dtype(orig_dtype).name,
-                    shape=(int(flat.size),), nbytes=bucket_bytes,
+                    shape=(bucket_size,), nbytes=bucket_bytes,
                     wire=(f"{_res.fast.wire}/{_res.slow.wire}"
-                          if hier_bucket
-                          else jnp.dtype(flat.dtype).name),
-                    path="jit", count=len(parts), axis=_axis_label)
+                          if hier_bucket else wire_name),
+                    path="jit", count=len(bucket), axis=_axis_label,
+                    payload=payload)
         # Named scope per fused bucket — the jit-trace analog of the
         # reference's NVTX op ranges; buckets appear as
         # hvdt.fused_allreduce.bN in XPlane/profiler output.
@@ -581,25 +605,30 @@ def fused_allreduce(tree, axis: AxisName = "dp", op: ReduceOp = ReduceOp.AVERAGE
                 from ..transport.hierarchy import hierarchical_allreduce_flat
 
                 red = hierarchical_allreduce_flat(
-                    flat, _res, op=op,
+                    sent[0], _res, op=op,
                     prescale_factor=prescale_factor,
                     postscale_factor=postscale_factor)
-            elif quant_wire and float_bucket:
+            elif quant_bucket:
                 from ..quant.collectives import quantized_allreduce_flat
 
                 red = quantized_allreduce_flat(
-                    flat, axis, op=op,
+                    sent[0], axis, op=op,
                     prescale_factor=prescale_factor,
                     postscale_factor=postscale_factor,
                     wire=quant_leg)
             else:
-                red = allreduce(flat, axis, op, prescale_factor,
-                                postscale_factor)
-        if red.dtype != orig_dtype:
-            red = red.astype(orig_dtype)
+                red = allreduce(sent if as_leaves else sent[0], axis, op,
+                                prescale_factor, postscale_factor)
+        if as_leaves:
+            for i, r in zip(bucket, red):
+                out_leaves[i] = r.astype(orig_dtype)
+            continue
+        red = red.astype(orig_dtype)
         offset = 0
-        for i, shape, sz in zip(bucket, shapes, sizes):
-            out_leaves[i] = lax.dynamic_slice_in_dim(red, offset, sz).reshape(shape)
+        for i in bucket:
+            sz = leaves[i].size
+            out_leaves[i] = lax.dynamic_slice_in_dim(
+                red, offset, sz).reshape(leaves[i].shape)
             offset += sz
     return jax.tree.unflatten(treedef, out_leaves)
 

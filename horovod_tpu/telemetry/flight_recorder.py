@@ -99,7 +99,8 @@ class FlightRecorder:
     # -- recording ----------------------------------------------------------
     def _new_event(self, op: str, name: str, dtype: str, shape, nbytes: int,
                    wire: str, path: str, count: int,
-                   status: str, axis: str = "") -> Dict[str, Any]:
+                   status: str, axis: str = "",
+                   payload: str = "") -> Dict[str, Any]:
         ev = {
             "seq": 0,                       # assigned under the lock
             "op": str(op).lower(),
@@ -115,6 +116,10 @@ class FlightRecorder:
             # set) — lets a desync report say WHICH interconnect tier
             # the divergent collective was crossing.
             "axis": str(axis),
+            # A fused bucket's form: "leaves" (``count`` operands of one
+            # psum in their own shapes, ``shape`` their total size) or "flat"
+            # (one packed vector); "" where no bucket is involved.
+            "payload": str(payload),
             "start_ts": time.time(),
             "end_ts": None,
             "status": status,
@@ -155,10 +160,11 @@ class FlightRecorder:
     def record(self, op: str, name: str, dtype: str = "",
                shape: Optional[Sequence[int]] = None, nbytes: int = 0,
                wire: str = "", path: str = "jit", count: int = 1,
-               status: str = TRACED, axis: str = "") -> int:
+               status: str = TRACED, axis: str = "",
+               payload: str = "") -> int:
         """One-shot event (jit trace-time buckets, external sequences)."""
         ev = self._new_event(op, name, dtype, shape, nbytes, wire, path,
-                             count, status, axis)
+                             count, status, axis, payload)
         ev["end_ts"] = ev["start_ts"]
         return self._append(ev)
 

@@ -16,10 +16,12 @@ wrapper objects and no metric lookups.  Tests identity-check both.
 
 Metric catalog (docs/observability.md has the full table):
 
-* ``hvdt_collective_bytes_total{op,dtype,wire,path[,axis]}`` — bytes on
-  wire (jit paths label the mesh axis the collective reduces over;
-  hierarchical transport records one series per tier hop)
-* ``hvdt_collectives_total{op,dtype,wire,path[,axis]}`` — collective count
+* ``hvdt_collective_bytes_total{op,dtype,wire,path[,axis][,payload]}`` —
+  bytes on wire (jit paths label the mesh axis the collective reduces
+  over; hierarchical transport records one series per tier hop;
+  ``fused_allreduce`` labels a bucket's form, ``payload=leaves|flat``)
+* ``hvdt_collectives_total{op,dtype,wire,path[,axis][,payload]}`` —
+  collective count (a fused bucket counts the leaves it carries)
 * ``hvdt_wire_bytes_total{axis,wire}`` — per-mesh-axis wire bytes (the
   hierarchical-savings view: compare the dcn-axis series against the
   ici-axis series on /metrics)
@@ -115,14 +117,21 @@ class CollectiveRecorder:
     # -- collectives --------------------------------------------------------
     def record_collective(self, op: str, dtype: str, wire: str,
                           nbytes: float, count: int = 1,
-                          path: str = "eager", axis: str = "") -> None:
+                          path: str = "eager", axis: str = "",
+                          payload: str = "") -> None:
         """``axis`` (when known — the jit paths pass the mesh axis/tier
         the collective reduces over) adds an axis label to the main
         counters AND books the per-axis ``hvdt_wire_bytes_total``
         series; empty (eager/negotiated paths, where the reduce group
-        is a process set, not a mesh axis) keeps the legacy label set."""
+        is a process set, not a mesh axis) keeps the legacy label set.
+        ``payload`` (``fused_allreduce`` alone passes it) labels the
+        bucket's form: ``leaves`` (one psum over the leaves' own
+        shapes) or ``flat`` (packed into one vector);
+        ``count`` is the leaves the collective carries."""
         labels = dict(op=str(op).lower(), dtype=str(dtype),
                       wire=str(wire), path=path)
+        if payload:
+            labels["payload"] = str(payload)
         if axis:
             labels["axis"] = str(axis)
             self._wire_bytes.inc(float(nbytes), axis=str(axis),

@@ -391,11 +391,11 @@ CATALOG: Dict[str, MetricSpec] = {
     for s in [
         # -- collectives (telemetry/instrument.py) --
         _m("hvdt_collective_bytes_total", "counter",
-           ("op", "dtype", "wire", "path", "axis"),
+           ("op", "dtype", "wire", "path", "axis", "payload"),
            "Bytes on the wire per collective (path=eager counts "
            "executions; path=jit counts traced programs)"),
         _m("hvdt_collectives_total", "counter",
-           ("op", "dtype", "wire", "path", "axis"),
+           ("op", "dtype", "wire", "path", "axis", "payload"),
            "Collectives recorded, labelled op/dtype/wire/path"),
         _m("hvdt_wire_bytes_total", "counter", ("axis", "wire"),
            "Bytes on the wire per mesh axis — the per-tier view of "

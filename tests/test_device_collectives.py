@@ -5,6 +5,8 @@ correctness incl. average/prescale/postscale (test_torch.py:59+), here
 expressed through shard_map over a simulated 8-device CPU mesh (SURVEY.md §4).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -325,3 +327,220 @@ def test_alltoall_uneven_rejects_bad_splits(mesh8):
         _per_rank(mesh8,
                   lambda t: dev.alltoall_uneven(t[0], M, "dp")[0],
                   jnp.zeros((8, 8, 2)), in_spec=P("dp"), out_spec=P("dp"))
+
+
+# ---------------------------------------------------------------------------
+# fused_allreduce: a bucket is a collective; its payload's form follows the
+# wire (PR 47).  The exact and the cast wires send the leaves as they are,
+# the wires that cut the payload up (block-scaled, reduce-scatter) and Adasum
+# keep one flat vector.
+# ---------------------------------------------------------------------------
+
+
+def _flat_oracle(leaves, axis, op, threshold_bytes, prescale=1.0,
+                 postscale=1.0, wire_dtype=None):
+    """The flat-buffer exchange as it stood before PR 47, on the same
+    bucket plan: ravel + concatenate, one reduction of the vector, slice
+    + reshape back."""
+    out = [None] * len(leaves)
+    for bucket in dev.fused_allreduce_buckets(leaves, threshold_bytes):
+        parts = [leaves[i] for i in bucket]
+        flat = jnp.concatenate([jnp.ravel(p) for p in parts])
+        orig = flat.dtype
+        if wire_dtype is not None:
+            flat = flat.astype(wire_dtype)
+        red = dev.allreduce(flat, axis, op, prescale, postscale)
+        red = red.astype(orig)
+        offset = 0
+        for i in bucket:
+            out[i] = jax.lax.dynamic_slice_in_dim(
+                red, offset, leaves[i].size).reshape(leaves[i].shape)
+            offset += leaves[i].size
+    return out
+
+
+def _mixed_leaves(seed=0):
+    """Per-rank leaves of unlike shapes and dtypes, stacked over 8 ranks."""
+    rng = np.random.RandomState(seed)
+    f32 = lambda *s: jnp.asarray(rng.randn(8, *s), jnp.float32)
+    return [f32(3, 5), f32(7), f32(), f32(4, 2, 3),
+            jnp.asarray(rng.randn(8, 6, 2), jnp.bfloat16),
+            jnp.asarray(rng.randint(-50, 50, (8, 9)), jnp.int32),
+            jnp.asarray(rng.randn(8, 11), jnp.bfloat16)]
+
+
+@pytest.mark.parametrize("threshold", [64, 1 << 20],
+                         ids=["a_leaf_a_bucket", "many_leaves_a_bucket"])
+@pytest.mark.parametrize("wire", [None, jnp.bfloat16], ids=["exact", "bf16"])
+@pytest.mark.parametrize("op", [ReduceOp.SUM, ReduceOp.AVERAGE, ReduceOp.MIN,
+                                ReduceOp.MAX], ids=lambda op: op.name)
+def test_fused_allreduce_on_leaves_equals_the_flat_buffer_bitwise(
+        mesh8, op, wire, threshold):
+    leaves = _mixed_leaves()
+    plan = dev.fused_allreduce_buckets([l[0] for l in leaves], threshold)
+    assert (max(map(len, plan)) == 1) == (threshold == 64)
+
+    pre, post = (0.5, 3.0) if op == ReduceOp.SUM else (1.0, 1.0)
+
+    def body(*ls):
+        ls = list(ls)
+        got = dev.fused_allreduce(ls, "dp", op, threshold_bytes=threshold,
+                                  prescale_factor=pre, postscale_factor=post,
+                                  wire_dtype=wire)
+        want = _flat_oracle(ls, "dp", op, threshold, pre, post, wire)
+        return tuple(got), tuple(want)
+
+    n = len(leaves)
+    got, want = shard_map(body, mesh=mesh8, in_specs=(P("dp"),) * n,
+                          out_specs=((P(),) * n, (P(),) * n))(*leaves)
+    for g, w, leaf in zip(got, want, leaves):
+        assert g.dtype == w.dtype == leaf.dtype
+        assert g.shape == w.shape == (1,) + leaf.shape[1:]
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+
+
+def _exchange_ops(text):
+    """(op, operand types, result types) of every StableHLO operation
+    under the ``hvdt.exchange`` scope in
+    ``Lowered.as_text(debug_info=True)``; a region op (all_reduce) carries
+    its types and location on the line that closes it."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    found, opened = [], None
+    for line in text.splitlines():
+        op = re.search(r'"?(stablehlo\.\w+)"?', line)
+        where = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if line.rstrip().endswith("({"):
+            opened = op.group(1)
+            continue
+        if line.lstrip().startswith("}) :"):
+            op_name, opened = opened, None
+        elif op and where:
+            op_name = op.group(1)
+        else:
+            continue
+        if where and "hvdt.exchange" in names.get(where.group(1), ""):
+            types = line.split(" : ", 1)[1] if " : " in line else ""
+            found.append((op_name, *(re.findall(r"tensor<([^>]*)>", side)
+                                     for side in types.partition("->")[::2])))
+    return found
+
+
+_STEP_PARAMS = {"w": (6, 8, 16), "b": (16,), "e": (32, 8), "g": ()}
+
+
+def _dp_step_text(hvd, mesh, axis, **optimizer_kwargs):
+    """The lowered text of a step shaped like ``benchmark/harness.py``
+    ``build_dp_step``: per-rank gradients of ``pvary_tree``'d parameters
+    into ``DistributedOptimizer``'s update."""
+    import optax
+
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3), axis=axis,
+                                   **optimizer_kwargs)
+    params = {k: jnp.ones(s) for k, s in _STEP_PARAMS.items()}
+    state = opt.init(params)
+
+    def local_step(params, state, x):
+        diff = hvd.optimizer.pvary_tree(params, axis)
+        loss, grads = jax.value_and_grad(
+            lambda p: sum((l ** 2).sum() for l in jax.tree.leaves(p))
+            * x.mean())(diff)
+        updates, state = opt.update(grads, state, params)
+        return (optax.apply_updates(params, updates), state,
+                jax.lax.pmean(loss, axis))
+
+    step = jax.jit(jax.shard_map(
+        local_step, mesh=mesh, in_specs=(P(), P(), P(axis)),
+        out_specs=(P(), P(), P())))
+    return step.lower(params, state,
+                      jnp.ones((16, 4))).as_text(debug_info=True)
+
+
+_RELAYOUTS = {"stablehlo.reshape", "stablehlo.concatenate",
+              "stablehlo.dynamic_slice"}
+
+
+@pytest.mark.parametrize("wire,elem", [("none", "f32"), ("bf16", "bf16")])
+def test_a_dp_step_exchanges_the_leaves_in_their_own_shapes(hvd, mesh8, wire,
+                                                            elem):
+    from horovod_tpu.ops.compression import Compression
+
+    ops = _exchange_ops(_dp_step_text(
+        hvd, mesh8, "dp", compression=getattr(Compression, wire)))
+    assert not _RELAYOUTS & {op for op, _, _ in ops}
+    reduced = sorted(t[0] for op, t, _ in ops
+                     if op == "stablehlo.all_reduce")
+    leaves = sorted("x".join(map(str, s)) + ("x" if s else "") + elem
+                    for s in _STEP_PARAMS.values())
+    assert reduced == leaves
+
+
+def _int8_step(hvd, mesh8, monkeypatch):
+    from horovod_tpu.ops.compression import Compression
+
+    return _dp_step_text(hvd, mesh8, "dp", compression=Compression.int8)
+
+
+def _hierarchical_step(hvd, mesh8, monkeypatch):
+    from jax.sharding import Mesh
+
+    from horovod_tpu import transport
+
+    monkeypatch.setenv("HVDT_TRANSPORT", "auto")
+    transport.reset()
+    try:
+        return _dp_step_text(
+            hvd, Mesh(mesh8.devices.reshape(2, 4), ("dcn", "ici")),
+            ("dcn", "ici"))
+    finally:
+        transport.reset()
+
+
+def _adasum_step(hvd, mesh8, monkeypatch):
+    return _dp_step_text(hvd, mesh8, "dp", op=ReduceOp.ADASUM)
+
+
+@pytest.mark.parametrize("lower,collective", [
+    (_int8_step, "stablehlo.all_to_all"),
+    (_hierarchical_step, "stablehlo.reduce_scatter"),
+    (_adasum_step, "stablehlo.all_to_all"),
+], ids=["int8", "hierarchical", "adasum"])
+def test_wires_that_cut_the_payload_up_keep_the_flat_bucket(
+        hvd, mesh8, monkeypatch, lower, collective):
+    ops = _exchange_ops(lower(hvd, mesh8, monkeypatch))
+    assert {"stablehlo.dynamic_slice", collective} <= {op for op, _, _ in ops}
+    # The bucket's leaves are packed into one vector of their total size.
+    total = sum(int(np.prod(s)) for s in _STEP_PARAMS.values())
+    assert [f"{total}xf32"] in [out for op, _, out in ops
+                                if op == "stablehlo.concatenate"]
+
+
+@pytest.mark.parametrize("op,payload", [(ReduceOp.SUM, "leaves"),
+                                        (ReduceOp.ADASUM, "flat")])
+def test_the_recorders_say_which_form_a_bucket_took(mesh8, monkeypatch, op,
+                                                    payload):
+    from horovod_tpu.telemetry import flight_recorder as frm
+    from horovod_tpu.telemetry import instrument as tinst
+    from horovod_tpu.telemetry import metrics as tmetrics
+
+    monkeypatch.setenv("HVDT_TELEMETRY", "1")
+    monkeypatch.setenv("HVDT_FLIGHT_RECORDER", "1")
+    tmetrics.reset_default_registry()
+    tinst.reset()
+    frm.reset()
+    try:
+        leaves = [jnp.ones((8, 64, 4)), jnp.ones((8, 256))]
+        shard_map(lambda a, b: tuple(dev.fused_allreduce([a, b], "dp", op)),
+                  mesh=mesh8, in_specs=(P("dp"), P("dp")),
+                  out_specs=(P(), P()))(*leaves)
+        (event,) = [e for e in frm.get_flight_recorder().events()
+                    if e["name"] == "fused.b0"]
+        # Either form: the leaves a collective carries and their size.
+        assert (event["payload"], event["count"], event["shape"]) == (
+            payload, 2, [512])
+        count = tmetrics.default_registry().get("hvdt_collectives_total")
+        assert count.value(op="allreduce", dtype="float32", wire="float32",
+                           path="jit", axis="dp", payload=payload) == 2
+    finally:
+        tmetrics.reset_default_registry()
+        tinst.reset()
+        frm.reset()

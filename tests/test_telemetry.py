@@ -199,7 +199,7 @@ class TestCollectiveCounters:
         # per-shard bucket: (1, 64) f32 = 256 B, recorded at trace time
         # (jit-path records carry the reduce-axis label)
         assert c.value(op="allreduce", dtype="float32", wire="float32",
-                       path="jit", axis="dp") == 64 * 4
+                       path="jit", axis="dp", payload="leaves") == 64 * 4
         wb = telemetry_on.get("hvdt_wire_bytes_total")
         assert wb.value(axis="dp", wire="float32") == 64 * 4
         fill = telemetry_on.get("hvdt_fusion_fill_ratio")

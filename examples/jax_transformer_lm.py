@@ -84,6 +84,21 @@ PRESETS = {
                                "granite_4_0_h_micro.json"),
         seq=8192, batch=1, dtype="bfloat16", remat=True, loss_chunk=8192,
         dp=1, tp=1),
+    # LFM2-24B-A2B (LiquidAI, 23.8B-A2.3B) as one chip's share of a
+    # four-chip layer: the benchmark's configuration lfm2_24b_a2b (cell
+    # lfm2_24b_s8192): layers 1-5 of 40 (a leading conv + dense layer, then
+    # attention, conv, conv, conv, all sparse), double-gated short
+    # convolutions, GQA-64 with q / k norm, top-4 of 64 experts picked by
+    # sigmoid score plus a selection bias (16 held), a tied head over 8,192
+    # of 65,536 rows; 771M parameters, 11.5 GiB of training state.  (The
+    # benchmark's family also ties the router's start over the four ranks
+    # and draws the bias; here both start as the published constructor's.)
+    "lfm2_24b": dict(
+        published=os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               os.pardir, "benchmark", "configs",
+                               "lfm2_24b_a2b.json"),
+        seq=8192, batch=2, dtype="bfloat16", remat=True, loss_chunk=8192,
+        dp=1, tp=1),
 }
 
 
@@ -111,9 +126,10 @@ def main():
                         "config.json describes (models.config_from_"
                         "published): per-kind heads, windows and rotary "
                         "settings, dense and sparse feed-forwards, EVA "
-                        "attention, Mamba-2 layers, several prediction "
-                        "heads.  The "
-                        "file's own layers / experts / experts_first / "
+                        "attention, Mamba-2 and short-convolution layers, "
+                        "several prediction heads.  The "
+                        "file's own layers / layers_first / experts / "
+                        "experts_first / "
                         "vocab / heads / heads_first keys, where present, "
                         "cut it to this device's share; the size flags "
                         "are ignored; data-parallel layouts only")
@@ -196,6 +212,7 @@ def main():
             published = json.load(f)
         cfg = config_from_published(
             published, layers=published.get("layers"),
+            layers_first=published.get("layers_first", 0),
             experts=published.get("experts"),
             experts_first=published.get("experts_first", 0),
             vocab=published.get("vocab"),
@@ -205,6 +222,7 @@ def main():
             # what the model's code does and its config.json has no key
             # for, where the file states it
             shared_gate=published.get("shared_expert_gate", False),
+            normalize_eps=published.get("router_normalize_eps", 0.0),
             **{field: published[key] for field, key in (
                 ("out_gate", "attn_output_gate"), ("qk_norm", "qk_norm"),
                 ("zero_centered_norm", "zero_centered_norm"),

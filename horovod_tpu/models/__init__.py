@@ -14,6 +14,7 @@ from .transformer import (  # noqa: F401
     LayerKind,
     LinearMixer,
     StateSpaceMixer,
+    ShortConv,
     Eva,
     Rope,
     Experts,

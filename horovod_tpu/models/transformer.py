@@ -38,6 +38,7 @@ from ..ops.attention import attention
 from ..ops.eva import eva_attention, eva_visible_pairs
 from ..ops.gated_delta import gated_delta_net, scan_macs_per_token
 from ..ops.pallas_kernels import rope
+from ..ops.short_conv import gated_short_conv
 from ..ops.ssd import mamba2_mixer
 from ..ops.ssd import scan_macs_per_token as ssd_macs_per_token
 from ..parallel.moe import (ROUTE_SAVED, moe_dispatch_combine,
@@ -47,8 +48,8 @@ from ..parallel.ring_attention import ring_attention
 from ..quant import fp8 as _fp8
 
 __all__ = [
-    "TransformerConfig", "LayerKind", "LinearMixer", "StateSpaceMixer", "Eva",
-    "Rope", "Experts",
+    "TransformerConfig", "LayerKind", "LinearMixer", "StateSpaceMixer",
+    "ShortConv", "Eva", "Rope", "Experts",
     "config_from_published", "transformer_init", "transformer_apply",
     "transformer_loss", "transformer_block_diffusion_loss",
     "block_diffusion_corrupt", "transformer_logical_axes",
@@ -133,6 +134,16 @@ class StateSpaceMixer:
 
 
 @dataclasses.dataclass(frozen=True)
+class ShortConv:
+    """The sizes of a double-gated short convolution
+    (``ops/short_conv.py``): a causal depthwise convolution of ``taps``
+    taps (with a bias where ``bias``) over the model's own width, between
+    two elementwise gates."""
+    taps: int = 3
+    bias: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
 class Eva:
     """The sizes of EVA attention (``ops/eva.py``): exact softmax inside
     aligned windows of ``window`` positions, joined in one softmax with a
@@ -150,8 +161,9 @@ class LayerKind:
     its own head counts, window and rotary settings, with ``eva`` set under
     EVA's two masks in place of ``window``'s, with ``rope`` None without any
     position term; or, with ``linear`` set, the Gated DeltaNet of those
-    sizes, or with ``ssm`` the Mamba-2 mixer of those, which read none of
-    the attention fields) and its feed-forward (dense SwiGLU of width
+    sizes, with ``ssm`` the Mamba-2 mixer of those, or with ``conv`` the
+    double-gated short convolution, which read none of the attention
+    fields) and its feed-forward (dense SwiGLU of width
     ``d_ff``, or with ``sparse`` the configuration's expert layer,
     ``TransformerConfig.moe``).
     ``heads`` / ``kv_heads`` are the heads held here: heads ``heads_first
@@ -168,6 +180,7 @@ class LayerKind:
     eva: Optional[Eva] = None
     ssm: Optional[StateSpaceMixer] = None
     heads_first: int = 0
+    conv: Optional[ShortConv] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,7 +188,11 @@ class Experts:
     """The expert layer of a configuration: ``held`` experts of width
     ``d_ff`` live here, experts ``first .. first + held - 1`` of the
     ``routed`` the router scores (0 = the held ones are all there are);
-    a token picks ``per_token``; see ``parallel.moe.moe_held_experts``."""
+    a token picks ``per_token``; see ``parallel.moe.moe_held_experts``.
+    With ``select_bias`` a layer carries ``router_bias`` [routed], float32:
+    the picks are the largest of score + bias, the weights the scores at
+    them without it; ``normalize_eps`` > 0 is added to the picked scores'
+    sum before they are divided by it."""
     held: int
     d_ff: int
     routed: int = 0
@@ -187,6 +204,8 @@ class Experts:
     gated: bool = True               # SwiGLU experts (else silu(x W_up) W_down)
     shared_d_ff: int = 0             # > 0: a shared expert of this width
     shared_gate: bool = False        # its output times sigmoid(x . ws_sg)
+    select_bias: bool = False
+    normalize_eps: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -289,7 +308,7 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.heads)
         if self.diffusion_block and (
                 self.sp > 1 or self.pp > 1 or any(
-                    k.window or k.linear or k.eva or k.ssm
+                    k.window or k.linear or k.eva or k.ssm or k.conv
                     for k in self.leading + self.period)):
             raise ValueError(
                 "diffusion over blocks runs full softmax attention with sp "
@@ -358,6 +377,7 @@ class TransformerConfig:
 
 def config_from_published(published: Dict[str, Any], *,
                           layers: Optional[int] = None,
+                          layers_first: int = 0,
                           experts: Optional[int] = None,
                           experts_first: int = 0,
                           vocab: Optional[int] = None,
@@ -365,6 +385,7 @@ def config_from_published(published: Dict[str, Any], *,
                           heads_first: int = 0,
                           router_score: str = "sigmoid",
                           shared_gate: bool = False,
+                          normalize_eps: float = 0.0,
                           **fields) -> TransformerConfig:
     """A pattern configuration from a model's published settings, cut to
     this device's share of it.
@@ -379,35 +400,41 @@ def config_from_published(published: Dict[str, Any], *,
     ``linear_value_head_dim``, ``linear_conv_kernel_dim`` / ``mamba``, a
     Mamba-2 mixer of ``mamba_n_heads``, ``mamba_d_head``,
     ``mamba_d_state``, ``mamba_n_groups``, ``mamba_d_conv``,
-    ``mamba_conv_bias``, ``mamba_chunk_size``; any other entry is refused)
-    or ``full_attention_interval`` (every
+    ``mamba_conv_bias``, ``mamba_chunk_size`` / ``conv``, a double-gated
+    short convolution of ``conv_L_cache`` taps with ``conv_bias``; any
+    other entry is refused) or ``full_attention_interval`` (every
     n-th layer full attention, the others linear, as ``transformers``
     derives ``layer_types`` from it), ``rope_parameters`` (by layer type,
     or one group) or ``rope_theta`` and ``partial_rotary_factor`` at top
     level, ``position_embedding_type`` (``rope``, or ``nope``: attention
-    without a position term), ``mlp_layer_types`` (``dense`` of
+    without a position term), ``mlp_layer_types`` or ``num_dense_layers``
+    (that many leading layers dense, the rest sparse) (``dense`` of
     ``shared_intermediate_size``, else of ``intermediate_size`` /
     ``sparse``: ``num_experts`` of ``moe_intermediate_size``,
     ``num_experts_per_tok`` picked, ``norm_topk_prob``,
-    ``moe_routed_scaling_factor``, a shared expert of
-    ``shared_expert_intermediate_size``), ``gating``,
+    ``moe_routed_scaling_factor`` or ``routed_scaling_factor``,
+    ``use_expert_bias`` (a selection bias, ``Experts.select_bias``), a
+    shared expert of ``shared_expert_intermediate_size``), ``gating``,
     ``tie_word_embeddings``, ``vocab_size``, ``num_hidden_layers``;
     ``attention_class`` (``eva``: every attention layer is EVA attention of
     ``window_size`` and ``chunk_size``, its learned vectors started at
     ``init_std``), ``num_pred_heads`` (prediction heads a row),
     ``norm_add_unit_offset`` (every RMSNorm scales by 1 + gain),
-    ``rms_norm_eps``, and the four constants ``embedding_multiplier``,
+    ``rms_norm_eps`` or ``norm_eps``, and the four constants
+    ``embedding_multiplier``,
     ``residual_multiplier``, ``attention_multiplier`` (the score scale)
     and ``logits_scaling`` (the logits' divisor).  No width is an
-    argument.  The cut: the first
-    ``layers`` layers (the leading ones and at least a period), ``experts``
+    argument.  The cut: ``layers`` layers from layer ``layers_first`` on
+    (what is left of the leading ones there and at least a period),
+    ``experts``
     of each sparse layer's experts from ``experts_first`` on (the router
     keeps its width), the first ``vocab`` rows of the vocabulary, ``heads``
     of each attention layer's query heads from ``heads_first`` on with
     their share of the key heads (attention divided by heads: wq, wk, wv
     by columns, wo by rows; what the absent heads would add to the
-    sublayer's output is left out).  ``router_score`` and
-    ``shared_gate`` (the shared expert's sigmoid gate) are what
+    sublayer's output is left out).  ``router_score``,
+    ``shared_gate`` (the shared expert's sigmoid gate) and
+    ``normalize_eps`` (``Experts.normalize_eps``) are what
     ``config.json`` leaves to modelling code, as are the ``fields``
     ``out_gate``, ``qk_norm``, ``zero_centered_norm`` and
     ``diffusion_block`` (the objective: a model trained by diffusion over
@@ -428,7 +455,7 @@ def config_from_published(published: Dict[str, Any], *,
         "linear_attention" if interval and (i + 1) % interval
         else "full_attention" for i in range(depth)]
     known = ("full_attention", "attention", "sliding_attention",
-             "linear_attention", "mamba")
+             "linear_attention", "mamba", "conv")
     unknown = sorted(set(layer_types) - set(known))
     if unknown:
         raise ValueError(f"layer_types holds {unknown}: one of {known}")
@@ -440,8 +467,10 @@ def config_from_published(published: Dict[str, Any], *,
         # experts beside the shared feed-forward; a bias on w_in and w_out
         if c.get(unbuilt):
             raise ValueError(f"{unbuilt}={c[unbuilt]!r} is not built yet")
+    dense_first = c.get("num_dense_layers", 0)
     mlp_types = c.get("mlp_layer_types") or \
-        ["sparse" if c.get("num_experts") else "dense"] * depth
+        ["dense"] * dense_first + ["sparse" if c.get("num_experts")
+                                   else "dense"] * (depth - dense_first)
     dense_width = c.get("shared_intermediate_size") or \
         c.get("intermediate_size")
     ropes = c.get("rope_parameters") or {
@@ -459,6 +488,9 @@ def config_from_published(published: Dict[str, Any], *,
         state=c["mamba_d_state"], groups=c["mamba_n_groups"],
         conv=c["mamba_d_conv"], conv_bias=bool(c["mamba_conv_bias"]),
         chunk=c["mamba_chunk_size"]) if "mamba" in layer_types else None
+    conv = ShortConv(taps=c["conv_L_cache"],
+                     bias=bool(c.get("conv_bias", False))) \
+        if "conv" in layer_types else None
 
     def rope_of(layer_type: str) -> Optional[Rope]:
         if positions == "nope":
@@ -487,6 +519,8 @@ def config_from_published(published: Dict[str, Any], *,
                              **feed_forward)
         if layer_types[i] == "mamba":
             return LayerKind(heads=0, kv_heads=0, ssm=ssm, **feed_forward)
+        if layer_types[i] == "conv":
+            return LayerKind(heads=0, kv_heads=0, conv=conv, **feed_forward)
         held = heads or published_heads[i]
         kv_held, ragged = divmod(c["num_key_value_heads"] * held,
                                  published_heads[i])
@@ -507,28 +541,40 @@ def config_from_published(published: Dict[str, Any], *,
     lead, period = next(
         (n, p) for n in range(depth) for p in range(1, (depth - n) // 2 + 1)
         if all(kinds[i] == kinds[i + p] for i in range(n, depth - p)))
-    layers = layers or depth
+    layers = layers or depth - layers_first
+    if layers_first < 0 or layers_first + layers > depth:
+        raise ValueError(
+            f"layers {layers_first} .. {layers_first + layers - 1} of "
+            f"{depth}")
+    # The held range's own leading layers: what it holds of the stack's.
     # Whole periods only: what does not fill one goes to the leading layers
     # (the whole stack of 1 + 39 layers at period 4 is 4 leading + 9 x 4).
+    lead = max(lead - layers_first, 0)
     lead += (layers - lead) % period
     if layers - lead < period:
         raise ValueError(
-            f"layers={layers}: keep the {lead} leading layers and at least "
-            f"one whole period of {period} after them")
+            f"layers={layers} from layer {layers_first}: keep the {lead} "
+            f"leading layers and at least one whole period of {period} "
+            "after them")
+    kinds = kinds[layers_first:layers_first + layers]
     moe = None
-    if any(k.sparse for k in kinds[:layers]):
+    if any(k.sparse for k in kinds):
         routed = c["num_experts"]
         moe = Experts(
             held=experts or routed, d_ff=c["moe_intermediate_size"],
             routed=routed, per_token=c["num_experts_per_tok"],
             first=experts_first, score=router_score,
             normalize=bool(c.get("norm_topk_prob", True)),
-            scale=float(c.get("moe_routed_scaling_factor", 1.0)),
+            scale=float(c.get("moe_routed_scaling_factor",
+                              c.get("routed_scaling_factor", 1.0))),
             shared_d_ff=c.get("shared_expert_intermediate_size", 0),
-            shared_gate=shared_gate)
+            shared_gate=shared_gate,
+            select_bias=bool(c.get("use_expert_bias", False)),
+            normalize_eps=normalize_eps)
     fields.setdefault("out_gate", "head" if c.get("gating") else "")
     fields.setdefault("pred_heads", c.get("num_pred_heads", 1))
-    fields.setdefault("norm_eps", float(c.get("rms_norm_eps", 1e-6)))
+    fields.setdefault("norm_eps", float(
+        c.get("rms_norm_eps", c.get("norm_eps", 1e-6))))
     for constant in ("embedding_multiplier", "residual_multiplier",
                      "attention_multiplier", "logits_scaling"):
         if constant in c:
@@ -573,6 +619,14 @@ def _init_linear_mixer(ks, cfg: TransformerConfig, m: LinearMixer) -> Dict:
     }
 
 
+def _init_taps(key, taps: int, shape, dtype):
+    """A depthwise convolution's taps or its bias: uniform in
+    +-taps^-0.5, torch's Conv1d default at one channel a group."""
+    bound = taps ** -0.5
+    return jax.random.uniform(key, shape, minval=-bound,
+                              maxval=bound).astype(dtype)
+
+
 def _init_state_space_mixer(ks, cfg: TransformerConfig,
                             m: StateSpaceMixer) -> Dict:
     """The Mamba-2 mixer's leaves (``ops.ssd.mamba2_mixer`` says what each
@@ -583,17 +637,11 @@ def _init_state_space_mixer(ks, cfg: TransformerConfig,
     (arXiv:2405.21060), so that a head's state lives for tens to
     thousands of tokens and not for one."""
     d, pd = cfg.d_model, cfg.param_dtype
-    bound = m.conv ** -0.5
     lo, hi = math.log(1e-3), math.log(1e-1)
-
-    def uniform(shape):
-        return jax.random.uniform(next(ks), shape, minval=-bound,
-                                  maxval=bound).astype(pd)
-
     p = {
         "w_in": _init_linear(next(ks), d,
                              (d, m.inner + m.conv_width + m.heads), pd),
-        "conv": uniform((m.conv, m.conv_width)),
+        "conv": _init_taps(next(ks), m.conv, (m.conv, m.conv_width), pd),
         "a_log": jnp.log(jnp.arange(1, m.heads + 1, dtype=jnp.float32)
                          ).astype(pd),
         "d_skip": jnp.ones((m.heads,), pd),
@@ -601,7 +649,7 @@ def _init_state_space_mixer(ks, cfg: TransformerConfig,
         "w_out": _init_linear(next(ks), m.inner, (m.inner, d), pd),
     }
     if m.conv_bias:
-        p["conv_bias"] = uniform((m.conv_width,))
+        p["conv_bias"] = _init_taps(next(ks), m.conv, (m.conv_width,), pd)
     step = jnp.exp(jax.random.uniform(next(ks), (m.heads,), minval=lo,
                                       maxval=hi))
     # softplus(dt_bias) = step
@@ -609,12 +657,26 @@ def _init_state_space_mixer(ks, cfg: TransformerConfig,
     return p
 
 
+def _init_short_conv(ks, cfg: TransformerConfig, m: ShortConv) -> Dict:
+    """The double-gated short convolution's leaves
+    (``ops.short_conv.gated_short_conv`` says what each is): the taps and
+    the bias as the published constructor's Conv1d draws them."""
+    d, pd = cfg.d_model, cfg.param_dtype
+    p = {"w_in": _init_linear(next(ks), d, (d, 3 * d), pd),
+         "conv": _init_taps(next(ks), m.taps, (m.taps, d), pd),
+         "w_out": _init_linear(next(ks), d, (d, d), pd)}
+    if m.bias:
+        p["conv_bias"] = _init_taps(next(ks), m.taps, (d,), pd)
+    return p
+
+
 def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
     """One layer of a pattern: its mixer (attention of ``kind``'s sizes,
     with ``wg`` or a query projection twice as wide where the output is
     gated and ``q_norm`` / ``k_norm`` where q and k are normed; or the
-    linear or the state-space mixer's leaves) and its dense or sparse
-    feed-forward."""
+    linear, the state-space or the short-convolution mixer's leaves) and
+    its dense or sparse feed-forward (with ``router_bias``, zeros, where
+    the picks take a selection bias: the published constructor's)."""
     d, dh, pd = cfg.d_model, cfg.head_dim, cfg.param_dtype
     h, hk = kind.heads, kind.kv_heads
     # Twelve keys serve every layer from before the shared expert's gate;
@@ -626,6 +688,8 @@ def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
         p.update(_init_linear_mixer(ks, cfg, kind.linear))
     elif kind.ssm is not None:
         p.update(_init_state_space_mixer(ks, cfg, kind.ssm))
+    elif kind.conv is not None:
+        p.update(_init_short_conv(ks, cfg, kind.conv))
     else:
         wide = 2 if cfg.out_gate == "elementwise" else 1
         p.update(
@@ -652,6 +716,8 @@ def _init_layer(key, cfg: TransformerConfig, kind: LayerKind) -> Dict:
     moe = cfg.moe
     e, f = moe.held, moe.d_ff
     p["w_router"] = _init_linear(next(ks), d, (d, moe.routed or e), pd)
+    if moe.select_bias:
+        p["router_bias"] = jnp.zeros((moe.routed or e,), jnp.float32)
     p["w_up"] = _init_linear(next(ks), d, (e, d, f), pd)
     if moe.gated:
         p["w_gate"] = _init_linear(next(ks), d, (e, d, f), pd)
@@ -787,6 +853,13 @@ def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
                         ssd_norm=(None,), w_out=(None, "embed"))
             if kind.ssm.conv_bias:
                 axes["conv_bias"] = (None,)
+        elif kind.conv is not None:
+            # Whole on every tp rank: [B | C | X] are three blocks of one
+            # dimension, which a contiguous shard would cut across.
+            axes.update(w_in=("embed", None), conv=(None, None),
+                        w_out=(None, "embed"))
+            if kind.conv.bias:
+                axes["conv_bias"] = (None,)
         else:
             axes.update(wq=("embed", "heads"), wk=("embed", "kv"),
                         wv=("embed", "kv"), wo=("heads", "embed"))
@@ -800,6 +873,8 @@ def _pattern_logical_axes(cfg: TransformerConfig) -> Dict:
             axes.update(w_router=("embed", None),
                         w_up=("experts", "embed", "mlp"),
                         w_down=("experts", "mlp", "embed"))
+            if cfg.moe.select_bias:
+                axes["router_bias"] = (None,)
             if cfg.moe.gated:
                 axes["w_gate"] = ("experts", "embed", "mlp")
             if cfg.moe.shared_d_ff:
@@ -997,7 +1072,9 @@ def _moe_mlp(p, x, cfg: TransformerConfig):
         out, aux = moe_held_experts(
             tokens, p["w_router"], p["w_up"], p["w_down"], p.get("w_gate"),
             top_k=moe.per_token, experts_first=moe.first, score=moe.score,
-            normalize=moe.normalize, scale=moe.scale, shared_fn=shared)
+            normalize=moe.normalize, scale=moe.scale,
+            select_bias=p["router_bias"] if moe.select_bias else None,
+            normalize_eps=moe.normalize_eps, shared_fn=shared)
     return out.reshape(b, l, d), aux
 
 
@@ -1082,6 +1159,9 @@ def _block(p, x, positions, cfg: TransformerConfig,
         with jax.named_scope("hvdt.ssd"):
             a = mamba2_mixer(_norm(x, p["ln1"], cfg), p, proj=_proj,
                              eps=cfg.norm_eps, **kind.ssm.sizes)
+    elif kind.conv is not None:
+        with jax.named_scope("hvdt.sconv"):
+            a = gated_short_conv(_norm(x, p["ln1"], cfg), p, proj=_proj)
     else:
         with jax.named_scope("hvdt.attention"):
             a = _attention(p, _norm(x, p["ln1"], cfg), positions, cfg, kind)
@@ -1646,7 +1726,8 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
     """Approximate forward-pass matmul FLOPs per token (for MFU metrics):
     the full score square, a window at its width, the output gate's
     projection, a linear mixer's projections, convolution and chunked
-    scan, a state-space mixer's likewise; of a sparse layer the router, a
+    scan, a state-space mixer's likewise, a short convolution's
+    projections and taps; of a sparse layer the router, a
     token's picks that land on held experts in expectation and the shared
     expert with its gate.  Under
     diffusion over blocks a token is two rows of every layer (the noisy
@@ -1667,6 +1748,8 @@ def transformer_flops_per_token(cfg: TransformerConfig) -> float:
             return 2 * (d * (m.inner + m.conv_width + m.heads)
                         + m.conv * m.conv_width + m.inner * d
                         + ssd_macs_per_token(**m.sizes))
+        if kind.conv is not None:
+            return 2 * (d * 3 * d + kind.conv.taps * d + d * d)
         h, hk = kind.heads, kind.kv_heads
         gate = {"": 0, "head": h, "elementwise": h * dh}[cfg.out_gate]
         attn_proj = 2 * d * (h * dh + 2 * hk * dh + h * dh + gate)

@@ -68,7 +68,9 @@ CHUNK = 64
 
 
 def causal_conv(x: jax.Array, w: jax.Array,
-                bias: Optional[jax.Array] = None) -> jax.Array:
+                bias: Optional[jax.Array] = None, *,
+                times: Optional[jax.Array] = None,
+                gate: Optional[jax.Array] = None) -> jax.Array:
     """A causal depthwise convolution: x [B, L, C], w [taps, C], ``bias``
     [C] or none; ``y_t = sum_i w[i] x[t - (taps - 1) + i] + bias`` with
     zeros left of the sequence.  Shifted multiply-adds: each tap's slice of
@@ -77,14 +79,29 @@ def causal_conv(x: jax.Array, w: jax.Array,
     forward / forward + backward (PERF.md, PR 33): this form 2.00 / 7.03;
     the padded input widened to float32 first 5.29 / 11.36; rolls and a
     mask 7.06 / 17.68; ``lax.conv_general_dilated`` with one channel a
-    group 6.55 forward."""
+    group 6.55 forward.
+
+    ``times`` and ``gate`` [B, L, C], each or both, make it the
+    double-gated form ``y_t = gate_t (sum_i w[i] (x times)[t - (taps - 1) +
+    i] + bias)``: the input's gate multiplies each tap's slice and the
+    output's the sum, both in float32 inside that same fusion, so that
+    neither ``x times`` nor the ungated sum exists as an array."""
     taps, l = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    left = ((0, 0), (taps - 1, 0), (0, 0))
+    padded = jnp.pad(x, left)
     w = w.astype(jnp.float32)
-    y = sum(padded[:, i:i + l].astype(jnp.float32) * w[i]
-            for i in range(taps))
+    if times is None:
+        y = sum(padded[:, i:i + l].astype(jnp.float32) * w[i]
+                for i in range(taps))
+    else:
+        with_it = jnp.pad(times, left)
+        y = sum(padded[:, i:i + l].astype(jnp.float32)
+                * with_it[:, i:i + l].astype(jnp.float32) * w[i]
+                for i in range(taps))
     if bias is not None:
         y = y + bias.astype(jnp.float32)
+    if gate is not None:
+        y = y * gate.astype(jnp.float32)
     return y.astype(x.dtype)
 
 

@@ -193,31 +193,57 @@ def _scores(z, score: str):
                      "(expected 'sigmoid' or 'softmax')")
 
 
-def _weights_of_picked(picked, normalize: bool, scale: float):
+def _weights_of_picked(picked, normalize: bool, scale: float,
+                       eps: float = 0.0):
     if normalize:
-        picked = picked / jnp.maximum(picked.sum(-1, keepdims=True), 1e-20)
+        total = picked.sum(-1, keepdims=True)
+        picked = picked / (total + eps if eps else
+                           jnp.maximum(total, 1e-20))
     return picked * scale
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def _picks_of_logits(z, score, top_k, normalize, scale):
+def _picked_and_experts(scores, select_bias, top_k):
+    """A row's scores at its picks and the picks [T, k]: the k largest
+    scores, or with ``select_bias`` [E] the k largest of ``scores +
+    select_bias``, whose picked scores are still the scores' own (the bias
+    chooses and does not weigh)."""
+    if select_bias is None:
+        return lax.top_k(scores, top_k)
+    # A scope of its own: the compiled step says which form it routes by.
+    with jax.named_scope("hvdt.moe.route.select_bias"):
+        experts = lax.top_k(scores + lax.stop_gradient(select_bias),
+                            top_k)[1]
+    lanes = jnp.arange(scores.shape[-1], dtype=experts.dtype)
+    # [T, E] -> [T, k] by comparison, one fused pass: no gather.
+    return jnp.sum(jnp.where(experts[..., None] == lanes,
+                             scores[:, None, :], 0.0), axis=-1), experts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _picks_of_logits(z, select_bias, score, top_k, normalize, scale, eps):
     """Logits [T, E] -> (picked experts [T, k], their weights [T, k]) where
     the cotangent needs a token's k picked scores and no other: ``sigmoid``
-    scores, or ``softmax`` scores divided by their sum over the picks."""
-    return _picks_of_logits_fwd(z, score, top_k, normalize, scale)[0]
+    scores, or ``softmax`` scores divided by their sum over the picks.
+    ``select_bias`` [E] or None: added to the scores for the choice alone;
+    no cotangent reaches it."""
+    return _picks_of_logits_fwd(z, select_bias, score, top_k, normalize,
+                                scale, eps)[0]
 
 
-def _picks_of_logits_fwd(z, score, top_k, normalize, scale):
-    picked, experts = map(_saved, lax.top_k(_scores(z, score), top_k))
+def _picks_of_logits_fwd(z, select_bias, score, top_k, normalize, scale,
+                         eps):
+    picked, experts = map(_saved, _picked_and_experts(
+        _scores(z, score), select_bias, top_k))
     lanes = jnp.arange(z.shape[-1], dtype=experts.dtype)
-    return ((experts, _weights_of_picked(picked, normalize, scale)),
-            (experts, picked, lanes))
+    return ((experts, _weights_of_picked(picked, normalize, scale, eps)),
+            (experts, picked, lanes,
+             None if select_bias is None else jnp.zeros_like(select_bias)))
 
 
-def _picks_of_logits_bwd(score, top_k, normalize, scale, res, g):
-    experts, picked, lanes = res
+def _picks_of_logits_bwd(score, top_k, normalize, scale, eps, res, g):
+    experts, picked, lanes, no_bias_cotangent = res
     _, weights_vjp = jax.vjp(
-        lambda p: _weights_of_picked(p, normalize, scale), picked)
+        lambda p: _weights_of_picked(p, normalize, scale, eps), picked)
     d_picked, = weights_vjp(g[1])
     if score == "sigmoid":
         dz = d_picked * picked * (1.0 - picked)
@@ -229,37 +255,69 @@ def _picks_of_logits_bwd(score, top_k, normalize, scale, res, g):
         dz = d_picked * picked
     # [T, k] -> [T, E] by comparison, one fused pass: no scatter.
     return (jnp.sum(jnp.where(experts[..., None] == lanes, dz[..., None],
-                              0.0), axis=1),)
+                              0.0), axis=1), no_bias_cotangent)
 
 
 _picks_of_logits.defvjp(_picks_of_logits_fwd, _picks_of_logits_bwd)
 
 
+def _count_route(select_bias) -> None:
+    """One traced route, by what chose its picks (trace time, as the
+    collectives' counters: a program's count, not a step's)."""
+    from ..telemetry import instrument as _ti
+
+    rec = _ti.get_recorder()
+    if rec is not None:
+        rec.registry.counter(
+            "hvdt_moe_routes_total",
+            "Expert-layer routes traced, labelled by what chose the picks: "
+            "select=score (the scores themselves) or score_plus_bias (the "
+            "scores plus a selection bias that does not weigh)").inc(
+            1.0, select="score" if select_bias is None
+            else "score_plus_bias")
+
+
 def moe_route(x: jax.Array, w_router: jax.Array, *, top_k: int,
               score: str = "sigmoid", normalize: bool = True,
-              scale: float = 1.0):
+              scale: float = 1.0, select_bias: Optional[jax.Array] = None,
+              normalize_eps: float = 0.0):
     """Scores over every routed expert and each token's picks, in float32
     (the router's product at ``HIGHEST`` precision: a pick is a
     comparison of scores, and a bf16 product decides thousands of them
     otherwise).  ``x`` [T, D], ``w_router`` [D, E] -> (scores [T, E],
     picked experts [T, k], their weights [T, k]).  ``score`` is
     ``"sigmoid"`` or ``"softmax"``; ``normalize`` divides the picked scores
-    by their sum; ``scale`` multiplies the weights.
+    by their sum (plus ``normalize_eps`` where that is set; else the sum is
+    floored at 1e-20); ``scale`` multiplies the weights.  ``select_bias``
+    [E], float32: the picks are the k largest of ``scores + select_bias``
+    and the weights are still made of the scores at them, without it; it
+    is a constant of the step (``stop_gradient``: its gradient is exactly
+    zero) that a balancing rule outside the loss would move.
 
     The picks and the picked scores carry :data:`ROUTE_SAVED`.  The
     weights' cotangent reaches the logits from the k picked scores of a
     token alone (:func:`_picks_of_logits`), except for ``softmax`` scores
-    that are not normalised over the picks, whose rule reads the whole row:
-    there it is autodiff's, through the picked scores gathered by the named
-    picks (``lax.top_k``'s own rule gathers by an index nothing names)."""
+    whose weights are not homogeneous in the picked scores (not normalised
+    over the picks, or normalised with a ``normalize_eps``), whose rule
+    reads the whole row: there it is autodiff's, through the picked scores
+    gathered by the named picks (``lax.top_k``'s own rule gathers by an
+    index nothing names)."""
+    _count_route(select_bias)
+    if select_bias is not None:
+        select_bias = select_bias.astype(jnp.float32)
     z = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST)
     scores = _scores(z, score)
-    if score == "softmax" and not normalize:
-        experts = _saved(lax.top_k(lax.stop_gradient(scores), top_k)[1])
+    if score == "softmax" and (not normalize or normalize_eps):
+        chosen_by = lax.stop_gradient(scores)
+        if select_bias is not None:
+            chosen_by = chosen_by + lax.stop_gradient(select_bias)
+        experts = _saved(lax.top_k(chosen_by, top_k)[1])
         picked = _saved(jnp.take_along_axis(scores, experts, axis=-1))
-        return scores, experts, _weights_of_picked(picked, normalize, scale)
-    return (scores,) + _picks_of_logits(z, score, top_k, normalize, scale)
+        return scores, experts, _weights_of_picked(
+            picked, normalize, scale, normalize_eps)
+    return (scores,) + _picks_of_logits(
+        z, select_bias, score, top_k, normalize, scale, float(normalize_eps))
 
 
 def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
@@ -267,6 +325,8 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
                      *, top_k: int, experts_first: int = 0,
                      score: str = "sigmoid", normalize: bool = True,
                      scale: float = 1.0,
+                     select_bias: Optional[jax.Array] = None,
+                     normalize_eps: float = 0.0,
                      shared_fn: Optional[Callable[[jax.Array], jax.Array]]
                      = None) -> Tuple[jax.Array, MoEAux]:
     """A top-k expert layer over the experts held here, without drops.
@@ -275,7 +335,9 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
     ``E`` routed experts; ``w_up`` / ``w_gate`` [H, D, F] and ``w_down``
     [H, F, D] are the ``H`` held ones, experts ``experts_first ..
     experts_first + H - 1`` of the layer (``w_gate`` None: ``silu(x W_up)
-    W_down``, else SwiGLU ``(silu(x W_gate) * x W_up) W_down``).  Returns
+    W_down``, else SwiGLU ``(silu(x W_gate) * x W_up) W_down``);
+    ``select_bias`` [E] and ``normalize_eps`` are :func:`moe_route`'s (a
+    bias that chooses the picks and does not weigh them).  Returns
     ``sum over a token's picks that are held of weight * expert(x)`` plus
     ``shared_fn(x)``: with ``H < E`` that is this share's PART of the
     layer (what the absent experts would add is another device's to
@@ -305,7 +367,8 @@ def moe_held_experts(x: jax.Array, w_router: jax.Array, w_up: jax.Array,
     with jax.named_scope("hvdt.moe.route"):
         scores, experts, weights = moe_route(
             x, w_router, top_k=k, score=score, normalize=normalize,
-            scale=scale)
+            scale=scale, select_bias=select_bias,
+            normalize_eps=normalize_eps)
         local = experts.reshape(m) - experts_first            # pick t*k + i
         held = _saved(jnp.logical_and(local >= 0, local < e_held))
         # Held picks first, by expert; the others behind them.

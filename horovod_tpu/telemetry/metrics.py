@@ -592,6 +592,11 @@ CATALOG: Dict[str, MetricSpec] = {
         _m("hvdt_moe_max_expert_rows", "gauge", (),
            "Rows of the fullest held expert in the last reported step "
            "(moe_held_experts; report_moe_aux)"),
+        _m("hvdt_moe_routes_total", "counter", ("select",),
+           "Expert-layer routes traced (moe_route; trace time, a count "
+           "per compiled program), labelled by what chose the picks: "
+           "select=score (the scores themselves) or score_plus_bias (the "
+           "scores plus a selection bias that does not weigh)"),
         _m("hvdt_pipeline_mfu", "gauge", (),
            "Model FLOPs utilization of the last reported pipeline step "
            "(achieved model FLOP/s / peak; report_pipeline_mfu)"),

@@ -73,6 +73,10 @@ TOY_PUBLISHED = {
         shared_intermediate_size=96, intermediate_size=96, mamba_n_heads=8,
         mamba_d_head=8, mamba_d_state=16, mamba_chunk_size=16,
         vocab_size=512, vocab=128),
+    "lfm2_24b_a2b": lambda published: dict(
+        hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+        intermediate_size=96, moe_intermediate_size=32, num_experts=8,
+        num_experts_per_tok=2, experts=4, vocab_size=512, vocab=128),
 }
 
 
@@ -85,7 +89,9 @@ def test_a_published_layer_pattern_model_by_the_same_path(tmp_path, name):
     gated full-attention one; 4 of 16 experts held; EvaByte: EVA attention
     over four windows with 4 of 8 heads held, three prediction heads;
     granite-4.0-h-micro: nine Mamba-2 layers and an attention layer without
-    a position term), through the example's single-device step."""
+    a position term; LFM2-24B-A2B: layers 1-5, short convolutions, a dense
+    layer before four sparse ones routed by score plus bias), through the
+    example's single-device step."""
     import json
 
     with open(os.path.join(REPO, "benchmark", "configs",
@@ -115,7 +121,8 @@ def test_the_presets_of_published_models_name_the_benchmarks_files():
                                       1),
                                      ("evabyte", "evabyte", 32768, 1),
                                      ("granite_h_micro",
-                                      "granite_4_0_h_micro", 8192, 1)):
+                                      "granite_4_0_h_micro", 8192, 1),
+                                     ("lfm2_24b", "lfm2_24b_a2b", 8192, 2)):
         got = example.PRESETS[preset]
         assert os.path.samefile(got["published"], os.path.join(
             REPO, "benchmark", "configs", name + ".json"))

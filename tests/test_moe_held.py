@@ -269,8 +269,11 @@ def _route_of(score, normalize):
     return dict(top_k=3, score=score, normalize=normalize, scale=2.5)
 
 
-def _plain_route(x, w_router, *, top_k, score, normalize, scale):
-    """The route as autodiff alone differentiates it."""
+def _plain_route(x, w_router, *, top_k, score, normalize, scale,
+                 select_bias=None, normalize_eps=0.0):
+    """The route as autodiff alone differentiates it (no selection bias,
+    no epsilon: tests/test_moe_select_bias.py has those)."""
+    assert select_bias is None and not normalize_eps
     z = jnp.dot(x, w_router, precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(z) if score == "sigmoid" else \
         jax.nn.softmax(z, -1)

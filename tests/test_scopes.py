@@ -938,3 +938,82 @@ def test_the_scans_children_account_for_all_of_it(monkeypatch, on_tpu):
         ("chunk", "ssd_chunk_state", False), ("out", "ssd_chunk_out", False),
         ("chunk", "ssd_chunk_state_bwd", True),
         ("out", "ssd_chunk_out_bwd", True)]) if on_tpu else [])
+
+
+# ---------------------------------------------------------------------------
+# The double-gated short convolution (PR 48): hvdt.sconv beside
+# hvdt.attention, hvdt.gdn and hvdt.ssd, its three parts, and the route
+# that picks by score plus bias.
+# ---------------------------------------------------------------------------
+
+
+def short_conv_config():
+    conv = models.LayerKind(heads=0, kv_heads=0, sparse=True,
+                            conv=models.ShortConv(taps=3))
+    full = models.LayerKind(heads=2, kv_heads=1, sparse=True)
+    cfg = models.TransformerConfig(
+        vocab=256, d_model=32, head_dim=16, layers=4,
+        leading=(models.LayerKind(heads=0, kv_heads=0, d_ff=48,
+                                  conv=models.ShortConv(taps=3)),),
+        period=(full, conv, conv), max_seq=64, remat=True, loss_chunk=128,
+        qk_norm=True, moe=models.Experts(
+            held=2, d_ff=16, routed=8, per_token=2, select_bias=True,
+            normalize_eps=1e-6))
+    params = jax.eval_shape(
+        lambda k: models.transformer_init(k, cfg), jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    return jax.value_and_grad(
+        lambda p, t: models.transformer_loss(p, t, cfg)), params, tokens
+
+
+@pytest.fixture(scope="module")
+def short_conv_grad_text():
+    return compiled_text(*short_conv_config())
+
+
+@pytest.mark.parametrize("path", _under(
+    NESTED, "hvdt.sconv", (".in", ".conv", ".out")))
+def test_the_short_convolution_carries_its_scopes(short_conv_grad_text,
+                                                  path):
+    """``hvdt.sconv`` around the whole sublayer, inside it the three parts
+    the benchmark's ``sconv_*`` readers read; forward, recompute and
+    backward, in the scan of the period's run of layers."""
+    assert path in short_conv_grad_text
+
+
+def test_the_short_convolution_is_a_sibling_of_attention(
+        short_conv_grad_text):
+    """Nothing of the mixer is under ``hvdt.attention`` (``attention_ms``
+    keeps meaning softmax attention), the leading layer carries the same
+    names outside the scan, the attention layer of the same period keeps
+    ``hvdt.attention``, and a route that takes a selection bias says so
+    under ``hvdt.moe.route`` in the forward alone."""
+    text = short_conv_grad_text
+    assert "hvdt.attention/hvdt.sconv" not in text
+    assert "hvdt.sconv/hvdt.attention" not in text
+    assert "hvdt.gdn" not in text and "hvdt.ssd" not in text
+    assert re.search(r"while/body/closed_call/hvdt\.attention/", text)
+    assert [line for line in text.splitlines()
+            if "hvdt.sconv/hvdt.sconv.conv/" in line
+            and "while/body" not in line]
+    select = "hvdt.moe/hvdt.moe.route/hvdt.moe.route.select_bias/"
+    assert select in text
+    assert not [line for line in text.splitlines()
+                if select in line and "rematted_computation" in line]
+
+
+def test_the_three_parts_account_for_all_of_the_short_convolution():
+    """Every equation of the mixer under ``jax.grad`` is under exactly one
+    of ``.in``, ``.conv`` and ``.out``."""
+    from horovod_tpu.ops.short_conv import gated_short_conv
+
+    p = {"w_in": jnp.ones((32, 96)), "conv": jnp.ones((3, 32)),
+         "w_out": jnp.ones((32, 32))}
+    grad = jax.grad(lambda x, p: gated_short_conv(
+        x, p, proj=lambda a, w: a @ w).sum(), argnums=(0, 1))
+    stacks = [s for _, s in _name_stacks(jax.make_jaxpr(grad)(
+        jnp.ones((1, 64, 32)), p).jaxpr)]
+    parts = [re.findall(r"hvdt\.sconv\.(\w+)", s) for s in stacks
+             if "jvp()" != s and "transpose(jvp())" != s]   # the test's sum
+    assert len(parts) > 20 and all(len(set(c)) == 1 for c in parts)
+    assert {c[0] for c in parts} == {"in", "conv", "out"}

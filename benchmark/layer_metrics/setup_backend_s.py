@@ -1,0 +1,16 @@
+"""Start-up layer: the XLA backend's first touch inside ``hvd.init()``
+(``jax.process_index()`` to ``jax.devices()``: the client's creation,
+libtpu's start, the chips opened): ``hvdt_startup_seconds{phase="backend"}``.  Moves ``setup_s``.
+
+A reader of the start-up layer returns the process's total when it is
+called.  That is set-up's total: nothing may compile inside the window
+(``correct`` demands ``compiles_in_window == 0``) and the ahead-of-time
+executable the harness calls traces nothing.  None where the program has
+no compile ledger (a parent older than PR 51)."""
+
+from benchmark.layer_metrics.setup_ledger import process_ledger
+
+
+def read(ctx):
+    ledger = process_ledger()
+    return None if ledger is None else ledger.startup.get("backend")

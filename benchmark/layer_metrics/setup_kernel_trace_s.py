@@ -1,0 +1,15 @@
+"""Start-up layer: host seconds inside ``kernel_scope`` blocks
+(``hvdt_kernel_trace_seconds_total``; a part of ``setup_trace_s``).  Moves ``setup_s``.
+
+A reader of the start-up layer returns the process's total when it is
+called.  That is set-up's total: nothing may compile inside the window
+(``correct`` demands ``compiles_in_window == 0``) and the ahead-of-time
+executable the harness calls traces nothing.  None where the program has
+no compile ledger (a parent older than PR 51)."""
+
+from benchmark.layer_metrics.setup_ledger import process_ledger
+
+
+def read(ctx):
+    ledger = process_ledger()
+    return None if ledger is None else ledger.kernel_seconds()

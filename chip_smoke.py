@@ -1023,38 +1023,10 @@ def phase_kernels(kernels=KERNELS, **sizes):
 # ---------------------------------------------------------------------------
 
 
-class CompileLog:
-    """Counts what the persistent compilation cache did in this process
-    (jax.monitoring events): requests, hits, and the backend compiles of
-    >= 1 s that were not hits."""
-
-    def __init__(self):
-        from jax import monitoring
-
-        self.requests = self.hits = 0
-        self.slow = []
-        self._hit = False       # the request being served was a hit
-        monitoring.register_event_listener(self._event)
-        monitoring.register_event_duration_secs_listener(self._duration)
-
-    def _event(self, name, **_):
-        if name == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-            self._hit = False
-        elif name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-            self._hit = True
-
-    def _duration(self, name, secs, **_):
-        # Fires for a hit too (the time to load the executable).
-        if name == "/jax/core/compile/backend_compile_duration" \
-                and secs >= 1.0 and not self._hit:
-            self.slow.append(round(secs, 1))
-
-
 def main() -> int:
     from horovod_tpu.ops import overlap
     from horovod_tpu.step_pipeline import enable_compilation_cache
+    from horovod_tpu.telemetry import compile_ledger
     from horovod_tpu.telemetry.step_stats import peak_flops_for
 
     import jax
@@ -1065,7 +1037,9 @@ def main() -> int:
     # one run and 1.2 s in the next would otherwise never be found again.
     cache_dir = enable_compilation_cache(
         default=os.path.join(ROOT, ".xla_cache"), min_compile_secs=0.5)
-    compiles = CompileLog()
+    # What the persistent cache did in this process: the library's own
+    # account (jax.monitoring), installed by the call above.
+    compiles = compile_ledger.get_ledger()
 
     import horovod_tpu as hvd
 
@@ -1119,9 +1093,11 @@ def main() -> int:
     cached = os.listdir(cache_dir)
     if not cached:
         raise AssertionError(f"compile cache {cache_dir} is empty")
-    say(f"compile cache: {compiles.requests} requests, {compiles.hits} "
-        f"hits, {len(compiles.slow)} cache-miss compiles of >= 1 s "
-        f"{compiles.slow}; "
+    slow = {p.name: round(p.compile_s, 1)
+            for p in compiles.programs.values() if p.compile_s >= 1.0}
+    say(f"compile cache: {compiles.requests} requests, "
+        f"{compiles.builds(hit=True)} hits, {len(slow)} programs with "
+        f">= 1 s of cache-miss compiles {slow}; "
         f"{len(cached)} entries in {cache_dir}")
     if "horovod_tpu.native" in sys.modules:
         raise AssertionError("the jit path loaded the native core")

@@ -20,6 +20,10 @@ Typical use::
 
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # to _IMPORT_SECONDS, this file's end
+
 __version__ = "0.1.0"
 
 from .common.basics import (  # noqa: F401
@@ -151,3 +155,8 @@ def __getattr__(name):
         raise AttributeError(
             f"horovod_tpu.{name} is unavailable: {e}") from e
     raise AttributeError(f"module 'horovod_tpu' has no attribute {name!r}")
+
+
+# hvdt_startup_seconds{phase="import"}: what the statements above took
+# (they pull in jax), handed to telemetry/compile_ledger at its install.
+_IMPORT_SECONDS = _time.perf_counter() - _IMPORT_T0

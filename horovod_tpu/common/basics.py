@@ -31,6 +31,7 @@ import atexit
 import dataclasses
 import os
 import threading
+import time
 from typing import Any, List, Optional, Sequence
 
 from . import config
@@ -207,6 +208,7 @@ def init(
     """
     import jax
 
+    t_init = time.perf_counter()
     with _state.lock:
         if _state.initialized:
             log.debug("init() called twice; ignoring")
@@ -282,6 +284,10 @@ def init(
                 process_id=proc_id,
             )
 
+        # The XLA backend's first touch (the client's creation, libtpu's
+        # start, the chips opened), timed apart from the rest of init():
+        # hvdt_startup_seconds{phase="backend"} and {phase="init"}.
+        t_backend = time.perf_counter()
         p_rank = jax.process_index()
         p_size = jax.process_count()
 
@@ -294,6 +300,7 @@ def init(
             cross_rank_, cross_size_ = p_rank, p_size
 
         devices = jax.devices()
+        backend_s = time.perf_counter() - t_backend
         topo = Topology(
             rank=p_rank,
             size=p_size,
@@ -336,6 +343,14 @@ def init(
         from ..telemetry.step_stats import maybe_publish_expected_cost
 
         maybe_publish_expected_cost()
+
+        from ..telemetry import compile_ledger
+
+        # installed by enable_compilation_cache() above
+        ledger = compile_ledger.get_ledger()
+        ledger.note_startup("backend", backend_s)
+        ledger.note_startup("init",
+                            time.perf_counter() - t_init - backend_s)
 
 
 def shutdown() -> None:

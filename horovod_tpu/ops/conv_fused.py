@@ -36,6 +36,7 @@ import jax.numpy as jnp
 # rows on real TPU; a hand-rolled 8-row check would pass interpret-mode
 # tests and then fail Mosaic lowering on hardware), and the
 # shard_map/check_vma out-shape helper.
+from ..telemetry.compile_ledger import kernel_scope
 from .pallas_kernels import _fit_block, _use_interpret, _vma_kw
 
 __all__ = ["matmul_bn_relu", "conv1x1_bn_relu", "conv1x1_bn_relu_reference",
@@ -154,7 +155,7 @@ def _mm_forward(a, w, scale, bias, relu, block_m, block_n, block_k):
     grid = (m // bm, n // bn, k // bk)
     a, w, scale, bias = _vma_align(a, w, scale, bias)
 
-    with jax.named_scope("hvdt.kernel.conv1x1_bn"):
+    with kernel_scope("conv1x1_bn"):
         return pl.pallas_call(
             functools.partial(_mm_kernel, relu=relu),
             grid=grid,
@@ -304,7 +305,7 @@ def matmul_batch_stats(a: jax.Array, w: jax.Array, *, block_m: int = 512,
     stat_spec = pl.BlockSpec((None, 1, bn), lambda i, j, kk: (i, 0, j))
     stat_shape = jax.ShapeDtypeStruct((m // bm, 1, n), jnp.float32,
                                       **_vma_kw(a, w))
-    with jax.named_scope("hvdt.kernel.conv1x1_bn_stats"):
+    with kernel_scope("conv1x1_bn_stats"):
         z, s1, s2 = pl.pallas_call(
             _mm_stats_kernel,
             grid=grid,

@@ -55,6 +55,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.compile_ledger import kernel_scope
 from .pallas_kernels import _use_interpret, _vma_kw
 
 __all__ = ["fused_adam", "fused_sgd", "fused_update_eligible",
@@ -160,7 +161,7 @@ def _adam_leaf_fused(p, g, m, v, scalars, *, b1, b2, eps, eps_root, wd):
     # tensor operands; m and v are the last two inputs → alias onto the
     # m_new/v_new outputs (in-place moments under donation).
     aliases = {n_in - 1: 1, n_in: 2}
-    with jax.named_scope("hvdt.kernel.fused_adam"):
+    with kernel_scope("fused_adam"):
         d, mo, vo = pl.pallas_call(
             functools.partial(_adam_kernel, b1=b1, b2=b2, eps=eps,
                               eps_root=eps_root, wd=wd),
@@ -310,7 +311,7 @@ def _sgd_leaf_fused(g, m, scalars, *, momentum, nesterov):
     rows = g2.shape[0]
     br = _row_block(rows)
     spec = pl.BlockSpec((br, _LANES), lambda i, *_: (i, 0))
-    with jax.named_scope("hvdt.kernel.fused_sgd"):
+    with kernel_scope("fused_sgd"):
         d, mo = pl.pallas_call(
             functools.partial(_sgd_kernel, momentum=momentum,
                               nesterov=nesterov),

@@ -80,6 +80,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.compile_ledger import kernel_scope
+
 __all__ = ["flash_attention", "flash_attention_stats", "flash_block_update",
            "flash_grad_block", "rope", "moe_sum_rows", "eva_summary_tiles",
            "eva_summary_attention", "attention_reference"]
@@ -258,7 +260,7 @@ def _flash_call(q, k, v, acc, m, l, q_offset, k_offset, *, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)])
-    with jax.named_scope("hvdt.kernel.flash_fwd"):
+    with kernel_scope("flash_fwd"):
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
@@ -768,10 +770,10 @@ def _flash_local_call(q, k, v, *, heads, causal, scale, block_q, block_k,
         window=window, key_blocks=lk // block_k)
     if bd is not None:
         windowed = dict(bd=bd, key_blocks=stream)
-    with jax.named_scope("hvdt.kernel.eva_win_fwd" if eva
-                         else "hvdt.kernel.flash_bd_fwd" if bd is not None
-                         else "hvdt.kernel.flash_fwd" if window is None
-                         else "hvdt.kernel.flash_win_fwd"):
+    with kernel_scope("eva_win_fwd" if eva
+                      else "flash_bd_fwd" if bd is not None
+                      else "flash_fwd" if window is None
+                      else "flash_win_fwd"):
         return pl.pallas_call(
             functools.partial(
                 _local_kernel, causal=causal, scale=scale,
@@ -1059,10 +1061,10 @@ def _flash_local_bwd_call(q, k, v, do, lse, delta, *, heads, causal, scale,
     windowed = {} if window is None else dict(window=window)
     if bd is not None:
         windowed = dict(bd=bd, stream=stream)
-    with jax.named_scope("hvdt.kernel.eva_win_bwd" if eva
-                         else "hvdt.kernel.flash_bd_bwd" if bd is not None
-                         else "hvdt.kernel.flash_bwd" if window is None
-                         else "hvdt.kernel.flash_win_bwd"):
+    with kernel_scope("eva_win_bwd" if eva
+                      else "flash_bd_bwd" if bd is not None
+                      else "flash_bwd" if window is None
+                      else "flash_win_bwd"):
         return pl.pallas_call(
             functools.partial(
                 _local_bwd_kernel, causal=causal, scale=scale,
@@ -1569,7 +1571,7 @@ def flash_grad_block(q, k, v, do, out, lse, *, q_offset=0, k_offset=0,
     col_q = pl.BlockSpec((1, 1, block_q, 1),
                          lambda bb, hh, qq, kk, *_: (bb, hh, qq, 0))
 
-    with jax.named_scope("hvdt.kernel.flash_dq"):
+    with kernel_scope("flash_dq"):
         dq, = pl.pallas_call(
             functools.partial(_dq_kernel, causal=causal, scale=float(scale)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1596,7 +1598,7 @@ def flash_grad_block(q, k, v, do, out, lse, *, q_offset=0, k_offset=0,
                           lambda bb, hh, kk, qq, *_: (bb, hh, kk, 0))
     col_q2 = pl.BlockSpec((1, 1, block_q, 1),
                           lambda bb, hh, kk, qq, *_: (bb, hh, qq, 0))
-    with jax.named_scope("hvdt.kernel.flash_dkv"):
+    with kernel_scope("flash_dkv"):
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, causal=causal, scale=float(scale)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1721,7 +1723,7 @@ def unit_lower_inverse_slabs(cols: jax.Array) -> jax.Array:
     if pad:
         cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, pad),))
     spec = pl.BlockSpec((c, None, c, lanes), lambda gg, mm: (0, gg, 0, mm))
-    with jax.named_scope("hvdt.kernel.gdn_inverse"):
+    with kernel_scope("gdn_inverse"):
         t = pl.pallas_call(
             functools.partial(_inverse_kernel, guarded=_use_interpret()),
             grid=(cols.shape[1], (m + pad) // lanes),
@@ -1802,9 +1804,9 @@ def _gdn_call(kernel, grid, in_specs, out_specs, out_shape, operands, part):
 
     vma = _vma_kw(*operands)
     out_shape = [jax.ShapeDtypeStruct(s, d, **vma) for s, d in out_shape]
-    with jax.named_scope("hvdt.kernel.gdn_chunk_before" if part == "before"
-                         else "hvdt.kernel.gdn_chunk_after" if part == "after"
-                         else "hvdt.kernel.gdn_chunk_bwd"):
+    with kernel_scope("gdn_chunk_before" if part == "before"
+                      else "gdn_chunk_after" if part == "after"
+                      else "gdn_chunk_bwd"):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape,
@@ -2191,10 +2193,8 @@ def _ssd_call(kernel, grid, in_specs, out_specs, out_shape, operands, part):
 
     vma = _vma_kw(*operands)
     out_shape = [jax.ShapeDtypeStruct(s, d, **vma) for s, d in out_shape]
-    with jax.named_scope(("hvdt.kernel.ssd_chunk_state",
-                          "hvdt.kernel.ssd_chunk_out",
-                          "hvdt.kernel.ssd_chunk_state_bwd",
-                          "hvdt.kernel.ssd_chunk_out_bwd")[part]):
+    with kernel_scope(("ssd_chunk_state", "ssd_chunk_out",
+                       "ssd_chunk_state_bwd", "ssd_chunk_out_bwd")[part]):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape,
@@ -2615,7 +2615,7 @@ def _rope_call(x, cos, sin, *, half: int, conj: bool, block):
         for t in (cos, sin))
     xspec = pl.BlockSpec(block, lambda bb, ll, hh: (bb, ll, hh))
     tspec = pl.BlockSpec(block, lambda bb, ll, hh: (bb, ll, 0))
-    with jax.named_scope("hvdt.kernel.rope"):
+    with kernel_scope("rope"):
         return pl.pallas_call(
             functools.partial(_rope_kernel, head_dim=head_dim, half=half,
                               conj=conj),
@@ -2904,7 +2904,7 @@ def moe_sum_rows(rows, inverse, held, segment, *, segments: int):
     rows = pcast_to_union(rows, place)
     smem = functools.partial(pl.BlockSpec, index_map=lambda i: (i,),
                              memory_space=pltpu.SMEM)
-    with jax.named_scope("hvdt.kernel.moe_sum_rows"):
+    with kernel_scope("moe_sum_rows"):
         return pl.pallas_call(
             functools.partial(
                 _moe_sum_rows_kernel, k=k, groups=groups,
@@ -3159,7 +3159,7 @@ def _eva_summary_fwd_call(q, ks, vs, *, heads: int, window: int, per: int):
         q, ks, window, per, heads, _EVA_FWD_TILE)
     d = q.shape[2] // heads
     kw = _vma_kw(q, ks, vs)
-    with jax.named_scope("hvdt.kernel.eva_sum_fwd"):
+    with kernel_scope("eva_sum_fwd"):
         return pl.pallas_call(
             functools.partial(_eva_summary_kernel, **settings),
             grid=grid, in_specs=[rows, summaries, summaries],
@@ -3190,7 +3190,7 @@ def _eva_summary_bwd_calls(q, ks, vs, do, lse, delta, *, heads: int,
     d = q.shape[2] // heads
     kw = _vma_kw(q, ks, vs, do, lse, delta)
     params = pltpu.CompilerParams(vmem_limit_bytes=_FWD_VMEM_LIMIT)
-    with jax.named_scope("hvdt.kernel.eva_sum_dq"):
+    with kernel_scope("eva_sum_dq"):
         dq = pl.pallas_call(
             functools.partial(_eva_summary_dq_kernel, **settings),
             grid=grid,
@@ -3212,7 +3212,7 @@ def _eva_summary_bwd_calls(q, ks, vs, do, lse, delta, *, heads: int,
         lambda bb, hh, tt, ss: (bb, hh, 0, window_seen(tt, ss)))
     summaries = pl.BlockSpec((1, tile, d),
                              lambda bb, hh, tt, ss: (bb, tt, hh))
-    with jax.named_scope("hvdt.kernel.eva_sum_dkv"):
+    with kernel_scope("eva_sum_dkv"):
         dk, dv = pl.pallas_call(
             functools.partial(_eva_summary_dkv_kernel, windows=windows,
                               **settings),

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from ..common import config
 from ..ops.pallas_kernels import _use_interpret, _vma_kw
+from ..telemetry.compile_ledger import kernel_scope
 
 __all__ = [
     "quant_block_size",
@@ -159,7 +160,7 @@ def _quantize_pallas(x2):
     kw = _vma_kw(x2)
     spec = pl.BlockSpec((br, block), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    with jax.named_scope("hvdt.kernel.quantize"):
+    with kernel_scope("quantize"):
         q, s = pl.pallas_call(
             _quant_kernel,
             grid=(nblocks // br,),
@@ -182,7 +183,7 @@ def _dequantize_pallas(q2, scales):
     kw = _vma_kw(q2, scales)
     spec = pl.BlockSpec((br, block), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    with jax.named_scope("hvdt.kernel.dequantize"):
+    with kernel_scope("dequantize"):
         return pl.pallas_call(
             _dequant_kernel,
             grid=(nblocks // br,),
@@ -341,7 +342,7 @@ def _quantize4_pallas(x2):
     spec = pl.BlockSpec((br, block), lambda i: (i, 0))
     pspec = pl.BlockSpec((br, block // 2), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    with jax.named_scope("hvdt.kernel.quantize4"):
+    with kernel_scope("quantize4"):
         p, s = pl.pallas_call(
             _quant4_kernel,
             grid=(nblocks // br,),
@@ -366,7 +367,7 @@ def _dequantize4_pallas(p2, scales):
     pspec = pl.BlockSpec((br, half), lambda i: (i, 0))
     spec = pl.BlockSpec((br, 2 * half), lambda i: (i, 0))
     sspec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
-    with jax.named_scope("hvdt.kernel.dequantize4"):
+    with kernel_scope("dequantize4"):
         return pl.pallas_call(
             _dequant4_kernel,
             grid=(nblocks // br,),

@@ -69,6 +69,11 @@ def enable_compilation_cache(path: Optional[str] = None, *,
 
     import jax
 
+    from .telemetry import compile_ledger
+
+    # Before anything compiles: whichever of this and hvd.init() runs
+    # first puts the process's compile ledger on jax.monitoring.
+    compile_ledger.install()
     from_jax_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
     if from_jax_env:
         path = jax.config.jax_compilation_cache_dir
@@ -123,9 +128,11 @@ def donated_step(fn, *, donate_argnums=(0, 1), compile_cache=None,
     """
     import jax
 
+    from .telemetry import compile_ledger
     from .telemetry.instrument import wrap_step
 
     enable_compilation_cache(compile_cache)
+    compile_ledger.note_step_program(fn)
     return wrap_step(jax.jit(fn, donate_argnums=donate_argnums,
                              **jit_kwargs))
 

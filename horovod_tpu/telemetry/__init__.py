@@ -14,6 +14,11 @@ and ad-hoc module-level ints).  Layering, bottom up:
 * :mod:`~horovod_tpu.telemetry.step_stats` — :class:`StepTimer`
   (step time, examples/s, MFU) and :class:`GoodputLedger` (time lost to
   recompiles / restores / recovered faults);
+* :mod:`~horovod_tpu.telemetry.compile_ledger` — set-up seen from
+  inside: the package's one ``jax.monitoring`` consumer (what was traced,
+  lowered, compiled or loaded from the persistent cache, per program and
+  per ``hvdt.kernel.*`` site, and the start-up phases JAX does not name);
+  always on, it runs only when JAX compiles;
 * :mod:`~horovod_tpu.telemetry.straggler` — cross-rank step-duration
   skew detection publishing a ``straggler_rank`` gauge;
 * :mod:`~horovod_tpu.telemetry.exporter` — per-worker ``/metrics`` +

@@ -440,6 +440,30 @@ CATALOG: Dict[str, MetricSpec] = {
            "Recovery-time-budget seconds by phase (checkpoint_snapshot "
            "| checkpoint_write | rendezvous | compile | restore | "
            "replay)"),
+        # --- start-up from inside (telemetry/compile_ledger.py) ---
+        _m("hvdt_compile_seconds_total", "counter", ("stage", "role"),
+           "Seconds JAX spent bringing programs to the device, by stage "
+           "(trace: outermost spans only | lower | cache_load: backend "
+           "spans the persistent cache served | compile: those it did "
+           "not) and role (step: programs donated_step built | other)"),
+        _m("hvdt_compiles_total", "counter", ("cache", "role"),
+           "Backend builds, by what the persistent cache did (hit: "
+           "loaded | miss: compiled) and role"),
+        _m("hvdt_recompiles_total", "counter", ("program",),
+           "Builds of a donated_step program after its first (new "
+           "shapes, or a dropped cache), by program"),
+        _m("hvdt_compile_cache_saved_seconds_total", "counter", (),
+           "Compile seconds the persistent cache saved (JAX's "
+           "compile_time_saved_sec, over the loads that saved any)"),
+        _m("hvdt_startup_seconds", "gauge", ("phase",),
+           "Start-up seconds JAX does not name, by phase (import: the "
+           "package's | init: hvd.init() less the backend | backend: "
+           "the XLA backend's first touch)"),
+        _m("hvdt_kernel_traces_total", "counter", ("kernel",),
+           "Times a hvdt.kernel.* site's body was traced, by kernel"),
+        _m("hvdt_kernel_trace_seconds_total", "counter", ("kernel",),
+           "Host seconds inside a hvdt.kernel.* site's block (trace "
+           "time: the block never runs with the program), by kernel"),
         _m("hvdt_injected_faults", "gauge", (),
            "Faults the HVDT_FAULT_PLAN injector has fired"),
         _m("hvdt_emergency_checkpoints", "gauge", (),

@@ -304,7 +304,9 @@ def test_every_pallas_call_site_lowers_under_its_name(build, kernel):
 
 def test_no_pallas_call_site_is_left_without_a_name():
     """A new ``pl.pallas_call`` arrives with a ``hvdt.kernel.`` scope in
-    the ``with`` statement above it (a site that lowers under one of
+    the ``with`` statement above it, entered through ``kernel_scope``
+    (``telemetry/compile_ledger.py``, PR 51: the scope, and a count of the
+    site's traces) (a site that lowers under one of
     several names, as the local flash calls do under a window, under the
     block mask, on EVA's windows and under none, names them all there), and
     with a case in
@@ -323,11 +325,57 @@ def test_no_pallas_call_site_is_left_without_a_name():
                 while not lines[start].lstrip().startswith("with "):
                     start -= 1
                 statement = " ".join(lines[start:i])
-                assert "named_scope(" in statement and i - start <= 4
-                names = re.findall(r'"hvdt\.kernel\.(\w+)"', statement)
+                assert "kernel_scope(" in statement and i - start <= 4
+                # every quoted word but what ``part`` is compared with
+                names = re.findall(r'(?<!== )"(\w+)"', statement)
                 assert names, f"{mod.__name__}:{i + 1} has no kernel scope"
                 named.extend(names)
     assert sorted(named) == sorted(k.split(".")[0] for _, k in KERNEL_SITES)
+
+
+def test_no_kernel_scope_is_entered_but_through_the_ledger():
+    """Every ``hvdt.kernel.*`` site of the package goes through
+    ``kernel_scope``: no literal is left beside it."""
+    import inspect
+
+    from horovod_tpu.ops import conv_fused, optim_kernels, pallas_kernels
+    from horovod_tpu.quant import kernels as quant_kernels
+
+    for mod in (pallas_kernels, conv_fused, optim_kernels, quant_kernels):
+        source = inspect.getsource(mod)
+        assert not re.search(r'named_scope\(\(?"hvdt\.kernel\.', source)
+        assert "kernel_scope(" in source
+
+
+def test_kernel_scope_counts_a_trace_a_shape_and_none_on_a_second_call():
+    """``kernel_scope`` is ``jax.named_scope("hvdt.kernel.<name>")`` (the
+    lowered name is the one above) and counts one trace of the site a
+    distinct shape; a second call of the same jitted entry traces
+    nothing, so it counts nothing."""
+    from horovod_tpu.ops import pallas_kernels as pk
+    from horovod_tpu.telemetry import compile_ledger, default_registry
+
+    site = compile_ledger.get_ledger().kernels.setdefault(
+        "rope", compile_ledger.KernelSite())
+    counter = default_registry().counter("hvdt_kernel_traces_total")
+    before = site.traces, site.seconds, counter.value(kernel="rope")
+    entry = jax.jit(lambda x, table: pk._rope_call(
+        x, table, table, half=32, conj=False, block=(1, 16, 128)))
+
+    def args(seq):
+        return jnp.ones((1, seq, 128)), jnp.ones((1, seq, 64))
+
+    assert "hvdt.kernel.rope" in entry.lower(*args(16)).as_text(
+        debug_info=True)
+    assert site.traces == before[0] + 1 and site.seconds > before[1]
+    entry(*args(16))                    # the shape the lowering traced
+    assert site.traces == before[0] + 1
+    entry(*args(32))
+    assert site.traces == before[0] + 2
+    entry(*args(32))
+    entry(*args(16))
+    assert site.traces == before[0] + 2
+    assert counter.value(kernel="rope") - before[2] == site.traces - before[0]
 
 
 def test_lm_gradient_runs_flash_attention_as_three_bare_calls(monkeypatch):
